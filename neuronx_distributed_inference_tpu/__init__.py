@@ -17,45 +17,6 @@ Import as ``import neuronx_distributed_inference_tpu as nxdi_tpu``.
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "set_mesh"):
-    # jax < 0.6 compat: ``jax.set_mesh`` supersedes the legacy ``with mesh:``
-    # resource-env context. Real set_mesh works BOTH as a plain statement
-    # (sets the mesh for the rest of the scope) and as a context manager —
-    # mirror that: the legacy mesh context is entered at call time (so the
-    # statement form takes effect immediately, thread-locally) and exited at
-    # ``__exit__`` for the ``with`` form (whose net scope is identical).
-    # Known approximation: statement-form calls are set-once (they stack,
-    # never pop) and ``jax.set_mesh(None)`` cannot clear an earlier mesh —
-    # sufficient for this package, which only uses the ``with`` form.
-    class _SetMeshCompat:
-        def __init__(self, mesh):
-            self._mesh = mesh
-            self._active = mesh is not None
-            if self._active:
-                mesh.__enter__()
-
-        def __enter__(self):
-            return self._mesh
-
-        def __exit__(self, *exc):
-            if self._active:
-                self._active = False
-                return self._mesh.__exit__(*exc)
-            return None
-
-    _jax.set_mesh = _SetMeshCompat
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams"):
-        # jax < 0.6 compat: TPUCompilerParams was renamed CompilerParams
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:  # pragma: no cover - pallas always ships with jax[tpu]
-    pass
-
 from neuronx_distributed_inference_tpu.config import (  # noqa: F401
     InferenceConfig,
     TpuConfig,

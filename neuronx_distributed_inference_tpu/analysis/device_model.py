@@ -604,8 +604,8 @@ def compare_report(path: str) -> str:
         lines.append("  (no comparable tok/s keys found in the summary)")
     lines.append(
         "projections are nameplate lower bounds on time: measured/projected"
-        " - 1 near 0 means device-limited; strongly negative means host/"
-        "relay gap or model error — see PERF.md 'Static roofline cost model'"
+        " - 1 near 0 means device-limited; strongly negative means a host "
+        "gap or model error — see PERF.md 'Static roofline cost model'"
     )
     return "\n".join(lines)
 

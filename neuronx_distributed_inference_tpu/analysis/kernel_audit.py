@@ -523,7 +523,7 @@ def _instance_signature(spec, case, tiles):
 
     try:
         inst = kr.instantiate(spec, case, tiles=tiles)
-    except Exception:
+    except ValueError:
         return None  # the wrapper itself rejects the tiling
     budget = get_device().vmem_bytes
     if vmem_findings(inst.key, "x", inst.vmem_bytes, budget):

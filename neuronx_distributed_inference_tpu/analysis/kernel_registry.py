@@ -523,7 +523,7 @@ def hand_picked_tiles(table_kernel: str, shape_class: str) -> Optional[Dict[str,
 def _sub_jaxprs(eqn):
     """Every sub-jaxpr an equation carries — including tuple-valued params
     (``cond``'s ``branches``)."""
-    import jax.core as jcore
+    from jax.extend import core as jcore
 
     out = []
     for v in eqn.params.values():
@@ -536,13 +536,24 @@ def _sub_jaxprs(eqn):
     return out
 
 
-def _find_pallas_eqns(jaxpr):
+def _block_dim(d) -> int:
+    """Size of one BlockSpec dimension as the traced grid mapping carries
+    it: ``pl.Blocked(n)`` / ``pl.Element(n)`` wrap the size, a squeezed
+    (``None``) dimension holds one element."""
+    from jax.experimental import pallas as pl
+
+    if isinstance(d, pl.Squeezed):
+        return 1
+    return int(d.block_size)
+
+
+def find_pallas_eqns(jaxpr):
     hits = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             hits.append(eqn)
         for sub in _sub_jaxprs(eqn):
-            hits.extend(_find_pallas_eqns(sub))
+            hits.extend(find_pallas_eqns(sub))
     return hits
 
 
@@ -590,18 +601,18 @@ def instantiate(
         ctx = contextlib.nullcontext()
     with ctx:
         jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args)
-    eqns = _find_pallas_eqns(jaxpr.jaxpr)
+    eqns = find_pallas_eqns(jaxpr.jaxpr)
     if not eqns:
         raise RuntimeError(f"{spec.name}/{case.shape_class}: no pallas_call traced")
     eqn = eqns[0]
     gm = eqn.params["grid_mapping"]
     blocks: List[BlockInfo] = []
     for i, bm in enumerate(gm.block_mappings):
-        sd = bm.array_shape_dtype
+        sd = bm.array_aval
         blocks.append(
             BlockInfo(
                 role="in" if i < gm.num_inputs else "out",
-                block_shape=tuple(int(d) for d in bm.block_shape),
+                block_shape=tuple(_block_dim(d) for d in bm.block_shape),
                 array_shape=tuple(int(d) for d in sd.shape),
                 dtype=str(sd.dtype),
                 itemsize=int(np.dtype(sd.dtype).itemsize),

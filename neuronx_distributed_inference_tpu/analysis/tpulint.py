@@ -60,8 +60,7 @@ The rules encode contracts the runtime relies on but Python cannot enforce:
   body is only ``pass`` in ``runtime/`` or ``telemetry/``. A swallowed
   failure on a serving or observability path is an invisible leak — the
   containment story (typed degradation, loud failure) depends on every
-  broad catch either handling or re-raising. Catch the typed class (see the
-  narrowed ``compilation_cache`` guard in runtime/application.py) or let it
+  broad catch either handling or re-raising. Catch the typed class or let it
   propagate. The lifecycle audit (LIFE803) carries the ERROR-level version
   for runtime/.
 - **TPU108 large-unsharded-constant** (warning, baselined — zero entries

@@ -546,6 +546,8 @@ class TpuConfig:
     logical_nc_config: int = 1  # kept for config-surface parity; no-op on TPU
     skip_warmup: bool = False
     save_sharded_checkpoint: bool = False
+    # persistent XLA cache directory; JAX_COMPILATION_CACHE_DIR, when set,
+    # wins over it (utils/compile_cache.py)
     compilation_cache_dir: Optional[str] = None
     scratchpad_page_size: Optional[int] = None  # parity no-op
 

@@ -54,7 +54,9 @@ def build_parser() -> argparse.ArgumentParser:
     # model) — enforced in main() so the trace generator runs standalone
     run.add_argument("--model-path", default=None)
     run.add_argument("--compiled-model-path", default=None)
-    run.add_argument("--compilation-cache-dir", default=None)
+    run.add_argument("--compilation-cache-dir", default=None,
+                     help="persistent XLA cache directory (JAX_COMPILATION_CACHE_DIR, "
+                          "when set, wins; default <checkout>/.bench_cache/xla)")
     run.add_argument("--random-weights", action="store_true",
                      help="skip checkpoint load; random weights (perf/testing)")
 

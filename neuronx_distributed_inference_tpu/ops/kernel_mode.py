@@ -7,7 +7,13 @@ breaks only on hardware (r1 ``_pick_chunk``; r3 the flash ``key_valid``
 BlockSpec). :func:`force_compiled_kernels` flips the wrappers to emit real
 Mosaic kernels regardless of host backend, so the suite can AOT-lower every
 kernel (and whole model programs) for the TPU target from a CPU host via
-``jax.export(..., platforms=["tpu"])`` — see tests/test_tpu_lowering.py.
+``jax.export(..., platforms=["tpu"])`` — see tests/test_tpu_lowering.py —
+and COMPILE them for a described chip (tests/test_chip_compile.py).
+
+Whether a kernel is compiled or interpreted hangs on ``jax.default_backend()``
+alone, and every auto gate below falls to the native path in silence when it
+is not "tpu". Nothing on the chip path may lean on that unverified:
+``chip_smoke.py`` phase 3 reads the kernels out of the compiled executables.
 
 Dispatch gates
 --------------

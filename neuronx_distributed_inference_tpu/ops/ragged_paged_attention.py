@@ -305,7 +305,6 @@ def _dispatch_ragged_kernel(
     per-head math on each shard with NO cross-shard collectives inside, and
     the tp>1 stream stays byte-identical to tp=1 and to the native fallback
     (pinned in tests/test_ragged_tp.py)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from neuronx_distributed_inference_tpu.parallel.mesh import (
@@ -341,9 +340,9 @@ def _dispatch_ragged_kernel(
             interpret=interpret,
         )
 
-    return shard_map(
+    return jax.shard_map(
         per_shard, mesh=mesh, in_specs=tuple(in_specs), out_specs=head,
-        check_rep=False,
+        check_vma=False,
     )(*args)
 
 

@@ -113,24 +113,23 @@ def build_mesh(
         raise ValueError(f"mesh shape {shape} needs {n} devices, have {len(devices)}")
     devices = devices[:n]
     if ddp_degree > 1 and jax.process_count() > 1:
-        try:
-            dev_array = mesh_utils.create_hybrid_device_mesh(
-                (1,) + shape[1:], (ddp_degree, 1, 1, 1, 1), devices=devices
-            )
-            return Mesh(dev_array, FULL_AXES)
-        except Exception as e:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "hybrid ICI/DCN mesh construction failed (%s); falling back "
-                "to a flat device mesh — the ddp axis may land on ICI and "
-                "tensor-parallel collectives on DCN, which is SLOW. Check "
-                "that ddp_degree matches the slice count.",
-                e,
-            )
-    try:
+        # a failure here is an error, not a reason to flatten: on a flat
+        # mesh the ddp axis may land on ICI and the tensor-parallel
+        # collectives on DCN. The outer granule is the slice where the
+        # devices span several (TPU multi-slice), else the process
+        # (several hosts on one slice; multi-process CPU)
+        n_slices = len({getattr(d, "slice_index", 0) for d in devices})
+        dev_array = mesh_utils.create_hybrid_device_mesh(
+            (1,) + shape[1:], (ddp_degree, 1, 1, 1, 1), devices=devices,
+            process_is_granule=n_slices == 1,
+        )
+    elif n == len(jax.devices()):
+        # the whole platform: topology-aware order, and what it refuses
+        # propagates
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    else:
+        # an explicit subset (one replica's partition, tp < chip count):
+        # the caller's device order IS the layout
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, FULL_AXES)
 
@@ -191,15 +190,14 @@ def sharding_str(sharding) -> str:
     return pspec_str(spec)
 
 
-def ambient_mesh() -> Optional[Mesh]:
-    """The physical mesh of the enclosing ``jax.set_mesh`` / ``with mesh:``
-    scope, or None outside one. Readable at TRACE time from inside jit —
-    how the ragged mixed-step dispatch finds the mesh to ``shard_map`` the
-    kernel over without threading it through model code
-    (ops/ragged_paged_attention.ragged_attention)."""
-    from jax._src import mesh as _mesh_lib
-
-    m = _mesh_lib.thread_resources.env.physical_mesh
+def ambient_mesh():
+    """The (abstract) mesh of the enclosing ``jax.set_mesh`` scope, or None
+    outside one. Readable at TRACE time from inside jit — how the ragged
+    mixed-step dispatch finds the mesh to ``jax.shard_map`` the kernel over
+    without threading it through model code
+    (ops/ragged_paged_attention.ragged_attention). Axis names and sizes
+    only: it carries no devices."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

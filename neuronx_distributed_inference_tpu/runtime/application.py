@@ -51,6 +51,7 @@ from neuronx_distributed_inference_tpu.runtime.model_runner import (
     TAG_TOKEN_GENERATION,
 )
 from neuronx_distributed_inference_tpu.telemetry.tracing import default_session
+from neuronx_distributed_inference_tpu.utils.compile_cache import configure_compile_cache
 from neuronx_distributed_inference_tpu.utils.hf_checkpoint import load_state_dict
 
 
@@ -215,6 +216,7 @@ class TpuModelForCausalLM:
         """Load weights onto the mesh + allocate the KV cache
         (reference application_base.py:317-419)."""
         tc = self.config.tpu_config
+        configure_compile_cache(tc.compilation_cache_dir)
         from neuronx_distributed_inference_tpu.ops.quant import (
             has_quantized_checkpoint,
             load_quantized_checkpoint,
@@ -239,10 +241,14 @@ class TpuModelForCausalLM:
             pspecs = quantized_pspecs(self.builder.param_pspecs(), params)
         else:
             if random_weights:
-                # quantize-at-load: generate on host so the full-precision
-                # model never stages in HBM (int8 8B on a 16G chip)
+                # generate on host whenever the full-precision model must
+                # not stage on ONE chip: quantize-at-load (int8 8B on a 16G
+                # chip), and any multi-device mesh — device-side generation
+                # builds every leaf whole on the default device before
+                # shard_pytree re-places it, and a tp-sharded bf16 8B does
+                # not pass through one 16G chip
                 params = self.builder.random_params(
-                    on_host=tc.quantized or tc.weight_int4
+                    on_host=tc.quantized or tc.weight_int4 or self.mesh.size > 1
                 )
             else:
                 sd = state_dict if state_dict is not None else load_state_dict(
@@ -390,8 +396,9 @@ class TpuModelForCausalLM:
 
     def compile(self, compiled_model_path: Optional[str] = None):
         """AOT-compile every (sub-model, bucket) program
-        (reference application_base.py:292-315). With the persistent XLA
-        compilation cache this also serves as the on-disk artifact.
+        (reference application_base.py:292-315). The persistent XLA
+        compilation cache (utils/compile_cache.py says where it lives) keeps
+        the executables across processes.
 
         With ``save_sharded_checkpoint`` a PRESHARDED weight artifact lives
         next to the cache (utils/presharded.py; reference
@@ -400,23 +407,11 @@ class TpuModelForCausalLM:
         resharding.
         """
         tc = self.config.tpu_config
+        configure_compile_cache(tc.compilation_cache_dir)
         presharded_dir = None
         if compiled_model_path:
             os.makedirs(compiled_model_path, exist_ok=True)
             self.config.save(compiled_model_path)
-            cache_dir = tc.compilation_cache_dir or os.path.join(
-                compiled_model_path, "xla_cache"
-            )
-            # best-effort: an unavailable XLA cache only costs compile time
-            # — but only the TYPED unavailability classes are swallowed
-            # (import drift, an already-initialized cache, an unwritable
-            # dir); anything else propagates (tpulint TPU110)
-            try:
-                from jax.experimental.compilation_cache import compilation_cache
-
-                compilation_cache.set_cache_dir(cache_dir)
-            except (ImportError, RuntimeError, OSError, ValueError):
-                pass
             presharded_dir = os.path.join(compiled_model_path, "presharded")
         # LoRA-attached trees never round-trip through the artifact: adapter
         # identity isn't part of the fingerprint, and serving adapter weights

@@ -175,6 +175,6 @@ def test_use_quant_matmul_refuses_model_sharded_mesh():
     mesh = build_mesh(tp_degree=2)
     with km.quant_matmul_mode(True):
         assert km.use_quant_matmul(8, 512, 512)
-        with mesh:
+        with jax.set_mesh(mesh):
             assert not km.use_quant_matmul(8, 512, 512)
         assert km.use_quant_matmul(8, 512, 512)

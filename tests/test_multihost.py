@@ -68,17 +68,9 @@ _WORKER = textwrap.dedent(
 
     import jax
     jax.config.update("jax_platforms", "cpu")
-    # 2 virtual CPU devices per process: the config knob on new jax; on
-    # jax < 0.5 fall back to the XLA flag, which the backend reads at first
-    # device use (still ahead of us here). Never set both — new jax rejects
-    # the combination at backend init.
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=2"
-        )
+    # 2 virtual CPU devices per process (never together with the XLA flag:
+    # jax rejects the combination at backend init)
+    jax.config.update("jax_num_cpu_devices", 2)
     import numpy as np
 
     port, pid = sys.argv[1], int(sys.argv[2])

@@ -274,7 +274,7 @@ def test_lower_sharded_mixed_step_program_tp2():
         chain_tokens=sds((R, 1), jnp.int32),
     )
     fn = functools.partial(mixed_forward, spec=spec)
-    with mesh, force_compiled_kernels():
+    with jax.set_mesh(mesh), force_compiled_kernels():
         exp = export.export(jax.jit(fn), platforms=["tpu"])(
             params, cache, inputs, None
         )
