@@ -1,0 +1,259 @@
+"""Build the system under test from a configuration file: the application,
+its weights on the device(s) from the seed, and the warm-up of the shapes a
+cell can reach. The only file of the benchmark that imports the program.
+
+What is copied from the repo's own tools, and why it is a copy: the
+construction follows ``bench.build_app`` (block_kv branch) and the weight
+hand-over follows ``chip_smoke.build(weights_from=...)``; ``CompileLog`` is
+``chip_smoke.CompileLog``. PR 21 proved all three on the chip. The benchmark
+keeps its own so that a later PR can change those tools, not the yardstick.
+"""
+
+from __future__ import annotations
+
+import copy
+import os
+from typing import Dict, Iterable, List, Optional, Tuple
+
+#: keys of a configuration file that describe the benchmark's use of the
+#: model; every other top-level key is an attribute of the model's config
+META_KEYS = frozenset(
+    {"name", "source", "deployment", "assumed", "reduced", "tpu_config",
+     "chunked_prefill", "why", "rehearsal", "notes", "memory"}
+)
+
+WEIGHT_STD = 0.02
+
+
+class CompileLog:
+    """Counts what reaches the compiler, through ``jax.monitoring``: every
+    jit-cache miss ends in one backend-compile event (a real compile or a
+    retrieval from the persistent cache)."""
+
+    BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+    CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration, **_):
+        if event == self.BACKEND_COMPILE:
+            self.compiles += 1
+            self.compile_s += duration
+
+    def _on_event(self, event, **_):
+        if event == self.CACHE_HIT:
+            self.cache_hits += 1
+        elif event == self.CACHE_MISS:
+            self.cache_misses += 1
+
+    def facts(self) -> dict:
+        return {"compiles": self.compiles, "compile_s": self.compile_s,
+                "cache_hits": self.cache_hits, "cache_misses": self.cache_misses}
+
+
+def configure_cache() -> str:
+    """The persistent compilation cache, at the place the program's own
+    helper decides (``JAX_COMPILATION_CACHE_DIR`` if set, else the fixed
+    ``<checkout>/.bench_cache/xla``), holding EVERY program: the default
+    thresholds keep the many programs that compile in under a second out of
+    it, and a run pays for them again each time."""
+    import jax
+
+    from neuronx_distributed_inference_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    path = configure_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
+
+
+def resolve_config(config: dict, rehearsal: bool) -> dict:
+    """The configuration as run: the file itself, or, for the CPU rehearsal,
+    the file with its ``rehearsal`` overrides laid over it."""
+    cfg = copy.deepcopy(config)
+    if rehearsal:
+        over = cfg.get("rehearsal") or {}
+        for key, val in over.get("model", {}).items():
+            cfg[key] = val
+        cfg["tpu_config"] = {**cfg["tpu_config"], **over.get("tpu_config", {})}
+        cfg["chunked_prefill"] = {**cfg.get("chunked_prefill", {}), **over.get("chunked_prefill", {})}
+    return cfg
+
+
+def model_attrs(cfg: dict) -> dict:
+    return {k: v for k, v in cfg.items() if k not in META_KEYS}
+
+
+def build_app(cfg: dict, devices, seed: int, *, tpu_overrides: Optional[dict] = None,
+              chunked_overrides: Optional[dict] = None):
+    """The application exactly as a deployment builds it — paged cache,
+    chunked prefill, continuous batching — with NO weights and no cache yet.
+    Every TpuConfig option the file does not set stays at the program's
+    default."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig, TpuConfig
+    from neuronx_distributed_inference_tpu.models import get_model_builder
+    from neuronx_distributed_inference_tpu.parallel.mesh import mesh_from_config
+    from neuronx_distributed_inference_tpu.runtime.application import TpuModelForCausalLM
+
+    opts = {**cfg["tpu_config"], **(tpu_overrides or {})}
+    cp = {**cfg.get("chunked_prefill", {}), **(chunked_overrides or {})}
+    tc = TpuConfig(seed=int(seed) % (2**31), chunked_prefill_config=ChunkedPrefillConfig(**cp), **opts)
+    attrs = model_attrs(cfg)
+    config_cls = get_model_builder(attrs["model_type"]).config_cls
+    icfg = config_cls(tc, load_config=lambda c: [setattr(c, k, v) for k, v in attrs.items()])
+    return TpuModelForCausalLM(None, icfg, mesh=mesh_from_config(tc, devices=list(devices)))
+
+
+def _is_norm(path: Tuple[str, ...]) -> bool:
+    return any("norm" in p for p in path)
+
+
+def make_weights(app, seed: int):
+    """(params, pspecs): the whole parameter tree made on the device(s) in
+    ONE jitted call from ``seed``, in the dtype it is served in, each leaf
+    born with the sharding the builder declares — nothing passes through
+    the host or through one chip. Matrices are N(0, 0.02), layer by layer
+    (a float32 temporary of one layer, not of the stack); norm weights are
+    1 + N(0, 0.05), so that a norm weight applied wrongly shows against the
+    reference; a tied model gets the transposed head the program keeps."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from neuronx_distributed_inference_tpu.config import to_dtype
+    from neuronx_distributed_inference_tpu.modules.rope import compute_inv_freq
+
+    b = app.builder
+    dtype = to_dtype(app.config.tpu_config.dtype)
+    shapes = b.param_shapes()
+    pspecs = b.param_pspecs()
+    tied = "lm_head" not in shapes
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple)
+    )
+    paths = [tuple(getattr(k, "key", str(k)) for k in kp) for kp, _ in flat]
+    inv_freq = compute_inv_freq(app.config)
+
+    def one(key, path, shape):
+        if path[0] == "rope":
+            return inv_freq
+        if _is_norm(path):
+            return (1.0 + 0.05 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+        if len(shape) == 3:  # stacked over layers
+            keys = jax.random.split(key, shape[0])
+            return jax.lax.map(
+                lambda k: (WEIGHT_STD * jax.random.normal(k, shape[1:], jnp.float32)).astype(dtype),
+                keys,
+            )
+        return (WEIGHT_STD * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+    def generate(key):
+        keys = jax.random.split(key, len(flat))
+        leaves = [one(k, p, s) for k, p, (_, s) in zip(keys, paths, flat)]
+        params = jax.tree_util.tree_unflatten(treedef, leaves)
+        if tied:
+            params["lm_head"] = {"weight": params["embed_tokens"]["weight"].T}
+        return params
+
+    def sharding(spec):
+        return NamedSharding(app.mesh, spec if spec is not None else P())
+
+    out_shardings = jax.tree.map(sharding, pspecs, is_leaf=lambda x: isinstance(x, P) or x is None)
+    # a large seed folds into the key's two 32-bit words
+    key = jax.random.fold_in(jax.random.PRNGKey(int(seed) % (2**31)), int(seed) >> 31)
+    with jax.set_mesh(app.mesh):
+        params = jax.jit(generate, out_shardings=out_shardings)(key)
+    jax.block_until_ready(params)
+    return params, pspecs
+
+
+def give_weights(app, params, pspecs):
+    """Hand a parameter tree to an application and give it a fresh cache."""
+    app.params, app._pspecs = params, pspecs
+    app.init_kv_cache()
+
+
+def reachable_shapes(app, max_prompt: int, max_context: int) -> List[Tuple[int, int]]:
+    """(q length, kv bucket) of every program the split serving step can
+    dispatch for prompts up to ``max_prompt`` and contexts up to
+    ``max_context``: a decode step (q=1) at each kv bucket a context can
+    fall in, and a prefill chunk at each rung of the program's q ladder for
+    each kv bucket a prompt position can fall in."""
+    from neuronx_distributed_inference_tpu.modules import autobucketing
+
+    tc = app.config.tpu_config
+    buckets = app.token_generation_model.buckets
+    top_ctx = autobucketing.get_target_bucket(buckets, min(max_context, tc.seq_len))
+    top_prompt = autobucketing.get_target_bucket(buckets, max_prompt)
+    shapes = [(1, b) for b in buckets if b <= top_ctx]
+    for q in autobucketing.generate_chunk_q_buckets(tc):
+        shapes += [(q, b) for b in buckets if b <= top_prompt]
+    return shapes
+
+
+def warm_up(app, shapes: Iterable[Tuple[int, int]]):
+    """Run each program once on inputs that write to the garbage block (the
+    program's own warm-up does the same for ALL its shapes). A decode step
+    is run twice: the serving loop feeds it token ids from the host on a
+    row's first step and ids still on the device (the previous step's
+    output, chained) after that, and jit keeps a program for each. An
+    application that serves through the ragged mixed step has one family
+    of programs and its own warm-up for it."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    if app.mixed_step_model is not None:
+        app.warmup()
+        return
+    tkg = app.token_generation_model
+    for q, bucket in shapes:
+        inputs = tkg.example_inputs(bucket, q_len=q if q > 1 else None)
+        out = tkg(app.params, app.kv_cache, inputs, None)
+        if q == 1:
+            chained = jnp.where(
+                jnp.ones(inputs.input_ids.shape, bool),
+                out.tokens[:, -1:].astype(jnp.int32), inputs.input_ids,
+            )
+            out = tkg(app.params, out.cache, dataclasses.replace(inputs, input_ids=chained), None)
+        app.kv_cache = out.cache
+    jax.block_until_ready(app.kv_cache)
+
+
+def kernel_census(app, shapes: Iterable[Tuple[int, int]]) -> Dict[str, int]:
+    """{"decode": n, "prefill": n}: the number of ``tpu_custom_call`` in the
+    compiled decode step (q=1) and in the compiled full prefill chunk
+    (largest q), each at the widest kv bucket the cell reaches. Whether a
+    Pallas kernel is IN a program is read from the executable, not from a
+    gate (as ``chip_smoke.kernel_census``)."""
+    shapes = list(shapes)
+    tkg = app.token_generation_model
+    out = {}
+    for name, pick in (("decode", [s for s in shapes if s[0] == 1]),
+                       ("prefill", [s for s in shapes if s[0] > 1])):
+        if not pick:
+            continue
+        q, bucket = max(pick)
+        inputs = tkg.example_inputs(bucket, q_len=q if q > 1 else None)
+        _, _, compiled = tkg.trace_program(app.params, app.kv_cache, inputs, None)
+        out[name] = compiled.as_text().count("tpu_custom_call")
+    return out
+
+
+def cache_dir_listing(path: str) -> int:
+    try:
+        return len(os.listdir(path))
+    except OSError:
+        return 0

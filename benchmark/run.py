@@ -1,0 +1,293 @@
+#!/usr/bin/env python3
+"""One run of one cell:
+
+    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+A new process finds the cell's chips or fails, builds the model from the
+cell's configuration file, makes its bf16 weights on the device(s) from
+``--seed``, checks the served logits against the plain reference, warms the
+shapes the cell's traffic can reach, offers the traffic for ``--seconds`` on
+the wall clock, and prints ONE last line of JSON: ``correct``, ``attempted``,
+``failed``, ``metrics``, ``device`` and, traced, ``breakdown``. With
+``--trace 0`` the metrics are the cell's end-to-end metrics; with
+``--trace 1`` a slice at the end of the window is profiled and the metrics
+are the cell's per-layer metrics. Earlier lines are JSON facts of the run.
+
+This file knows no cell, mix, configuration or metric by name: it finds them
+through ``harness/catalog.py`` by the names in ``BENCHMARK.json``.
+``--rehearsal 1`` (the selftest's) lays the configuration's tiny
+``rehearsal`` preset over it and lets a CPU through; its line names the
+device ``cpu`` and carries counts only.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS = time.perf_counter()
+
+import argparse  # noqa: E402
+import importlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))  # the checkout: the program and `benchmark`
+
+#: seconds profiled at the end of a traced window: some ten steps of the
+#: system as it is; stopping the profiler takes seconds, and at the end of the
+#: window it disturbs nothing that is measured
+TRACE_SLICE_S = 6.0
+
+
+def emit(**facts):
+    print(json.dumps(facts), flush=True)
+
+
+def parse(argv):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearsal", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--catalog-root", default=None,
+                    help="selftest and knee sweep: a directory with another BENCHMARK.json "
+                         "and benchmark/ data files (cells at other rates are data)")
+    ap.add_argument("--out", default=None,
+                    help="also write the run's facts (and a traced run's trace summary) here")
+    return ap.parse_args(argv)
+
+
+class Profiler:
+    """Starts and stops the profiler from the driver's loop: the last
+    ``TRACE_SLICE_S`` seconds of the window. The device is drained (a tiny
+    program queued behind everything dispatched so far, and waited for)
+    before the profiler starts and, inside a driver span, before it stops:
+    the trace then holds the device work of exactly the steps the driver
+    ran in between, whole, and the readers count the work of those steps
+    and no other."""
+
+    def __init__(self, trace_dir: str, driver, devices):
+        import jax
+        import numpy as np
+
+        self.profiler = jax.profiler
+        self.dir = trace_dir
+        self.driver = driver
+        self.stop_at = driver.seconds
+        self.start_at = max(0.0, self.stop_at - TRACE_SLICE_S)
+        self.started = self.stopped = None
+        self._bump = jax.jit(lambda x: x + 1)
+        self._marks = [jax.device_put(np.int32(0), d) for d in devices]
+        self.drain()  # compiles here, in set-up
+
+    def drain(self):
+        import jax
+
+        jax.block_until_ready([self._bump(m) for m in self._marks])
+
+    def tick(self, now: float):
+        if self.started is None and now >= self.start_at:
+            self.drain()
+            opts = self.profiler.ProfileOptions()
+            opts.python_tracer_level = 0  # the driver's TraceMe spans only
+            opts.host_tracer_level = 2
+            self.profiler.start_trace(self.dir, profiler_options=opts)
+            self.started = now
+        elif self.started is not None and self.stopped is None and now >= self.stop_at:
+            with self.driver.span("trace_drain"):
+                self.drain()
+            self.profiler.stop_trace()
+            self.stopped = now
+
+
+def warm_drive(app, vocab: int, seed: int):
+    """Two short requests through a throw-away session at the served batch:
+    the small host-side programs of the serving loop (token chaining, pads)
+    compile here and not in the window."""
+    import numpy as np
+
+    from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
+
+    rng = np.random.default_rng([int(seed), 9])
+    session = ServingSession(app)
+    for i, n in enumerate((24, 150)):
+        session.add_request(f"warm-{i}", rng.integers(0, vocab, size=n), max_new_tokens=4)
+    for _ in range(32):
+        if not session.active:
+            break
+        session.step()
+
+
+def main(argv=None) -> int:
+    args = parse(argv)
+    from benchmark.harness import catalog, correct, device, stats, system
+    from benchmark.harness.driver import LoadDriver
+    from benchmark.harness.traffic import Traffic, scale_mix
+
+    rehearsal = bool(args.rehearsal)
+    cell = catalog.load_cell(args.workload, root=args.catalog_root or catalog.REPO_DIR)
+    try:
+        devices, peaks, device_info = device.find_chips(cell.chips, rehearsal=rehearsal)
+    except device.DeviceError as e:
+        print(f"benchmark: {e}", file=sys.stderr)
+        return 2
+    log = system.CompileLog()
+    cache_dir = system.configure_cache()
+    cfg = system.resolve_config(cell.config, rehearsal)
+    attrs = system.model_attrs(cfg)
+    degree = cfg["tpu_config"].get("tp_degree", 1)
+    emit(phase="start", cell=cell.name, seed=args.seed, seconds=args.seconds,
+         trace=args.trace, device=device_info, compile_cache=cache_dir,
+         cache_entries=system.cache_dir_listing(cache_dir))
+
+    # ---- set-up: model, weights, correctness, warm-up -----------------------
+    t = time.perf_counter()
+    app = system.build_app(cfg, devices, args.seed)
+    params, pspecs = system.make_weights(app, args.seed)
+    emit(phase="weights", seconds=time.perf_counter() - t, **log.facts())
+    spec, mix = cell.spec, cell.traffic
+    if rehearsal:
+        spec = {**spec, **spec.get("rehearsal", {})}
+        mix = scale_mix(mix, cfg["tpu_config"]["seq_len"] - 2)
+    traffic = Traffic(
+        mix, seed=args.seed, vocab_size=attrs["vocab_size"], loop=spec["loop"],
+        seconds=args.seconds, rate_rps=spec.get("rate_rps"),
+        max_prompt_len=cfg["tpu_config"]["seq_len"] - 2,
+    )
+    t = time.perf_counter()
+    try:
+        model_facts = correct.check_model(cfg, devices, args.seed, params, pspecs, degree,
+                                          traffic.bounds()["max_prompt"])
+        model_ok = True
+    except correct.CorrectnessError as e:
+        model_facts, model_ok = {"error": str(e), **e.facts}, False
+    emit(phase="reference", ok=model_ok, seconds=time.perf_counter() - t, **model_facts)
+
+    t = time.perf_counter()
+    system.give_weights(app, params, pspecs)
+    shapes = system.reachable_shapes(app, **traffic.bounds())
+    system.warm_up(app, shapes)
+    kernels = system.kernel_census(app, shapes) if args.trace and not rehearsal else {}
+    warm_drive(app, attrs["vocab_size"], args.seed)
+    emit(phase="warm_up", seconds=time.perf_counter() - t, programs=len(shapes),
+         kernels=kernels, traffic=traffic.summary(), digest=traffic.digest(), **log.facts())
+
+    from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
+
+    telemetry = None
+    if args.trace:
+        from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+
+        telemetry = TelemetrySession(enabled=True)
+    session = ServingSession(app, telemetry=telemetry)
+    driver = LoadDriver(
+        session, traffic, loop=spec["loop"], seconds=args.seconds,
+        clients=spec.get("clients", 0), prestart=int(spec.get("prestart", 0)),
+        traced=bool(args.trace),
+    )
+    if driver.prestart:
+        driver.fill()
+    profiler = None
+    if args.trace:
+        trace_dir = os.path.join(os.path.dirname(HERE), ".bench_cache", "trace")
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        profiler = Profiler(trace_dir, driver, devices)
+    counters_before = telemetry.registry.snapshot() if telemetry else None
+    compiles_before = log.compiles
+    setup_s = time.perf_counter() - T_PROCESS
+
+    # ---- the window ---------------------------------------------------------
+    wall = driver.run(on_tick=profiler.tick if profiler else None)
+    if profiler is not None and profiler.started is not None:
+        profiler.tick(float("inf"))  # a window that ended between two ticks
+    compiled_in_window = log.compiles - compiles_before
+    counters = None
+    if telemetry:
+        counters = {"before": counters_before, "after": telemetry.registry.snapshot()}
+
+    # ---- reduction ------------------------------------------------------------
+    records = list(driver.records.values())
+    summary = stats.summarize(records, driver.window_s)
+    spans = stats.span_stats(driver.spans, driver.window_s)
+    faults = correct.check_window(records, session, attrs["vocab_size"])
+    counted = sum(len(session.requests[r.req_id].generated) for r in records
+                  if r.req_id in session.requests)
+    stamped = sum(r.tokens for r in records)
+    emit(phase="window", wall_s=wall, window_s=driver.window_s, summary=summary, spans=spans,
+         compiled_in_window=compiled_in_window, faults=faults[:10],
+         tokens_counted=counted, tokens_stamped=stamped,
+         backlog_mid=_at(driver.samples["backlog"], args.seconds * 0.5),
+         backlog_end=_at(driver.samples["backlog"], args.seconds))
+    correct_all = bool(model_ok and not faults and compiled_in_window == 0 and counted == stamped)
+
+    device_out = dict(device_info, memory_peak_bytes=device.memory_peak_bytes(devices))
+    metrics, breakdown = {}, None
+    if rehearsal:
+        for key in ("finished", "out_tokens"):
+            metrics[key] = {"value": summary[key], "unit": "count"}
+    elif not args.trace:
+        values = dict(summary, setup_s=setup_s)
+        for m in cell.end_to_end:
+            if m["name"] not in values:
+                print(f"benchmark: no sample for end-to-end metric {m['name']}", file=sys.stderr)
+                return 3
+            metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+    if args.trace and not rehearsal:
+        from benchmark.harness import trace_reduce
+
+        path = trace_reduce.find_xplane(profiler.dir)
+        reduced = trace_reduce.reduce_trace(path)
+        # the driver-side layer metrics stop where the slice starts: starting
+        # the profiler stalls the loop for about a second, which is not the
+        # load generator's lateness nor the scheduler's step time
+        before = profiler.started
+        ctx = dict(summary=stats.summarize(records, before, due_before=before),
+                   spans=stats.span_stats(driver.spans, before),
+                   samples=driver.samples, counters=counters,
+                   trace=reduced, slice=(profiler.started, profiler.stopped), peaks=peaks,
+                   attrs=attrs, chips=degree, kernels=kernels)
+        for m in cell.per_layer:
+            reader = importlib.import_module("benchmark.harness.readers." + m["reader"]["reader"])
+            value = reader.read(m["reader"], ctx)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        device_out.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
+        breakdown = reduced["breakdown"]
+        emit(phase="trace", file_bytes=os.path.getsize(path), chips=reduced["chips"],
+             collectives=reduced["collectives"], idle_by_span=reduced["idle_by_span"],
+             modules=sorted(reduced["module_sums"].items(), key=lambda kv: -kv[1][1])[:10])
+        if args.out:
+            os.makedirs(args.out, exist_ok=True)
+            with open(os.path.join(args.out, f"{cell.name}.trace_describe.json"), "w") as f:
+                json.dump(trace_reduce.describe(path), f, indent=1)
+        if reduced["busy_s"] <= 0:
+            print("benchmark: the trace holds no device operation", file=sys.stderr)
+            return 4
+    result = {"correct": correct_all, "attempted": int(summary["attempted"]),
+              "failed": int(summary["failed"]), "metrics": metrics, "device": device_out}
+    if breakdown is not None:
+        result["breakdown"] = breakdown
+    if args.out:
+        os.makedirs(args.out, exist_ok=True)
+        with open(os.path.join(args.out, f"{cell.name}.seed{args.seed}.trace{args.trace}.json"), "w") as f:
+            json.dump({"result": result, "summary": summary, "spans": spans}, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+def _at(samples, t):
+    """The last per-step sample at or before time ``t`` (None if none)."""
+    value = None
+    for when, v in samples:
+        if when > t:
+            break
+        value = v
+    return value
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,101 @@
+"""``BENCHMARK.json`` against the letter of the benchmark's contract, as far
+as it can be checked without the chip."""
+
+import json
+import os
+import re
+
+from benchmark.harness import catalog
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj).*size|_dim$|_rank$|head_dim|"
+                   r"expansion|experts_per_tok")
+
+
+def bench():
+    with open(os.path.join(catalog.REPO_DIR, "BENCHMARK.json")) as f:
+        raw = f.read()
+    assert len(raw.encode()) <= 64 * 1024
+    return json.loads(raw)
+
+
+def one_line(s, limit=200):
+    return isinstance(s, str) and 1 <= len(s) <= limit and "\n" not in s and "\t" not in s
+
+
+def test_top_level_and_command():
+    b = bench()
+    assert set(b) == {"command", "paths", "run_seconds", "configs", "workloads",
+                      "end_to_end", "per_layer"}
+    assert 1 <= len(b["command"]) <= 32 and all(one_line(w) for w in b["command"])
+    assert 1 <= len(b["paths"]) <= 16 and all(PATH.match(p) for p in b["paths"])
+    for word in b["command"]:
+        assert not word.startswith("/") and ".." not in word.split("/")
+        if "/" in word:
+            assert any(word.startswith(p + "/") for p in b["paths"])
+    assert isinstance(b["run_seconds"], int) and 1 <= b["run_seconds"] <= 51
+    # a full check with the full 24 cells has to fit
+    assert (2 + 14 * 24) * (b["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+def test_configs_and_cells():
+    b = bench()
+    names = [c["name"] for c in b["configs"]]
+    assert 1 <= len(names) <= 24 and len(set(names)) == len(names)
+    files = [c["file"] for c in b["configs"]]
+    assert len(set(files)) == len(files)
+    for c in b["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and one_line(c["source"]) and one_line(c["why"])
+        assert any(c["file"].startswith(p + "/") for p in b["paths"]) and PATH.match(c["file"])
+        assert len(c["reduced"]) <= 16 and not any(WIDTH.search(k) for k in c["reduced"])
+        assert os.path.exists(os.path.join(catalog.REPO_DIR, c["file"]))
+    cells = b["workloads"]
+    assert 1 <= len(cells) <= 24
+    assert len({w["name"] for w in cells}) == len(cells)
+    assert len({(w["config"], w["traffic"]) for w in cells}) == len(cells)
+    for w in cells:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"]) and w["config"] in names
+        assert w["chips"] in (1, 4) and one_line(w["why"])
+    assert {w["config"] for w in cells} == set(names)
+    four = sum(1 for w in cells if w["chips"] == 4)
+    assert four <= max(1, len(cells) // 4)
+
+
+def test_metrics():
+    b = bench()
+    cells = {w["name"] for w in b["workloads"]}
+    every = b["end_to_end"] + b["per_layer"]
+    assert 1 <= len(b["end_to_end"]) <= 16 and 1 <= len(b["per_layer"]) <= 128
+    assert len({m["name"] for m in every}) == len(every)
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+    for m in b["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in b["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+        assert one_line(m["layer"]) and m["moves"] in e2e and m["source"] in SOURCES
+        where = set(m.get("workloads", cells))
+        assert where <= set(e2e[m["moves"]].get("workloads", cells))
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+    for m in every:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        assert set(m.get("workloads", [])) <= cells
+    for cell in cells:
+        mine = [m["name"] for m in b["end_to_end"] if cell in m.get("workloads", cells)]
+        assert "setup_s" in mine and len(mine) >= 2
+        assert any(cell in m.get("workloads", cells) for m in b["per_layer"])
+
+
+def test_files_under_paths_are_named_from_name_characters():
+    for root, dirs, files in os.walk(catalog.BENCH_DIR):
+        dirs[:] = [d for d in dirs if d not in ("__pycache__", ".pytest_cache")]
+        for f in files:
+            assert re.match(r"^[A-Za-z0-9_.\-]+$", f), os.path.join(root, f)
