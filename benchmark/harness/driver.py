@@ -14,6 +14,7 @@ step == one second); its numbers are step counts. This one reads seconds.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
@@ -25,6 +26,15 @@ from .traffic import Traffic, TrafficRequest
 #: add_request refusals that mean "no room now": the request stays in the
 #: driver's backlog and is offered again after the next step
 RETRY_REASONS = ("no_slot", "kv_blocks", "backlog")
+
+#: the traced phase of a ``--trace 2`` run lets the session come back to the
+#: occupancy it had when the window closed before the slice starts, but
+#: waits no longer than this
+SETTLE_LIMIT_S = 6.0
+
+#: appended to the ids of the requests the traced phase sends again (the
+#: first round, an open loop's further arrivals): no id of the window recurs
+PHASE_TAG = "-traced"
 
 #: after the window closes the loop keeps stepping until every request that
 #: was due has its first token (a long prompt due at the window's end still
@@ -61,11 +71,18 @@ class LoadDriver:
         self._open: Dict[str, RequestRecord] = {}  # admitted, not yet finished
         self._seen: Dict[str, int] = {}  # tokens already stamped per request
         self._t0: Optional[float] = None
+        self._occupancy_at_close = 0  # requests in the session when the window closed
         self._annotation = None
         if self.traced:
-            import jax.profiler
+            self._trace_spans()
 
-            self._annotation = jax.profiler.TraceAnnotation
+    def _trace_spans(self):
+        """From here on the driver's spans are also ``TraceAnnotation``s
+        (on the profiler's clock) and each step's work is sampled."""
+        import jax.profiler
+
+        self.traced = True
+        self._annotation = jax.profiler.TraceAnnotation
 
     # ---- clock and spans ---------------------------------------------------
 
@@ -216,6 +233,7 @@ class LoadDriver:
                 idle_clients = 0
             if not sending and drain_from is None:
                 drain_from = self.window_s = now
+                self._occupancy_at_close = len(self.session.active)
             if self._backlog and (sending or drain_from is not None):
                 with self.span("admit"):
                     self._admit()
@@ -247,3 +265,85 @@ class LoadDriver:
             if rec.due_s is not None and not rec.commits:
                 rec.failed = "no_first_token"
         return self.clock() - t_open
+
+    def trace_phase(self, profiler, arrivals: Optional[Traffic] = None) -> dict:
+        """After ``run()`` (``--trace 2``): a few seconds more of the same
+        traffic for the traced slice. The loop re-opens — a closed loop's
+        idle clients send again (a first round in mid-prefill is sent
+        again, under new ids), an open loop goes on with ``arrivals`` (a
+        Traffic of its own at the cell's rate, due times from the start of
+        this phase, ids tagged) — and runs until the session holds what it
+        held when the window closed, nothing waits in the backlog and, in a
+        closed loop whose first round is in mid-decode, no prompt is left
+        to prefill (what ``fill()`` waits for: the clients that went idle
+        during the drain send together, which the window's steady state
+        does not), but no longer than SETTLE_LIMIT_S. Then ``profiler.arm(now)`` and
+        ``profiler.tick(now)`` once per turn until it has stopped. The
+        phase has no drain: what is in flight at its end is abandoned.
+        Nothing here touches what the window measured: ``window_s``, the
+        window's spans and the records of its requests are read before
+        this is called. Returns the phase's own facts."""
+        self._trace_spans()
+        t_phase = self.now()
+        backlog0 = len(self._backlog)
+        sent = 0
+        if self.loop == "closed":
+            idle_clients = max(0, self.clients - len(self._open) - len(self._backlog))
+            if self.traffic.first_round_kind == "mid_prefill":
+                for req in self.traffic.first_round(self.prestart)[:idle_clients]:
+                    self._release(dataclasses.replace(req, req_id=req.req_id + PHASE_TAG), None)
+                    idle_clients -= 1
+                    sent += 1
+        prefill_first = self.loop == "closed" and self.traffic.first_round_kind == "mid_decode"
+        settled = None
+        steps = 0
+        while profiler.stopped is None:
+            now = self.now()
+            if settled is not None:
+                profiler.tick(now)
+                if profiler.stopped is not None:
+                    break
+            if self.loop == "open":
+                while sent < len(arrivals) and t_phase + float(arrivals.due[sent]) <= now:
+                    req = arrivals.request(sent)
+                    self._release(dataclasses.replace(req, req_id=req.req_id + PHASE_TAG),
+                                  t_phase + float(arrivals.due[sent]))
+                    sent += 1
+            else:
+                for _ in range(idle_clients):
+                    self._release(self.traffic.request(self._next), now)
+                    self._next += 1
+                    sent += 1
+                idle_clients = 0
+            if self._backlog:
+                with self.span("admit"):
+                    self._admit()
+            if self._has_work():
+                self._sample_work(now)
+                with self.span("step"):
+                    finished = self._step()
+                steps += 1
+                if self.loop == "closed":
+                    idle_clients += finished
+            else:
+                with self.span("wait_for_arrival"):
+                    self.sleep(0.001)
+            if settled is None and (
+                (not self._backlog and len(self.session.active) >= self._occupancy_at_close
+                 and not (prefill_first and self.session.prefilling))
+                or now - t_phase > SETTLE_LIMIT_S
+            ):
+                settled = self.now()
+                profiler.arm(settled)
+        def step_ms(t0, t1):
+            d = [b - a for name, a, b in self.spans if name == "step" and t0 <= a < t1]
+            return {"count": len(d), "mean_ms": sum(d) / len(d) * 1e3} if d else {"count": 0}
+
+        # telemetry is on from t_phase, the profiler only during the slice:
+        # the two step times tell the cost of the one from that of the other
+        sliced = (profiler.started, profiler.stopped)
+        return {"phase_s": self.now() - t_phase, "settle_s": settled - t_phase, "steps": steps,
+                "step_ms_settling": step_ms(t_phase, sliced[0]), "step_ms_slice": step_ms(*sliced),
+                "sent": sent, "backlog_at_start": backlog0,
+                "occupancy_at_close": self._occupancy_at_close,
+                "occupancy_at_slice": len(self.session.active)}

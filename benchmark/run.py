@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One run of one cell:
 
-    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 A new process finds the cell's chips or fails, builds the model from the
 cell's configuration file, makes its bf16 weights on the device(s) from
@@ -11,7 +11,12 @@ the wall clock, and prints ONE last line of JSON: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``. With
 ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` a slice at the end of the window is profiled and the metrics
-are the cell's per-layer metrics. Earlier lines are JSON facts of the run.
+are the cell's per-layer metrics. ``--trace 2`` is a ``--trace 0`` run up to
+the closing of the window — its end-to-end numbers, ``correct``,
+``attempted`` and ``failed`` are taken there and held — followed, in the
+same process, by a short traced phase of the same traffic (``traced_phase``
+below); its last line carries both kinds of metric side by side. Earlier
+lines are JSON facts of the run.
 
 This file knows no cell, mix, configuration or metric by name: it finds them
 through ``harness/catalog.py`` by the names in ``BENCHMARK.json``.
@@ -51,7 +56,7 @@ def parse(argv):
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearsal", type=int, choices=(0, 1), default=0)
     ap.add_argument("--catalog-root", default=None,
                     help="selftest and knee sweep: a directory with another BENCHMARK.json "
@@ -70,15 +75,21 @@ class Profiler:
     ran in between, whole, and the readers count the work of those steps
     and no other."""
 
-    def __init__(self, trace_dir: str, driver, devices):
+    def __init__(self, trace_dir: str, driver, devices, telemetry=None):
         import jax
         import numpy as np
 
         self.profiler = jax.profiler
         self.dir = trace_dir
         self.driver = driver
+        #: ``--trace 2``: the profiler is started and stopped through the
+        #: program's own control (``TelemetrySession.start/stop``), the
+        #: slice is armed by the driver's traced phase and not by the clock
+        self.telemetry = telemetry
         self.stop_at = driver.seconds
         self.start_at = max(0.0, self.stop_at - TRACE_SLICE_S)
+        if telemetry is not None:
+            self.start_at = self.stop_at = float("inf")
         self.started = self.stopped = None
         self._bump = jax.jit(lambda x: x + 1)
         self._marks = [jax.device_put(np.int32(0), d) for d in devices]
@@ -89,18 +100,28 @@ class Profiler:
 
         jax.block_until_ready([self._bump(m) for m in self._marks])
 
+    def arm(self, now: float):
+        """The slice starts at the next tick and lasts TRACE_SLICE_S."""
+        self.start_at, self.stop_at = now, now + TRACE_SLICE_S
+
     def tick(self, now: float):
         if self.started is None and now >= self.start_at:
             self.drain()
-            opts = self.profiler.ProfileOptions()
-            opts.python_tracer_level = 0  # the driver's TraceMe spans only
-            opts.host_tracer_level = 2
-            self.profiler.start_trace(self.dir, profiler_options=opts)
+            if self.telemetry is not None:
+                self.telemetry.start(profile_dir=self.dir)
+            else:
+                opts = self.profiler.ProfileOptions()
+                opts.python_tracer_level = 0  # the driver's TraceMe spans only
+                opts.host_tracer_level = 2
+                self.profiler.start_trace(self.dir, profiler_options=opts)
             self.started = now
         elif self.started is not None and self.stopped is None and now >= self.stop_at:
             with self.driver.span("trace_drain"):
                 self.drain()
-            self.profiler.stop_trace()
+            if self.telemetry is not None:
+                self.telemetry.stop()
+            else:
+                self.profiler.stop_trace()
             self.stopped = now
 
 
@@ -171,7 +192,7 @@ def main(argv=None) -> int:
     system.give_weights(app, params, pspecs)
     shapes = system.reachable_shapes(app, **traffic.bounds())
     system.warm_up(app, shapes)
-    kernels = system.kernel_census(app, shapes) if args.trace and not rehearsal else {}
+    kernels = system.kernel_census(app, shapes) if args.trace == 1 and not rehearsal else {}
     warm_drive(app, attrs["vocab_size"], args.seed)
     emit(phase="warm_up", seconds=time.perf_counter() - t, programs=len(shapes),
          kernels=kernels, traffic=traffic.summary(), digest=traffic.digest(), **log.facts())
@@ -182,21 +203,22 @@ def main(argv=None) -> int:
     if args.trace:
         from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
 
-        telemetry = TelemetrySession(enabled=True)
+        # --trace 2: present but stopped until the window has closed
+        telemetry = TelemetrySession(enabled=args.trace == 1)
     session = ServingSession(app, telemetry=telemetry)
     driver = LoadDriver(
         session, traffic, loop=spec["loop"], seconds=args.seconds,
         clients=spec.get("clients", 0), prestart=int(spec.get("prestart", 0)),
-        traced=bool(args.trace),
+        traced=args.trace == 1,
     )
     if driver.prestart:
         driver.fill()
     profiler = None
-    if args.trace:
-        trace_dir = os.path.join(os.path.dirname(HERE), ".bench_cache", "trace")
+    trace_dir = os.path.join(os.path.dirname(HERE), ".bench_cache", "trace")
+    if args.trace == 1:
         shutil.rmtree(trace_dir, ignore_errors=True)
         profiler = Profiler(trace_dir, driver, devices)
-    counters_before = telemetry.registry.snapshot() if telemetry else None
+    counters_before = telemetry.registry.snapshot() if args.trace == 1 else None
     compiles_before = log.compiles
     setup_s = time.perf_counter() - T_PROCESS
 
@@ -206,7 +228,7 @@ def main(argv=None) -> int:
         profiler.tick(float("inf"))  # a window that ended between two ticks
     compiled_in_window = log.compiles - compiles_before
     counters = None
-    if telemetry:
+    if args.trace == 1:
         counters = {"before": counters_before, "after": telemetry.registry.snapshot()}
 
     # ---- reduction ------------------------------------------------------------
@@ -229,42 +251,70 @@ def main(argv=None) -> int:
     if rehearsal:
         for key in ("finished", "out_tokens"):
             metrics[key] = {"value": summary[key], "unit": "count"}
-    elif not args.trace:
+    elif args.trace != 1:
         values = dict(summary, setup_s=setup_s)
         for m in cell.end_to_end:
             if m["name"] not in values:
                 print(f"benchmark: no sample for end-to-end metric {m['name']}", file=sys.stderr)
                 return 3
             metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
-    if args.trace and not rehearsal:
+    if args.trace == 2:
+        # everything above is taken and held; from here on nothing reads the
+        # window's records again
+        phase = traced_phase(args, spec, mix, cfg, attrs, app, shapes, driver,
+                             telemetry, devices, trace_dir, log, rehearsal)
+        profiler, kernels, counters = phase["profiler"], phase["kernels"], phase["counters"]
+        emit(phase="traced_phase", **phase["facts"])
+        device_out["memory_peak_bytes"] = device.memory_peak_bytes(devices)  # of the whole run
+    # a CPU rehearsal of --trace 2 reduces its (host-only) trace and calls
+    # every reader, so that the path is exercised end to end, but prints
+    # only what a CPU run may say: the metrics that are counts
+    if args.trace and (not rehearsal or args.trace == 2):
         from benchmark.harness import trace_reduce
 
         path = trace_reduce.find_xplane(profiler.dir)
         reduced = trace_reduce.reduce_trace(path)
-        # the driver-side layer metrics stop where the slice starts: starting
-        # the profiler stalls the loop for about a second, which is not the
-        # load generator's lateness nor the scheduler's step time
-        before = profiler.started
-        ctx = dict(summary=stats.summarize(records, before, due_before=before),
-                   spans=stats.span_stats(driver.spans, before),
+        if args.trace == 1:
+            # the driver-side layer metrics stop where the slice starts:
+            # starting the profiler stalls the loop for about a second, which
+            # is not the load generator's lateness nor the scheduler's step time
+            before = profiler.started
+            summary_ctx = stats.summarize(records, before, due_before=before)
+            spans_ctx = stats.span_stats(driver.spans, before)
+        else:
+            # the measured window, whole and untraced
+            summary_ctx, spans_ctx = summary, spans
+        ctx = dict(summary=summary_ctx, spans=spans_ctx,
                    samples=driver.samples, counters=counters,
                    trace=reduced, slice=(profiler.started, profiler.stopped), peaks=peaks,
                    attrs=attrs, chips=degree, kernels=kernels)
+        read_by_name = []
         for m in cell.per_layer:
             reader = importlib.import_module("benchmark.harness.readers." + m["reader"]["reader"])
             value = reader.read(m["reader"], ctx)
             if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        device_out.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
-        breakdown = reduced["breakdown"]
+                read_by_name.append(m["name"])
+                if not rehearsal or m["source"] == "program_counter":
+                    metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        if not rehearsal:
+            device_out.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
+            breakdown = reduced["breakdown"]
         emit(phase="trace", file_bytes=os.path.getsize(path), chips=reduced["chips"],
              collectives=reduced["collectives"], idle_by_span=reduced["idle_by_span"],
+             span_counts=reduced["span_counts"], read=read_by_name,
              modules=sorted(reduced["module_sums"].items(), key=lambda kv: -kv[1][1])[:10])
         if args.out:
             os.makedirs(args.out, exist_ok=True)
             with open(os.path.join(args.out, f"{cell.name}.trace_describe.json"), "w") as f:
                 json.dump(trace_reduce.describe(path), f, indent=1)
-        if reduced["busy_s"] <= 0:
+        if args.trace == 2:
+            if args.out:
+                table = importlib.import_module(
+                    "benchmark.harness.readers.program_span").idle_by_program_span(path)
+                with open(os.path.join(args.out, f"{cell.name}.idle_by_program_span.json"), "w") as f:
+                    json.dump(table, f, indent=1)
+            shutil.rmtree(trace_dir, ignore_errors=True)  # reduced: the trace can go
+        if reduced["busy_s"] <= 0 and not rehearsal:
             print("benchmark: the trace holds no device operation", file=sys.stderr)
             return 4
     result = {"correct": correct_all, "attempted": int(summary["attempted"]),
@@ -277,6 +327,50 @@ def main(argv=None) -> int:
             json.dump({"result": result, "summary": summary, "spans": spans}, f, indent=1)
     print(json.dumps(result), flush=True)
     return 0
+
+
+def traced_phase(args, spec, mix, cfg, attrs, app, shapes, driver, telemetry,
+                 devices, trace_dir, log, rehearsal) -> dict:
+    """``--trace 2``, after the window has closed and its numbers are held:
+    what only a traced run needs is built now (the kernel census, the
+    profiler's drain program), the profiler is started and stopped once and
+    that trace thrown away (the cost of its first start falls into no
+    number), then the program's telemetry is started, the driver re-opens
+    the traffic and settles, and TRACE_SLICE_S of steps are profiled between
+    two drains of the device. Returns the profiler (its slice and
+    directory), the kernel census, the registry's snapshots at both ends of
+    the phase, and the phase's facts."""
+    from benchmark.harness import system
+    from benchmark.harness.driver import SETTLE_LIMIT_S
+    from benchmark.harness.traffic import Traffic
+
+    t0 = time.perf_counter()
+    kernels = system.kernel_census(app, shapes) if not rehearsal else {}
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    profiler = Profiler(trace_dir, driver, devices, telemetry=telemetry)
+    throwaway = trace_dir + ".first"
+    telemetry.start(profile_dir=throwaway)
+    telemetry.stop()
+    shutil.rmtree(throwaway, ignore_errors=True)
+    built_s = time.perf_counter() - t0
+    arrivals = None
+    if spec["loop"] == "open":
+        # an open loop goes on at the cell's rate, from the same mix: a
+        # Traffic of its own, long enough for the settling and the slice
+        arrivals = Traffic(
+            mix, seed=args.seed + 1, vocab_size=attrs["vocab_size"], loop="open",
+            seconds=SETTLE_LIMIT_S + 2 * TRACE_SLICE_S, rate_rps=spec.get("rate_rps"),
+            max_prompt_len=cfg["tpu_config"]["seq_len"] - 2,
+        )
+    compiles_before = log.compiles
+    telemetry.start()
+    before = telemetry.registry.snapshot()
+    facts = driver.trace_phase(profiler, arrivals)
+    counters = {"before": before, "after": telemetry.registry.snapshot()}
+    telemetry.stop()  # a slice that never started leaves the session recording
+    facts.update(built_s=built_s, compiled_in_phase=log.compiles - compiles_before,
+                 slice=[profiler.started, profiler.stopped])
+    return {"profiler": profiler, "kernels": kernels, "counters": counters, "facts": facts}
 
 
 def _at(samples, t):

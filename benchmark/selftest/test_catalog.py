@@ -25,7 +25,11 @@ def test_the_catalog_holds_to_its_own_rules():
         for m in cell.per_layer:
             assert m["moves"] in e2e
             importlib.import_module("benchmark.harness.readers." + m["reader"]["reader"])
-        assert cell.spec["reports"] == [m["name"] for m in cell.end_to_end + cell.per_layer]
+        # a cell file lists what the cell reported when the file was written; a
+        # later PR adds metrics by entries in BENCHMARK.json, at the end of their
+        # list, and may not edit the cell file: the list is then the head of it
+        names = [m["name"] for m in cell.end_to_end + cell.per_layer]
+        assert cell.spec["reports"] == names[: len(cell.spec["reports"])]
     # every data file is named by some entry: nothing lies about unused
     named = {os.path.basename(c["file"]) for c in bench["configs"]}
     assert set(os.listdir(os.path.join(catalog.BENCH_DIR, "configs"))) == named

@@ -28,8 +28,9 @@ def one_line(s, limit=200):
 
 def test_top_level_and_command():
     b = bench()
-    assert set(b) == {"command", "paths", "run_seconds", "configs", "workloads",
-                      "end_to_end", "per_layer"}
+    assert set(b) - {"trace_in_run"} == {"command", "paths", "run_seconds", "configs",
+                                          "workloads", "end_to_end", "per_layer"}
+    assert b.get("trace_in_run", True) is True  # the key is there to say yes, or not there
     assert 1 <= len(b["command"]) <= 32 and all(one_line(w) for w in b["command"])
     assert 1 <= len(b["paths"]) <= 16 and all(PATH.match(p) for p in b["paths"])
     for word in b["command"]:

@@ -138,6 +138,9 @@ REPLICA_OWNED = frozenset({
     "_ReplicaStepWorker", "WatchdogError",
     "PrefillReplicaHandle", "DisaggregatedPipeline",
     "_HealthStateMachine",  # the shared health-machine base of both handles
+    # a step-timeline span lives on the stack of the thread that opened it
+    # (``with tel.span(...)``), and each thread has its own stack of them
+    "_Span", "_SpanStack",
 })
 
 #: router-global objects: written ONLY by the router thread — a write

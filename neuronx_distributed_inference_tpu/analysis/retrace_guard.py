@@ -29,6 +29,7 @@ traced". Two consumers:
 from __future__ import annotations
 
 import os
+import re
 from typing import List, Optional
 
 __all__ = [
@@ -96,16 +97,23 @@ def note_trace(tag: str, sealed: bool = False) -> None:
         )
 
 
-def trace_marker(tag: str, fn, owner=None):
+def trace_marker(tag: str, fn, owner=None, name: Optional[str] = None):
     """Wrap ``fn`` (the function handed to ``jax.jit``) so each trace calls
     :func:`note_trace`. ``owner`` is the runner whose ``_sealed`` attribute
     arms the hard-failure mode; the attribute is read at trace time so
-    sealing after wrap works."""
+    sealing after wrap works.
+
+    The wrapper is NAMED: ``jax.jit`` calls its program ``jit_<name>``, and
+    that is what a profiler trace lists under ``XLA Modules``. ``name``
+    defaults to the tag (characters outside ``[A-Za-z0-9_]`` become ``_``),
+    so a trace tells the programs of different runners apart; a runner
+    with several programs under one tag names each."""
 
     def wrapped(*args, **kwargs):
         note_trace(tag, sealed=bool(owner is not None and getattr(owner, "_sealed", False)))
         return fn(*args, **kwargs)
 
+    wrapped.__name__ = wrapped.__qualname__ = re.sub(r"\W", "_", name or tag)
     return wrapped
 
 

@@ -183,7 +183,7 @@ def _mask_tiles(mask: jax.Array, nkv: int, bs: int):
 
 
 def _common_call(
-    kernel, grid, in_specs, out_specs, operands, out_shape, scratch, interpret
+    kernel, grid, in_specs, out_specs, operands, out_shape, scratch, interpret, name
 ):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(operands[0]),
@@ -200,6 +200,7 @@ def _common_call(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=name,
     )(*operands[0], *operands[1])
 
 
@@ -273,6 +274,7 @@ def tkg_decode_attention(
             pltpu.VMEM((Hq * K, D), jnp.float32),
         ],
         interpret=interpret,
+        name="tkg_decode_attention",
     )
     out = _unprep_out(out, B, K, Hq, D)
     if quantized:
@@ -351,6 +353,7 @@ def paged_tkg_decode_attention(
             pltpu.VMEM((Hq * K, D), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_tkg_decode_attention",
     )
     out = _unprep_out(out, B, K, Hq, D)
     if quantized:

@@ -205,6 +205,7 @@ def paged_flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_flash_attention",
     )(
         block_table.astype(jnp.int32),
         kv_limit.astype(jnp.int32),

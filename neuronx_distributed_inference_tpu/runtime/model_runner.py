@@ -89,10 +89,37 @@ class SubModelRunner:
         # and the param tree can change shape (e.g. quantization adds scale
         # leaves) without invalidating the runner
         step = partial(forward, spec=spec, phase=phase, mlp_fn=mlp_fn, layer_fn=layer_fn)
-        self._fn = jax.jit(
-            trace_marker(tag, step, owner=self),
-            donate_argnums=(1,),  # cache in-place (reference KV aliasing)
-        )
+
+        def program(name):
+            return jax.jit(
+                trace_marker(tag, step, owner=self, name=name),
+                donate_argnums=(1,),  # cache in-place (reference KV aliasing)
+            )
+
+        # a token-generation runner serves two kinds of program from the
+        # one function: the step at n_active_tokens and the multi-token
+        # chunk / prefix-prefill pass. Each gets its own jitted callable so
+        # a profiler trace names them apart (``jit_<tag>_decode`` and
+        # ``jit_<tag>_chunk``); shapes never overlap, so the number of
+        # compilations and the programs themselves are what one callable
+        # gave.
+        if phase == PHASE_CONTEXT_ENCODING:
+            self._step_program, self._chunk_program = program(tag), None
+        else:
+            self._step_program = program(f"{tag}_decode")
+            self._chunk_program = program(f"{tag}_chunk")
+
+    def program_for(self, inputs: StepInputs):
+        """The jitted callable that serves these inputs."""
+        if self._chunk_program is not None and inputs.input_ids.shape[1] != self.n_active_tokens:
+            return self._chunk_program
+        return self._step_program
+
+    def _fn(self, params, cache, inputs: StepInputs, rng=None):
+        """Every dispatch of a step program goes through here (the timing,
+        tap and capture hooks of utils/ and chip_smoke replace it per
+        instance)."""
+        return self.program_for(inputs)(params, cache, inputs, rng)
 
     def seal(self):
         """Arm the retrace guard: any later trace of this runner's step
@@ -230,7 +257,7 @@ class SubModelRunner:
         under the runner's mesh so in-graph constraints resolve exactly as
         they do in :meth:`__call__`."""
         with jax.set_mesh(self.mesh):
-            traced = self._fn.trace(params, cache, inputs, rng)
+            traced = self.program_for(inputs).trace(params, cache, inputs, rng)
             lowered = traced.lower()
             compiled = lowered.compile()
         return traced, lowered, compiled

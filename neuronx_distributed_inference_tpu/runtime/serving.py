@@ -52,7 +52,10 @@ from neuronx_distributed_inference_tpu.runtime.faults import (
     WatchdogError,
     fill_kv_rows,
 )
-from neuronx_distributed_inference_tpu.telemetry.tracing import default_session
+from neuronx_distributed_inference_tpu.telemetry.tracing import (
+    NULL_SPAN,
+    default_session,
+)
 
 # ---------------------------------------------------------------------------
 # fault containment: request statuses, typed admission verdicts, retry policy
@@ -350,12 +353,14 @@ class ServingSession:
         ``admission_validation=False`` restores the legacy raise-late
         behavior. ``deadline_s`` overrides the config-wide
         ``request_deadline_s`` wall-clock TTL for this request."""
-        req = self._new_request(req_id, input_ids, max_new_tokens,
-                                eos_token_id, deadline_s)
-        bounce = self._front_door(req)
-        if bounce is not None:
-            return bounce
-        return self._admit(req, self.free_slots[0])
+        with self.tel.span("serving.admit", req_id=req_id) as sp:
+            req = self._new_request(req_id, input_ids, max_new_tokens,
+                                    eos_token_id, deadline_s)
+            verdict = self._front_door(req)
+            if verdict is None:
+                verdict = self._admit(req, self.free_slots[0])
+            sp.note(verdict="admitted" if verdict else verdict.reason)
+        return verdict
 
     def _new_request(self, req_id, input_ids, max_new_tokens, eos_token_id,
                      deadline_s) -> Request:
@@ -1087,56 +1092,67 @@ class ServingSession:
         max_pos = max(r.prefill_pos + n for r, n in rows)
         width = get_target_bucket(self.app.token_generation_model.buckets, max_pos)
         mb = width // bs
-
-        ids = np.zeros((B, qb), np.int32)
-        positions = np.zeros((B, qb), np.int32)
-        mask = np.zeros((B, width), np.int32)
-        slot_mapping = np.full((B, qb), -1, np.int32)
-        block_table = np.zeros((B, mb), np.int32)
-        seq_ids = np.full((B,), -1, np.int32)
-        for req, n in rows:
-            s = req.slot
-            start = req.prefill_pos
-            ids[s, :n] = req.input_ids[start : start + n]
-            # padded tail positions continue so their (garbage) writes/reads
-            # stay in the masked region
-            positions[s] = start + np.arange(qb, dtype=np.int32)
-            mask[s, : start + n] = 1
-            slot_mapping[s, :n] = self.allocator.slot_mapping(
-                s, np.arange(start, start + n)
-            )
-            block_table[s] = self.allocator.block_table(s, mb)
-            seq_ids[s] = s
-
+        real = sum(n for _, n in rows)
+        tel = self.tel
         tkg = self.app.token_generation_model
-
-        def dispatch():
-            with self.tel.span("serving.prefill_chunk", rows=len(rows)):
+        with tel.span(
+            "serving.prefill_chunk", rows=len(rows), real_tokens=real,
+            padded_tokens=B * qb - real, q_bucket=qb, kv_bucket=width,
+        ):
+            with tel.span("serving.prefill_chunk.prepare"):
+                ids = np.zeros((B, qb), np.int32)
+                positions = np.zeros((B, qb), np.int32)
+                mask = np.zeros((B, width), np.int32)
+                slot_mapping = np.full((B, qb), -1, np.int32)
+                block_table = np.zeros((B, mb), np.int32)
+                seq_ids = np.full((B,), -1, np.int32)
+                for req, n in rows:
+                    s = req.slot
+                    start = req.prefill_pos
+                    ids[s, :n] = req.input_ids[start : start + n]
+                    # padded tail positions continue so their (garbage)
+                    # writes/reads stay in the masked region
+                    positions[s] = start + np.arange(qb, dtype=np.int32)
+                    mask[s, : start + n] = 1
+                    slot_mapping[s, :n] = self.allocator.slot_mapping(
+                        s, np.arange(start, start + n)
+                    )
+                    block_table[s] = self.allocator.block_table(s, mb)
+                    seq_ids[s] = s
                 inputs, _ = tkg.prepare(
                     ids, mask, positions, seq_ids, self._session_sampling_params(),
                     slot_mapping=slot_mapping, block_table=block_table,
                 )
-                return tkg(self.app.params, self.app.kv_cache, inputs, None)
 
-        out = self._guarded_dispatch("prefill_chunk", [r for r, _ in rows], dispatch)
-        if out is None:
-            return True  # in-flight rows terminally FAILED(dispatch_error)
-        self._start_fetch(out.tokens)
-        self.app.kv_cache = out.cache
-        self.tel.step("prefill")
-        self.tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
-        for req, n in rows:
-            self._note_prefill(req, n)
-        self.tel.pool_gauges(
-            len(self.active), self.kv_pool_bytes, self.kv_free_bytes
-        )
-        tokens = np.asarray(out.tokens)
+            def dispatch():
+                with tel.span("serving.prefill_chunk.dispatch"):
+                    return tkg(self.app.params, self.app.kv_cache, inputs, None)
 
-        for req, n in rows:
-            req.prefill_pos += n
-            if req.prefill_pos >= req.prompt_len:
-                # the last prompt token's output IS the first generated token
-                self._finish_prefill(req, int(tokens[req.slot, n - 1]))
+            out = self._guarded_dispatch("prefill_chunk", [r for r, _ in rows], dispatch)
+            if out is None:
+                return True  # in-flight rows terminally FAILED(dispatch_error)
+            self._start_fetch(out.tokens)
+            self.app.kv_cache = out.cache
+            tel.step("prefill")
+            tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
+            # what the program really ran over: the full slot batch at the
+            # q bucket, whatever the number of rows prefilling
+            tel.prefill_pass(real, B * qb - real)
+            for req, n in rows:
+                self._note_prefill(req, n)
+            tel.pool_gauges(
+                len(self.active), self.kv_pool_bytes, self.kv_free_bytes
+            )
+            with tel.span("serving.prefill_chunk.fetch_wait") as wait:
+                tokens = np.asarray(out.tokens)
+            self._step_fetch_wait_s += wait.dur_s
+            with tel.span("serving.prefill_chunk.commit"):
+                for req, n in rows:
+                    req.prefill_pos += n
+                    if req.prefill_pos >= req.prompt_len:
+                        # the last prompt token's output IS the first
+                        # generated token
+                        self._finish_prefill(req, int(tokens[req.slot, n - 1]))
         return True
 
     def _finish(self, req: Request, reason: Optional[str] = None, scrub: bool = False):
@@ -1212,23 +1228,37 @@ class ServingSession:
         :meth:`run_to_completion` always uses the fastest chained modes.
         """
         self._step_index += 1
-        # progress baseline BEFORE re-admission: a successful re-admission
-        # commits real tokens (the resumed prefill's next token) and those
-        # must count as forward progress, or a preempt/re-admit churn that
-        # advances one token per cycle would trip a spurious WatchdogError.
-        # The genuinely-livelocked case still escalates: a failed
-        # re-admission moves none of the signature counters.
-        before = self._progress_signature()
-        if self.faults is not None:
-            self.faults.on_step_begin(self)
-        self._expire_deadlines()
-        self._readmit_preempted()
-        if self.faults is not None and self.faults.stalled(self):
-            results: Dict[str, int] = {}
-        else:
-            results = self._step_inner()
-        progressed = bool(results) or self._progress_signature() != before
-        self._watchdog_tick(progressed)
+        tel = self.tel
+        with tel.span("serving.step", step=self._step_index) as step_span:
+            self._step_fetch_wait_s = 0.0
+            with tel.span("serving.housekeeping"):
+                # progress baseline BEFORE re-admission: a successful
+                # re-admission commits real tokens (the resumed prefill's
+                # next token) and those must count as forward progress, or a
+                # preempt/re-admit churn that advances one token per cycle
+                # would trip a spurious WatchdogError. The genuinely-
+                # livelocked case still escalates: a failed re-admission
+                # moves none of the signature counters.
+                before = self._progress_signature()
+                if self.faults is not None:
+                    self.faults.on_step_begin(self)
+                self._expire_deadlines()
+                self._readmit_preempted()
+            if self.faults is not None and self.faults.stalled(self):
+                results: Dict[str, int] = {}
+            else:
+                results = self._step_inner()
+            with tel.span("serving.housekeeping"):
+                progressed = bool(results) or self._progress_signature() != before
+                self._watchdog_tick(progressed)
+        if step_span is not NULL_SPAN and not self.ragged:
+            # the split step's host / fetch-wait split, from the spans' own
+            # two numbers (a ragged step times itself, _note_step_timing)
+            wait_s = min(self._step_fetch_wait_s, step_span.dur_s)
+            tel.step_timing(
+                (step_span.dur_s - wait_s) * 1e3, wait_s * 1e3,
+                replica=self._tel_replica,
+            )
         return results
 
     def _step_inner(self) -> Dict[str, int]:
@@ -1640,67 +1670,72 @@ class ServingSession:
         import jax.numpy as jnp
 
         B = self.num_slots
-        last = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B, 1), np.int32)
-        seq_ids = np.full((B,), -1, np.int32)
-        for r, p in rows:
-            last[r.slot, 0] = r.last_token
-            pos[r.slot, 0] = p
-            seq_ids[r.slot] = r.slot
-        block_table = None
-        if self.block_mode:
-            bs = self.allocator.block_size
-            width = get_target_bucket(
-                self.app.token_generation_model.buckets, int(pos.max()) + 1
-            )
-            mb = width // bs
-            block_table = np.zeros((B, mb), np.int32)
-            for r, p in list(rows):
-                try:
-                    self._alloc(r.slot, p + 1)
-                except RuntimeError:
-                    # pool exhausted mid-decode: preempt this request so the
-                    # others keep running (vLLM-style preemption; it re-
-                    # queues AHEAD of new arrivals and resumes byte-
-                    # identically once blocks free up)
-                    self._preempt(r)
-                    rows.remove((r, p))
-                    continue
-                block_table[r.slot] = self.allocator.block_table(r.slot, mb)
-            if not rows:
-                return None, []
-            # no host slot mapping: decode writes derive their slots IN-GRAPH
-            # from the block table (models/base.run_decoder_layers; reference
-            # generate_tokengen_slot_mapping)
-        else:
-            width = int(pos.max()) + 1
-        mask = (np.arange(width)[None, :] <= pos).astype(np.int32)
-        last_arr = last
-        if last_override is not None:
-            pend_tokens, chained = last_override
-            ch = np.zeros((B, 1), bool)
-            ch[np.asarray(chained, np.int64)] = True
-            last_arr = jnp.where(
-                jnp.asarray(ch), pend_tokens.astype(jnp.int32), jnp.asarray(last)
-            )
-        # inactive rows: mask garbage anyway
+        tel = self.tel
         tkg = self.app.token_generation_model
-
-        def dispatch():
-            with self.tel.span("serving.decode", rows=len(rows)):
+        with tel.span("serving.decode", rows=len(rows)) as decode_span:
+            with tel.span("serving.decode.prepare"):
+                last = np.zeros((B, 1), np.int32)
+                pos = np.zeros((B, 1), np.int32)
+                seq_ids = np.full((B,), -1, np.int32)
+                for r, p in rows:
+                    last[r.slot, 0] = r.last_token
+                    pos[r.slot, 0] = p
+                    seq_ids[r.slot] = r.slot
+                block_table = None
+                if self.block_mode:
+                    bs = self.allocator.block_size
+                    width = get_target_bucket(tkg.buckets, int(pos.max()) + 1)
+                    mb = width // bs
+                    block_table = np.zeros((B, mb), np.int32)
+                    for r, p in list(rows):
+                        try:
+                            self._alloc(r.slot, p + 1)
+                        except RuntimeError:
+                            # pool exhausted mid-decode: preempt this request
+                            # so the others keep running (vLLM-style
+                            # preemption; it re-queues AHEAD of new arrivals
+                            # and resumes byte-identically once blocks free
+                            # up)
+                            self._preempt(r)
+                            rows.remove((r, p))
+                            continue
+                        block_table[r.slot] = self.allocator.block_table(r.slot, mb)
+                    if not rows:
+                        return None, []
+                    # no host slot mapping: decode writes derive their slots
+                    # IN-GRAPH from the block table
+                    # (models/base.run_decoder_layers; reference
+                    # generate_tokengen_slot_mapping)
+                else:
+                    width = int(pos.max()) + 1
+                mask = (np.arange(width)[None, :] <= pos).astype(np.int32)
+                last_arr = last
+                if last_override is not None:
+                    pend_tokens, chained = last_override
+                    ch = np.zeros((B, 1), bool)
+                    ch[np.asarray(chained, np.int64)] = True
+                    last_arr = jnp.where(
+                        jnp.asarray(ch), pend_tokens.astype(jnp.int32), jnp.asarray(last)
+                    )
+                # inactive rows: mask garbage anyway
                 inputs, _ = tkg.prepare(
                     last_arr, mask, pos, seq_ids, self._session_sampling_params(),
                     block_table=block_table,
                 )
-                return tkg(self.app.params, self.app.kv_cache, inputs, None)
+            decode_span.note(rows=len(rows), kv_bucket=tkg.last_bucket)
 
-        out = self._guarded_dispatch("decode", [r for r, _ in rows], dispatch)
+            def dispatch():
+                with tel.span("serving.decode.dispatch"):
+                    return tkg(self.app.params, self.app.kv_cache, inputs, None)
+
+            out = self._guarded_dispatch("decode", [r for r, _ in rows], dispatch)
         if out is None:
             return None, []  # in-flight rows terminally FAILED(dispatch_error)
         self.app.kv_cache = out.cache
-        self.tel.step("decode")
-        self.tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
-        self.tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
+        tel.step("decode")
+        tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
+        tel.decode_pass(len(rows), B)
+        tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
         return out, [(r, p, r.slot, r.epoch) for r, p in rows]
 
     def _consume(self, pend, results: Dict[str, int]):
@@ -1708,22 +1743,26 @@ class ServingSession:
         Rows whose request already finished (terminated after that dispatch)
         or was evicted since (stale epoch) are speculative leftovers —
         discarded; rows carrying the non-finite sentinel are quarantined."""
-        tokens = np.asarray(pend[0])[:, -1]  # the only device sync per step
-        if self.faults is not None:
-            tokens = self.faults.corrupt_tokens(self, tokens)
-        for req, p, slot, epoch in pend[1]:
-            if req.finished or req.preempted or req.epoch != epoch:
-                continue
-            tok = int(tokens[slot])
-            if tok < 0:
-                self._quarantine(req)
-                continue
-            req.generated.append(tok)
-            self._commit_tokens(req, 1)
-            req.pos = p + 1
-            results[req.req_id] = tok
-            if self._is_done(req, tok):
-                self._finish(req)
+        tel = self.tel
+        with tel.span("serving.fetch_wait") as wait:
+            tokens = np.asarray(pend[0])[:, -1]  # the only device sync per step
+        self._step_fetch_wait_s += wait.dur_s
+        with tel.span("serving.commit"):
+            if self.faults is not None:
+                tokens = self.faults.corrupt_tokens(self, tokens)
+            for req, p, slot, epoch in pend[1]:
+                if req.finished or req.preempted or req.epoch != epoch:
+                    continue
+                tok = int(tokens[slot])
+                if tok < 0:
+                    self._quarantine(req)
+                    continue
+                req.generated.append(tok)
+                self._commit_tokens(req, 1)
+                req.pos = p + 1
+                results[req.req_id] = tok
+                if self._is_done(req, tok):
+                    self._finish(req)
 
     def run_to_completion(self, decode_chunk_size: int = 16) -> Dict[str, List[int]]:
         """Drain the session. When every active request is decoding (no
@@ -2699,7 +2738,9 @@ class SpeculativeServingSession(ServingSession):
             self.app.token_generation_model.last_bucket,
         )
         self.tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
-        greedy = np.asarray(jax.device_get(v_out.tokens))[:B]  # (B, k)
+        with self.tel.span("serving.fetch_wait") as wait:
+            greedy = np.asarray(jax.device_get(v_out.tokens))[:B]  # (B, k)
+        self._step_fetch_wait_s += wait.dur_s
         if self.faults is not None:
             greedy = self.faults.corrupt_tokens(self, greedy)
 

@@ -128,6 +128,13 @@ class SpanStore:
                 self.dropped += 1
             self._done.append(sp)
 
+    def close_all(self, t: float, **attrs) -> None:
+        """Close every open span at ``t`` (the session stopped recording:
+        nothing may dangle)."""
+        with self._lock:
+            for span_id in list(self._open):
+                self.end(span_id, t, **attrs)
+
     def is_open(self, span_id: str) -> bool:
         with self._lock:
             return span_id in self._open

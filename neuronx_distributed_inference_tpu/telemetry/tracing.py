@@ -37,6 +37,7 @@ operable counter instead of only an assertion.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import re
@@ -44,7 +45,6 @@ import threading
 import time
 import warnings
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -117,12 +117,95 @@ class RequestTrace:
         return self.t_first_dispatch - self.t_submit
 
 
+class _NullSpan:
+    """What a stopped session's :meth:`TelemetrySession.span` returns: one
+    shared object, nothing recorded, no clock read."""
+
+    __slots__ = ()
+    dur_s = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def note(self, **fields) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One open step-timeline span of a started session (see
+    :meth:`TelemetrySession.span`)."""
+
+    __slots__ = ("tel", "name", "fields", "t0", "dur_s", "_ann", "_stack")
+
+    def __init__(self, tel, name, fields):
+        self.tel = tel
+        self.name = name
+        self.fields = fields
+        self.dur_s = 0.0
+
+    def note(self, **fields) -> None:
+        """Fields learnt inside the span: they go on its span event (the
+        ``TraceAnnotation`` carries those known at entry)."""
+        self.fields.update(fields)
+
+    def __enter__(self):
+        stack = self._stack = self.tel._span_stack.open
+        if "step" not in self.fields and stack and "step" in stack[-1].fields:
+            self.fields["step"] = stack[-1].fields["step"]
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.fields)
+        stack.append(self)
+        self.t0 = self.tel.clock()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        t1 = self.tel.clock()
+        self.dur_s = t1 - self.t0
+        self._stack.pop()
+        parent = self._stack[-1].name if self._stack else None
+        self.tel.event("span", name=self.name, t0=self.t0, t1=t1,
+                       dur_ms=self.dur_s * 1e3, parent=parent, **self.fields)
+        return None
+
+
+class _SpanStack(threading.local):
+    """The open step-timeline spans of the calling thread, innermost last
+    (one session is shared by every replica thread of a router)."""
+
+    def __init__(self):
+        self.open: List[_Span] = []
+
+
+def newest_xplane(profile_dir: str) -> Optional[str]:
+    """The newest ``*.xplane.pb`` (or ``.gz``) under a profiler directory,
+    by modification time: repeated captures into one directory resolve to
+    the latest trace."""
+    paths = [
+        p
+        for pat in ("*.xplane.pb", "*.xplane.pb.gz")
+        for p in glob.glob(os.path.join(profile_dir, "**", pat), recursive=True)
+    ]
+    return max(paths, key=os.path.getmtime) if paths else None
+
+
 class TelemetrySession:
     """Metrics + traces + events for one serving session / process.
 
-    ``enabled=False`` builds an inert session: every record method returns
+    ``enabled=False`` builds a STOPPED session: every record method returns
     immediately, no instruments are created, no retrace listener installs —
-    the disabled path is a handful of attribute loads per call.
+    the stopped path is a handful of attribute loads per call, and
+    :meth:`span` hands back one shared null context. :meth:`start` turns
+    recording on in the running process (creating the instruments the first
+    time) and, given a directory, the device profiler with it;
+    :meth:`stop` turns both off again. ``enabled=True`` is a session
+    started at construction.
 
     Thread safety (the CONC601 contract): one TelemetrySession is shared by
     every replica of a thread-per-replica router
@@ -146,7 +229,6 @@ class TelemetrySession:
         max_events: Optional[int] = None,
         max_completed: int = 10000,
     ):
-        self.enabled = bool(enabled)
         self.registry = registry if registry is not None else metrics_mod.MetricsRegistry()
         self.clock = clock
         self._lock = threading.RLock()
@@ -161,7 +243,7 @@ class TelemetrySession:
         self._jsonl_path = jsonl_path
         self._jsonl_file = None
         self._listener = None
-        #: the causal span timeline (ISSUE 19) — None on a disabled session
+        #: the causal span timeline (ISSUE 19) — None until the first start()
         self.spans: Optional[spans_mod.SpanStore] = None
         #: optional live SLO monitor (attach_slo_monitor)
         self.slo_monitor = None
@@ -176,9 +258,18 @@ class TelemetrySession:
         self._phase_count: Dict[str, int] = {}
         self._tenant_of: Dict[str, str] = {}
         self._dropped_events = 0
-        if not self.enabled:
-            return
-        self.spans = spans_mod.SpanStore(max_spans=max_events)
+        self._max_spans = max_events
+        #: directory of the profiler trace this session started (None: none)
+        self._profile_dir: Optional[str] = None
+        self._span_stack = _SpanStack()
+        self.enabled = False
+        if enabled:
+            self.start()
+
+    def _build_instruments(self) -> None:
+        """Create the span store and every metric family, once. A session
+        that is never started creates none: its registry stays empty."""
+        self.spans = spans_mod.SpanStore(max_spans=self._max_spans)
         r = self.registry
         self._tel_dropped = r.counter(
             "nxdi_telemetry_dropped_total",
@@ -244,6 +335,21 @@ class TelemetrySession:
             "nxdi_bucket_dispatch_total",
             "compiled-program census: which (model, bucket) served",
             labels=("model", "bucket"))
+        self._prefill_real = r.counter(
+            "nxdi_prefill_real_tokens_total",
+            "prompt tokens the split path's chunk passes really advanced "
+            "(sum of each prefilling row's chunk)")
+        self._prefill_padded = r.counter(
+            "nxdi_prefill_padded_tokens_total",
+            "token positions the split path's chunk passes ran beyond the "
+            "real ones: num_slots x q_bucket - real, per pass")
+        self._decode_rows = r.counter(
+            "nxdi_decode_rows_total",
+            "live rows in the split path's decode dispatches")
+        self._decode_slots = r.counter(
+            "nxdi_decode_slots_total",
+            "rows the split path's decode dispatches were run over "
+            "(num_slots per dispatch; rows / slots = the useful share)")
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -422,15 +528,69 @@ class TelemetrySession:
             "nxdi_sealed_retrace_total",
             "forbidden post-seal retraces (steady-state recompiles)",
             labels=("tag",))
-        if jsonl_path:
-            self._jsonl_file = open(jsonl_path, "a")
-        self._listener = self._on_trace
-        retrace_guard.add_trace_listener(self._listener)
 
     # ---- lifecycle of the session itself ---------------------------------
 
-    def close(self) -> None:
+    def start(self, profile_dir: Optional[str] = None) -> "TelemetrySession":
+        """Turn recording on in the running process: spans, counters, the
+        event log and the retrace-guard bridge. Idempotent; the instruments
+        are created on the first start. With ``profile_dir`` the device
+        profiler (``jax.profiler``) starts too, writing there, unless this
+        session already runs it — so a started session can add the profiler
+        later by calling ``start(profile_dir=...)`` again. The program's
+        spans are ``TraceAnnotation``s: they land in that trace on the
+        device trace's clock.
+
+        Requests admitted while the session was stopped have no
+        :class:`RequestTrace` and no span-tree node; every lifecycle record
+        tolerates them (they count in counters and step spans only)."""
         with self._lock:
+            if self.spans is None:
+                self._build_instruments()
+            if self._jsonl_path and self._jsonl_file is None:
+                self._jsonl_file = open(self._jsonl_path, "a")
+            if self._listener is None:
+                self._listener = self._on_trace
+                retrace_guard.add_trace_listener(self._listener)
+            self.enabled = True
+            if profile_dir is not None and self._profile_dir is None:
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0  # TraceAnnotation spans only
+                opts.host_tracer_level = 2
+                jax.profiler.start_trace(profile_dir, profiler_options=opts)
+                self._profile_dir = profile_dir
+        return self
+
+    def stop(self) -> Optional[str]:
+        """Turn recording off; stop the profiler if :meth:`start` started
+        it and return its trace file (``*.xplane.pb``; None without a
+        profile). In-flight request traces are dropped and every open span
+        is closed at the stop time, so nothing dangles and a later
+        :meth:`start` treats those requests as admitted while stopped."""
+        trace_path = None
+        with self._lock:
+            if self._profile_dir is not None:
+                profile_dir, self._profile_dir = self._profile_dir, None
+                jax.profiler.stop_trace()
+                trace_path = newest_xplane(profile_dir)
+            if not self.enabled:
+                return trace_path
+            self.enabled = False
+            if self._listener is not None:
+                retrace_guard.remove_trace_listener(self._listener)
+                self._listener = None
+            self.traces.clear()
+            self.spans.close_all(self.clock(), reason="telemetry_stopped")
+        return trace_path
+
+    def close(self) -> None:
+        """Release what the session holds outside itself: the retrace
+        listener, the JSONL stream, a profiler it started. What it recorded
+        stays readable."""
+        with self._lock:
+            if self._profile_dir is not None:
+                self._profile_dir = None
+                jax.profiler.stop_trace()
             if self._listener is not None:
                 retrace_guard.remove_trace_listener(self._listener)
                 self._listener = None
@@ -523,7 +683,7 @@ class TelemetrySession:
         RLock before any serialization (the ISSUE-19 bugfix — same
         family-copy pattern as the metrics exposition), so a racing
         replica thread cannot half-mutate what gets written."""
-        if not self.enabled or self.spans is None:
+        if self.spans is None:
             trace = {
                 "traceEvents": [], "displayTimeUnit": "ms",
                 "otherData": {"dropped_spans": 0},
@@ -546,19 +706,19 @@ class TelemetrySession:
             return {}
         return self.spans.span_tree()
 
-    @contextmanager
     def span(self, name: str, **fields):
-        """Named step-timeline scope: a ``jax.profiler.TraceAnnotation`` on
-        the host timeline plus a structured span event. Bounds the HOST-side
-        dispatch (async dispatches return before the device finishes — no
-        sync is forced)."""
+        """Named step-timeline scope, used as ``with tel.span(...) as sp``:
+        a ``jax.profiler.TraceAnnotation`` on the host timeline (on the
+        device trace's clock when the profiler runs) plus a structured span
+        event carrying name, start, end, the enclosing span of this thread
+        (``parent``) and the ``step`` it belongs to (given, or inherited
+        from the enclosing span). ``sp.dur_s`` reads the duration after the
+        block. Bounds the HOST side only: no sync is forced. A stopped
+        session returns one shared null context — no clock read, no
+        allocation."""
         if not self.enabled:
-            yield
-            return
-        t0 = self.clock()
-        with jax.profiler.TraceAnnotation(name):
-            yield
-        self.event("span", name=name, dur_ms=(self.clock() - t0) * 1e3, **fields)
+            return NULL_SPAN
+        return _Span(self, name, fields)
 
     # ---- request lifecycle -----------------------------------------------
 
@@ -780,10 +940,13 @@ class TelemetrySession:
                 self.spans.end(f"{inode}/prefill", now)
             else:
                 self.spans.end(f"{inode}/queue", now)
-            self.spans.begin(
-                f"{inode}/decode", "decode", track, now,
-                parent_id=inode, lane=base,
-            )
+            if self.spans.is_open(inode):
+                # a request admitted while the session was stopped has no
+                # incarnation span to hang a decode phase on
+                self.spans.begin(
+                    f"{inode}/decode", "decode", track, now,
+                    parent_id=inode, lane=base,
+                )
             mon = self.slo_monitor
         if mon is not None:
             mon.note_first_token(req_id, now)
@@ -848,6 +1011,23 @@ class TelemetrySession:
         if not self.enabled:
             return
         self._bucket.child((model, str(int(bucket)))).inc()
+
+    def prefill_pass(self, real_tokens: int, padded_tokens: int) -> None:
+        """One chunk pass of the split serving step: the prompt tokens it
+        advanced and the padded positions the program ran besides
+        (real + padded == num_slots x q_bucket)."""
+        if not self.enabled:
+            return
+        self._prefill_real.inc(real_tokens)
+        self._prefill_padded.inc(padded_tokens)
+
+    def decode_pass(self, rows: int, slots: int) -> None:
+        """One decode dispatch of the split serving step: its live rows and
+        the slot batch the program ran over."""
+        if not self.enabled:
+            return
+        self._decode_rows.inc(rows)
+        self._decode_slots.inc(slots)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

@@ -9,19 +9,27 @@ protobuf into the same kind of per-op summary JSON the reference emits.
 
 from __future__ import annotations
 
-import glob
 import gzip
 import json
-import os
 from contextlib import contextmanager
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 
+from neuronx_distributed_inference_tpu.telemetry.tracing import (
+    TelemetrySession,
+    default_session,
+    newest_xplane,
+)
+
 
 @contextmanager
-def profile_capture(out_dir: str):
-    """Capture a device trace for the enclosed block.
+def profile_capture(out_dir: str, telemetry: Optional[TelemetrySession] = None):
+    """Capture a device trace for the enclosed block, through the telemetry
+    session's one start/stop control (``telemetry``, default the process
+    session): the profiler and the program's own spans are on together, so
+    the trace carries ``app.*`` / ``serving.*`` host spans next to the
+    device ops they launched.
 
     Usage::
 
@@ -29,14 +37,18 @@ def profile_capture(out_dir: str):
             run_model()
 
     The trace lands in ``out_dir/plugins/profile/<ts>/`` and is viewable with
-    ``tensorboard --logdir out_dir`` (XProf).
+    ``tensorboard --logdir out_dir`` (XProf). A session that was recording
+    before keeps recording after.
     """
-    os.makedirs(out_dir, exist_ok=True)
-    jax.profiler.start_trace(out_dir)
+    tel = telemetry if telemetry is not None else default_session()
+    was_recording = tel.enabled
+    tel.start(profile_dir=out_dir)
     try:
         yield
     finally:
-        jax.profiler.stop_trace()
+        tel.stop()
+        if was_recording:
+            tel.start()
 
 
 def profile_fn(fn: Callable, out_dir: str, n_warmup: int = 1, n_profile: int = 2):
@@ -53,137 +65,37 @@ def profile_fn(fn: Callable, out_dir: str, n_warmup: int = 1, n_profile: int = 2
     return summarize_trace(out_dir)
 
 
-def _find_xplane(out_dir: str) -> Optional[str]:
-    """Newest xplane artifact under ``out_dir`` — plain OR gzipped.
-
-    jax/xprof write ``*.xplane.pb`` or ``*.xplane.pb.gz`` depending on
-    version; ``_parse_xplane_minimal`` already handles gzip, so both must be
-    discoverable. Newest-by-mtime (not lexicographic) so repeated captures
-    into one directory summarize the latest trace."""
-    paths = [
-        p
-        for pat in ("*.xplane.pb", "*.xplane.pb.gz")
-        for p in glob.glob(os.path.join(out_dir, "**", pat), recursive=True)
-    ]
-    return max(paths, key=os.path.getmtime) if paths else None
-
-
 def summarize_trace(out_dir: str, top: int = 25) -> Dict:
-    """Parse the captured xplane into a per-op time summary (best effort —
-    the xplane proto schema is internal to XLA; fall back to file listing).
-
-    Returns {"ops": [{"name", "total_us", "count"}...], "total_us": N} or
-    {"trace_dir": ...} when the proto isn't parseable in this environment.
-    """
-    path = _find_xplane(out_dir)
+    """Per-op device time of the newest trace under ``out_dir``, read with
+    ``jax.profiler.ProfileData`` (what the benchmark's reduction trusts):
+    {"ops": [{"name", "total_us", "count"}...], "total_us": N} over the
+    ``XLA Ops`` lines of the device planes; {"trace_dir", "ops": []} when
+    no trace is there."""
+    path = newest_xplane(out_dir)
     if path is None:
         return {"trace_dir": out_dir, "ops": []}
-    try:
-        return _parse_xplane_minimal(path, top)
-    except Exception as e:  # pragma: no cover - schema drift
-        return {"trace_dir": out_dir, "error": str(e), "ops": []}
-
-
-def _read_varint(buf: bytes, i: int):
-    r = 0
-    shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        r |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return r, i
-        shift += 7
-
-
-def _fields(buf: bytes):
-    """Iterate (field_number, wire_type, value) over a protobuf message."""
-    i = 0
-    n = len(buf)
-    while i < n:
-        tag, i = _read_varint(buf, i)
-        fnum, wt = tag >> 3, tag & 7
-        if wt == 0:
-            v, i = _read_varint(buf, i)
-        elif wt == 2:
-            ln, i = _read_varint(buf, i)
-            v = buf[i : i + ln]
-            i += ln
-        elif wt == 5:
-            v = buf[i : i + 4]
-            i += 4
-        elif wt == 1:
-            v = buf[i : i + 8]
-            i += 8
-        else:  # groups unused in xplane
-            raise ValueError(f"wire type {wt}")
-        yield fnum, wt, v
-
-
-def _parse_xplane_minimal(path: str, top: int) -> Dict:
-    """Minimal xplane reader: XSpace{planes:1}.XPlane{name:2, lines:3,
-    event_metadata:4, stat_metadata:5}.XLine{events:4 (older traces: 6)}.
-    XEvent{metadata_id:1, offset_ps:2, duration_ps:3}. Aggregates
-    device-plane op durations by event metadata name."""
-    data = open(path, "rb").read()
     if path.endswith(".gz"):
-        data = gzip.decompress(data)
+        with gzip.open(path, "rb") as f:
+            data = jax.profiler.ProfileData.from_serialized_xspace(f.read())
+    else:
+        data = jax.profiler.ProfileData.from_file(path)
     ops: Dict[str, Dict] = {}
-    total_ps = 0
-    for fnum, _, plane in _fields(data):
-        if fnum != 1:
+    for plane in data.planes:
+        if not plane.name.startswith("/device:"):
             continue
-        name = b""
-        meta: Dict[int, str] = {}
-        lines: List[bytes] = []
-        for pf, _, pv in _fields(plane):
-            if pf == 2 and isinstance(pv, bytes):
-                name = pv
-            elif pf == 3:
-                lines.append(pv)
-            elif pf in (4, 5):
-                # event_metadata map<int64, XEventMetadata>: entry {key:1,
-                # value:2 = XEventMetadata{id:1, name:2}}. Current traces
-                # put it at plane field 4; 5 is stat_metadata, whose ids
-                # live in a SEPARATE space — only use it as a fallback and
-                # let event_metadata (4) always win on id collisions
-                k = None
-                m = b""
-                for ef, _, ev in _fields(pv):
-                    if ef == 1:
-                        k = ev
-                    elif ef == 2:
-                        m = ev
-                if k is not None:
-                    mname = ""
-                    for mf, _, mv in _fields(m):
-                        if mf == 2 and isinstance(mv, bytes):
-                            mname = mv.decode("utf-8", "replace")
-                    if mname and (pf == 4 or k not in meta):
-                        meta[k] = mname
-        if b"TPU" not in name and b"/device" not in name and b"Device" not in name:
-            continue
-        for line in lines:
-            for lf, _, lv in _fields(line):
-                # XLine events have appeared at field 4 (current jax/xprof)
-                # and field 6 (older traces) — accept both
-                if lf not in (4, 6):
-                    continue
-                mid, dur = None, 0
-                for ef, wt, ev in _fields(lv):
-                    if ef == 1 and wt == 0:
-                        mid = ev
-                    elif ef == 3 and wt == 0:
-                        dur = ev
-                oname = meta.get(mid, f"op_{mid}")
-                rec = ops.setdefault(oname, {"name": oname, "total_us": 0.0, "count": 0})
-                rec["total_us"] += dur / 1e6
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                name = e.name.split(" = ", 1)[0].lstrip("%")
+                rec = ops.setdefault(name, {"name": name, "total_us": 0.0, "count": 0})
+                rec["total_us"] += e.duration_ns / 1e3
                 rec["count"] += 1
-                total_ps += dur
+    total_us = sum(r["total_us"] for r in ops.values())
     ranked = sorted(ops.values(), key=lambda r: -r["total_us"])[:top]
     for r in ranked:
         r["total_us"] = round(r["total_us"], 1)
-    return {"total_us": round(total_ps / 1e6, 1), "ops": ranked}
+    return {"total_us": round(total_us, 1), "ops": ranked}
 
 
 def save_summary(summary: Dict, out_path: str):

@@ -173,8 +173,8 @@ def _compile_step(app, runner, inputs, params, cache):
     inputs = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), inputs
     )
-    with jax.set_mesh(app.mesh), force_compiled_kernels():
-        return runner._fn.trace(params, cache, inputs, None).lower().compile()
+    with force_compiled_kernels():
+        return runner.trace_program(params, cache, inputs, None)[2]
 
 
 def test_whole_1b_decode_step_compiles_for_v5e(chip_mesh):

@@ -591,7 +591,7 @@ def _max_float_size(app):
     runner = app.token_generation_model
     inputs = runner.example_inputs(runner.buckets[-1])
     with jax.set_mesh(app.mesh):
-        traced = runner._fn.trace(app.params, app.kv_cache, inputs, None)
+        traced = runner.program_for(inputs).trace(app.params, app.kv_cache, inputs, None)
     return max(_float_aval_sizes(traced.jaxpr.jaxpr))
 
 

@@ -125,29 +125,45 @@ def test_debug_logging_smoke(app, caplog):
 
 
 def test_profiler_capture(tmp_path, app):
-    """jax.profiler trace capture + xplane summary (reference
-    utils/profiling.py:33-66)."""
+    """jax.profiler trace capture through the telemetry session's control +
+    the per-op summary on ``ProfileData`` (reference
+    utils/profiling.py:33-66). The capture turns the program's spans on with
+    the profiler, so the trace holds them on the profiler's clock; the
+    session is stopped again afterwards."""
+    from jax.profiler import ProfileData
+
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+    from neuronx_distributed_inference_tpu.telemetry import tracing as tel_tracing
     from neuronx_distributed_inference_tpu.utils.profiling import profile_fn
 
-    summary = profile_fn(
-        lambda: app.generate(PROMPT, MASK, max_new_tokens=2).sequences,
-        str(tmp_path / "prof"), n_warmup=1, n_profile=1,
-    )
-    assert "ops" in summary
-    # the trace directory must exist with an xplane artifact
-    assert glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"), recursive=True) or (
-        "trace_dir" in summary or summary["ops"]
-    )
+    tel = TelemetrySession(enabled=False)
+    prev = tel_tracing.default_session()
+    tel_tracing.set_default_session(tel)
+    try:
+        summary = profile_fn(
+            lambda: app.generate(PROMPT, MASK, max_new_tokens=2).sequences,
+            str(tmp_path / "prof"), n_warmup=1, n_profile=1,
+        )
+    finally:
+        tel_tracing.set_default_session(prev)
+    # a CPU trace has no device plane: the summary is there and empty
+    assert summary == {"total_us": 0.0, "ops": []}
+    (path,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"), recursive=True)
+    host_events = {
+        e.name
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+    }
+    assert "app.cte" in host_events  # the program's own span, in the profiler's trace
+    assert not tel.enabled and any(e["name"] == "app.cte" for e in tel.events)
 
 
-def test_find_xplane_includes_gz_and_picks_newest(tmp_path):
-    """ISSUE 4 satellite: the xplane glob must see gzipped traces
-    (*.xplane.pb.gz — _parse_xplane_minimal already handles gzip) and pick
-    the NEWEST artifact by mtime, not lexicographic order."""
-    from neuronx_distributed_inference_tpu.utils.profiling import (
-        _find_xplane,
-        summarize_trace,
-    )
+def test_newest_xplane_includes_gz_and_summary_reads_it(tmp_path):
+    """The xplane glob must see gzipped traces (*.xplane.pb.gz) and pick the
+    NEWEST artifact by mtime, not lexicographic order; ``summarize_trace``
+    reads either through ``ProfileData``."""
+    from neuronx_distributed_inference_tpu.telemetry.tracing import newest_xplane
+    from neuronx_distributed_inference_tpu.utils.profiling import summarize_trace
 
     d1 = tmp_path / "plugins" / "profile" / "2024_01_01"
     d2 = tmp_path / "plugins" / "profile" / "2024_01_02"
@@ -155,21 +171,21 @@ def test_find_xplane_includes_gz_and_picks_newest(tmp_path):
     d2.mkdir(parents=True)
     old = d1 / "host.xplane.pb"
     old.write_bytes(b"")
-    new = d2 / "host.xplane.pb.gz"  # gzipped: previously NEVER found
+    new = d2 / "host.xplane.pb.gz"
     import gzip as _gzip
 
     new.write_bytes(_gzip.compress(b""))
     os.utime(old, (1_000_000, 1_000_000))
     os.utime(new, (2_000_000, 2_000_000))
-    assert _find_xplane(str(tmp_path)) == str(new)
-    # the gz artifact parses through the existing gzip-aware reader
-    summary = summarize_trace(str(tmp_path))
-    assert summary == {"total_us": 0.0, "ops": []}
+    assert newest_xplane(str(tmp_path)) == str(new)
+    assert summarize_trace(str(tmp_path)) == {"total_us": 0.0, "ops": []}
 
     # newest-by-mtime also holds within one suffix, against lexicographic
     os.utime(old, (3_000_000, 3_000_000))
-    assert _find_xplane(str(tmp_path)) == str(old)
-    assert _find_xplane(str(tmp_path / "empty-nowhere")) is None
+    assert newest_xplane(str(tmp_path)) == str(old)
+    assert summarize_trace(str(tmp_path)) == {"total_us": 0.0, "ops": []}
+    assert newest_xplane(str(tmp_path / "empty-nowhere")) is None
+    assert summarize_trace(str(tmp_path / "empty-nowhere"))["ops"] == []
 
 
 def _decode_from_cache(a, history, pos, n_steps):

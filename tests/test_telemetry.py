@@ -16,6 +16,7 @@ The load-bearing assertions:
 import importlib.util
 import json
 import pathlib
+import time
 
 import jax
 import numpy as np
@@ -942,3 +943,246 @@ def test_observability_doc_matches_registered_families():
         "docs/OBSERVABILITY.md names families nothing registers: "
         f"{unregistered}"
     )
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 23: start/stop in a running process, spans and counters of the split
+# serving step, named step programs and kernels
+# ---------------------------------------------------------------------------
+
+
+def _paged_config(**model):
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+
+    tpu = dict(
+        is_continuous_batching=True, batch_size=4, ctx_batch_size=1,
+        is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=24,
+        is_chunked_prefill=True, seq_len=64,
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=4, kernel_q_tile_size=16),
+    )
+    tpu.update(model.pop("tpu", {}))
+    return make_tiny_config(tpu=tpu, **model)
+
+
+@pytest.fixture(scope="module")
+def paged_app():
+    """The default split serving path: paged cache, chunked prefill, 1-ahead
+    decode, four slots."""
+    cfg = _paged_config()
+    return TpuModelForCausalLM(None, cfg).load(state_dict=make_random_hf_state_dict(cfg))
+
+
+SPLIT_PROMPTS = (list(range(1, 41)), [5, 17, 92, 41], list(range(60, 82)))
+
+
+def _drive_split(app, tel, switch=None, steps=40):
+    """Three requests through ``step()`` (never ``run_to_completion``: the
+    split step is what is instrumented), the second and third arriving while
+    the first is in flight. ``switch`` maps a step number to "start"/"stop",
+    applied before that step."""
+    app.init_kv_cache()
+    sess = ServingSession(app, telemetry=tel)
+    arrivals = {0: 0, 2: 1, 5: 2}
+    for k in range(steps):
+        if switch and k in switch:
+            getattr(tel, switch[k])()
+        if k in arrivals:
+            i = arrivals[k]
+            assert sess.add_request(f"r{i}", SPLIT_PROMPTS[i], max_new_tokens=6 + i)
+        if k > max(arrivals) and not sess.active:
+            break
+        sess.step()
+    assert not sess.active
+    return {rid: list(r.generated) for rid, r in sess.requests.items()}
+
+
+def test_start_and_stop_mid_run_change_nothing(paged_app, monkeypatch):
+    """A session that is started and stopped (twice) while requests are in
+    flight produces the tokens of one that never was, performs the same
+    number of device fetches, compiles nothing, and leaves no span open and
+    no request trace behind after ``stop()``."""
+    from neuronx_distributed_inference_tpu.analysis import RetraceGuard
+
+    golden = _drive_split(paged_app, TelemetrySession(enabled=False))  # compiles
+    counter = {"n": 0}
+    real_asarray, real_device_get = np.asarray, jax.device_get
+
+    def counting_asarray(a, *args, **kwargs):
+        counter["n"] += isinstance(a, jax.Array)
+        return real_asarray(a, *args, **kwargs)
+
+    def counting_device_get(x, *args, **kwargs):
+        counter["n"] += 1
+        return real_device_get(x, *args, **kwargs)
+
+    monkeypatch.setattr(np, "asarray", counting_asarray)
+    monkeypatch.setattr(jax, "device_get", counting_device_get)
+    never = TelemetrySession(enabled=False)
+    out_never = _drive_split(paged_app, never)
+    fetches_never, counter["n"] = counter["n"], 0
+    tel = TelemetrySession(enabled=False)
+    with RetraceGuard() as guard:
+        out = _drive_split(paged_app, tel, switch={1: "start", 4: "stop", 7: "start", 11: "stop"})
+    assert out == out_never == golden and sum(map(len, out.values())) == 6 + 7 + 8
+    assert counter["n"] == fetches_never > 0
+    assert guard.traces == []
+    assert never.registry.snapshot() == {} and not never.events
+    # r0 was admitted while stopped: counted, never traced, nothing dangling
+    assert not tel.enabled and not tel.traces
+    assert all(t_end is not None for *_, t_end in tel.span_tree().values())
+    snap = tel.registry.snapshot()
+    assert snap["nxdi_tokens_generated_total"]["samples"][0]["value"] > 0
+    assert "r0" not in {t.req_id for t in tel.completed}
+    names = {e["name"] for e in tel.events if e["type"] == "span"}
+    assert {"serving.step", "serving.fetch_wait", "serving.prefill_chunk"} <= names
+    tel.close()
+
+
+def _span_events(tel):
+    return [e for e in tel.events if e["type"] == "span"]
+
+
+def test_span_tree_of_the_split_step(paged_app):
+    """Every span of a split step names its parent and carries the step's
+    index; the fetch waits lie inside the step; ``nxdi_step_host_ms`` and
+    ``nxdi_step_fetch_wait_ms`` are the step span minus / the fetch-wait
+    spans, one observation per step."""
+    with TelemetrySession() as tel:
+        _drive_split(paged_app, tel)
+    spans = _span_events(tel)
+    parents = {
+        "serving.housekeeping": "serving.step",
+        "serving.prefill_chunk": "serving.step",
+        "serving.prefill_chunk.prepare": "serving.prefill_chunk",
+        "serving.prefill_chunk.dispatch": "serving.prefill_chunk",
+        "serving.prefill_chunk.fetch_wait": "serving.prefill_chunk",
+        "serving.prefill_chunk.commit": "serving.prefill_chunk",
+        "serving.decode": "serving.step",
+        "serving.decode.prepare": "serving.decode",
+        "serving.decode.dispatch": "serving.decode",
+        "serving.fetch_wait": "serving.step",
+        "serving.commit": "serving.step",
+        "serving.step": None,
+        "serving.admit": None,
+    }
+    assert {e["name"] for e in spans} == set(parents)
+    for e in spans:
+        assert e["parent"] == parents[e["name"]], e
+        assert e["t0"] <= e["t1"]
+    steps = {e["step"]: e for e in spans if e["name"] == "serving.step"}
+    assert sorted(steps) == list(range(1, len(steps) + 1))
+    both = [k for k in steps
+            if {"serving.prefill_chunk", "serving.decode", "serving.fetch_wait"}
+            <= {e["name"] for e in spans if e.get("step") == k}]
+    assert both, "no step held a chunk pass, a decode dispatch and a consume"
+    for e in spans:
+        if e["name"] not in ("serving.step", "serving.admit"):
+            outer = steps[e["step"]]
+            assert outer["t0"] <= e["t0"] and e["t1"] <= outer["t1"], e
+    admits = [e for e in spans if e["name"] == "serving.admit"]
+    assert [(e["req_id"], e["verdict"]) for e in admits] == [
+        ("r0", "admitted"), ("r1", "admitted"), ("r2", "admitted")]
+    decode = next(e for e in spans if e["name"] == "serving.decode")
+    assert decode["rows"] >= 1 and decode["kv_bucket"] in paged_app.token_generation_model.buckets
+    snap = tel.registry.snapshot()
+    host, wait = (snap[n]["samples"][0] for n in ("nxdi_step_host_ms", "nxdi_step_fetch_wait_ms"))
+    assert host["count"] == wait["count"] == len(steps)
+    waited = sum(e["dur_ms"] for e in spans if e["name"].endswith("fetch_wait"))
+    assert wait["sum"] == pytest.approx(waited, rel=1e-6)
+    assert host["sum"] + wait["sum"] == pytest.approx(
+        sum(e["dur_ms"] for e in steps.values()), rel=1e-6)
+
+
+def test_padding_counters_of_the_split_step(paged_app):
+    """Per chunk pass real + padded == num_slots x q_bucket, and the
+    counters are the sums over the passes; a decode dispatch runs over every
+    slot whatever its rows."""
+    with TelemetrySession() as tel:
+        _drive_split(paged_app, tel)
+    spans = _span_events(tel)
+    slots = paged_app.config.tpu_config.chunked_prefill_config.max_num_seqs
+    chunks = [e for e in spans if e["name"] == "serving.prefill_chunk"]
+    assert len(chunks) >= 4
+    for e in chunks:
+        assert e["real_tokens"] + e["padded_tokens"] == slots * e["q_bucket"]
+        assert 0 < e["real_tokens"] <= e["rows"] * e["q_bucket"]
+
+    def value(name):
+        return tel.registry.snapshot()[name]["samples"][0]["value"]
+
+    assert value("nxdi_prefill_real_tokens_total") == sum(map(len, SPLIT_PROMPTS))
+    assert value("nxdi_prefill_real_tokens_total") == sum(e["real_tokens"] for e in chunks)
+    assert value("nxdi_prefill_padded_tokens_total") == sum(e["padded_tokens"] for e in chunks)
+    decodes = [e for e in spans if e["name"] == "serving.decode"]
+    assert value("nxdi_decode_slots_total") == slots * len(decodes)
+    assert value("nxdi_decode_rows_total") == sum(e["rows"] for e in decodes)
+    assert 0 < value("nxdi_decode_rows_total") <= value("nxdi_decode_slots_total")
+
+
+def test_stopped_session_span_is_the_shared_null_context():
+    """A session that is stopped (never started, or stopped again) hands out
+    ONE null context: no clock read, nothing recorded, no instrument made."""
+
+    def no_clock():
+        raise AssertionError("a stopped session read the clock")
+
+    tel = TelemetrySession(enabled=False, clock=no_clock)
+    assert tel.span("a", rows=1) is tel.span("b") is tel_tracing.NULL_SPAN
+    with tel.span("a") as sp:
+        assert sp.dur_s == 0.0
+    tel.prefill_pass(3, 5)
+    tel.decode_pass(1, 4)
+    tel.step_timing(1.0, 1.0)
+    assert tel.registry.snapshot() == {} and not tel.events and tel.spans is None
+    assert tel.stop() is None  # stopping what never started is a no-op
+    tel.clock = time.perf_counter
+    tel.start()
+    assert tel.span("a") is not tel_tracing.NULL_SPAN
+    with tel.span("outer", step=7):
+        with tel.span("inner"):
+            pass
+    tel.stop()
+    assert tel.span("a") is tel_tracing.NULL_SPAN
+    inner, outer = (e for e in tel.events if e["type"] == "span")
+    assert (inner["name"], inner["parent"], inner["step"]) == ("inner", "outer", 7)
+    assert (outer["name"], outer["parent"], outer["step"]) == ("outer", None, 7)
+    tel.start()  # instruments are created once: the same families, no error
+    tel.close()
+
+
+@pytest.mark.parametrize("q_len,module,kernel", [
+    (None, "jit_token_generation_model_decode", "paged_tkg_decode_attention"),
+    (128, "jit_token_generation_model_chunk", "paged_flash_attention"),
+])
+def test_step_programs_and_kernels_carry_stable_names(q_len, module, kernel):
+    """Lowered for the TPU target, the decode step and the chunk pass of
+    the one token-generation runner are two modules named by tag and kind,
+    and each holds its Pallas kernel under the name the benchmark's trace
+    readers search for (``pallas_call(name=...)``, not the accident of a
+    jitted wrapper's name)."""
+    from jax import export
+
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.ops.kernel_mode import force_compiled_kernels
+
+    cfg = _paged_config(
+        hidden_size=256, intermediate_size=256, num_attention_heads=4,
+        num_key_value_heads=2, max_position_embeddings=1024,
+        tpu=dict(
+            seq_len=512, dtype="bfloat16", pa_block_size=32, pa_num_blocks=40,
+            token_generation_buckets=[512], attn_kernel_enabled=True,
+            attn_block_tkg_kernel_enabled=True,
+            chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=4, kernel_q_tile_size=128),
+        ),
+    )
+    app = TpuModelForCausalLM(None, cfg).load(random_weights=True)
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(512, q_len=q_len)
+    program = tkg.program_for(inputs)
+    with jax.set_mesh(app.mesh), force_compiled_kernels():
+        text = export.export(program, platforms=["tpu"])(
+            app.params, app.kv_cache, inputs, None).mlir_module()
+    assert f"module @{module} " in text
+    assert f'kernel_name = "{kernel}"' in text
+    other = "paged_flash_attention" if q_len is None else "paged_tkg_decode_attention"
+    assert f'kernel_name = "{other}"' not in text
