@@ -14,8 +14,15 @@ a token-major ``(block_size, H_kv, d)`` block for H_kv > 1).
 
 Device side (pure functions used inside the jitted step):
 - writes scatter token K/V through a flat ``slot_mapping`` (block *
-  block_size + offset); invalid slots (< 0) land in the reserved garbage
-  block 0 (reference's reserved block, block_kv_cache_manager.py:11-80).
+  block_size + offset); invalid slots (< 0) are dropped, idle rows' slots
+  land in the reserved garbage block 0 (reference's reserved block,
+  block_kv_cache_manager.py:11-80). The scatter has two forms that write the
+  same bytes: the head as an INDEXED dim (window ``(D,)``) at decode and
+  speculation widths, so that the layer scan's cache carry keeps the
+  row-major layout a Pallas operand takes and the step holds no copy of the
+  pool; the head in the WINDOW (``(H, D)``, 8x fewer index rows) for prefill
+  chunks and whenever the head axis is sharded
+  (:func:`update_block_cache_at_layer` says which and why).
 - decode reads gather blocks by the per-sequence ``block_table`` and view
   them as a contiguous (B, max_blocks*block_size) cache — logical position
   order is preserved, so the normal decode masks apply unchanged.
@@ -41,6 +48,8 @@ from neuronx_distributed_inference_tpu.modules.kvcache import (
     is_kv_quant_dtype,
     layer_dequant_factors,
 )
+from neuronx_distributed_inference_tpu.ops.kernel_mode import TKG_MAX_Q_LEN
+from neuronx_distributed_inference_tpu.parallel.mesh import MODEL_AXES, ambient_mesh
 
 GARBAGE_BLOCK = 0  # block id 0 reserved for invalid-slot writes
 
@@ -121,13 +130,21 @@ def kv_block_bytes(
 def block_cache_spec(quantized: bool = False):
     from jax.sharding import PartitionSpec as P
 
-    from neuronx_distributed_inference_tpu.parallel.mesh import MODEL_AXES
-
     spec = P(None, None, MODEL_AXES, None, None)
     if quantized:
         stream = QuantizedKV(data=spec, scale=P(None, MODEL_AXES))
         return BlockKVCache(k=stream, v=stream)
     return BlockKVCache(k=spec, v=spec)
+
+
+def _heads_sharded() -> bool:
+    """Whether the cache's head axis (``block_cache_spec``: MODEL_AXES) is
+    split over more than one device in the enclosing ``jax.set_mesh`` scope —
+    the observable the paged kernels' single-shard gate rests on."""
+    mesh = ambient_mesh()
+    return mesh is not None and any(
+        dict(mesh.shape).get(a, 1) > 1 for a in MODEL_AXES
+    )
 
 
 def update_block_cache_at_layer(
@@ -146,14 +163,58 @@ def update_block_cache_at_layer(
     out-of-range indices; -1 would WRAP to the last real block and corrupt
     it) — same net effect as the reference's garbage-block writes.
 
+    Which scatter, and why. The TPU compiler lays a scatter's operand out
+    with the update WINDOW's dims minor-most, and the layer scan's cache
+    carry takes that layout. The paged kernels that read the cache are Pallas
+    custom calls, which take only the default row-major layout. With the
+    head in the window (``(H, D)``: token-major ``{4,2,3,1,0}``) whatever a
+    kernel reads is relaid first: the WHOLE stacked pool once per layer
+    where the kernel takes the stacked cache (paged TKG decode), one layer's
+    slice where it takes a slice (paged flash, ragged), and the pool twice
+    more at the program's entry and exit either way. With the head an
+    INDEXED dim (window ``(D,)``, already minor-most in the head-major
+    layout) the carry stays row-major and nothing is relaid, but the scatter
+    has H times the index rows, and on a v5e a row costs ~60 ns whatever its
+    width. So the form is selected on the static ``S`` of ``slot_mapping``,
+    here and nowhere else:
+
+    * ``S <= TKG_MAX_Q_LEN`` (16: decode and speculation widths, the widths
+      the stacked-cache decode kernel serves) — per-head form. The served
+      Qwen3-1.7B decode step (48 rows, 28 layers, pool 2 x 1.94 GB) compiles
+      to 0 pool-shaped copies and 0 GB of temporaries against 6 and 3.89 GB,
+      and runs in 56 ms against 406 ms (v5e, kv bucket 1024, PERF.md PR 24).
+    * ``S > TKG_MAX_Q_LEN`` (prefill chunks of 32-128 tokens, the packed
+      ragged axis) — window form. There only a layer's slice and the
+      entry/exit pair are relaid (~20 ms a pass), less than 8x the rows
+      cost: the chunk program runs in 153 / 187 / 303 ms at q 32 / 64 / 128
+      against 170 / 246 / 445 ms per-head (same chip run).
+    * Head axis sharded (``block_cache_spec`` over a tp/ep/cp > 1 mesh) —
+      window form at every ``S``. There the paged kernels are gated off
+      (ops/kernel_mode.single_shard), no custom call demands a layout, and
+      the per-head form would add two all-gathers per scatter where the
+      window form has no collective.
+
     Quantized caches quantize fused into this scatter with the running
     per-(layer, head) absmax (see kvcache.update_cache_at_layer); invalid
-    (garbage) slots are excluded from the scale update."""
+    (garbage) slots are excluded from the scale update. The code streams are
+    written by the same scatter; the scales never pass through it."""
     L, NB1, H, bs, D = k_cache.shape
     B, S = slot_mapping.shape
     slots = slot_mapping.reshape(B * S)
     blocks = jnp.where(slots >= 0, slots // bs, NB1)
     offs = jnp.where(slots >= 0, slots % bs, 0)
+    per_head = S <= TKG_MAX_Q_LEN and not _heads_sharded()
+
+    def write(data, new):
+        rows = new.reshape(B * S, H, D).astype(data.dtype)
+        if per_head:  # head indexed, window (D,): the carry stays row-major
+            heads = jnp.arange(H)[None, :]
+            return data.at[layer_idx, blocks[:, None], heads, offs[:, None]].set(
+                rows, mode="drop"
+            )
+        # window (H, D): one index row per token, the carry token-major
+        return data.at[layer_idx, blocks, :, offs].set(rows, mode="drop")
+
     if isinstance(k_cache, QuantizedKV):
         # scale-update mask: negative (dropped) slots AND garbage-block
         # writes are excluded — idle serving rows carry all-zero block
@@ -162,20 +223,11 @@ def update_block_cache_at_layer(
         valid = (slot_mapping >= 0) & (slot_mapping // bs != GARBAGE_BLOCK)
         k_codes, k_scale = _quantized_update(k_cache, k_new, layer_idx, valid)
         v_codes, v_scale = _quantized_update(v_cache, v_new, layer_idx, valid)
-        k_data = k_cache.data.at[layer_idx, blocks, :, offs].set(
-            k_codes.reshape(B * S, H, D), mode="drop"
+        return (
+            QuantizedKV(write(k_cache.data, k_codes), k_scale),
+            QuantizedKV(write(v_cache.data, v_codes), v_scale),
         )
-        v_data = v_cache.data.at[layer_idx, blocks, :, offs].set(
-            v_codes.reshape(B * S, H, D), mode="drop"
-        )
-        return QuantizedKV(k_data, k_scale), QuantizedKV(v_data, v_scale)
-    k_cache = k_cache.at[layer_idx, blocks, :, offs].set(
-        k_new.reshape(B * S, H, D).astype(k_cache.dtype), mode="drop"
-    )
-    v_cache = v_cache.at[layer_idx, blocks, :, offs].set(
-        v_new.reshape(B * S, H, D).astype(v_cache.dtype), mode="drop"
-    )
-    return k_cache, v_cache
+    return write(k_cache, k_new), write(v_cache, v_new)
 
 
 def slot_mapping_from_block_table(

@@ -143,6 +143,13 @@ def use_packed(spec) -> bool:
     return ok
 
 
+#: widest query the TKG decode kernels serve (decode and speculation widths).
+#: The paged one reads the STACKED block cache, so up to this width the paged
+#: KV write keeps the scan's cache carry in the kernel's layout
+#: (modules/block_kvcache.update_block_cache_at_layer selects on it).
+TKG_MAX_Q_LEN = 16
+
+
 def use_tkg(spec, q_len: int, kv_width: int) -> bool:
     """Gate for the decode kernels (contiguous + paged TKG).
     ``spec.use_tkg_kernel`` (config attn_block_tkg_kernel_enabled): None =
@@ -152,7 +159,7 @@ def use_tkg(spec, q_len: int, kv_width: int) -> bool:
     if enabled is False:
         return False
     ok = (
-        q_len <= 16
+        q_len <= TKG_MAX_Q_LEN
         and spec.head_dim % 64 == 0
         and kv_width >= 128
         and kv_width % min(512, kv_width) == 0

@@ -52,6 +52,143 @@ def test_update_drops_negative_slots():
     np.testing.assert_array_equal(k_up, k0)  # NOTHING else (esp. last block)
 
 
+def _write_case(dtype, S, seed=0):
+    """A 3-layer cache with junk in it, a (B, S) write whose slots cover every
+    kind: real blocks, the garbage block (slot >= 0 inside block 0), negative
+    (dropped), and the LAST block's last offset (where a wrapped -1 would
+    land)."""
+    import jax
+    import jax.numpy as jnp
+
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        init_block_cache,
+    )
+    L, NB, bs, H, D, B = 3, 12, 32, 4, 16, 3
+    rng = np.random.default_rng(seed)
+    cache = init_block_cache(L, NB, bs, H, D, dtype=dtype)
+
+    def junk(x):
+        if x.ndim == 2:  # (L, H) running scales
+            return jnp.asarray(rng.uniform(0.5, 1.5, x.shape), x.dtype)
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(-100, 100, x.shape), x.dtype)
+        return jnp.asarray(rng.normal(size=x.shape), jnp.float32).astype(x.dtype)
+
+    cache = jax.tree.map(junk, cache)
+    slots = np.full((B, S), -1, np.int64)
+    n = max(1, (3 * S) // 4)  # the tail of every row stays negative
+    slots[0, :n] = 2 * bs + 5 + np.arange(n)  # real blocks, crossing borders
+    slots[1, :n] = np.arange(n) % bs  # idle row: INTO the garbage block
+    slots[2, :n] = (NB + 1) * bs - 1 - np.arange(n)  # down from the last slot
+    if S > bs:  # keep garbage-block offsets distinct (scatter order is free)
+        slots[1, bs:] = -1
+    k_new = jnp.asarray(rng.normal(size=(B, S, H, D)) * 2.0, jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(B, S, H, D)) * 0.5, jnp.float32)
+    return cache, jnp.asarray(slots, jnp.int32), k_new, v_new
+
+
+def _loop_write(data, rows, layer, slots):
+    """The plain reference: one assignment per (layer, block, head, offset)."""
+    out = np.array(data)
+    rows = np.asarray(rows)
+    bs = out.shape[3]
+    B, S = slots.shape
+    for b in range(B):
+        for s in range(S):
+            slot = int(slots[b, s])
+            if slot < 0:
+                continue
+            for h in range(out.shape[2]):
+                out[layer, slot // bs, h, slot % bs] = rows[b, s, h]
+    return out
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("S", [1, 128], ids=["decode_S1", "chunk_S128"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8", "fp8"])
+def test_paged_write_matches_plain_loop(dtype, S):
+    """Both scatter forms of update_block_cache_at_layer (per-head at S=1,
+    token window at S=128) against a NumPy loop, BIT-equal: real slots,
+    garbage-block slots, negative slots dropped, last block intact. A
+    quantized cache's codes are placed the same way and its scales are
+    exactly what the shared quantizer returns — the write never touches
+    them."""
+    import jax.numpy as jnp
+
+    from neuronx_distributed_inference_tpu.config import to_dtype
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        GARBAGE_BLOCK,
+        update_block_cache_at_layer,
+    )
+    from neuronx_distributed_inference_tpu.modules.kvcache import (
+        QuantizedKV,
+        _quantized_update,
+    )
+
+    cache, slots, k_new, v_new = _write_case(to_dtype(dtype), S)
+    layer = 1
+    k_up, v_up = update_block_cache_at_layer(
+        cache.k, cache.v, k_new, v_new, jnp.int32(layer), slots
+    )
+    sl = np.asarray(slots)
+    for stream, new, up in ((cache.k, k_new, k_up), (cache.v, v_new, v_up)):
+        if isinstance(stream, QuantizedKV):
+            bs = stream.data.shape[3]
+            valid = (slots >= 0) & (slots // bs != GARBAGE_BLOCK)
+            codes, scale = _quantized_update(stream, new, jnp.int32(layer), valid)
+            np.testing.assert_array_equal(np.asarray(up.scale), np.asarray(scale))
+            # layers the write does not address keep their scale
+            np.testing.assert_array_equal(
+                np.asarray(up.scale)[[0, 2]], np.asarray(stream.scale)[[0, 2]]
+            )
+            want, got = _loop_write(stream.data, codes, layer, sl), up.data
+        else:
+            want, got = _loop_write(stream, new.astype(stream.dtype), layer, sl), up
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("S", [1, 128], ids=["decode_S1", "chunk_S128"])
+def test_paged_write_on_head_sharded_cache_has_no_collective(S):
+    """tp=4 with the cache head-sharded (block_cache_spec): the compiled
+    write holds NO collective at either width — each shard writes its own
+    heads (the window form is kept there; with the head in the scatter's
+    indices GSPMD gathers the updates) — and writes what the loop writes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        block_cache_spec,
+        update_block_cache_at_layer,
+    )
+    from neuronx_distributed_inference_tpu.parallel.mesh import MODEL_AXES, build_mesh
+
+    cache, slots, k_new, v_new = _write_case(jnp.bfloat16, S)
+    mesh = build_mesh(tp_degree=4)
+    spec = block_cache_spec()
+    cache_sh = NamedSharding(mesh, spec.k)
+    new_sh = NamedSharding(mesh, P(None, None, MODEL_AXES, None))
+    rep = NamedSharding(mesh, P())
+    fn = jax.jit(
+        update_block_cache_at_layer,
+        in_shardings=(cache_sh, cache_sh, new_sh, new_sh, rep, rep),
+        out_shardings=(cache_sh, cache_sh),
+    )
+    args = (cache.k, cache.v, k_new, v_new, jnp.int32(1), slots)
+    with jax.set_mesh(mesh):
+        hlo = fn.lower(*args).compile().as_text()
+        k_up, _ = fn(*args)
+    for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute",
+               "reduce-scatter"):
+        assert op not in hlo, op
+    want = _loop_write(cache.k, k_new.astype(jnp.bfloat16), 1, np.asarray(slots))
+    np.testing.assert_array_equal(_bits(k_up), _bits(want))
+
+
 def _session_apps():
     sd = None
     apps = []

@@ -15,6 +15,8 @@ module — a TPU executable written from here cannot be read back without a
 chip, and the next run would warn on every entry.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -217,3 +219,62 @@ def test_tp4_ragged_mixed_step_keeps_its_kernel(chip_mesh):
     assert _custom_calls(compiled) >= 1
     assert "all-reduce" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes < 1.2e9
+
+
+# Qwen3-1.7B's head and layer geometry (16/8 heads of 128, 28 layers, hidden
+# 2048, vocab 151936) on the llama graph: the paged serving step of the
+# benchmark's one-chip configuration, whose KV pool is 2 x 1.94 GB
+QWEN3_1P7B_GEOMETRY = dict(
+    LLAMA_1B, intermediate_size=6144, num_attention_heads=16,
+    num_key_value_heads=8, num_hidden_layers=28, vocab_size=151936,
+    head_dim=128, max_position_embeddings=40960,
+)
+
+
+def _pool_copies(compiled, pool_shape):
+    """(in the layer scan's body, elsewhere): the ``copy`` ops of the
+    optimized HLO whose result has the block pool's shape. A copy the
+    compiler put after the scatter carries the scatter's ``op_name`` under
+    ``while/body``; one at the program's entry or exit carries none."""
+    pool = "bf16[" + ",".join(str(d) for d in pool_shape) + "]"
+    lines = [
+        line for line in compiled.as_text().splitlines()
+        if re.search(r"= " + re.escape(pool) + r"\{[^}]*\} copy\(", line)
+    ]
+    in_scan = sum("while/body" in line for line in lines)
+    return in_scan, len(lines) - in_scan
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
+    """The paged KV write leaves the layer scan's cache carry in the layout
+    the kernel reads (modules/block_kvcache.update_block_cache_at_layer), at
+    the benchmark's widths: 48 slots, 1056 blocks x 32 tokens, 28 layers.
+
+    decode (48 x 1, per-head scatter): NO pool-shaped copy anywhere — with
+    the head in the scatter's window this program held 6 (two per layer in
+    the scan, two at entry, two at exit) and 3.89 GB of temporaries.
+    chunk (48 x 128, window scatter kept: 8x fewer index rows): none in the
+    scan — the paged flash kernel takes one layer's slice — and the
+    entry/exit pair for K and for V."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+
+    app, params, cache = _abstract_app(
+        QWEN3_1P7B_GEOMETRY, chip_mesh(1), batch_size=48, seq_len=8192,
+        context_encoding_buckets=[8192], token_generation_buckets=[1024, 8192],
+        is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
+        pa_num_blocks=1056, pa_block_size=32, is_chunked_prefill=True,
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=48),
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    assert _custom_calls(compiled) >= 1
+    in_scan, outside = _pool_copies(compiled, cache.k.shape)
+    assert in_scan == 0
+    if program == "decode":
+        assert outside == 0
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    else:
+        assert outside == 4
