@@ -1,7 +1,8 @@
 """What decides ``correct``.
 
-(a) Logits of the served model against the plain reference
-    (``reference.py``), outside the window, at the configuration's full
+(a) Logits of the served model against the plain reference the
+    configuration names (``references/<cfg["reference"]>.py``, ``dense`` where
+    it names none), outside the window, at the configuration's full
     width, on two prompts made from the seed: one as long as the longest
     prompt of the cell's traffic, one of a single partial chunk. A probe
     application — the cell's own configuration (``seq_len``, kv buckets,
@@ -18,12 +19,19 @@
     prompt) or four tokens from the seed (long prompt: a second pass of
     all its chunks, through the session, would double the probe's time).
     The logits at the last prompt position and the four decode steps
-    after it are read, and the same tokens go through the reference.
-      - logits:  max|served - ref| <= LOGIT_TOL * max|ref|
-      - session (short prompt): every token the session chose is, by the REFERENCE's
-        logits, within LOGIT_TOL * max|ref| of the best token at its
-        position. Random weights give near-flat logits, so equality of
-        argmax is not asked (PR 21: two paths agreed on 4 of 8 requests).
+    after it are read, and the same tokens go through the reference twice:
+    in float32 (``ref32``) and as its bf16 twin (``twin``: the same
+    equations with the roundings a faultless bf16 deployment of this depth
+    and tensor-parallel degree states). Per probe row
+        err   = max|served - ref32|
+        floor = max|twin - ref32|      the noise a sound bf16 model shows HERE
+        scale = max|ref32|
+      - logits:  err <= K * floor
+      - session (short prompt): every token the session chose is, by ref32,
+        within K * floor of the best token at its position. Random weights
+        give near-flat logits, so equality of argmax is not asked (PR 21:
+        two paths agreed on 4 of 8 requests).
+    No constant of scale: depth, width and layout move err and floor alike.
 (b) In the window: every finished request has exactly its budget of tokens,
     all inside the vocabulary; none ended FAILED.
 (c) No compilation inside the window (``system.CompileLog``).
@@ -31,24 +39,37 @@
 
 from __future__ import annotations
 
+import importlib
+import time
 from typing import Dict, List
 
 import numpy as np
 
-from . import reference, system
+from . import system
 
-#: max|served - ref| <= LOGIT_TOL * max|ref|. The served model multiplies in
-#: bf16 and rounds every activation to bf16 (8 mantissa bits, eps 0.4%);
-#: through 28 layers the roundings random-walk to a few percent of the logit
-#: scale. Read on the chip against this float32 reference (PR 22, Qwen3-1.7B,
-#: scale 4.3-4.8, some 80 prompts of 100 to 6144 tokens over 40 seeds):
-#: 2.9-3.7%, the same for a 6144-token prompt at the 8192 bucket (3.3%) as
-#: for 100 tokens (3.0%) — the size PR 21 read between two bf16 paths of the
-#: 1B (2.6-2.9%). The bound sits 0.8 points above the largest reading: the
-#: maximum over 5 x 151936 logits moves little from seed to seed. A fault
-#: that matters (a mis-masked tile, a dropped head, a norm weight not
-#: applied, fp8 in place of bf16) moves logits by tens of percent of scale.
-LOGIT_TOL = 0.045
+#: err <= K * floor, ONE number for every configuration: depth, width and
+#: tensor-parallel layout move ``err`` and ``floor`` alike, so their ratio is
+#: a property of the program and not of the model. Read on the chip with
+#: ``selftest/read_ratio.py`` and in the cells' own runs (PR 26, TPU v5 lite):
+#:   Qwen3-1.7B, one chip, 28 layers: 40 rows over 20 seeds (prompts of 256
+#:     and 6144 tokens, the kv widths of the decode and the longprompt cell,
+#:     and the 100-token session prompt): err / floor 0.859 - 1.243, median
+#:     1.04 (err 2.6 - 3.9% of scale, as PR 22 read it);
+#:   Qwen3-14B at tp = 4, four chips, 40 layers: 24 rows over 12 seeds (prompts
+#:     of 2048 tokens, the chat cell's kv width, and 100): 0.957 - 1.208 (err
+#:     5.0 - 6.5% of scale: over the old constant 0.045, with nothing at fault);
+#:   the CONTROL, the reference itself at fp8-e4m3 (the nearest precision
+#:     below bf16) in the program's place, same prompts and tokens:
+#:     16.8 - 38.9 on the 1.7B (8 rows), 15.0 - 25.2 on the 14B (6 rows).
+#: K is the largest sound reading plus the share PR 22 left above its own
+#: largest (0.8 of 3.7: 1.243 x 1.22 = 1.51), held to the 1.5 the issue allows;
+#: the control's smallest is ten times that. A ratio of two maxima over
+#: 5 x 151936 logits swings by some 10% from seed to seed; the ratio of the
+#: root mean squares, printed beside it, read 0.985 - 1.159 on the same 64 rows.
+#: What the rule fails at a small size on the CPU (``selftest/test_correct.py``):
+#: a norm weight not applied 14 - 16, a KV head dropped 98 - 114, the mask off
+#: by one tile 122 - 161, fp8 in place of bf16 18 - 26.
+K = 1.5
 
 PROBE_SHORT_PROMPT = 100  # one partial chunk of the default 128
 PROBE_DECODE_STEPS = 4
@@ -77,6 +98,12 @@ def probe_overrides(cfg: dict, max_prompt: int) -> Dict[str, dict]:
         "tpu": dict(batch_size=PROBE_SLOTS, output_logits=True, pa_num_blocks=1 + 2 * per_row),
         "chunked": dict(max_num_seqs=PROBE_SLOTS),
     }
+
+
+def load_reference(cfg: dict):
+    """The module of the plain reference this configuration names."""
+    return importlib.import_module(
+        "benchmark.harness.references." + cfg.get("reference", "dense"))
 
 
 def _session_tokens(probe, prompts: List[np.ndarray], budget: int) -> List[List[int]]:
@@ -151,54 +178,91 @@ def _forced_logits(probe, prompts: List[np.ndarray], forced: List[List[int]],
     return [np.stack(g) for g in got]
 
 
-def check_model(cfg: dict, devices, seed: int, params, pspecs, degree: int,
-                max_prompt: int) -> dict:
-    """Part (a), with one prompt of ``max_prompt`` tokens (the longest of
-    the cell's traffic). Raises CorrectnessError; returns the facts it read."""
-    attrs = system.model_attrs(cfg)
-    geo = reference.Geometry.from_config(attrs, degree)
+def serve_probe(cfg: dict, devices, seed: int, params, pspecs, max_prompt: int):
+    """(prompts, chosen, served): the two probe prompts, per prompt the
+    tokens that follow it (the long prompt's from the seed, the short
+    prompt's as the probe session chose them) and the served logits
+    (1 + PROBE_DECODE_STEPS, V) at the last prompt position and after each
+    of the first PROBE_DECODE_STEPS of them. The probe application is gone
+    when this returns."""
+    vocab = system.model_attrs(cfg)["vocab_size"]
     over = probe_overrides(cfg, max_prompt)
-    lengths = (max_prompt, PROBE_SHORT_PROMPT)
     probe = system.build_app(cfg, devices, seed, tpu_overrides=over["tpu"],
                              chunked_overrides=over["chunked"])
     system.give_weights(probe, params, pspecs)
     rng = np.random.default_rng([int(seed), 7])
-    prompts = [rng.integers(0, geo.vocab, size=n).astype(np.int32) for n in lengths]
+    prompts = [rng.integers(0, vocab, size=n).astype(np.int32)
+               for n in (max_prompt, PROBE_SHORT_PROMPT)]
     budget = PROBE_DECODE_STEPS + 1
     try:
-        chosen = [[int(t) for t in rng.integers(0, geo.vocab, size=budget)],
+        chosen = [[int(t) for t in rng.integers(0, vocab, size=budget)],
                   _session_tokens(probe, prompts[1:], budget)[0]]
         probe.init_kv_cache()
         served = _forced_logits(probe, prompts, chosen, probe_width(cfg, max_prompt))
     finally:
         probe.params = probe.kv_cache = None
-    facts = {"tolerance": LOGIT_TOL, "prompts": list(lengths), "rows": []}
+    return prompts, chosen, served
+
+
+def probe_row(prompt, chosen):
+    """(tokens, positions) of one probe row as the reference sees it: the
+    prompt with the first PROBE_DECODE_STEPS tokens that followed it, read at
+    the last prompt position and after each of those tokens."""
+    tokens = list(prompt) + list(chosen[:PROBE_DECODE_STEPS])
+    return tokens, [len(prompt) - 1 + k for k in range(PROBE_DECODE_STEPS + 1)]
+
+
+def judge(cfg: dict, params, degree: int, prompts, chosen, served) -> dict:
+    """``served`` against the float32 reference and its bf16 twin, row by
+    row (module docstring). Raises CorrectnessError; returns the facts it
+    read: per row ``err``, ``floor``, ``scale``, ``ratio`` = err / floor and
+    the same ratio of root mean squares (steadier than a ratio of maxima;
+    printed, not judged)."""
+    reference = load_reference(cfg)
+    geo = reference.geometry(system.model_attrs(cfg), degree)
+    budget = PROBE_DECODE_STEPS + 1
+    facts = {"K": K, "reference": reference.__name__.rsplit(".", 1)[-1],
+             "prompts": [len(p) for p in prompts], "rows": []}
     errors = []
+    rms = lambda a: float(np.sqrt(np.mean(np.square(a, dtype=np.float64))))
     for r, p in enumerate(prompts):
-        tokens = list(p) + chosen[r][:PROBE_DECODE_STEPS]
-        positions = [len(p) - 1 + k for k in range(budget)]
+        tokens, positions = probe_row(p, chosen[r])
+        t0 = time.perf_counter()
         ref = reference.reference_logits(params, geo, tokens, positions)
-        cmp = reference.compare(served[r], ref)
+        t1 = time.perf_counter()
+        twin = reference.twin_logits(params, geo, tokens, positions)
+        t2 = time.perf_counter()
+        got = np.asarray(served[r], np.float32)
+        err, floor = float(np.abs(got - ref).max()), float(np.abs(twin - ref).max())
         # how far below the reference's best each token the session chose is
-        regret = 0.0
+        regret = None
         if r > 0:  # the short prompt's tokens are the session's
             regret = float(max(ref[k].max() - ref[k, chosen[r][k]] for k in range(budget)))
-        facts["rows"].append({**cmp, "prompt": len(p), "session_token_regret": regret if r else None})
-        if not cmp["finite"]:
+        facts["rows"].append({"prompt": len(p), "err": err, "floor": floor,
+                              "scale": float(np.abs(ref).max()),
+                              "ratio": err / floor if floor > 0 else None,
+                              "limit": K * floor, "session_token_regret": regret,
+                              "rms_ratio": rms(got - ref) / max(rms(twin - ref), 1e-30),
+                              "ref32_s": t1 - t0, "twin_s": t2 - t1})
+        if not np.isfinite(got).all():
             errors.append(f"prompt {r}: non-finite logits from the served model")
-        if cmp["max_abs_err"] > LOGIT_TOL * cmp["scale"]:
-            errors.append(
-                f"prompt {r}: max logit error {cmp['max_abs_err']:.4g} > "
-                f"{LOGIT_TOL} x reference scale {cmp['scale']:.4g}"
-            )
-        if regret > LOGIT_TOL * cmp["scale"]:
+        if not err <= K * floor:
+            errors.append(f"prompt {r}: max logit error {err:.4g} > {K} x the bf16 twin's {floor:.4g}")
+        if regret is not None and regret > K * floor:
             errors.append(
                 f"prompt {r}: a token the session chose is {regret:.4g} below the "
-                f"reference's best, more than {LOGIT_TOL} x scale {cmp['scale']:.4g}"
+                f"reference's best, more than {K} x the bf16 twin's error {floor:.4g}"
             )
     if errors:
         raise CorrectnessError("; ".join(errors), facts)
     return facts
+
+
+def check_model(cfg: dict, devices, seed: int, params, pspecs, degree: int,
+                max_prompt: int) -> dict:
+    """Part (a), with one prompt of ``max_prompt`` tokens (the longest of
+    the cell's traffic). Raises CorrectnessError; returns the facts it read."""
+    return judge(cfg, params, degree, *serve_probe(cfg, devices, seed, params, pspecs, max_prompt))
 
 
 def check_window(records, session, vocab: int) -> List[str]:

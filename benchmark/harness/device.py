@@ -43,11 +43,18 @@ def find_chips(chips: int, *, rehearsal: bool = False):
     return devices[:chips], peaks, info
 
 
+def memory_by_chip(devices) -> List[dict]:
+    """Per device, bytes in use now and at the peak so far (a fact line of
+    the run: which chip is the fullest, and when)."""
+    out = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        out.append({"in_use": int(stats.get("bytes_in_use", 0)),
+                    "peak": int(stats.get("peak_bytes_in_use", 0))})
+    return out
+
+
 def memory_peak_bytes(devices) -> int:
     """Peak bytes in use on the fullest of ``devices`` (0 where the backend
     keeps no count, as the CPU does)."""
-    peak = 0
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
-    return peak
+    return max((m["peak"] for m in memory_by_chip(devices)), default=0)

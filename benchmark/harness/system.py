@@ -19,7 +19,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 #: model; every other top-level key is an attribute of the model's config
 META_KEYS = frozenset(
     {"name", "source", "deployment", "assumed", "reduced", "tpu_config",
-     "chunked_prefill", "why", "rehearsal", "notes", "memory"}
+     "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference"}
 )
 
 WEIGHT_STD = 0.02

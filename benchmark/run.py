@@ -195,7 +195,8 @@ def main(argv=None) -> int:
     kernels = system.kernel_census(app, shapes) if args.trace == 1 and not rehearsal else {}
     warm_drive(app, attrs["vocab_size"], args.seed)
     emit(phase="warm_up", seconds=time.perf_counter() - t, programs=len(shapes),
-         kernels=kernels, traffic=traffic.summary(), digest=traffic.digest(), **log.facts())
+         kernels=kernels, traffic=traffic.summary(), digest=traffic.digest(),
+         memory=device.memory_by_chip(devices), **log.facts())
 
     from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
 
@@ -242,8 +243,12 @@ def main(argv=None) -> int:
     emit(phase="window", wall_s=wall, window_s=driver.window_s, summary=summary, spans=spans,
          compiled_in_window=compiled_in_window, faults=faults[:10],
          tokens_counted=counted, tokens_stamped=stamped,
+         preemptions=sum(getattr(session.requests[r.req_id], "preemptions", 0)
+                         for r in records if r.req_id in session.requests),
          backlog_mid=_at(driver.samples["backlog"], args.seconds * 0.5),
-         backlog_end=_at(driver.samples["backlog"], args.seconds))
+         backlog_end=_at(driver.samples["backlog"], args.seconds),
+         in_flight_mid=_in_flight(records, args.seconds * 0.5),
+         in_flight_end=_in_flight(records, args.seconds))
     correct_all = bool(model_ok and not faults and compiled_in_window == 0 and counted == stamped)
 
     device_out = dict(device_info, memory_peak_bytes=device.memory_peak_bytes(devices))
@@ -381,6 +386,14 @@ def _at(samples, t):
             break
         value = v
     return value
+
+
+def _in_flight(records, t) -> int:
+    """Requests admitted at or before time ``t`` that had not ended by then,
+    from the records' own stamps (nothing is sampled in the loop for it)."""
+    ended = lambda r: (r.finished or r.failed) and r.commits and r.commits[-1][0] <= t
+    return sum(1 for r in records
+               if r.admitted_s is not None and r.admitted_s <= t and not ended(r))
 
 
 if __name__ == "__main__":

@@ -9,7 +9,8 @@ import shutil
 
 import pytest
 
-from benchmark.harness import catalog
+from benchmark.harness import catalog, correct
+from benchmark.harness.system import model_attrs
 from benchmark.harness.traffic import Traffic
 
 DATA_DIRS = ("configs", "traffic", "workloads", "layer_metrics")
@@ -43,14 +44,22 @@ def test_the_catalog_holds_to_its_own_rules():
 
 
 def test_no_width_is_cut_and_assumed_keys_are_listed():
+    """The contract's rules, of every configuration whatever its family:
+    ``reduced`` names no width, agrees with BENCHMARK.json, and every model
+    key that no catalog row backs is listed as assumed."""
+    from benchmark.selftest.test_contract import WIDTH
+
+    with open(os.path.join(catalog.REPO_DIR, "BENCHMARK.json")) as f:
+        entries = {os.path.basename(c["file"]): c for c in json.load(f)["configs"]}
     for name in os.listdir(os.path.join(catalog.BENCH_DIR, "configs")):
         with open(os.path.join(catalog.BENCH_DIR, "configs", name)) as f:
             cfg = json.load(f)
-        assert cfg["reduced"] == []
-        from benchmark.harness.system import model_attrs
-
+        assert not any(WIDTH.search(k) for k in cfg["reduced"])
+        assert cfg["reduced"] == entries[name]["reduced"] and cfg["source"] == entries[name]["source"]
         assert sorted(model_attrs(cfg)) == sorted(cfg["assumed"]["keys"])
-        assert cfg["head_dim"] == 128 and cfg["vocab_size"] == 151936
+        # the reference it names (``dense`` where it names none) is a module with the interface
+        ref = correct.load_reference(cfg)
+        assert all(callable(getattr(ref, f)) for f in ("geometry", "reference_logits", "twin_logits"))
 
 
 @pytest.fixture
@@ -68,7 +77,7 @@ def write(path, obj):
         json.dump(obj, f)
 
 
-def test_a_later_pr_adds_files_and_entries_only(copy):
+def test_a_later_pr_adds_files_and_entries_only(copy, monkeypatch):
     bdir = copy / "benchmark"
     with open(copy / "BENCHMARK.json") as f:
         bench = json.load(f)
@@ -86,7 +95,16 @@ def test_a_later_pr_adds_files_and_entries_only(copy):
     with open(bdir / "configs" / "qwen3-1p7b.json") as f:
         cfg = json.load(f)
     cfg.update(name="other-1b", model_type="mistral", source="https://example.org/other/config.json",
-               hidden_size=1024, num_hidden_layers=4)
+               hidden_size=1024, num_hidden_layers=4, reference="other_family")
+    # ... whose family brings its own plain reference: a module file, found by the name the configuration gives
+    from benchmark.harness import references
+
+    (copy / "references").mkdir()
+    (copy / "references" / "other_family.py").write_text(
+        "def geometry(attrs, degree):\n    return (attrs['hidden_size'], degree)\n"
+        "def reference_logits(params, geo, tokens, positions):\n    return 'float32'\n"
+        "def twin_logits(params, geo, tokens, positions):\n    return 'twin'\n")
+    monkeypatch.setattr(references, "__path__", list(references.__path__) + [str(copy / "references")])
     write(bdir / "configs" / "other-1b.json", cfg)
     bench["configs"].append({"name": "other-1b", "source": cfg["source"],
                              "file": "benchmark/configs/other-1b.json", "reduced": [], "why": "x"})
@@ -118,6 +136,10 @@ def test_a_later_pr_adds_files_and_entries_only(copy):
     assert len(cells) == len(bench["workloads"])
     cell = cells["other-1b.burst"]
     assert cell.config["model_type"] == "mistral" and cell.traffic_name == "burst"
+    ref = correct.load_reference(cell.config)
+    assert ref.geometry(cell.config, 1) == (1024, 1) and ref.twin_logits(None, None, [], []) == "twin"
+    assert "reference" not in model_attrs(cell.config)  # the program's config never sees the key
+    assert correct.load_reference(cells["qwen3-1p7b.burst"].config).__name__.endswith(".dense")
     assert [m["name"] for m in cell.per_layer] == ["sched.decode_steps", "admit.call_ms"]
     # the one general generator reads the new mix; the readers read the new metrics
     t = Traffic(cell.traffic, seed=1, vocab_size=1000, loop="open", seconds=10.0,

@@ -44,41 +44,6 @@ def test_rehearsal_of_every_cell(cell, chips):
     assert window["tokens_counted"] == window["tokens_stamped"]
 
 
-def test_the_four_chip_example_rehearses_on_four_virtual_devices(tmp_path):
-    """``data/tp4_example``: the four-chip cell PR 22 could not bring up on the
-    chip (PERF.md section 7). Added to a copy of the catalog as a later PR
-    would add it — files and entries only — it runs tensor-parallel over
-    four virtual devices: weights born sharded, the reference on them."""
-    import shutil
-
-    ex = os.path.join(os.path.dirname(__file__), "data", "tp4_example")
-    root = tmp_path / "repo"
-    shutil.copytree(catalog.BENCH_DIR, root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__", "selftest", "harness", "run.py"))
-    with open(os.path.join(catalog.REPO_DIR, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    with open(os.path.join(ex, "entries.json")) as f:
-        entries = json.load(f)
-    cell = entries["workload"]["name"]
-    shutil.copy(os.path.join(ex, "config.json"), root / entries["config"]["file"])
-    shutil.copy(os.path.join(ex, "cell.json"), root / "benchmark" / "workloads" / (cell + ".json"))
-    shutil.copy(os.path.join(ex, "coll.exposed_share.json"),
-                root / "benchmark" / "layer_metrics" / "coll.exposed_share.json")
-    bench["configs"].append(entries["config"])
-    bench["workloads"].append(entries["workload"])
-    bench["per_layer"].append(entries["per_layer"])
-    for m in bench["end_to_end"]:
-        if m["name"] in ("ttft_p50_ms", "tpot_p95_ms"):
-            m["workloads"] = m["workloads"] + [cell]
-    with open(root / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f)
-    assert cell in catalog.check_catalog(root=str(root))
-    p = run(cell, "--trace", "0", "--catalog-root", str(root), devices=4)
-    assert p.returncode == 0, p.stderr[-2000:]
-    last = json.loads(p.stdout.strip().splitlines()[-1])
-    assert last["correct"] is True and last["device"]["count"] == 4
-
-
 def test_traced_rehearsal_starts_and_stops_the_profiler():
     p = run(cells()[0][0], "--trace", "1")
     assert p.returncode == 0, p.stderr[-2000:]
