@@ -193,6 +193,26 @@ def _paged_tkg_case(B, MB, bs, cache_dtype):
     return build
 
 
+def _ssm_update_case(rows):
+    """Granite-4.0-H-micro's state-space geometry (36 layers, 64 heads of 64,
+    state 128) at ``rows`` serving slots: the benchmark's decode cell."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import ssm_state_update as su
+
+        L, H, P, N = 36, 64, 64, 128
+        state = _sds((L, rows, H, P, N), jnp.float32)
+        x = _sds((rows, H, P), jnp.bfloat16)
+        bc = _sds((rows, N), jnp.bfloat16)
+        args = (state, _sds((), jnp.int32), x, bc, bc, _sds((rows, H), jnp.float32),
+                _sds((H,), jnp.float32), _sds((rows,), jnp.bool_), _sds((rows,), jnp.bool_))
+        return _unjit(su.ssm_state_update), args
+
+    return build
+
+
 def _paged_flash_case(Sq, MB, bs, cache_dtype):
     def build():
         import jax.numpy as jnp
@@ -471,6 +491,17 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         cases=(KernelCase("h2048_i8192", "bfloat16", _moe_case(4, 2, 8)),),
     ),
     KernelSpec(
+        name="ssm_state_update",
+        site=("ssm_state_update.py", "ssm_state_update"),
+        entry="ssm_state_update",
+        fallback="neuronx_distributed_inference_tpu.modules.ssm:mamba2_step",
+        parity_test="tests/test_ssm.py",
+        lowering_test="tests/test_chip_compile.py",
+        # heads_per_block is a keyword of the entry (16: a 512 KiB tile),
+        # not a tuning-table entry: nothing was swept on the chip yet
+        cases=(KernelCase("rows48", "float32", _ssm_update_case(48)),),
+    ),
+    KernelSpec(
         name="quant_matmul",
         site=("quant_matmul.py", "quant_matmul"),
         entry="quant_matmul",
@@ -578,6 +609,27 @@ def _dot_stats(jaxpr, out):
     return out
 
 
+#: vector-unit arithmetic counted for a kernel that has NO matrix product
+#: (ssm_state_update: a multiply-add over a float32 tile and a lane sum)
+_VECTOR_OPS = frozenset({"mul", "add", "sub", "reduce_sum"})
+
+
+def _vector_flops(jaxpr) -> int:
+    """Elementwise multiplies, adds and sum-reductions of a kernel jaxpr, one
+    operation per element (``reduce_sum``: per element read)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in _VECTOR_OPS:
+            aval = (eqn.invars if eqn.primitive.name == "reduce_sum" else eqn.outvars)[0].aval
+            n = 1
+            for d in getattr(aval, "shape", ()):
+                n *= d
+            total += n
+        for sub in _sub_jaxprs(eqn):
+            total += _vector_flops(sub)
+    return total
+
+
 def instantiate(
     spec: KernelSpec, case: KernelCase, tiles: Optional[Dict[str, int]] = None
 ) -> KernelInstance:
@@ -647,7 +699,9 @@ def instantiate(
         grid=tuple(int(g) for g in gm.grid),
         blocks=blocks,
         scratch=scratch,
-        flops_per_step=int(jaxpr_flops(kj)),
+        # matrix-unit FLOPs; a kernel with no product at all is counted by
+        # its vector-unit arithmetic instead (it has work, just no MXU work)
+        flops_per_step=int(jaxpr_flops(kj) or _vector_flops(kj)),
         dot_stats=_dot_stats(kj, []),
     )
 

@@ -978,6 +978,34 @@ class MoETpuConfig(TpuConfig):
 CONFIG_FILE = "tpu_config.json"  # reference: neuron_config.json (config.py:22)
 
 
+class SlotStateServingError(NotImplementedError):
+    """An option that cannot serve a model whose layers keep a constant-size
+    per-slot state (state-space layers) was set for one."""
+
+
+def validate_slot_state_serving(tc: "TpuConfig") -> None:
+    """Refuse, for a model whose builder declares per-slot state
+    (``cache_layers()`` with ``SLOT_STATE``), every option that would serve
+    it wrongly rather than not at all. One line each: none is a silent
+    wrong answer."""
+    speculation = (
+        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
+        or tc.enable_eagle_speculation or tc.serving_spec_ragged
+    )
+    refusals = (
+        (tc.is_prefix_caching, "is_prefix_caching: a recurrent state cannot be shared block by block"),
+        (tc.serving_ragged, "serving_ragged: the ragged mixed step runs attention layers only"),
+        (speculation, "speculation: rejected drafts would need a snapshot of the state to roll back to"),
+        (tc.kv_quantized, "kv_cache_dtype quantisation: the recurrent state is kept in float32"),
+        (tc.tp_degree * tc.ep_degree * tc.cp_degree * tc.attention_dp_degree
+         * tc.data_parallel_degree > 1,
+         "tp/ep/cp/dp degree > 1: the state update is not partitioned"),
+    )
+    for flag, why in refusals:
+        if flag:
+            raise SlotStateServingError(f"a model with state-space layers cannot be served with {why}")
+
+
 class InferenceConfig:
     """TpuConfig + HF model attributes (reference config.py:716-909).
 

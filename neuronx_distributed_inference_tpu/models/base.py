@@ -127,6 +127,39 @@ class ModelSpec:
     # fused decode MLP kernel (config fused_mlp_kernel_enabled):
     # None = auto on TPU (single shard), True = force, False = off
     use_fused_mlp: Optional[bool] = None
+    # Granite scalar multipliers (published config keys of the same names):
+    # the embedding is scaled, every residual update is scaled, the logits
+    # are divided. 1.0 = the plain decoder; nothing is emitted for it.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
+
+class LayerStack:
+    """A builder's own runner of the WHOLE layer stack, returned by
+    ``builder.layer_fn()`` in place of a per-layer function when the stack
+    mixes layers that keep different kinds of state (models/granite_hybrid.py:
+    paged K/V beside a constant-size recurrent state). :func:`run_decoder_layers`
+    hands it the embedded hidden state and the cache pytree and applies the
+    final norm to what it returns:
+
+        stack(params, hidden, cache, inputs, *, spec, phase, mlp_fn) -> (hidden, new_cache)
+    """
+
+    def __call__(self, params, hidden, cache, inputs, *, spec, phase, mlp_fn):
+        raise NotImplementedError
+
+
+def residual_add(residual: jax.Array, update: jax.Array, spec: "ModelSpec") -> jax.Array:
+    """``residual + residual_multiplier * update``. The product is taken in
+    float32 and rounded to the model dtype before the add, as the published
+    bf16 model takes it (a tensor times a Python scalar): the multiplier
+    itself is NOT rounded to the model dtype — 0.22 in bf16 is 0.21973, a
+    0.12% error on every residual update, all of one sign (read on the chip,
+    PR 28: 19% over the bf16 twin's noise)."""
+    if spec.residual_multiplier != 1.0:
+        update = (update.astype(jnp.float32) * spec.residual_multiplier).astype(update.dtype)
+    return residual + update
 
 
 @jax.tree_util.register_dataclass
@@ -425,7 +458,7 @@ def _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn, adapter_ids, fused_ok
         hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps,
         spec.norm_type,
     )
-    return residual + mlp_fn(mp, hidden, spec)
+    return residual_add(residual, mlp_fn(mp, hidden, spec), spec)
 
 
 def decoder_layer(
@@ -732,7 +765,7 @@ def decoder_layer(
 
         attn_out = tensor_taps.tap("attn_out", attn_out, layer_idx)
     hidden = o_project(layer_params["self_attn"], attn_out, aspec, adapter_ids=adapter_ids)
-    hidden = residual + hidden
+    hidden = residual_add(residual, hidden, spec)
 
     hidden = _decoder_layer_mlp(
         layer_params, hidden, spec, mlp_fn, adapter_ids, fused_block_ok
@@ -820,6 +853,8 @@ def lm_head(params: dict, hidden: jax.Array, spec: ModelSpec) -> jax.Array:
     logits = quant_linear(params["lm_head"], hidden)
     if spec.cast_logits_fp32:
         logits = logits.astype(jnp.float32)
+    if spec.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(spec.logits_scaling, logits.dtype)
     return mask_padded_logits(logits, spec.vocab_size)
 
 
@@ -828,6 +863,25 @@ def gather_last_token(hidden: jax.Array, attention_mask: jax.Array) -> jax.Array
     (reference last-token gather, model_base.py:1038-1060)."""
     idx = jnp.maximum(jnp.sum(attention_mask.astype(jnp.int32), axis=1) - 1, 0)
     return jnp.take_along_axis(hidden, idx[:, None, None], axis=1)
+
+
+def paged_block_inputs(inputs: StepInputs, block_size: int):
+    """(slot_mapping (B,S), block_table (B,MB), kv_limit (B,)) of a step on
+    the paged cache."""
+    slot_mapping = inputs.slot_mapping
+    if slot_mapping is None:
+        # in-graph slot-mapping generation from the block table (reference
+        # generate_tokengen_slot_mapping) — the host sends tables only
+        from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+            slot_mapping_from_block_table,
+        )
+
+        slot_mapping = slot_mapping_from_block_table(
+            inputs.block_table, inputs.position_ids, block_size
+        )
+    # valid cache length per row, for the paged flash kernel's bounds
+    kv_limit = jnp.sum(inputs.attention_mask.astype(jnp.int32), axis=-1)
+    return slot_mapping, inputs.block_table, kv_limit
 
 
 def run_decoder_layers(
@@ -858,6 +912,15 @@ def run_decoder_layers(
     model_base.py:1444-1447) — returns a third value, the (B, S, C*H) concat
     of the named layers' outputs, accumulated in-scan (uniform stacks only).
     """
+    if isinstance(layer_fn, LayerStack):
+        from neuronx_distributed_inference_tpu.modules import tensor_taps
+
+        hidden, new_cache = layer_fn(
+            params, hidden, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn
+        )
+        hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
+        return tensor_taps.tap("final_hidden", hidden), new_cache
+
     inv_freq = params["rope"]["inv_freq"]
     rope_pos = (
         inputs.rope_position_ids
@@ -918,22 +981,7 @@ def run_decoder_layers(
         )
     positions = inputs.position_ids
 
-    block_inputs = None
-    if is_block:
-        slot_mapping = inputs.slot_mapping
-        if slot_mapping is None:
-            # in-graph slot-mapping generation from the block table (reference
-            # generate_tokengen_slot_mapping) — the host sends tables only
-            from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-                slot_mapping_from_block_table,
-            )
-
-            slot_mapping = slot_mapping_from_block_table(
-                inputs.block_table, positions, cache.block_size
-            )
-        # valid cache length per row, for the paged flash kernel's bounds
-        kv_limit = jnp.sum(inputs.attention_mask.astype(jnp.int32), axis=-1)
-        block_inputs = (slot_mapping, inputs.block_table, kv_limit)
+    block_inputs = paged_block_inputs(inputs, cache.block_size) if is_block else None
 
     # strided-CP causal load balancing (reference attention_base.py:698-711):
     # zigzag-permute the sequence so each cp rank's contiguous stripe owns an
@@ -1244,6 +1292,8 @@ def model_logits(
         hidden = inputs.inputs_embeds
     else:
         hidden = embed(params, inputs.input_ids)
+        if spec.embedding_multiplier != 1.0:
+            hidden = (hidden.astype(jnp.float32) * spec.embedding_multiplier).astype(hidden.dtype)
     hidden = tensor_taps.tap("embed", hidden)
     if capture_layers is not None:
         hidden, new_cache, full_hidden = run_decoder_layers(

@@ -464,6 +464,23 @@ class DecoderModelBuilder:
         decoder_layer (models/base.py). MLA-style attention overrides this."""
         return None
 
+    def cache_layers(self):
+        """What each layer keeps between steps, in model order
+        (modules/block_kvcache: ``PAGED_KV`` — a K/V stream of
+        ``(kv_heads, head_dim)`` per token, paged over the block pool — or
+        ``SLOT_STATE`` — a constant-size state per serving slot, built by
+        :meth:`init_slot_state`). The application sizes the block pool over
+        the paging layers only."""
+        from neuronx_distributed_inference_tpu.modules.block_kvcache import PAGED_KV
+
+        return (PAGED_KV,) * self.config.num_hidden_layers
+
+    def init_slot_state(self, num_slots: int):
+        """(state pytree, its PartitionSpec tree) of the ``SLOT_STATE``
+        layers for ``num_slots`` serving slots; None for a model whose layers
+        all page."""
+        return None
+
     def cache_pspecs(self):
         """Declared PartitionSpec tree for this model's KV cache — the
         machine-readable sharding contract the static analyzer audits

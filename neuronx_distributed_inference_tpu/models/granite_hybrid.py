@@ -1,0 +1,474 @@
+"""Granite-4.0-H (``granitemoehybrid``) model plugin: Mamba-2 state-space
+layers beside GQA attention layers in one stack.
+
+Published implementation: ``transformers``
+``models/granitemoehybrid/modeling_granitemoehybrid.py``. Per layer ``l`` of
+``layer_types`` (granite-4.0-h-micro: 40 layers, attention at 5, 15, 25, 35 —
+a period of ten = 5 mamba, 1 attention, 4 mamba):
+
+    h = h + residual_multiplier * Mixer_l(rmsnorm(h))
+    h = h + residual_multiplier * MLP(rmsnorm(h))        # the "shared" SwiGLU MLP
+    logits = rmsnorm(h) @ embed.T / logits_scaling,  h0 = embed[ids] * embedding_multiplier
+
+The attention mixer is the shared ``decoder_layer`` with no positional
+embedding (``position_embedding_type: "nope"``) and softmax scale
+``attention_multiplier``; the Mamba-2 mixer is modules/ssm.py.
+
+What differs from every other plugin is WHAT A LAYER KEEPS: an attention
+layer pages K/V over the block pool, a state-space layer keeps a constant
+per-slot state (``cache_layers`` / ``init_slot_state``; the application
+builds ``HybridBlockCache``). The stack is therefore run by
+:class:`HybridStack` (a ``models/base.LayerStack``): one ``lax.scan`` over
+the periods of the layer pattern, inside it one scan per run of like
+layers, each layer's weights taken from its kind's stacked tree by a
+computed index — three compiled layer bodies for 40 layers.
+
+Served on the paged, chunked, continuously batched path only
+(``ServingSession``); what cannot serve a constant-size state is refused at
+config time (config.validate_slot_state_serving).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from neuronx_distributed_inference_tpu.config import (
+    InferenceConfig,
+    to_dtype,
+    validate_slot_state_serving,
+)
+from neuronx_distributed_inference_tpu.models.base import (
+    PHASE_TOKEN_GENERATION,
+    LayerStack,
+    ModelSpec,
+    _decoder_layer_mlp,
+    build_mask,
+    decoder_layer,
+    paged_block_inputs,
+    residual_add,
+)
+from neuronx_distributed_inference_tpu.models.builder import DecoderModelBuilder
+from neuronx_distributed_inference_tpu.models.registry import register_model
+from neuronx_distributed_inference_tpu.modules import ssm
+from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+    PAGED_KV,
+    SLOT_STATE,
+    HybridBlockCache,
+)
+from neuronx_distributed_inference_tpu.modules.norm import rms_norm
+from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
+from neuronx_distributed_inference_tpu.ops.quant import linear
+
+MAMBA, ATTENTION = "mamba", "attention"
+
+
+class GraniteHybridInferenceConfig(InferenceConfig):
+    _REQUIRED_ATTRS = (
+        "hidden_size", "num_attention_heads", "num_hidden_layers", "num_key_value_heads",
+        "vocab_size", "shared_intermediate_size", "layer_types", "attention_multiplier",
+        "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv",
+    )
+
+    def validate_config(self):
+        super().validate_config()
+        if getattr(self, "num_local_experts", 0):
+            raise NotImplementedError(
+                "granitemoehybrid with num_local_experts > 0: the routed-expert layer "
+                "is not implemented (only the dense shared MLP is)"
+            )
+        pe = getattr(self, "position_embedding_type", "nope")
+        if pe != "nope":
+            raise NotImplementedError(
+                f"granitemoehybrid with position_embedding_type={pe!r}: only 'nope' "
+                "(no rotation of q and k) is implemented"
+            )
+        kinds = tuple(self.layer_types)
+        if len(kinds) != self.num_hidden_layers or set(kinds) - {MAMBA, ATTENTION}:
+            raise ValueError(
+                "layer_types must name 'mamba' or 'attention' for each of "
+                f"num_hidden_layers={self.num_hidden_layers} layers, got {kinds}"
+            )
+        if MAMBA in kinds:
+            validate_slot_state_serving(self.tpu_config)
+
+
+def layer_runs(kinds: Tuple[str, ...]) -> Tuple[int, List[Tuple[str, int, int]]]:
+    """(number of periods, runs of one period): the shortest prefix that
+    repeats to give ``kinds``, cut into runs of like layers — (kind, rank of
+    the run's first layer among the period's layers of that kind, length)."""
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1) if n % p == 0 and kinds == kinds[:p] * (n // p))
+    runs, seen = [], {MAMBA: 0, ATTENTION: 0}
+    for kind in kinds[:period]:
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1], runs[-1][2] + 1)
+        else:
+            runs.append((kind, seen[kind], 1))
+        seen[kind] += 1
+    return n // period, runs
+
+
+def _take(tree, i):
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
+                spec: ModelSpec, sspec: ssm.SSMSpec, mlp_fn):
+    """One state-space layer: hidden (R, Q, H); ``state`` the stacked
+    per-slot state of ALL such layers, advanced at index ``li`` for the
+    ``valid`` (R, Q) positions; ``reset`` (R,) rows start from zero."""
+    R, Q, _ = hidden.shape
+    Hn, Pd, N, G = sspec.num_heads, sspec.head_dim, sspec.state_size, sspec.n_groups
+    d_inner, conv_dim = sspec.d_inner, sspec.conv_dim
+    f32 = jnp.float32
+    m = lp["mixer"]
+    x = rms_norm(hidden, lp["input_layernorm"]["weight"], spec.rms_eps)
+    # the published in_proj, 2048 -> [z | xBC | dt] = 4096 + 4352 + 64, held as
+    # two matrices: 8512 columns are not a whole number of 128-lane tiles, and
+    # the compiler then copies the STACKED weight (1.26 GB) into another layout
+    # on every step; 8448 and 64 it takes as they are
+    proj = linear(m["in_proj"], x)
+    z, xBC = proj[..., :d_inner], proj[..., d_inner:]
+    dt = jax.nn.softplus(linear(m["dt_proj"], x).astype(f32) + m["dt_bias"].astype(f32))
+    A = -jnp.exp(m["A_log"].astype(f32))
+
+    tail = jax.lax.dynamic_index_in_dim(state.conv, li, 0, keepdims=False)
+    tail = jnp.where(reset[None, :, None], jnp.zeros((), tail.dtype), tail)
+    n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+    xBC, tail = ssm.causal_conv(xBC, tail, m["conv1d"]["weight"], m["conv1d"]["bias"], n_valid)
+    conv = jax.lax.dynamic_update_index_in_dim(state.conv, tail, li, 0)
+    xBC = xBC.astype(hidden.dtype)
+    xs = xBC[..., :d_inner].reshape(R, Q, Hn, Pd)
+    Bm = xBC[..., d_inner : d_inner + G * N].reshape(R, Q, G, N)
+    Cm = xBC[..., d_inner + G * N :].reshape(R, Q, G, N)
+
+    if Q == 1 and G == 1:
+        from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
+
+        y, new_ssm = ssm_state_update(
+            state.ssm, li, xs[:, 0], Bm[:, 0, 0], Cm[:, 0, 0], dt[:, 0], A,
+            valid[:, 0], reset, interpret=kernel_interpret(),
+        )
+        y = y[:, None]
+    else:
+        s = jax.lax.dynamic_index_in_dim(state.ssm, li, 0, keepdims=False)
+        s = jnp.where(reset[:, None, None, None], 0.0, s)
+        y, s = ssm.mamba2_chunk(xs, Bm, Cm, dt, A, s, valid, chunk_size=sspec.chunk_size)
+        new_ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, s, li, 0)
+    y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
+    gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps)
+    hidden = residual_add(hidden, linear(m["out_proj"], gated), spec)
+    hidden = _decoder_layer_mlp(lp, hidden, spec, mlp_fn, None, False)
+    return hidden, ssm.RecurrentState(conv=conv, ssm=new_ssm)
+
+
+class HybridStack(LayerStack):
+    """Runs a stack of ``layer_types`` over ``HybridBlockCache``."""
+
+    def __init__(self, layer_types: Tuple[str, ...], sspec: ssm.SSMSpec):
+        self.layer_types = tuple(layer_types)
+        self.sspec = sspec
+        self.periods, self.runs = layer_runs(self.layer_types)
+        per_period = len(self.layer_types) // self.periods
+        self.per_period = {
+            kind: sum(1 for k in self.layer_types[:per_period] if k == kind)
+            for kind in (MAMBA, ATTENTION)
+        }
+
+    def __call__(self, params, hidden, cache, inputs, *, spec, phase, mlp_fn):
+        if phase != PHASE_TOKEN_GENERATION or inputs.block_table is None:
+            raise NotImplementedError(
+                "a stack with state-space layers runs on the paged serving path only "
+                "(chunk and decode programs of the token-generation runner)"
+            )
+        if not isinstance(cache, HybridBlockCache):
+            raise TypeError(f"expected a HybridBlockCache, got {type(cache).__name__}")
+        positions = inputs.position_ids
+        # a position advances the state iff its row is live and it writes K/V
+        # somewhere real: padded chunk tails and rows that sit a pass out
+        # carry slot_mapping / seq_id -1
+        valid = jnp.broadcast_to((inputs.seq_ids >= 0)[:, None], positions.shape)
+        if inputs.slot_mapping is not None:
+            valid = valid & (inputs.slot_mapping >= 0)
+        # state lifetime without a host call: a row whose first position in
+        # this pass is 0 starts from zero state (a new request in a reused
+        # slot, a re-prefill after preemption, a probe's fresh cache)
+        reset = valid[:, 0] & (positions[:, 0] == 0)
+        block_inputs = paged_block_inputs(inputs, cache.block_size)
+        mask = build_mask(inputs, spec, phase)
+        layers = params["layers"]
+
+        def attention(carry, li):
+            h, k, v, st = carry
+            h, k, v = decoder_layer(
+                _take(layers[ATTENTION], li), h, None, None, k, v, li, mask,
+                inputs.seq_ids, positions, spec, phase, mlp_fn, block_inputs=block_inputs,
+            )
+            return (h, k, v, st), None
+
+        def mamba(carry, li):
+            h, k, v, st = carry
+            h, st = mamba_layer(
+                _take(layers[MAMBA], li), h, st, li, valid, reset, spec, self.sspec, mlp_fn
+            )
+            return (h, k, v, st), None
+
+        bodies = {MAMBA: mamba, ATTENTION: attention}
+
+        def period(carry, p):
+            for kind, first, count in self.runs:
+                base = p * self.per_period[kind] + first
+                if count == 1:
+                    carry, _ = bodies[kind](carry, base)
+                else:
+                    carry, _ = jax.lax.scan(
+                        bodies[kind], carry, base + jnp.arange(count, dtype=jnp.int32)
+                    )
+            return carry, None
+
+        carry = (hidden, cache.k, cache.v, cache.state)
+        if self.periods == 1:
+            carry, _ = period(carry, jnp.int32(0))
+        else:
+            carry, _ = jax.lax.scan(period, carry, jnp.arange(self.periods, dtype=jnp.int32))
+        hidden, k, v, state = carry
+        return hidden, HybridBlockCache(k=k, v=v, state=state)
+
+
+@register_model("granitemoehybrid")
+class GraniteHybridModelBuilder(DecoderModelBuilder):
+    """Granite-4.0-H: Mamba-2 + GQA attention, dense shared MLP."""
+
+    config_cls = GraniteHybridInferenceConfig
+
+    def __init__(self, config):
+        super().__init__(config)
+        tc = config.tpu_config
+        self.layer_types = tuple(config.layer_types)
+        self.n_mamba = self.layer_types.count(MAMBA)
+        self.n_attention = self.layer_types.count(ATTENTION)
+        if self.n_mamba and not (tc.is_block_kv_layout and tc.is_chunked_prefill):
+            raise NotImplementedError(
+                "granitemoehybrid is served on the paged, chunked path only: set "
+                "is_block_kv_layout, is_chunked_prefill and is_continuous_batching"
+            )
+
+    def ssm_spec(self) -> ssm.SSMSpec:
+        cfg = self.config
+        return ssm.SSMSpec(
+            num_heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head,
+            state_size=cfg.mamba_d_state, n_groups=getattr(cfg, "mamba_n_groups", 1),
+            conv_kernel=cfg.mamba_d_conv, chunk_size=getattr(cfg, "mamba_chunk_size", 256),
+            rms_eps=getattr(cfg, "rms_norm_eps", 1e-5),
+        )
+
+    def attn_spec(self):
+        return dataclasses.replace(
+            super().attn_spec(), scale=float(self.config.attention_multiplier), use_rope=False
+        )
+
+    def model_spec(self) -> ModelSpec:
+        cfg = self.config
+        return dataclasses.replace(
+            super().model_spec(),
+            intermediate_size=cfg.shared_intermediate_size,
+            embedding_multiplier=float(getattr(cfg, "embedding_multiplier", 1.0)),
+            residual_multiplier=float(getattr(cfg, "residual_multiplier", 1.0)),
+            logits_scaling=float(getattr(cfg, "logits_scaling", 1.0)),
+        )
+
+    def layer_fn(self):
+        return HybridStack(self.layer_types, self.ssm_spec())
+
+    # ---- what each layer keeps -------------------------------------------
+
+    def cache_layers(self):
+        return tuple(SLOT_STATE if k == MAMBA else PAGED_KV for k in self.layer_types)
+
+    def init_slot_state(self, num_slots: int):
+        if not self.n_mamba:
+            return None
+        state = ssm.init_recurrent_state(
+            self.ssm_spec(), self.n_mamba, num_slots, to_dtype(self.config.tpu_config.dtype)
+        )
+        return state, ssm.recurrent_state_pspecs()
+
+    # ---- params ------------------------------------------------------------
+
+    def _common_shapes(self, L: int) -> Dict:
+        H, I = self.config.hidden_size, self.config.shared_intermediate_size
+        return {
+            "input_layernorm": {"weight": (L, H)},
+            "post_attention_layernorm": {"weight": (L, H)},
+            "mlp": {
+                "gate_proj": {"weight": (L, H, I)},
+                "up_proj": {"weight": (L, H, I)},
+                "down_proj": {"weight": (L, I, H)},
+            },
+        }
+
+    def param_shapes(self) -> Dict:
+        cfg = self.config
+        H, D = cfg.hidden_size, self.head_dim
+        Hq, Hkv = self.gqa.q_heads, self.gqa.kv_heads
+        s = self.ssm_spec()
+        Lm, La = self.n_mamba, self.n_attention
+        if cfg.tpu_config.fused_qkv:
+            attn = {"qkv_proj": {"weight": (La, H, (Hq + 2 * Hkv) * D)}}
+        else:
+            attn = {
+                "q_proj": {"weight": (La, H, Hq * D)},
+                "k_proj": {"weight": (La, H, Hkv * D)},
+                "v_proj": {"weight": (La, H, Hkv * D)},
+            }
+        attn["o_proj"] = {"weight": (La, Hq * D, H)}
+        shapes = {
+            "embed_tokens": {"weight": (self.padded_vocab, H)},
+            "layers": {
+                MAMBA: {
+                    **self._common_shapes(Lm),
+                    "mixer": {
+                        "in_proj": {"weight": (Lm, H, s.d_inner + s.conv_dim)},
+                        "dt_proj": {"weight": (Lm, H, s.num_heads)},
+                        "conv1d": {"weight": (Lm, s.conv_kernel, s.conv_dim),
+                                   "bias": (Lm, s.conv_dim)},
+                        "A_log": (Lm, s.num_heads),
+                        "D": (Lm, s.num_heads),
+                        "dt_bias": (Lm, s.num_heads),
+                        "norm": {"weight": (Lm, s.d_inner)},
+                        "out_proj": {"weight": (Lm, s.d_inner, H)},
+                    },
+                },
+                ATTENTION: {**self._common_shapes(La), "self_attn": attn},
+            },
+            "norm": {"weight": (H,)},
+        }
+        if not getattr(cfg, "tie_word_embeddings", False):
+            shapes["lm_head"] = {"weight": (H, self.padded_vocab)}
+        return shapes
+
+    def param_pspecs(self) -> Dict:
+        # tp_degree 1 (config.validate_slot_state_serving): everything replicated
+        specs = jax.tree.map(
+            lambda _: P(), self.param_shapes(), is_leaf=lambda x: isinstance(x, tuple)
+        )
+        specs["lm_head"] = {"weight": P()}
+        return specs
+
+    def random_params(self, key=None, dtype=None, on_host: bool = False) -> Dict:
+        """Random init for tests: matrices N(0, 0.02), norm weights 1, and the
+        PUBLISHED initialisation of the recurrence (``A_log = log(1..heads)``,
+        ``dt_bias`` the inverse softplus of dt log-uniform in 1e-3..1e-1,
+        ``D = 1``): slow-decay heads, under which a lost carry shows."""
+        dtype = dtype or to_dtype(self.config.tpu_config.dtype)
+        params = self.random_tree(self.param_shapes(), key, dtype, on_host, std=0.02)
+        ones = lambda a: jnp.ones_like(jnp.asarray(a))
+        for kind in (MAMBA, ATTENTION):
+            for name in ("input_layernorm", "post_attention_layernorm"):
+                params["layers"][kind][name]["weight"] = ones(params["layers"][kind][name]["weight"])
+        params["norm"]["weight"] = ones(params["norm"]["weight"])
+        mixer = params["layers"][MAMBA]["mixer"]
+        Lm, Hn = self.n_mamba, self.config.mamba_n_heads
+        rng = np.random.default_rng(self.config.tpu_config.seed)
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (Lm, Hn)))
+        mixer["norm"]["weight"] = ones(mixer["norm"]["weight"])
+        mixer["A_log"] = jnp.asarray(np.tile(np.log(np.arange(1, Hn + 1.0)), (Lm, 1)), dtype)
+        mixer["dt_bias"] = jnp.asarray(dt + np.log(-np.expm1(-dt)), dtype)
+        mixer["D"] = jnp.ones((Lm, Hn), dtype)
+        if getattr(self.config, "tie_word_embeddings", False):
+            params["lm_head"] = {"weight": jnp.asarray(params["embed_tokens"]["weight"]).T}
+        return params
+
+    HF_MAMBA = "mamba."
+    HF_MLP = "shared_mlp."
+
+    def convert_hf_state_dict(self, sd: Dict[str, np.ndarray], dtype=None) -> Dict:
+        """HF ``GraniteMoeHybridForCausalLM`` checkpoint -> the stacked tree."""
+        cfg = self.config
+        dtype = dtype or to_dtype(cfg.tpu_config.dtype)
+        I = cfg.shared_intermediate_size
+
+        def get(name):
+            if name not in sd:
+                raise KeyError(f"missing HF weight {name}; have e.g. {list(sd)[:5]}")
+            return np.asarray(sd[name])
+
+        idx = {kind: [i for i, k in enumerate(self.layer_types) if k == kind]
+               for kind in (MAMBA, ATTENTION)}
+
+        def stack(kind, fn):
+            return jnp.asarray(
+                np.stack([fn(self.HF_LAYER_PREFIX.format(i=i)) for i in idx[kind]]), dtype
+            )
+
+        def common(kind):
+            return {
+                "input_layernorm": {"weight": stack(kind, lambda p: get(p + "input_layernorm.weight"))},
+                "post_attention_layernorm": {
+                    "weight": stack(kind, lambda p: get(p + "post_attention_layernorm.weight"))},
+                "mlp": {
+                    "gate_proj": {"weight": stack(
+                        kind, lambda p: get(p + self.HF_MLP + "input_linear.weight")[:I].T)},
+                    "up_proj": {"weight": stack(
+                        kind, lambda p: get(p + self.HF_MLP + "input_linear.weight")[I:].T)},
+                    "down_proj": {"weight": stack(
+                        kind, lambda p: get(p + self.HF_MLP + "output_linear.weight").T)},
+                },
+            }
+
+        mm = lambda name: (lambda p: get(p + self.HF_MAMBA + name))
+        embed = get(self.HF_EMBED)
+        vpad = self.padded_vocab - embed.shape[0]
+        if vpad:
+            embed = np.pad(embed, ((0, vpad), (0, 0)))
+        params = {
+            "embed_tokens": {"weight": jnp.asarray(embed, dtype)},
+            "layers": {
+                MAMBA: {
+                    **common(MAMBA),
+                    "mixer": {
+                        # HF in_proj rows are [z | xBC | dt]
+                        "in_proj": {"weight": stack(
+                            MAMBA, lambda p: mm("in_proj.weight")(p)[:-cfg.mamba_n_heads].T)},
+                        "dt_proj": {"weight": stack(
+                            MAMBA, lambda p: mm("in_proj.weight")(p)[-cfg.mamba_n_heads:].T)},
+                        # HF depthwise conv weight (conv_dim, 1, K) -> (K, conv_dim)
+                        "conv1d": {
+                            "weight": stack(MAMBA, lambda p: mm("conv1d.weight")(p)[:, 0, :].T),
+                            "bias": stack(MAMBA, mm("conv1d.bias")),
+                        },
+                        "A_log": stack(MAMBA, mm("A_log")),
+                        "D": stack(MAMBA, mm("D")),
+                        "dt_bias": stack(MAMBA, mm("dt_bias")),
+                        "norm": {"weight": stack(MAMBA, mm("norm.weight"))},
+                        "out_proj": {"weight": stack(MAMBA, lambda p: mm("out_proj.weight")(p).T)},
+                    },
+                },
+                ATTENTION: {
+                    **common(ATTENTION),
+                    "self_attn": {
+                        n: {"weight": stack(ATTENTION, lambda p, n=n: get(p + f"self_attn.{n}.weight").T)}
+                        for n in ("q_proj", "k_proj", "v_proj", "o_proj")
+                    },
+                },
+            },
+            "norm": {"weight": jnp.asarray(get(self.HF_NORM), dtype)},
+        }
+        if cfg.tpu_config.fused_qkv:
+            sa = params["layers"][ATTENTION]["self_attn"]
+            sa["qkv_proj"] = {"weight": jnp.concatenate(
+                [sa.pop(n)["weight"] for n in ("q_proj", "k_proj", "v_proj")], axis=-1)}
+        if getattr(cfg, "tie_word_embeddings", False):
+            params["lm_head"] = {"weight": params["embed_tokens"]["weight"].T}
+        else:
+            lm = get(self.HF_LM_HEAD).T
+            if vpad:
+                lm = np.pad(lm, ((0, 0), (0, vpad)))
+            params["lm_head"] = {"weight": jnp.asarray(lm, dtype)}
+        return params

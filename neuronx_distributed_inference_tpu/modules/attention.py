@@ -68,6 +68,9 @@ class AttnSpec:
     model_parallel: int = 1
     # clamp qkv projection outputs to [-clip, clip] (DBRX clip_qkv)
     qkv_clip: Optional[float] = None
+    # False: no positional embedding at all (Granite-4.0-H
+    # position_embedding_type "nope"): q and k are not rotated
+    use_rope: bool = True
 
     @property
     def softmax_scale(self) -> float:
@@ -132,8 +135,9 @@ def qkv_project(
     if spec.qk_norm:  # per-head rmsnorm before rope (reference qwen3, qk norm)
         q = rms_norm(q, params["q_norm"]["weight"], spec.rms_norm_eps)
         k = rms_norm(k, params["k_norm"]["weight"], spec.rms_norm_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if spec.use_rope:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     return q, k, v
 
 

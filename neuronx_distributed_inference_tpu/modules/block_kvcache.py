@@ -94,6 +94,29 @@ class BlockKVCache:
         return self.k.shape[3]
 
 
+#: what a layer keeps between steps, as a model's builder declares it
+#: (``builder.cache_layers()``, one entry per layer): a paged K/V stream of
+#: ``(H_kv, D)`` per token, or a constant-size state per serving slot
+PAGED_KV = "paged_kv"
+SLOT_STATE = "slot_state"
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class HybridBlockCache(BlockKVCache):
+    """The cache of a model whose layers keep two kinds of state, as ONE
+    donated pytree: ``k``/``v`` are the block pool over the layers that page
+    (indexed by a layer's rank among the paging layers), ``state`` is the
+    builder's per-slot state over the layers that do not
+    (modules/ssm.RecurrentState). The block allocator sees the pool only: a
+    slot's state is as large at token 1 as at token 10^5."""
+
+    state: object = None
+
+    #: the fields that are NOT streams of blocks (runtime/faults.fill_kv_rows)
+    SLOT_FIELDS = ("state",)
+
+
 def init_block_cache(
     num_layers: int,
     num_blocks: int,

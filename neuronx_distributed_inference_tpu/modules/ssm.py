@@ -1,0 +1,247 @@
+"""Mamba-2 state-space mixer and its recurrent-state container.
+
+A state-space layer keeps, per serving slot, a CONSTANT-size state instead of
+a K/V stream that grows with the context: the last ``d_conv - 1`` inputs of
+its depthwise causal convolution (the "conv tail") and the matrix state ``S``
+of the selective recurrence
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (outer) B_t,      y_t = S_t . C_t + D x_t
+
+(Mamba-2, "Transformers are SSMs", Dao & Gu 2024; published Granite-4.0-H
+implementation: ``transformers`` ``modeling_granitemoehybrid.py``).
+
+Two forms of the same recurrence:
+
+* :func:`mamba2_chunk` — a (rows, q) chunk given the incoming state, in the
+  chunked "state-space dual" form: inside a sub-chunk of at most
+  ``chunk_size`` positions the output is a masked quadratic form (matrix
+  products), between sub-chunks a state is carried.
+* :func:`mamba2_step` — q = 1, the plain update (the decode program runs
+  ``ops/ssm_state_update.py`` instead, which is held to this function).
+
+Both take a per-position validity mask. The contract every caller relies on
+(serving: a padded chunk tail, a slot that is idle or decoding while others
+prefill, a warm-up pass): **an invalid position leaves the conv tail and
+``S`` bit-identical** — the recurrence sees ``dt = 0`` and ``x = 0`` there
+(decay ``exp(0) = 1``, increment 0), the conv tail shifts by the number of
+VALID positions only, and a row with no valid position is passed through by
+a select. Valid positions are a prefix of the row (what chunked prefill
+builds).
+
+``S`` is float32 whatever the model dtype: the recurrence compounds over a
+request's whole life. The conv tail holds copies of inputs in the model
+dtype.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+@dataclass(frozen=True)
+class SSMSpec:
+    """Static sizes of a Mamba-2 mixer (the published config's ``mamba_*``)."""
+
+    num_heads: int
+    head_dim: int
+    state_size: int
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 256
+    rms_eps: float = 1e-5
+
+    @property
+    def d_inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.state_size
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class RecurrentState:
+    """The per-slot state of every state-space layer of a model.
+
+    conv: (L, d_conv - 1, slots, conv_dim), model dtype — slots on the
+          second-minor axis so that the array tiles without padding.
+    ssm:  (L, slots, heads, head_dim, state_size), float32.
+
+    Row ``r`` of a batch owns slot ``r`` (the serving session builds its
+    batches so); ``seq_ids`` only says whether the row is live."""
+
+    conv: jax.Array
+    ssm: jax.Array
+
+    @property
+    def num_slots(self) -> int:
+        return self.ssm.shape[1]
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.conv.size * self.conv.dtype.itemsize + self.ssm.size * 4)
+
+
+def init_recurrent_state(spec: SSMSpec, num_layers: int, num_slots: int, dtype) -> RecurrentState:
+    return RecurrentState(
+        conv=jnp.zeros((num_layers, spec.conv_kernel - 1, num_slots, spec.conv_dim), dtype),
+        ssm=jnp.zeros(
+            (num_layers, num_slots, spec.num_heads, spec.head_dim, spec.state_size), jnp.float32
+        ),
+    )
+
+
+def recurrent_state_pspecs() -> RecurrentState:
+    from jax.sharding import PartitionSpec as P
+
+    return RecurrentState(conv=P(), ssm=P())
+
+
+def fill_state_slots(state: RecurrentState, slots, value: float) -> RecurrentState:
+    """Overwrite the state of whole slots in every layer (scrub: 0.0)."""
+    idx = jnp.asarray(slots, jnp.int32)
+    return RecurrentState(
+        conv=state.conv.at[:, :, idx].set(value), ssm=state.ssm.at[:, idx].set(value)
+    )
+
+
+# ---------------------------------------------------------------------------
+# depthwise causal convolution with a carried tail
+# ---------------------------------------------------------------------------
+
+
+def causal_conv(
+    xBC: jax.Array,  # (R, Q, C) this pass's inputs
+    tail: jax.Array,  # (K-1, R, C) the last K-1 VALID inputs before this pass
+    weight: jax.Array,  # (K, C)
+    bias: jax.Array,  # (C,)
+    n_valid: jax.Array,  # (R,) int32: valid positions are [0, n_valid)
+) -> Tuple[jax.Array, jax.Array]:
+    """``silu(conv(x) + b)`` over [tail ; x] and the tail after the row's
+    valid positions. Returns (out (R, Q, C) float32, new tail (K-1, R, C))."""
+    K = weight.shape[0]
+    Q = xBC.shape[1]
+    window = jnp.concatenate([jnp.swapaxes(tail, 0, 1).astype(xBC.dtype), xBC], axis=1)
+    w = weight.astype(jnp.float32)
+    acc = bias.astype(jnp.float32)[None, None, :]
+    for k in range(K):
+        acc = acc + w[k][None, None, :] * window[:, k : k + Q].astype(jnp.float32)
+    # the tail after n valid inputs is window[n : n + K - 1]: pure copies,
+    # so a row with n = 0 keeps its tail bit for bit
+    idx = n_valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]  # (R, K-1)
+    new_tail = jnp.take_along_axis(window, idx[:, :, None], axis=1)
+    return jax.nn.silu(acc), jnp.swapaxes(new_tail, 0, 1).astype(tail.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the recurrence
+# ---------------------------------------------------------------------------
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _grouped(a: jax.Array, axis: int, groups: int) -> jax.Array:
+    """Split the head axis into (groups, heads per group): B and C are shared
+    by the heads of a group, and are never repeated per head."""
+    return a.reshape(a.shape[:axis] + (groups, a.shape[axis] // groups) + a.shape[axis + 1 :])
+
+
+def mamba2_step(
+    x: jax.Array,  # (R, H, P)
+    B: jax.Array,  # (R, G, N)
+    C: jax.Array,  # (R, G, N)
+    dt: jax.Array,  # (R, H) after softplus
+    A: jax.Array,  # (H,) negative
+    state: jax.Array,  # (R, H, P, N) float32
+    valid: jax.Array,  # (R,) bool
+) -> Tuple[jax.Array, jax.Array]:
+    """One token per row. Returns (y (R, H, P) float32 WITHOUT the D skip,
+    new state); invalid rows keep their state bit for bit."""
+    G = B.shape[1]
+    f32 = jnp.float32
+    dt = jnp.where(valid[:, None], dt.astype(f32), 0.0)
+    dA = jnp.exp(dt * A.astype(f32)[None, :])  # (R, H)
+    dtx = dt[:, :, None] * x.astype(f32)  # (R, H, P)
+    s5 = _grouped(state, 1, G)  # (R, G, Hg, P, N)
+    Bg, Cg = B.astype(f32)[:, :, None, None, :], C.astype(f32)[:, :, None, None, :]
+    new = s5 * _grouped(dA, 1, G)[..., None, None] + _grouped(dtx, 1, G)[..., None] * Bg
+    new = jnp.where(valid[:, None, None, None, None], new, s5)
+    y = jnp.sum(new * Cg, axis=-1)
+    return y.reshape(x.shape), new.reshape(state.shape)
+
+
+def _ssd_subchunk(x, B, C, dt, A, state):
+    """One sub-chunk in the quadratic form. x (R,Q,H,P), B,C (R,Q,G,N),
+    dt (R,Q,H) float32 with dt = 0 at invalid positions, state (R,H,P,N)."""
+    R, Q, H, P = x.shape
+    G = B.shape[2]
+    cum = jnp.cumsum(dt * A[None, None, :], axis=1)  # (R, Q, H), inclusive, <= 0
+    cum_h = jnp.transpose(cum, (0, 2, 1))  # (R, H, Q)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    seg = cum_h[:, :, :, None] - cum_h[:, :, None, :]  # (R, H, i, j) = sum a_{j+1..i}
+    decay = jnp.exp(jnp.where(causal[None, None], seg, -jnp.inf))  # 0 above the diagonal
+    scores = jnp.einsum("rign,rjgn->rgij", C, B, precision=_HI)  # (R, G, i, j)
+    M = scores[:, :, None] * _grouped(decay, 1, G)  # (R, G, Hg, i, j)
+    dtx = _grouped(dt[..., None] * x, 2, G)  # (R, Q, G, Hg, P)
+    s5 = _grouped(state, 1, G)  # (R, G, Hg, P, N)
+    y_diag = jnp.einsum("rgkij,rjgkp->rigkp", M, dtx, precision=_HI)
+    y_off = jnp.einsum("rign,rgkpn->rigkp", C, s5, precision=_HI)
+    y = y_diag + y_off * _grouped(jnp.exp(cum), 2, G)[..., None]
+    total = cum[:, -1, :]  # (R, H)
+    w = jnp.exp(total[:, None, :] - cum)  # (R, Q, H): decay from position j to the end
+    new = s5 * _grouped(jnp.exp(total), 1, G)[..., None, None] + jnp.einsum(
+        "rjgkp,rjgn->rgkpn", dtx * _grouped(w, 2, G)[..., None], B, precision=_HI
+    )
+    return y.reshape(R, Q, H, P), new.reshape(state.shape)
+
+
+def mamba2_chunk(
+    x: jax.Array,  # (R, Q, H, P)
+    B: jax.Array,  # (R, Q, G, N)
+    C: jax.Array,  # (R, Q, G, N)
+    dt: jax.Array,  # (R, Q, H) after softplus
+    A: jax.Array,  # (H,) negative
+    state: jax.Array,  # (R, H, P, N) float32, the state BEFORE this chunk
+    valid: jax.Array,  # (R, Q) bool, a prefix of each row
+    chunk_size: int = 256,
+) -> Tuple[jax.Array, jax.Array]:
+    """A chunk of Q positions per row from ``state``. Returns (y (R,Q,H,P)
+    float32 WITHOUT the D skip, state after each row's valid positions).
+    Matrix products run at ``Precision.HIGHEST``: their operands are float32
+    (the state, cumulative decays), which the default would round to bf16."""
+    f32 = jnp.float32
+    R, Q, H, P = x.shape
+    dt = jnp.where(valid[..., None], dt.astype(f32), 0.0)
+    x, B, C, A = x.astype(f32), B.astype(f32), C.astype(f32), A.astype(f32)
+    sub = min(int(chunk_size), Q)
+    n_sub = -(-Q // sub)
+    if n_sub == 1:
+        y, new = _ssd_subchunk(x, B, C, dt, A, state)
+    else:
+        pad = n_sub * sub - Q
+
+        def split(a):  # (R, Q, ...) -> (n_sub, R, sub, ...), zero padded (dt = 0: no-ops)
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            return jnp.swapaxes(a.reshape((R, n_sub, sub) + a.shape[2:]), 0, 1)
+
+        def body(s, t):
+            y_c, s = _ssd_subchunk(*t, A, s)
+            return s, y_c
+
+        new, ys = jax.lax.scan(body, state, (split(x), split(B), split(C), split(dt)))
+        y = jnp.swapaxes(ys, 0, 1).reshape(R, n_sub * sub, H, P)[:, :Q]
+    new = jnp.where(jnp.any(valid, axis=1)[:, None, None, None], new, state)
+    return y, new
+
+
+def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """``rmsnorm_w(y * silu(z))`` over the last axis (one group): computed in
+    float32, returned in ``z``'s dtype."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    return (g * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(z.dtype)

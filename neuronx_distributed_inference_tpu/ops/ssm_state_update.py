@@ -1,0 +1,136 @@
+"""Decode-step update of the Mamba-2 recurrent state, as a Pallas kernel.
+
+One token per row:
+
+    S <- exp(dt A) S + (dt x) (outer) B,        y = S . C
+
+on the STACKED state ``(L, slots, heads, head_dim, state_size)`` float32 of
+every state-space layer, at one layer. At 48 rows a decode step of
+Granite-4.0-H-micro reads and writes 36 x 96 MiB of state; a carried buffer
+that is copied once a layer is the whole step (PERF.md, PR 24). So the state
+is aliased in and out (``input_output_aliases``): the kernel visits the
+(row, head block) tiles of one layer, every other byte of the buffer stays
+where it is, and the decode executable holds no state-sized copy
+(tests/test_chip_compile.py pins that).
+
+Grid ``(rows, heads / heads_per_block)``. A tile is ``(hb, P, N)`` float32
+with the state size ``N`` on the lanes. The per-(head, p) scalars of the
+update — ``dt x`` and the decay ``exp(dt A)`` — must then be broadcast along
+lanes, so they arrive with ``P`` on the sublanes: one packed operand
+``coef (rows, heads/hb, P, 2 hb)``, columns ``[0, hb)`` = ``dt x`` of the
+block's heads, ``[hb, 2 hb)`` = their decays. ``B`` and ``C`` are lane
+vectors. ``y`` leaves as ``(rows, heads/hb, P, hb)`` and is transposed back
+outside. The two small operands cost ~3% of the state's bytes each at
+``hb = 16``.
+
+Validity: ``dt = 0`` for an invalid row (decay 1, increment 0), so its state
+is rewritten bit for bit. ``reset`` rows start from zero (a select on the
+loaded tile, not a product: a non-finite state must not survive it).
+
+One group of B/C only (``mamba_n_groups`` 1); the caller keeps
+``modules/ssm.mamba2_step`` for anything else.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: heads per tile: 16 x (64, 128) float32 = 512 KiB in, as much out
+DEFAULT_HEADS_PER_BLOCK = 16
+
+
+def _kernel(li_ref, reset_ref, coef_ref, b_ref, c_ref, s_ref, y_ref, out_ref, *, hb):
+    r = pl.program_id(0)
+    P, N = s_ref.shape[-2], s_ref.shape[-1]
+    coef = coef_ref[...]  # (P, 2 hb)
+    b = b_ref[...]  # (1, N)
+    c = c_ref[...]
+    from_zero = jnp.full((P, N), reset_ref[r], jnp.int32) != 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, hb), 1)
+    y = jnp.zeros((P, hb), jnp.float32)
+    for i in range(hb):
+        s = jnp.where(from_zero, 0.0, s_ref[i])
+        new = s * coef[:, hb + i : hb + i + 1] + coef[:, i : i + 1] * b
+        out_ref[i] = new
+        y = jnp.where(lane == i, jnp.sum(new * c, axis=1, keepdims=True), y)
+    y_ref[...] = y
+
+
+def pick_heads_per_block(num_heads: int, want: int = DEFAULT_HEADS_PER_BLOCK) -> int:
+    hb = min(want, num_heads)
+    while num_heads % hb:
+        hb -= 1
+    return hb
+
+
+@functools.partial(jax.jit, static_argnames=("heads_per_block", "interpret"))
+def ssm_state_update(
+    state: jax.Array,  # (L, R, H, P, N) float32: EVERY layer's state
+    layer_idx: jax.Array,  # int32 scalar
+    x: jax.Array,  # (R, H, P)
+    B: jax.Array,  # (R, N)
+    C: jax.Array,  # (R, N)
+    dt: jax.Array,  # (R, H) after softplus
+    A: jax.Array,  # (H,) negative
+    valid: jax.Array,  # (R,) bool: False leaves the row's state as it is
+    reset: jax.Array,  # (R,) bool: the row starts from a zero state
+    *,
+    heads_per_block: Optional[int] = None,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """Returns (y (R, H, P) float32 without the D skip, the stacked state with
+    layer ``layer_idx`` advanced)."""
+    L, R, H, P, N = state.shape
+    hb = heads_per_block or pick_heads_per_block(H)
+    assert H % hb == 0, (H, hb)
+    J = H // hb
+    f32 = jnp.float32
+    dt = jnp.where(valid[:, None], dt.astype(f32), 0.0)
+    dA = jnp.exp(dt * A.astype(f32)[None, :])  # (R, H)
+    dtx = dt[:, :, None] * x.astype(f32)  # (R, H, P)
+    coef = jnp.concatenate(
+        [
+            jnp.transpose(dtx.reshape(R, J, hb, P), (0, 1, 3, 2)),
+            jnp.broadcast_to(dA.reshape(R, J, 1, hb), (R, J, P, hb)),
+        ],
+        axis=-1,
+    )  # (R, J, P, 2 hb)
+    li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
+    flags = (reset & valid).astype(jnp.int32)
+    tile = pl.BlockSpec((None, None, hb, P, N), lambda r, j, li, rs: (li[0], r, j, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(R, J),
+        in_specs=[
+            pl.BlockSpec((None, None, P, 2 * hb), lambda r, j, li, rs: (r, j, 0, 0)),
+            pl.BlockSpec((None, 1, N), lambda r, j, li, rs: (r, 0, 0)),
+            pl.BlockSpec((None, 1, N), lambda r, j, li, rs: (r, 0, 0)),
+            tile,
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, P, hb), lambda r, j, li, rs: (r, j, 0, 0)),
+            tile,
+        ],
+    )
+    y, new = pl.pallas_call(
+        functools.partial(_kernel, hb=hb),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((R, J, P, hb), f32),
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+        ],
+        # operands: li, flags, coef, B, C, state -> outputs: y, state
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+        name="ssm_state_update",
+    )(li, flags, coef, B.astype(f32).reshape(R, 1, N), C.astype(f32).reshape(R, 1, N), state)
+    return jnp.transpose(y, (0, 1, 3, 2)).reshape(R, H, P), new

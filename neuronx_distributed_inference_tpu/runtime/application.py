@@ -287,16 +287,29 @@ class TpuModelForCausalLM:
             raise RuntimeError("call load() before declared_pspecs()")
         return self._pspecs, self._cache_pspecs
 
+    @property
+    def paged_layers(self) -> int:
+        """How many layers page K/V over the block pool (the builder's
+        declaration; every layer, for a model without per-slot state)."""
+        from neuronx_distributed_inference_tpu.modules.block_kvcache import PAGED_KV
+
+        return sum(1 for kind in self.builder.cache_layers() if kind == PAGED_KV)
+
     def init_kv_cache(self):
         tc = self.config.tpu_config
         dt = to_dtype(tc.kv_cache_dtype or tc.dtype)
         if tc.is_block_kv_layout:
             from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+                HybridBlockCache,
                 block_cache_spec,
                 init_block_cache,
                 kv_block_bytes,
             )
 
+            # the pool spans the layers that page; a layer that keeps a
+            # constant-size state per slot (builder.cache_layers) costs no
+            # block, and its state is built beside the pool below
+            paged = self.paged_layers
             if tc.pa_num_blocks is None and tc.pa_pool_bytes is not None:
                 # byte-budgeted pool: the block count follows the TRUE
                 # per-block cost in the cache dtype — a quantized cache
@@ -305,7 +318,7 @@ class TpuModelForCausalLM:
                     1,
                     tc.pa_pool_bytes
                     // kv_block_bytes(
-                        self.spec.num_layers,
+                        paged,
                         tc.pa_block_size,
                         self.spec.attn.num_kv_heads,
                         self.spec.attn.head_dim,
@@ -313,7 +326,7 @@ class TpuModelForCausalLM:
                     ),
                 )
             cache = init_block_cache(
-                self.spec.num_layers,
+                paged,
                 tc.pa_num_blocks,
                 tc.pa_block_size,
                 self.spec.attn.num_kv_heads,
@@ -321,6 +334,15 @@ class TpuModelForCausalLM:
                 dtype=dt,
             )
             self._cache_pspecs = block_cache_spec(quantized=tc.kv_quantized)
+            slot_state = self.builder.init_slot_state(
+                tc.kv_cache_batch_size or tc.max_batch_size
+            )
+            if slot_state is not None:
+                state, state_pspecs = slot_state
+                cache = HybridBlockCache(k=cache.k, v=cache.v, state=state)
+                self._cache_pspecs = HybridBlockCache(
+                    k=self._cache_pspecs.k, v=self._cache_pspecs.v, state=state_pspecs
+                )
             self.kv_cache = shard_pytree(cache, self._cache_pspecs, self.mesh)
             return
         self._cache_pspecs = self.builder.cache_pspecs()

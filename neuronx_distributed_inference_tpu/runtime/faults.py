@@ -437,12 +437,26 @@ def fill_kv_rows(cache, row_ids: np.ndarray, value: float):
             )
         return stream.at[:, idx].set(value)
 
+    # a hybrid cache carries per-slot state beside its streams of blocks
+    # (HybridBlockCache.SLOT_FIELDS): block ids do not index it
+    keep = getattr(cache, "SLOT_FIELDS", ())
     return type(cache)(
         **{
-            f.name: fill(getattr(cache, f.name))
+            f.name: getattr(cache, f.name) if f.name in keep else fill(getattr(cache, f.name))
             for f in dataclasses.fields(cache)
         }
     )
+
+
+def fill_slot_state(cache, slots, value: float):
+    """Overwrite the constant-size per-slot state (every state-space layer)
+    of ``slots`` in a :class:`~..modules.block_kvcache.HybridBlockCache`;
+    the block pool is left as it is."""
+    import dataclasses
+
+    from neuronx_distributed_inference_tpu.modules.ssm import fill_state_slots
+
+    return dataclasses.replace(cache, state=fill_state_slots(cache.state, slots, value))
 
 
 def _poison_row(session, slot: int) -> bool:

@@ -51,6 +51,7 @@ from neuronx_distributed_inference_tpu.runtime.faults import (
     RETRYABLE_DISPATCH_ERRORS,
     WatchdogError,
     fill_kv_rows,
+    fill_slot_state,
 )
 from neuronx_distributed_inference_tpu.telemetry.tracing import (
     NULL_SPAN,
@@ -236,7 +237,9 @@ class ServingSession:
             # bf16 itemsize): quantized caches admit ~2x the blocks for the
             # same pool budget, and this is what capacity reporting uses
             self.block_bytes = kv_block_bytes(
-                app.spec.num_layers,
+                # the layers that page (a hybrid model's state-space layers
+                # keep a per-slot state and cost no block)
+                getattr(app, "paged_layers", app.spec.num_layers),
                 tc.pa_block_size,
                 app.spec.attn.num_kv_heads,
                 app.spec.attn.head_dim,
@@ -312,6 +315,12 @@ class ServingSession:
             # the mixed step shard_maps the Pallas kernel over the
             # head-parallel grid axis, so no warning/fallback here — see
             # docs/SERVING.md "Sharded meshes"
+        # a model whose layers keep a constant-size per-slot state
+        # (state-space layers; HybridBlockCache.state): the scrub of a slot
+        # covers it, and the three nxdi_ssm_* families count it
+        state = getattr(app.kv_cache, "state", None)
+        self.slot_state = state is not None
+        self.slot_state_bytes = state.nbytes if self.slot_state else 0
         self.tel.pool_gauges(0, self.kv_pool_bytes, self.kv_free_bytes)
 
     @property
@@ -581,6 +590,11 @@ class ServingSession:
                 blocks = self.allocator.quarantine_seq(req.slot)
                 if blocks:
                     self.app.kv_cache = fill_kv_rows(self.app.kv_cache, blocks, 0.0)
+                if self.slot_state:
+                    # the slot's recurrent state too: the next occupant
+                    # starts from zero anyway (position-0 rule), but a
+                    # non-finite state must not sit in a free slot
+                    self.app.kv_cache = fill_slot_state(self.app.kv_cache, [req.slot], 0.0)
             else:
                 self.allocator.free_seq(req.slot)
             self._bt_sync(req.slot)
@@ -1138,6 +1152,11 @@ class ServingSession:
             # what the program really ran over: the full slot batch at the
             # q bucket, whatever the number of rows prefilling
             tel.prefill_pass(real, B * qb - real)
+            if self.slot_state:
+                tel.ssm_pass(
+                    "chunk", len(rows), self.slot_state_bytes,
+                    resets=sum(1 for r, _ in rows if r.prefill_pos == 0),
+                )
             for req, n in rows:
                 self._note_prefill(req, n)
             tel.pool_gauges(
@@ -1735,6 +1754,8 @@ class ServingSession:
         tel.step("decode")
         tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
         tel.decode_pass(len(rows), B)
+        if self.slot_state:
+            tel.ssm_pass("decode", len(rows), self.slot_state_bytes)
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
         return out, [(r, p, r.slot, r.epoch) for r, p in rows]
 
@@ -2107,6 +2128,13 @@ class SpeculativeServingSession(ServingSession):
             clock=clock,
             sleep_fn=sleep_fn,
         )
+        if self.slot_state or getattr(draft_app.kv_cache, "state", None) is not None:
+            from neuronx_distributed_inference_tpu.config import SlotStateServingError
+
+            raise SlotStateServingError(
+                "a model with state-space layers cannot be served with speculation: "
+                "rejected drafts would need a snapshot of the state to roll back to"
+            )
         tc_d = draft_app.config.tpu_config
         spec = app.spec
         if self.spec_ragged:

@@ -350,6 +350,18 @@ class TelemetrySession:
             "nxdi_decode_slots_total",
             "rows the split path's decode dispatches were run over "
             "(num_slots per dispatch; rows / slots = the useful share)")
+        self._ssm_rows = r.counter(
+            "nxdi_ssm_rows_advanced_total",
+            "rows whose recurrent (state-space) state a dispatch of the "
+            "split serving step advanced", labels=("program",))
+        self._ssm_resets = r.counter(
+            "nxdi_ssm_state_resets_total",
+            "rows a chunk pass started from a zero recurrent state (first "
+            "position 0: a new request, or a re-prefill after preemption)")
+        self._ssm_bytes = r.gauge(
+            "nxdi_ssm_state_bytes",
+            "HBM of the per-slot recurrent state (conv tails + float32 SSM "
+            "state, every state-space layer, every slot)")
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -1028,6 +1040,19 @@ class TelemetrySession:
             return
         self._decode_rows.inc(rows)
         self._decode_slots.inc(slots)
+
+    def ssm_pass(self, program: str, rows: int, state_bytes: int, resets: int = 0) -> None:
+        """One dispatch of the split serving step over a model with
+        state-space layers: the rows whose recurrent state it advanced
+        (``program``: "decode" or "chunk"), of those the rows it started
+        from zero, and the bytes the state of all slots holds. Counted from
+        what the step already knows."""
+        if not self.enabled:
+            return
+        self._ssm_rows.child((program,)).inc(rows)
+        self._ssm_bytes.set(state_bytes)
+        if resets:
+            self._ssm_resets.inc(resets)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

@@ -78,6 +78,7 @@ MAIN_PATH_KERNELS = [
     ("ragged_paged_attention", "mixed", "bfloat16"),  # ragged mixed step
     ("ragged_paged_attention", "mixed", "int8"),
     ("quant_matmul", "k4096_n14336", "bfloat16"),  # 8B int4 weights
+    ("ssm_state_update", "rows48", "float32"),  # granite-4.0-h-micro decode, 48 slots
 ]
 
 
@@ -278,3 +279,95 @@ def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
     else:
         assert outside == 4
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-micro: state-space layers beside paged attention
+# ---------------------------------------------------------------------------
+
+
+def _abstract_hybrid_app(mesh, slots=48, blocks=2048):
+    """The benchmark's configuration (benchmark/configs/granite-4.0-h-micro.json:
+    the published widths, all 40 layers) over a described chip, with params
+    and the hybrid cache as ShapeDtypeStructs."""
+    import json
+    import os
+
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig, TpuConfig
+    from neuronx_distributed_inference_tpu.models import get_model_builder
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        HybridBlockCache,
+        init_block_cache,
+    )
+    from neuronx_distributed_inference_tpu.runtime.application import (
+        TpuModelForCausalLM,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
+        file = json.load(f)
+    meta = {"name", "source", "deployment", "assumed", "reduced", "tpu_config",
+            "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference"}
+    attrs = {k: v for k, v in file.items() if k not in meta}
+    tc = TpuConfig(
+        **{**file["tpu_config"], "batch_size": slots, "pa_num_blocks": blocks},
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=slots),
+        # the auto gates ask jax.default_backend(), which is the CPU here
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    cls = get_model_builder(attrs["model_type"]).config_cls
+    cfg = cls(tc, load_config=lambda c: [setattr(c, k, v) for k, v in attrs.items()])
+    app = TpuModelForCausalLM(None, cfg, mesh=mesh)
+    b = app.builder
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, P()))
+
+    def cache():
+        pool = init_block_cache(app.paged_layers, blocks, tc.pa_block_size, b.gqa.kv_heads,
+                                b.head_dim, dtype=jnp.bfloat16)
+        return HybridBlockCache(k=pool.k, v=pool.v, state=b.init_slot_state(slots)[0])
+
+    params = jax.tree.map(place, jax.eval_shape(b.random_params))
+    return app, params, jax.tree.map(place, jax.eval_shape(cache))
+
+
+def _copies_of(compiled, dtype, shape):
+    want = dtype + "[" + ",".join(str(d) for d in shape) + "]"
+    return [
+        line for line in compiled.as_text().splitlines()
+        if re.search(r"= " + re.escape(want) + r"\{[^}]*\} copy\(", line)
+    ]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, program):
+    """granite-4.0-h-micro at the benchmark's widths (48 slots, 36 x 96 MiB of
+    float32 state, a pool of 2048 blocks over the 4 attention layers), both
+    step programs compiled for a described v5e.
+
+    decode: ``ssm_state_update`` (state aliased in and out, one layer's tiles
+    visited) is in the executable, there is NO copy of the state's shape, and
+    the temporaries are the four copies of the 0.27 GB block pool that the
+    chip's default layout at head_dim 64 costs the paged kernels (PERF.md
+    section 7) and nothing of the state's size: under 1.2 GB, where one copy
+    of the state is 3.6 GB. chunk: no copy of the state either, and the
+    whole program (arguments + temporaries) under the 14.75 GiB the issue
+    set for 48 slots."""
+    app, params, cache = _abstract_hybrid_app(chip_mesh(1))
+    assert cache.k.shape[0] == 4 and cache.state.ssm.shape[:2] == (36, 48)
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(1024, q_len=128 if program == "chunk" else None)
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    assert not _copies_of(compiled, "f32", cache.state.ssm.shape)
+    mem = compiled.memory_analysis()
+    if program == "decode":
+        assert "ssm_state_update" in text and "paged_tkg_decode_attention" in text
+        assert mem.temp_size_in_bytes < 1.2e9
+        assert len(_copies_of(compiled, "bf16", cache.k.shape)) <= 4
+    else:
+        assert "paged_flash_attention" in text and "ssm_state_update" not in text
+        planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                   - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert planned < 14.75 * 2**30

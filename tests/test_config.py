@@ -265,3 +265,60 @@ def test_presharded_quantized_roundtrip(tmp_path):
     w = app2.params["layers"]["self_attn"]["q_proj"]
     assert w["weight"].dtype == jnp.int8 and "scale" in w
     np.testing.assert_array_equal(out, ref)
+
+
+# ---------------------------------------------------------------------------
+# a model whose layers keep a constant-size per-slot state (state-space
+# layers: models/granite_hybrid.py) refuses what cannot serve that state
+# ---------------------------------------------------------------------------
+
+_HYBRID_ATTRS = dict(
+    model_type="granitemoehybrid", hidden_size=64, shared_intermediate_size=128,
+    num_attention_heads=4, num_key_value_heads=2, num_hidden_layers=2,
+    layer_types=["mamba", "attention"], vocab_size=512, attention_multiplier=0.125,
+    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4,
+    position_embedding_type="nope", num_local_experts=0, tie_word_embeddings=True,
+)
+
+
+def _hybrid_config(**tpu):
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.models import get_model_builder
+
+    opts = dict(
+        batch_size=2, seq_len=128, is_continuous_batching=True, ctx_batch_size=1,
+        is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=16, is_chunked_prefill=True,
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=2, kernel_q_tile_size=16),
+    )
+    opts.update(tpu)
+    cls = get_model_builder("granitemoehybrid").config_cls
+    return cls(TpuConfig(**opts),
+               load_config=lambda c: [setattr(c, k, v) for k, v in _HYBRID_ATTRS.items()])
+
+
+def test_slot_state_model_accepts_the_paged_chunked_path():
+    cfg = _hybrid_config()
+    assert cfg.layer_types == ["mamba", "attention"]
+
+
+@pytest.mark.parametrize("tpu,match", [
+    (dict(is_prefix_caching=True), "is_prefix_caching"),
+    (dict(serving_ragged=True), "serving_ragged"),
+    (dict(speculation_length=4), "speculation"),
+    (dict(kv_cache_dtype="int8"), "kv_cache_dtype"),
+    (dict(tp_degree=2), "degree > 1"),
+], ids=["prefix_caching", "ragged", "speculation", "kv_quant", "tp2"])
+def test_slot_state_model_refuses_what_cannot_serve_its_state(tpu, match):
+    from neuronx_distributed_inference_tpu.config import SlotStateServingError
+
+    with pytest.raises(SlotStateServingError, match=match):
+        _hybrid_config(**tpu)
+
+
+def test_slot_state_model_refuses_the_unpaged_paths():
+    from neuronx_distributed_inference_tpu.models import get_model_builder
+
+    cfg = _hybrid_config(is_block_kv_layout=False, is_chunked_prefill=False,
+                         chunked_prefill_config=None)
+    with pytest.raises(NotImplementedError, match="paged, chunked path"):
+        get_model_builder("granitemoehybrid")(cfg)

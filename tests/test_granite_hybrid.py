@@ -1,0 +1,304 @@
+"""Granite-4.0-H (granitemoehybrid) on the paged, chunked serving path:
+the plain reference against the published implementation, and the system
+against the plain reference — logits, not tokens — across a chunk boundary
+with slow-decay heads, slot reuse, preemption and scrub.
+
+Small size, CPU, seeded random weights. The reference is the benchmark's own
+(``benchmark/harness/references/granite_hybrid.py``: sequential recurrence,
+no cache, no kernel); the tests import it as ``benchmark/selftest`` does.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.harness.references import granite_hybrid as ref
+from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig, TpuConfig
+from neuronx_distributed_inference_tpu.models import get_model_builder
+from neuronx_distributed_inference_tpu.runtime.application import TpuModelForCausalLM
+from neuronx_distributed_inference_tpu.runtime.faults import FaultInjector
+from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
+
+CHUNK = 16
+ATTRS = dict(
+    model_type="granitemoehybrid", hidden_size=64, shared_intermediate_size=128,
+    intermediate_size=128, num_attention_heads=4, num_key_value_heads=2,
+    num_hidden_layers=4, layer_types=["mamba", "attention", "mamba", "mamba"],
+    vocab_size=512, rms_norm_eps=1e-5, hidden_act="silu", rope_theta=10000,
+    # all four multipliers off 1, so that one left out shows
+    attention_multiplier=0.2, embedding_multiplier=3.0, residual_multiplier=0.5,
+    logits_scaling=2.0,
+    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4, mamba_n_groups=1,
+    mamba_chunk_size=256, mamba_expand=2, mamba_conv_bias=True, mamba_proj_bias=False,
+    attention_bias=False, position_embedding_type="nope", num_local_experts=0,
+    num_experts_per_tok=0, tie_word_embeddings=True,
+)
+
+
+def make_config(attrs=ATTRS, **tpu):
+    opts = dict(
+        dtype="float32", batch_size=4, seq_len=256, enable_bucketing=True,
+        context_encoding_buckets=[256], token_generation_buckets=[128, 256],
+        is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
+        pa_block_size=16, pa_num_blocks=48, is_chunked_prefill=True, fused_qkv=True,
+        output_logits=True,
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=4, kernel_q_tile_size=CHUNK),
+    )
+    opts.update(tpu)
+    cls = get_model_builder("granitemoehybrid").config_cls
+    return cls(TpuConfig(**opts), load_config=lambda c: [setattr(c, k, v) for k, v in attrs.items()])
+
+
+@pytest.fixture(scope="module")
+def app():
+    # random_params: the published initialisation of A_log / dt_bias / D
+    return TpuModelForCausalLM(None, make_config()).load(random_weights=True)
+
+
+class LogitSpy:
+    """Records (request slot -> logits at its real positions) of every
+    dispatch of the token-generation runner."""
+
+    def __init__(self, app):
+        self.app, self.rows = app, []
+        self.runner = app.token_generation_model
+        self.orig = self.runner._fn
+
+    def __enter__(self):
+        def spy(params, cache, inputs, rng=None):
+            out = self.orig(params, cache, inputs, rng)
+            self.rows.append((np.asarray(inputs.seq_ids), np.asarray(inputs.position_ids),
+                              np.asarray(inputs.slot_mapping) if inputs.slot_mapping is not None
+                              else None, np.asarray(out.logits)))
+            return out
+
+        self.runner._fn = spy
+        return self
+
+    def __exit__(self, *exc):
+        self.runner._fn = self.orig
+
+    def at(self, slot: int, position: int):
+        """The LAST logits served for (slot, position) (a re-prefill after
+        preemption serves a position twice)."""
+        found = None
+        for seq_ids, pos, sm, logits in self.rows:
+            if seq_ids[slot] < 0:
+                continue
+            for q in range(pos.shape[1]):
+                if pos[slot, q] == position and (sm is None or sm[slot, q] >= 0):
+                    found = logits[slot, q]
+        assert found is not None, (slot, position)
+        return found
+
+
+def drain(session, limit=200):
+    for _ in range(limit):
+        if not (session.active or session._readmit):
+            return
+        session.step()
+    raise AssertionError("the session did not drain")
+
+
+def check_request(app, spy, slot, prompt, generated, tol=2e-5):
+    """Served logits at the last prompt position and after every generated
+    token but the last, against the reference's full forward."""
+    geo = ref.geometry(ATTRS, 1)
+    positions = [len(prompt) - 1 + k for k in range(len(generated))]
+    want = ref.reference_logits(app.params, geo, list(prompt) + list(generated[:-1]), positions)
+    got = np.stack([spy.at(slot, p) for p in positions])
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol * max(1.0, np.abs(want).max()))
+
+
+def test_reference_is_the_published_implementation():
+    torch = pytest.importorskip("torch")
+    transformers = pytest.importorskip("transformers")
+    if not hasattr(transformers, "GraniteMoeHybridForCausalLM"):
+        pytest.skip("this transformers has no granitemoehybrid")
+    hf_cfg = transformers.GraniteMoeHybridConfig(
+        **{k: v for k, v in ATTRS.items() if k != "model_type"})
+    torch.manual_seed(0)
+    model = transformers.GraniteMoeHybridForCausalLM(hf_cfg).eval().float()
+    H = ATTRS["mamba_n_heads"]
+    rng = np.random.default_rng(0)
+    with torch.no_grad():
+        for name, p in model.named_parameters():
+            if "norm" in name:
+                p.add_(0.1 * torch.randn_like(p))  # a norm weight left out shows
+            if name.endswith("mamba.D"):
+                p.add_(0.3 * torch.randn_like(p))
+            if name.endswith("mamba.dt_bias"):  # published: dt log-uniform in 1e-3..1e-1
+                dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), H))
+                p.copy_(torch.tensor(dt + np.log(-np.expm1(-dt)), dtype=p.dtype))
+        assert torch.allclose(model.model.layers[0].mamba.A_log,
+                              torch.log(torch.arange(1, H + 1.0)))
+    sd = {k: v.detach().numpy() for k, v in model.state_dict().items()}
+    cfg = make_config()
+    params = get_model_builder("granitemoehybrid")(cfg).convert_hf_state_dict(sd)
+    ids = np.random.default_rng(1).integers(0, ATTRS["vocab_size"], size=37)
+    with torch.no_grad():
+        want = model(torch.tensor(ids)[None]).logits[0].numpy()
+    got = ref.reference_logits(params, ref.geometry(ATTRS, 1), ids, list(range(len(ids))))
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+def test_chunked_prefill_then_decode_is_the_reference_and_a_reused_slot_starts_clean(app):
+    """A prompt of 2.5 chunks, then 1-ahead decode; a second request is
+    admitted into the slot the first has left, beside a third in another."""
+    app.init_kv_cache()
+    rng = np.random.default_rng(2)
+    first = rng.integers(0, 512, size=int(2.5 * CHUNK))
+    with LogitSpy(app) as spy:
+        s = ServingSession(app)
+        s.add_request("first", first, max_new_tokens=6)
+        drain(s)
+        assert s.requests["first"].slot == -1
+        check_request(app, spy, 0, first, s.requests["first"].generated)
+        spy.rows.clear()
+        second, third = rng.integers(0, 512, size=21), rng.integers(0, 512, size=50)
+        s.add_request("second", second, max_new_tokens=5)
+        s.add_request("third", third, max_new_tokens=5)
+        slots = {r: s.requests[r].slot for r in ("second", "third")}
+        assert slots["second"] == 0  # the slot "first" held, state and all
+        drain(s)
+        check_request(app, spy, slots["second"], second, s.requests["second"].generated)
+        check_request(app, spy, slots["third"], third, s.requests["third"].generated)
+
+
+def test_the_carry_between_chunks_is_not_small_here(app):
+    """The control of the test above: with the carried state zeroed before
+    the prompt's last chunk, the logits move by several times its tolerance —
+    the heads of this initialisation decay slowly (dt is small, so the state's
+    share of a layer's output is small beside the D skip: float32 sees it)."""
+    from neuronx_distributed_inference_tpu.runtime.faults import fill_slot_state
+
+    app.init_kv_cache()
+    prompt = np.random.default_rng(2).integers(0, 512, size=int(2.5 * CHUNK))
+    with LogitSpy(app) as spy:
+        s = ServingSession(app)
+        s.add_request("r", prompt, max_new_tokens=1)
+        s.step(), s.step()
+        app.kv_cache = fill_slot_state(app.kv_cache, [0], 0.0)
+        drain(s)
+        got = spy.at(0, len(prompt) - 1)
+    want = ref.reference_logits(app.params, ref.geometry(ATTRS, 1), prompt, [len(prompt) - 1])[0]
+    # five times what check_request allows (2e-5 of the scale)
+    assert np.abs(got - want).max() > 5 * 2e-5 * max(1.0, np.abs(want).max())
+
+
+def test_preempt_then_resume_gives_the_logits_of_an_undisturbed_run(app):
+    app.init_kv_cache()
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 512, size=n) for n in (40, 27)]
+    with LogitSpy(app) as spy:
+        s = ServingSession(app, fault_injector=FaultInjector().exhaust_pool(5))
+        for i, p in enumerate(prompts):
+            s.add_request(f"r{i}", p, max_new_tokens=8)
+        drain(s)
+        assert sum(s.requests[f"r{i}"].preemptions for i in range(2)) >= 1
+        for i, p in enumerate(prompts):
+            req = s.requests[f"r{i}"]
+            assert len(req.generated) == 8
+            # re-admission may land in either slot: find it by the logits' row
+            geo = ref.geometry(ATTRS, 1)
+            positions = [len(p) - 1 + k for k in range(8)]
+            want = ref.reference_logits(app.params, geo, list(p) + req.generated[:-1], positions)
+            ok = False
+            for slot in range(s.num_slots):
+                try:
+                    got = np.stack([spy.at(slot, q) for q in positions])
+                except AssertionError:
+                    continue
+                ok = ok or np.abs(got - want).max() <= 2e-5 * max(1.0, np.abs(want).max())
+            assert ok, f"r{i}: no slot served the reference's logits after the preemption"
+
+
+def test_scrub_zeroes_the_slots_state_and_only_it(app):
+    app.init_kv_cache()
+    rng = np.random.default_rng(4)
+    s = ServingSession(app)
+    s.add_request("victim", rng.integers(0, 512, size=30), max_new_tokens=6)
+    s.add_request("bystander", rng.integers(0, 512, size=30), max_new_tokens=6)
+    for _ in range(4):
+        s.step()
+    state = app.kv_cache.state
+    before = (np.asarray(state.ssm[:, 1]), np.asarray(state.conv[:, :, 1]))
+    assert np.abs(np.asarray(state.ssm[:, 0])).max() > 0
+    victim = s.requests["victim"]
+    s._finish(victim, reason="test", scrub=True)
+    state = app.kv_cache.state
+    assert not np.asarray(state.ssm[:, 0]).any() and not np.asarray(state.conv[:, :, 0]).any()
+    assert np.array_equal(np.asarray(state.ssm[:, 1]), before[0])
+    assert np.array_equal(np.asarray(state.conv[:, :, 1]), before[1])
+    drain(s)
+    assert len(s.requests["bystander"].generated) == 6
+
+
+def test_the_pool_spans_the_attention_layers_only(app):
+    cache = app.kv_cache
+    assert app.paged_layers == 1 and cache.k.shape[0] == 1
+    assert cache.state.ssm.shape[:2] == (3, 4) and cache.state.ssm.dtype == jnp.float32
+    s = ServingSession(app)
+    per_token = 1 * 2 * 2 * 16 * 4  # one attention layer x K,V x 2 kv heads x head_dim x float32
+    assert s.block_bytes == per_token * 16
+
+
+def test_ssm_counters_count_rows_and_resets(app):
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+
+    app.init_kv_cache()
+    tel = TelemetrySession(enabled=True)
+    s = ServingSession(app, telemetry=tel)
+    rng = np.random.default_rng(5)
+    s.add_request("a", rng.integers(0, 512, size=40), max_new_tokens=3)  # 3 chunk passes
+    s.add_request("b", rng.integers(0, 512, size=10), max_new_tokens=3)  # 1
+    drain(s)
+    snap = tel.registry.snapshot()
+    by_program = {x["labels"]["program"]: x["value"]
+                  for x in snap["nxdi_ssm_rows_advanced_total"]["samples"]}
+    assert by_program["chunk"] == 4
+    assert by_program["decode"] >= 4  # 2 tokens each after the prefill's, plus 1-ahead extras
+    assert snap["nxdi_ssm_state_resets_total"]["samples"][0]["value"] == 2
+    assert snap["nxdi_ssm_state_bytes"]["samples"][0]["value"] == app.kv_cache.state.nbytes
+
+
+@pytest.mark.parametrize("attrs,match", [
+    (dict(num_local_experts=4), "num_local_experts"),
+    (dict(position_embedding_type="rope"), "position_embedding_type"),
+])
+def test_unwritten_mechanisms_are_refused_not_guessed(attrs, match):
+    with pytest.raises(NotImplementedError, match=match):
+        make_config({**ATTRS, **attrs})
+
+
+def test_layer_runs_of_the_published_pattern():
+    from neuronx_distributed_inference_tpu.models.granite_hybrid import layer_runs
+
+    period = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
+    assert layer_runs(tuple(period * 4)) == (
+        4, [("mamba", 0, 5), ("attention", 0, 1), ("mamba", 5, 4)])
+    assert layer_runs(tuple(ATTRS["layer_types"])) == (
+        1, [("mamba", 0, 1), ("attention", 0, 1), ("mamba", 1, 2)])
+
+
+def test_bf16_serving_stays_within_the_twins_noise():
+    """The benchmark's own rule (correct.judge: err <= 1.5 x the bf16 twin's
+    error) at the small size: the served bf16 model on the teacher-forced
+    probe path, kernel and all."""
+    from benchmark.harness import correct
+
+    cfg = make_config(dtype="bfloat16")
+    bapp = TpuModelForCausalLM(None, cfg).load(random_weights=True)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, 512, size=n).astype(np.int32) for n in (int(2.5 * CHUNK), 9)]
+    chosen = [[int(t) for t in rng.integers(0, 512, size=5)] for _ in prompts]
+    served = correct._forced_logits(bapp, prompts, chosen, 128)
+    geo = ref.geometry(ATTRS, 1)
+    for p, c, got in zip(prompts, chosen, served):
+        tokens, positions = correct.probe_row(p, c)
+        want = ref.reference_logits(bapp.params, geo, tokens, positions)
+        twin = ref.twin_logits(bapp.params, geo, tokens, positions)
+        err, floor = np.abs(got - want).max(), np.abs(twin - want).max()
+        assert err <= correct.K * floor, (err, floor)

@@ -1,0 +1,142 @@
+"""modules/ssm.py: the chunked state-space-dual form, the one-token step and
+the sequential recurrence are one function; masked positions and rows leave
+conv tail and state bit-identical; the Pallas decode update
+(ops/ssm_state_update.py, interpret mode here) is the one-token step."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuronx_distributed_inference_tpu.modules import ssm
+
+R, H, P, N = 3, 4, 8, 16
+
+
+def _inputs(Q, G=1, seed=0, slow=True):
+    rng = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    x, B, C = f(R, Q, H, P), f(R, Q, G, N), f(R, Q, G, N)
+    # slow-decay heads (the published initialisation: dt in 1e-3..1e-1, A = -(1..H))
+    # hold a lost carry to account; fast ones forget it within a few tokens
+    dt = jnp.asarray(rng.uniform(1e-3, 1e-1, (R, Q, H)) if slow else rng.uniform(0.3, 1.0, (R, Q, H)),
+                     jnp.float32)
+    A = -jnp.arange(1, H + 1, dtype=jnp.float32)
+    state = f(R, H, P, N)
+    return x, B, C, dt, A, state
+
+
+def _sequential(x, B, C, dt, A, state, valid):
+    """The recurrence, token by token, in numpy float64."""
+    x, B, C, dt, A, s = (np.asarray(a, np.float64) for a in (x, B, C, dt, A, state))
+    G = B.shape[2]
+    ys = np.zeros(x.shape)
+    for r in range(x.shape[0]):
+        for t in range(x.shape[1]):
+            if not valid[r, t]:
+                continue
+            Bh, Ch = np.repeat(B[r, t], H // G, 0), np.repeat(C[r, t], H // G, 0)
+            s[r] = np.exp(dt[r, t] * A)[:, None, None] * s[r] + (
+                dt[r, t][:, None] * x[r, t])[:, :, None] * Bh[:, None, :]
+            ys[r, t] = np.einsum("hpn,hn->hp", s[r], Ch)
+    return ys, s
+
+
+@pytest.mark.parametrize("Q,chunk,G", [(12, 4, 1), (12, 5, 1), (12, 256, 1), (7, 3, 2), (1, 256, 1)])
+def test_chunk_form_is_the_sequential_recurrence(Q, chunk, G):
+    x, B, C, dt, A, state = _inputs(Q, G)
+    valid = np.ones((R, Q), bool)
+    y, new = ssm.mamba2_chunk(x, B, C, dt, A, state, jnp.asarray(valid), chunk_size=chunk)
+    y_ref, s_ref = _sequential(x, B, C, dt, A, state, valid)
+    np.testing.assert_allclose(np.asarray(y), y_ref, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(new), s_ref, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("G", [1, 2])
+def test_step_repeated_is_the_chunk(G):
+    Q = 9
+    x, B, C, dt, A, state = _inputs(Q, G, seed=1)
+    valid = jnp.ones((R, Q), bool)
+    y_c, s_c = ssm.mamba2_chunk(x, B, C, dt, A, state, valid, chunk_size=4)
+    s, ys = state, []
+    for t in range(Q):
+        y_t, s = ssm.mamba2_step(x[:, t], B[:, t], C[:, t], dt[:, t], A, s, jnp.ones((R,), bool))
+        ys.append(y_t)
+    np.testing.assert_allclose(np.asarray(jnp.stack(ys, 1)), np.asarray(y_c), rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_c), rtol=2e-4, atol=2e-4)
+
+
+def test_a_chunk_split_in_two_carries_its_state():
+    """Two passes of 5 and 7 positions equal one of 12 (slow-decay heads: a
+    zeroed carry would show)."""
+    x, B, C, dt, A, state = _inputs(12, seed=2)
+    ones = lambda q: jnp.ones((R, q), bool)
+    y, s = ssm.mamba2_chunk(x, B, C, dt, A, state, ones(12))
+    y1, s1 = ssm.mamba2_chunk(x[:, :5], B[:, :5], C[:, :5], dt[:, :5], A, state, ones(5))
+    y2, s2 = ssm.mamba2_chunk(x[:, 5:], B[:, 5:], C[:, 5:], dt[:, 5:], A, s1, ones(7))
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)), np.asarray(y), rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(s2), np.asarray(s), rtol=2e-4, atol=2e-4)
+    y2z, _ = ssm.mamba2_chunk(x[:, 5:], B[:, 5:], C[:, 5:], dt[:, 5:], A, jnp.zeros_like(s1), ones(7))
+    assert float(jnp.abs(y2z - y2).max()) > 0.1  # the carry is not small here
+
+
+@pytest.mark.parametrize("form", ["chunk", "step"])
+def test_masked_positions_and_rows_leave_the_state_bit_identical(form):
+    Q = 8
+    x, B, C, dt, A, state = _inputs(Q, seed=3)
+    n = np.array([5, 0, Q])  # row 0 padded after 5, row 1 idle, row 2 full
+    valid = np.arange(Q)[None, :] < n[:, None]
+    if form == "chunk":
+        y, new = ssm.mamba2_chunk(x, B, C, dt, A, state, jnp.asarray(valid), chunk_size=3)
+        _, ref = ssm.mamba2_chunk(x[:, :5], B[:, :5], C[:, :5], dt[:, :5], A, state,
+                                  jnp.ones((R, 5), bool), chunk_size=3)
+        np.testing.assert_allclose(np.asarray(new[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-6)
+    else:
+        _, new = ssm.mamba2_step(x[:, 0], B[:, 0], C[:, 0], dt[:, 0], A, state, jnp.asarray(n > 0))
+    assert np.array_equal(np.asarray(new[1]), np.asarray(state[1]))  # bit for bit
+
+
+def test_conv_tail_shifts_by_the_count_of_real_tokens():
+    K, Cdim, Q = 4, 6, 8
+    rng = np.random.default_rng(4)
+    xs = jnp.asarray(rng.standard_normal((R, Q, Cdim)), jnp.bfloat16)
+    tail = jnp.asarray(rng.standard_normal((K - 1, R, Cdim)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((K, Cdim)), jnp.float32)
+    b = jnp.asarray(rng.standard_normal((Cdim,)), jnp.float32)
+    n = np.array([5, 0, 2])
+    out, new_tail = ssm.causal_conv(xs, tail, w, b, jnp.asarray(n, jnp.int32))
+    assert np.array_equal(np.asarray(new_tail[:, 1], np.float32), np.asarray(tail[:, 1], np.float32))
+    window = np.concatenate([np.swapaxes(np.asarray(tail, np.float32), 0, 1),
+                             np.asarray(xs, np.float32)], 1)
+    for r in range(R):
+        assert np.array_equal(np.asarray(new_tail[:, r], np.float32), window[r, n[r] : n[r] + K - 1])
+    ref = sum(np.asarray(w)[k] * window[:, k : k + Q] for k in range(K)) + np.asarray(b)
+    np.testing.assert_allclose(np.asarray(out), ref / (1 + np.exp(-ref)), rtol=1e-5, atol=1e-5)
+    # two passes (5 then 3 tokens of row 0) see what one pass of 8 sees
+    out_a, tail_a = ssm.causal_conv(xs[:, :5], tail, w, b, jnp.full((R,), 5, jnp.int32))
+    out_b, _ = ssm.causal_conv(xs[:, 5:], tail_a, w, b, jnp.full((R,), 3, jnp.int32))
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([out_a, out_b], 1)), np.asarray(out),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("heads_per_block", [2, 4])
+def test_pallas_state_update_is_the_step(heads_per_block):
+    from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
+
+    L, li = 3, 1
+    x, B, C, dt, A, _ = _inputs(1, seed=5)
+    rng = np.random.default_rng(6)
+    stacked = jnp.asarray(rng.standard_normal((L, R, H, P, N)), jnp.float32)
+    valid = jnp.asarray([True, False, True])
+    reset = jnp.asarray([False, False, True])
+    y, new = ssm_state_update(
+        stacked, jnp.int32(li), x[:, 0], B[:, 0, 0], C[:, 0, 0], dt[:, 0], A, valid, reset,
+        heads_per_block=heads_per_block, interpret=True,
+    )
+    start = jnp.where(reset[:, None, None, None], 0.0, stacked[li])
+    y_ref, s_ref = ssm.mamba2_step(x[:, 0], B[:, 0], C[:, 0], dt[:, 0], A, start, valid)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[li]), np.asarray(s_ref), rtol=1e-6, atol=1e-6)
+    assert np.array_equal(np.asarray(new[li, 1]), np.asarray(stacked[li, 1]))  # the idle row
+    for other in (0, 2):  # the other layers are not touched
+        assert np.array_equal(np.asarray(new[other]), np.asarray(stacked[other]))
