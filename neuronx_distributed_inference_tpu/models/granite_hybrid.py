@@ -119,10 +119,14 @@ def _take(tree, i):
 
 
 def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
-                spec: ModelSpec, sspec: ssm.SSMSpec, mlp_fn):
+                spec: ModelSpec, sspec: ssm.SSMSpec, mlp_fn, slots=None):
     """One state-space layer: hidden (R, Q, H); ``state`` the stacked
     per-slot state of ALL such layers, advanced at index ``li`` for the
-    ``valid`` (R, Q) positions; ``reset`` (R,) rows start from zero."""
+    ``valid`` (R, Q) positions; ``reset`` (R,) rows start from zero.
+    ``slots`` (R,): the slot each row's state is taken from at entry and
+    written back to at exit (the chunk program; an index past the last slot
+    = an empty row, whose write is dropped); None = row r owns slot r (the
+    decode program, one row per slot)."""
     R, Q, _ = hidden.shape
     Hn, Pd, N, G = sspec.num_heads, sspec.head_dim, sspec.state_size, sspec.n_groups
     d_inner, conv_dim = sspec.d_inner, sspec.conv_dim
@@ -138,17 +142,20 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
     dt = jax.nn.softplus(linear(m["dt_proj"], x).astype(f32) + m["dt_bias"].astype(f32))
     A = -jnp.exp(m["A_log"].astype(f32))
 
-    tail = jax.lax.dynamic_index_in_dim(state.conv, li, 0, keepdims=False)
+    tails = jax.lax.dynamic_index_in_dim(state.conv, li, 0, keepdims=False)
+    tail = tails if slots is None else jnp.take(tails, slots, axis=1, mode="fill", fill_value=0)
     tail = jnp.where(reset[None, :, None], jnp.zeros((), tail.dtype), tail)
     n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
     xBC, tail = ssm.causal_conv(xBC, tail, m["conv1d"]["weight"], m["conv1d"]["bias"], n_valid)
+    if slots is not None:
+        tail = tails.at[:, slots].set(tail, mode="drop", unique_indices=True)
     conv = jax.lax.dynamic_update_index_in_dim(state.conv, tail, li, 0)
     xBC = xBC.astype(hidden.dtype)
     xs = xBC[..., :d_inner].reshape(R, Q, Hn, Pd)
     Bm = xBC[..., d_inner : d_inner + G * N].reshape(R, Q, G, N)
     Cm = xBC[..., d_inner + G * N :].reshape(R, Q, G, N)
 
-    if Q == 1 and G == 1:
+    if Q == 1 and G == 1 and slots is None:
         from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
 
         y, new_ssm = ssm_state_update(
@@ -157,10 +164,18 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
         )
         y = y[:, None]
     else:
-        s = jax.lax.dynamic_index_in_dim(state.ssm, li, 0, keepdims=False)
+        if slots is None:
+            s = jax.lax.dynamic_index_in_dim(state.ssm, li, 0, keepdims=False)
+        else:
+            # straight from / into the stacked state: R x 2 MiB a layer,
+            # never a layer's whole (slots, ...) slice
+            s = state.ssm.at[li, slots].get(mode="fill", fill_value=0.0)
         s = jnp.where(reset[:, None, None, None], 0.0, s)
         y, s = ssm.mamba2_chunk(xs, Bm, Cm, dt, A, s, valid, chunk_size=sspec.chunk_size)
-        new_ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, s, li, 0)
+        if slots is None:
+            new_ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, s, li, 0)
+        else:
+            new_ssm = state.ssm.at[li, slots].set(s, mode="drop", unique_indices=True)
     y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
     gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps)
     hidden = residual_add(hidden, linear(m["out_proj"], gated), spec)
@@ -200,6 +215,17 @@ class HybridStack(LayerStack):
         # this pass is 0 starts from zero state (a new request in a reused
         # slot, a re-prefill after preemption, a probe's fresh cache)
         reset = valid[:, 0] & (positions[:, 0] == 0)
+        # whose state a row advances. The chunk program (handed a slot
+        # mapping) is chunk_rows wide and its rows carry their slot in
+        # seq_ids; an empty row gets an index of its own past the last slot,
+        # so its write-back is dropped and the indices stay unique. The
+        # decode program has one row per slot: row r owns slot r.
+        slots = None
+        if inputs.slot_mapping is not None:
+            rows = jnp.arange(positions.shape[0], dtype=jnp.int32)
+            slots = jnp.where(
+                inputs.seq_ids >= 0, inputs.seq_ids, cache.state.num_slots + rows
+            )
         block_inputs = paged_block_inputs(inputs, cache.block_size)
         mask = build_mask(inputs, spec, phase)
         layers = params["layers"]
@@ -215,7 +241,8 @@ class HybridStack(LayerStack):
         def mamba(carry, li):
             h, k, v, st = carry
             h, st = mamba_layer(
-                _take(layers[MAMBA], li), h, st, li, valid, reset, spec, self.sspec, mlp_fn
+                _take(layers[MAMBA], li), h, st, li, valid, reset, spec, self.sspec, mlp_fn,
+                slots=slots,
             )
             return (h, k, v, st), None
 

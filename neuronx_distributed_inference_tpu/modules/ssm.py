@@ -72,8 +72,13 @@ class RecurrentState:
           second-minor axis so that the array tiles without padding.
     ssm:  (L, slots, heads, head_dim, state_size), float32.
 
-    Row ``r`` of a batch owns slot ``r`` (the serving session builds its
-    batches so); ``seq_ids`` only says whether the row is live."""
+    Which slot a row of a batch advances depends on the program. The
+    decode program has one row per slot: row ``r`` owns slot ``r`` and
+    ``seq_ids`` only says whether the row is live. The chunk program is
+    ``ops/kernel_mode.CHUNK_ROWS`` rows wide and a row's slot is its
+    ``seq_ids`` entry: the state of its rows is gathered at a layer's entry
+    and written back at its exit (``models/granite_hybrid.mamba_layer``),
+    and the state of every slot that sits the pass out is not touched."""
 
     conv: jax.Array
     ssm: jax.Array

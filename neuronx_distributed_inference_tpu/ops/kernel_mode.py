@@ -149,6 +149,18 @@ def use_packed(spec) -> bool:
 #: (modules/block_kvcache.update_block_cache_at_layer selects on it).
 TKG_MAX_Q_LEN = 16
 
+#: rows of the paged prefill-chunk program (the multi-token token-generation
+#: pass that is handed BOTH a slot mapping and a block table). Its rows are
+#: addressed by slot (``seq_ids``, block table, slot mapping), so the program
+#: is as wide as the rows that prefill at once and not as the slot count; a
+#: pass over more rows is several dispatches of the one program
+#: (runtime/model_runner.SubModelRunner.chunk_rows). 8 rows x 128 positions is
+#: 4x past the v5e's ridge (197 TFLOP/s / 819 GB/s = 240 FLOP per weight
+#: byte), it is the sublane tile, and it is the reference's default
+#: ``max_num_seqs``. A constant of the program: ONE program per (q bucket,
+#: kv bucket), which a warm-up that knows only those two finds.
+CHUNK_ROWS = 8
+
 
 def use_tkg(spec, q_len: int, kv_width: int) -> bool:
     """Gate for the decode kernels (contiguous + paged TKG).

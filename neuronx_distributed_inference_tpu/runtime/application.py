@@ -805,6 +805,13 @@ class TpuModelForCausalLM:
         context encoding). Chunked/prior-KV prefill passes multi-token
         inputs through the TKG program — pass ``phase="tkg"`` explicitly.
 
+        A paged chunk pass (``phase="tkg"`` with BOTH ``slot_mapping`` and
+        ``block_table``) runs the chunk program, which is
+        ``runner.chunk_rows`` wide and addresses its rows by slot: row ``i``
+        belongs to slot ``seq_ids[i]`` whatever ``i`` is, more rows than
+        the program is wide are run in groups, and the results come back in
+        the caller's row order.
+
         Returns (tokens (B, K) np.ndarray, logits (B, K, V) np.ndarray or
         None). Updates the app's KV cache in place; all scheduling state
         stays with the caller.
@@ -821,6 +828,24 @@ class TpuModelForCausalLM:
             self.context_encoding_model if phase == "cte"
             else self.token_generation_model
         )
+        R = runner.chunk_rows
+        if runner.is_paged_chunk(slot_mapping, block_table) and B > R:
+            per_row = [input_ids, position_ids, seq_ids, attention_mask, sampling_params,
+                       slot_mapping, block_table]
+            parts = []
+            for i in range(0, B, R):
+                ids, pos, sid, mask, sp, sm, bt = (
+                    None if a is None else np.asarray(a)[i : i + R] for a in per_row
+                )
+                parts.append(self.forward(
+                    ids, pos, sid, attention_mask=mask, sampling_params=sp,
+                    slot_mapping=sm, block_table=bt, phase=phase, key=key,
+                ))
+            tokens = np.concatenate([t for t, _ in parts])
+            logits = None
+            if parts[0][1] is not None:
+                logits = np.concatenate([lg for _, lg in parts])
+            return tokens, logits
         if sampling_params is None:
             sampling_params = prepare_sampling_params(B)
         if attention_mask is None:

@@ -38,6 +38,7 @@ from neuronx_distributed_inference_tpu.models.base import (
 from neuronx_distributed_inference_tpu.modules.autobucketing import get_target_bucket
 from neuronx_distributed_inference_tpu.modules.kvcache import KVCache, cache_spec
 from neuronx_distributed_inference_tpu.modules.sampling import prepare_sampling_params
+from neuronx_distributed_inference_tpu.ops.kernel_mode import CHUNK_ROWS
 from neuronx_distributed_inference_tpu.utils.snapshot import debug_log_step
 
 TAG_CONTEXT_ENCODING = "context_encoding_model"
@@ -108,6 +109,25 @@ class SubModelRunner:
         else:
             self._step_program = program(f"{tag}_decode")
             self._chunk_program = program(f"{tag}_chunk")
+
+    @property
+    def chunk_rows(self) -> int:
+        """Rows of the PAGED chunk program (a token-generation pass handed
+        both a slot mapping and a block table: chunked and prefix prefill).
+        Its rows are addressed by slot, so it is compiled at this fixed
+        small width whatever the slot count; callers pack the rows that
+        prefill into groups of it (ops/kernel_mode.CHUNK_ROWS)."""
+        return min(CHUNK_ROWS, self.batch_size)
+
+    def is_paged_chunk(self, slot_mapping, block_table) -> bool:
+        """Whether a call with these fields runs the paged chunk program
+        (field presence is the serving paths' convention: CTE slot mapping
+        only; decode block table only; chunk / prefix prefill both)."""
+        return (
+            self.phase != PHASE_CONTEXT_ENCODING
+            and slot_mapping is not None
+            and block_table is not None
+        )
 
     def program_for(self, inputs: StepInputs):
         """The jitted callable that serves these inputs."""
@@ -244,7 +264,10 @@ class SubModelRunner:
             # keep the caller's dtype (the merged-embedding table's compute
             # dtype) — forcing fp32 would silently run bf16 prefill in fp32
             arrs["inputs_embeds"] = np.asarray(inputs_embeds)
-        arrs = self._pad_batch(arrs, self.batch_size)
+        # the paged chunk program is chunk_rows wide (rows addressed by slot);
+        # every other program has one row per slot
+        paged_chunk = self.is_paged_chunk(slot_mapping, block_table)
+        arrs = self._pad_batch(arrs, self.chunk_rows if paged_chunk else self.batch_size)
         return StepInputs(**{k: jnp.asarray(v) for k, v in arrs.items()}), B
 
     def trace_program(self, params, cache: KVCache, inputs: StepInputs, rng=None):
@@ -371,9 +394,12 @@ class SubModelRunner:
         """Reference: input_generator (model_wrapper.py:203-367).
 
         ``q_len`` > 1 builds a chunked/prefix-prefill example: multi-token
-        TKG inputs with BOTH slot_mapping and block_table, matching
-        ServingSession._prefill_chunks' call shape."""
+        TKG inputs with BOTH slot_mapping and block_table at ``chunk_rows``
+        rows, matching ServingSession._prefill_chunks' call shape (what
+        :meth:`prepare` pads such a call to)."""
         B = self.batch_size
+        if q_len and self.block_kv and self.phase != PHASE_CONTEXT_ENCODING:
+            B = self.chunk_rows
         if self.phase == PHASE_CONTEXT_ENCODING:
             S = bucket
             ids = np.zeros((B, S), np.int32)

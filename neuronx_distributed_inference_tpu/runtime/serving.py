@@ -23,9 +23,14 @@ give exactly "attend prior KV + causal among new tokens".
 
 Chunked prefill (reference modules/chunked_prefill/scheduler.py
 GridTileScheduler + flash_pa_with_schedule): long prompts are processed in
-fixed-size chunks through the SAME prior-KV pass, batching chunks of up to
-``max_num_seqs`` different requests per dispatch. Programs are keyed by the
-2-D (q_bucket, kv_bucket) shape — the TPU answer to the reference's 2-D
+fixed-size chunks through the SAME prior-KV pass, advancing up to
+``max_num_seqs`` different requests per step. The chunk program is
+``ops/kernel_mode.CHUNK_ROWS`` (8) rows wide whatever the slot count: its
+rows are COMPACT and addressed by slot (block table, slot mapping and
+``seq_ids`` are data), so a pass over n requests is ceil(n / 8) dispatches of
+the one program and computes nothing for the slots that sit the pass out
+(per pass real + padded == dispatches x 8 x q_bucket). Programs are keyed by
+the 2-D (q_bucket, kv_bucket) shape — the TPU answer to the reference's 2-D
 chunked-prefill buckets (autobucketing.py:101). Decode runs as its own
 batched pass instead of being concatenated into the prefill tile schedule:
 two async dispatches with static shapes beat one megakernel under XLA.
@@ -1078,7 +1083,8 @@ class ServingSession:
         self, reqs: List[Request], chunk_size: int, preempt: bool = False
     ) -> bool:
         """One batched prior-KV prefill pass: each request advances by up to
-        ``chunk_size`` prompt tokens (2-D (q_bucket, kv_bucket) program).
+        ``chunk_size`` prompt tokens (2-D (q_bucket, kv_bucket) program,
+        ``chunk_rows`` wide: one dispatch per group of that many requests).
 
         A request that cannot get KV blocks is preempted when ``preempt``
         (step()-driven chunked serving — never stalls the session); otherwise
@@ -1100,78 +1106,99 @@ class ServingSession:
         if not rows:
             return True
 
-        B = self.num_slots
+        # the chunk program is R rows wide and its rows are addressed by slot
+        # (block table, slot mapping, seq_ids): the pass packs the requests
+        # that prefill into rows 0..n-1 in groups of R, one dispatch a group
+        tkg = self.app.token_generation_model
+        R = tkg.chunk_rows
+        groups = [rows[i : i + R] for i in range(0, len(rows), R)]
         qb = pow2_bucket(max(n for _, n in rows))
         bs = self.allocator.block_size
         max_pos = max(r.prefill_pos + n for r, n in rows)
-        width = get_target_bucket(self.app.token_generation_model.buckets, max_pos)
+        width = get_target_bucket(tkg.buckets, max_pos)
         mb = width // bs
         real = sum(n for _, n in rows)
         tel = self.tel
-        tkg = self.app.token_generation_model
+        sampling = self._session_sampling_params()[:R]
         with tel.span(
             "serving.prefill_chunk", rows=len(rows), real_tokens=real,
-            padded_tokens=B * qb - real, q_bucket=qb, kv_bucket=width,
+            padded_tokens=len(groups) * R * qb - real, q_bucket=qb, kv_bucket=width,
+            dispatches=len(groups),
         ):
-            with tel.span("serving.prefill_chunk.prepare"):
-                ids = np.zeros((B, qb), np.int32)
-                positions = np.zeros((B, qb), np.int32)
-                mask = np.zeros((B, width), np.int32)
-                slot_mapping = np.full((B, qb), -1, np.int32)
-                block_table = np.zeros((B, mb), np.int32)
-                seq_ids = np.full((B,), -1, np.int32)
-                for req, n in rows:
-                    s = req.slot
-                    start = req.prefill_pos
-                    ids[s, :n] = req.input_ids[start : start + n]
-                    # padded tail positions continue so their (garbage)
-                    # writes/reads stay in the masked region
-                    positions[s] = start + np.arange(qb, dtype=np.int32)
-                    mask[s, : start + n] = 1
-                    slot_mapping[s, :n] = self.allocator.slot_mapping(
-                        s, np.arange(start, start + n)
+            # every group is dispatched before the first fetch is waited on
+            # (the dispatches are asynchronous); a failed dispatch fails its
+            # own rows and the other groups go on
+            flights = []
+            for group in groups:
+                with tel.span("serving.prefill_chunk.prepare"):
+                    ids = np.zeros((R, qb), np.int32)
+                    positions = np.zeros((R, qb), np.int32)
+                    mask = np.zeros((R, width), np.int32)
+                    slot_mapping = np.full((R, qb), -1, np.int32)
+                    block_table = np.zeros((R, mb), np.int32)
+                    seq_ids = np.full((R,), -1, np.int32)
+                    for row, (req, n) in enumerate(group):
+                        s = req.slot
+                        start = req.prefill_pos
+                        ids[row, :n] = req.input_ids[start : start + n]
+                        # padded tail positions continue so their (garbage)
+                        # writes/reads stay in the masked region
+                        positions[row] = start + np.arange(qb, dtype=np.int32)
+                        mask[row, : start + n] = 1
+                        slot_mapping[row, :n] = self.allocator.slot_mapping(
+                            s, np.arange(start, start + n)
+                        )
+                        block_table[row] = self.allocator.block_table(s, mb)
+                        seq_ids[row] = s
+                    inputs, _ = tkg.prepare(
+                        ids, mask, positions, seq_ids, sampling,
+                        slot_mapping=slot_mapping, block_table=block_table,
                     )
-                    block_table[s] = self.allocator.block_table(s, mb)
-                    seq_ids[s] = s
-                inputs, _ = tkg.prepare(
-                    ids, mask, positions, seq_ids, self._session_sampling_params(),
-                    slot_mapping=slot_mapping, block_table=block_table,
+
+                def dispatch(inputs=inputs):
+                    with tel.span("serving.prefill_chunk.dispatch"):
+                        return tkg(self.app.params, self.app.kv_cache, inputs, None)
+
+                out = self._guarded_dispatch(
+                    "prefill_chunk", [r for r, _ in group], dispatch
                 )
-
-            def dispatch():
-                with tel.span("serving.prefill_chunk.dispatch"):
-                    return tkg(self.app.params, self.app.kv_cache, inputs, None)
-
-            out = self._guarded_dispatch("prefill_chunk", [r for r, _ in rows], dispatch)
-            if out is None:
-                return True  # in-flight rows terminally FAILED(dispatch_error)
-            self._start_fetch(out.tokens)
-            self.app.kv_cache = out.cache
+                if out is None:
+                    continue  # this group's rows terminally FAILED(dispatch_error)
+                self._start_fetch(out.tokens)
+                self.app.kv_cache = out.cache
+                tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
+                flights.append((group, out.tokens))
+            if not flights:
+                return True
+            ran = [row for group, _ in flights for row in group]
+            ran_real = sum(n for _, n in ran)
             tel.step("prefill")
-            tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
-            # what the program really ran over: the full slot batch at the
-            # q bucket, whatever the number of rows prefilling
-            tel.prefill_pass(real, B * qb - real)
+            # what the program really ran over: R rows at the q bucket a
+            # dispatch, whatever the number of rows prefilling
+            tel.prefill_pass(
+                ran_real, len(flights) * R * qb - ran_real, dispatches=len(flights)
+            )
             if self.slot_state:
                 tel.ssm_pass(
-                    "chunk", len(rows), self.slot_state_bytes,
-                    resets=sum(1 for r, _ in rows if r.prefill_pos == 0),
+                    "chunk", len(ran), self.slot_state_bytes,
+                    resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
                 )
-            for req, n in rows:
+            for req, n in ran:
                 self._note_prefill(req, n)
             tel.pool_gauges(
                 len(self.active), self.kv_pool_bytes, self.kv_free_bytes
             )
             with tel.span("serving.prefill_chunk.fetch_wait") as wait:
-                tokens = np.asarray(out.tokens)
+                fetched = [(group, np.asarray(tokens)) for group, tokens in flights]
             self._step_fetch_wait_s += wait.dur_s
             with tel.span("serving.prefill_chunk.commit"):
-                for req, n in rows:
-                    req.prefill_pos += n
-                    if req.prefill_pos >= req.prompt_len:
-                        # the last prompt token's output IS the first
-                        # generated token
-                        self._finish_prefill(req, int(tokens[req.slot, n - 1]))
+                for group, tokens in fetched:
+                    for row, (req, n) in enumerate(group):
+                        req.prefill_pos += n
+                        if req.prefill_pos >= req.prompt_len:
+                            # the last prompt token's output IS the first
+                            # generated token
+                            self._finish_prefill(req, int(tokens[row, n - 1]))
         return True
 
     def _finish(self, req: Request, reason: Optional[str] = None, scrub: bool = False):

@@ -342,7 +342,11 @@ class TelemetrySession:
         self._prefill_padded = r.counter(
             "nxdi_prefill_padded_tokens_total",
             "token positions the split path's chunk passes ran beyond the "
-            "real ones: num_slots x q_bucket - real, per pass")
+            "real ones: dispatches x chunk rows x q_bucket - real, per pass")
+        self._prefill_dispatches = r.counter(
+            "nxdi_prefill_chunk_dispatches_total",
+            "dispatches of the chunk program by the split path's chunk "
+            "passes (over nxdi_steps_total{kind=prefill}: dispatches a pass)")
         self._decode_rows = r.counter(
             "nxdi_decode_rows_total",
             "live rows in the split path's decode dispatches")
@@ -1024,14 +1028,16 @@ class TelemetrySession:
             return
         self._bucket.child((model, str(int(bucket)))).inc()
 
-    def prefill_pass(self, real_tokens: int, padded_tokens: int) -> None:
+    def prefill_pass(self, real_tokens: int, padded_tokens: int, dispatches: int = 1) -> None:
         """One chunk pass of the split serving step: the prompt tokens it
-        advanced and the padded positions the program ran besides
-        (real + padded == num_slots x q_bucket)."""
+        advanced, the padded positions the program ran besides, and the
+        dispatches of the chunk program it took
+        (real + padded == dispatches x chunk rows x q_bucket)."""
         if not self.enabled:
             return
         self._prefill_real.inc(real_tokens)
         self._prefill_padded.inc(padded_tokens)
+        self._prefill_dispatches.inc(dispatches)
 
     def decode_pass(self, rows: int, slots: int) -> None:
         """One decode dispatch of the split serving step: its live rows and

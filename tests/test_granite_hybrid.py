@@ -59,7 +59,7 @@ def app():
 
 class LogitSpy:
     """Records (request slot -> logits at its real positions) of every
-    dispatch of the token-generation runner."""
+    dispatch of the token-generation runner (a row's slot is its seq_id)."""
 
     def __init__(self, app):
         self.app, self.rows = app, []
@@ -85,11 +85,12 @@ class LogitSpy:
         preemption serves a position twice)."""
         found = None
         for seq_ids, pos, sm, logits in self.rows:
-            if seq_ids[slot] < 0:
-                continue
-            for q in range(pos.shape[1]):
-                if pos[slot, q] == position and (sm is None or sm[slot, q] >= 0):
-                    found = logits[slot, q]
+            # the chunk program's rows are compact and carry their slot in
+            # seq_ids; the decode program's row r is slot r (seq_ids[r] == r)
+            for row in np.flatnonzero(seq_ids == slot):
+                for q in range(pos.shape[1]):
+                    if pos[row, q] == position and (sm is None or sm[row, q] >= 0):
+                        found = logits[row, q]
         assert found is not None, (slot, position)
         return found
 

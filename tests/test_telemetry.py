@@ -1094,17 +1094,20 @@ def test_span_tree_of_the_split_step(paged_app):
 
 
 def test_padding_counters_of_the_split_step(paged_app):
-    """Per chunk pass real + padded == num_slots x q_bucket, and the
-    counters are the sums over the passes; a decode dispatch runs over every
-    slot whatever its rows."""
+    """Per chunk pass real + padded == dispatches x chunk rows x q_bucket,
+    and the counters are the sums over the passes; a decode dispatch runs
+    over every slot whatever its rows."""
     with TelemetrySession() as tel:
         _drive_split(paged_app, tel)
     spans = _span_events(tel)
     slots = paged_app.config.tpu_config.chunked_prefill_config.max_num_seqs
     chunks = [e for e in spans if e["name"] == "serving.prefill_chunk"]
     assert len(chunks) >= 4
+    chunk_rows = paged_app.token_generation_model.chunk_rows
+    assert chunk_rows == min(8, slots)
     for e in chunks:
-        assert e["real_tokens"] + e["padded_tokens"] == slots * e["q_bucket"]
+        assert e["dispatches"] == 1  # never more rows than the program is wide
+        assert e["real_tokens"] + e["padded_tokens"] == chunk_rows * e["q_bucket"]
         assert 0 < e["real_tokens"] <= e["rows"] * e["q_bucket"]
 
     def value(name):
@@ -1113,6 +1116,7 @@ def test_padding_counters_of_the_split_step(paged_app):
     assert value("nxdi_prefill_real_tokens_total") == sum(map(len, SPLIT_PROMPTS))
     assert value("nxdi_prefill_real_tokens_total") == sum(e["real_tokens"] for e in chunks)
     assert value("nxdi_prefill_padded_tokens_total") == sum(e["padded_tokens"] for e in chunks)
+    assert value("nxdi_prefill_chunk_dispatches_total") == len(chunks)
     decodes = [e for e in spans if e["name"] == "serving.decode"]
     assert value("nxdi_decode_slots_total") == slots * len(decodes)
     assert value("nxdi_decode_rows_total") == sum(e["rows"] for e in decodes)
