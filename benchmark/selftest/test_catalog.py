@@ -4,6 +4,7 @@ only — shown on a throw-away copy of the catalog in a temporary directory."""
 
 import importlib
 import json
+import math
 import os
 import shutil
 
@@ -46,7 +47,8 @@ def test_the_catalog_holds_to_its_own_rules():
 def test_no_width_is_cut_and_assumed_keys_are_listed():
     """The contract's rules, of every configuration whatever its family:
     ``reduced`` names no width, agrees with BENCHMARK.json, and every model
-    key that no catalog row backs is listed as assumed."""
+    key that no catalog row backs is listed as assumed: all of them, or, where
+    the file names the catalog row it was read from key for key, none."""
     from benchmark.selftest.test_contract import WIDTH
 
     with open(os.path.join(catalog.REPO_DIR, "BENCHMARK.json")) as f:
@@ -56,10 +58,31 @@ def test_no_width_is_cut_and_assumed_keys_are_listed():
             cfg = json.load(f)
         assert not any(WIDTH.search(k) for k in cfg["reduced"])
         assert cfg["reduced"] == entries[name]["reduced"] and cfg["source"] == entries[name]["source"]
-        assert sorted(model_attrs(cfg)) == sorted(cfg["assumed"]["keys"])
+        assumed, note = cfg["assumed"]["keys"], cfg["assumed"].get("_note", "")
+        from_catalog_row = "architectures.jsonl" in note and cfg["name"] in note
+        assert sorted(assumed) == sorted(model_attrs(cfg)) or (assumed == [] and from_catalog_row)
         # the reference it names (``dense`` where it names none) is a module with the interface
         ref = correct.load_reference(cfg)
         assert all(callable(getattr(ref, f)) for f in ("geometry", "reference_logits", "twin_logits"))
+
+
+def test_a_cell_file_says_what_benchmark_json_says_and_sits_where_it_says():
+    """A cell's one-line ``why`` is the same in both places, and an open-loop
+    cell whose file gives the arithmetic of its seat (the knee read on the
+    chip, the share of it the cell sits at, the service time read there) is
+    seated by it: ``rate_rps`` = share x knee rounded down to 0.1 req/s,
+    ``prestart`` = rate x service time (the steady number in flight), and
+    the requests due in a window are what the generator will draw."""
+    with open(os.path.join(catalog.REPO_DIR, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    why = {w["name"]: w["why"] for w in bench["workloads"]}
+    for cell in catalog.check_catalog().values():
+        assert cell.spec["why"] == why[cell.name]
+        knee = cell.spec.get("knee", {})
+        if "share" in knee:
+            assert cell.spec["rate_rps"] == math.floor(knee["share"] * knee["rps"] * 10 + 1e-9) / 10
+            assert cell.spec["prestart"] == round(cell.spec["rate_rps"] * knee["service_s"])
+            assert round(cell.spec["rate_rps"] * bench["run_seconds"]) == knee["due_in_window"]
 
 
 @pytest.fixture
