@@ -48,15 +48,14 @@ import time
 #: kernel (a mis-masked tile, a dropped head) moves logits by their own scale.
 LOGIT_TOL = 0.05
 
-#: phase 2/3/4 shape: the bench headline point with one bucket each
+#: phase 2/3/4 shape: one bucket each
 GENERATE_SHAPE = dict(
     batch=2, prompt_lens=(128, 97), new_tokens=64,
     seq_len=512, ce_buckets=(128,), tkg_buckets=(512,),
 )
 
-#: phase 5 shape: bench.py's ``serving_1b_int8`` point (paged cache, chunked
-#: prefill, 8 slots) with mixed prompt lengths and budgets; bf16 so the
-#: serving apps share the generate app's weights
+#: phase 5 shape: paged cache, chunked prefill, 8 slots, mixed prompt lengths
+#: and budgets; bf16 so the serving apps share the generate app's weights
 SERVING_SHAPE = dict(
     prompt_lens=(128, 37, 200, 64, 16, 150, 90, 255),
     budgets=(48, 32, 40, 48, 24, 32, 48, 40),
@@ -137,33 +136,51 @@ class CompileLog:
 
 def build(attrs, shape, seed, *, extra=None, weights_from=None, paged=False,
           devices=None, load=True):
-    """One app through ``bench.build_app`` (the construction every bench
-    point uses). ``weights_from``: share that app's device weights instead
-    of loading a second 3 GB copy — same model shape, so same param tree.
-    Never a presharded artifact (``cache_key`` stays None): weights come
-    from ``seed`` alone."""
-    import bench
+    """One application, built the way a deployment builds it: bucketed
+    CTE/TKG programs on the contiguous cache, or (``paged``) continuous
+    batching with chunked prefill on the paged cache; the fused QKV layout
+    either way. ``weights_from``: share that app's device weights instead of
+    loading a second 3 GB copy — same model shape, so same param tree.
+    ``load=False`` returns the app unloaded: the caller brings the weights.
+    Weights come from ``seed`` alone."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig, TpuConfig
+    from neuronx_distributed_inference_tpu.models.llama import LlamaInferenceConfig
+    from neuronx_distributed_inference_tpu.parallel.mesh import mesh_from_config
+    from neuronx_distributed_inference_tpu.runtime.application import TpuModelForCausalLM
+    from neuronx_distributed_inference_tpu.utils.compile_cache import configure_compile_cache
 
+    # an app that shares weights never calls load(), which is where the
+    # program points the persistent compile cache
+    configure_compile_cache()
     if paged:
         kw = dict(
-            batch=shape["max_seqs"], ce_buckets=[shape["seq_len"]],
-            tkg_buckets=[shape["seq_len"]],
-            block_kv=dict(num_blocks=shape["blocks"],
-                          block_size=shape["block_size"],
-                          max_seqs=shape["max_seqs"], q_tile=shape["q_tile"]),
+            batch_size=shape["max_seqs"],
+            context_encoding_buckets=[shape["seq_len"]],
+            token_generation_buckets=[shape["seq_len"]],
+            is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
+            pa_num_blocks=shape["blocks"], pa_block_size=shape["block_size"],
+            is_chunked_prefill=True,
+            chunked_prefill_config=ChunkedPrefillConfig(
+                max_num_seqs=shape["max_seqs"], kernel_q_tile_size=shape["q_tile"]),
         )
     else:
-        kw = dict(batch=shape["batch"], ce_buckets=shape["ce_buckets"],
-                  tkg_buckets=shape["tkg_buckets"])
-    app = bench.build_app(
-        attrs, seq_len=shape["seq_len"], devices=devices,
-        extra_tpu=dict(seed=seed, output_logits=True, retrace_guard=True,
-                       **(extra or {})),
-        load=load and weights_from is None, **kw,
+        kw = dict(batch_size=shape["batch"],
+                  context_encoding_buckets=list(shape["ce_buckets"]),
+                  token_generation_buckets=list(shape["tkg_buckets"]))
+    tc = TpuConfig(
+        seq_len=shape["seq_len"], dtype="bfloat16", enable_bucketing=True, fused_qkv=True,
+        seed=seed, output_logits=True, retrace_guard=True, **kw, **(extra or {}),
     )
+    # a router replica's mesh spans its own partition of the devices
+    mesh = mesh_from_config(tc, devices=devices) if devices is not None else None
+    cfg = LlamaInferenceConfig(
+        tc, load_config=lambda c: [setattr(c, k, v) for k, v in attrs.items()])
+    app = TpuModelForCausalLM(None, cfg, mesh=mesh)
     if weights_from is not None:
         app.params, app._pspecs = weights_from.params, weights_from._pspecs
         app.init_kv_cache()
+    elif load:
+        app.load(random_weights=True)
     return app
 
 
@@ -486,8 +503,8 @@ class FirstStepTap:
 
 
 def drain(session, prompts, budgets):
-    """bench.measure_serving's arrival pattern: two requests up front, one
-    more per scheduler step, then drain."""
+    """Staggered arrivals: two requests up front, one more per scheduler
+    step, then drain."""
     n = len(prompts)
     nxt = 0
     while nxt < 2:
@@ -518,7 +535,7 @@ def serve_twice(app, prompts, budgets, log, tap_runner, vocab):
 
     c0, s0 = log.compiles, log.compile_s
     warm = drain(ServingSession(app), prompts, budgets)
-    app.init_kv_cache()  # fresh block pool, as between bench runs
+    app.init_kv_cache()  # fresh block pool
     c1, s1 = log.compiles, log.compile_s
     session = ServingSession(app)
     with FirstStepTap(tap_runner) as tap:

@@ -77,16 +77,6 @@ def build_parser() -> argparse.ArgumentParser:
     )
     parser.add_argument("--json", action="store_true", help="JSON report")
     parser.add_argument(
-        "--compare",
-        metavar="BENCH_JSON",
-        default=None,
-        help=(
-            "offline measured-vs-projected report over a committed bench "
-            "summary (BENCH_rNN.json); prints per-row error and exits 0 — "
-            "informational, no gate"
-        ),
-    )
-    parser.add_argument(
         "--suites",
         default=",".join(ALL_SUITES),
         help=f"comma list of {ALL_SUITES} (default: all)",
@@ -218,25 +208,6 @@ def baseline_diffs(before: Dict[str, str], after: Dict[str, str]) -> str:
 def main(argv=None) -> int:
     parser = build_parser()
     args = parser.parse_args(argv)
-    if args.compare:
-        # measured-vs-projected over a committed bench summary: hardware
-        # session zero's comparison tool — informational, exit 0 on any
-        # readable summary (an unreadable file is a usage error, like an
-        # unknown --suites name). Standalone: silently ignoring a combined
-        # --json/--suites/--write-baseline would look like the gate ran.
-        if args.json or args.write_baseline or args.suites != ",".join(ALL_SUITES):
-            parser.error(
-                "--compare is a standalone report; it cannot be combined "
-                "with --json, --suites or --write-baseline"
-            )
-        from neuronx_distributed_inference_tpu.analysis import device_model
-
-        try:
-            report = device_model.compare_report(args.compare)
-        except (OSError, ValueError) as e:
-            parser.error(f"--compare {args.compare}: {e}")
-        print(report)
-        return 0
     suites = parse_suites(parser, args.suites)
 
     before = _read_baselines() if args.write_baseline else None

@@ -1,19 +1,15 @@
 """TPU device-spec registry + the analytic roofline projection model.
 
-One table of nameplate numbers (peak FLOP/s by dtype, HBM GB/s, ICI GB/s)
-and one set of closed-form llama-shaped cost formulas, consumed by THREE
-places so the repo has a single source of truth for "how fast should this
-be":
+One table of nameplate numbers (peak FLOP/s by dtype, HBM GB/s, ICI GB/s,
+VMEM per core) and one set of closed-form llama-shaped cost formulas, so
+the repo has a single source of truth for "how fast should this be":
 
 - :mod:`.cost_audit` projects a lower-bound step time / tok/s for every
   audited (family, bucket) program from its HLO-derived FLOPs/bytes census;
-- ``bench.py`` emits ``projected_tok_s`` / ``model_error_frac`` beside every
-  measured row (the measured-vs-predicted hook hardware session zero
-  validates);
+- :mod:`.kernel_audit` budgets every kernel's VMEM against the device's;
+- ``chip_smoke.py`` resolves the chip it found against the registry;
 - ``python -m neuronx_distributed_inference_tpu.analysis.device_model``
-  prints the markdown projection tables committed in PERF.md — the
-  hand-written estimates those tables replace are gone; regenerate, don't
-  re-type.
+  prints the device line and the prefill projection table.
 
 The registry numbers are NAMEPLATE (vendor peak). Measured efficiency on
 this stack is ~67–92% of nameplate depending on op mix (PERF.md rounds
@@ -25,7 +21,6 @@ be reviewed.
 
 from __future__ import annotations
 
-import json
 from dataclasses import dataclass
 from typing import Dict, Optional
 
@@ -114,9 +109,8 @@ DEVICE_REGISTRY: Dict[str, DeviceSpec] = {
     ),
 }
 
-#: the bench's target chip — projections on a host with no resolvable TPU
-#: (the CPU harness) are computed against this spec with model_error_frac
-#: left null (bench contract, tests/test_bench_smoke.py)
+#: the benchmark's chip — projections on a host with no resolvable TPU (the
+#: CPU harness) are computed against this spec
 DEFAULT_DEVICE = "v5e"
 
 _KIND_PATTERNS = (
@@ -149,7 +143,7 @@ def get_device(name: str = DEFAULT_DEVICE) -> DeviceSpec:
 
 
 # ---------------------------------------------------------------------------
-# model shapes (bench.py imports these — one definition)
+# model shapes
 # ---------------------------------------------------------------------------
 
 LLAMA_1B = dict(
@@ -303,10 +297,9 @@ def prefill_projection(
     }
 
 
-#: the bench spec-serving draft shape: a 1B-width, 4-layer truncation (the
-#: EAGLE-class "few-layer draft over the target's width" regime; bench.py's
-#: spec-ragged row builds its random-weight draft from the same dict so the
-#: projection and the measurement share one shape definition)
+#: the default draft shape of the speculative projection: a 1B-width,
+#: 4-layer truncation (the EAGLE-class "few-layer draft over the target's
+#: width" regime)
 LLAMA_1B_DRAFT4 = dict(LLAMA_1B, num_hidden_layers=4)
 
 
@@ -336,9 +329,7 @@ def spec_decode_projection(
     device: Optional[DeviceSpec] = None,
     tp: int = 1,
 ) -> Dict[str, float]:
-    """Draft-assisted decode ceiling at a given ACCEPTANCE RATE — the
-    acceptance-parameterized projection the spec-serving bench row and
-    ``--compare`` consume.
+    """Draft-assisted decode ceiling at a given ACCEPTANCE RATE.
 
     One round = one packed verify pass over ``draft_len + 1`` query tokens
     per row (HBM cost == a plain decode step: weights stream once, the KV
@@ -385,246 +376,13 @@ def spec_decode_projection(
 
 
 # ---------------------------------------------------------------------------
-# bench-row projection table (the non-tiny bench.py suite shapes)
+# table renderer
 # ---------------------------------------------------------------------------
-
-#: each measured bench row's analytic shape — kv_width is the TKG bucket the
-#: measured decode actually runs at (bench._suite_params non-tiny values);
-#: kind "serving" projects the aggregate device ceiling at the slot count.
-BENCH_ROW_MODELS: Dict[str, dict] = {
-    "bf16_1b_bs1": dict(model=LLAMA_1B, kind="decode", batch=1, kv_width=512,
-                        weight_dtype="bfloat16", kv_dtype="bfloat16"),
-    "bf16_1b_bs4": dict(model=LLAMA_1B, kind="decode", batch=4, kv_width=512,
-                        weight_dtype="bfloat16", kv_dtype="bfloat16"),
-    "int8_1b_bs1": dict(model=LLAMA_1B, kind="decode", batch=1, kv_width=512,
-                        weight_dtype="int8", kv_dtype="bfloat16"),
-    "serving_1b_int8": dict(model=LLAMA_1B, kind="serving", batch=8,
-                            kv_width=1024, weight_dtype="int8",
-                            kv_dtype="bfloat16"),
-    "serving_1b_int8_ragged": dict(model=LLAMA_1B, kind="serving", batch=8,
-                                   kv_width=1024, weight_dtype="int8",
-                                   kv_dtype="bfloat16"),
-    "serving_1b_int8_ragged_async": dict(model=LLAMA_1B, kind="serving",
-                                         batch=8, kv_width=1024,
-                                         weight_dtype="int8",
-                                         kv_dtype="bfloat16"),
-    # spec-serving row (serving_spec_ragged): the acceptance-parameterized
-    # projection — PERF r5's committed operating point is acceptance 0.8
-    # with a k=4 program (3 drafts); bench.py records the MEASURED
-    # acceptance beside it (spec_ragged_acceptance) so hardware session
-    # zero can re-project at the observed rate before judging the error
-    "serving_1b_int8_spec_ragged": dict(model=LLAMA_1B, kind="serving_spec",
-                                        batch=8, kv_width=1024,
-                                        weight_dtype="int8",
-                                        kv_dtype="bfloat16",
-                                        acceptance=0.8, draft_len=3,
-                                        draft=LLAMA_1B_DRAFT4),
-    # router row, as committed: 2 replicas SHARING one chip, 8-request mix
-    # -> each replica streams its own weight copy for its 4-request share,
-    # so the aggregate ceiling is the batch-4 single-chip projection (NOT
-    # batch-8: two weight streams halve the per-replica bandwidth). On
-    # scale-out hardware bench.py multiplies by the count of
-    # non-overlapping replica meshes instead.
-    "serving_1b_int8_router": dict(model=LLAMA_1B, kind="serving", batch=4,
-                                   kv_width=1024, weight_dtype="int8",
-                                   kv_dtype="bfloat16"),
-    # threaded-stepping row (router_threading): the DEVICE ceiling is the
-    # same as the sequential router row — threading removes host
-    # serialization, it does not change what each replica's chip streams;
-    # the row's win shows up as measured tok/s approaching this same
-    # projection (and in router_step_overlap_frac), not as a new ceiling
-    "serving_1b_int8_router_threaded": dict(
-        model=LLAMA_1B, kind="serving", batch=4, kv_width=1024,
-        weight_dtype="int8", kv_dtype="bfloat16"),
-    # disaggregated-prefill-tier row (ISSUE 15): the DEVICE ceiling is the
-    # router row's — the tier moves WHERE prefill runs (a dedicated
-    # replica), not what each decode chip streams per request; the row's
-    # own numbers (handoffs, hand-off failure census, local-prefill
-    # fallbacks) are containment metrics the device model does not project
-    "serving_1b_int8_disagg": dict(model=LLAMA_1B, kind="serving", batch=4,
-                                   kv_width=1024, weight_dtype="int8",
-                                   kv_dtype="bfloat16"),
-    # elastic add/retire row (ISSUE 20): the DEVICE ceiling is the router
-    # row's — retiring one replica mid-drain and adding a fresh one changes
-    # WHICH replica streams each request, not what a replica's chip streams
-    # per step; the row's own numbers (retired/added counts, leaked blocks
-    # and threads, attainment vs the static drain) are stewardship metrics
-    # the device model does not project
-    "serving_1b_int8_elastic": dict(model=LLAMA_1B, kind="serving", batch=4,
-                                    kv_width=1024, weight_dtype="int8",
-                                    kv_dtype="bfloat16"),
-    # open-loop goodput rows (ISSUE 14): the DEVICE ceiling is the same
-    # full-slot serving projection — goodput (SLO-met tokens/s) is bounded
-    # by throughput, which is bounded by this; the rows' own numbers
-    # (attainment, dip, recovery) are workload metrics the device model
-    # does not project. The chaos row's 2 replicas share the committed
-    # 1-chip harness, so its ceiling stays the single-mesh projection.
-    "serving_1b_int8_goodput": dict(model=LLAMA_1B, kind="serving", batch=8,
-                                    kv_width=1024, weight_dtype="int8",
-                                    kv_dtype="bfloat16"),
-    "serving_1b_int8_goodput_burst": dict(model=LLAMA_1B, kind="serving",
-                                          batch=8, kv_width=1024,
-                                          weight_dtype="int8",
-                                          kv_dtype="bfloat16"),
-    "serving_1b_int8_goodput_chaos": dict(model=LLAMA_1B, kind="serving",
-                                          batch=8, kv_width=1024,
-                                          weight_dtype="int8",
-                                          kv_dtype="bfloat16"),
-    # disaggregated chaos row (ISSUE 15): same full-slot serving ceiling —
-    # the prefill-tier kill is a containment scenario (decode capacity
-    # survives; placements degrade to local prefill), not a new ceiling
-    "serving_1b_int8_disagg_chaos": dict(model=LLAMA_1B, kind="serving",
-                                         batch=8, kv_width=1024,
-                                         weight_dtype="int8",
-                                         kv_dtype="bfloat16"),
-    "int8_8b_bs1": dict(model=LLAMA_8B, kind="decode", batch=1, kv_width=512,
-                        weight_dtype="int8", kv_dtype="bfloat16"),
-    # w4 rows (ISSUE 17): grouped-int4 packed weights (ops/quant_matmul).
-    # The 8B decode row is the flagship — weight-read bytes drop ~2x vs the
-    # int8 row above, and the projection's ceiling moves with them.
-    "bf16_8b_int4": dict(model=LLAMA_8B, kind="decode", batch=1, kv_width=512,
-                         weight_dtype="int4", kv_dtype="bfloat16"),
-    "serving_1b_int4_ragged": dict(model=LLAMA_1B, kind="serving", batch=8,
-                                   kv_width=1024, weight_dtype="int4",
-                                   kv_dtype="bfloat16"),
-    "bf16_1b_8k": dict(model=LLAMA_1B, kind="decode", batch=1, kv_width=8704,
-                       weight_dtype="bfloat16", kv_dtype="bfloat16"),
-    "bf16_1b_8k_kvq8": dict(model=LLAMA_1B, kind="decode", batch=1,
-                            kv_width=8704, weight_dtype="bfloat16",
-                            kv_dtype="int8"),
-    "bf16_1b_16k": dict(model=LLAMA_1B, kind="decode", batch=1,
-                        kv_width=16896, weight_dtype="bfloat16",
-                        kv_dtype="bfloat16"),
-    "bf16_1b_16k_kvq8": dict(model=LLAMA_1B, kind="decode", batch=1,
-                             kv_width=16896, weight_dtype="bfloat16",
-                             kv_dtype="int8"),
-}
-
-
-def project_bench_row(name: str, device: Optional[DeviceSpec] = None) -> Optional[dict]:
-    """Projected decode tok/s (device ceiling) for one bench row name; None
-    for rows the table doesn't model. ``serving_spec`` rows project through
-    the acceptance-parameterized speculative model."""
-    row = BENCH_ROW_MODELS.get(name)
-    if row is None:
-        return None
-    if row.get("kind") == "serving_spec":
-        return spec_decode_projection(
-            row["model"], batch=row["batch"], kv_width=row["kv_width"],
-            acceptance=row["acceptance"], draft_len=row["draft_len"],
-            draft_attrs=row.get("draft"),
-            weight_dtype=row["weight_dtype"], kv_dtype=row["kv_dtype"],
-            device=device,
-        )
-    return decode_projection(
-        row["model"], batch=row["batch"], kv_width=row["kv_width"],
-        weight_dtype=row["weight_dtype"], kv_dtype=row["kv_dtype"],
-        device=device,
-    )
-
-
-#: bench summary-line key -> (row whose projection it compares against,
-#: summary key holding the run's OWN recorded projection or None). A
-#: recorded projection wins over the static table: the run knows things
-#: the table cannot (e.g. the router row's count of non-overlapping
-#: replica meshes on multi-chip hardware), so the bench row and the
-#: --compare report can never disagree about the same run.
-COMPARE_KEYS = (
-    ("value", "bf16_1b_bs1", "projected_tok_s"),
-    ("decode_bs4_tok_s", "bf16_1b_bs4", None),
-    ("int8_1b_tok_s", "int8_1b_bs1", None),
-    ("serving_tok_s", "serving_1b_int8", "serving_projected_tok_s"),
-    ("ragged_tok_s", "serving_1b_int8_ragged", None),
-    ("ragged_async_tok_s", "serving_1b_int8_ragged_async", None),
-    # the spec row records its own projection: the bench re-projects at the
-    # MEASURED acceptance rate, which the static table cannot know
-    ("spec_ragged_tok_s", "serving_1b_int8_spec_ragged",
-     "spec_ragged_projected_tok_s"),
-    ("router_tok_s", "serving_1b_int8_router", "router_projected_tok_s"),
-    ("router_threaded_tok_s", "serving_1b_int8_router_threaded", None),
-    # goodput vs the same serving ceiling: the gap between goodput_tok_s
-    # and the projection decomposes into (device gap) x (SLO attainment) —
-    # the report line makes an SLO-driven collapse visible offline
-    ("goodput_tok_s", "serving_1b_int8_goodput", None),
-    ("int8_8b_tok_s", "int8_8b_bs1", None),
-    # w4 rows record their own projections (the run re-derives them at the
-    # measured shape), so the static table is the fallback comparator
-    ("w4_tok_s", "bf16_8b_int4", "w4_projected_tok_s"),
-    ("w4_serving_tok_s", "serving_1b_int4_ragged", "w4_serving_projected_tok_s"),
-    ("ctx8k_tok_s", "bf16_1b_8k", None),
-    ("kvq8_8k_tok_s", "bf16_1b_8k_kvq8", None),
-    ("long_ctx_tok_s", "bf16_1b_16k", None),
-    ("kvq8_16k_tok_s", "bf16_1b_16k_kvq8", None),
-)
-
-
-def compare_report(path: str) -> str:
-    """Offline measured-vs-projected report over a committed bench summary
-    (``BENCH_rNN.json`` — either the raw summary line or the driver wrapper
-    with the summary under ``"parsed"``). Informational: per-row error
-    fractions, no gate — hardware session zero's comparison tool."""
-    with open(path) as f:
-        data = json.load(f)
-    if not isinstance(data, dict):
-        raise ValueError(
-            f"bench summary must be a JSON object, got {type(data).__name__}"
-        )
-    if isinstance(data.get("parsed"), dict):
-        data = data["parsed"]
-    device_str = str(data.get("device") or "")
-    spec = resolve_device(device_str)
-    resolved = spec is not None
-    spec = spec or get_device()
-    note = "" if resolved else (
-        f", UNRESOLVED: projecting {DEFAULT_DEVICE} — errors are not meaningful"
-    )
-    lines = [
-        f"measured-vs-projected (device {device_str or '<none>'} -> "
-        f"{spec.name} spec{note})",
-        f"  {'row':<30} {'measured':>10} {'projected':>10} {'err':>8}  bound",
-    ]
-    n = 0
-    for key, row_name, recorded_key in COMPARE_KEYS:
-        measured = data.get(key)
-        if measured is None:
-            continue
-        proj = project_bench_row(row_name, spec)
-        if proj is None:
-            continue
-        recorded = data.get(recorded_key) if recorded_key else None
-        projected = recorded if recorded else proj["tok_s"]
-        err = measured / projected - 1.0
-        lines.append(
-            f"  {row_name:<30} {measured:>10.1f} {projected:>10.1f} "
-            f"{err:>+7.1%}  {proj['bound']}"
-            f"{' (recorded)' if recorded else ''}"
-        )
-        n += 1
-    if n == 0:
-        lines.append("  (no comparable tok/s keys found in the summary)")
-    lines.append(
-        "projections are nameplate lower bounds on time: measured/projected"
-        " - 1 near 0 means device-limited; strongly negative means a host "
-        "gap or model error — see PERF.md 'Static roofline cost model'"
-    )
-    return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------------
-# PERF.md table renderer
-# ---------------------------------------------------------------------------
-
-
-def _fmt_bytes(n: float) -> str:
-    for unit, div in (("GB", 1e9), ("MB", 1e6), ("KB", 1e3)):
-        if n >= div:
-            return f"{n / div:.1f} {unit}"
-    return f"{n:.0f} B"
 
 
 def render_projection_tables(device: str = DEFAULT_DEVICE) -> str:
-    """The markdown tables PERF.md commits (regenerate with
-    ``python -m neuronx_distributed_inference_tpu.analysis.device_model``)."""
+    """The device line and the prefill projections as markdown
+    (``python -m neuronx_distributed_inference_tpu.analysis.device_model``)."""
     spec = get_device(device)
     out = [
         f"<!-- generated by python -m neuronx_distributed_inference_tpu."
@@ -637,19 +395,6 @@ def render_projection_tables(device: str = DEFAULT_DEVICE) -> str:
         f"{spec.hbm_bw / 1e9:.0f} GB/s, ICI {spec.ici_bw / 1e9:.0f} GB/s, "
         f"VMEM {spec.vmem_bytes // (1024 ** 2)} MiB/core, "
         f"ridge {spec.ridge_flops_per_byte:.0f} FLOP/byte.",
-        "",
-        "| bench row | weights | KV read/step | bound | projected tok/s |",
-        "|---|---|---|---|---|",
-    ]
-    for name, row in BENCH_ROW_MODELS.items():
-        p = project_bench_row(name, spec)
-        out.append(
-            f"| {name} (bs={row['batch']}, kv {row['kv_width']}) | "
-            f"{_fmt_bytes(p['weight_bytes'])} | "
-            f"{_fmt_bytes(p['kv_read_bytes'])} | {p['bound']} | "
-            f"{p['tok_s']:.0f} |"
-        )
-    out += [
         "",
         "| prefill | prompt | lower-bound wall | prefill tok/s ceiling |",
         "|---|---|---|---|",
@@ -669,5 +414,5 @@ def render_projection_tables(device: str = DEFAULT_DEVICE) -> str:
     return "\n".join(out)
 
 
-if __name__ == "__main__":  # pragma: no cover - exercised via PERF.md regen
+if __name__ == "__main__":  # pragma: no cover
     print(render_projection_tables())

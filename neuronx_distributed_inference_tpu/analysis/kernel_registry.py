@@ -35,9 +35,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 OPS_DIR = pathlib.Path(__file__).resolve().parent.parent / "ops"
 REPO_ROOT = OPS_DIR.parent.parent
 
-#: committed 1B/8B attention shapes (device_model.LLAMA_1B / LLAMA_8B and
-#: the BENCH_ROW_MODELS kv buckets) — literal here so a registry import
-#: cannot recurse into the traced-suite modules
+#: committed 1B/8B attention shapes (device_model.LLAMA_1B / LLAMA_8B) —
+#: literal here so a registry import cannot recurse into the traced-suite
+#: modules
 _1B = dict(H=2048, I=8192, Hq=32, Hkv=8, D=64, L=16)
 _8B = dict(H=4096, I=14336, Hq=32, Hkv=8, D=128, L=32)
 
@@ -266,53 +266,6 @@ def _ragged_case(T, R, MB, bs, cache_dtype):
     return build
 
 
-def _fused_attn_case(B, bucket):
-    def build():
-        import jax.numpy as jnp
-
-        from neuronx_distributed_inference_tpu.ops import decode_block as db
-
-        m = _1B
-        H, Hq, Hkv, D, L = m["H"], m["Hq"], m["Hkv"], m["D"], m["L"]
-        N3 = (Hq + 2 * Hkv) * D
-        x = _sds((B, 1, H), jnp.bfloat16)
-        gamma = _sds((H,), jnp.bfloat16)
-        wqkv = _sds((H, N3), jnp.bfloat16)
-        wout = _sds((Hq * D, H), jnp.bfloat16)
-        cs = _sds((B, 1, D // 2), jnp.float32)
-        cache = _sds((L, B, bucket, Hkv, D), jnp.bfloat16)
-        li = _sds((), jnp.int32)
-        slots = _sds((B,), jnp.int32)
-        mask = _sds((B, 1, 1, bucket), jnp.bool_)
-        pos = _sds((B, 1), jnp.int32)
-        fn = functools.partial(
-            _unjit(db.fused_attn_block),
-            scale=D ** -0.5, eps=1e-5, n_kv=Hkv,
-        )
-        return fn, (x, gamma, wqkv, wout, cs, cs, cache, cache, li, slots,
-                    mask, pos)
-
-    return build
-
-
-def _fused_mlp_case(B):
-    def build():
-        import jax.numpy as jnp
-
-        from neuronx_distributed_inference_tpu.ops import decode_block as db
-
-        m = _1B
-        H, I = m["H"], m["I"]
-        x = _sds((B, 1, H), jnp.bfloat16)
-        gamma = _sds((H,), jnp.bfloat16)
-        wg = _sds((H, I), jnp.bfloat16)
-        wd = _sds((I, H), jnp.bfloat16)
-        fn = functools.partial(_unjit(db.fused_mlp_block), eps=1e-5)
-        return fn, (x, gamma, wg, wg, wd)
-
-    return build
-
-
 def _qmm_case(B, model):
     """Decode-shaped int4 fused-dequant matmul at the model's widest linear
     (the H -> I up/gate projection — the weight-read roofline term)."""
@@ -457,30 +410,6 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         ),
     ),
     KernelSpec(
-        name="fused_attn_block",
-        site=("decode_block.py", "fused_attn_block"),
-        entry="fused_attn_block",
-        fallback="neuronx_distributed_inference_tpu.models.base:decoder_layer",
-        parity_test="tests/test_decode_block.py",
-        tile_params=("ta_cap", "tc_cap", "bs"),
-        sweep=(
-            ("ta_cap", (128, 256, 512)),
-            ("tc_cap", (256, 512)),
-            ("bs", (512,)),
-        ),
-        cases=(KernelCase("h2048", "bfloat16", _fused_attn_case(4, 512)),),
-    ),
-    KernelSpec(
-        name="fused_mlp_block",
-        site=("decode_block.py", "fused_mlp_block"),
-        entry="fused_mlp_block",
-        fallback="neuronx_distributed_inference_tpu.models.base:_decoder_layer_mlp",
-        parity_test="tests/test_decode_block.py",
-        tile_params=("ti_cap",),
-        sweep=(("ti_cap", (128, 256, 512, 1024)),),
-        cases=(KernelCase("i8192", "bfloat16", _fused_mlp_case(4)),),
-    ),
-    KernelSpec(
         name="fused_moe_decode",
         site=("moe_decode.py", "fused_moe_decode"),
         entry="fused_moe_decode",
@@ -532,8 +461,6 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     "tkg_decode_attention": {"*": {"bs": 512}},
     "paged_flash_attention": {"*": {"tq": 128}},
     "ragged_paged_attention": {"*": {"tq": 16}},
-    "fused_attn_block": {"*": {"ta_cap": 256, "tc_cap": 512, "bs": 512}},
-    "fused_mlp_block": {"*": {"ti_cap": 512}},
     "fused_moe_decode": {"*": {"ti_cap": 512}},
     "quant_matmul": {"*": {"bn": 256}},
 }

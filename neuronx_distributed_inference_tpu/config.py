@@ -445,12 +445,6 @@ class TpuConfig:
     # inert default `false`, which now pins the native path — re-save the
     # artifact (or edit tpu_config.json to null) to restore auto.
     attn_block_tkg_kernel_enabled: Optional[bool] = None
-    # fused decode-layer Pallas kernels (ops/decode_block.py): the attention
-    # BLOCK (rmsnorm+fused-QKV+rope+attention+o-proj, reference
-    # attention_block_tokengen_nki_kernel, attention_base.py:1609 — requires
-    # fused_qkv) and the gated-MLP block. Tri-state like the other kernels.
-    fused_attn_block_kernel_enabled: Optional[bool] = None
-    fused_mlp_kernel_enabled: Optional[bool] = None
     k_cache_transposed: bool = False
     qk_norm: bool = False
 

@@ -1,4 +1,4 @@
-"""inference_demo-style CLI: compile / load / generate / accuracy / benchmark.
+"""inference_demo-style CLI: compile / load / generate / accuracy.
 
 TPU-native re-design of the reference CLI
 (reference: src/neuronx_distributed_inference/inference_demo.py — argparse
@@ -11,7 +11,7 @@ Usage:
         --compiled-model-path /tmp/compiled \
         --batch-size 1 --seq-len 1024 --tp-degree 1 \
         --prompt "I believe the meaning of life is" \
-        --benchmark --check-accuracy-mode token-matching
+        --check-accuracy-mode token-matching
 """
 
 from __future__ import annotations
@@ -161,8 +161,8 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument(
         "--serving-spec-ragged", action="store_true",
         help="speculative verification inside the ragged mixed step "
-        "(serving-session config consumed by drivers like bench.py's "
-        "spec-ragged row — the demo itself runs one generate() session): "
+        "(serving-session config — the demo itself runs one generate() "
+        "session): "
         "spec rows carry draft tokens as extra packed query positions, one "
         "mixed dispatch per step serves prefill + decode + spec-verify rows "
         "(requires --serving-ragged, --is-chunked-prefill and "
@@ -172,10 +172,9 @@ def build_parser() -> argparse.ArgumentParser:
 
     run.add_argument(
         "--serving-replicas", type=int, default=1,
-        help="multi-replica router config (runtime/router.py, consumed by "
-        "serving drivers like bench.py's router row — the demo itself runs "
-        "one generate() session): how many single-chip replica sessions "
-        "ServingRouter routes over; 1 = no router layer",
+        help="multi-replica router config (runtime/router.py — the demo "
+        "itself runs one generate() session): how many single-chip replica "
+        "sessions ServingRouter routes over; 1 = no router layer",
     )
     run.add_argument(
         "--router-policy", default="least_loaded",
@@ -188,8 +187,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     run.add_argument(
         "--router-prefill-replicas", type=int, default=0,
-        help="disaggregated prefill tier (router config consumed by serving "
-        "drivers like bench.py's disagg rows): carve this many of "
+        help="disaggregated prefill tier (router config): carve this many of "
         "--serving-replicas out as dedicated prefill replicas feeding "
         "decode replicas over the contained KV hand-off; 0 = no tier "
         "(requires the contiguous cache; docs/SERVING.md)",
@@ -206,8 +204,7 @@ def build_parser() -> argparse.ArgumentParser:
         "counts as a failed attempt and retries (None disables)",
     )
     onoff("router-threading", False, dest="router_threading",
-          help="thread-per-replica router stepping (router config consumed "
-          "by serving drivers like bench.py's router rows): every alive "
+          help="thread-per-replica router stepping (router config): every alive "
           "replica's step() dispatches from a persistent worker pool and "
           "joins at a per-step barrier, so replica device steps overlap "
           "instead of host-serializing; placement/failover/telemetry stay "
@@ -241,8 +238,8 @@ def build_parser() -> argparse.ArgumentParser:
     # workload engine (workload/generator.py; docs/WORKLOADS.md): seeded
     # open-loop traffic generation. --workload-trace-out materializes the
     # reproducible arrival trace as JSON and exits WITHOUT loading a model
-    # — the artifact replays through the WorkloadDriver / the bench
-    # goodput rows (same seed => byte-identical trace, pinned).
+    # — the artifact replays through the WorkloadDriver (same seed =>
+    # byte-identical trace, pinned).
     run.add_argument("--workload-seed", type=int, default=0,
                      help="workload trace seed (same seed => byte-identical "
                           "arrival trace)")
@@ -356,11 +353,9 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--max-new-tokens", type=int, default=64)
 
     # eval
-    run.add_argument("--benchmark", action="store_true")
     run.add_argument("--check-accuracy-mode", default="skip",
                      choices=["skip", "token-matching", "logit-matching"])
     run.add_argument("--divergence-difference-tol", type=float, default=0.001)
-    run.add_argument("--num-runs", type=int, default=5)
     run.add_argument("--skip-warmup", action="store_true")
 
     # observability (reference inference_demo.py:329-334 + profiling)
@@ -822,15 +817,6 @@ def run_inference(args) -> int:
         if not report.passed:
             return 1
 
-    if args.benchmark:
-        from neuronx_distributed_inference_tpu.utils.benchmark import benchmark_sampling
-
-        report = benchmark_sampling(
-            app, input_ids, attention_mask,
-            max_new_tokens=args.max_new_tokens, num_runs=args.num_runs,
-            report_path="benchmark_report.json",
-        )
-        print(json.dumps(report, indent=2))
     return 0
 
 
@@ -886,8 +872,8 @@ def run_image_gen(args) -> int:
 def run_workload_trace(args) -> int:
     """--workload-trace-out: materialize the seeded arrival trace and write
     it as JSON (no model load — trace generation is pure host data). The
-    artifact is the reproducibility handle: archive it beside a bench
-    goodput run and replay it bit-exactly later."""
+    artifact is the reproducibility handle: archive it beside a goodput
+    run and replay it bit-exactly later."""
     from neuronx_distributed_inference_tpu.workload import (
         generate,
         standard_spec,

@@ -124,9 +124,6 @@ class ModelSpec:
     # heterogeneous layer stacks: None = one uniform group (spec-level
     # sliding_window / attention_chunk_size apply)
     layer_groups: Optional[Tuple[LayerGroupSpec, ...]] = None
-    # fused decode MLP kernel (config fused_mlp_kernel_enabled):
-    # None = auto on TPU (single shard), True = force, False = off
-    use_fused_mlp: Optional[bool] = None
     # Granite scalar multipliers (published config keys of the same names):
     # the embedding is scaled, every residual update is scaled, the logits
     # are divided. 1.0 = the plain decoder; nothing is emitted for it.
@@ -373,92 +370,14 @@ def contiguous_decode_attend(
     return attn_out
 
 
-def _plain_weight(entry) -> bool:
-    """True when a projection entry is a plain unquantized, un-LoRA'd,
-    bias-free weight the fused kernels can stream directly."""
-    return (
-        isinstance(entry, dict)
-        and "weight" in entry
-        and "scale" not in entry
-        and "lora_A" not in entry
-        and "bias" not in entry
-    )
-
-
-def _fused_attn_eligible(
-    layer_params, k_cache, v_cache, mask, spec, cos, window, chunk
-) -> bool:
-    """LAYER-level preconditions for the fused decode attention block (the
-    step-level ones — plain decode, no overrides — are certified by
-    run_decoder_layers via ``fused_block_ok``)."""
-    from neuronx_distributed_inference_tpu.ops.decode_block import use_fused_attn_block
-
-    aspec = spec.attn
-    sa = layer_params.get("self_attn", {})
-    K = mask.shape[-2]
-    # the kernel's ACTIVE (in-flight) part is pure causal over the K new
-    # tokens: windowed/chunked models are only eligible at K == 1 (a token
-    # always attends itself; the PRIOR mask carries the window/chunk bounds)
-    plain_flavor = (
-        window is None
-        and chunk is None
-        and not spec.sliding_window
-        and not spec.attention_chunk_size
-    )
-    return (
-        (plain_flavor or K == 1)
-        and not isinstance(k_cache, tuple)  # contiguous cache only
-        # quantized caches ride the TKG kernel's fused dequant instead (the
-        # fused block kernel streams the cache in its storage dtype)
-        and not isinstance(k_cache, QuantizedKV)
-        and spec.bounded_window is None
-        and spec.norm_type == "rmsnorm"
-        and "qkv_proj" in sa
-        and _plain_weight(sa["qkv_proj"])
-        and _plain_weight(sa.get("o_proj"))
-        and not aspec.has_sink
-        and aspec.qkv_shards == 1
-        and k_cache.shape == v_cache.shape
-        and cos.shape[-1] * 2 == aspec.head_dim
-        and use_fused_attn_block(aspec, mask.shape[-2], mask.shape[-1])
-    )
-
-
-def _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn, adapter_ids, fused_ok):
-    """post-attention norm + MLP + residual, with the fused decode-MLP Pallas
-    path when the step and the layer's MLP structure allow it."""
-    mp = layer_params["mlp"]
-    if (
-        fused_ok
-        and mlp_fn is gated_mlp
-        and spec.use_fused_mlp is not False
-        and spec.norm_type == "rmsnorm"
-        and spec.act in ("silu", "gelu", "gelu_pytorch_tanh")
-        and adapter_ids is None
-        and all(_plain_weight(mp.get(k)) for k in ("gate_proj", "up_proj", "down_proj"))
-        # AUTO = OFF (see ops/decode_block.use_fused_attn_block): measured
-        # slower than the XLA fusion at bs=1; force with
-        # fused_mlp_kernel_enabled=True
-        and spec.use_fused_mlp
-    ):
-        from neuronx_distributed_inference_tpu.ops.decode_block import fused_mlp_block
-
-        return fused_mlp_block(
-            hidden,
-            layer_params["post_attention_layernorm"]["weight"],
-            mp["gate_proj"]["weight"],
-            mp["up_proj"]["weight"],
-            mp["down_proj"]["weight"],
-            eps=spec.rms_eps,
-            act=spec.act,
-            interpret=kernel_interpret(),
-        )
+def _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn):
+    """post-attention norm + MLP + residual."""
     residual = hidden
     hidden = apply_norm(
         hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps,
         spec.norm_type,
     )
-    return residual_add(residual, mlp_fn(mp, hidden, spec), spec)
+    return residual_add(residual, mlp_fn(layer_params["mlp"], hidden, spec), spec)
 
 
 def decoder_layer(
@@ -485,9 +404,6 @@ def decoder_layer(
     window: Optional[int] = None,
     chunk: Optional[int] = None,
     flavor_select: Optional[Tuple] = None,
-    # run_decoder_layers certifies the STEP-level fused-kernel preconditions
-    # (plain decode, no rope/mask overrides, no taps/adapters, single shard)
-    fused_block_ok: bool = False,
     # ragged mixed-step descriptors (row_start, row_len, ctx_len), each (R,):
     # attention runs the ragged paged kernel/fallback instead of the
     # per-phase paths (phase == PHASE_MIXED; mask is unused — the kernel
@@ -501,41 +417,6 @@ def decoder_layer(
     kvcache.update_cache_at_layer). Returns (hidden, k_cache, v_cache).
     """
     aspec = spec.attn
-    if fused_block_ok and _fused_attn_eligible(
-        layer_params, k_cache, v_cache, mask, spec, cos, window, chunk
-    ):
-        # fused decode attention block: rmsnorm + fused-QKV + rope + prior/
-        # active attention + o-proj + residual in ONE Pallas pipeline; the
-        # kernel returns k_new/v_new for the normal cache scatter (reference
-        # attention_block_tokengen kernel with update_cache_in_kernel=False,
-        # attention_base.py:1609)
-        from neuronx_distributed_inference_tpu.ops.decode_block import fused_attn_block
-
-        sa = layer_params["self_attn"]
-        hidden, k_new, v_new = fused_attn_block(
-            hidden,
-            layer_params["input_layernorm"]["weight"],
-            sa["qkv_proj"]["weight"],
-            sa["o_proj"]["weight"],
-            cos,
-            sin,
-            k_cache,
-            v_cache,
-            layer_idx,
-            slot_ids,
-            mask,
-            positions,
-            scale=aspec.softmax_scale,
-            eps=spec.rms_eps,
-            n_kv=aspec.num_kv_heads,
-            interpret=kernel_interpret(),
-        )
-        k_cache, v_cache = update_cache_at_layer(
-            k_cache, v_cache, k_new, v_new, layer_idx, slot_ids, positions
-        )
-        return _decoder_layer_mlp(
-            layer_params, hidden, spec, mlp_fn, adapter_ids, fused_block_ok
-        ), k_cache, v_cache
     residual = hidden
     hidden = apply_norm(
         hidden, layer_params["input_layernorm"]["weight"], spec.rms_eps, spec.norm_type
@@ -767,9 +648,7 @@ def decoder_layer(
     hidden = o_project(layer_params["self_attn"], attn_out, aspec, adapter_ids=adapter_ids)
     hidden = residual_add(residual, hidden, spec)
 
-    hidden = _decoder_layer_mlp(
-        layer_params, hidden, spec, mlp_fn, adapter_ids, fused_block_ok
-    )
+    hidden = _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn)
     if spec.cp_enabled and phase == PHASE_CONTEXT_ENCODING:
         from neuronx_distributed_inference_tpu.parallel import context_parallel as cpx
 
@@ -1047,23 +926,6 @@ def run_decoder_layers(
             "per-layer tensor taps require a uniform (single-group) stack"
         )
 
-    # step-level preconditions for the fused decode-layer kernels: plain
-    # contiguous-cache decode, contiguous write positions (no token-tree rope
-    # or mask overrides), no taps/adapters, one model-parallel shard. The
-    # layer-level structure checks happen inside decoder_layer.
-    fused_eligible = (
-        phase != PHASE_CONTEXT_ENCODING
-        and not is_block
-        and not interleaved
-        and inputs.rope_position_ids is None
-        and inputs.mask_override is None
-        and inputs.adapter_ids is None
-        and spec.attention_dp == 1
-        and spec.data_parallel == 1
-        and not spec.cp_enabled
-        and taps_ctx is None
-    )
-
     if prestacked:
         if capture_layers is not None:
             raise NotImplementedError(
@@ -1166,8 +1028,6 @@ def run_decoder_layers(
                     if phase == PHASE_CONTEXT_ENCODING:
                         fs = (tuple(uniq), fl)
                 kw = {}
-                if g_layer is decoder_layer:
-                    kw["fused_block_ok"] = fused_eligible
                 if fs is not None:
                     kw["flavor_select"] = fs
                 elif phase == PHASE_CONTEXT_ENCODING:
@@ -1217,13 +1077,10 @@ def run_decoder_layers(
                           key_valid=key_valid, window=window, chunk=chunk):
                 h, k_c, v_c, cap = carry
                 layer_params, li = xs
-                kw = {}
-                if g_layer is decoder_layer:
-                    kw["fused_block_ok"] = fused_eligible
                 h, k_c, v_c = g_layer(
                     layer_params, h, cos, sin, k_c, v_c, li, mask, slot_ids, positions,
                     spec, phase, g_mlp, key_valid=key_valid, block_inputs=block_inputs,
-                    adapter_ids=inputs.adapter_ids, window=window, chunk=chunk, **kw,
+                    adapter_ids=inputs.adapter_ids, window=window, chunk=chunk,
                 )
                 if cap is not None:
                     hit = (cap_idx == li)[:, None, None, None]
@@ -1333,7 +1190,6 @@ def decode_steps(
     mlp_fn: Callable = gated_mlp,
     layer_fn: Optional[Callable] = None,
     adapter_ids: Optional[jax.Array] = None,
-    unroll: int = 1,
     block_table: Optional[jax.Array] = None,
 ):
     """Run ``num_steps`` whole decode iterations in ONE compiled program.
@@ -1386,11 +1242,11 @@ def decode_steps(
         step_rngs = None
         (cache, last, pos), (tokens, logits) = jax.lax.scan(
             lambda c, _: body(c, None), (cache, last_tokens, positions), None,
-            length=num_steps, unroll=unroll,
+            length=num_steps,
         )
     else:
         (cache, last, pos), (tokens, logits) = jax.lax.scan(
-            body, (cache, last_tokens, positions), step_rngs, unroll=unroll
+            body, (cache, last_tokens, positions), step_rngs
         )
     tokens = jnp.swapaxes(tokens, 0, 1)  # (B, num_steps)
     out_logits = jnp.swapaxes(logits, 0, 1) if spec.output_logits else None

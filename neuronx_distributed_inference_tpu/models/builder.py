@@ -91,7 +91,6 @@ class DecoderModelBuilder:
             use_flash_kernel=tc.attn_kernel_enabled,
             use_packed_heads=tc.attn_packed_kernel_enabled,
             use_tkg_kernel=tc.attn_block_tkg_kernel_enabled,
-            use_fused_block=tc.fused_attn_block_kernel_enabled,
             qkv_shards=self.degree if tc.fused_qkv else 1,
             model_parallel=self.degree,
         )
@@ -123,7 +122,6 @@ class DecoderModelBuilder:
             cast_logits_fp32=tc.cast_logits_fp32,
             attention_scaling=rope_attention_scaling(cfg),
             norm_type=self.norm_type,
-            use_fused_mlp=tc.fused_mlp_kernel_enabled,
         )
         return self._finalize_bounded(spec)
 

@@ -179,7 +179,7 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
     y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
     gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps)
     hidden = residual_add(hidden, linear(m["out_proj"], gated), spec)
-    hidden = _decoder_layer_mlp(lp, hidden, spec, mlp_fn, None, False)
+    hidden = _decoder_layer_mlp(lp, hidden, spec, mlp_fn)
     return hidden, ssm.RecurrentState(conv=conv, ssm=new_ssm)
 
 

@@ -55,9 +55,6 @@ class AttnSpec:
     # decode (TKG) attention kernel (config attn_block_tkg_kernel_enabled):
     # None = auto on TPU, True = force, False = native path
     use_tkg_kernel: Optional[bool] = None
-    # fused decode attention BLOCK kernel (norm+QKV+rope+attention+o-proj in
-    # one pass; config fused_attn_block_kernel_enabled) — same tri-state
-    use_fused_block: Optional[bool] = None
     # model-parallel degree of the rank-interleaved fused-qkv layout
     # (builder._fuse_qkv); 1 when fused_qkv is off
     qkv_shards: int = 1

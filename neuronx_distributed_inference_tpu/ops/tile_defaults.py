@@ -6,9 +6,8 @@ Every Pallas kernel in ``ops/`` resolves its default tile sizes through
 a ``provenance`` field: ``hand_picked`` entries mirror the historical
 in-code constants (the kernel audit errors if they drift apart — see
 KERN704 in ``analysis/kernel_audit.py``); a hardware session that re-runs
-the ``scripts/prefill_profile.py`` / ``scripts/decode_scaling.py`` sweeps
-promotes them to ``measured``, at which point the table — not this file's
-fallbacks — is the source of truth.
+the ``scripts/decode_scaling.py`` sweeps promotes them to ``measured``, at
+which point the table — not this file's fallbacks — is the source of truth.
 
 This module must stay import-light (json + pathlib only): the kernels pull
 defaults at trace time and must not drag the analysis package, jax-extras,

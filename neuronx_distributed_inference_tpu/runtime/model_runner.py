@@ -19,7 +19,6 @@ token_generation, ...; reference model_wrapper.py:32-37). Responsibilities:
 
 from __future__ import annotations
 
-import os
 from contextlib import contextmanager
 from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
@@ -343,7 +342,6 @@ class SubModelRunner:
                 bucket=bucket,
                 mlp_fn=self.mlp_fn,
                 layer_fn=self.layer_fn,
-                unroll=int(os.environ.get("NXDI_TPU_DECODE_UNROLL", "1")),
             )
             tag = f"{self.tag}:decode[{num_steps},{bucket}]"
             state = {"traced": False}

@@ -15,7 +15,7 @@ Exposition:
 - :meth:`MetricsRegistry.prometheus_text` — Prometheus text format 0.0.4
   (scrape it from any HTTP handler, or dump to a file).
 - :meth:`MetricsRegistry.snapshot` — a JSON-able dict
-  (``--metrics-out`` in inference_demo/bench; pretty-printed by
+  (``--metrics-out`` in inference_demo; pretty-printed by
   ``scripts/metrics_report.py``).
 
 Histograms use FIXED bucket bounds chosen at registration (cumulative
@@ -162,8 +162,7 @@ class Histogram:
         """Bucket-resolution quantile estimate (upper bound of the bucket the
         q-th observation falls in; +Inf tail reports the largest finite
         bound). None when empty. Coarse by design — exact percentiles come
-        from traces, not histograms (utils/benchmark + bench serving rows
-        use per-request traces)."""
+        from per-request traces, not histograms."""
         if self.count == 0:
             return None
         rank = q * self.count
@@ -381,7 +380,7 @@ def catalog_drift(
     return undocumented, unregistered
 
 
-# process-default registry: the demo/bench ``--metrics-out`` target and the
+# process-default registry: the demo's ``--metrics-out`` target and the
 # registry :func:`..tracing.default_session` records into
 _DEFAULT = MetricsRegistry()
 

@@ -9,7 +9,7 @@ Rule (ISSUE 21):
   one fixed directory inside the checkout, ``.bench_cache/xla``
   (git-ignored). Never a path made from a temporary name, a pid or the time.
 
-``load()``/``compile()``, ``bench.py`` and ``chip_smoke.py`` all call
+``load()``/``compile()``, ``benchmark/harness/system.py`` and ``chip_smoke.py`` all call
 :func:`configure_compile_cache`; it is the only ``set_cache_dir`` call site.
 """
 

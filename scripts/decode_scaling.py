@@ -1,19 +1,12 @@
 #!/usr/bin/env python
-"""Batched-decode efficiency study + the promised fused-kernel revisit at
-bs >= 4 (VERDICT r4 next #5: explain the bs=4 gap to the weights-read-once
-ideal and re-measure ops/decode_block.py in the batch regime its r4
-deferral named).
-
-For bs in {1, 2, 4, 8}, bf16-1B decode (contiguous cache, tkg bucket 512):
-- XLA-fused native path (the default) tok/s;
-- fused decode-layer Pallas kernels FORCED on (attention block + MLP block,
-  fused_attn_block_kernel_enabled=True/fused_mlp_kernel_enabled=True);
-- the HBM roofline ideal: decode is weight-bandwidth-bound, so
-  ideal step = (weight bytes + bs * kv bytes/step) / 819 GB/s and
-  ideal tok/s = bs / step.
+"""Kernel tile sweeps for the decode path, for a session on the chip: the
+TKG decode-attention kernel across its legal kv tiles (``bs``) and the int4
+fused-dequant matmul across its legal output tiles (``bn``), each at a
+committed registry shape. Candidates come from the kernel audit's
+``legal_tiles``; a winner measured on hardware is what gets promoted into
+``analysis/tuning_table.json`` with provenance ``measured``.
 
 Run on hardware: python scripts/decode_scaling.py
-CPU smoke:       python scripts/decode_scaling.py --tiny --cpu
 """
 
 import json
@@ -24,32 +17,6 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import numpy as np
-
-HBM_GBS = 819e9  # v5e
-
-
-def _weight_bytes(hf, dtype_bytes=2):
-    """Per-STEP streamed weight bytes: layers + lm_head. The input embed
-    table is a bs-row gather, not a stream — counting it would overstate
-    the roofline for untied-embedding models."""
-    H, I, L, V = (hf["hidden_size"], hf["intermediate_size"],
-                  hf["num_hidden_layers"], hf["vocab_size"])
-    Hq, Hkv = hf["num_attention_heads"], hf["num_key_value_heads"]
-    D = hf.get("head_dim", H // Hq)
-    per_layer = H * Hq * D + 2 * H * Hkv * D + Hq * D * H + 3 * H * I
-    lm_head = V * H
-    return (L * per_layer + lm_head) * dtype_bytes
-
-
-def measure_bs(app, bs, hf, prompt_len=128, gen=256):
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, hf["vocab_size"] - 10, size=(bs, prompt_len))
-    mask = np.ones_like(ids)
-    app.generate(ids, mask, max_new_tokens=gen)  # compile/warm
-    t0 = time.time()
-    out = app.generate(ids, mask, max_new_tokens=gen)
-    dt = time.time() - t0
-    return out.num_generated * bs / dt
 
 
 def sweep_tkg_tiles(bucket=512, dtype="bfloat16", B=1, n=20):
@@ -134,60 +101,11 @@ def sweep_quant_matmul_tiles(shape_class="k2048_n8192", B=8, n=20,
     return rows
 
 
-def run(tiny=False):
-    import bench
-
-    hf = dict(bench.TINY if tiny else bench.LLAMA_1B)
-    seq = 64 if tiny else 1024
-    ce = [16] if tiny else [128]
-    tkg = [64] if tiny else [512]
-    prompt, gen = (8, 8) if tiny else (128, 256)
-    wb = _weight_bytes(hf)
-    out = {"weight_gb": round(wb / 1e9, 2), "rows": []}
-    for bs in (1, 2, 4, 8):
-        row = {"bs": bs}
-        for name, extra in (
-            ("xla", {}),
-            ("fused_blocks", dict(
-                fused_attn_block_kernel_enabled=True,
-                fused_mlp_kernel_enabled=True,
-            )),
-        ):
-            app = bench.build_app(
-                hf, batch=bs, seq_len=seq, ce_buckets=ce, tkg_buckets=tkg,
-                quantized=False,
-                cache_key=(None if tiny else "bf16_1b"),
-                extra_tpu=extra,
-            )
-            row[f"{name}_tok_s"] = round(measure_bs(app, bs, hf, prompt, gen), 1)
-            del app
-        # per-step KV traffic: read bs * pos * Hkv * D * 2 streams * 2B —
-        # use the midpoint position of the measured run
-        Hkv = hf["num_key_value_heads"]
-        D = hf.get("head_dim", hf["hidden_size"] // hf["num_attention_heads"])
-        kv = bs * (prompt + gen / 2) * Hkv * D * 2 * 2
-        ideal_step = (wb + kv) / HBM_GBS
-        row["roofline_tok_s"] = round(bs / ideal_step, 1)
-        row["xla_pct_of_roofline"] = round(
-            100 * row["xla_tok_s"] / row["roofline_tok_s"], 1
-        )
-        out["rows"].append(row)
-    if not tiny:
-        # kernel-level kv-tile sweep over the gate-legal candidates only
-        out["tkg_tile_sweep_kv512"] = sweep_tkg_tiles(bucket=512)
-        # int4 quant-matmul output-tile sweep at the committed 1B decode
-        # shape (ISSUE 17) — same legal_tiles-sourced candidate contract
-        out["quant_matmul_tile_sweep_1b"] = sweep_quant_matmul_tiles()
-    return out
-
-
 def main():
-    if "--cpu" in sys.argv:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-    res = run(tiny="--tiny" in sys.argv)
-    print(json.dumps(res), flush=True)
+    print(json.dumps({
+        "tkg_tile_sweep_kv512": sweep_tkg_tiles(bucket=512),
+        "quant_matmul_tile_sweep_1b": sweep_quant_matmul_tiles(),
+    }), flush=True)
 
 
 if __name__ == "__main__":

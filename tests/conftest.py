@@ -125,3 +125,49 @@ def make_random_hf_state_dict(cfg, seed=0):
         sd[p + "input_layernorm.weight"] = np.ones(H, np.float32)
         sd[p + "post_attention_layernorm.weight"] = np.ones(H, np.float32)
     return sd
+
+
+class LogitSpy:
+    """Records (request slot -> logits at its real positions) of every
+    dispatch of the token-generation runner (a row's slot is its seq_id)."""
+
+    def __init__(self, app):
+        self.app, self.rows = app, []
+        self.runner = app.token_generation_model
+        self.orig = self.runner._fn
+
+    def __enter__(self):
+        def spy(params, cache, inputs, rng=None):
+            out = self.orig(params, cache, inputs, rng)
+            self.rows.append((np.asarray(inputs.seq_ids), np.asarray(inputs.position_ids),
+                              np.asarray(inputs.slot_mapping) if inputs.slot_mapping is not None
+                              else None, np.asarray(out.logits)))
+            return out
+
+        self.runner._fn = spy
+        return self
+
+    def __exit__(self, *exc):
+        self.runner._fn = self.orig
+
+    def at(self, slot: int, position: int):
+        """The LAST logits served for (slot, position) (a re-prefill after
+        preemption serves a position twice)."""
+        found = None
+        for seq_ids, pos, sm, logits in self.rows:
+            # the chunk program's rows are compact and carry their slot in
+            # seq_ids; the decode program's row r is slot r (seq_ids[r] == r)
+            for row in np.flatnonzero(seq_ids == slot):
+                for q in range(pos.shape[1]):
+                    if pos[row, q] == position and (sm is None or sm[row, q] >= 0):
+                        found = logits[row, q]
+        assert found is not None, (slot, position)
+        return found
+
+
+def drain(session, limit=200):
+    for _ in range(limit):
+        if not (session.active or session._readmit):
+            return
+        session.step()
+    raise AssertionError("the session did not drain")

@@ -2,11 +2,10 @@
 shape with the kernels FORCED on (so they run interpreted — the same
 dispatch, census and comparison code the chip run takes), the script itself
 refuses to pass off the chip, and the pieces that keep a run from missing
-the chip in silence (the compile-cache helper, bench's device check and exit
-code, a bench parent that stays off the backend) hold.
+the chip in silence (the compile-cache helper) hold; ``build`` ends with the
+``TpuConfig`` each of its four uses asks for.
 """
 
-import json
 import os
 import shutil
 import subprocess
@@ -17,11 +16,15 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-import bench  # noqa: E402
 import chip_smoke as cs  # noqa: E402
 
-# head_dim 64 is the narrowest the kernels take; everything else stays TINY
-ATTRS = dict(bench.TINY, head_dim=64)
+# head_dim 64 is the narrowest the kernels take; everything else is tiny
+ATTRS = dict(
+    model_type="llama", hidden_size=64, intermediate_size=128, num_attention_heads=4,
+    num_key_value_heads=2, num_hidden_layers=2, vocab_size=128, rms_norm_eps=1e-5,
+    rope_theta=10000.0, max_position_embeddings=256, hidden_act="silu",
+    tie_word_embeddings=False, head_dim=64,
+)
 FORCE = dict(attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True)
 GEN = dict(batch=2, prompt_lens=(128, 97), new_tokens=8, seq_len=256,
            ce_buckets=(128,), tkg_buckets=(256,))
@@ -155,45 +158,46 @@ def test_cache_helper_unset_uses_the_fixed_checkout_dir(monkeypatch, cache_calls
     assert cache_calls == [fixed, "/from/tpu_config"]
 
 
-# ---- bench: no way to miss the chip ---------------------------------------
+# ---- build: the one construction the phases share --------------------------
 
 
-def test_bench_measuring_path_refuses_the_cpu():
-    with pytest.raises(RuntimeError, match="measures on a TPU"):
-        bench._require_chip()
-    with pytest.raises(RuntimeError, match="measures on a TPU"):
-        bench._device_spec(tiny=False)
-    assert bench._device_spec(tiny=True) is None  # tests' path: counts
-
-
-@pytest.mark.parametrize(
-    "points,failed",
-    [
-        ({"a": {"decode_tok_s": 1.0}}, False),
-        ({"a": {"decode_tok_s": 1.0}, "b": {"error": "boom"}}, True),
-        ({"a": {"decode_tok_s": 1.0}, "b": {"skipped_budget": True}}, True),
-    ],
-)
-def test_bench_suite_exit_code_follows_errors(points, failed):
-    assert bench.suite_failed(points) is failed
-
-
-def test_bench_parent_never_initialises_a_backend(tmp_path):
-    """Suite mode starts one child per point and a chip belongs to one
-    process: on every route the parent takes besides run_suite itself
-    (summary line, --metrics-out, --ops-port) no backend may come up."""
-    out = tmp_path / "metrics.json"
-    code = (
-        "import sys, bench\n"
-        f"sys.argv = ['bench.py', '--metrics-out', {str(out)!r}, '--ops-port', '0']\n"
-        "with bench._ops_server() as ops:\n"
-        "    assert ops is not None\n"
-        "    bench._emit({})\n"
-        "    bench._dump_metrics(bench._metrics_out_path())\n"
-        "from jax._src import xla_bridge\n"
-        "assert not xla_bridge.backends_are_initialized(), 'backend initialised'\n"
-    )
-    proc = _run([sys.executable, "-c", code], ROOT)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert json.loads(proc.stdout.strip().splitlines()[-1])["value"] is None
-    assert out.exists()
+@pytest.mark.parametrize("case", ["unpaged", "paged", "weights_from", "unloaded"])
+def test_build_ends_with_the_config_it_was_asked_for(case, generated):
+    base, _ = generated
+    if case == "unpaged":
+        app = cs.build(ATTRS, GEN, 3, extra=dict(attn_kernel_enabled=False), load=False)
+    elif case == "paged":
+        app = cs.build(ATTRS, SERVE, 3, paged=True, load=False)
+    elif case == "weights_from":
+        app = cs.build(ATTRS, SERVE, 3, paged=True, weights_from=base,
+                       extra=dict(serving_ragged=True))
+    else:
+        app = cs.build(ATTRS, GEN, 3, load=False)
+    tc = app.config.tpu_config
+    # every application: bf16, the fused QKV layout, logits out, the guard on
+    assert (tc.dtype, tc.fused_qkv, tc.output_logits, tc.retrace_guard, tc.seed) == (
+        "bfloat16", True, True, True, 3)
+    assert tc.quantized is False and tc.tp_degree == 1
+    if case in ("paged", "weights_from"):
+        assert (tc.batch_size, tc.seq_len) == (SERVE["max_seqs"], SERVE["seq_len"])
+        assert tc.context_encoding_buckets == tc.token_generation_buckets == [SERVE["seq_len"]]
+        assert tc.is_block_kv_layout and tc.is_chunked_prefill and tc.is_continuous_batching
+        assert (tc.pa_num_blocks, tc.pa_block_size) == (SERVE["blocks"], SERVE["block_size"])
+        cp = tc.chunked_prefill_config
+        assert (cp.max_num_seqs, cp.kernel_q_tile_size) == (SERVE["max_seqs"], SERVE["q_tile"])
+    else:
+        assert (tc.batch_size, tc.seq_len) == (GEN["batch"], GEN["seq_len"])
+        assert tc.context_encoding_buckets == list(GEN["ce_buckets"])
+        assert tc.token_generation_buckets == list(GEN["tkg_buckets"])
+        assert not tc.is_block_kv_layout and not tc.is_chunked_prefill
+    if case == "unpaged":
+        assert tc.attn_kernel_enabled is False  # ``extra`` reaches the config
+    if case == "weights_from":
+        assert tc.serving_ragged is True
+        assert app.params is base.params and app._pspecs is base._pspecs
+        # a cache of its own, in the paged layout: the pool and its garbage block
+        assert app.kv_cache is not base.kv_cache
+        assert app.kv_cache.k.shape[1] == SERVE["blocks"] + 1
+        assert app.kv_cache.k.shape != base.kv_cache.k.shape
+    else:
+        assert app.params is None and app.kv_cache is None

@@ -375,12 +375,19 @@ def test_legal_tiles_flash_full_grid():
     assert {"bq": 512, "bkv": 512} in tiles
 
 
-def test_legal_tiles_prunes_over_budget():
-    # fused MLP at I=8192: ti_cap=1024 would put the double-buffered weight
-    # windows over the 16 MiB budget — it must NOT be emitted
-    tiles = ka.legal_tiles("fused_mlp_block", "i8192", "bfloat16")
-    assert {"ti_cap": 1024} not in tiles
-    assert {"ti_cap": 512} in tiles
+def test_legal_tiles_prunes_over_budget(monkeypatch):
+    # int4 matmul at N=8192 under a wider sweep than the registry commits:
+    # bn=8192 (the whole width in one step) would put the double-buffered
+    # weight windows over the 16 MiB budget — it must NOT be emitted
+    import dataclasses
+
+    from neuronx_distributed_inference_tpu.analysis import kernel_registry as kr
+
+    spec = next(s for s in kr.REGISTRY if s.name == "quant_matmul")
+    wide = dataclasses.replace(spec, sweep=(("bn", (512, 4096, 8192)),))
+    monkeypatch.setattr(kr, "REGISTRY", tuple(wide if s is spec else s for s in kr.REGISTRY))
+    tiles = ka.legal_tiles("quant_matmul", "k2048_n8192", "bfloat16")
+    assert tiles == [{"bn": 512}, {"bn": 4096}]
 
 
 def test_legal_tiles_enforces_packing_contract():
@@ -405,13 +412,28 @@ def test_legal_tiles_unknown_kernel_raises():
 
 
 def test_sweep_scripts_source_candidates_from_legal_tiles():
-    """The sweep scripts carry no hand-built tile list: their candidate
-    sets come from legal_tiles (the dedupe this PR promised)."""
+    """The sweep script carries no hand-built tile list: its candidate
+    sets come from legal_tiles."""
     import pathlib
 
     root = pathlib.Path(__file__).resolve().parent.parent
-    for rel in ("scripts/prefill_profile.py", "scripts/decode_scaling.py"):
-        assert "legal_tiles" in (root / rel).read_text(), rel
+    assert "legal_tiles" in (root / "scripts/decode_scaling.py").read_text()
+
+
+def test_quant_matmul_tile_sweep():
+    """The int4 quant-matmul bn sweep measures every gate-legal candidate
+    from legal_tiles at the committed 1B shape — interpret mode on CPU, the
+    identical code path hardware runs compiled."""
+    import pathlib
+    import sys
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent / "scripts"))
+    import decode_scaling
+
+    sweep = decode_scaling.sweep_quant_matmul_tiles(n=1, interpret=True)
+    assert set(sweep) == {"bn128", "bn256", "bn512"}
+    for bn, row in sweep.items():
+        assert row.get("us", 0) > 0, (bn, row)
 
 
 # ---------------------------------------------------------------------------

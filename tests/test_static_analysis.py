@@ -1260,11 +1260,10 @@ def test_cost504_detects_regime_flip(tmp_path):
 
 def test_device_model_projections():
     """The analytic roofline: registry resolution, the committed 1B/8B
-    numbers PERF.md cites, and the dtype/width monotonicities the bench
-    rows rely on."""
+    numbers, and the dtype/width monotonicities."""
     from neuronx_distributed_inference_tpu.analysis import device_model as dm
 
-    # device_kind resolution (the bench's device strings)
+    # device_kind resolution (jax's device strings)
     assert dm.resolve_device("TPU v5 lite0").name == "v5e"
     assert dm.resolve_device("TPU v4").name == "v4"
     assert dm.resolve_device("cpu") is None
@@ -1289,86 +1288,6 @@ def test_device_model_projections():
     # prefill: compute-bound at real sequence lengths
     pf = dm.prefill_projection(dm.LLAMA_1B, batch=1, seq=8192)
     assert pf["bound"] == "flops" and pf["t_pass_s"] > 0
-    # every bench row the suite measures has a projection model, and the
-    # model's shape (batch / kv bucket / dtypes) matches what run_point's
-    # live projection derives from the SAME suite params — the two
-    # projected_tok_s sources (bench rows vs --compare/PERF tables) can
-    # never silently diverge
-    import bench
-
-    params = bench._suite_params(tiny=False)
-    assert set(dm.BENCH_ROW_MODELS) == set(params)
-    for name, row in dm.BENCH_ROW_MODELS.items():
-        p = params[name]
-        if "serving" in p:
-            s = p["serving"]
-            if "router" in p:
-                exp_batch = max(
-                    1, p["router"]["n_requests"] // p["router"]["replicas"]
-                )
-            else:
-                exp_batch = s["max_seqs"]
-            exp_kv = s["seq"]
-        else:
-            ctx = p["prompt"] + p["gen"]
-            exp_kv = min([b for b in p["tkg"] if b >= ctx] or [max(p["tkg"])])
-            exp_batch = p["batch"]
-        assert row["batch"] == exp_batch, name
-        assert row["kv_width"] == exp_kv, name
-        assert row["weight_dtype"] == (p.get("extra_tpu") or {}).get(
-            "weight_dtype", "int8" if p["quantized"] else "bfloat16"
-        ), name
-        assert row["kv_dtype"] == (p.get("extra_tpu") or {}).get(
-            "kv_cache_dtype", "bfloat16"
-        ), name
-    for key, row_name, _recorded in dm.COMPARE_KEYS:
-        assert row_name in dm.BENCH_ROW_MODELS
-
-
-def test_cli_compare_report_exits_zero(tmp_path, capsys):
-    """--compare: the offline measured-vs-projected report over a bench
-    summary file — per-row error lines, exit 0 (informational), both the
-    raw summary and the driver-wrapper ({"parsed": ...}) formats."""
-    import json
-
-    from neuronx_distributed_inference_tpu.analysis.__main__ import main
-
-    summary = {
-        "value": 248.8, "int8_1b_tok_s": 410.1, "serving_tok_s": 113.8,
-        "device": "TPU v5 lite0",
-    }
-    p = tmp_path / "BENCH_r99.json"
-    p.write_text(json.dumps({"rc": 0, "parsed": summary}))
-    rc = main(["--compare", str(p)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "bf16_1b_bs1" in out and "serving_1b_int8" in out
-    assert "v5e" in out
-    # measured 248.8 vs the 329 ceiling: ~-24%
-    assert "-24" in out
-    # raw-summary format parses identically
-    p2 = tmp_path / "raw.json"
-    p2.write_text(json.dumps(summary))
-    assert main(["--compare", str(p2)]) == 0
-    capsys.readouterr()
-    # a summary that RECORDS its own projection (the router row's
-    # mesh-scaled ceiling) wins over the static table — the bench row and
-    # the offline report can never disagree about one run
-    p3 = tmp_path / "recorded.json"
-    p3.write_text(json.dumps({
-        "router_tok_s": 4000.0, "router_projected_tok_s": 4782.0,
-        "device": "TPU v5 lite0",
-    }))
-    assert main(["--compare", str(p3)]) == 0
-    out = capsys.readouterr().out
-    assert "4782.0" in out and "(recorded)" in out
-    assert "-16" in out  # 4000/4782 - 1, not an impossible +67% vs 2391
-    # --compare is standalone: combining it with gate flags must error
-    # (exit 2), never silently skip the gate
-    with pytest.raises(SystemExit) as exc:
-        main(["--compare", str(p2), "--json"])
-    assert exc.value.code not in (0, None)
-    assert "standalone" in capsys.readouterr().err
 
 
 def _hot_path_snippet(omit=()):

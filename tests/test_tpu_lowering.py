@@ -197,83 +197,7 @@ def test_lower_paged_flash(B, Hkv):
 
 
 # ---------------------------------------------------------------------------
-# fused decode-layer kernels (attention block + MLP block)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("B", [1, 4])
-@pytest.mark.parametrize("K", [1, 4])
-def test_lower_fused_attn_block(B, K):
-    from neuronx_distributed_inference_tpu.ops.decode_block import fused_attn_block
-
-    L, Hq, Hkv, D, H = 2, 8, 2, 64, 512
-    bucket, S_max = 512, 1024
-    x = sds((B, K, H), jnp.bfloat16)
-    gamma = sds((H,), jnp.float32)
-    wqkv = sds((H, (Hq + 2 * Hkv) * D), jnp.bfloat16)
-    wout = sds((Hq * D, H), jnp.bfloat16)
-    cs = sds((B, K, D // 2), jnp.float32)
-    cache = sds((L, B + 1, S_max, Hkv, D), jnp.bfloat16)
-    li = sds((), jnp.int32)
-    sl = sds((B,), jnp.int32)
-    mask = sds((B, 1, K, bucket), jnp.bool_)
-    pos = sds((B, K), jnp.int32)
-    fn = functools.partial(
-        fused_attn_block, scale=D**-0.5, eps=1e-5, n_kv=Hkv, interpret=False
-    )
-    lower_tpu(
-        lambda *a: fn(*a), x, gamma, wqkv, wout, cs, cs, cache, cache, li, sl,
-        mask, pos,
-    )
-
-
-@pytest.mark.parametrize("B,K", [(1, 1), (4, 4)])
-def test_lower_fused_mlp_block(B, K):
-    from neuronx_distributed_inference_tpu.ops.decode_block import fused_mlp_block
-
-    H, I = 512, 1024
-    x = sds((B, K, H), jnp.bfloat16)
-    gamma = sds((H,), jnp.float32)
-    wg = sds((H, I), jnp.bfloat16)
-    wd = sds((I, H), jnp.bfloat16)
-    fn = functools.partial(fused_mlp_block, eps=1e-5, act="silu", interpret=False)
-    lower_tpu(lambda x, g, a, b, c: fn(x, g, a, b, c), x, gamma, wg, wg, wd)
-
-
-def test_lower_fused_blocks_bench_shapes():
-    """The exact 1B bench decode shapes with the fused kernels on."""
-    from neuronx_distributed_inference_tpu.ops.decode_block import (
-        fused_attn_block,
-        fused_mlp_block,
-    )
-
-    L, Hq, Hkv, D, H, I = 16, 32, 8, 64, 2048, 8192
-    for bucket in (512, 1024):
-        x = sds((1, 1, H), jnp.bfloat16)
-        gamma = sds((H,), jnp.float32)
-        wqkv = sds((H, (Hq + 2 * Hkv) * D), jnp.bfloat16)
-        wout = sds((Hq * D, H), jnp.bfloat16)
-        cs = sds((1, 1, D // 2), jnp.float32)
-        cache = sds((L, 2, 1024, Hkv, D), jnp.bfloat16)
-        fn = functools.partial(
-            fused_attn_block, scale=D**-0.5, eps=1e-5, n_kv=Hkv, interpret=False
-        )
-        lower_tpu(
-            lambda *a: fn(*a), x, gamma, wqkv, wout, cs, cs, cache, cache,
-            sds((), jnp.int32), sds((1,), jnp.int32),
-            sds((1, 1, 1, bucket), jnp.bool_), sds((1, 1), jnp.int32),
-        )
-    fnm = functools.partial(fused_mlp_block, eps=1e-5, act="silu", interpret=False)
-    lower_tpu(
-        lambda x, g, a, b, c: fnm(x, g, a, b, c),
-        sds((1, 1, H), jnp.bfloat16), sds((H,), jnp.float32),
-        sds((H, I), jnp.bfloat16), sds((H, I), jnp.bfloat16),
-        sds((I, H), jnp.bfloat16),
-    )
-
-
-# ---------------------------------------------------------------------------
-# bench program set — the EXACT kernel shapes bench.py drives
+# the 1B program set — the kernel shapes chip_smoke.py drives
 # (llama-3.2-1B: Hq=32, Hkv=8, D=64; prefill 128/512; decode buckets 512/1024)
 # ---------------------------------------------------------------------------
 
