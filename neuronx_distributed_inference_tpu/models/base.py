@@ -330,7 +330,7 @@ def contiguous_decode_attend(
     (with attention-DP batch sharding when active). Shared by decoder_layer
     and the EAGLE3 draft layer."""
     from neuronx_distributed_inference_tpu.ops.decode_attention import (
-        tkg_decode_attention,
+        dispatch_tkg_decode,
         use_tkg_kernel,
     )
 
@@ -347,10 +347,9 @@ def contiguous_decode_attend(
         # decode/speculation attention straight off the stacked cache —
         # no bucket-slice copy, no repeat_kv broadcast (reference TKG
         # kernel, attention_base.py:1467)
-        return tkg_decode_attention(
+        return dispatch_tkg_decode(
             q, k_cache, v_cache, layer_idx, mask, sink,
             scale=aspec.softmax_scale,
-            n_kv=aspec.num_kv_heads,
             interpret=kernel_interpret(),
         )
     if spec.attention_dp > 1 or spec.data_parallel > 1:
@@ -471,7 +470,8 @@ def decoder_layer(
 
         slot_mapping, block_table, kv_limit = block_inputs
         k_cache, v_cache = update_block_cache_at_layer(
-            k_cache, v_cache, k, v, layer_idx, slot_mapping
+            k_cache, v_cache, k, v, layer_idx, slot_mapping,
+            packed=ragged_rows is not None,
         )
     else:
         if bounded:
@@ -533,10 +533,14 @@ def decoder_layer(
     elif is_block:
         from neuronx_distributed_inference_tpu.ops.paged_flash_attention import (
             _use_paged_flash,
-            paged_flash_attention,
+            dispatch_paged_flash,
         )
 
         Sq = q.shape[1]
+        # the paged kernels launch per head shard of the mesh (no collective
+        # inside); attention-DP shards the BATCH around the attention
+        # instead, and keeps the native path
+        dp_shards = spec.attention_dp * spec.data_parallel
         # the paged kernel implements the plain causal+prefix mask only: the
         # MODEL must have no windowed/chunked attention anywhere, including
         # inside layer groups (a group's mask never reaches the kernel)
@@ -551,7 +555,12 @@ def decoder_layer(
                 )
             )
         )
-        if sink is None and plain_model and _use_paged_flash(aspec, Sq):
+        if (
+            sink is None
+            and plain_model
+            and dp_shards == 1
+            and _use_paged_flash(aspec, Sq)
+        ):
             # chunked/prefix prefill rides the paged flash kernel: blocks are
             # DMA'd straight from the cache via the block table — no gather
             # materialization (reference flash_pa_with_schedule.py:157). A
@@ -566,7 +575,7 @@ def decoder_layer(
                 k_arr, v_arr = k_cache, v_cache
             k_l = jax.lax.dynamic_index_in_dim(k_arr, layer_idx, axis=0, keepdims=False)
             v_l = jax.lax.dynamic_index_in_dim(v_arr, layer_idx, axis=0, keepdims=False)
-            attn_out = paged_flash_attention(
+            attn_out = dispatch_paged_flash(
                 q, k_l, v_l, block_table, positions, kv_limit,
                 scale=aspec.softmax_scale,
                 n_rep=aspec.num_heads // aspec.num_kv_heads,
@@ -575,13 +584,12 @@ def decoder_layer(
             )
         else:
             from neuronx_distributed_inference_tpu.ops.decode_attention import (
-                paged_tkg_decode_attention,
+                dispatch_paged_tkg_decode,
                 use_tkg_kernel,
             )
 
             bs = k_cache.shape[3]  # (L, NB+1, Hkv, bs, D) head-major
             width_ok = mask.shape[-1] == block_table.shape[1] * bs
-            dp_shards = spec.attention_dp * spec.data_parallel
             if (
                 dp_shards == 1
                 and width_ok
@@ -591,10 +599,9 @@ def decoder_layer(
                 # decode/speculation off the paged cache: blocks DMA'd via the
                 # block table — no gather materialization (reference block TKG
                 # mega kernel, attention_base.py:1609)
-                attn_out = paged_tkg_decode_attention(
+                attn_out = dispatch_paged_tkg_decode(
                     q, k_cache, v_cache, layer_idx, block_table, mask, sink,
                     scale=aspec.softmax_scale,
-                    n_kv=aspec.num_kv_heads,
                     interpret=kernel_interpret(),
                 )
             else:

@@ -59,9 +59,12 @@ class AttnSpec:
     # (builder._fuse_qkv); 1 when fused_qkv is off
     qkv_shards: int = 1
     # full model-parallel degree (tp*ep). pallas_call carries no GSPMD
-    # partitioning rule, so with sharded operands XLA replicates them
-    # (all-gathering the head-sharded cache per layer per step) — the kernel
-    # AUTO paths therefore require degree 1; force-enable opts in regardless.
+    # partitioning rule, so the kernels that read a cache (paged flash, TKG,
+    # ragged) are launched once per head shard of the mesh
+    # (parallel/sharding.shard_over_heads) and their gates ask only that both
+    # head counts divide this degree; the contiguous flash prefill has no
+    # such launch and its AUTO path still requires degree 1
+    # (ops/kernel_mode.heads_divide, single_shard).
     model_parallel: int = 1
     # clamp qkv projection outputs to [-clip, clip] (DBRX clip_qkv)
     qkv_clip: Optional[float] = None

@@ -21,8 +21,9 @@ Device side (pure functions used inside the jitted step):
   speculation widths, so that the layer scan's cache carry keeps the
   row-major layout a Pallas operand takes and the step holds no copy of the
   pool; the head in the WINDOW (``(H, D)``, 8x fewer index rows) for prefill
-  chunks and whenever the head axis is sharded
-  (:func:`update_block_cache_at_layer` says which and why).
+  chunks where a device holds at least 8 heads
+  (:func:`update_block_cache_at_layer` says which and why). On a
+  head-sharded mesh the first form runs per shard, each writing its own heads.
 - decode reads gather blocks by the per-sequence ``block_table`` and view
   them as a contiguous (B, max_blocks*block_size) cache — logical position
   order is preserved, so the normal decode masks apply unchanged.
@@ -49,9 +50,24 @@ from neuronx_distributed_inference_tpu.modules.kvcache import (
     layer_dequant_factors,
 )
 from neuronx_distributed_inference_tpu.ops.kernel_mode import TKG_MAX_Q_LEN
-from neuronx_distributed_inference_tpu.parallel.mesh import MODEL_AXES, ambient_mesh
+from neuronx_distributed_inference_tpu.parallel.mesh import (
+    AXIS_DDP,
+    AXIS_DP,
+    MODEL_AXES,
+    ambient_mesh,
+)
+from neuronx_distributed_inference_tpu.parallel.sharding import (
+    head_shard_degree,
+    shard_over_heads,
+)
 
 GARBAGE_BLOCK = 0  # block id 0 reserved for invalid-slot writes
+
+#: fewest heads a device must hold for the paged KV write to put the head in
+#: the scatter's WINDOW: the chip's tile is (8, 128), and under a window of
+#: fewer heads than sublanes the TPU compiler re-lays the whole pool around
+#: every layer's scatter (update_block_cache_at_layer)
+WINDOW_MIN_HEADS = 8
 
 
 def prefix_chain_keys(tokens: np.ndarray, block_size: int) -> List[bytes]:
@@ -160,13 +176,14 @@ def block_cache_spec(quantized: bool = False):
     return BlockKVCache(k=spec, v=spec)
 
 
-def _heads_sharded() -> bool:
-    """Whether the cache's head axis (``block_cache_spec``: MODEL_AXES) is
-    split over more than one device in the enclosing ``jax.set_mesh`` scope —
-    the observable the paged kernels' single-shard gate rests on."""
+def _batch_sharded() -> bool:
+    """Whether the enclosing ``jax.set_mesh`` scope splits the BATCH around
+    the attention (attention-DP, whole-model DP): there the block pool is
+    replicated over those axes, the paged kernels are not run
+    (models/base.decoder_layer) and no custom call demands a layout."""
     mesh = ambient_mesh()
     return mesh is not None and any(
-        dict(mesh.shape).get(a, 1) > 1 for a in MODEL_AXES
+        dict(mesh.shape).get(a, 1) > 1 for a in (AXIS_DDP, AXIS_DP)
     )
 
 
@@ -177,6 +194,7 @@ def update_block_cache_at_layer(
     v_new: jax.Array,
     layer_idx: jax.Array,
     slot_mapping: jax.Array,  # (B, S) global slots; < 0 -> garbage block
+    packed: bool = False,  # the rows are the mixed step's ONE packed token axis
 ) -> Tuple[jax.Array, jax.Array]:
     """Scatter token K/V into the paged cache at one layer (reference
     scatter-by-slot, block_kv_cache_manager.py). The full stacked cache is
@@ -198,24 +216,44 @@ def update_block_cache_at_layer(
     INDEXED dim (window ``(D,)``, already minor-most in the head-major
     layout) the carry stays row-major and nothing is relaid, but the scatter
     has H times the index rows, and on a v5e a row costs ~60 ns whatever its
-    width. So the form is selected on the static ``S`` of ``slot_mapping``,
-    here and nowhere else:
+    width. So the form is selected here and nowhere else, on the static ``S``
+    of ``slot_mapping`` and on the heads ONE device holds (``H`` over the
+    ambient mesh's head shards):
 
     * ``S <= TKG_MAX_Q_LEN`` (16: decode and speculation widths, the widths
       the stacked-cache decode kernel serves) — per-head form. The served
       Qwen3-1.7B decode step (48 rows, 28 layers, pool 2 x 1.94 GB) compiles
       to 0 pool-shaped copies and 0 GB of temporaries against 6 and 3.89 GB,
       and runs in 56 ms against 406 ms (v5e, kv bucket 1024, PERF.md PR 24).
-    * ``S > TKG_MAX_Q_LEN`` (prefill chunks of 32-128 tokens, the packed
-      ragged axis) — window form. There only a layer's slice and the
-      entry/exit pair are relaid (~20 ms a pass), less than 8x the rows
-      cost: the chunk program runs in 153 / 187 / 303 ms at q 32 / 64 / 128
-      against 170 / 246 / 445 ms per-head (same chip run).
+    * ``S > TKG_MAX_Q_LEN`` (prefill chunks of 32-128 tokens) and at least
+      ``WINDOW_MIN_HEADS`` heads a device — window form. There only a
+      layer's slice and the entry/exit pair are relaid (~20 ms a pass), less
+      than 8x the rows cost: the chunk program ran in 153 / 187 / 303 ms at
+      q 32 / 64 / 128 against 170 / 246 / 445 ms per-head (same chip run).
+    * Fewer heads a device than the tile has sublanes (Qwen3-14B at tp = 4:
+      2 of 8) — per-head form at every ``S``. Under a window of 2 or 4 heads
+      the compiler re-lays the whole pool FOUR times around every layer's
+      scatter, sharded or not (described-chip compiles at 1 / 2 / 4 / 8 / 16
+      heads, bf16 and int8, PERF.md PR 33: none from 8 heads up); on the
+      chip the tp = 4 chunk dispatch read ~360 ms with it where native
+      attention had read ~130. And the rows are few: 8 x 128 tokens x 2
+      heads.
+    * The ragged mixed step says ``packed``: its ``S`` is the packed token
+      axis of every row and its kernel takes a layer's slice, so it skips
+      the test on ``S`` (one form at every packed width keeps its bucket
+      programs one structure: analysis/graph_audit GRAPH205).
     * Head axis sharded (``block_cache_spec`` over a tp/ep/cp > 1 mesh) —
-      window form at every ``S``. There the paged kernels are gated off
-      (ops/kernel_mode.single_shard), no custom call demands a layout, and
-      the per-head form would add two all-gathers per scatter where the
-      window form has no collective.
+      the same selection: the paged kernels run there too, once per head
+      shard (``parallel/sharding.shard_over_heads``), and demand the same
+      layout of each shard's slice of the pool. The per-head form INDEXES
+      the sharded dim, which leaves GSPMD free to gather operand and updates
+      (two all-gathers a scatter when nothing else pinned the carry), so it
+      runs under the same ``shard_over_heads``: each shard writes its own
+      ``H / degree`` heads by shard-local head index, the token indices are
+      replicated, and no collective can appear. The window form has the head
+      in the window and partitions as it stands. Only where the BATCH is
+      sharded around the attention (attention-DP; no kernel runs there) does
+      the window form serve every call.
 
     Quantized caches quantize fused into this scatter with the running
     per-(layer, head) absmax (see kvcache.update_cache_at_layer); invalid
@@ -226,14 +264,25 @@ def update_block_cache_at_layer(
     slots = slot_mapping.reshape(B * S)
     blocks = jnp.where(slots >= 0, slots // bs, NB1)
     offs = jnp.where(slots >= 0, slots % bs, 0)
-    per_head = S <= TKG_MAX_Q_LEN and not _heads_sharded()
+    per_head = not _batch_sharded() and (
+        H // head_shard_degree() < WINDOW_MIN_HEADS
+        or (S <= TKG_MAX_Q_LEN and not packed)
+    )
+
+    def scatter_per_head(data, rows, layer_idx, blocks, offs):
+        # head indexed, window (D,): the carry stays row-major. Under a
+        # head-sharded mesh ``data`` and ``rows`` are one shard's heads
+        heads = jnp.arange(data.shape[2])[None, :]
+        return data.at[layer_idx, blocks[:, None], heads, offs[:, None]].set(
+            rows, mode="drop"
+        )
 
     def write(data, new):
         rows = new.reshape(B * S, H, D).astype(data.dtype)
-        if per_head:  # head indexed, window (D,): the carry stays row-major
-            heads = jnp.arange(H)[None, :]
-            return data.at[layer_idx, blocks[:, None], heads, offs[:, None]].set(
-                rows, mode="drop"
+        if per_head:
+            return shard_over_heads(
+                scatter_per_head, (data, rows, layer_idx, blocks, offs),
+                in_heads=(2, 1, None, None, None), out_heads=2,
             )
         # window (H, D): one index row per token, the carry token-major
         return data.at[layer_idx, blocks, :, offs].set(rows, mode="drop")

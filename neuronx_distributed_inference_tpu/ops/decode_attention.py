@@ -359,3 +359,59 @@ def paged_tkg_decode_attention(
     if quantized:
         out = _apply_v_dequant(out, v_quant, layer_idx, n_rep).astype(out_dtype)
     return out
+
+
+# ---------------------------------------------------------------------------
+# per-shard launch (parallel/sharding.shard_over_heads): what the model calls
+# ---------------------------------------------------------------------------
+
+
+def _cache_heads(cache, axis: int):
+    """Head-axis positions of one cache stream for ``shard_over_heads``: the
+    code/value array's ``axis``, and the (L, H) scales of a quantized one."""
+    return QuantizedKV(data=axis, scale=1) if isinstance(cache, QuantizedKV) else axis
+
+
+def dispatch_tkg_decode(
+    q, k_cache, v_cache, layer_idx, mask, sink=None, *, scale, interpret
+):
+    """:func:`tkg_decode_attention` once per head shard of the ambient mesh:
+    q, the sink logits and the output split on the q heads, the stacked
+    contiguous cache ``(L, R, S_max, Hkv, D)`` on the kv heads, layer index
+    and mask replicated; no collective inside. The plain call at degree 1."""
+    from neuronx_distributed_inference_tpu.parallel.sharding import shard_over_heads
+
+    def per_shard(q_s, k_s, v_s, li, m, sink_s):
+        return tkg_decode_attention(
+            q_s, k_s, v_s, li, m, sink_s,
+            scale=scale, n_kv=k_s.shape[3], interpret=interpret,
+        )
+
+    heads = _cache_heads(k_cache, 3)
+    return shard_over_heads(
+        per_shard, (q, k_cache, v_cache, layer_idx, mask, sink),
+        in_heads=(2, heads, heads, None, None, 0), out_heads=2,
+    )
+
+
+def dispatch_paged_tkg_decode(
+    q, k_cache, v_cache, layer_idx, block_table, mask, sink=None, *, scale, interpret
+):
+    """:func:`paged_tkg_decode_attention` once per head shard: the stacked
+    block pool ``(L, NB+1, Hkv, bs, D)`` splits on the kv heads exactly as
+    the layer scan carries it (block_kvcache.block_cache_spec), block table
+    and mask are replicated. At tp = 4 a chip's kernel reads its own 2 of
+    Qwen3-14B's 8 kv heads for its own 10 of 40 q heads."""
+    from neuronx_distributed_inference_tpu.parallel.sharding import shard_over_heads
+
+    def per_shard(q_s, k_s, v_s, li, bt, m, sink_s):
+        return paged_tkg_decode_attention(
+            q_s, k_s, v_s, li, bt, m, sink_s,
+            scale=scale, n_kv=k_s.shape[2], interpret=interpret,
+        )
+
+    heads = _cache_heads(k_cache, 2)
+    return shard_over_heads(
+        per_shard, (q, k_cache, v_cache, layer_idx, block_table, mask, sink),
+        in_heads=(2, heads, heads, None, None, None, 0), out_heads=2,
+    )

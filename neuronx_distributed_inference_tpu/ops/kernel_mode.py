@@ -25,20 +25,27 @@ change to one kernel's auto condition must not silently flip another's.
 The kernel modules re-export their historical names (``_use_flash``,
 ``use_tkg_kernel``, ...) as aliases of these predicates.
 
+A ``pallas_call`` has no partitioning rule, so a kernel meets a model-parallel
+mesh in one of two ways. The attention kernels that read a cache (paged flash,
+TKG contiguous + paged, ragged) are per-head work: their dispatches launch
+them once per head shard (``parallel/sharding.shard_over_heads``, the plain
+call at degree 1), so their gates rest on what they can observe of the call —
+shape, ``head_dim``, kv width, backend — and on both head counts dividing the
+degree (:func:`heads_divide`; ``GQASharding`` guarantees it). The kernels with
+no such dispatch (contiguous flash prefill, whose q may be sequence-sharded
+under context parallelism; the fused MoE decode; the int4 matmul) keep
+:func:`single_shard` in their auto condition.
+
 Gate summary (auto path):
 
 ============================  ==============================================
 kernel                        auto condition beyond the shape guards
 ============================  ==============================================
 flash / packed prefill        single model-parallel shard, TPU backend
-paged flash prefill           single shard, TPU, q_len >= 64
-TKG decode (contig + paged)   single shard, TPU, kv_width >= 512
+paged flash prefill           TPU, q_len >= 64, heads divide the degree
+TKG decode (contig + paged)   TPU, kv_width >= 512, heads divide the degree
 fused MoE decode              OFF (force-only pending hardware wins)
-ragged mixed-step             TPU backend — **sharded meshes included**:
-                              the mixed step wraps the kernel in
-                              ``shard_map`` over the head-parallel grid
-                              axis, so tp>1 no longer forces the native
-                              gather fallback (ISSUE 17)
+ragged mixed-step             TPU backend, heads divide the degree
 int4 quant matmul             TPU backend + single shard (see
                               :func:`use_quant_matmul`)
 ============================  ==============================================
@@ -88,10 +95,20 @@ def on_tpu() -> bool:
 
 def single_shard(spec) -> bool:
     """One model-parallel shard: the auto condition for kernels whose
-    pallas_call carries no GSPMD partitioning rule — a sharded operand
-    would be all-gathered per launch. The ragged mixed-step kernel is the
-    exception: its dispatch shard_maps over the head axis instead."""
+    pallas_call meets a sharded operand bare — it would be all-gathered per
+    launch. The cache-reading attention kernels are not among them: their
+    dispatches launch per head shard (:func:`heads_divide`)."""
     return spec.model_parallel == 1
+
+
+def heads_divide(spec) -> bool:
+    """Both head counts divide the model-parallel degree: what a per-shard
+    launch over the head axis needs (parallel/sharding.shard_over_heads).
+    ``GQASharding``'s kv replication guarantees it for a built model; a
+    hand-built spec that breaks it degrades to the native path, forced or
+    not, instead of a ``shard_map`` error."""
+    mp = spec.model_parallel
+    return spec.num_heads % mp == 0 and spec.num_kv_heads % mp == 0
 
 
 def flash_shape_ok(spec, seq_len: int) -> bool:
@@ -175,13 +192,11 @@ def use_tkg(spec, q_len: int, kv_width: int) -> bool:
         and spec.head_dim % 64 == 0
         and kv_width >= 128
         and kv_width % min(512, kv_width) == 0
+        and heads_divide(spec)
     )
     if enabled:
         return ok
-    # auto path: single model-parallel shard only — pallas_call has no GSPMD
-    # partitioning rule, so a head-sharded cache operand would be all-gathered
-    # per layer per step (force-enable opts in regardless)
-    return ok and kv_width >= 512 and single_shard(spec) and on_tpu()
+    return ok and kv_width >= 512 and on_tpu()
 
 
 def use_paged_flash(spec, q_len: int) -> bool:
@@ -189,12 +204,16 @@ def use_paged_flash(spec, q_len: int) -> bool:
     (decode q_len==1 rides the TKG kernel), lane-aligned head_dim; auto-on
     for TPU at kernel-worthy chunk sizes, force-on/off via
     attn_kernel_enabled."""
-    if spec.use_flash_kernel is False or q_len < 8 or spec.head_dim % 64 != 0:
+    if (
+        spec.use_flash_kernel is False
+        or q_len < 8
+        or spec.head_dim % 64 != 0
+        or not heads_divide(spec)
+    ):
         return False
     if spec.use_flash_kernel:
         return True
-    # auto path requires one model-parallel shard (see AttnSpec.model_parallel)
-    return q_len >= 64 and single_shard(spec) and on_tpu()
+    return q_len >= 64 and on_tpu()
 
 
 def use_moe_tkg(spec, params: dict, n_tokens: int) -> bool:
@@ -235,22 +254,17 @@ def use_ragged(spec, total_q: int, ragged_q_tile: int = 16) -> bool:
     head_dim and tile-aligned packing; tri-state force via
     ``use_flash_kernel`` like the other attention kernels.
 
-    Unlike the other gates there is NO single-shard condition: the mixed
-    step dispatches the kernel through ``shard_map`` over the head-parallel
-    grid axis (q heads and paged KV blocks are head-sharded, descriptors
-    are replicated host metadata), so tp>1 meshes run the kernel per-shard
-    with no collectives inside (ISSUE 17). The head counts must divide the
-    model-parallel degree — guaranteed by GQASharding's kv replication, and
-    re-checked here so a hand-built spec degrades to the native path
-    instead of a shard_map error."""
+    Like the other cache-reading kernels there is NO single-shard
+    condition: the mixed step launches the kernel per head shard (q heads
+    and paged KV blocks are head-sharded, descriptors are replicated host
+    metadata), so tp>1 meshes run it with no collectives inside (ISSUE 17);
+    the head counts must divide the degree (:func:`heads_divide`)."""
     if (
         spec.use_flash_kernel is False
         or spec.head_dim % 64 != 0
         or total_q % ragged_q_tile != 0
+        or not heads_divide(spec)
     ):
-        return False
-    mp = spec.model_parallel
-    if mp > 1 and (spec.num_heads % mp or spec.num_kv_heads % mp):
         return False
     if spec.use_flash_kernel:
         return True
@@ -306,15 +320,11 @@ def use_quant_matmul(rows: int, k: int, n: int, group: int = QMM_GROUP) -> bool:
     mode = _QMM_MODE[-1]
     if mode is False:
         return False
-    from neuronx_distributed_inference_tpu.parallel.mesh import (
-        ALL_AXES,
-        ambient_mesh,
+    from neuronx_distributed_inference_tpu.parallel.sharding import (
+        head_shard_degree,
     )
 
-    mesh = ambient_mesh()
-    sharded = mesh is not None and any(
-        dict(mesh.shape).get(a, 1) > 1 for a in ALL_AXES
-    )
+    sharded = head_shard_degree() > 1  # the weights' TENSOR axes
     ok = rows <= 64 and n % 128 == 0 and k >= 2 * group and not sharded
     if mode is True:
         if not ok:

@@ -219,3 +219,27 @@ def paged_flash_attention(
     if v_scale is not None:
         out = (out * jnp.repeat(v_scale, n_rep)[None, None, :, None]).astype(out_dtype)
     return out
+
+
+def dispatch_paged_flash(
+    q, k_cache, v_cache, block_table, positions, kv_limit,
+    *, scale, n_rep, k_scale=None, v_scale=None, interpret,
+):
+    """:func:`paged_flash_attention` once per head shard of the ambient mesh
+    (parallel/sharding.shard_over_heads): q and the output split on the q
+    heads, one layer's block pool ``(NB+1, Hkv, bs, D)`` and the per-head
+    dequant factors on the kv heads, block table, positions and ``kv_limit``
+    replicated; no collective inside. The plain call at degree 1."""
+    from neuronx_distributed_inference_tpu.parallel.sharding import shard_over_heads
+
+    def per_shard(q_s, k_s, v_s, bt, pos, lim, ks_s, vs_s):
+        return paged_flash_attention(
+            q_s, k_s, v_s, bt, pos, lim,
+            scale=scale, n_rep=n_rep, k_scale=ks_s, v_scale=vs_s,
+            interpret=interpret,
+        )
+
+    return shard_over_heads(
+        per_shard, (q, k_cache, v_cache, block_table, positions, kv_limit, k_scale, v_scale),
+        in_heads=(2, 1, 1, None, None, None, 0, 0), out_heads=2,
+    )

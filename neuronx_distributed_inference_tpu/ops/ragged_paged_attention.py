@@ -301,49 +301,26 @@ def _dispatch_ragged_kernel(
     ``sharding.TENSOR`` axes the projection weights use), the descriptors
     (block table, row start/len, context lengths) are replicated host
     metadata, and GQA replication (parallel/sharding.GQASharding) guarantees
-    both head counts divide the degree — so ``shard_map`` runs the identical
-    per-head math on each shard with NO cross-shard collectives inside, and
-    the tp>1 stream stays byte-identical to tp=1 and to the native fallback
-    (pinned in tests/test_ragged_tp.py)."""
-    from jax.sharding import PartitionSpec as P
-
-    from neuronx_distributed_inference_tpu.parallel.mesh import (
-        ALL_AXES,
-        ambient_mesh,
+    both head counts divide the degree — so ``shard_over_heads`` runs the
+    identical per-head math on each shard with NO cross-shard collectives
+    inside, and the tp>1 stream stays byte-identical to tp=1 and to the
+    native fallback (pinned in tests/test_ragged_tp.py)."""
+    from neuronx_distributed_inference_tpu.parallel.sharding import (
+        shard_over_heads,
     )
 
-    mesh = ambient_mesh()
-    axes = tuple(a for a in ALL_AXES if mesh is not None and a in mesh.shape)
-    degree = 1
-    for a in axes:
-        degree *= mesh.shape[a]
-    if degree == 1:
-        return ragged_paged_attention(
-            q3, k_l, v_l, block_table, row_start, row_len, ctx_len,
-            scale=scale, n_rep=n_rep, k_scale=k_scale, v_scale=v_scale,
-            interpret=interpret,
-        )
-
-    head = P(None, axes, None)
-    args = [q3, k_l, v_l, block_table, row_start, row_len, ctx_len]
-    in_specs = [head, P(None, axes, None, None), P(None, axes, None, None),
-                P(), P(), P(), P()]
-    if k_scale is not None:
-        args += [k_scale, v_scale]
-        in_specs += [P(axes), P(axes)]
-
-    def per_shard(q_s, k_s, v_s, bt, rs, rl, cl, *scales):
-        ks_s, vs_s = scales if scales else (None, None)
+    def per_shard(q_s, k_s, v_s, bt, rs, rl, cl, ks_s, vs_s):
         return ragged_paged_attention(
             q_s, k_s, v_s, bt, rs, rl, cl,
             scale=scale, n_rep=n_rep, k_scale=ks_s, v_scale=vs_s,
             interpret=interpret,
         )
 
-    return jax.shard_map(
-        per_shard, mesh=mesh, in_specs=tuple(in_specs), out_specs=head,
-        check_vma=False,
-    )(*args)
+    return shard_over_heads(
+        per_shard,
+        (q3, k_l, v_l, block_table, row_start, row_len, ctx_len, k_scale, v_scale),
+        in_heads=(1, 1, 1, None, None, None, None, 0, 0), out_heads=1,
+    )
 
 
 def ragged_attention_native(

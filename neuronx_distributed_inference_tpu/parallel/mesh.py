@@ -192,11 +192,11 @@ def sharding_str(sharding) -> str:
 
 def ambient_mesh():
     """The (abstract) mesh of the enclosing ``jax.set_mesh`` scope, or None
-    outside one. Readable at TRACE time from inside jit — how the ragged
-    mixed-step dispatch finds the mesh to ``jax.shard_map`` the kernel over
-    without threading it through model code
-    (ops/ragged_paged_attention.ragged_attention). Axis names and sizes
-    only: it carries no devices."""
+    outside one. Readable at TRACE time from inside jit — how the attention
+    kernels' dispatches and the paged KV write find the mesh to
+    ``jax.shard_map`` over without threading it through model code
+    (parallel/sharding.shard_over_heads). Axis names and sizes only: it
+    carries no devices."""
     m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 

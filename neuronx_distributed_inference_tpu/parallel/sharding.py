@@ -25,7 +25,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from neuronx_distributed_inference_tpu.parallel.mesh import ALL_AXES, MODEL_AXES
+from neuronx_distributed_inference_tpu.parallel.mesh import (
+    ALL_AXES,
+    MODEL_AXES,
+    ambient_mesh,
+)
 
 # Logical axis names used in model param spec trees. Weight tensor-parallel
 # dims shard over EVERY mesh axis (dp included — attention-DP subdivides the
@@ -130,6 +134,58 @@ def constrain(x, spec: P):
         if "requires a non-empty mesh" in str(e):
             return x
         raise
+
+
+def head_shard_degree() -> int:
+    """How many ways the ambient mesh (the enclosing ``jax.set_mesh`` scope)
+    splits the attention head axis: the product of its :data:`TENSOR` axes,
+    1 outside a mesh. The observable :func:`shard_over_heads` launches on."""
+    mesh = ambient_mesh()
+    if mesh is None:
+        return 1
+    return math.prod(dict(mesh.shape).get(a, 1) for a in TENSOR)
+
+
+def shard_over_heads(fn, args, in_heads, out_heads):
+    """``fn(*args)``, once per model-parallel shard of the head axis.
+
+    A ``pallas_call`` carries no partitioning rule, and a scatter whose
+    INDEXED dim is sharded may be gathered; both are per-head work with no
+    cross-head term, so on a head-sharded mesh they run inside
+    ``jax.shard_map`` over the :data:`TENSOR` axes: every shard sees its own
+    ``H / degree`` heads and the whole of everything else, and no collective
+    appears inside (GQASharding makes both head counts divide the degree).
+    At degree 1 — one chip, or no mesh — this IS the plain call, so a
+    one-chip program lowers to exactly what it lowers to without it.
+
+    ``in_heads`` names, per argument, the position of its head axis (``None``
+    = replicated: block tables, masks, positions, layer index); a pytree
+    argument takes a pytree of positions. ``out_heads`` likewise for the
+    result. An argument that IS ``None`` (no sink, no dequant factors)
+    reaches ``fn`` as ``None``. Shared by the ragged, paged-flash and TKG
+    attention dispatches and the per-head paged KV write."""
+    if head_shard_degree() == 1:
+        return fn(*args)
+    mesh = ambient_mesh()
+    axes = tuple(a for a in TENSOR if a in mesh.shape)
+    given = [i for i, a in enumerate(args) if a is not None]
+
+    def spec(pos):
+        return P() if pos is None else P(*([None] * pos), axes)
+
+    def specs(tree):
+        return jax.tree.map(spec, tree, is_leaf=lambda x: x is None)
+
+    def per_shard(*shards):
+        full = [None] * len(args)
+        for i, shard in zip(given, shards):
+            full[i] = shard
+        return fn(*full)
+
+    return jax.shard_map(
+        per_shard, mesh=mesh, in_specs=specs(tuple(in_heads[i] for i in given)),
+        out_specs=specs(out_heads), check_vma=False,
+    )(*(args[i] for i in given))
 
 
 def make_sharding_fn(mesh: Mesh):

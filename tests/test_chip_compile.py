@@ -3,10 +3,11 @@
 ``tests/test_tpu_lowering.py`` stops at Mosaic MLIR (``jax.export``): it
 cannot see the fast-memory limit, a mis-tiled slice, or a kernel that cannot
 be partitioned. Here the main-path kernels at Llama-3.2-1B and 3.1-8B widths,
-one whole 1B decode step and the tp=4 ragged mixed step (the ``shard_map``
-dispatch) are COMPILED for a described ``v5e:2x2`` chip — the TPU compiler is
-installed on the CPU harness and raises what the chip's would. Nothing runs,
-so this says nothing about results or times (chip_smoke.py does).
+one whole 1B decode step, the tp=4 ragged mixed step and the four-chip
+cell's two paged step programs (the per-shard dispatch) are COMPILED for a
+described ``v5e:2x2`` chip — the TPU compiler is installed on the CPU harness
+and raises what the chip's would. Nothing runs, so this says nothing about
+results or times (chip_smoke.py does).
 
 The topology, and everything built from it, lives in module-scoped fixtures:
 only one process may load the TPU library, so it must not be touched while
@@ -233,17 +234,19 @@ QWEN3_1P7B_GEOMETRY = dict(
 
 
 def _pool_copies(compiled, pool_shape):
-    """(in the layer scan's body, elsewhere): the ``copy`` ops of the
-    optimized HLO whose result has the block pool's shape. A copy the
-    compiler put after the scatter carries the scatter's ``op_name`` under
-    ``while/body``; one at the program's entry or exit carries none."""
-    pool = "bf16[" + ",".join(str(d) for d in pool_shape) + "]"
-    lines = [
-        line for line in compiled.as_text().splitlines()
-        if re.search(r"= " + re.escape(pool) + r"\{[^}]*\} copy\(", line)
-    ]
-    in_scan = sum("while/body" in line for line in lines)
-    return in_scan, len(lines) - in_scan
+    """(in a called computation, in the entry computation): the ``copy`` ops
+    of the optimized HLO whose result has as many elements as the block
+    pool, whatever shape it was bitcast to on the way (the relayout around a
+    window-form scatter copies ``bf16[L*(NB+1)*bs, H, D]``). The layer scan's
+    body is a called computation and is printed before ``ENTRY``; a copy at
+    the program's entry or exit is in the entry computation."""
+    size = int(np.prod(pool_shape))
+    called, _, entry = compiled.as_text().partition("\nENTRY ")
+
+    def count(text):
+        shapes = re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", text)
+        return sum(np.prod([int(d) for d in sh.split(",")]) == size for sh in shapes)
+    return count(called), count(entry)
 
 
 def _planned_bytes(compiled) -> int:
@@ -291,6 +294,82 @@ def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
     else:
         assert outside == 4
         assert _planned_bytes(compiled) < 9.6 * 2**30
+
+
+# Qwen3-14B's geometry (40/8 heads of 128, 40 layers, hidden 5120, vocab
+# 151936) on the llama graph, as benchmark/configs/qwen3-14b-tp4.json serves
+# it on four chips: 64 slots, 1024 blocks x 32 tokens, kv 1024/2048/4096
+QWEN3_14B_GEOMETRY = dict(
+    LLAMA_1B, hidden_size=5120, intermediate_size=17408, num_attention_heads=40,
+    num_key_value_heads=8, num_hidden_layers=40, vocab_size=151936,
+    head_dim=128, max_position_embeddings=40960,
+)
+
+
+def _cache_gathers(compiled, pool_shape):
+    """The all-gathers of the optimized HLO whose result is the block pool
+    or one layer's slice of it, at any head count: what a paged kernel or a
+    per-head scatter handed a head-sharded pool BARE costs."""
+    _, nb1, _, bs, d = pool_shape
+    want = re.compile(rf"= \w+\[(\d+,)?{nb1},\d+,{bs},{d}\]\S* all-gather(-start)?\(")
+    return [line for line in compiled.as_text().splitlines() if want.search(line)]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_tp4_paged_serving_step_runs_its_kernels_per_shard(chip_mesh, program):
+    """The four-chip cell's two step programs (qwen3-14b-tp4.chat) on a 2x2
+    mesh: the paged kernels and the per-head KV write run once per head
+    shard (parallel/sharding.shard_over_heads), so the PARTITIONED
+    executable holds the custom call, each chip's 2 of the 8 kv heads stay
+    where they are (no all-gather of anything pool-shaped), and the layer
+    scan's cache carry is in the kernel's layout.
+
+    Both programs: NO copy of a chip's pool slice anywhere, in any shape,
+    and next to no temporaries. decode (64 x 1): native attention over 64
+    rows x the kv bucket planned 12.13 GiB a chip (PERF.md, PR 26), this
+    plans under 8.5; with the write in its window form (what a sharded head
+    axis took before) the scan's body held two such copies per layer. chunk
+    (8 x 128): a chip holds 2 kv heads, fewer than the tile's 8 sublanes, so
+    its write is per-head too (block_kvcache.WINDOW_MIN_HEADS); in the
+    window form the scan's body re-laid the pool FOUR times per layer as
+    ``bf16[L*(NB+1)*bs, 2, 128]`` (~360 ms a dispatch on the chip, PERF.md
+    PR 33), which a search for the pool's own shape does not see."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+
+    app, params, cache = _abstract_app(
+        QWEN3_14B_GEOMETRY, chip_mesh(4), batch_size=64, seq_len=4096, tp_degree=4,
+        context_encoding_buckets=[4096], token_generation_buckets=[1024, 2048, 4096],
+        is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
+        pa_num_blocks=1024, pa_block_size=32, is_chunked_prefill=True,
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=64),
+        # the auto gates ask jax.default_backend(), which is the CPU here
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(4096, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((64, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
+    assert _custom_calls(compiled) >= 1 and kernel in compiled.as_text()
+    assert not _cache_gathers(compiled, cache.k.shape)
+    L, nb1, heads, bs, d = cache.k.shape
+    assert _pool_copies(compiled, (L, nb1, heads // 4, bs, d)) == (0, 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    assert _planned_bytes(compiled) < 8.5 * 2**30
+
+
+def test_tp4_contiguous_decode_step_runs_its_kernel_per_shard(chip_mesh):
+    """``generate()``'s decode step (contiguous cache) at tp = 4: the TKG
+    kernel shares the paged one's gate, so it shares the per-shard launch."""
+    app, params, cache = _abstract_app(
+        LLAMA_1B, chip_mesh(4), batch_size=4, seq_len=512, tp_degree=4,
+        context_encoding_buckets=[128], token_generation_buckets=[512],
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    tkg = app.token_generation_model
+    compiled = _compile_step(app, tkg, tkg.example_inputs(512), params, cache)
+    assert _custom_calls(compiled) >= 1 and "tkg_decode_attention" in compiled.as_text()
+    assert "all-reduce" in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

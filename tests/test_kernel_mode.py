@@ -5,9 +5,11 @@ Every kernel/native auto-gate lives in ONE module with a shared tri-state
 convention (None = auto, True = force with shape guards + a warning on
 fallback, False = off). These tests pin each predicate in isolation so a
 change to one kernel's auto condition cannot silently flip another's — in
-particular, the ISSUE 17 ragged-gate change (sharded meshes now allowed)
-must NOT loosen the single-shard requirement on the flash / paged / TKG /
-MoE gates, whose pallas_calls still carry no GSPMD partitioning rule.
+particular, the cache-reading attention kernels (ragged since ISSUE 17,
+paged flash and TKG since ISSUE 33) launch per head shard and ask only that
+the head counts divide the degree, which must NOT loosen the single-shard
+requirement on the contiguous flash / MoE gates, whose pallas_calls still
+meet a sharded operand bare.
 
 The suite runs on the CPU harness, so ``on_tpu()`` is False throughout:
 auto paths that require TPU are asserted off here and force-enabled paths
@@ -32,6 +34,13 @@ def test_on_tpu_and_single_shard():
     assert km.on_tpu() is False  # the CPU harness
     assert km.single_shard(_spec())
     assert not km.single_shard(_spec(model_parallel=2))
+
+
+def test_heads_divide():
+    assert km.heads_divide(_spec())  # degree 1 divides anything
+    assert km.heads_divide(_spec(model_parallel=2))  # 8 / 2 heads over 2
+    assert not km.heads_divide(_spec(model_parallel=4))  # 2 kv heads over 4
+    assert not km.heads_divide(_spec(model_parallel=3))
 
 
 def test_flash_shape_ok():
@@ -72,8 +81,12 @@ def test_use_tkg_shape_guards_and_auto():
     assert not km.use_tkg(forced, q_len=32, kv_width=512)  # not decode-sized
     assert not km.use_tkg(forced, q_len=1, kv_width=96)  # unaligned kv
     assert not km.use_tkg(_spec(use_tkg_kernel=False), 1, 512)
-    # auto requires TPU + kv_width >= 512 + single shard
+    # auto requires TPU + kv_width >= 512
     assert not km.use_tkg(_spec(), 1, 512)
+    # a sharded mesh is served per head shard: no single-shard condition,
+    # but the head counts must divide the degree, forced or not
+    assert km.use_tkg(_spec(use_tkg_kernel=True, model_parallel=2), 1, 512)
+    assert not km.use_tkg(_spec(use_tkg_kernel=True, model_parallel=4), 1, 512)
     odd_d = AttnSpec(
         num_heads=8, num_kv_heads=2, head_dim=80, use_tkg_kernel=True
     )
@@ -87,6 +100,29 @@ def test_use_paged_flash_prefill_only():
     assert not km.use_paged_flash(forced, q_len=4)  # decode-sized: TKG's job
     assert not km.use_paged_flash(_spec(use_flash_kernel=False), 64)
     assert not km.use_paged_flash(_spec(), 64)  # auto requires TPU
+    assert km.use_paged_flash(_spec(use_flash_kernel=True, model_parallel=2), 64)
+    assert not km.use_paged_flash(
+        _spec(use_flash_kernel=True, model_parallel=4), 64
+    )  # 2 kv heads do not divide 4: native, not a shard_map error
+
+
+def test_auto_gates_on_a_sharded_mesh(monkeypatch):
+    """What the four-chip cell takes (Qwen3-14B, 40 / 8 heads of 128 over
+    tp = 4, every kernel option at its default) once the backend is a TPU:
+    both paged kernels and the decode kernel; the contiguous flash prefill,
+    which has no per-shard launch, keeps its single-shard condition."""
+    monkeypatch.setattr(km, "on_tpu", lambda: True)
+    tp4 = AttnSpec(num_heads=40, num_kv_heads=8, head_dim=128, model_parallel=4)
+    tp1 = AttnSpec(num_heads=40, num_kv_heads=8, head_dim=128)
+    for spec in (tp1, tp4):
+        assert km.use_tkg(spec, q_len=1, kv_width=2048)
+        assert not km.use_tkg(spec, q_len=1, kv_width=256)  # auto: kv >= 512
+        assert km.use_paged_flash(spec, q_len=128)
+        assert not km.use_paged_flash(spec, q_len=32)  # auto: q >= 64
+        assert km.use_ragged(spec, total_q=128)
+    assert km.use_flash(tp1, 128) and not km.use_flash(tp4, 128)
+    odd = AttnSpec(num_heads=40, num_kv_heads=8, head_dim=128, model_parallel=16)
+    assert not km.use_tkg(odd, 1, 2048) and not km.use_paged_flash(odd, 128)
 
 
 def _moe_spec(**kw):
@@ -116,7 +152,7 @@ def test_use_moe_tkg_force_only_with_structural_guards():
 
 def test_use_ragged_allows_sharded_meshes():
     """The ISSUE 17 gate change: NO single-shard condition — the dispatch
-    shard_maps over the head axis — but head counts must divide the
+    launches per head shard — but head counts must divide the
     model-parallel degree so a hand-built spec degrades to native."""
     forced = _spec(use_flash_kernel=True)
     assert km.use_ragged(forced, total_q=64)
