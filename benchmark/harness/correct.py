@@ -32,9 +32,56 @@
         give near-flat logits, so equality of argmax is not asked (PR 21:
         two paths agreed on 4 of 8 requests).
     No constant of scale: depth, width and layout move err and floor alike.
+
+    A model that chooses (an expert layer's router, any argmax or top-k
+    inside the step): replay, then margin. The hidden state of a sound bf16
+    model is some per cent of scale off float32 by its last layers; a top-1
+    choice among near-tied scores flips under that (a few decisions of a
+    thousand at 4 layers on the CPU; more with depth), so the served model,
+    ``ref32`` and ``twin`` would each take another route, and err and floor
+    would both be maxima over a handful of flips
+    (``selftest/test_correct_choices.py`` reads the ratio that gives: 0.2
+    to 32 over 20 seeds of a sound program). So
+    three parties opt in, each by name, and a configuration that does not
+    pays not one extra pass:
+      - the configuration file names, under ``probe_tpu_config``, the option
+        of the program that makes the step return its choices (laid over
+        the probe application only, as ``output_logits`` is);
+      - the program's ``forward`` then returns a third value beside
+        ``(tokens, logits)``: a dict ``name -> int array (B, S, ...)`` of the
+        choices made at every position of the pass (an expert layer:
+        ``(B, S, L_moe, k)``; index E where a router may skip). The harness
+        keeps, per probe row, the choices of EVERY token of the row (prompt
+        chunks and decode steps, padding dropped by the bookkeeping that
+        picks the logits): the K and V of context tokens depend on their
+        routes too;
+      - the reference module sets ``CHOICES = True`` and is called as
+        ``reference_logits(params, geo, tokens, positions, choices=row)``
+        and ``twin_logits(..., choices=row)``, ``row`` a dict
+        ``name -> (len(tokens), ...)``: both FOLLOW the served selection and
+        compute everything else (scores, affinities, experts) themselves,
+        so ``floor`` is rounding noise alone and ``err <= K * floor`` keeps
+        its meaning and its K. It also gives
+        ``choice_margins(params, geo, tokens, choices) -> (regret,
+        score_floor[, differing])``, one number per choosing layer, on the
+        replayed path: ``regret[l]`` the most, over tokens, by which the
+        served choice's selection score lies below the best candidate's in
+        float32; ``score_floor[l]`` the largest |twin's selection score -
+        float32's| over tokens and candidates; ``differing[l]`` (printed,
+        not judged) the decisions that are not float32's own.
+      - margin:  regret[l] <= 2 * K * score_floor[l]  for every layer l.
+        Why 2 K: a model that takes the argmax of scores s + e picks j over
+        the best i only if s[i] - s[j] < e[j] - e[i] <= 2 max|e|, and a
+        sound model's e is within K of the twin's, as its logits are.
+    A reference that sets ``CHOICES`` while the probe returned no third
+    value is a CorrectnessError, not a silent fall-back to three routes.
+    The session rule is read on the replayed ``ref32``.
 (b) In the window: every finished request has exactly its budget of tokens,
     all inside the vocabulary; none ended FAILED.
 (c) No compilation inside the window (``system.CompileLog``).
+
+``compared`` lists every number of (a), (b) and (c) beside its limit, for
+the run's last lines.
 """
 
 from __future__ import annotations
@@ -95,7 +142,8 @@ def probe_width(cfg: dict, max_prompt: int) -> int:
 def probe_overrides(cfg: dict, max_prompt: int) -> Dict[str, dict]:
     per_row = probe_width(cfg, max_prompt) // cfg["tpu_config"]["pa_block_size"]
     return {
-        "tpu": dict(batch_size=PROBE_SLOTS, output_logits=True, pa_num_blocks=1 + 2 * per_row),
+        "tpu": dict(cfg.get("probe_tpu_config") or {}, batch_size=PROBE_SLOTS, output_logits=True,
+                    pa_num_blocks=1 + 2 * per_row),
         "chunked": dict(max_num_seqs=PROBE_SLOTS),
     }
 
@@ -128,13 +176,15 @@ def _session_tokens(probe, prompts: List[np.ndarray], budget: int) -> List[List[
     return out
 
 
-def _forced_logits(probe, prompts: List[np.ndarray], forced: List[List[int]],
-                   width: int) -> List[np.ndarray]:
+def _forced_pass(probe, prompts: List[np.ndarray], forced: List[List[int]], width: int):
     """Teacher-forced pass through ``app.forward`` on the paged cache: the
     prompt in chunks of the session's chunk size, then one decode step per
     forced token. Row r owns blocks 1 + r*per_row ... (block 0 is the
-    program's garbage block). Returns per prompt the (1 + steps, V) logits
-    at the last prompt position and after each forced token."""
+    program's garbage block). Returns (logits, choices): per prompt the
+    (1 + steps, V) logits at the last prompt position and after each forced
+    token, and, where ``forward`` returns a third value (module docstring,
+    "A model that chooses"), per prompt a dict ``name -> (len(prompt) +
+    steps, ...)`` of the choices made at every token of the row; else None."""
     tc = probe.config.tpu_config
     bs = tc.pa_block_size
     per_row = width // bs
@@ -144,6 +194,12 @@ def _forced_logits(probe, prompts: List[np.ndarray], forced: List[List[int]],
     seq_ids = np.arange(B, dtype=np.int32)
     slot = lambda r, pos: table[r, pos // bs] * bs + pos % bs
     got = [[] for _ in prompts]
+    chose = [{} for _ in prompts]  # row -> name -> pieces in token order
+
+    def keep(aux, r, n):
+        for name, a in (aux[0] if aux else {}).items():
+            chose[r].setdefault(name, []).append(np.asarray(a[r, :n]))
+
     longest = max(len(p) for p in prompts)
     for start in range(0, longest, chunk):
         ids = np.zeros((B, chunk), np.int32)
@@ -151,40 +207,52 @@ def _forced_logits(probe, prompts: List[np.ndarray], forced: List[List[int]],
         sm = np.full((B, chunk), -1, np.int32)
         mask = np.zeros((B, width), np.int32)
         rows = seq_ids.copy()
-        ends = {}
+        ends, live = {}, {}
         for r, p in enumerate(prompts):
             n = min(chunk, len(p) - start)
             pos[r] = start + np.arange(chunk)
             if n <= 0:
                 rows[r] = -1
                 continue
+            live[r] = n
             ids[r, :n] = p[start : start + n]
             sm[r, :n] = slot(r, start + np.arange(n))
             mask[r, : start + n] = 1
             if start + n == len(p):
                 ends[r] = n - 1
-        _, logits = probe.forward(ids, pos, rows, attention_mask=mask, slot_mapping=sm,
-                                  block_table=table, phase="tkg")
+        _, logits, *aux = probe.forward(ids, pos, rows, attention_mask=mask, slot_mapping=sm,
+                                        block_table=table, phase="tkg")
         for r, idx in ends.items():
             got[r].append(np.asarray(logits[r, idx], np.float32))
+        for r, n in live.items():
+            keep(aux, r, n)
     for step in range(PROBE_DECODE_STEPS):
         ids = np.asarray([[f[step]] for f in forced], np.int32)
         pos = np.asarray([[len(p) + step] for p in prompts], np.int32)
         mask = (np.arange(width)[None, :] <= pos).astype(np.int32)
-        _, logits = probe.forward(ids, pos, seq_ids, attention_mask=mask,
-                                  block_table=table, phase="tkg")
+        _, logits, *aux = probe.forward(ids, pos, seq_ids, attention_mask=mask,
+                                        block_table=table, phase="tkg")
         for r in range(B):
             got[r].append(np.asarray(logits[r, 0], np.float32))
-    return [np.stack(g) for g in got]
+            keep(aux, r, 1)
+    choices = [{name: np.concatenate(parts) for name, parts in row.items()} for row in chose]
+    return [np.stack(g) for g in got], choices if any(choices) else None
+
+
+def _forced_logits(probe, prompts: List[np.ndarray], forced: List[List[int]],
+                   width: int) -> List[np.ndarray]:
+    """The logits of ``_forced_pass``."""
+    return _forced_pass(probe, prompts, forced, width)[0]
 
 
 def serve_probe(cfg: dict, devices, seed: int, params, pspecs, max_prompt: int):
-    """(prompts, chosen, served): the two probe prompts, per prompt the
-    tokens that follow it (the long prompt's from the seed, the short
-    prompt's as the probe session chose them) and the served logits
+    """(prompts, chosen, served, choices): the two probe prompts, per prompt
+    the tokens that follow it (the long prompt's from the seed, the short
+    prompt's as the probe session chose them), the served logits
     (1 + PROBE_DECODE_STEPS, V) at the last prompt position and after each
-    of the first PROBE_DECODE_STEPS of them. The probe application is gone
-    when this returns."""
+    of the first PROBE_DECODE_STEPS of them, and the choices the forced pass
+    returned per row (None where the program returns none). The probe
+    application is gone when this returns."""
     vocab = system.model_attrs(cfg)["vocab_size"]
     over = probe_overrides(cfg, max_prompt)
     probe = system.build_app(cfg, devices, seed, tpu_overrides=over["tpu"],
@@ -198,10 +266,10 @@ def serve_probe(cfg: dict, devices, seed: int, params, pspecs, max_prompt: int):
         chosen = [[int(t) for t in rng.integers(0, vocab, size=budget)],
                   _session_tokens(probe, prompts[1:], budget)[0]]
         probe.init_kv_cache()
-        served = _forced_logits(probe, prompts, chosen, probe_width(cfg, max_prompt))
+        served, choices = _forced_pass(probe, prompts, chosen, probe_width(cfg, max_prompt))
     finally:
         probe.params = probe.kv_cache = None
-    return prompts, chosen, served
+    return prompts, chosen, served, choices
 
 
 def probe_row(prompt, chosen):
@@ -212,25 +280,33 @@ def probe_row(prompt, chosen):
     return tokens, [len(prompt) - 1 + k for k in range(PROBE_DECODE_STEPS + 1)]
 
 
-def judge(cfg: dict, params, degree: int, prompts, chosen, served) -> dict:
+def judge(cfg: dict, params, degree: int, prompts, chosen, served, choices=None) -> dict:
     """``served`` against the float32 reference and its bf16 twin, row by
-    row (module docstring). Raises CorrectnessError; returns the facts it
-    read: per row ``err``, ``floor``, ``scale``, ``ratio`` = err / floor and
-    the same ratio of root mean squares (steadier than a ratio of maxima;
-    printed, not judged)."""
+    row (module docstring); with a reference that replays (``CHOICES``),
+    both follow ``choices`` (per row, ``_forced_pass``'s) and every choosing
+    layer is held to its margin. Raises CorrectnessError; returns the facts
+    it read: per row ``err``, ``floor``, ``scale``, ``ratio`` = err / floor
+    and the same ratio of root mean squares (steadier than a ratio of
+    maxima; printed, not judged), and per choosing layer ``regret``,
+    ``score_floor`` and the decisions that are not float32's own."""
     reference = load_reference(cfg)
     geo = reference.geometry(system.model_attrs(cfg), degree)
     budget = PROBE_DECODE_STEPS + 1
+    replay = bool(getattr(reference, "CHOICES", False))
     facts = {"K": K, "reference": reference.__name__.rsplit(".", 1)[-1],
              "prompts": [len(p) for p in prompts], "rows": []}
+    if replay and choices is None:
+        raise CorrectnessError(
+            "the configuration's reference replays choices and the program returned none", facts)
     errors = []
     rms = lambda a: float(np.sqrt(np.mean(np.square(a, dtype=np.float64))))
     for r, p in enumerate(prompts):
         tokens, positions = probe_row(p, chosen[r])
+        follow = {"choices": choices[r]} if replay else {}
         t0 = time.perf_counter()
-        ref = reference.reference_logits(params, geo, tokens, positions)
+        ref = reference.reference_logits(params, geo, tokens, positions, **follow)
         t1 = time.perf_counter()
-        twin = reference.twin_logits(params, geo, tokens, positions)
+        twin = reference.twin_logits(params, geo, tokens, positions, **follow)
         t2 = time.perf_counter()
         got = np.asarray(served[r], np.float32)
         err, floor = float(np.abs(got - ref).max()), float(np.abs(twin - ref).max())
@@ -238,12 +314,13 @@ def judge(cfg: dict, params, degree: int, prompts, chosen, served) -> dict:
         regret = None
         if r > 0:  # the short prompt's tokens are the session's
             regret = float(max(ref[k].max() - ref[k, chosen[r][k]] for k in range(budget)))
-        facts["rows"].append({"prompt": len(p), "err": err, "floor": floor,
-                              "scale": float(np.abs(ref).max()),
-                              "ratio": err / floor if floor > 0 else None,
-                              "limit": K * floor, "session_token_regret": regret,
-                              "rms_ratio": rms(got - ref) / max(rms(twin - ref), 1e-30),
-                              "ref32_s": t1 - t0, "twin_s": t2 - t1})
+        row = {"prompt": len(p), "err": err, "floor": floor,
+               "scale": float(np.abs(ref).max()),
+               "ratio": err / floor if floor > 0 else None,
+               "limit": K * floor, "session_token_regret": regret,
+               "rms_ratio": rms(got - ref) / max(rms(twin - ref), 1e-30),
+               "ref32_s": t1 - t0, "twin_s": t2 - t1}
+        facts["rows"].append(row)
         if not np.isfinite(got).all():
             errors.append(f"prompt {r}: non-finite logits from the served model")
         if not err <= K * floor:
@@ -253,6 +330,18 @@ def judge(cfg: dict, params, degree: int, prompts, chosen, served) -> dict:
                 f"prompt {r}: a token the session chose is {regret:.4g} below the "
                 f"reference's best, more than {K} x the bf16 twin's error {floor:.4g}"
             )
+        if replay:
+            margins, score_floor, *differing = reference.choice_margins(params, geo, tokens, choices[r])
+            margins, score_floor = (np.asarray(a, np.float64).ravel() for a in (margins, score_floor))
+            row.update(choice_regret=margins.tolist(), choice_score_floor=score_floor.tolist(),
+                       choice_limit=(2 * K * score_floor).tolist(), choices_s=time.perf_counter() - t2)
+            if differing:
+                row["choices_not_float32s"] = np.asarray(differing[0]).ravel().astype(int).tolist()
+            for l in np.flatnonzero(~(margins <= 2 * K * score_floor)):
+                errors.append(
+                    f"prompt {r}: margin: a choice of choosing layer {l} scores {margins[l]:.4g} "
+                    f"below the best candidate, more than 2 x {K} x the bf16 twin's score error "
+                    f"{score_floor[l]:.4g}")
     if errors:
         raise CorrectnessError("; ".join(errors), facts)
     return facts
@@ -280,3 +369,22 @@ def check_window(records, session, vocab: int) -> List[str]:
         if rec.finished and len(gen) != rec.budget:
             faults.append(f"{rec.req_id}: finished with {len(gen)} of {rec.budget} tokens")
     return faults
+
+
+def compared(model_facts: dict, faults: List[str], compiled_in_window: int,
+             tokens_counted: int, tokens_stamped: int) -> Dict[str, List[float]]:
+    """name -> [number, limit] of everything ``correct`` was decided by: per
+    probe row the logit error and the session's regret against K x floor
+    and, where choices were replayed, each choosing layer's regret against
+    2 K x its score floor; then the window's exact comparisons."""
+    out = {}
+    for r, row in enumerate(model_facts.get("rows", [])):
+        out[f"row{r}.logit_err"] = [row["err"], row["limit"]]
+        if row["session_token_regret"] is not None:
+            out[f"row{r}.session_regret"] = [row["session_token_regret"], row["limit"]]
+        for l, pair in enumerate(zip(row.get("choice_regret", []), row.get("choice_limit", []))):
+            out[f"row{r}.choice_regret.{l}"] = list(pair)
+    out["window_faults"] = [len(faults), 0]
+    out["compiled_in_window"] = [compiled_in_window, 0]
+    out["tokens_not_stamped"] = [abs(tokens_counted - tokens_stamped), 0]
+    return out

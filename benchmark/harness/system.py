@@ -2,11 +2,34 @@
 its weights on the device(s) from the seed, and the warm-up of the shapes a
 cell can reach. The only file of the benchmark that imports the program.
 
-What is copied from the repo's own tools, and why it is a copy: the
-construction follows ``bench.build_app`` (block_kv branch) and the weight
-hand-over follows ``chip_smoke.build(weights_from=...)``; ``CompileLog`` is
-``chip_smoke.CompileLog``. PR 21 proved all three on the chip. The benchmark
-keeps its own so that a later PR can change those tools, not the yardstick.
+The benchmark keeps its own construction (``build_app``), weight hand-over
+(``give_weights``) and compile counter (``CompileLog``) so that a PR can
+change the program's tools and not the yardstick.
+
+Keys of a configuration file that are the benchmark's and never reach the
+model's attributes (``META_KEYS``), two of them for a model that needs more
+than the default:
+
+``weights``
+    an ordered list of rules for ``make_weights``; the first whose ``match``
+    (a regular expression searched in ``"/".join(path)`` of a leaf, e.g.
+    ``layers/mlp/router/weight``) finds the leaf decides it:
+        {"match": ..., "std": s}               N(0, s)
+        {"match": ..., "mean": m, "std": s}    m + N(0, s)
+        {"match": ..., "value": v}             the constant v
+    A leaf that no rule matches gets the default (``rope`` = the inverse
+    frequencies, a path with "norm" in it 1 + N(0, 0.05), anything else
+    N(0, 0.02)). So a configuration can say "this balancing bias is 0",
+    "this gate is about 1", "this router has std s" in its own file. A rule
+    that matches no leaf is an error that names it. A configuration that
+    declares no rules gets, bit for bit, the weights it got before the key
+    existed (``selftest/test_weights_rules.py`` holds that against a frozen
+    copy).
+``probe_tpu_config``
+    a dict of ``TpuConfig`` options that ``correct.probe_overrides`` lays
+    over the PROBE application only (as it lays ``output_logits``): where a
+    model makes discrete choices, the option of the program that makes the
+    step return them (``correct.py``'s docstring, "A model that chooses").
 """
 
 from __future__ import annotations
@@ -19,7 +42,8 @@ from typing import Dict, Iterable, List, Optional, Tuple
 #: model; every other top-level key is an attribute of the model's config
 META_KEYS = frozenset(
     {"name", "source", "deployment", "assumed", "reduced", "tpu_config",
-     "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference"}
+     "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference",
+     "weights", "probe_tpu_config"}
 )
 
 WEIGHT_STD = 0.02
@@ -119,14 +143,37 @@ def _is_norm(path: Tuple[str, ...]) -> bool:
     return any("norm" in p for p in path)
 
 
-def make_weights(app, seed: int):
-    """(params, pspecs): the whole parameter tree made on the device(s) in
-    ONE jitted call from ``seed``, in the dtype it is served in, each leaf
-    born with the sharding the builder declares — nothing passes through
-    the host or through one chip. Matrices are N(0, 0.02), layer by layer
-    (a float32 temporary of one layer, not of the stack); norm weights are
-    1 + N(0, 0.05), so that a norm weight applied wrongly shows against the
-    reference; a tied model gets the transposed head the program keeps."""
+class WeightRuleError(ValueError):
+    """A ``weights`` rule of a configuration is malformed or matches no leaf."""
+
+
+def _leaf_rules(rules, paths) -> List[Optional[Tuple[float, float]]]:
+    """Per leaf the (mean, std) of the first rule that matches its path
+    (std None: the constant ``mean``), or None where no rule does."""
+    import re
+
+    parsed = []
+    for rule in rules or ():
+        keys = set(rule)
+        if keys == {"match", "value"}:
+            dist = (float(rule["value"]), None)
+        elif keys in ({"match", "std"}, {"match", "mean", "std"}):
+            dist = (float(rule.get("mean", 0.0)), float(rule["std"]))
+        else:
+            raise WeightRuleError(
+                f"weights rule {rule!r}: give 'match' with 'std', 'mean' and 'std', or 'value'")
+        parsed.append((re.compile(rule["match"]), dist, rule))
+    joined = ["/".join(p) for p in paths]
+    out = [next((dist for rx, dist, _ in parsed if rx.search(name)), None) for name in joined]
+    for rx, _, rule in parsed:
+        if not any(rx.search(name) for name in joined):
+            raise WeightRuleError(f"weights rule {rule!r} matches no leaf of {sorted(joined)}")
+    return out
+
+
+def weights_program(app, rules=None):
+    """(generate, out_shardings, pspecs): ``generate(key)`` is the function
+    ``make_weights`` jits, apart so that a test can read its jaxpr."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -143,24 +190,32 @@ def make_weights(app, seed: int):
         shapes, is_leaf=lambda x: isinstance(x, tuple)
     )
     paths = [tuple(getattr(k, "key", str(k)) for k in kp) for kp, _ in flat]
+    declared = _leaf_rules(rules, paths)
     inv_freq = compute_inv_freq(app.config)
 
-    def one(key, path, shape):
+    def draw(key, shape, mean, std):
+        """mean + N(0, std) in the served dtype; a leaf of rank >= 3 one
+        leading index at a time, so that the float32 temporary is one matrix
+        (one expert's, of a stack over layers and experts)."""
+        if len(shape) >= 3:
+            keys = jax.random.split(key, shape[0])
+            return jax.lax.map(lambda k: draw(k, shape[1:], mean, std), keys)
+        noise = std * jax.random.normal(key, shape, jnp.float32)
+        return (noise if mean == 0.0 else mean + noise).astype(dtype)
+
+    def one(key, path, shape, rule):
+        if rule is not None:
+            mean, std = rule
+            return jnp.full(shape, mean, dtype) if std is None else draw(key, shape, mean, std)
         if path[0] == "rope":
             return inv_freq
         if _is_norm(path):
-            return (1.0 + 0.05 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
-        if len(shape) == 3:  # stacked over layers
-            keys = jax.random.split(key, shape[0])
-            return jax.lax.map(
-                lambda k: (WEIGHT_STD * jax.random.normal(k, shape[1:], jnp.float32)).astype(dtype),
-                keys,
-            )
-        return (WEIGHT_STD * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+            return draw(key, shape, 1.0, 0.05)
+        return draw(key, shape, 0.0, WEIGHT_STD)
 
     def generate(key):
         keys = jax.random.split(key, len(flat))
-        leaves = [one(k, p, s) for k, p, (_, s) in zip(keys, paths, flat)]
+        leaves = [one(k, p, s, r) for k, p, (_, s), r in zip(keys, paths, flat, declared)]
         params = jax.tree_util.tree_unflatten(treedef, leaves)
         if tied:
             params["lm_head"] = {"weight": params["embed_tokens"]["weight"].T}
@@ -170,6 +225,23 @@ def make_weights(app, seed: int):
         return NamedSharding(app.mesh, spec if spec is not None else P())
 
     out_shardings = jax.tree.map(sharding, pspecs, is_leaf=lambda x: isinstance(x, P) or x is None)
+    return generate, out_shardings, pspecs
+
+
+def make_weights(app, seed: int, rules=None):
+    """(params, pspecs): the whole parameter tree made on the device(s) in
+    ONE jitted call from ``seed``, in the dtype it is served in, each leaf
+    born with the sharding the builder declares — nothing passes through
+    the host or through one chip. ``rules`` is the configuration's
+    ``weights`` list (module docstring); a leaf it does not name is
+    N(0, 0.02), or 1 + N(0, 0.05) for a norm weight, so that a norm weight
+    applied wrongly shows against the reference. Stacks are drawn one matrix
+    at a time (a float32 temporary of one layer's or one expert's matrix,
+    not of the stack); a tied model gets the transposed head the program
+    keeps."""
+    import jax
+
+    generate, out_shardings, pspecs = weights_program(app, rules)
     # a large seed folds into the key's two 32-bit words
     key = jax.random.fold_in(jax.random.PRNGKey(int(seed) % (2**31)), int(seed) >> 31)
     with jax.set_mesh(app.mesh):

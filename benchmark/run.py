@@ -8,7 +8,11 @@ cell's configuration file, makes its bf16 weights on the device(s) from
 ``--seed``, checks the served logits against the plain reference, warms the
 shapes the cell's traffic can reach, offers the traffic for ``--seconds`` on
 the wall clock, and prints ONE last line of JSON: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``. With
+``failed``, ``metrics``, ``device``, traced ``breakdown``, ``host`` (what
+the host's cores did while the window ran: facts for the reader of a run
+that reads off its set, ``harness/hostfacts.py``; the driver ignores the
+key) and last ``compared``: every number ``correct`` was decided by, beside its limit
+(the same, a line each, are the run's last lines on standard error). With
 ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` a slice at the end of the window is profiled and the metrics
 are the cell's per-layer metrics. ``--trace 2`` is a ``--trace 0`` run up to
@@ -145,7 +149,7 @@ def warm_drive(app, vocab: int, seed: int):
 
 def main(argv=None) -> int:
     args = parse(argv)
-    from benchmark.harness import catalog, correct, device, stats, system
+    from benchmark.harness import catalog, correct, device, hostfacts, stats, system
     from benchmark.harness.driver import LoadDriver
     from benchmark.harness.traffic import Traffic, scale_mix
 
@@ -168,7 +172,7 @@ def main(argv=None) -> int:
     # ---- set-up: model, weights, correctness, warm-up -----------------------
     t = time.perf_counter()
     app = system.build_app(cfg, devices, args.seed)
-    params, pspecs = system.make_weights(app, args.seed)
+    params, pspecs = system.make_weights(app, args.seed, cfg.get("weights"))
     emit(phase="weights", seconds=time.perf_counter() - t, **log.facts())
     spec, mix = cell.spec, cell.traffic
     if rehearsal:
@@ -200,12 +204,13 @@ def main(argv=None) -> int:
 
     from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
 
-    telemetry = None
-    if args.trace:
-        from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
 
-        # --trace 2: present but stopped until the window has closed
-        telemetry = TelemetrySession(enabled=args.trace == 1)
+    # a session of the run's own in every mode, STOPPED unless --trace 1 (a
+    # stopped one is what ServingSession takes by default): up to the closing
+    # of the window a --trace 0 and a --trace 2 run execute the same
+    # statements, and only --trace 2 starts it, afterwards
+    telemetry = TelemetrySession(enabled=args.trace == 1)
     session = ServingSession(app, telemetry=telemetry)
     driver = LoadDriver(
         session, traffic, loop=spec["loop"], seconds=args.seconds,
@@ -224,7 +229,10 @@ def main(argv=None) -> int:
     setup_s = time.perf_counter() - T_PROCESS
 
     # ---- the window ---------------------------------------------------------
+    watch = hostfacts.HostWatch()
+    watch.start()
     wall = driver.run(on_tick=profiler.tick if profiler else None)
+    host = watch.stop()
     if profiler is not None and profiler.started is not None:
         profiler.tick(float("inf"))  # a window that ended between two ticks
     compiled_in_window = log.compiles - compiles_before
@@ -236,11 +244,13 @@ def main(argv=None) -> int:
     records = list(driver.records.values())
     summary = stats.summarize(records, driver.window_s)
     spans = stats.span_stats(driver.spans, driver.window_s)
+    host["steps"] = hostfacts.step_facts(driver.spans, driver.window_s)
     faults = correct.check_window(records, session, attrs["vocab_size"])
     counted = sum(len(session.requests[r.req_id].generated) for r in records
                   if r.req_id in session.requests)
     stamped = sum(r.tokens for r in records)
     emit(phase="window", wall_s=wall, window_s=driver.window_s, summary=summary, spans=spans,
+         host=host,
          compiled_in_window=compiled_in_window, faults=faults[:10],
          tokens_counted=counted, tokens_stamped=stamped,
          preemptions=sum(getattr(session.requests[r.req_id], "preemptions", 0)
@@ -250,6 +260,7 @@ def main(argv=None) -> int:
          in_flight_mid=_in_flight(records, args.seconds * 0.5),
          in_flight_end=_in_flight(records, args.seconds))
     correct_all = bool(model_ok and not faults and compiled_in_window == 0 and counted == stamped)
+    compared = correct.compared(model_facts, faults, compiled_in_window, counted, stamped)
 
     device_out = dict(device_info, memory_peak_bytes=device.memory_peak_bytes(devices))
     metrics, breakdown = {}, None
@@ -326,11 +337,18 @@ def main(argv=None) -> int:
               "failed": int(summary["failed"]), "metrics": metrics, "device": device_out}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    result["host"] = host  # what the host did meanwhile (harness/hostfacts.py); the driver ignores it
+    result["compared"] = compared  # every number beside its limit; the last key of the line
     if args.out:
         os.makedirs(args.out, exist_ok=True)
         with open(os.path.join(args.out, f"{cell.name}.seed{args.seed}.trace{args.trace}.json"), "w") as f:
             json.dump({"result": result, "summary": summary, "spans": spans}, f, indent=1)
     print(json.dumps(result), flush=True)
+    for name, (number, limit) in compared.items():
+        print(f"compared {name}: {number!r} limit {limit!r}", file=sys.stderr)
+    if not model_ok:
+        print(f"compared: {model_facts['error']}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -72,12 +72,12 @@ def main(argv=None) -> int:
         seed, max_prompt = args.first_seed + 2 * k, widths[k % len(widths)]
         t0 = time.perf_counter()
         app = system.build_app(cfg, devices, seed)
-        params, pspecs = system.make_weights(app, seed)
+        params, pspecs = system.make_weights(app, seed, cfg.get("weights"))
         t1 = time.perf_counter()
-        prompts, chosen, served = correct.serve_probe(cfg, devices, seed, params, pspecs, max_prompt)
+        prompts, chosen, served, choices = correct.serve_probe(cfg, devices, seed, params, pspecs, max_prompt)
         t2 = time.perf_counter()
         try:
-            facts, ok = correct.judge(cfg, params, degree, prompts, chosen, served), True
+            facts, ok = correct.judge(cfg, params, degree, prompts, chosen, served, choices), True
         except correct.CorrectnessError as e:
             facts, ok = {"error": str(e), **e.facts}, False
         t3 = time.perf_counter()
@@ -86,11 +86,13 @@ def main(argv=None) -> int:
         line = dict(seed=seed, max_prompt=max_prompt, ok=ok, weights_s=t1 - t0, probe_s=t2 - t1,
                     judge_s=t3 - t2, rows=facts["rows"], error=facts.get("error"))
         if k < args.control:
+            # a reference that replays follows the served routes at fp8 too
+            follow = lambda r: {"choices": choices[r]} if getattr(reference, "CHOICES", False) else {}
             fp8 = [reference.reference_logits(params, geo, *correct.probe_row(p, chosen[r]),
-                                              rounding=jnp.float8_e4m3fn)
+                                              rounding=jnp.float8_e4m3fn, **follow(r))
                    for r, p in enumerate(prompts)]
             try:
-                facts8, passed = correct.judge(cfg, params, degree, prompts, chosen, fp8), True
+                facts8, passed = correct.judge(cfg, params, degree, prompts, chosen, fp8, choices), True
             except correct.CorrectnessError as e:
                 facts8, passed = e.facts, False
             bad += passed

@@ -85,8 +85,7 @@ def test_a_cell_file_says_what_benchmark_json_says_and_sits_where_it_says():
             assert round(cell.spec["rate_rps"] * bench["run_seconds"]) == knee["due_in_window"]
 
 
-@pytest.fixture
-def copy(tmp_path):
+def copy_catalog(tmp_path):
     root = tmp_path / "repo"
     (root / "benchmark").mkdir(parents=True)
     shutil.copy(os.path.join(catalog.REPO_DIR, "BENCHMARK.json"), root / "BENCHMARK.json")
@@ -95,9 +94,39 @@ def copy(tmp_path):
     return root
 
 
+@pytest.fixture
+def copy(tmp_path):
+    return copy_catalog(tmp_path)
+
+
 def write(path, obj):
     with open(path, "w") as f:
         json.dump(obj, f)
+
+
+def add_configuration_with_decode_cell(root, name: str, **keys) -> dict:
+    """What a later PR does with files and entries alone, on a copy of the
+    catalog: ``configs/<name>.json`` (the qwen3-1p7b file with ``keys`` laid
+    over it), the cell ``<name>.decode`` (qwen3-1p7b.decode's file and
+    metrics) and their entries. Returns the configuration written."""
+    bdir = root / "benchmark"
+    with open(bdir / "configs" / "qwen3-1p7b.json") as f:
+        cfg = dict(json.load(f), name=name, **keys)
+    write(bdir / "configs" / (name + ".json"), cfg)
+    with open(bdir / "workloads" / "qwen3-1p7b.decode.json") as f:
+        spec = dict(json.load(f), config=name)
+    write(bdir / "workloads" / (name + ".decode.json"), spec)
+    with open(root / "BENCHMARK.json") as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": name, "source": cfg["source"], "reduced": [],
+                             "file": f"benchmark/configs/{name}.json", "why": "x"})
+    bench["workloads"].append({"name": name + ".decode", "config": name, "traffic": "decode",
+                               "chips": 1, "why": spec["why"]})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "qwen3-1p7b.decode" in m.get("workloads", []):
+            m["workloads"] = m["workloads"] + [name + ".decode"]
+    write(root / "BENCHMARK.json", bench)
+    return cfg
 
 
 def test_a_later_pr_adds_files_and_entries_only(copy, monkeypatch):
@@ -179,6 +208,28 @@ def test_a_later_pr_adds_files_and_entries_only(copy, monkeypatch):
         reader = importlib.import_module("benchmark.harness.readers." + m["reader"]["reader"])
         got[m["name"]] = reader.read(m["reader"], ctx)
     assert got == {"sched.decode_steps": 42.0, "admit.call_ms": 100.0}
+
+
+def test_a_configuration_may_carry_weight_rules_and_probe_options(copy):
+    """``weights`` and ``probe_tpu_config`` are the benchmark's keys: a later
+    PR's configuration file may carry both, the catalog loads it, and neither
+    reaches the model's attributes or the served application's options."""
+    import jax
+
+    from benchmark.harness import system
+
+    rules = [{"match": "router/weight$", "std": 0.5}, {"match": "q_norm", "mean": 1.0, "std": 0.0}]
+    add_configuration_with_decode_cell(copy, "ruled-1b", weights=rules,
+                                       probe_tpu_config={"output_logits": False})
+    cell = catalog.check_catalog(root=str(copy))["ruled-1b.decode"]
+    assert cell.config["weights"] == rules and cell.config["probe_tpu_config"] == {"output_logits": False}
+    run = system.resolve_config(cell.config, rehearsal=True)
+    assert not {"weights", "probe_tpu_config"} & set(model_attrs(run))
+    app = system.build_app(run, jax.devices()[:1], 1)
+    assert not hasattr(app.config, "weights") and not hasattr(app.config, "probe_tpu_config")
+    assert app.config.tpu_config.output_logits is False  # the served application: the file's, not the probe's
+    probe = correct.probe_overrides(run, 64)["tpu"]
+    assert probe["output_logits"] is True and probe["batch_size"] == correct.PROBE_SLOTS
 
 
 def test_a_reader_that_finds_nothing_returns_nothing():

@@ -35,7 +35,11 @@ def test_rehearsal_of_every_cell(cell, chips):
     p = run(cell, "--trace", "0", devices=chips)
     assert p.returncode == 0, p.stderr[-2000:]
     last = json.loads(p.stdout.strip().splitlines()[-1])
-    assert set(last) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(last) == ["correct", "attempted", "failed", "metrics", "device", "host", "compared"]
+    assert last["host"]["steps"]["count"] > 0 and last["host"]["wall_s"] > 0  # harness/hostfacts.py
+    assert all(number <= limit for number, limit in last["compared"].values())
+    shown = [l.split()[1].rstrip(":") for l in p.stderr.splitlines()[-len(last["compared"]):]]
+    assert shown == list(last["compared"])  # the same, beside their limits, end standard error
     assert last["correct"] is True and last["failed"] == 0 and last["attempted"] > 0
     assert last["device"]["platform"] == "cpu" and last["device"]["count"] == chips
     assert all(m["unit"] == "count" for m in last["metrics"].values())

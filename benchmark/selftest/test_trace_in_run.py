@@ -36,7 +36,8 @@ def test_trace_2_is_a_trace_0_run_followed_by_a_traced_phase(cell, chips):
         assert w["summary"]["failed"] == 0
     if f0["warm_up"]["traffic"]["loop"] == "open":  # a closed loop sends by the machine's speed
         assert f2["window"]["summary"]["attempted"] == f0["window"]["summary"]["attempted"]
-    assert set(last2) == set(last0) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(last2) == set(last0) == {"correct", "attempted", "failed", "metrics", "device", "host", "compared"}
+    assert last2["compared"] == last0["compared"]  # same seed: the same weights, probe and verdict
     assert last2["correct"] is True and last2["failed"] == 0
     # held at the closing of the window: the line's counts are the window's
     assert last2["attempted"] == f2["window"]["summary"]["attempted"]
@@ -62,3 +63,19 @@ def test_trace_2_is_a_trace_0_run_followed_by_a_traced_phase(cell, chips):
 
 def test_the_trace_is_deleted_once_it_is_reduced():
     assert not os.path.exists(os.path.join(catalog.REPO_DIR, ".bench_cache", "trace"))
+
+
+def test_no_statement_before_the_window_closes_tells_trace_2_from_trace_0():
+    """Read off ``run.py`` itself: up to the reduction of the closed window,
+    ``--trace`` is looked at only to ask whether it is 1 (the older separate
+    traced run) and to print it; a ``--trace 2`` run therefore executes the
+    statements of a ``--trace 0`` run, the same objects built and nothing of
+    the profiler imported, started or allocated (PR 34's refusal round)."""
+    import re
+
+    with open(os.path.join(os.path.dirname(catalog.__file__), "..", "run.py")) as f:
+        main = f.read().split("def main(", 1)[1]
+    before, after = main.split("# ---- reduction", 1)
+    uses = re.findall(r"args\.trace\b[^\n]{0,8}", before)
+    assert uses and all(u.startswith(("args.trace == 1", "args.trace,")) for u in uses), uses
+    assert "args.trace == 2" in after  # the traced phase comes after, and only there
