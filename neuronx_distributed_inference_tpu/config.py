@@ -319,6 +319,11 @@ class TpuConfig:
     on_device_sampling_config: Optional[OnDeviceSamplingConfig] = None
     max_topk: int = 256
     output_logits: bool = False
+    # the step also returns the discrete choices its layers made (an expert
+    # layer's selection: name -> int (B, S, L_moe, k)) as ``forward``'s third
+    # value; for a correctness probe that replays them. A model whose stack
+    # returns none refuses it at trace time (models/base.forward)
+    output_choices: bool = False
 
     # --- KV cache --------------------------------------------------------
     # None = store in `dtype`; "int8"/"fp8" build the quantized cache
@@ -974,30 +979,32 @@ CONFIG_FILE = "tpu_config.json"  # reference: neuron_config.json (config.py:22)
 
 class SlotStateServingError(NotImplementedError):
     """An option that cannot serve a model whose layers keep a constant-size
-    per-slot state (state-space layers) was set for one."""
+    per-slot state (state-space layers; a one-token carry) was set for one."""
 
 
-def validate_slot_state_serving(tc: "TpuConfig") -> None:
+def validate_slot_state_serving(tc: "TpuConfig", what: str = "state-space layers",
+                                state: str = "recurrent state") -> None:
     """Refuse, for a model whose builder declares per-slot state
-    (``cache_layers()`` with ``SLOT_STATE``), every option that would serve
-    it wrongly rather than not at all. One line each: none is a silent
-    wrong answer."""
+    (``init_slot_state()``: ``what`` keeps a ``state`` per slot), every
+    option that would serve it wrongly rather than not at all. One line
+    each: none is a silent wrong answer."""
     speculation = (
         tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
         or tc.enable_eagle_speculation or tc.serving_spec_ragged
     )
     refusals = (
-        (tc.is_prefix_caching, "is_prefix_caching: a recurrent state cannot be shared block by block"),
-        (tc.serving_ragged, "serving_ragged: the ragged mixed step runs attention layers only"),
-        (speculation, "speculation: rejected drafts would need a snapshot of the state to roll back to"),
-        (tc.kv_quantized, "kv_cache_dtype quantisation: the recurrent state is kept in float32"),
+        (tc.is_prefix_caching, f"is_prefix_caching: a {state} cannot be shared block by block"),
+        (tc.serving_ragged, f"serving_ragged: the ragged mixed step advances no {state}"),
+        (speculation, f"speculation: rejected drafts would need a snapshot of the {state} to roll back to"),
+        (tc.kv_quantized, f"kv_cache_dtype quantisation: the {state} is kept unquantised beside the pool "
+                          "and the two are not held to a reference together"),
         (tc.tp_degree * tc.ep_degree * tc.cp_degree * tc.attention_dp_degree
          * tc.data_parallel_degree > 1,
-         "tp/ep/cp/dp degree > 1: the state update is not partitioned"),
+         f"tp/ep/cp/dp degree > 1: the {state}'s update is not partitioned"),
     )
     for flag, why in refusals:
         if flag:
-            raise SlotStateServingError(f"a model with state-space layers cannot be served with {why}")
+            raise SlotStateServingError(f"a model with {what} cannot be served with {why}")
 
 
 class InferenceConfig:

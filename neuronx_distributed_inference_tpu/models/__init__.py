@@ -17,3 +17,4 @@ from neuronx_distributed_inference_tpu.models import gpt_oss  # noqa: F401
 from neuronx_distributed_inference_tpu.models import dbrx  # noqa: F401
 from neuronx_distributed_inference_tpu.models import llama4  # noqa: F401
 from neuronx_distributed_inference_tpu.models import granite_hybrid  # noqa: F401
+from neuronx_distributed_inference_tpu.models import zaya  # noqa: F401

@@ -109,6 +109,9 @@ class ModelSpec:
     do_sample: bool = False
     max_topk: int = 256
     output_logits: bool = False
+    # the step also returns the discrete choices its layers made (an expert
+    # layer's selection), as StepOutput.aux: config ``output_choices``
+    output_choices: bool = False
     cast_logits_fp32: bool = True
     # rope
     attention_scaling: float = 1.0
@@ -141,10 +144,40 @@ class LayerStack:
     final norm to what it returns:
 
         stack(params, hidden, cache, inputs, *, spec, phase, mlp_fn) -> (hidden, new_cache)
+
+    A stack whose layers make discrete choices (models/zaya.py: an expert
+    layer's selection) returns them as a third value under
+    ``spec.output_choices``: a dict ``name -> int array (B, S, ...)``, which
+    :func:`forward` hands on as ``StepOutput.aux``.
     """
 
     def __call__(self, params, hidden, cache, inputs, *, spec, phase, mlp_fn):
         raise NotImplementedError
+
+
+def slot_state_rows(inputs: "StepInputs", num_slots: int):
+    """For a :class:`LayerStack` whose layers keep a constant-size state per
+    serving slot, from a pass's inputs: ``valid`` (B, S) — a position
+    advances the state iff its row is live and it writes K/V somewhere real
+    (padded chunk tails and rows that sit a pass out carry slot_mapping /
+    seq_id -1); ``reset`` (B,) — state lifetime without a host call: a row
+    whose first position in this pass is 0 starts from zero state (a new
+    request in a reused slot, a re-prefill after preemption, a probe's fresh
+    cache); ``slots`` (B,) — whose state a row advances. The chunk program
+    (handed a slot mapping) is chunk_rows wide and its rows carry their slot
+    in ``seq_ids``; an empty row gets an index of its own past the last
+    slot, so its write-back is dropped and the indices stay unique. The
+    decode program has one row per slot: row r owns slot r, ``slots`` None."""
+    positions = inputs.position_ids
+    valid = jnp.broadcast_to((inputs.seq_ids >= 0)[:, None], positions.shape)
+    if inputs.slot_mapping is not None:
+        valid = valid & (inputs.slot_mapping >= 0)
+    reset = valid[:, 0] & (positions[:, 0] == 0)
+    slots = None
+    if inputs.slot_mapping is not None:
+        rows = jnp.arange(positions.shape[0], dtype=jnp.int32)
+        slots = jnp.where(inputs.seq_ids >= 0, inputs.seq_ids, num_slots + rows)
+    return valid, reset, slots
 
 
 def residual_add(residual: jax.Array, update: jax.Array, spec: "ModelSpec") -> jax.Array:
@@ -194,6 +227,9 @@ class StepOutput:
     tokens: jax.Array  # (B, K) int32
     logits: Optional[jax.Array]  # (B, K, V) or None
     cache: KVCache
+    # spec.output_choices: name -> int (B, S, ...) choices of the pass (an
+    # expert layer: (B, S, L_moe, k)); None = no leaf, the program is the same
+    aux: Optional[dict] = None
 
 
 #: sentinel emitted in place of a sampled/argmax token when the row's logits
@@ -369,6 +405,123 @@ def contiguous_decode_attend(
     return attn_out
 
 
+def paged_attend(
+    q: jax.Array,  # (B, Sq, Hq, D)
+    k_cache: jax.Array,  # the stacked block pool, this pass's K/V already written
+    v_cache: jax.Array,
+    layer_idx: jax.Array,
+    mask: jax.Array,
+    block_table: jax.Array,  # (B, MB)
+    kv_limit: jax.Array,  # (B,)
+    positions: jax.Array,
+    spec: ModelSpec,
+    sink: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Attention of the split serving step over the paged cache, for any
+    layer that pages K/V at ``(H_kv, D)``: a prefill chunk rides the paged
+    flash kernel, a decode / speculation step the paged TKG kernel, each
+    where its gate admits the call (ops/kernel_mode); else blocks are
+    gathered by the table and attended natively. Shared by
+    :func:`decoder_layer` and the stacks that compute q, k, v their own way
+    (models/zaya.py)."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        read_block_cache_at_layer,
+    )
+    from neuronx_distributed_inference_tpu.ops.paged_flash_attention import (
+        _use_paged_flash,
+        dispatch_paged_flash,
+    )
+
+    aspec = spec.attn
+    Sq = q.shape[1]
+    # the paged kernels launch per head shard of the mesh (no collective
+    # inside); attention-DP shards the BATCH around the attention
+    # instead, and keeps the native path
+    dp_shards = spec.attention_dp * spec.data_parallel
+    # the paged kernel implements the plain causal+prefix mask only: the
+    # MODEL must have no windowed/chunked attention anywhere, including
+    # inside layer groups (a group's mask never reaches the kernel)
+    plain_model = (
+        not spec.sliding_window
+        and not spec.attention_chunk_size
+        and (
+            spec.layer_groups is None
+            or all(
+                g.sliding_window is None and g.attention_chunk_size is None
+                for g in spec.layer_groups
+            )
+        )
+    )
+    if (
+        sink is None
+        and plain_model
+        and dp_shards == 1
+        and _use_paged_flash(aspec, Sq)
+    ):
+        # chunked/prefix prefill rides the paged flash kernel: blocks are
+        # DMA'd straight from the cache via the block table — no gather
+        # materialization (reference flash_pa_with_schedule.py:157). A
+        # quantized cache hands the kernel this layer's code blocks plus
+        # per-head dequant factors — the prior-KV path reads narrow tiles
+        ks = vs = None
+        if isinstance(k_cache, QuantizedKV):
+            ks = layer_dequant_factors(k_cache, layer_idx)
+            vs = layer_dequant_factors(v_cache, layer_idx)
+            k_arr, v_arr = k_cache.data, v_cache.data
+        else:
+            k_arr, v_arr = k_cache, v_cache
+        k_l = jax.lax.dynamic_index_in_dim(k_arr, layer_idx, axis=0, keepdims=False)
+        v_l = jax.lax.dynamic_index_in_dim(v_arr, layer_idx, axis=0, keepdims=False)
+        attn_out = dispatch_paged_flash(
+            q, k_l, v_l, block_table, positions, kv_limit,
+            scale=aspec.softmax_scale,
+            n_rep=aspec.num_heads // aspec.num_kv_heads,
+            k_scale=ks, v_scale=vs,
+            interpret=kernel_interpret(),
+        )
+    else:
+        from neuronx_distributed_inference_tpu.ops.decode_attention import (
+            dispatch_paged_tkg_decode,
+            use_tkg_kernel,
+        )
+
+        bs = k_cache.shape[3]  # (L, NB+1, Hkv, bs, D) head-major
+        width_ok = mask.shape[-1] == block_table.shape[1] * bs
+        if (
+            dp_shards == 1
+            and width_ok
+            and k_cache.shape == v_cache.shape
+            and use_tkg_kernel(aspec, Sq, mask.shape[-1])
+        ):
+            # decode/speculation off the paged cache: blocks DMA'd via the
+            # block table — no gather materialization (reference block TKG
+            # mega kernel, attention_base.py:1609)
+            attn_out = dispatch_paged_tkg_decode(
+                q, k_cache, v_cache, layer_idx, block_table, mask, sink,
+                scale=aspec.softmax_scale,
+                interpret=kernel_interpret(),
+            )
+        else:
+            if dp_shards > 1:
+                # attention-DP over the paged cache: the batch shards over
+                # dp around the attention (GSPMD all-to-all heads<->batch)
+                # while the block pool stays REPLICATED over dp — any
+                # shard reads any block (the contiguous cache dp-shards
+                # its batch dim instead; reference attention_base.py:2308)
+                from neuronx_distributed_inference_tpu.parallel import (
+                    attention_dp as adp,
+                )
+
+                q = adp.shard_decode_q(q)
+            k_r, v_r = read_block_cache_at_layer(
+                k_cache, v_cache, layer_idx, block_table
+            )
+            attn_out = attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
+            if dp_shards > 1:
+                attn_out = adp.unshard_attn_out(attn_out)
+    return attn_out
+
+
 def _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn):
     """post-attention norm + MLP + residual."""
     residual = hidden
@@ -464,13 +617,11 @@ def decoder_layer(
         k_cache, v_cache = (k_full, k_ring), (v_full, v_ring)
     elif is_block:
         from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-            read_block_cache_at_layer,
             update_block_cache_at_layer,
         )
 
-        slot_mapping, block_table, kv_limit = block_inputs
         k_cache, v_cache = update_block_cache_at_layer(
-            k_cache, v_cache, k, v, layer_idx, slot_mapping,
+            k_cache, v_cache, k, v, layer_idx, block_inputs[0],
             packed=ragged_rows is not None,
         )
     else:
@@ -531,97 +682,10 @@ def decoder_layer(
             rs, rl, cl, aspec, interpret=kernel_interpret(),
         )
     elif is_block:
-        from neuronx_distributed_inference_tpu.ops.paged_flash_attention import (
-            _use_paged_flash,
-            dispatch_paged_flash,
+        attn_out = paged_attend(
+            q, k_cache, v_cache, layer_idx, mask, block_inputs[1], block_inputs[2],
+            positions, spec, sink,
         )
-
-        Sq = q.shape[1]
-        # the paged kernels launch per head shard of the mesh (no collective
-        # inside); attention-DP shards the BATCH around the attention
-        # instead, and keeps the native path
-        dp_shards = spec.attention_dp * spec.data_parallel
-        # the paged kernel implements the plain causal+prefix mask only: the
-        # MODEL must have no windowed/chunked attention anywhere, including
-        # inside layer groups (a group's mask never reaches the kernel)
-        plain_model = (
-            not spec.sliding_window
-            and not spec.attention_chunk_size
-            and (
-                spec.layer_groups is None
-                or all(
-                    g.sliding_window is None and g.attention_chunk_size is None
-                    for g in spec.layer_groups
-                )
-            )
-        )
-        if (
-            sink is None
-            and plain_model
-            and dp_shards == 1
-            and _use_paged_flash(aspec, Sq)
-        ):
-            # chunked/prefix prefill rides the paged flash kernel: blocks are
-            # DMA'd straight from the cache via the block table — no gather
-            # materialization (reference flash_pa_with_schedule.py:157). A
-            # quantized cache hands the kernel this layer's code blocks plus
-            # per-head dequant factors — the prior-KV path reads narrow tiles
-            ks = vs = None
-            if isinstance(k_cache, QuantizedKV):
-                ks = layer_dequant_factors(k_cache, layer_idx)
-                vs = layer_dequant_factors(v_cache, layer_idx)
-                k_arr, v_arr = k_cache.data, v_cache.data
-            else:
-                k_arr, v_arr = k_cache, v_cache
-            k_l = jax.lax.dynamic_index_in_dim(k_arr, layer_idx, axis=0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(v_arr, layer_idx, axis=0, keepdims=False)
-            attn_out = dispatch_paged_flash(
-                q, k_l, v_l, block_table, positions, kv_limit,
-                scale=aspec.softmax_scale,
-                n_rep=aspec.num_heads // aspec.num_kv_heads,
-                k_scale=ks, v_scale=vs,
-                interpret=kernel_interpret(),
-            )
-        else:
-            from neuronx_distributed_inference_tpu.ops.decode_attention import (
-                dispatch_paged_tkg_decode,
-                use_tkg_kernel,
-            )
-
-            bs = k_cache.shape[3]  # (L, NB+1, Hkv, bs, D) head-major
-            width_ok = mask.shape[-1] == block_table.shape[1] * bs
-            if (
-                dp_shards == 1
-                and width_ok
-                and k_cache.shape == v_cache.shape
-                and use_tkg_kernel(aspec, Sq, mask.shape[-1])
-            ):
-                # decode/speculation off the paged cache: blocks DMA'd via the
-                # block table — no gather materialization (reference block TKG
-                # mega kernel, attention_base.py:1609)
-                attn_out = dispatch_paged_tkg_decode(
-                    q, k_cache, v_cache, layer_idx, block_table, mask, sink,
-                    scale=aspec.softmax_scale,
-                    interpret=kernel_interpret(),
-                )
-            else:
-                if dp_shards > 1:
-                    # attention-DP over the paged cache: the batch shards over
-                    # dp around the attention (GSPMD all-to-all heads<->batch)
-                    # while the block pool stays REPLICATED over dp — any
-                    # shard reads any block (the contiguous cache dp-shards
-                    # its batch dim instead; reference attention_base.py:2308)
-                    from neuronx_distributed_inference_tpu.parallel import (
-                        attention_dp as adp,
-                    )
-
-                    q = adp.shard_decode_q(q)
-                k_r, v_r = read_block_cache_at_layer(
-                    k_cache, v_cache, layer_idx, block_table
-                )
-                attn_out = attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
-                if dp_shards > 1:
-                    attn_out = adp.unshard_attn_out(attn_out)
     elif bounded:
         attn_out = ring_attention(
             q, k, v, k_prior, v_prior, positions, spec.bounded_window, aspec, sink
@@ -801,11 +865,11 @@ def run_decoder_layers(
     if isinstance(layer_fn, LayerStack):
         from neuronx_distributed_inference_tpu.modules import tensor_taps
 
-        hidden, new_cache = layer_fn(
+        hidden, new_cache, *aux = layer_fn(
             params, hidden, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn
         )
         hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
-        return tensor_taps.tap("final_hidden", hidden), new_cache
+        return (tensor_taps.tap("final_hidden", hidden), new_cache, *aux)
 
     inv_freq = params["rope"]["inv_freq"]
     rope_pos = (
@@ -1139,9 +1203,12 @@ def model_logits(
     layer_fn: Optional[Callable] = None,
     return_hidden: bool = False,
     capture_layers: Optional[Tuple[int, ...]] = None,
+    return_aux: bool = False,
 ):
     """Backbone + lm head, no sampling: returns (logits (B, K, V), new cache)
-    [, full-sequence hidden states when ``return_hidden``].
+    [, full-sequence hidden states when ``return_hidden``; or, with
+    ``return_aux``, what a :class:`LayerStack` returned beside its cache
+    (``spec.output_choices``: the layers' choices), None where nothing did].
 
     ``capture_layers``: EAGLE3 — with ``return_hidden``, the returned hidden
     is the (B, S, C*H) multi-layer capture concat instead of the final hidden
@@ -1159,13 +1226,14 @@ def model_logits(
         if spec.embedding_multiplier != 1.0:
             hidden = (hidden.astype(jnp.float32) * spec.embedding_multiplier).astype(hidden.dtype)
     hidden = tensor_taps.tap("embed", hidden)
+    aux = ()
     if capture_layers is not None:
         hidden, new_cache, full_hidden = run_decoder_layers(
             params, hidden, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn,
             layer_fn=layer_fn, capture_layers=capture_layers,
         )
     else:
-        hidden, new_cache = run_decoder_layers(
+        hidden, new_cache, *aux = run_decoder_layers(
             params, hidden, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn,
             layer_fn=layer_fn,
         )
@@ -1179,6 +1247,8 @@ def model_logits(
     logits = tensor_taps.tap("logits", logits)
     if return_hidden:
         return logits, new_cache, full_hidden
+    if return_aux:
+        return logits, new_cache, (aux[0] if aux else None)
     return logits, new_cache
 
 
@@ -1518,9 +1588,15 @@ def forward(
     layer_fn: Optional[Callable] = None,
 ) -> StepOutput:
     """The traced step function (reference NeuronBaseModel.forward, model_base.py:732)."""
-    logits, new_cache = model_logits(
-        params, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn, layer_fn=layer_fn
+    logits, new_cache, aux = model_logits(
+        params, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn, layer_fn=layer_fn,
+        return_aux=True,
     )
+    if spec.output_choices and aux is None:
+        raise NotImplementedError(
+            "output_choices: this model's layer stack returns no choices "
+            "(models/zaya.py's does)"
+        )
     if spec.on_device_sampling:
         tokens = sample_tokens(
             logits,
@@ -1534,4 +1610,4 @@ def forward(
     tokens = mark_non_finite_tokens(tokens, logits)
 
     out_logits = logits if spec.output_logits else None
-    return StepOutput(tokens=tokens, logits=out_logits, cache=new_cache)
+    return StepOutput(tokens=tokens, logits=out_logits, cache=new_cache, aux=aux)

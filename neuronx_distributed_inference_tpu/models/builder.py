@@ -119,6 +119,7 @@ class DecoderModelBuilder:
             do_sample=bool(ods and ods.do_sample),
             max_topk=tc.max_topk,
             output_logits=tc.output_logits,
+            output_choices=tc.output_choices,
             cast_logits_fp32=tc.cast_logits_fp32,
             attention_scaling=rope_attention_scaling(cfg),
             norm_type=self.norm_type,
@@ -477,6 +478,12 @@ class DecoderModelBuilder:
         """(state pytree, its PartitionSpec tree) of the ``SLOT_STATE``
         layers for ``num_slots`` serving slots; None for a model whose layers
         all page."""
+        return None
+
+    def expert_layers(self):
+        """(expert layers, experts each holds here, experts per token) of a
+        model whose routed experts the serving step's ``nxdi_moe_*`` counters
+        count; None = none, or the builder does not say."""
         return None
 
     def cache_pspecs(self):

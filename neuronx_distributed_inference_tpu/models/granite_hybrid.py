@@ -52,6 +52,7 @@ from neuronx_distributed_inference_tpu.models.base import (
     decoder_layer,
     paged_block_inputs,
     residual_add,
+    slot_state_rows,
 )
 from neuronx_distributed_inference_tpu.models.builder import DecoderModelBuilder
 from neuronx_distributed_inference_tpu.models.registry import register_model
@@ -205,27 +206,7 @@ class HybridStack(LayerStack):
         if not isinstance(cache, HybridBlockCache):
             raise TypeError(f"expected a HybridBlockCache, got {type(cache).__name__}")
         positions = inputs.position_ids
-        # a position advances the state iff its row is live and it writes K/V
-        # somewhere real: padded chunk tails and rows that sit a pass out
-        # carry slot_mapping / seq_id -1
-        valid = jnp.broadcast_to((inputs.seq_ids >= 0)[:, None], positions.shape)
-        if inputs.slot_mapping is not None:
-            valid = valid & (inputs.slot_mapping >= 0)
-        # state lifetime without a host call: a row whose first position in
-        # this pass is 0 starts from zero state (a new request in a reused
-        # slot, a re-prefill after preemption, a probe's fresh cache)
-        reset = valid[:, 0] & (positions[:, 0] == 0)
-        # whose state a row advances. The chunk program (handed a slot
-        # mapping) is chunk_rows wide and its rows carry their slot in
-        # seq_ids; an empty row gets an index of its own past the last slot,
-        # so its write-back is dropped and the indices stay unique. The
-        # decode program has one row per slot: row r owns slot r.
-        slots = None
-        if inputs.slot_mapping is not None:
-            rows = jnp.arange(positions.shape[0], dtype=jnp.int32)
-            slots = jnp.where(
-                inputs.seq_ids >= 0, inputs.seq_ids, cache.state.num_slots + rows
-            )
+        valid, reset, slots = slot_state_rows(inputs, cache.state.num_slots)
         block_inputs = paged_block_inputs(inputs, cache.block_size)
         mask = build_mask(inputs, spec, phase)
         layers = params["layers"]

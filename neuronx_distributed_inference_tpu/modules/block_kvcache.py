@@ -123,9 +123,12 @@ class HybridBlockCache(BlockKVCache):
     """The cache of a model whose layers keep two kinds of state, as ONE
     donated pytree: ``k``/``v`` are the block pool over the layers that page
     (indexed by a layer's rank among the paging layers), ``state`` is the
-    builder's per-slot state over the layers that do not
-    (modules/ssm.RecurrentState). The block allocator sees the pool only: a
-    slot's state is as large at token 1 as at token 10^5."""
+    builder's per-slot state: of the layers that do not page
+    (modules/ssm.RecurrentState), or kept by every layer BESIDE its K/V
+    (modules/latent_attention.TokenCarry). A state gives ``num_slots``,
+    ``nbytes``, ``fill_slots(slots, value)`` and ``KIND``, the family of
+    serving counters that counts it. The block allocator sees the pool only:
+    a slot's state is as large at token 1 as at token 10^5."""
 
     state: object = None
 

@@ -366,19 +366,13 @@ def expert_mlps_dense(
     return jnp.einsum("te,eth->th", aff, y)
 
 
-def moe_layer(
-    params: dict,
-    hidden: jax.Array,  # (B, S, H)
-    spec: MoESpec,
-    shared_mlp_fn=None,
-) -> jax.Array:
-    """Full MoE block (reference initialize_moe_module product, moe_v2.py:23)."""
+def linear_router(params: dict, x: jax.Array, spec: MoESpec) -> Tuple[jax.Array, jax.Array]:
+    """The default router: one linear map of the token to expert logits
+    (``params["router"]``: weight (H, E), optional bias and DeepSeek-V3
+    correction bias), then :func:`router_top_k`. x (T, H) -> ((T, E) float32
+    affinities, zero outside the top-k; (T, E) bool selection)."""
     from neuronx_distributed_inference_tpu.config import to_dtype
 
-    B, S, H = hidden.shape
-    x = hidden.reshape(B * S, H)
-    n_active = S  # gate on SEQUENCE length: decode (S=1..spec_len) stays
-    # dense however large the batch is; prefill buckets/chunks go sparse
     rdt = to_dtype(spec.router_dtype)
     router_logits = x.astype(rdt) @ params["router"]["weight"].astype(rdt)
     if spec.router_bias:
@@ -386,9 +380,69 @@ def moe_layer(
     correction = params["router"].get("e_score_correction_bias")
     if correction is not None:
         correction = correction.astype(jnp.float32)
-    affinities, selected = router_top_k(
+    return router_top_k(
         router_logits.astype(jnp.float32), spec, correction_bias=correction
-    )  # (T, E) fp32, (T, E) bool
+    )
+
+
+def carried_mlp_router(
+    params: dict,  # one layer's router leaves (models/zaya.py param_shapes)
+    x: jax.Array,  # (T, H) the normalised hidden state
+    carry: jax.Array,  # (T, R) float32: the layer before's r, zeros at the first layer
+    eps: float,
+):
+    """A router that is a small network with a carry from layer to layer
+    (ZAYA1): ``r = x W_d + b_d + gamma * carry``; ``z = rmsnorm(r)``;
+    ``logits = gelu(gelu(z W_1 + b_1) W_2 + b_2) W_3``; ``p = softmax``;
+    top-1 of ``p + b_bal``, weighted by ``p`` of the chosen expert, not
+    renormalised. Float32 throughout, every product at full precision (the
+    network is R x R: nothing beside an expert's matrices): a top-1 choice
+    among near-tied scores is the last thing to round.
+
+    Returns ((T, E) float32 affinities, zero outside the choice; (T, E) bool
+    selection; r (T, R) float32, the next layer's carry: the value BEFORE
+    the norm; the chosen expert (T,) int32)."""
+    from neuronx_distributed_inference_tpu.modules.norm import rms_norm
+
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    dense = lambda a, name: (
+        jnp.matmul(a, params[name]["weight"].astype(f32), precision=hi)
+        + params[name]["bias"].astype(f32)
+    )
+    gelu = lambda a: jax.nn.gelu(a, approximate=False)
+    down = params["down_proj"]
+    # x and W_d as they are stored: a product of bf16 operands is exact in
+    # the float32 it accumulates in, whatever the precision asked for
+    r = jnp.matmul(x, down["weight"], preferred_element_type=f32,
+                   precision=hi if x.dtype == f32 else None)
+    r = r + down["bias"].astype(f32) + params["gamma"].astype(f32) * carry
+    z = rms_norm(r, params["norm"]["weight"], eps)
+    t = gelu(dense(gelu(dense(z, "fc1")), "fc2"))
+    p = jax.nn.softmax(jnp.matmul(t, params["fc3"]["weight"].astype(f32), precision=hi), axis=-1)
+    choice = jnp.argmax(p + params["balance_bias"].astype(f32), axis=-1).astype(jnp.int32)
+    selected = jax.nn.one_hot(choice, p.shape[-1], dtype=bool)
+    return jnp.where(selected, p, 0.0), selected, r, choice
+
+
+def moe_layer(
+    params: dict,
+    hidden: jax.Array,  # (B, S, H)
+    spec: MoESpec,
+    shared_mlp_fn=None,
+    router=linear_router,
+) -> jax.Array:
+    """Full MoE block (reference initialize_moe_module product, moe_v2.py:23).
+
+    ``router(params, x (T, H), spec) -> (affinities (T, E) float32, selected
+    (T, E) bool)`` is the builder's: the linear router unless a model brings
+    its own (models/zaya.py: a down-projection, a carry from the layer
+    before and an MLP). The expert strategy by shape below is shared."""
+    B, S, H = hidden.shape
+    x = hidden.reshape(B * S, H)
+    n_active = S  # gate on SEQUENCE length: decode (S=1..spec_len) stays
+    # dense however large the batch is; prefill buckets/chunks go sparse
+    affinities, selected = router(params, x, spec)  # (T, E) fp32, (T, E) bool
     # dispatch strategy: decode (tiny T) and EP-sharded experts stay on the
     # dense all-experts path (reference moe_token_gen_all_experts); large-T
     # prefill takes a sparse dispatch — dropless grouped matmuls, or

@@ -142,20 +142,26 @@ def rope_cos_sin(position_ids: jnp.ndarray, inv_freq: jnp.ndarray, attention_sca
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """Apply rotary embedding, HF "half-rotation" convention.
 
-    x: (B, S, H, D); cos/sin: (B, S, D/2). Matches the reference/HF
-    ``rotate_half`` formulation (modules/attention/utils.py:220-240) so logits
-    match HF checkpoints bit-for-bit in fp32.
+    x: (B, S, H, D); cos/sin: (B, S, R/2), R <= D the rotary dimension.
+    R = D matches the reference/HF ``rotate_half`` formulation
+    (modules/attention/utils.py:220-240) so logits match HF checkpoints
+    bit-for-bit in fp32. R < D is a PARTIAL rotary (``partial_rotary_factor``;
+    the tables say how wide): the first R dimensions of a head rotate, as
+    pairs (i, i + R/2), and the other D - R pass through unchanged.
     """
-    d2 = x.shape[-1] // 2
+    d2 = cos.shape[-1]
     x1 = x[..., :d2]
-    x2 = x[..., d2:]
+    x2 = x[..., d2 : 2 * d2]
     cos = cos[:, :, None, :]
     sin = sin[:, :, None, :]
     xf1 = x1.astype(jnp.float32)
     xf2 = x2.astype(jnp.float32)
     out1 = xf1 * cos - xf2 * sin
     out2 = xf2 * cos + xf1 * sin
-    return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+    rotated = jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+    if 2 * d2 == x.shape[-1]:
+        return rotated
+    return jnp.concatenate([rotated, x[..., 2 * d2 :]], axis=-1)
 
 
 def apply_rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:

@@ -91,6 +91,16 @@ class RecurrentState:
     def nbytes(self) -> int:
         return int(self.conv.size * self.conv.dtype.itemsize + self.ssm.size * 4)
 
+    #: which family of the serving step's counters counts this state
+    KIND = "ssm"
+
+    def fill_slots(self, slots, value: float) -> "RecurrentState":
+        """Overwrite the state of whole slots in every layer (scrub: 0.0)."""
+        idx = jnp.asarray(slots, jnp.int32)
+        return RecurrentState(
+            conv=self.conv.at[:, :, idx].set(value), ssm=self.ssm.at[:, idx].set(value)
+        )
+
 
 def init_recurrent_state(spec: SSMSpec, num_layers: int, num_slots: int, dtype) -> RecurrentState:
     return RecurrentState(
@@ -107,17 +117,25 @@ def recurrent_state_pspecs() -> RecurrentState:
     return RecurrentState(conv=P(), ssm=P())
 
 
-def fill_state_slots(state: RecurrentState, slots, value: float) -> RecurrentState:
-    """Overwrite the state of whole slots in every layer (scrub: 0.0)."""
-    idx = jnp.asarray(slots, jnp.int32)
-    return RecurrentState(
-        conv=state.conv.at[:, :, idx].set(value), ssm=state.ssm.at[:, idx].set(value)
-    )
-
-
 # ---------------------------------------------------------------------------
 # depthwise causal convolution with a carried tail
 # ---------------------------------------------------------------------------
+
+
+def carried_window(x: jax.Array, tail: jax.Array) -> jax.Array:
+    """``[tail ; x]`` along the positions: x (R, Q, C) this pass's inputs,
+    tail (K-1, R, C) the last K-1 VALID inputs before this pass. Returns
+    (R, K-1+Q, C) in ``x``'s dtype: position ``K-1+q`` is this pass's ``q``."""
+    return jnp.concatenate([jnp.swapaxes(tail, 0, 1).astype(x.dtype), x], axis=1)
+
+
+def tail_after(window: jax.Array, n_valid: jax.Array, taps: int, dtype) -> jax.Array:
+    """The tail (taps, R, C) after a row's ``n_valid`` (R,) valid positions
+    of ``window`` (:func:`carried_window`): ``window[n : n + taps]``, pure
+    copies, so a row with n = 0 keeps its tail bit for bit."""
+    idx = n_valid[:, None] + jnp.arange(taps, dtype=jnp.int32)[None, :]  # (R, taps)
+    new_tail = jnp.take_along_axis(window, idx[:, :, None], axis=1)
+    return jnp.swapaxes(new_tail, 0, 1).astype(dtype)
 
 
 def causal_conv(
@@ -126,21 +144,20 @@ def causal_conv(
     weight: jax.Array,  # (K, C)
     bias: jax.Array,  # (C,)
     n_valid: jax.Array,  # (R,) int32: valid positions are [0, n_valid)
+    activation=jax.nn.silu,
 ) -> Tuple[jax.Array, jax.Array]:
-    """``silu(conv(x) + b)`` over [tail ; x] and the tail after the row's
-    valid positions. Returns (out (R, Q, C) float32, new tail (K-1, R, C))."""
+    """``activation(conv(x) + b)`` (depthwise) over [tail ; x] and the tail
+    after the row's valid positions. Returns (out (R, Q, C) float32, new
+    tail (K-1, R, C)). ``activation`` None: the plain sum."""
     K = weight.shape[0]
     Q = xBC.shape[1]
-    window = jnp.concatenate([jnp.swapaxes(tail, 0, 1).astype(xBC.dtype), xBC], axis=1)
+    window = carried_window(xBC, tail)
     w = weight.astype(jnp.float32)
     acc = bias.astype(jnp.float32)[None, None, :]
     for k in range(K):
         acc = acc + w[k][None, None, :] * window[:, k : k + Q].astype(jnp.float32)
-    # the tail after n valid inputs is window[n : n + K - 1]: pure copies,
-    # so a row with n = 0 keeps its tail bit for bit
-    idx = n_valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]  # (R, K-1)
-    new_tail = jnp.take_along_axis(window, idx[:, :, None], axis=1)
-    return jax.nn.silu(acc), jnp.swapaxes(new_tail, 0, 1).astype(tail.dtype)
+    out = acc if activation is None else activation(acc)
+    return out, tail_after(window, n_valid, K - 1, tail.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -813,8 +813,10 @@ class TpuModelForCausalLM:
         the caller's row order.
 
         Returns (tokens (B, K) np.ndarray, logits (B, K, V) np.ndarray or
-        None). Updates the app's KV cache in place; all scheduling state
-        stays with the caller.
+        None) and, under ``TpuConfig.output_choices``, a third value: the
+        choices the pass made, ``name -> int np.ndarray (B, S, ...)`` (an
+        expert layer: ``(B, S, L_moe, k)``). Updates the app's KV cache in
+        place; all scheduling state stays with the caller.
         """
         input_ids = np.asarray(input_ids)
         position_ids = np.asarray(position_ids)
@@ -841,11 +843,15 @@ class TpuModelForCausalLM:
                     ids, pos, sid, attention_mask=mask, sampling_params=sp,
                     slot_mapping=sm, block_table=bt, phase=phase, key=key,
                 ))
-            tokens = np.concatenate([t for t, _ in parts])
+            tokens = np.concatenate([p[0] for p in parts])
             logits = None
             if parts[0][1] is not None:
-                logits = np.concatenate([lg for _, lg in parts])
-            return tokens, logits
+                logits = np.concatenate([p[1] for p in parts])
+            if len(parts[0]) == 2:
+                return tokens, logits
+            return tokens, logits, {
+                name: np.concatenate([p[2][name] for p in parts]) for name in parts[0][2]
+            }
         if sampling_params is None:
             sampling_params = prepare_sampling_params(B)
         if attention_mask is None:
@@ -872,11 +878,13 @@ class TpuModelForCausalLM:
         tel.step("prefill" if phase == "cte" else "decode")
         tel.bucket_dispatch(runner.tag, runner.last_bucket)
         # one host round-trip per step: tokens + logits in a single fetch
-        tokens, logits = jax.device_get((out.tokens, out.logits))
+        tokens, logits, aux = jax.device_get((out.tokens, out.logits, out.aux))
         tokens = np.asarray(tokens)[:B]
         if logits is not None:
             logits = np.asarray(logits)[:B]
-        return tokens, logits
+        if aux is None:
+            return tokens, logits
+        return tokens, logits, {name: np.asarray(a)[:B] for name, a in aux.items()}
 
     def _pos_limit(self) -> int:
         """Largest writable position: a ring cache bounds SLOTS, not

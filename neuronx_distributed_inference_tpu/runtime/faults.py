@@ -449,14 +449,13 @@ def fill_kv_rows(cache, row_ids: np.ndarray, value: float):
 
 
 def fill_slot_state(cache, slots, value: float):
-    """Overwrite the constant-size per-slot state (every state-space layer)
-    of ``slots`` in a :class:`~..modules.block_kvcache.HybridBlockCache`;
-    the block pool is left as it is."""
+    """Overwrite the constant-size per-slot state (every layer that keeps
+    one: state-space layers, a one-token carry) of ``slots`` in a
+    :class:`~..modules.block_kvcache.HybridBlockCache`; the block pool is
+    left as it is."""
     import dataclasses
 
-    from neuronx_distributed_inference_tpu.modules.ssm import fill_state_slots
-
-    return dataclasses.replace(cache, state=fill_state_slots(cache.state, slots, value))
+    return dataclasses.replace(cache, state=cache.state.fill_slots(slots, value))
 
 
 def _poison_row(session, slot: int) -> bool:

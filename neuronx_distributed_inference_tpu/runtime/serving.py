@@ -321,11 +321,15 @@ class ServingSession:
             # head-parallel grid axis, so no warning/fallback here — see
             # docs/SERVING.md "Sharded meshes"
         # a model whose layers keep a constant-size per-slot state
-        # (state-space layers; HybridBlockCache.state): the scrub of a slot
-        # covers it, and the three nxdi_ssm_* families count it
+        # (HybridBlockCache.state: state-space layers, a one-token carry):
+        # the scrub of a slot covers it, and the family its KIND names
+        # counts it (nxdi_ssm_*, nxdi_latent_carry_*); a model with routed
+        # experts that says so is counted by nxdi_moe_* (_count_pass)
         state = getattr(app.kv_cache, "state", None)
         self.slot_state = state is not None
+        self.slot_state_kind = getattr(state, "KIND", None)
         self.slot_state_bytes = state.nbytes if self.slot_state else 0
+        self.expert_layers = app.builder.expert_layers()
         self.tel.pool_gauges(0, self.kv_pool_bytes, self.kv_free_bytes)
 
     @property
@@ -1178,11 +1182,10 @@ class ServingSession:
             tel.prefill_pass(
                 ran_real, len(flights) * R * qb - ran_real, dispatches=len(flights)
             )
-            if self.slot_state:
-                tel.ssm_pass(
-                    "chunk", len(ran), self.slot_state_bytes,
-                    resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
-                )
+            self._count_pass(
+                "chunk", len(ran), ran_real, len(flights),
+                resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
+            )
             for req, n in ran:
                 self._note_prefill(req, n)
             tel.pool_gauges(
@@ -1781,10 +1784,23 @@ class ServingSession:
         tel.step("decode")
         tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
         tel.decode_pass(len(rows), B)
-        if self.slot_state:
-            tel.ssm_pass("decode", len(rows), self.slot_state_bytes)
+        self._count_pass("decode", len(rows), len(rows), 1)
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
         return out, [(r, p, r.slot, r.epoch) for r, p in rows]
+
+    def _count_pass(self, program: str, rows: int, tokens: int, dispatches: int,
+                    resets: int = 0) -> None:
+        """What a pass of the split serving step ("decode" or "chunk") did
+        to per-slot state and routed experts, from what the step already
+        knows: ``rows`` live rows over ``tokens`` real token positions in
+        ``dispatches`` dispatches, ``resets`` of the rows from position 0."""
+        if self.slot_state_kind == "ssm":
+            self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets)
+        elif self.slot_state_kind == "latent_carry":
+            self.tel.carry_pass(program, rows)
+        if self.expert_layers is not None:
+            layers, experts, top_k = self.expert_layers
+            self.tel.moe_pass(program, tokens * layers * top_k, dispatches * layers * experts)
 
     def _consume(self, pend, results: Dict[str, int]):
         """Fetch a dispatched decode step and apply termination bookkeeping.

@@ -366,6 +366,25 @@ class TelemetrySession:
             "nxdi_ssm_state_bytes",
             "HBM of the per-slot recurrent state (conv tails + float32 SSM "
             "state, every state-space layer, every slot)")
+        self._carry_rows = r.counter(
+            "nxdi_latent_carry_rows_advanced_total",
+            "rows whose one-token carry (latent attention with conv mixing: "
+            "the last token's conv inputs and shifted value half, every "
+            "layer) a dispatch of the split serving step advanced",
+            labels=("program",))
+        self._moe_rows = r.counter(
+            "nxdi_moe_rows_routed_total",
+            "token rows the split serving step routed to an expert: real "
+            "token positions x expert layers x experts per token",
+            labels=("program",))
+        self._moe_experts = r.counter(
+            "nxdi_moe_experts_hit_total",
+            "experts whose weights a dispatch of the split serving step "
+            "streamed, summed over expert layers: EVERY expert a layer holds, "
+            "since the decode strategy computes all of them (and at 48 rows "
+            "top-1 of 16 hits 15.3 on average); not a count of the distinct "
+            "experts with a live row, which only the device knows",
+            labels=("program",))
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -1059,6 +1078,23 @@ class TelemetrySession:
         self._ssm_bytes.set(state_bytes)
         if resets:
             self._ssm_resets.inc(resets)
+
+    def carry_pass(self, program: str, rows: int) -> None:
+        """One pass of the split serving step over a model that keeps a
+        one-token carry per slot: the rows whose carry it advanced."""
+        if not self.enabled:
+            return
+        self._carry_rows.child((program,)).inc(rows)
+
+    def moe_pass(self, program: str, rows_routed: int, experts: int) -> None:
+        """One pass of the split serving step over a model with routed
+        experts: token rows routed (x layers x experts per token) and the
+        experts whose weights its dispatches streamed (every held expert of
+        every expert layer, per dispatch)."""
+        if not self.enabled:
+            return
+        self._moe_rows.child((program,)).inc(rows_routed)
+        self._moe_experts.child((program,)).inc(experts)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

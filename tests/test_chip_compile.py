@@ -377,10 +377,11 @@ def test_tp4_contiguous_decode_step_runs_its_kernel_per_shard(chip_mesh):
 # ---------------------------------------------------------------------------
 
 
-def _abstract_hybrid_app(mesh, slots=48, blocks=2048):
-    """The benchmark's configuration (benchmark/configs/granite-4.0-h-micro.json:
-    the published widths, all 40 layers) over a described chip, with params
-    and the hybrid cache as ShapeDtypeStructs."""
+def _abstract_hybrid_app(mesh, config="granite-4.0-h-micro"):
+    """A benchmark configuration whose layers keep per-slot state
+    (benchmark/configs/<config>.json: the published widths, its slots and
+    pool) over a described chip, with params and the hybrid cache as
+    ShapeDtypeStructs."""
     import json
     import os
 
@@ -395,14 +396,15 @@ def _abstract_hybrid_app(mesh, slots=48, blocks=2048):
     )
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
         file = json.load(f)
     meta = {"name", "source", "deployment", "assumed", "reduced", "tpu_config",
-            "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference"}
+            "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference",
+            "weights", "probe_tpu_config"}
     attrs = {k: v for k, v in file.items() if k not in meta}
     tc = TpuConfig(
-        **{**file["tpu_config"], "batch_size": slots, "pa_num_blocks": blocks},
-        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=slots),
+        **file["tpu_config"],
+        chunked_prefill_config=ChunkedPrefillConfig(**file["chunked_prefill"]),
         # the auto gates ask jax.default_backend(), which is the CPU here
         attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
     )
@@ -415,9 +417,9 @@ def _abstract_hybrid_app(mesh, slots=48, blocks=2048):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, P()))
 
     def cache():
-        pool = init_block_cache(app.paged_layers, blocks, tc.pa_block_size, b.gqa.kv_heads,
-                                b.head_dim, dtype=jnp.bfloat16)
-        return HybridBlockCache(k=pool.k, v=pool.v, state=b.init_slot_state(slots)[0])
+        pool = init_block_cache(app.paged_layers, tc.pa_num_blocks, tc.pa_block_size,
+                                b.gqa.kv_heads, b.head_dim, dtype=jnp.bfloat16)
+        return HybridBlockCache(k=pool.k, v=pool.v, state=b.init_slot_state(tc.batch_size)[0])
 
     params = jax.tree.map(place, jax.eval_shape(b.random_params))
     return app, params, jax.tree.map(place, jax.eval_shape(cache))
@@ -464,3 +466,35 @@ def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, pro
     else:
         assert "paged_flash_attention" in text and "ssm_state_update" not in text
         assert _planned_bytes(compiled) < 11.9 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# zaya1-8b: a one-token carry per slot beside paged KV, top-1 experts
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, program):
+    """zaya1-8b at the benchmark's widths (benchmark/configs/zaya1-8b.json: 16
+    experts, the whole vocabulary, 20 layers, 48 slots, 2048 blocks), both
+    step programs compiled for a described v5e: the pool spans all 20 layers
+    at (2, 128) a token beside a (20, 48, 2688) carry; the decode program
+    holds ``paged_tkg_decode_attention`` and the 8-row chunk program
+    ``paged_flash_attention`` (two KV heads a device: the per-head KV write,
+    no copy of the pool in the layer scan), and each plans under 14.75 GiB of
+    the chip's 15.75."""
+    app, params, cache = _abstract_hybrid_app(chip_mesh(1), "zaya1-8b")
+    assert cache.k.shape == (20, 2049, 2, 32, 128) and cache.state.last.shape == (20, 48, 2688)
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(2048, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((48, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
+    assert kernel in text and _custom_calls(compiled) >= 1
+    assert _pool_copies(compiled, cache.k.shape)[0] == 0
+    mem = compiled.memory_analysis()
+    print(f"\nzaya1-8b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
