@@ -593,7 +593,7 @@ def legal_tiles(kernel: str, shape_class: str, dtype: str) -> List[Dict[str, int
 
 def _census_row(inst, ridge: float) -> dict:
     occ = _occupancy(inst.dot_stats)
-    bytes_step = inst.block_bytes_single
+    bytes_step = inst.block_bytes_single + inst.copy_bytes
     intensity = inst.flops_per_step / bytes_step if bytes_step else 0.0
     return {
         "location": f"ops/{inst.kernel}",

@@ -40,6 +40,9 @@ REPO_ROOT = OPS_DIR.parent.parent
 #: modules
 _1B = dict(H=2048, I=8192, Hq=32, Hkv=8, D=64, L=16)
 _8B = dict(H=4096, I=14336, Hq=32, Hkv=8, D=128, L=32)
+#: the attention of the benchmark's served decode programs at head_dim 128
+_QWEN3_1P7B = dict(Hq=16, Hkv=8, D=128, L=28)
+_KV2 = dict(Hq=8, Hkv=2, D=128, L=20)
 
 
 @dataclass(frozen=True)
@@ -63,6 +66,9 @@ class KernelSpec:
     tile_params: Tuple[str, ...] = ()  # free tile params read from the table
     sweep: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()  # param -> candidates
     table_kernel: Optional[str] = None  # tuning-table key (defaults to name)
+    # bytes a step copies by hand (``make_async_copy``) out of operands that
+    # stay in HBM and so have no block window, from the traced instance
+    step_copy_bytes: Optional[Callable[["KernelInstance"], int]] = None
 
     @property
     def table_key(self) -> str:
@@ -89,6 +95,7 @@ class KernelInstance:
     scratch: List[Tuple[Tuple[int, ...], str, int]]  # (shape, dtype, bytes)
     flops_per_step: int
     dot_stats: List[Tuple[int, int, int]]  # (flops, contract_depth, out_lanes)
+    copy_bytes: int = 0  # hand copies a step (KernelSpec.step_copy_bytes)
 
     @property
     def key(self) -> str:
@@ -172,13 +179,12 @@ def _tkg_case(B, bucket, model, cache_dtype):
     return build
 
 
-def _paged_tkg_case(B, MB, bs, cache_dtype):
+def _paged_tkg_case(B, MB, bs, cache_dtype, m=_1B):
     def build():
         import jax.numpy as jnp
 
         from neuronx_distributed_inference_tpu.ops import decode_attention as da
 
-        m = _1B
         q = _sds((B, 1, m["Hq"], m["D"]), jnp.bfloat16)
         cache = _sds((m["L"], 65, m["Hkv"], bs, m["D"]), jnp.dtype(cache_dtype))
         li = _sds((), jnp.int32)
@@ -367,13 +373,34 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         entry="paged_tkg_decode_attention",
         fallback=f"{_ATTN}:attention_decode",
         parity_test="tests/test_decode_attention.py",
-        # no free tile: the kv tile IS the paged-cache block size, a cache-
-        # layout decision owned by the serving config, not the tuning table
+        # the kv tile is ``pages`` pool blocks (ops/decode_attention.py::
+        # pages_per_step): the block size is a cache-layout decision of the
+        # serving config, how many blocks a step takes follows the block's
+        # SHAPE, which is the shape class here ("blk<Hkv>x<bs>x<D>")
+        tile_params=("pages",),
+        sweep=(("pages", (2, 4, 8, 16, 32)),),
+        # at head_dim 128 K and V stay in HBM: a step fills ONE of the two
+        # slots of each stream's VMEM scratch (the other is being attended);
+        # at head_dim 64 the blocks are windows and the scratch is statistics
+        step_copy_bytes=lambda inst: inst.scratch_bytes // 2 if len(inst.grid) == 1 else 0,
         cases=(
+            # head_dim 64 (Llama-3.2-1B, granite): one block a grid step
             KernelCase(
-                "kv1024", "bfloat16", _paged_tkg_case(8, 8, 128, "bfloat16")
+                "blk8x128x64", "bfloat16", _paged_tkg_case(8, 8, 128, "bfloat16")
             ),
-            KernelCase("kv1024", "int8", _paged_tkg_case(8, 8, 128, "int8")),
+            KernelCase("blk8x128x64", "int8", _paged_tkg_case(8, 8, 128, "int8")),
+            # the benchmark's decode programs: Qwen3-1.7B (48 slots, kv 1024)
+            # and 2 KV heads a chip (Qwen3-14B at tp = 4, ZAYA1-8B)
+            KernelCase(
+                "blk8x32x128", "bfloat16",
+                _paged_tkg_case(48, 32, 32, "bfloat16", _QWEN3_1P7B),
+            ),
+            KernelCase(
+                "blk8x32x128", "int8", _paged_tkg_case(48, 32, 32, "int8", _QWEN3_1P7B)
+            ),
+            KernelCase(
+                "blk2x32x128", "bfloat16", _paged_tkg_case(48, 32, 32, "bfloat16", _KV2)
+            ),
         ),
     ),
     KernelSpec(
@@ -460,6 +487,13 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     },
     "tkg_decode_attention": {"*": {"bs": 512}},
     "paged_flash_attention": {"*": {"tq": 128}},
+    # what pages_per_step's rule gives at each registered block shape (at
+    # most 1 MiB a stream and 512 tokens a group; 1 off the 128 lanes)
+    "paged_tkg_decode_attention": {
+        "blk8x128x64": {"pages": 1},
+        "blk8x32x128": {"pages": 16},
+        "blk2x32x128": {"pages": 16},
+    },
     "ragged_paged_attention": {"*": {"tq": 16}},
     "fused_moe_decode": {"*": {"ti_cap": 512}},
     "quant_matmul": {"*": {"bn": 256}},
@@ -587,6 +621,8 @@ def instantiate(
     gm = eqn.params["grid_mapping"]
     blocks: List[BlockInfo] = []
     for i, bm in enumerate(gm.block_mappings):
+        if str(getattr(bm.block_aval, "memory_space", None)) in ("any", "hbm"):
+            continue  # stays in HBM, copied by hand into scratch: no window
         sd = bm.array_aval
         blocks.append(
             BlockInfo(
@@ -601,6 +637,8 @@ def instantiate(
     scratch = []
     if gm.num_scratch_operands:
         for v in kj.invars[-gm.num_scratch_operands:]:
+            if str(getattr(v.aval, "memory_space", None)) in ("semaphore_mem", "smem"):
+                continue  # not vector memory
             shape = tuple(int(d) for d in v.aval.shape)
             n = 1
             for d in shape:
@@ -618,7 +656,7 @@ def instantiate(
             v = (entry.get("tiles") or {}).get(p, hand.get(p))
             if v is not None:
                 resolved[p] = int(v)
-    return KernelInstance(
+    inst = KernelInstance(
         kernel=spec.name,
         shape_class=case.shape_class,
         dtype=case.dtype,
@@ -631,6 +669,9 @@ def instantiate(
         flops_per_step=int(jaxpr_flops(kj) or _vector_flops(kj)),
         dot_stats=_dot_stats(kj, []),
     )
+    if spec.step_copy_bytes is not None:
+        inst.copy_bytes = int(spec.step_copy_bytes(inst))
+    return inst
 
 
 @functools.lru_cache(maxsize=1)

@@ -13,11 +13,31 @@ cache tiles straight out of the FULL stacked cache (layer index and block
 table ride scalar prefetch), with the decode mask fused in — nothing is
 materialized.
 
-Grid layout: (B, kv_tiles). Each step DMAs one (bs, Hkv, D) cache tile — all
-KV heads at once, so the last two block dims stay full-size for Mosaic — and
-an unrolled loop over the Hkv head groups runs the online softmax for that
-group's n_rep*K query rows (GQA needs NO repeat_kv: queries are pre-grouped
-rep-major). The cache is read exactly once, in tile-sized DMAs.
+Grid layout, contiguous cache: (B, kv_tiles). Each step DMAs one (bs, Hkv, D)
+cache tile — all KV heads at once, so the last two block dims stay full-size
+for Mosaic — and an unrolled loop over the Hkv head groups runs the online
+softmax for that group's n_rep*K query rows (GQA needs NO repeat_kv: queries
+are pre-grouped rep-major). The cache is read exactly once, in tile-sized
+DMAs; the default tile is 512 tokens.
+
+Grid layout, paged cache: (B,) — one step a ROW. The pool's blocks are 32
+tokens and a row's are scattered, so a tile is a GROUP of ``pages_per_step``
+blocks (16 at the served shapes: 512 tokens) that the kernel copies by hand:
+K and V stay in HBM (``pl.ANY``), an in-kernel loop runs over the row's live
+groups only, each group's blocks land in one of two VMEM slots while the
+group before it is attended (a row's last group starts the next LIVE row's
+first copies), and no block past a row's last live one is copied. A row with
+no live block costs one empty grid step; the cost of a dispatch follows the
+live context, not slots x bucket. One online-softmax update per KV head runs
+over a whole group, on query rows padded to the 8-sublane tile, the
+statistics carried in registers. Both products take the cache tile as it is
+stored: a float32 operand goes to the matrix unit as three bfloat16 parts
+that sum to it exactly (``_split3``), every product exact in the float32
+accumulator, so nothing is rounded that the float32 form would keep. At a
+head_dim that is no multiple of the 128 lanes the chip's compiler refuses a
+hand copy of a block (a 64-lane slice of an HBM ref), and the launch keeps
+one block a grid step through a ``BlockSpec`` on the block table
+(``_paged_by_block``, the contiguous kernel's body).
 
 Masking is taken from the SAME (B, 1, K, S_kv) boolean mask the native path
 uses — window/chunk/speculation decode masks all work unchanged — re-tiled to
@@ -183,7 +203,8 @@ def _mask_tiles(mask: jax.Array, nkv: int, bs: int):
 
 
 def _common_call(
-    kernel, grid, in_specs, out_specs, operands, out_shape, scratch, interpret, name
+    kernel, grid, in_specs, out_specs, operands, out_shape, scratch, interpret, name,
+    semantics=("parallel", "arbitrary"),
 ):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(operands[0]),
@@ -196,9 +217,7 @@ def _common_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name=name,
     )(*operands[0], *operands[1])
@@ -282,6 +301,323 @@ def tkg_decode_attention(
     return out
 
 
+# ---------------------------------------------------------------------------
+# paged decode: a group of pool blocks a step, no step past a row's context
+# ---------------------------------------------------------------------------
+
+#: what a group of pool blocks should hold per stream (K or V): large enough
+#: that the fixed cost of a step is paid per ~MB of cache and not per block,
+#: small enough that two slots of both streams sit in VMEM beside q, the
+#: row's mask and the accumulators (4 x this)
+GROUP_BYTES = 1024 * 1024
+#: and the most tokens a group may span: a row's last group is computed
+#: whole, so a wide group of small blocks wastes arithmetic on a short row
+GROUP_TOKENS = 512
+
+
+def pages_per_step(n_kv: int, bs: int, head_dim: int, cache_dtype, max_blocks: int) -> int:
+    """Pool blocks the paged decode kernel fetches and attends per step (its
+    ``P``): a power of two that follows the block's shape through the tuning
+    table, never more than the block table is wide (a table no multiple of
+    it wide is padded with dead entries). Host code calls this too
+    (``ServingSession`` counts the blocks the kernel walks)."""
+    dt = jnp.dtype(cache_dtype)
+    if head_dim % 128:
+        return 1  # blocks come through a BlockSpec, one a step: _paged_by_block
+    block_bytes = n_kv * bs * head_dim * dt.itemsize
+    p = 1
+    while 2 * p * block_bytes <= GROUP_BYTES and 2 * p * bs <= GROUP_TOKENS:
+        p *= 2
+    p = tile_default(
+        "paged_tkg_decode_attention", f"blk{n_kv}x{bs}x{head_dim}", dt.name, "pages", p
+    )
+    p = max(1, min(p, max_blocks))
+    return 1 << (p.bit_length() - 1)  # the kernel takes a group's live count apart by bits
+
+
+def kv_blocks_walked(
+    live_blocks, max_blocks: int, *, n_kv: int, bs: int, head_dim: int, cache_dtype
+) -> int:
+    """Block-table entries the paged decode kernel's kv axis attends for rows
+    whose contexts hold ``live_blocks`` (one count a row) blocks of a table
+    ``max_blocks`` wide: whole groups up to a row's last live block; every
+    entry of the table where blocks come one a grid step (``_paged_by_block``)."""
+    if head_dim % 128:
+        return max_blocks * len(live_blocks)
+    P = pages_per_step(n_kv, bs, head_dim, cache_dtype, max_blocks)
+    return sum(-(-n // P) * P for n in live_blocks)
+
+
+def _bf16_part(x):
+    """The top 16 bits of a float32: a value bfloat16 holds exactly. Cut, not
+    rounded, and through the bit pattern, so that no compiler may take the
+    float32 -> bfloat16 -> float32 pair for the identity."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _split3(x):
+    """float32 (R, N) -> bfloat16 (3R, N) whose three row blocks sum to ``x``
+    EXACTLY (8 + 8 + 8 significand bits): the matrix unit then multiplies a
+    float32 operand by a bfloat16 tile in one pass, every product exact in
+    its float32 accumulator, and nothing of ``x`` is rounded away."""
+    hi = _bf16_part(x)
+    rest = x - hi
+    mid = _bf16_part(rest)
+    return jnp.concatenate([hi, mid, rest - mid], axis=0).astype(jnp.bfloat16)
+
+
+def _dot_tile(x, tile, contract_tile_dim: int):
+    """``x`` (R, N), float32 or bfloat16, against one head's cache tile,
+    contracting ``tile``'s ``contract_tile_dim``, in float32. A tile whose
+    values bfloat16 holds exactly (bf16 itself, int8 / fp8 codes) goes to the
+    matrix unit as it is stored, a float32 ``x`` as its three bfloat16 parts;
+    any other tile (float32 caches: the parity tests) is converted and
+    multiplied in float32."""
+    dims = (((1,), (contract_tile_dim,)), ((), ()))
+    if tile.dtype == jnp.float32 or tile.dtype == jnp.float16:
+        return jax.lax.dot_general(
+            x.astype(jnp.float32), tile.astype(jnp.float32), dims,
+            preferred_element_type=jnp.float32,
+        )
+    if tile.dtype != jnp.bfloat16:
+        tile = tile.astype(jnp.float32).astype(jnp.bfloat16)  # codes: exact
+    if x.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(x, tile, dims, preferred_element_type=jnp.float32)
+    R = x.shape[0]
+    y = jax.lax.dot_general(_split3(x), tile, dims, preferred_element_type=jnp.float32)
+    return y[:R] + y[R : 2 * R] + y[2 * R :]
+
+
+def _paged_group_kernel(
+    li_ref, bt_ref, lo_ref, end_ref, live_from_ref, *rest,
+    scale, n_kv, P, has_sink, q_dtype,
+):
+    """One ROW per grid step; inside, a loop over the row's live block groups
+    only. K and V stay in HBM: each group's ``P`` blocks are copied into one
+    of two VMEM slots while the group before it (of this row or of the last
+    live row) is attended, so a row with no live block starts no copy and
+    runs no arithmetic."""
+    if has_sink:
+        q_ref, mask_ref, sink_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
+    else:
+        q_ref, mask_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
+        sink_ref = None
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    layer = li_ref[0]
+    bs = k_buf.shape[2] // P
+    R = q_ref.shape[2]  # a head group's query rows, padded to the sublane tile
+
+    streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def live_blocks(row, group):
+        """Blocks of ``group`` up to ``row``'s last live one: what is copied.
+        None past it (what lies there in the slot is an earlier group's,
+        masked)."""
+        return jnp.clip(end_ref[row] - group * P, 0, P)
+
+    def start(row, group, slot):
+        def block(p, _):
+            tokens = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            page = bt_ref[row, group * P + p]
+            for stream, (hbm, buf) in enumerate(streams):
+                pltpu.make_async_copy(
+                    hbm.at[layer, page], buf.at[slot, :, tokens, :], sems.at[stream, slot]
+                ).start()
+
+        jax.lax.fori_loop(0, live_blocks(row, group), block, None)
+
+    def wait(row, group, slot):
+        # a wait needs only the bytes and the semaphore: the live count taken
+        # apart into powers of two, one wait stands for a whole run of blocks
+        n = live_blocks(row, group)
+        width = P
+        while width:
+            @pl.when(n & width > 0)
+            def _(width=width):
+                for stream, (_, buf) in enumerate(streams):
+                    run = buf.at[slot, :, pl.ds(0, width * bs), :]
+                    pltpu.make_async_copy(run, run, sems.at[stream, slot]).wait()
+
+            width //= 2
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        # a masked token's probability is 0, and 0 x what the slot was born
+        # with need not be 0: V's slots start from zeros
+        v_buf[...] = jnp.zeros_like(v_buf)
+        row = live_from_ref[0]
+
+        @pl.when(row < B)
+        def _():
+            start(row, lo_ref[row], 0)
+
+    lo, hi = lo_ref[b], (end_ref[b] + P - 1) // P
+
+    def group(g, carry):
+        slot = slot_ref[0]
+        last = g == hi - 1
+        nrow = jnp.where(last, live_from_ref[b + 1], b)
+
+        @pl.when(nrow < B)
+        def _prefetch():
+            start(nrow, jnp.where(last, lo_ref[nrow], g + 1), 1 - slot)
+
+        wait(b, g, slot)
+        slot_ref[0] = 1 - slot
+
+        # (1, G) at one query token, (R, G) laid out per query row otherwise
+        row_mask = jnp.broadcast_to(mask_ref[0, g] > 0, (R, k_buf.shape[2]))
+        out = []
+        for h in range(n_kv):
+            m_prev, l_prev, acc_prev = carry[h]
+            q = q_ref[0, h].astype(q_dtype)  # (R, D): bfloat16 where q came so
+            s = _dot_tile(q, k_buf[slot, h], 1) * scale  # (R, G)
+            s = jnp.where(row_mask, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(row_mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc_prev * alpha + _dot_tile(p, v_buf[slot, h], 0)
+            out.append((m_new, l_new, acc))
+        return tuple(out)
+
+    D = q_ref.shape[3]
+    init = tuple(
+        (
+            jnp.full((R, 1), NEG_INF, jnp.float32),
+            jnp.zeros((R, 1), jnp.float32),
+            jnp.zeros((R, D), jnp.float32),
+        )
+        for _ in range(n_kv)
+    )
+    stats = jax.lax.fori_loop(lo, hi, group, init)
+    for h, (m, l, acc) in enumerate(stats):
+        if sink_ref is None:
+            o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        else:
+            # as _finalize: renormalize to max(m, sink); a row that saw no
+            # valid kv stays finite and writes zeros
+            sink = sink_ref[h]  # (R, 1)
+            m2 = jnp.maximum(m, sink)
+            alpha = jnp.exp(m - m2)
+            o_ref[0, h] = (acc * alpha / (l * alpha + jnp.exp(sink - m2))).astype(o_ref.dtype)
+
+
+def _paged_by_block(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, n_kv, interpret):
+    """The launch at a head_dim that is no multiple of the 128 lanes: the
+    chip's compiler refuses such a slice of an HBM ref, so the blocks cannot
+    be copied by hand; they come one a grid step through a ``BlockSpec`` on
+    the block table, ``(B, MB)`` steps, as the contiguous kernel's tiles do
+    (a dead step repeats the last block index and fetches nothing).
+    (B, K, Hq, D) -> (B, Hq*K, D)."""
+    B, K, Hq, D = q.shape
+    bs = k_cache.shape[3]
+    MB = block_table.shape[1]
+    m, tile_any = _mask_tiles(mask, MB, bs)
+    kernel = functools.partial(
+        _tkg_kernel, scale=scale, n_kv=n_kv, rk=Hq // n_kv * K, K=K, nkv=MB,
+        has_sink=sink is not None, n_prefetch=3, head_major=True,
+    )
+    in_specs = [
+        pl.BlockSpec((1, Hq * K, D), lambda b, j, li, bt, ta: (b, 0, 0)),
+        pl.BlockSpec((1, 1, K, bs), lambda b, j, li, bt, ta: (b, j, 0, 0)),
+    ]
+    tensors = [_prep_q(q), m]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((1, Hq), lambda b, j, li, bt, ta: (0, 0)))
+        tensors.append(sink.reshape(1, Hq))
+    block = pl.BlockSpec(
+        (1, 1, n_kv, bs, D), lambda b, j, li, bt, ta: (li[0], bt[b, j], 0, 0, 0)
+    )
+    in_specs += [block, block]
+    tensors += [k_cache, v_cache]
+    return _common_call(
+        kernel,
+        grid=(B, MB),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, Hq * K, D), lambda b, j, li, bt, ta: (b, 0, 0)),
+        operands=([li, block_table, tile_any], tensors),
+        out_shape=jax.ShapeDtypeStruct((B, Hq * K, D), q.dtype),
+        scratch=[
+            pltpu.VMEM((Hq * K, 1), jnp.float32),
+            pltpu.VMEM((Hq * K, 1), jnp.float32),
+            pltpu.VMEM((Hq * K, D), jnp.float32),
+        ],
+        interpret=interpret,
+        name="paged_tkg_decode_attention",
+    )
+
+
+def _paged_by_group(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, n_kv, P, interpret):
+    """The launch of :func:`_paged_group_kernel`. (B, K, Hq, D) -> (B, Hq*K, D)."""
+    B, K, Hq, D = q.shape
+    bs = k_cache.shape[3]
+    MB = block_table.shape[1]
+    n_rep = Hq // n_kv
+    rk = n_rep * K
+    R = -(-rk // 8) * 8
+    NG = -(-MB // P)
+    G = P * bs
+    pad = NG * P - MB  # a table no multiple of P wide: dead entries, masked
+    block_table = jnp.pad(block_table, ((0, 0), (0, pad)))
+    mask = jnp.pad(mask, ((0, 0), (0, 0), (0, 0), (0, pad * bs)))
+
+    def rows(x):  # (N, Hq*K, ...) -> (N, Hkv, R, ...): a head group's rows, padded
+        x = x.reshape(x.shape[0], n_kv, rk, *x.shape[2:])
+        return jnp.pad(x, ((0, 0), (0, 0), (0, R - rk)) + ((0, 0),) * (x.ndim - 3))
+
+    m, _ = _mask_tiles(mask, NG, G)  # (B, NG, K, G)
+    if K > 1:  # row r*K + t of a head group reads mask row t
+        m = jnp.pad(jnp.tile(m, (1, 1, n_rep, 1)), ((0, 0), (0, 0), (0, R - rk), (0, 0)))
+    # per row: one past its last live block, the group its first live block
+    # lies in, and from each row the next row that has any
+    live = _mask_tiles(mask, NG * P, bs)[1] > 0
+    idx = jnp.arange(NG * P, dtype=jnp.int32)
+    end = jnp.max(jnp.where(live, idx + 1, 0), axis=1)
+    lo = jnp.min(jnp.where(live, idx, NG * P - 1), axis=1) // P
+    live_from = jax.lax.cummin(
+        jnp.where(end > 0, jnp.arange(B, dtype=jnp.int32), B), reverse=True
+    )
+    live_from = jnp.concatenate([live_from, jnp.full((1,), B, jnp.int32)])
+
+    def row_spec(shape):
+        return pl.BlockSpec((1,) + shape, lambda b, *_: (b,) + (0,) * len(shape))
+
+    in_specs = [row_spec((n_kv, R, D)), row_spec(m.shape[1:])]
+    tensors = [rows(_prep_q(q).astype(jnp.float32)), m]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((n_kv, R, 1), lambda b, *_: (0, 0, 0)))
+        tensors.append(rows(jnp.repeat(sink.astype(jnp.float32), K)[None, :, None])[0])
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    tensors += [k_cache, v_cache]
+
+    out = _common_call(
+        functools.partial(
+            _paged_group_kernel, scale=scale, n_kv=n_kv, P=P, has_sink=sink is not None,
+            q_dtype=jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32,
+        ),
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=row_spec((n_kv, R, D)),
+        operands=([li, block_table, lo, end, live_from], tensors),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, R, D), q.dtype),
+        scratch=[
+            pltpu.VMEM((2, n_kv, G, D), k_cache.dtype),
+            pltpu.VMEM((2, n_kv, G, D), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+        interpret=interpret,
+        name="paged_tkg_decode_attention",
+        # rows in order: a row's last group starts the next live row's copies
+        semantics=("arbitrary",),
+    )
+    return out[:, :, :rk].reshape(B, Hq * K, D)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "n_kv", "interpret"))
 def paged_tkg_decode_attention(
     q: jax.Array,  # (B, K, Hq, D)
@@ -297,7 +633,8 @@ def paged_tkg_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Paged decode attention: cache blocks are DMA'd straight via the block
-    table (scalar prefetch) — kills the materializing
+    table (scalar prefetch), :func:`pages_per_step` of them a step and none
+    past a row's last live block — kills the materializing
     read_block_cache_at_layer gather on the serving decode path
     (reference attention_block_tokengen kernel, attention_base.py:1609).
     Quantized caches DMA the code blocks and dequantize in-register.
@@ -307,54 +644,19 @@ def paged_tkg_decode_attention(
     MB = block_table.shape[1]
     assert mask.shape[-1] == MB * bs, (mask.shape, MB, bs)
     n_rep = Hq // n_kv
-    rk = n_rep * K
     out_dtype = q.dtype
     quantized = isinstance(k_cache, QuantizedKV)
     if quantized:
         q = _fold_k_dequant(q, k_cache, layer_idx, n_rep)
         k_cache, v_quant = k_cache.data, v_cache
         v_cache = v_cache.data
-    qr = _prep_q(q)
-    m, tile_any = _mask_tiles(mask, MB, bs)
     li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
-
-    kernel = functools.partial(
-        _tkg_kernel, scale=scale, n_kv=n_kv, rk=rk, K=K, nkv=MB,
-        has_sink=sink is not None, n_prefetch=3, head_major=True,
-    )
-    in_specs = [
-        pl.BlockSpec((1, Hq * K, D), lambda b, j, li, bt, ta: (b, 0, 0)),
-        pl.BlockSpec((1, 1, K, bs), lambda b, j, li, bt, ta: (b, j, 0, 0)),
-    ]
-    tensors = [qr, m]
-    if sink is not None:
-        in_specs.append(pl.BlockSpec((1, Hq), lambda b, j, li, bt, ta: (0, 0)))
-        tensors.append(sink.reshape(1, Hq))
-    in_specs += [
-        pl.BlockSpec(
-            (1, 1, n_kv, bs, D), lambda b, j, li, bt, ta: (li[0], bt[b, j], 0, 0, 0)
-        ),
-        pl.BlockSpec(
-            (1, 1, n_kv, bs, D), lambda b, j, li, bt, ta: (li[0], bt[b, j], 0, 0, 0)
-        ),
-    ]
-    tensors += [k_cache, v_cache]
-
-    out = _common_call(
-        kernel,
-        grid=(B, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq * K, D), lambda b, j, li, bt, ta: (b, 0, 0)),
-        operands=([li, block_table.astype(jnp.int32), tile_any], tensors),
-        out_shape=jax.ShapeDtypeStruct((B, Hq * K, D), q.dtype),
-        scratch=[
-            pltpu.VMEM((Hq * K, 1), jnp.float32),
-            pltpu.VMEM((Hq * K, 1), jnp.float32),
-            pltpu.VMEM((Hq * K, D), jnp.float32),
-        ],
-        interpret=interpret,
-        name="paged_tkg_decode_attention",
-    )
+    operands = (q, k_cache, v_cache, li, block_table.astype(jnp.int32), mask, sink)
+    P = pages_per_step(n_kv, bs, D, k_cache.dtype, MB)
+    if D % 128:
+        out = _paged_by_block(*operands, scale=scale, n_kv=n_kv, interpret=interpret)
+    else:
+        out = _paged_by_group(*operands, scale=scale, n_kv=n_kv, P=P, interpret=interpret)
     out = _unprep_out(out, B, K, Hq, D)
     if quantized:
         out = _apply_v_dequant(out, v_quant, layer_idx, n_rep).astype(out_dtype)

@@ -38,6 +38,7 @@ two async dispatches with static shapes beat one megakernel under XLA.
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from collections import deque
@@ -249,6 +250,18 @@ class ServingSession:
                 app.spec.attn.num_kv_heads,
                 app.spec.attn.head_dim,
                 tc.kv_dtype,
+            )
+            # what the paged decode kernel attends for a row (host-known: it
+            # follows from the pool's shape a head shard, as the kernel's does)
+            from neuronx_distributed_inference_tpu.ops.decode_attention import (
+                kv_blocks_walked,
+            )
+
+            pool = app.kv_cache.k
+            self._kv_blocks_walked = functools.partial(
+                kv_blocks_walked,
+                n_kv=pool.shape[2] // app.spec.attn.model_parallel,
+                bs=pool.shape[3], head_dim=pool.shape[4], cache_dtype=pool.dtype,
             )
         # async 1-ahead decode (reference modules/async_execution.py:190):
         # the decode step dispatched last step(), not yet fetched —
@@ -1730,7 +1743,7 @@ class ServingSession:
                     last[r.slot, 0] = r.last_token
                     pos[r.slot, 0] = p
                     seq_ids[r.slot] = r.slot
-                block_table = None
+                block_table = kv_blocks = None
                 if self.block_mode:
                     bs = self.allocator.block_size
                     width = get_target_bucket(tkg.buckets, int(pos.max()) + 1)
@@ -1751,6 +1764,9 @@ class ServingSession:
                         block_table[r.slot] = self.allocator.block_table(r.slot, mb)
                     if not rows:
                         return None, []
+                    if tel.enabled:
+                        live = [-(-(p + 1) // bs) for _, p in rows]
+                        kv_blocks = (sum(live), self._kv_blocks_walked(live, mb))
                     # no host slot mapping: decode writes derive their slots
                     # IN-GRAPH from the block table
                     # (models/base.run_decoder_layers; reference
@@ -1784,16 +1800,19 @@ class ServingSession:
         tel.step("decode")
         tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
         tel.decode_pass(len(rows), B)
-        self._count_pass("decode", len(rows), len(rows), 1)
+        self._count_pass("decode", len(rows), len(rows), 1, kv_blocks=kv_blocks)
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
         return out, [(r, p, r.slot, r.epoch) for r, p in rows]
 
     def _count_pass(self, program: str, rows: int, tokens: int, dispatches: int,
-                    resets: int = 0) -> None:
+                    resets: int = 0, kv_blocks=None) -> None:
         """What a pass of the split serving step ("decode" or "chunk") did
         to per-slot state and routed experts, from what the step already
         knows: ``rows`` live rows over ``tokens`` real token positions in
-        ``dispatches`` dispatches, ``resets`` of the rows from position 0."""
+        ``dispatches`` dispatches, ``resets`` of the rows from position 0;
+        ``kv_blocks``: a decode pass's (live, walked) pool blocks."""
+        if kv_blocks is not None:
+            self.tel.decode_kv_blocks(*kv_blocks)
         if self.slot_state_kind == "ssm":
             self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets)
         elif self.slot_state_kind == "latent_carry":

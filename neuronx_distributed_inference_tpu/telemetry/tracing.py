@@ -385,6 +385,14 @@ class TelemetrySession:
             "top-1 of 16 hits 15.3 on average); not a count of the distinct "
             "experts with a live row, which only the device knows",
             labels=("program",))
+        self._decode_kv_blocks = r.counter(
+            "nxdi_decode_kv_blocks_total",
+            "pool blocks of the decoding rows per decode dispatch of the "
+            "split serving step: kind=live, the blocks their contexts hold; "
+            "kind=walked, the block-table entries the paged decode kernel's "
+            "kv axis attends for them (whole groups of blocks up to a row's "
+            "last live one). live / walked = the share of attended KV that "
+            "was live", labels=("kind",))
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -1095,6 +1103,14 @@ class TelemetrySession:
             return
         self._moe_rows.child((program,)).inc(rows_routed)
         self._moe_experts.child((program,)).inc(experts)
+
+    def decode_kv_blocks(self, live: int, walked: int) -> None:
+        """One decode dispatch over a paged cache: the pool blocks its rows'
+        contexts hold, and the block-table entries the kernel attends."""
+        if not self.enabled:
+            return
+        self._decode_kv_blocks.child(("live",)).inc(live)
+        self._decode_kv_blocks.child(("walked",)).inc(walked)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

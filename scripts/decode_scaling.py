@@ -1,8 +1,9 @@
 #!/usr/bin/env python
 """Kernel tile sweeps for the decode path, for a session on the chip: the
 TKG decode-attention kernel across its legal kv tiles (``bs``) and the int4
-fused-dequant matmul across its legal output tiles (``bn``), each at a
-committed registry shape. Candidates come from the kernel audit's
+fused-dequant matmul across its legal output tiles (``bn``) and the paged
+decode kernel across its legal group sizes (``pages``), each at a committed
+registry shape. Candidates come from the kernel audit's
 ``legal_tiles``; a winner measured on hardware is what gets promoted into
 ``analysis/tuning_table.json`` with provenance ``measured``.
 
@@ -101,10 +102,76 @@ def sweep_quant_matmul_tiles(shape_class="k2048_n8192", B=8, n=20,
     return rows
 
 
+def sweep_paged_pages(n_kv=8, n_q=16, layers=28, B=48, bs=32, MB=32, D=128, n=10):
+    """The paged decode kernel across the LEGAL group sizes (``pages``: pool
+    blocks a step) at a served decode shape, timed as the model runs it: once
+    a layer over the stacked pool, B slots whose contexts are drawn as the
+    benchmark's decode mix holds them (a prompt of 64-256 tokens and a uniform
+    share of an output of 256-768: ~420 tokens, a third of the rows past 512),
+    the block table a permutation.
+    Defaults: Qwen3-1.7B on one chip; ``n_kv=2, n_q=8, layers=20`` is the
+    two-KV-head shape (ZAYA1-8B; Qwen3-14B a chip at tp = 4). The winner is
+    what ``analysis/tuning_table.json`` holds as ``measured`` (PERF.md, PR 36)."""
+    import jax
+    import jax.numpy as jnp
+
+    from neuronx_distributed_inference_tpu.analysis.kernel_audit import legal_tiles
+    from neuronx_distributed_inference_tpu.ops.decode_attention import (
+        paged_tkg_decode_attention,
+    )
+    from neuronx_distributed_inference_tpu.ops.tile_defaults import tile_overrides
+
+    rng = np.random.RandomState(0)
+    lens = rng.randint(64, 257, size=B) + (rng.rand(B) * rng.randint(256, 769, size=B)).astype(int)
+    blocks = -(-lens // bs)
+    NB = int(blocks.sum())
+    key = jax.random.PRNGKey(0)
+    k_pool, v_pool = (
+        jax.random.normal(jax.random.fold_in(key, i), (layers, NB + 1, n_kv, bs, D), jnp.bfloat16)
+        for i in (1, 2)
+    )
+    q = jax.random.normal(key, (B, 1, n_q, D), jnp.bfloat16)
+    table = np.zeros((B, MB), np.int32)
+    pages = iter(rng.permutation(np.arange(1, NB + 1)))
+    for b in range(B):
+        table[b, : blocks[b]] = [next(pages) for _ in range(blocks[b])]
+    table = jnp.asarray(table)
+    mask = jnp.asarray(np.arange(MB * bs)[None, :] < lens[:, None])[:, None, None, :]
+    kernel = paged_tkg_decode_attention.__wrapped__  # the override is no jit cache key
+
+    def make_dispatch():  # a new function a candidate: jit caches traces by function
+        def dispatch(q, k_pool, v_pool, table, mask):
+            def layer(li, acc):
+                out = kernel(q, k_pool, v_pool, li, table, mask, scale=D**-0.5, n_kv=n_kv)
+                return acc + out.astype(jnp.float32)
+
+            return jax.lax.fori_loop(0, layers, layer, jnp.zeros(q.shape, jnp.float32))
+
+        return jax.jit(dispatch)
+
+    rows = {"live_kv_ms_at_peak": round(
+        float(lens.sum()) * n_kv * D * 2 * 2 * layers / 819e9 * 1e3, 3)}
+    for tiles in legal_tiles("paged_tkg_decode_attention", f"blk{n_kv}x{bs}x{D}", "bfloat16"):
+        with tile_overrides("paged_tkg_decode_attention", tiles):
+            try:
+                fn = make_dispatch()
+                fn(q, k_pool, v_pool, table, mask).block_until_ready()
+                t0 = time.time()
+                for _ in range(n):
+                    out = fn(q, k_pool, v_pool, table, mask)
+                out.block_until_ready()
+                rows[f"pages{tiles['pages']}"] = {"ms": round((time.time() - t0) / n * 1e3, 3)}
+            except Exception as e:  # a group the backend rejects
+                rows[f"pages{tiles['pages']}"] = {"error": str(e)[:80]}
+    return rows
+
+
 def main():
     print(json.dumps({
         "tkg_tile_sweep_kv512": sweep_tkg_tiles(bucket=512),
         "quant_matmul_tile_sweep_1b": sweep_quant_matmul_tiles(),
+        "paged_pages_sweep_qwen3_1p7b": sweep_paged_pages(),
+        "paged_pages_sweep_2kv": sweep_paged_pages(n_kv=2, n_q=8, layers=20),
     }), flush=True)
 
 

@@ -74,7 +74,10 @@ MAIN_PATH_KERNELS = [
     ("flash_attention", "masked", "bfloat16"),
     ("tkg_decode_attention", "kv512", "bfloat16"),  # 1B decode
     ("tkg_decode_attention", "kv512", "int8_8b"),  # 8B widths, int8 cache
-    ("paged_tkg_decode_attention", "kv1024", "bfloat16"),  # serving decode
+    ("paged_tkg_decode_attention", "blk8x128x64", "bfloat16"),  # serving decode, 1B: a block a step
+    ("paged_tkg_decode_attention", "blk8x32x128", "bfloat16"),  # Qwen3-1.7B: 16 blocks a step, 48 slots
+    ("paged_tkg_decode_attention", "blk8x32x128", "int8"),
+    ("paged_tkg_decode_attention", "blk2x32x128", "bfloat16"),  # 2 kv heads a chip (14B at tp=4, ZAYA1)
     ("paged_flash_attention", "sq512", "bfloat16"),  # chunked prefill
     ("ragged_paged_attention", "mixed", "bfloat16"),  # ragged mixed step
     ("ragged_paged_attention", "mixed", "int8"),

@@ -87,38 +87,107 @@ def test_tkg_contiguous_windowed_mask():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("K", [1, 4])
-def test_tkg_paged_parity(K):
+# what the paged kernel's unit of work (a group of ``pages_per_step`` pool
+# blocks of one row, none past the row's last live block) can get wrong.
+# ``valid`` = context tokens per row, a number or (groups, blocks, tokens) of
+# the case's own group width (0: a row with no live block). bs = 16: 2 heads
+# of 128 give P = 32 (the token limit), 8 heads P = 16 in float32 (the byte
+# limit), a table of 8 entries P = 8 = MB; at head_dim 64 blocks come one a
+# grid step.
+_E = 16  # a block's tokens
+PAGED_CASES = {
+    # the two cases this test had (K 1 and 4, contexts that end inside a block)
+    "k1": dict(K=1, valid=[6 * _E - 5, 3 * _E - 9]),
+    "k4": dict(K=4, valid=[6 * _E - 5, 3 * _E - 9]),
+    "d128_mb_eq_p": dict(D=128, valid=[6 * _E - 5, 3 * _E - 9]),
+    "d128_block_edge": dict(D=128, valid=[5 * _E, _E]),
+    "d128_mb_gt_p_group_edge": dict(D=128, MB=64, valid=[(1, 0, 0), (1, 0, 1), (2, 0, 0)]),
+    "d128_inside_second_group": dict(D=128, MB=64, valid=[(1, 7, -3), 9]),
+    "d128_dead_row_among_live": dict(D=128, MB=64, valid=[(1, 0, -1), 0, 40, 0, (1, 0, 5)]),
+    "d128_first_rows_dead": dict(D=128, MB=64, valid=[0, 0, 3 * _E]),
+    "d128_table_no_multiple_of_p": dict(D=128, MB=40, valid=[40 * _E, (1, 1, -2)]),
+    "d128_table_of_12": dict(D=128, MB=12, valid=[12 * _E, 9 * _E - 2, 0, 3]),
+    "d128_k4_sink": dict(D=128, MB=64, K=4, sink=True, valid=[(1, 0, 3), 0, 50]),
+    "d128_k4": dict(D=128, K=4, valid=[6 * _E - 5, 3 * _E - 9]),
+    "d128_sink": dict(D=128, sink=True, valid=[6 * _E - 5, 0]),
+    "d128_8kv": dict(D=128, HKV=8, HQ=16, MB=32, valid=[(1, 1, 4), 0, (2, 0, -1)]),
+    "d128_8kv_k4_sink": dict(D=128, HKV=8, HQ=16, K=4, sink=True, valid=[100, 7 * _E]),
+    "d128_bf16": dict(D=128, MB=64, dtype="bfloat16", valid=[(1, 1, 4), 0, 77]),
+    "d128_bf16_8kv_k4": dict(D=128, HKV=8, HQ=16, MB=64, K=4, dtype="bfloat16", valid=[(1, 0, 0), 31]),
+    "d128_bf16_q_bf16": dict(D=128, MB=64, dtype="bfloat16", q_dtype="bfloat16", valid=[(1, 1, 4), 0, 77]),
+    "d128_int8": dict(D=128, MB=64, dtype="int8", valid=[(1, 1, 4), 0, 77]),
+    "d128_int8_8kv_sink": dict(D=128, HKV=8, HQ=16, dtype="int8", sink=True, valid=[6 * _E - 5, 0]),
+    "d64_8kv_sink": dict(HKV=8, HQ=16, sink=True, valid=[6 * _E - 5, 0, 8 * _E]),
+    "d64_bf16_dead_row": dict(dtype="bfloat16", valid=[0, 5 * _E]),
+    "d64_int8_k4": dict(dtype="int8", K=4, valid=[6 * _E - 5, 3 * _E - 9]),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_tkg_paged_parity(case):
+    """Interpret mode against the native gather path. The block table's
+    entries are a permutation (never consecutive) and zero past a row's last
+    block; a dead row reads zeros from the kernel (the native softmax over an
+    all-masked row is a mean over garbage: not compared)."""
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
         read_block_cache_at_layer,
     )
+    from neuronx_distributed_inference_tpu.modules.kvcache import QuantizedKV
+    from neuronx_distributed_inference_tpu.ops.decode_attention import pages_per_step
 
+    c = dict(K=1, sink=False, HQ=HQ, HKV=HKV, D=D, MB=8, dtype="float32", q_dtype="float32")
+    c.update(PAGED_CASES[case])
+    K, hq, hkv, d, MB = c["K"], c["HQ"], c["HKV"], c["D"], c["MB"]
+    bs, layer = _E, 2
+    P = pages_per_step(hkv, bs, d, c["dtype"], MB)
+    limit = 2**20 // (hkv * bs * d * jnp.dtype(c["dtype"]).itemsize)  # 1 MiB a stream
+    most = min(32, limit, MB)
+    assert P == (1 if d == 64 else 1 << (most.bit_length() - 1))
+    valid = [
+        v if isinstance(v, int) else v[0] * P * bs + v[1] * bs + v[2] for v in c["valid"]
+    ]
+    assert max(valid) <= MB * bs
     rng = np.random.RandomState(3 + K)
-    B, NB, bs, MB = 2, 12, 16, 8
-    layer = 2
-    q = _rand(rng, B, K, HQ, D)
-    # head-major paged layout (L, NB+1, Hkv, bs, D)
-    k_cache = _rand(rng, L, NB + 1, HKV, bs, D)
-    v_cache = _rand(rng, L, NB + 1, HKV, bs, D)
-    # distinct non-garbage blocks per row; unused tail -> 0 (garbage)
+    B = len(valid)
+    NB = sum(-(-v // bs) for v in valid) + 5
+    q = _rand(rng, B, K, hq, d).astype(c["q_dtype"])
+    shape = (L, NB + 1, hkv, bs, d)  # head-major paged layout
+    if c["dtype"] == "int8":
+        k_cache, v_cache = (
+            QuantizedKV(
+                data=jnp.asarray(rng.randint(-127, 128, size=shape), jnp.int8),
+                scale=jnp.asarray(rng.uniform(0.5, 2.0, size=(L, hkv)), jnp.float32),
+            )
+            for _ in range(2)
+        )
+    else:
+        k_cache, v_cache = (_rand(rng, *shape).astype(c["dtype"]) for _ in range(2))
     bt = np.zeros((B, MB), np.int32)
-    bt[0, :6] = rng.permutation(np.arange(1, NB + 1))[:6]
-    bt[1, :3] = rng.permutation(np.arange(1, NB + 1))[:3]
+    pages = iter(rng.permutation(np.arange(1, NB + 1)))
+    for b, v in enumerate(valid):
+        n = -(-v // bs)
+        bt[b, :n] = [next(pages) for _ in range(n)]
     block_table = jnp.asarray(bt)
-    valid = [6 * bs - 5, 3 * bs - 9]
     mask, _ = _decode_mask(rng, B, K, MB * bs, valid)
+    sink_w = _rand(rng, hq) if c["sink"] else None
 
-    spec = _spec()
-    k_r, v_r = read_block_cache_at_layer(
-        k_cache, v_cache, jnp.int32(layer), block_table
+    spec = AttnSpec(num_heads=hq, num_kv_heads=hkv, head_dim=d, has_sink=c["sink"])
+    k_r, v_r = read_block_cache_at_layer(k_cache, v_cache, jnp.int32(layer), block_table)
+    ref = attention_decode(
+        q.astype(jnp.float32), k_r.astype(jnp.float32), v_r.astype(jnp.float32),
+        mask, spec, sink=sink_w,
     )
-    ref = attention_decode(q, k_r, v_r, mask, spec)
-
     out = paged_tkg_decode_attention(
-        q, k_cache, v_cache, jnp.int32(layer), block_table, mask, None,
-        scale=spec.softmax_scale, n_kv=HKV, interpret=True,
+        q, k_cache, v_cache, jnp.int32(layer), block_table, mask, sink_w,
+        scale=spec.softmax_scale, n_kv=hkv, interpret=True,
     )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    out = np.asarray(out.astype(jnp.float32))
+    live = np.asarray(valid) > 0
+    tol = dict(atol=2e-2, rtol=2e-2) if c["q_dtype"] == "bfloat16" else (
+        dict(atol=2e-3, rtol=2e-4) if c["dtype"] == "int8" else dict(atol=2e-5, rtol=2e-5))
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live], **tol)
+    assert not out[~live].any()
 
 
 def test_use_tkg_kernel_gates():
@@ -204,3 +273,56 @@ def test_tkg_kernel_serving_paged_decode():
         assert sess.add_request("r2", [64, 3, 27, 9, 14, 33], max_new_tokens=5)
         results[flag] = sess.run_to_completion()
     assert results[True] == results[False]
+
+
+@pytest.mark.parametrize("head_dim", [128, 32])
+def test_decode_kv_block_counter_reads_what_the_host_knows(head_dim):
+    """``nxdi_decode_kv_blocks_total``: per decode dispatch the pool blocks the
+    decoding rows' contexts hold (``live``) and the block-table entries the
+    paged kernel's kv axis attends for them (``walked``: whole groups of
+    ``pages_per_step`` blocks up to a row's last live one; every entry of the
+    table at a head_dim off the 128 lanes). Rows of KNOWN lengths: prompts of
+    40 and 6 tokens in blocks of 16, so decode dispatch i has contexts of
+    41 + i and 7 + i tokens."""
+    import sys, os
+    sys.path.insert(0, os.path.dirname(__file__))
+    from conftest import make_tiny_config, make_random_hf_state_dict
+
+    from neuronx_distributed_inference_tpu.ops.decode_attention import pages_per_step
+    from neuronx_distributed_inference_tpu.runtime.application import TpuModelForCausalLM
+    from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+
+    cfg = make_tiny_config(
+        hidden_size=2 * head_dim, num_attention_heads=2, num_key_value_heads=1,
+        tpu=dict(
+            seq_len=128, token_generation_buckets=[128], is_continuous_batching=True,
+            is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=64, batch_size=2,
+            ctx_batch_size=1, attn_block_tkg_kernel_enabled=True, async_mode=False,
+        ),
+    )
+    app = TpuModelForCausalLM(None, cfg)
+    app.load(state_dict=make_random_hf_state_dict(cfg))
+    tel = TelemetrySession(enabled=True)
+    sess = ServingSession(app, telemetry=tel)
+    seen = []
+    dispatch = sess._dispatch_decode
+    sess._dispatch_decode = lambda rows, *a, **k: (
+        seen.append([p + 1 for _, p in rows]), dispatch(rows, *a, **k))[1]
+    assert sess.add_request("long", list(range(1, 41)), max_new_tokens=10)
+    assert sess.add_request("short", [5, 17, 92, 41, 33, 88], max_new_tokens=5)
+    while sess.active:  # step by step, as a server is driven: the split step
+        sess.step()
+    # the lengths are the known ones: both rows decode together, then one alone
+    assert [41 + i for i in range(9)] == [c[0] for c in seen if c[0] > 40][:9]
+    assert [7, 8, 9, 10] == sorted(n for c in seen for n in c if n < 40)
+
+    MB, bs = 128 // 16, 16
+    P = pages_per_step(1, bs, head_dim, cfg.tpu_config.kv_dtype, MB)
+    assert P == (MB if head_dim == 128 else 1)
+    blocks = [-(-n // bs) for c in seen for n in c]
+    walked = [-(-n // P) * P if head_dim == 128 else MB for n in blocks]
+    snap = tel.registry.snapshot()["nxdi_decode_kv_blocks_total"]["samples"]
+    got = {x["labels"]["kind"]: x["value"] for x in snap}
+    assert got == {"live": sum(blocks), "walked": sum(walked)}
+    assert got["live"] == 3 * 8 + 4 * 1 + 1 * 4  # 41-48 tokens are 3 blocks, 49 is 4; 7-10 are 1
