@@ -76,8 +76,59 @@
     A reference that sets ``CHOICES`` while the probe returned no third
     value is a CorrectnessError, not a silent fall-back to three routes.
     The session rule is read on the replayed ``ref32``.
+
+    A model whose step is a block (a step fills several positions at once,
+    some of them holding a mask token, sees them both ways, and a token is
+    predicted AT its position): the probe drives passes the reference
+    plans. Three parties opt in, each by name; a reference that does not is
+    called exactly as above:
+      - the reference module sets ``PASSES = True`` and gives
+        ``probe_budget(geo) -> int`` (the tokens the probe session is asked
+        for and the seed draws for the long prompt, in place of
+        PROBE_DECODE_STEPS + 1) and ``probe_passes(geo, prompt, following,
+        revealed_at=None) -> (prefill_len, [pass, ...])``: how many of the
+        prompt's tokens the chunk passes carry (their one read is then at
+        ``prefill_len - 1``) and the passes that follow them, each
+        ``{"ids": (q,), "positions": (q,), "read": [indices of the pass
+        whose logits are compared], "chosen": [per read, the token the row
+        reveals there, or -1]}`` (further keys are the module's own).
+        ``following`` are the tokens after the prompt (the seed's, or the
+        session's) and ``revealed_at`` the session's record below (None for
+        the long prompt: the module makes an order from ``geo`` and the
+        tokens alone, so that the run is a function of ``--seed``). The
+        module, not this file, knows what a mask token is, what a pass that
+        commits is and which position predicts which token;
+      - the program: a finished request of the probe session carries
+        ``revealed_at``, one int per generated token: the ordinal, within
+        its block, of the pass that revealed it. The option that makes the
+        session record it is named under ``probe_tpu_config``. A ``PASSES``
+        reference whose session returned none is a CorrectnessError;
+      - ``_forced_pass`` runs the chunk passes over ``prompt[:prefill_len]``
+        and then every planned pass through the same ``forward``: ids
+        ``(rows, q)``, their positions, ``attention_mask = arange(width) <=
+        the pass's last position``, ``slot_mapping`` = the slots of its
+        positions, the block table; a row whose plan has ended sits out
+        (``seq_ids`` -1, no slot), as in the chunk passes. The logits are
+        kept at ``read`` and, where ``forward`` returns a third value, the
+        choices of EVERY token of every pass, in pass order.
+      - ``reference_logits(params, geo, prompt, passes[, choices=])`` and
+        ``twin_logits(...)`` are called once a row and give ``(1 + reads,
+        V)``; ``err``, ``floor``, ``K`` and ``err <= K * floor`` are what
+        they are.
+      - session, where it was predicted: for every read of the short
+        prompt's row with ``chosen >= 0``, that token is, by ref32 AT THAT
+        READ, within K * floor of the best. (The rule above with the
+        position the reference names in place of "the one before".)
+      - the reveal is a choice and is held as one: with ``CHOICES`` too,
+        ``choice_margins(params, geo, prompt, passes, choices)`` may return
+        more choosing layers than the model has; the module appends one for
+        the reveal (``regret`` the most, over passes that reveal, by which
+        the float32 confidence of a revealed position lies under the best
+        position's still masked after it; ``score_floor`` max |twin's
+        confidence - float32's|), held by the margin rule as it stands.
 (b) In the window: every finished request has exactly its budget of tokens,
-    all inside the vocabulary; none ended FAILED.
+    all inside the vocabulary and none of them an id the configuration
+    reserves; none ended FAILED.
 (c) No compilation inside the window (``system.CompileLog``).
 
 ``compared`` lists every number of (a), (b) and (c) beside its limit, for
@@ -132,9 +183,27 @@ class CorrectnessError(AssertionError):
         self.facts = facts or {}
 
 
+def _planner(cfg: dict):
+    """(reference, its geometry) where the configuration's reference plans
+    passes (module docstring, "A model whose step is a block"), else None."""
+    reference = load_reference(cfg)
+    if not getattr(reference, "PASSES", False):
+        return None
+    degree = cfg["tpu_config"].get("tp_degree", 1)
+    return reference, reference.geometry(system.model_attrs(cfg), degree)
+
+
+def probe_budget(cfg: dict) -> int:
+    """The tokens that follow a probe prompt: PROBE_DECODE_STEPS + 1, or
+    what a reference that plans passes asks for."""
+    planner = _planner(cfg)
+    return PROBE_DECODE_STEPS + 1 if planner is None else int(planner[0].probe_budget(planner[1]))
+
+
 def probe_width(cfg: dict, max_prompt: int) -> int:
-    """The kv bucket the cell's longest prompt ends in, decode steps included."""
-    need = max_prompt + PROBE_DECODE_STEPS + 1
+    """The kv bucket the cell's longest prompt ends in, the tokens that
+    follow it included."""
+    need = max_prompt + probe_budget(cfg)
     buckets = sorted(cfg["tpu_config"]["token_generation_buckets"])
     return next((b for b in buckets if b >= need), buckets[-1])
 
@@ -154,7 +223,10 @@ def load_reference(cfg: dict):
         "benchmark.harness.references." + cfg.get("reference", "dense"))
 
 
-def _session_tokens(probe, prompts: List[np.ndarray], budget: int) -> List[List[int]]:
+def _session_tokens(probe, prompts: List[np.ndarray], budget: int):
+    """(generated, revealed_at), per prompt: the tokens the probe session
+    generated, and the request's ``revealed_at`` where the program keeps one
+    (module docstring, "A model whose step is a block"), else None."""
     from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
 
     session = ServingSession(probe)
@@ -165,7 +237,7 @@ def _session_tokens(probe, prompts: List[np.ndarray], budget: int) -> List[List[
         if not session.active:
             break
         session.step()
-    out = []
+    out, revealed = [], []
     for i in range(len(prompts)):
         req = session.requests[f"probe-{i}"]
         if req.status != "finished" or len(req.generated) != budget:
@@ -173,10 +245,13 @@ def _session_tokens(probe, prompts: List[np.ndarray], budget: int) -> List[List[
                 f"probe request {i}: {req.status} with {len(req.generated)} of {budget} tokens"
             )
         out.append([int(t) for t in req.generated])
-    return out
+        at = getattr(req, "revealed_at", None)
+        revealed.append(None if at is None else [int(k) for k in at])
+    return out, revealed
 
 
-def _forced_pass(probe, prompts: List[np.ndarray], forced: List[List[int]], width: int):
+def _forced_pass(probe, prompts: List[np.ndarray], forced: List[List[int]], width: int,
+                 plans=None):
     """Teacher-forced pass through ``app.forward`` on the paged cache: the
     prompt in chunks of the session's chunk size, then one decode step per
     forced token. Row r owns blocks 1 + r*per_row ... (block 0 is the
@@ -184,7 +259,14 @@ def _forced_pass(probe, prompts: List[np.ndarray], forced: List[List[int]], widt
     (1 + steps, V) logits at the last prompt position and after each forced
     token, and, where ``forward`` returns a third value (module docstring,
     "A model that chooses"), per prompt a dict ``name -> (len(prompt) +
-    steps, ...)`` of the choices made at every token of the row; else None."""
+    steps, ...)`` of the choices made at every token of the row; else None.
+
+    With ``plans`` (per prompt ``probe_passes``'s ``(prefill_len, passes)``;
+    module docstring, "A model whose step is a block") the chunks carry
+    ``prompt[:prefill_len]`` and the planned passes take the decode steps'
+    place, ``forced`` unused: the logits are (1 + reads, V), at
+    ``prefill_len - 1`` and at every read in pass order, the choices
+    ``name -> (prefill_len + the passes' tokens, ...)``."""
     tc = probe.config.tpu_config
     bs = tc.pa_block_size
     per_row = width // bs
@@ -200,6 +282,10 @@ def _forced_pass(probe, prompts: List[np.ndarray], forced: List[List[int]], widt
         for name, a in (aux[0] if aux else {}).items():
             chose[r].setdefault(name, []).append(np.asarray(a[r, :n]))
 
+    if plans is not None:
+        if min(prefill_len for prefill_len, _ in plans) < 1:
+            raise CorrectnessError("a plan whose chunk passes carry no token has no first read")
+        prompts = [p[:prefill_len] for p, (prefill_len, _) in zip(prompts, plans)]
     longest = max(len(p) for p in prompts)
     for start in range(0, longest, chunk):
         ids = np.zeros((B, chunk), np.int32)
@@ -226,15 +312,39 @@ def _forced_pass(probe, prompts: List[np.ndarray], forced: List[List[int]], widt
             got[r].append(np.asarray(logits[r, idx], np.float32))
         for r, n in live.items():
             keep(aux, r, n)
-    for step in range(PROBE_DECODE_STEPS):
-        ids = np.asarray([[f[step]] for f in forced], np.int32)
-        pos = np.asarray([[len(p) + step] for p in prompts], np.int32)
-        mask = (np.arange(width)[None, :] <= pos).astype(np.int32)
-        _, logits, *aux = probe.forward(ids, pos, seq_ids, attention_mask=mask,
-                                        block_table=table, phase="tkg")
-        for r in range(B):
-            got[r].append(np.asarray(logits[r, 0], np.float32))
-            keep(aux, r, 1)
+    if plans is None:
+        for step in range(PROBE_DECODE_STEPS):
+            ids = np.asarray([[f[step]] for f in forced], np.int32)
+            pos = np.asarray([[len(p) + step] for p in prompts], np.int32)
+            mask = (np.arange(width)[None, :] <= pos).astype(np.int32)
+            _, logits, *aux = probe.forward(ids, pos, seq_ids, attention_mask=mask,
+                                            block_table=table, phase="tkg")
+            for r in range(B):
+                got[r].append(np.asarray(logits[r, 0], np.float32))
+                keep(aux, r, 1)
+    else:
+        for k in range(max(len(passes) for _, passes in plans)):
+            live = {r: passes[k] for r, (_, passes) in enumerate(plans) if k < len(passes)}
+            q = max(len(p["ids"]) for p in live.values())
+            ids = np.zeros((B, q), np.int32)
+            pos = np.zeros((B, q), np.int32)
+            sm = np.full((B, q), -1, np.int32)
+            mask = np.zeros((B, width), np.int32)
+            rows = np.full(B, -1, np.int32)  # a row whose plan has ended sits out
+            for r, p in live.items():
+                at = np.asarray(p["positions"], np.int32)
+                n = len(at)
+                rows[r] = r
+                ids[r, :n] = p["ids"]
+                pos[r] = np.concatenate([at, at[-1] + 1 + np.arange(q - n)])
+                sm[r, :n] = slot(r, at)
+                mask[r, : at.max() + 1] = 1
+            _, logits, *aux = probe.forward(ids, pos, rows, attention_mask=mask, slot_mapping=sm,
+                                            block_table=table, phase="tkg")
+            for r, p in live.items():
+                if len(p["read"]):
+                    got[r].extend(np.asarray(logits[r, np.asarray(p["read"])], np.float32))
+                keep(aux, r, len(p["ids"]))
     choices = [{name: np.concatenate(parts) for name, parts in row.items()} for row in chose]
     return [np.stack(g) for g in got], choices if any(choices) else None
 
@@ -246,30 +356,43 @@ def _forced_logits(probe, prompts: List[np.ndarray], forced: List[List[int]],
 
 
 def serve_probe(cfg: dict, devices, seed: int, params, pspecs, max_prompt: int):
-    """(prompts, chosen, served, choices): the two probe prompts, per prompt
-    the tokens that follow it (the long prompt's from the seed, the short
-    prompt's as the probe session chose them), the served logits
+    """(prompts, chosen, served, choices, plans): the two probe prompts, per
+    prompt the tokens that follow it (the long prompt's from the seed, the
+    short prompt's as the probe session chose them), the served logits
     (1 + PROBE_DECODE_STEPS, V) at the last prompt position and after each
-    of the first PROBE_DECODE_STEPS of them, and the choices the forced pass
-    returned per row (None where the program returns none). The probe
-    application is gone when this returns."""
+    of the first PROBE_DECODE_STEPS of them, the choices the forced pass
+    returned per row (None where the program returns none) and, for a
+    reference that plans passes, per prompt its ``(prefill_len, passes)``
+    (the served logits are then ``_forced_pass``'s (1 + reads, V); else
+    None). The probe application is gone when this returns."""
+    from .traffic import draw_ids
+
     vocab = system.model_attrs(cfg)["vocab_size"]
+    reserved = cfg.get("reserved_token_ids", ())
+    planner, budget = _planner(cfg), probe_budget(cfg)
     over = probe_overrides(cfg, max_prompt)
     probe = system.build_app(cfg, devices, seed, tpu_overrides=over["tpu"],
                              chunked_overrides=over["chunked"])
     system.give_weights(probe, params, pspecs)
     rng = np.random.default_rng([int(seed), 7])
-    prompts = [rng.integers(0, vocab, size=n).astype(np.int32)
+    prompts = [draw_ids(rng, vocab, n, reserved).astype(np.int32)
                for n in (max_prompt, PROBE_SHORT_PROMPT)]
-    budget = PROBE_DECODE_STEPS + 1
     try:
-        chosen = [[int(t) for t in rng.integers(0, vocab, size=budget)],
-                  _session_tokens(probe, prompts[1:], budget)[0]]
+        seeded = [int(t) for t in draw_ids(rng, vocab, budget, reserved)]
+        session, revealed_at = _session_tokens(probe, prompts[1:], budget)
+        chosen, plans = [seeded, session[0]], None
+        if planner is not None:
+            if revealed_at[0] is None:
+                raise CorrectnessError("the configuration's reference plans passes and the probe "
+                                       "session's request carries no revealed_at")
+            reference, geo = planner
+            plans = [reference.probe_passes(geo, prompts[0], chosen[0]),
+                     reference.probe_passes(geo, prompts[1], chosen[1], revealed_at[0])]
         probe.init_kv_cache()
-        served, choices = _forced_pass(probe, prompts, chosen, probe_width(cfg, max_prompt))
+        served, choices = _forced_pass(probe, prompts, chosen, probe_width(cfg, max_prompt), plans)
     finally:
         probe.params = probe.kv_cache = None
-    return prompts, chosen, served, choices
+    return prompts, chosen, served, choices, plans
 
 
 def probe_row(prompt, chosen):
@@ -280,11 +403,20 @@ def probe_row(prompt, chosen):
     return tokens, [len(prompt) - 1 + k for k in range(PROBE_DECODE_STEPS + 1)]
 
 
-def judge(cfg: dict, params, degree: int, prompts, chosen, served, choices=None) -> dict:
+def reference_args(prompt, chosen, plan=None) -> tuple:
+    """What a reference's two passes are handed after ``geo``: ``probe_row``'s
+    (tokens, positions), or (prompt, passes) of a row that was planned."""
+    return probe_row(prompt, chosen) if plan is None else (list(prompt), plan[1])
+
+
+def judge(cfg: dict, params, degree: int, prompts, chosen, served, choices=None,
+          plans=None) -> dict:
     """``served`` against the float32 reference and its bf16 twin, row by
     row (module docstring); with a reference that replays (``CHOICES``),
     both follow ``choices`` (per row, ``_forced_pass``'s) and every choosing
-    layer is held to its margin. Raises CorrectnessError; returns the facts
+    layer is held to its margin; with one that plans passes (``PASSES``),
+    ``plans`` are ``serve_probe``'s and a session's token is judged at the
+    read that predicted it. Raises CorrectnessError; returns the facts
     it read: per row ``err``, ``floor``, ``scale``, ``ratio`` = err / floor
     and the same ratio of root mean squares (steadier than a ratio of
     maxima; printed, not judged), and per choosing layer ``regret``,
@@ -298,28 +430,40 @@ def judge(cfg: dict, params, degree: int, prompts, chosen, served, choices=None)
     if replay and choices is None:
         raise CorrectnessError(
             "the configuration's reference replays choices and the program returned none", facts)
+    if bool(getattr(reference, "PASSES", False)) != (plans is not None):
+        raise CorrectnessError("a reference that plans passes, and it alone, is judged by its plans", facts)
     errors = []
     rms = lambda a: float(np.sqrt(np.mean(np.square(a, dtype=np.float64))))
     for r, p in enumerate(prompts):
-        tokens, positions = probe_row(p, chosen[r])
+        args = reference_args(p, chosen[r], plans and plans[r])
         follow = {"choices": choices[r]} if replay else {}
         t0 = time.perf_counter()
-        ref = reference.reference_logits(params, geo, tokens, positions, **follow)
+        ref = reference.reference_logits(params, geo, *args, **follow)
         t1 = time.perf_counter()
-        twin = reference.twin_logits(params, geo, tokens, positions, **follow)
+        twin = reference.twin_logits(params, geo, *args, **follow)
         t2 = time.perf_counter()
         got = np.asarray(served[r], np.float32)
+        if not got.shape == ref.shape == twin.shape:
+            raise CorrectnessError(f"prompt {r}: served logits {got.shape}, the reference's "
+                                   f"{ref.shape}, its twin's {twin.shape}", facts)
         err, floor = float(np.abs(got - ref).max()), float(np.abs(twin - ref).max())
+        # per compared position the token the row put there (-1: none): a token after each
+        # position read, or, planned, the one the plan says was revealed AT the read
+        picks = chosen[r][:budget] if plans is None else [-1] + [c for q in args[1] for c in q["chosen"]]
         # how far below the reference's best each token the session chose is
         regret = None
         if r > 0:  # the short prompt's tokens are the session's
-            regret = float(max(ref[k].max() - ref[k, chosen[r][k]] for k in range(budget)))
+            if max(picks) < 0:
+                raise CorrectnessError(f"prompt {r}: the plan reveals none of the session's tokens", facts)
+            regret = float(max(ref[k].max() - ref[k, c] for k, c in enumerate(picks) if c >= 0))
         row = {"prompt": len(p), "err": err, "floor": floor,
                "scale": float(np.abs(ref).max()),
                "ratio": err / floor if floor > 0 else None,
                "limit": K * floor, "session_token_regret": regret,
                "rms_ratio": rms(got - ref) / max(rms(twin - ref), 1e-30),
                "ref32_s": t1 - t0, "twin_s": t2 - t1}
+        if plans is not None:
+            row.update(prefill_len=plans[r][0], passes=len(args[1]), reads=len(picks) - 1)
         facts["rows"].append(row)
         if not np.isfinite(got).all():
             errors.append(f"prompt {r}: non-finite logits from the served model")
@@ -331,7 +475,8 @@ def judge(cfg: dict, params, degree: int, prompts, chosen, served, choices=None)
                 f"reference's best, more than {K} x the bf16 twin's error {floor:.4g}"
             )
         if replay:
-            margins, score_floor, *differing = reference.choice_margins(params, geo, tokens, choices[r])
+            margins, score_floor, *differing = reference.choice_margins(
+                params, geo, *(args if plans else args[:1]), choices[r])
             margins, score_floor = (np.asarray(a, np.float64).ravel() for a in (margins, score_floor))
             row.update(choice_regret=margins.tolist(), choice_score_floor=score_floor.tolist(),
                        choice_limit=(2 * K * score_floor).tolist(), choices_s=time.perf_counter() - t2)
@@ -354,9 +499,11 @@ def check_model(cfg: dict, devices, seed: int, params, pspecs, degree: int,
     return judge(cfg, params, degree, *serve_probe(cfg, devices, seed, params, pspecs, max_prompt))
 
 
-def check_window(records, session, vocab: int) -> List[str]:
-    """Part (b): the faults found in the window's requests (empty = none)."""
+def check_window(records, session, vocab: int, reserved=()) -> List[str]:
+    """Part (b): the faults found in the window's requests (empty = none);
+    ``reserved`` are the configuration's ``reserved_token_ids``."""
     faults = []
+    reserved = set(reserved)
     for rec in records:
         if rec.failed:
             faults.append(f"{rec.req_id}: {rec.failed}")
@@ -366,6 +513,8 @@ def check_window(records, session, vocab: int) -> List[str]:
         gen = req.generated
         if any(t < 0 or t >= vocab for t in gen):
             faults.append(f"{rec.req_id}: token outside the vocabulary")
+        for t in sorted(reserved.intersection(gen)):
+            faults.append(f"{rec.req_id}: reserved token {t} among its generated tokens")
         if rec.finished and len(gen) != rec.budget:
             faults.append(f"{rec.req_id}: finished with {len(gen)} of {rec.budget} tokens")
     return faults

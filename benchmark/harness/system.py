@@ -7,7 +7,7 @@ The benchmark keeps its own construction (``build_app``), weight hand-over
 change the program's tools and not the yardstick.
 
 Keys of a configuration file that are the benchmark's and never reach the
-model's attributes (``META_KEYS``), two of them for a model that needs more
+model's attributes (``META_KEYS``), three of them for a model that needs more
 than the default:
 
 ``weights``
@@ -29,7 +29,14 @@ than the default:
     a dict of ``TpuConfig`` options that ``correct.probe_overrides`` lays
     over the PROBE application only (as it lays ``output_logits``): where a
     model makes discrete choices, the option of the program that makes the
-    step return them (``correct.py``'s docstring, "A model that chooses").
+    step return them (``correct.py``'s docstring, "A model that chooses"),
+    or the session record at which pass it revealed a token ("A model whose
+    step is a block").
+``reserved_token_ids``
+    ids of the vocabulary that no prompt may hold and no request may
+    generate (a block model's mask token): ``traffic.draw_ids`` draws every
+    prompt without them, and ``correct.check_window`` makes a generated one
+    a fault of the window.
 """
 
 from __future__ import annotations
@@ -43,7 +50,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 META_KEYS = frozenset(
     {"name", "source", "deployment", "assumed", "reduced", "tpu_config",
      "chunked_prefill", "why", "rehearsal", "notes", "memory", "reference",
-     "weights", "probe_tpu_config"}
+     "weights", "probe_tpu_config", "reserved_token_ids"}
 )
 
 WEIGHT_STD = 0.02
@@ -259,9 +266,10 @@ def give_weights(app, params, pspecs):
 def reachable_shapes(app, max_prompt: int, max_context: int) -> List[Tuple[int, int]]:
     """(q length, kv bucket) of every program the split serving step can
     dispatch for prompts up to ``max_prompt`` and contexts up to
-    ``max_context``: a decode step (q=1) at each kv bucket a context can
-    fall in, and a prefill chunk at each rung of the program's q ladder for
-    each kv bucket a prompt position can fall in."""
+    ``max_context``: the decode step, written ``(1, bucket)`` whatever its
+    width in positions, at each kv bucket a context can fall in, and a
+    prefill chunk at each rung of the program's q ladder for each kv bucket
+    a prompt position can fall in."""
     from neuronx_distributed_inference_tpu.modules import autobucketing
 
     tc = app.config.tpu_config
@@ -275,18 +283,26 @@ def reachable_shapes(app, max_prompt: int, max_context: int) -> List[Tuple[int, 
 
 
 def warm_up(app, shapes: Iterable[Tuple[int, int]]):
-    """Run each program once on inputs that write to the garbage block (the
-    program's own warm-up does the same for ALL its shapes). A decode step
-    is run twice: the serving loop feeds it token ids from the host on a
-    row's first step and ids still on the device (the previous step's
-    output, chained) after that, and jit keeps a program for each. An
-    application that serves through the ragged mixed step has one family
-    of programs and its own warm-up for it."""
+    """Every program a session can dispatch for ``shapes`` compiles here.
+    An application that has ``warm_serving(shapes)`` is asked: which programs
+    its session dispatches, and every way it feeds them, is its own to say
+    (``(1, bucket)`` means "your decode step at this kv bucket"); rule (c)
+    of ``correct.py``, no compilation inside the window, holds it to
+    completeness. An application that serves through the ragged mixed step
+    has one family of programs and its own warm-up for it. Any other is
+    warmed by what is known of the split step: each program run once on
+    inputs that write to the garbage block, a decode step twice, because the
+    serving loop feeds it token ids from the host on a row's first step and
+    ids still on the device (the previous step's output, chained) after
+    that, and jit keeps a program for each."""
     import dataclasses
 
     import jax
     import jax.numpy as jnp
 
+    if hasattr(app, "warm_serving"):
+        app.warm_serving(list(shapes))
+        return
     if app.mixed_step_model is not None:
         app.warmup()
         return
@@ -306,7 +322,7 @@ def warm_up(app, shapes: Iterable[Tuple[int, int]]):
 
 def kernel_census(app, shapes: Iterable[Tuple[int, int]]) -> Dict[str, int]:
     """{"decode": n, "prefill": n}: the number of ``tpu_custom_call`` in the
-    compiled decode step (q=1) and in the compiled full prefill chunk
+    compiled decode step and in the compiled full prefill chunk
     (largest q), each at the widest kv bucket the cell reaches. Whether a
     Pallas kernel is IN a program is read from the executable, not from a
     gate (as ``chip_smoke.kernel_census``)."""

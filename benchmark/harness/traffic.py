@@ -26,6 +26,11 @@ system as it is holds some tens of requests; with the seed also permuting
 their order (the first design of PR 22) a median over them moved by 6-12%
 between seeds, from the order alone.
 
+Ids a configuration reserves (``reserved_token_ids`` in its file: a mask
+token, a pad) never appear in a prompt: ``draw_ids`` draws over the
+vocabulary without them. A configuration that reserves none draws the ids it
+always drew, bit for bit.
+
 ``first_round`` (optional, ``"mid_decode"`` by default) says where in their
 life the requests already in flight at the opening of the window are: see
 ``Traffic.first_round``.
@@ -40,7 +45,7 @@ from __future__ import annotations
 import hashlib
 import math
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -56,6 +61,23 @@ FIRST_ROUND_FLOOR = 16
 
 class TrafficError(ValueError):
     """A mix or a cell asks for traffic this generator cannot make."""
+
+
+def draw_ids(rng: np.random.Generator, vocab_size: int, size: int,
+             reserved: Sequence[int] = ()) -> np.ndarray:
+    """``size`` token ids, uniform over the vocabulary without ``reserved``:
+    ONE ``integers`` call over ``vocab_size - len(reserved)`` values, each
+    then shifted past every reserved id at or below it. With nothing
+    reserved that is the call every generator here made before."""
+    reserved = sorted(int(r) for r in reserved)
+    if len(set(reserved)) != len(reserved) or any(not 0 <= r < vocab_size for r in reserved):
+        raise TrafficError(f"reserved ids {reserved}: distinct ids inside the vocabulary, please")
+    if len(reserved) >= vocab_size:
+        raise TrafficError("every id of the vocabulary is reserved")
+    ids = rng.integers(0, vocab_size - len(reserved), size=size)
+    for r in reserved:  # ascending: an id shifted past one reserved id is tested against the next
+        ids = ids + (ids >= r)
+    return ids
 
 
 def draw_lengths(spec: dict, n: int, rng: np.random.RandomState) -> np.ndarray:
@@ -133,10 +155,11 @@ class Traffic:
 
     def __init__(self, mix: dict, *, seed: int, vocab_size: int, loop: str,
                  seconds: float, rate_rps: Optional[float] = None,
-                 max_prompt_len: Optional[int] = None):
+                 max_prompt_len: Optional[int] = None, reserved_ids: Sequence[int] = ()):
         self.mix = mix
         self.seed = int(seed)
         self.vocab_size = int(vocab_size)
+        self.reserved_ids = tuple(reserved_ids)
         self.loop = loop
         tenants = mix["tenants"]
         if not tenants:
@@ -185,11 +208,13 @@ class Traffic:
         # --seed draws the token ids. SeedSequence takes any non-negative
         # integer, so seeds beyond 2**31 need no folding.
         self._prefix: Dict[int, np.ndarray] = {
-            i: np.random.default_rng([self.seed, 1, i]).integers(
-                0, self.vocab_size, size=int(t.get("shared_prefix_len", 0))
-            )
+            i: self._ids([self.seed, 1, i], int(t.get("shared_prefix_len", 0)))
             for i, t in enumerate(tenants)
         }
+
+    def _ids(self, key: List[int], size: int) -> np.ndarray:
+        """``size`` prompt ids from the generator ``key`` seeds."""
+        return draw_ids(np.random.default_rng(key), self.vocab_size, size, self.reserved_ids)
 
     def __len__(self) -> int:
         return len(self.shapes)
@@ -201,9 +226,7 @@ class Traffic:
         if self.loop == "open" and index >= len(self.shapes):
             raise IndexError(index)
         prefix = self._prefix[shape.tenant]
-        suffix = np.random.default_rng([self.seed, 2, index]).integers(
-            0, self.vocab_size, size=shape.prompt_len - len(prefix)
-        )
+        suffix = self._ids([self.seed, 2, index], shape.prompt_len - len(prefix))
         tenant = self.tenants[shape.tenant]["name"]
         return TrafficRequest(
             index=index, req_id=f"{tenant}-{index:06d}", tenant=tenant,
@@ -244,9 +267,7 @@ class Traffic:
             cand = draw_lengths(t[cut], 16, rng).astype(np.float64)
             drawn = float(rng.choice(cand, p=cand / cand.sum()))
             lengths[cut] = max(FIRST_ROUND_FLOOR, int(round(drawn * frac[k])))
-            suffix = np.random.default_rng([self.seed, 4, k]).integers(
-                0, self.vocab_size, size=max(1, lengths["prompt"] - len(prefix))
-            )
+            suffix = self._ids([self.seed, 4, k], max(1, lengths["prompt"] - len(prefix)))
             out.append(TrafficRequest(
                 index=-1 - k, req_id=f"{t['name']}-first-{k:04d}", tenant=t["name"], due_s=None,
                 input_ids=np.concatenate([prefix, suffix]).astype(np.int32),

@@ -129,18 +129,19 @@ class Profiler:
             self.stopped = now
 
 
-def warm_drive(app, vocab: int, seed: int):
+def warm_drive(app, vocab: int, seed: int, reserved=()):
     """Two short requests through a throw-away session at the served batch:
     the small host-side programs of the serving loop (token chaining, pads)
     compile here and not in the window."""
     import numpy as np
 
+    from benchmark.harness.traffic import draw_ids
     from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
 
     rng = np.random.default_rng([int(seed), 9])
     session = ServingSession(app)
     for i, n in enumerate((24, 150)):
-        session.add_request(f"warm-{i}", rng.integers(0, vocab, size=n), max_new_tokens=4)
+        session.add_request(f"warm-{i}", draw_ids(rng, vocab, n, reserved), max_new_tokens=4)
     for _ in range(32):
         if not session.active:
             break
@@ -165,6 +166,7 @@ def main(argv=None) -> int:
     cfg = system.resolve_config(cell.config, rehearsal)
     attrs = system.model_attrs(cfg)
     degree = cfg["tpu_config"].get("tp_degree", 1)
+    reserved = cfg.get("reserved_token_ids", ())  # ids no prompt holds and no request may generate
     emit(phase="start", cell=cell.name, seed=args.seed, seconds=args.seconds,
          trace=args.trace, device=device_info, compile_cache=cache_dir,
          cache_entries=system.cache_dir_listing(cache_dir))
@@ -181,7 +183,7 @@ def main(argv=None) -> int:
     traffic = Traffic(
         mix, seed=args.seed, vocab_size=attrs["vocab_size"], loop=spec["loop"],
         seconds=args.seconds, rate_rps=spec.get("rate_rps"),
-        max_prompt_len=cfg["tpu_config"]["seq_len"] - 2,
+        max_prompt_len=cfg["tpu_config"]["seq_len"] - 2, reserved_ids=reserved,
     )
     t = time.perf_counter()
     try:
@@ -197,7 +199,7 @@ def main(argv=None) -> int:
     shapes = system.reachable_shapes(app, **traffic.bounds())
     system.warm_up(app, shapes)
     kernels = system.kernel_census(app, shapes) if args.trace == 1 and not rehearsal else {}
-    warm_drive(app, attrs["vocab_size"], args.seed)
+    warm_drive(app, attrs["vocab_size"], args.seed, reserved)
     emit(phase="warm_up", seconds=time.perf_counter() - t, programs=len(shapes),
          kernels=kernels, traffic=traffic.summary(), digest=traffic.digest(),
          memory=device.memory_by_chip(devices), **log.facts())
@@ -245,7 +247,7 @@ def main(argv=None) -> int:
     summary = stats.summarize(records, driver.window_s)
     spans = stats.span_stats(driver.spans, driver.window_s)
     host["steps"] = hostfacts.step_facts(driver.spans, driver.window_s)
-    faults = correct.check_window(records, session, attrs["vocab_size"])
+    faults = correct.check_window(records, session, attrs["vocab_size"], reserved)
     counted = sum(len(session.requests[r.req_id].generated) for r in records
                   if r.req_id in session.requests)
     stamped = sum(r.tokens for r in records)
@@ -384,6 +386,7 @@ def traced_phase(args, spec, mix, cfg, attrs, app, shapes, driver, telemetry,
             mix, seed=args.seed + 1, vocab_size=attrs["vocab_size"], loop="open",
             seconds=SETTLE_LIMIT_S + 2 * TRACE_SLICE_S, rate_rps=spec.get("rate_rps"),
             max_prompt_len=cfg["tpu_config"]["seq_len"] - 2,
+            reserved_ids=cfg.get("reserved_token_ids", ()),
         )
     compiles_before = log.compiles
     telemetry.start()

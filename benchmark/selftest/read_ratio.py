@@ -10,7 +10,8 @@ nearest precision below bf16, put in the program's place.
 One process, one set of compiled programs: per seed the weights are made
 anew, the probe of ``correct.serve_probe`` is served at the kv width the
 given longest prompt ends in (the widths given are taken in turn, seed after
-seed) and ``correct.judge`` reads the rows. For the first ``--control``
+seed) and ``correct.judge`` reads the rows (of a reference that plans passes:
+its passes; every row then carries the margins too, the reveal's last). For the first ``--control``
 seeds the same prompts and tokens go through the reference at
 ``rounding=float8_e4m3fn`` and ``judge`` is asked again with those logits as
 the served ones. One JSON line per seed; the last line sums up. Exits 1 if
@@ -74,10 +75,10 @@ def main(argv=None) -> int:
         app = system.build_app(cfg, devices, seed)
         params, pspecs = system.make_weights(app, seed, cfg.get("weights"))
         t1 = time.perf_counter()
-        prompts, chosen, served, choices = correct.serve_probe(cfg, devices, seed, params, pspecs, max_prompt)
+        prompts, chosen, served, choices, plans = correct.serve_probe(cfg, devices, seed, params, pspecs, max_prompt)
         t2 = time.perf_counter()
         try:
-            facts, ok = correct.judge(cfg, params, degree, prompts, chosen, served, choices), True
+            facts, ok = correct.judge(cfg, params, degree, prompts, chosen, served, choices, plans), True
         except correct.CorrectnessError as e:
             facts, ok = {"error": str(e), **e.facts}, False
         t3 = time.perf_counter()
@@ -88,11 +89,11 @@ def main(argv=None) -> int:
         if k < args.control:
             # a reference that replays follows the served routes at fp8 too
             follow = lambda r: {"choices": choices[r]} if getattr(reference, "CHOICES", False) else {}
-            fp8 = [reference.reference_logits(params, geo, *correct.probe_row(p, chosen[r]),
+            fp8 = [reference.reference_logits(params, geo, *correct.reference_args(p, chosen[r], plans and plans[r]),
                                               rounding=jnp.float8_e4m3fn, **follow(r))
                    for r, p in enumerate(prompts)]
             try:
-                facts8, passed = correct.judge(cfg, params, degree, prompts, chosen, fp8, choices), True
+                facts8, passed = correct.judge(cfg, params, degree, prompts, chosen, fp8, choices, plans), True
             except correct.CorrectnessError as e:
                 facts8, passed = e.facts, False
             bad += passed

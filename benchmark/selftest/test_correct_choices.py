@@ -100,7 +100,7 @@ def test_the_defect_unreplayed_the_ratio_is_a_ratio_of_flips(reference, weights,
     ratios, over = [], 0
     for seed in SEEDS:
         params, pspecs = weights(seed)
-        prompts, chosen, served, choices = correct.serve_probe(cfg, devices, seed, params, pspecs, MAX_PROMPT)
+        prompts, chosen, served, choices, _ = correct.serve_probe(cfg, devices, seed, params, pspecs, MAX_PROMPT)
         assert choices is None  # the program returns no choices yet
         assert all(np.isfinite(s).all() and s.shape == (5, cfg["vocab_size"]) for s in served)
         try:
@@ -185,7 +185,7 @@ def check_with(monkeypatch, cfg, seed, params, pspecs, stand_in) -> dict:
 
     monkeypatch.setattr(system, "build_app", lambda *a, **kw: stand_in)
     monkeypatch.setattr(correct, "_session_tokens",
-                        lambda probe, prompts, budget: probe.session_tokens(prompts, budget))
+                        lambda probe, prompts, budget: (probe.session_tokens(prompts, budget), [None] * len(prompts)))
     try:
         return correct.check_model(cfg, jax.devices()[:1], seed, params, pspecs, 1, MAX_PROMPT)
     except correct.CorrectnessError as e:

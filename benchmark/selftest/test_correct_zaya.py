@@ -66,7 +66,7 @@ def judged(tiny, served, choices):
 def test_a_sound_program_passes_and_its_routing_spreads(tiny, capsys):
     cfg, devices, params, pspecs, geo, *_ = tiny
     assert cfg["probe_tpu_config"] == {"output_choices": True} and ref.CHOICES
-    prompts, chosen, served, choices = correct.serve_probe(cfg, devices, SEED, params, pspecs, PROMPT)
+    prompts, chosen, served, choices, _ = correct.serve_probe(cfg, devices, SEED, params, pspecs, PROMPT)
     facts = correct.judge(cfg, params, 1, prompts, chosen, served, choices)
     assert facts["reference"] == "zaya"
     for row in facts["rows"]:

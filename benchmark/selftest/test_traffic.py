@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from benchmark.harness import catalog
-from benchmark.harness.traffic import Traffic, TrafficError, arrival_times, envelope, scale_mix
+from benchmark.harness.traffic import Traffic, TrafficError, arrival_times, draw_ids, envelope, scale_mix
 
 BIG = 4_000_000_123  # beyond 32 signed bits, as the driver's seeds are
 
@@ -127,3 +127,42 @@ def test_scale_mix_fits_the_rehearsal_preset():
     ten = m["tenants"][0]
     assert ten["prompt"]["max"] + ten["output"]["max"] <= 510
     assert scale_mix(mix("decode"), 100000) is not None
+
+
+def test_without_reserved_ids_every_prompt_is_the_parents_bit_for_bit():
+    """The ids ``Traffic`` drew before a configuration could reserve any: one
+    ``integers`` call over the whole vocabulary per prefix, request and
+    first-round request, from these generators."""
+    t = Traffic(mix("decode"), seed=BIG, vocab_size=151936, loop="closed", seconds=40.0)
+    for index in (0, 5, len(t) + 3):
+        then = np.random.default_rng([BIG, 2, index]).integers(0, 151936, size=len(t.request(index).input_ids))
+        assert t.request(index).input_ids.dtype == np.int32
+        assert np.array_equal(t.request(index).input_ids, then.astype(np.int32))
+    for k, r in enumerate(t.first_round(6)):
+        then = np.random.default_rng([BIG, 4, k]).integers(0, 151936, size=len(r.input_ids))
+        assert np.array_equal(r.input_ids, then.astype(np.int32))
+    m = mix("chat")
+    m["tenants"][0]["shared_prefix_len"] = 8
+    c = Traffic(m, seed=7, vocab_size=1000, loop="open", seconds=10.0, rate_rps=2.0)
+    prefix = np.random.default_rng([7, 1, 0]).integers(0, 1000, size=8)
+    assert np.array_equal(c.request(0).input_ids[:8], prefix)
+
+
+def test_reserved_ids_are_in_no_prompt_over_a_million_drawn_ids_and_the_rest_stay_uniform():
+    reserved = [0, 17, 18, 499]
+    t = Traffic(mix("longprompt"), seed=BIG, vocab_size=500, loop="closed", seconds=40.0,
+                reserved_ids=reserved)
+    counts, n = np.zeros(500, np.int64), 0
+    for r in t.first_round(8) + [t.request(i) for i in range(400)]:
+        counts += np.bincount(r.input_ids, minlength=500)
+        n += len(r.input_ids)
+        if n > 10**6:
+            break
+    assert n > 10**6 and not counts[reserved].any()
+    rest = np.delete(counts, reserved)
+    assert rest.min() > 0.9 * n / 496 and rest.max() < 1.1 * n / 496
+    ids = draw_ids(np.random.default_rng(3), 10, 1000, [9, 3, 4])  # any order, the edges too
+    assert set(ids) == {0, 1, 2, 5, 6, 7, 8}
+    for bad in ([3, 3], [10], [-1], list(range(10))):
+        with pytest.raises(TrafficError):
+            draw_ids(np.random.default_rng(3), 10, 5, bad)

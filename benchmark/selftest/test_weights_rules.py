@@ -1,7 +1,7 @@
 """``system.make_weights`` and the ``weights`` rules a configuration declares
 (``harness/system.py``'s docstring), at rehearsal size on the CPU:
 
-- the three configurations, which declare no rules, get bit for bit the
+- the configurations that declare no rules get bit for bit the
   weights a frozen copy of the generator as it stood before the rules
   (``frozen_make_weights``, PR 33's) gives for the same seed;
 - a rule list sets a bias leaf to 0, a gate leaf to 1 +- 0.05 and a router
@@ -107,7 +107,8 @@ def leaves_by_path(params) -> dict:
 @pytest.mark.parametrize("name", config_names())
 def test_a_configuration_without_rules_gets_the_weights_it_got(name):
     cfg, app = rehearsal_app(name)
-    assert "weights" not in cfg  # none of the benchmark's configurations declares rules
+    if "weights" in cfg:
+        pytest.skip(f"{name} declares weights rules: its weights are the rules', by its own file")
     now = leaves_by_path(system.make_weights(app, SEED, cfg.get("weights"))[0])
     then = leaves_by_path(frozen_make_weights(app, SEED))
     assert list(now) == list(then) and len(now) >= 10
