@@ -43,6 +43,8 @@ _8B = dict(H=4096, I=14336, Hq=32, Hkv=8, D=128, L=32)
 #: the attention of the benchmark's served decode programs at head_dim 128
 _QWEN3_1P7B = dict(Hq=16, Hkv=8, D=128, L=28)
 _KV2 = dict(Hq=8, Hkv=2, D=128, L=20)
+#: a block step: 4 query positions a row over 4 KV heads (sdar-30b-a3b)
+_BLOCK4 = dict(Hq=32, Hkv=4, D=128, L=6)
 
 
 @dataclass(frozen=True)
@@ -179,17 +181,17 @@ def _tkg_case(B, bucket, model, cache_dtype):
     return build
 
 
-def _paged_tkg_case(B, MB, bs, cache_dtype, m=_1B):
+def _paged_tkg_case(B, MB, bs, cache_dtype, m=_1B, K=1):
     def build():
         import jax.numpy as jnp
 
         from neuronx_distributed_inference_tpu.ops import decode_attention as da
 
-        q = _sds((B, 1, m["Hq"], m["D"]), jnp.bfloat16)
+        q = _sds((B, K, m["Hq"], m["D"]), jnp.bfloat16)
         cache = _sds((m["L"], 65, m["Hkv"], bs, m["D"]), jnp.dtype(cache_dtype))
         li = _sds((), jnp.int32)
         bt = _sds((B, MB), jnp.int32)
-        mask = _sds((B, 1, 1, MB * bs), jnp.bool_)
+        mask = _sds((B, 1, K, MB * bs), jnp.bool_)
         fn = functools.partial(
             _unjit(da.paged_tkg_decode_attention),
             scale=m["D"] ** -0.5, n_kv=m["Hkv"],
@@ -401,6 +403,11 @@ REGISTRY: Tuple[KernelSpec, ...] = (
             KernelCase(
                 "blk2x32x128", "bfloat16", _paged_tkg_case(48, 32, 32, "bfloat16", _KV2)
             ),
+            # a block step: K = 4 query positions a row, 32 query rows a KV head
+            KernelCase(
+                "blk4x32x128", "bfloat16",
+                _paged_tkg_case(48, 32, 32, "bfloat16", _BLOCK4, K=4),
+            ),
         ),
     ),
     KernelSpec(
@@ -493,6 +500,7 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
         "blk8x128x64": {"pages": 1},
         "blk8x32x128": {"pages": 16},
         "blk2x32x128": {"pages": 16},
+        "blk4x32x128": {"pages": 16},
     },
     "ragged_paged_attention": {"*": {"tq": 16}},
     "fused_moe_decode": {"*": {"ti_cap": 512}},

@@ -1007,6 +1007,53 @@ def validate_slot_state_serving(tc: "TpuConfig", what: str = "state-space layers
             raise SlotStateServingError(f"a model with {what} cannot be served with {why}")
 
 
+class BlockStepServingError(NotImplementedError):
+    """An option that cannot serve a model whose decode step fills a block of
+    positions (models/sdar.py) was set for one, or its block does not fit
+    the programs the serving path compiles."""
+
+
+def validate_block_step_serving(tc: "TpuConfig", block_length: int, denoise_steps: int,
+                                mask_token_id: int, vocab_size: int) -> None:
+    """Refuse, for a model whose builder declares a block step, every option
+    that is not built and tested for it. One line each: none is a silent
+    wrong answer."""
+    ods = tc.on_device_sampling_config
+    cpc = tc.chunked_prefill_config
+    chunk = cpc.kernel_q_tile_size if cpc else 128
+    speculation = (
+        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
+        or tc.enable_eagle_speculation or tc.serving_spec_ragged
+    )
+    refusals = (
+        (not (tc.is_block_kv_layout and tc.is_chunked_prefill and tc.is_continuous_batching),
+         "a contiguous cache or whole-prompt prefill: it is served on the paged, chunked path "
+         "only (is_block_kv_layout, is_chunked_prefill, is_continuous_batching)"),
+        (speculation, "speculation: a draft proposes one position after another"),
+        (tc.serving_ragged, "serving_ragged: the ragged mixed step has no block-causal mask"),
+        (tc.is_prefix_caching, "is_prefix_caching: a cached prefix would have to end on a block's "
+                               "edge and the match does not know blocks"),
+        (tc.kv_quantized, "kv_cache_dtype quantisation: not held to a reference under this mask"),
+        (bool(ods and ods.do_sample), "do_sample: the reveal ranks the confidence of the argmax"),
+        (tc.tp_degree * tc.ep_degree * tc.cp_degree * tc.attention_dp_degree
+         * tc.data_parallel_degree > 1, "tp/ep/cp/dp degree > 1: not built or tested for it"),
+        (tc.sliding_window or tc.attention_chunk_size,
+         "sliding_window / attention_chunk_size: one mask rule at a time"),
+        # the chunk program's q ladder starts at 8: a step under that width
+        # collides with no chunk program, and chunks of whole blocks keep a
+        # block's K and V in one pass
+        (block_length < 2 or block_length >= 8 or block_length & (block_length - 1),
+         f"block_length {block_length}: a power of two under 8, the first rung of the chunk "
+         "program's q ladder"),
+        (chunk % max(block_length, 1), f"a prefill chunk ({chunk}) that is no whole number of blocks"),
+        (not 1 <= denoise_steps <= block_length, f"denoise_steps {denoise_steps} outside 1..block_length"),
+        (not 0 <= mask_token_id < vocab_size, f"mask_token_id {mask_token_id} outside the vocabulary"),
+    )
+    for flag, why in refusals:
+        if flag:
+            raise BlockStepServingError(f"a block-step model cannot be served with {why}")
+
+
 class InferenceConfig:
     """TpuConfig + HF model attributes (reference config.py:716-909).
 

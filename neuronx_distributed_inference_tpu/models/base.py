@@ -81,6 +81,27 @@ class LayerGroupSpec:
 
 
 @dataclass(frozen=True)
+class BlockStepSpec:
+    """What a builder says of a model whose decode step fills a BLOCK of
+    positions (generation by diffusion over blocks; models/sdar.py). Position
+    ``i`` sees ``j`` iff ``j // block_length <= i // block_length``: causal
+    between blocks, both ways inside one, in the prompt too. A block's
+    unknown positions hold ``mask_token_id``; a denoise pass predicts a token
+    and a confidence AT each of them and reveals the ``per_pass`` most
+    confident; when none is left a commit pass runs the block once more and
+    its K and V stay (runtime/block_step.py has the session's side)."""
+
+    block_length: int
+    denoise_steps: int
+    mask_token_id: int
+
+    @property
+    def per_pass(self) -> int:
+        """Positions a denoise pass reveals (all that is left, in a block's last)."""
+        return -(-self.block_length // self.denoise_steps)
+
+
+@dataclass(frozen=True)
 class ModelSpec:
     """Static model hyperparams (global, post-GQA-transform head counts)."""
 
@@ -133,6 +154,14 @@ class ModelSpec:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # a decode step that fills a block of positions (BlockStepSpec); None =
+    # one position after another
+    block_step: Optional[BlockStepSpec] = None
+
+
+#: the name, in ``StepOutput.aux``, of the experts a pass's expert layers
+#: chose: int (B, S, L_moe, k)
+EXPERT_CHOICES = "experts"
 
 
 class LayerStack:
@@ -230,6 +259,11 @@ class StepOutput:
     # spec.output_choices: name -> int (B, S, ...) choices of the pass (an
     # expert layer: (B, S, L_moe, k)); None = no leaf, the program is the same
     aux: Optional[dict] = None
+    # a block step (spec.block_step, K = block_length): per position the
+    # softmax probability of ``tokens`` (float32), and the ids of the row's
+    # NEXT pass, ``where(revealed, tokens, input_ids)`` (block_reveal)
+    confidence: Optional[jax.Array] = None  # (B, K) float32
+    next_ids: Optional[jax.Array] = None  # (B, K) int32
 
 
 #: sentinel emitted in place of a sampled/argmax token when the row's logits
@@ -472,6 +506,10 @@ def paged_attend(
             k_arr, v_arr = k_cache, v_cache
         k_l = jax.lax.dynamic_index_in_dim(k_arr, layer_idx, axis=0, keepdims=False)
         v_l = jax.lax.dynamic_index_in_dim(v_arr, layer_idx, axis=0, keepdims=False)
+        if spec.block_step is not None:
+            # block-causal is the kernel's own rule (kv <= q position, under
+            # kv_limit) with each query's frontier at its block's end
+            positions = masks.block_frontier(positions, spec.block_step.block_length)
         attn_out = dispatch_paged_flash(
             q, k_l, v_l, block_table, positions, kv_limit,
             scale=aspec.softmax_scale,
@@ -768,13 +806,29 @@ def build_mask(
         return inputs.mask_override
     n_active = inputs.input_ids.shape[1]
     if phase == PHASE_CONTEXT_ENCODING:
+        if spec.block_step is not None:
+            from neuronx_distributed_inference_tpu.config import BlockStepServingError
+
+            raise BlockStepServingError(
+                "a block-step model cannot be served with whole-prompt context "
+                "encoding: its prompt runs through the chunk program (block-causal)"
+            )
         if chunk:
             return masks.chunked_mask(inputs.attention_mask, inputs.position_ids, chunk)
         if window:
             return masks.windowed_mask(inputs.attention_mask, inputs.position_ids, window)
         return masks.causal_mask(inputs.attention_mask)
     # token generation: base cache-validity mask, then attention-flavor bounds
-    if n_active > 1:  # speculation: multi-token decode
+    if spec.block_step is not None and n_active == spec.block_step.block_length:
+        # the block step: its positions see each other and everything the
+        # cache-valid mask (up to the block's last position) admits
+        mask = masks.token_gen_mask(inputs.attention_mask, n_active)
+    elif spec.block_step is not None:
+        # a prefill chunk of a block-step model: causal between blocks
+        mask = masks.block_causal_token_gen_mask(
+            inputs.attention_mask, inputs.position_ids, spec.block_step.block_length
+        )
+    elif n_active > 1:  # speculation: multi-token decode
         mask = masks.spec_token_gen_mask(inputs.attention_mask, inputs.position_ids)
     else:
         mask = masks.token_gen_mask(inputs.attention_mask, n_active)
@@ -1117,6 +1171,7 @@ def run_decoder_layers(
             )
     else:
         captured = None
+        choices = []  # per group, the choices its layers' MLPs returned
         if capture_layers is not None:
             # EAGLE3 multi-layer hidden capture rides the scan carry: one
             # (B, S, H) accumulator per tap, where-selected at its layer index
@@ -1148,6 +1203,18 @@ def run_decoder_layers(
                           key_valid=key_valid, window=window, chunk=chunk):
                 h, k_c, v_c, cap = carry
                 layer_params, li = xs
+                chose = []
+                if spec.output_choices:
+                    # an MLP that chooses (modules/moe.moe_layer: an expert
+                    # layer's selection) returns (update, choices) under
+                    # spec.output_choices; the choices ride the scan ys
+                    def g_mlp(p, x, s, inner=g_mlp):
+                        out = inner(p, x, s)
+                        if isinstance(out, tuple):
+                            out, picked = out
+                            chose.append(picked)
+                        return out
+
                 h, k_c, v_c = g_layer(
                     layer_params, h, cos, sin, k_c, v_c, li, mask, slot_ids, positions,
                     spec, phase, g_mlp, key_valid=key_valid, block_inputs=block_inputs,
@@ -1158,16 +1225,20 @@ def run_decoder_layers(
                     cap = jnp.where(hit, h[None].astype(cap.dtype), cap)
                 # per-layer tensor-tap captures ride the scan ys (stacked to
                 # (L, ...) — modules/tensor_taps)
-                return (h, k_c, v_c, cap), tensor_taps.collect_layer_taps(taps_ctx)
+                return (h, k_c, v_c, cap), (
+                    tensor_taps.collect_layer_taps(taps_ctx), chose[0] if chose else None
+                )
 
             # the full cache rides the CARRY (updated in place per layer); only
             # the layer params are scanned xs — no stacked-ys cache rebuild
-            (hidden, k_cache, v_cache, captured), tap_ys = jax.lax.scan(
+            (hidden, k_cache, v_cache, captured), (tap_ys, chose_ys) = jax.lax.scan(
                 scan_body,
                 (hidden, k_cache, v_cache, captured),
                 (group_params, offset + jnp.arange(num_layers, dtype=jnp.int32)),
             )
             tensor_taps.merge_layer_taps(taps_ctx, tap_ys)
+            if chose_ys is not None:
+                choices.append(chose_ys)  # (layers of the group, B, S, k)
             offset += num_layers
     if interleaved:
         new_cache = type(cache)(
@@ -1189,6 +1260,10 @@ def run_decoder_layers(
         C = captured.shape[0]
         cat = jnp.concatenate([captured[i] for i in range(C)], axis=-1)
         return hidden, new_cache, cat
+    if not prestacked and choices:
+        # (L_moe, B, S, k) -> (B, S, L_moe, k), as a LayerStack returns them
+        chose = jnp.transpose(jnp.concatenate(choices, axis=0), (1, 2, 0, 3))
+        return hidden, new_cache, {EXPERT_CHOICES: chose.astype(jnp.int32)}
     return hidden, new_cache
 
 
@@ -1576,6 +1651,30 @@ def mixed_forward(
     return StepOutput(tokens=tokens, logits=out_logits, cache=new_cache)
 
 
+def block_reveal(logits: jax.Array, input_ids: jax.Array, block: BlockStepSpec):
+    """What a block step predicts and reveals, in the graph: (tokens (B, K)
+    int32, confidence (B, K) float32, next ids (B, K) int32). A position's
+    token is the argmax over the vocabulary WITHOUT the mask token (a pass
+    never predicts a mask: the block step is greedy), its confidence that
+    token's softmax probability there. Of the positions that hold the mask
+    token the ``per_pass`` most confident are revealed, ties by position;
+    the row's next pass is fed ``where(revealed, token, id)``. A pass with no
+    mask left (a commit pass) reveals nothing and its next ids are its ids."""
+    z = logits.astype(jnp.float32)
+    z = jnp.where(jnp.arange(z.shape[-1]) == block.mask_token_id, -jnp.inf, z)
+    tokens = jnp.argmax(z, axis=-1).astype(jnp.int32)
+    confidence = 1.0 / jnp.sum(jnp.exp(z - jnp.max(z, axis=-1, keepdims=True)), axis=-1)
+    masked = input_ids == block.mask_token_id
+    score = jnp.where(masked, confidence, -1.0)
+    at = jnp.arange(score.shape[1])
+    # ahead[b, i, j]: position j is revealed before position i
+    ahead = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None]) & (at[None, None, :] < at[None, :, None])
+    )
+    revealed = masked & (jnp.sum(ahead, axis=-1) < block.per_pass)
+    return tokens, confidence, jnp.where(revealed, tokens, input_ids).astype(jnp.int32)
+
+
 def forward(
     params: dict,
     cache: KVCache,
@@ -1594,10 +1693,17 @@ def forward(
     )
     if spec.output_choices and aux is None:
         raise NotImplementedError(
-            "output_choices: this model's layer stack returns no choices "
-            "(models/zaya.py's does)"
+            "output_choices: this model's layers return no choices (an expert "
+            "layer's selection: modules/moe.moe_layer, models/zaya.py)"
         )
-    if spec.on_device_sampling:
+    confidence = next_ids = None
+    if (
+        spec.block_step is not None
+        and phase == PHASE_TOKEN_GENERATION
+        and inputs.input_ids.shape[1] == spec.block_step.block_length
+    ):
+        tokens, confidence, next_ids = block_reveal(logits, inputs.input_ids, spec.block_step)
+    elif spec.on_device_sampling:
         tokens = sample_tokens(
             logits,
             inputs.sampling_params,
@@ -1608,6 +1714,13 @@ def forward(
     else:
         tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     tokens = mark_non_finite_tokens(tokens, logits)
+    if next_ids is not None:
+        # the session fetches next_ids alone: a row with a non-finite
+        # position carries the sentinel there too
+        next_ids = jnp.where(jnp.any(tokens < 0, axis=1, keepdims=True), NON_FINITE_TOKEN, next_ids)
 
     out_logits = logits if spec.output_logits else None
-    return StepOutput(tokens=tokens, logits=out_logits, cache=new_cache, aux=aux)
+    return StepOutput(
+        tokens=tokens, logits=out_logits, cache=new_cache, aux=aux,
+        confidence=confidence, next_ids=next_ids,
+    )

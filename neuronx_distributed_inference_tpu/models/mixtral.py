@@ -195,7 +195,9 @@ class MoEDecoderModelBuilder(DecoderModelBuilder):
         mspec = self.moe_spec()
 
         def moe_mlp_fn(mlp_params, hidden, model_spec):
-            return moe_layer(mlp_params, hidden, mspec)
+            return moe_layer(
+                mlp_params, hidden, mspec, return_choices=model_spec.output_choices
+            )
 
         return moe_mlp_fn
 

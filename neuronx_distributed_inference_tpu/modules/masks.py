@@ -47,6 +47,22 @@ def spec_token_gen_mask(attention_mask: jnp.ndarray, position_ids: jnp.ndarray) 
     return (per_tok & attention_mask.astype(bool)[:, None, :])[:, None, :, :]
 
 
+def block_frontier(position_ids: jnp.ndarray, block: int) -> jnp.ndarray:
+    """The last position a query sees under the block-causal rule (position
+    ``i`` sees ``j`` iff ``j // block <= i // block``): its block's end."""
+    return position_ids // block * block + (block - 1)
+
+
+def block_causal_token_gen_mask(
+    attention_mask: jnp.ndarray, position_ids: jnp.ndarray, block: int
+) -> jnp.ndarray:
+    """Mask of a multi-token pass of a model that attends causally between
+    blocks of ``block`` positions and both ways inside one:
+    :func:`spec_token_gen_mask` with each token's frontier at its block's
+    end, clipped by the cache-valid mask. Returns (B, 1, K, S_cache)."""
+    return spec_token_gen_mask(attention_mask, block_frontier(position_ids, block))
+
+
 def windowed_mask(attention_mask: jnp.ndarray, position_ids: jnp.ndarray, window: int) -> jnp.ndarray:
     """Sliding-window causal mask for prefill (reference model_base.py:247-258).
 

@@ -431,8 +431,12 @@ def moe_layer(
     spec: MoESpec,
     shared_mlp_fn=None,
     router=linear_router,
+    return_choices: bool = False,
 ) -> jax.Array:
     """Full MoE block (reference initialize_moe_module product, moe_v2.py:23).
+    With ``return_choices`` (a builder passes ``ModelSpec.output_choices``):
+    (the block's output, the experts each position selected, int32
+    (B, S, top_k), most affine first).
 
     ``router(params, x (T, H), spec) -> (affinities (T, E) float32, selected
     (T, E) bool)`` is the builder's: the linear router unless a model brings
@@ -523,7 +527,12 @@ def moe_layer(
         out = expert_mlps_dense(expert_params, x, affinities, spec, selected)
     if shared_mlp_fn is not None:
         out = out + shared_mlp_fn(params["shared_experts"], x)
-    return out.reshape(B, S, H).astype(hidden.dtype)
+    out = out.reshape(B, S, H).astype(hidden.dtype)
+    if not return_choices:
+        return out
+    # the selection itself, not affinity nonzero-ness (router_top_k)
+    picked = jax.lax.top_k(jnp.where(selected, 1.0 + affinities, 0.0), spec.top_k)[1]
+    return out, picked.reshape(B, S, spec.top_k).astype(jnp.int32)
 
 
 def shared_expert_shapes(L: int, H: int, I: int, fused: bool) -> dict:

@@ -18,6 +18,7 @@ Lifecycle mapping (SURVEY §3.1-3.3):
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from dataclasses import dataclass
 from typing import List, Optional
@@ -158,9 +159,16 @@ class TpuModelForCausalLM:
             tc.tkg_batch_size,
             self.mesh,
             mlp_fn,
+            # a block-step model's decode step is its block wide
+            n_active_tokens=self.spec.block_step.block_length if self.spec.block_step else 1,
             **block_kwargs,
         )
         self.runners = [self.context_encoding_model, self.token_generation_model]
+        if self.spec.block_step is not None:
+            # which programs a block-step session dispatches, and every way
+            # it feeds them, is this application's to say: only such an
+            # application HAS the attribute (a caller asks with hasattr)
+            self.warm_serving = self._warm_block_serving
         # ragged mixed-step program family (serving_ragged): ONE dispatch per
         # serving step covers prefill chunks AND decode rows; its bucket axis
         # is the TOTAL packed query-token count, not a per-phase shape
@@ -551,6 +559,29 @@ class TpuModelForCausalLM:
             for runner in runners:
                 runner.seal()
 
+    def _warm_block_serving(self, shapes):
+        """``warm_serving(shapes)`` of a block-step application (bound in
+        ``__init__``): compile every program a :class:`ServingSession`
+        dispatches for ``shapes`` = [(q length, kv bucket), ...]. ``(1,
+        bucket)`` is the block step at that kv bucket, run once on ids from
+        the host (a block's first pass) and once on ids still on the device
+        (the pass before's ``next_ids``, chained): a denoise and a commit
+        pass are one program. ``(q, bucket)`` with q > 1 is the chunk
+        program. Every write goes to the garbage block; nothing is waited
+        for (a program compiles as it is dispatched)."""
+        tkg = self.token_generation_model
+        for q, bucket in shapes:
+            inputs = tkg.example_inputs(bucket, q_len=q if q > 1 else None)
+            out = tkg(self.params, self.kv_cache, inputs, None)
+            if q == 1:
+                chained = jnp.where(
+                    jnp.ones(inputs.input_ids.shape, bool), out.next_ids, inputs.input_ids
+                )
+                out = tkg(
+                    self.params, out.cache, dataclasses.replace(inputs, input_ids=chained), None
+                )
+            self.kv_cache = out.cache
+
     def capture_forward(
         self,
         input_ids: np.ndarray,
@@ -830,6 +861,26 @@ class TpuModelForCausalLM:
             self.context_encoding_model if phase == "cte"
             else self.token_generation_model
         )
+        block = self.spec.block_step
+        if (
+            block is not None and phase == "tkg" and S == block.block_length
+            and block_table is not None
+        ):
+            # a block wide on the paged cache is the SERVED block step, whose
+            # write slots derive in-graph from the block table; a row that
+            # sits out (seq id -1) writes to the garbage block
+            positions = np.where(seq_ids[:, None] >= 0, position_ids, 0)
+            block_table = np.where(seq_ids[:, None] >= 0, np.asarray(block_table), 0)
+            if slot_mapping is not None:
+                bs = self.config.tpu_config.pa_block_size
+                derived = np.take_along_axis(block_table, positions // bs, axis=1) * bs + positions % bs
+                given = np.asarray(slot_mapping)
+                if not np.array_equal(np.where(given >= 0, given, derived), derived):
+                    raise ValueError(
+                        "a block step writes its block's positions where the block table "
+                        "puts them: the slot mapping given names other slots"
+                    )
+                slot_mapping = None
         R = runner.chunk_rows
         if runner.is_paged_chunk(slot_mapping, block_table) and B > R:
             per_row = [input_ids, position_ids, seq_ids, attention_mask, sampling_params,

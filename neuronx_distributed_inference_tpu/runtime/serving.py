@@ -148,14 +148,27 @@ class Request:
     draft_ready: bool = False
     draft_len: int = 0
     accept_ewma: float = 1.0
+    # --- a block-step model (runtime/block_step.py): the prompt tokens the
+    # chunk program carries (its whole blocks; None = all of the prompt), the
+    # block in progress, and per generated token the ordinal within its
+    # block of the pass that revealed it (always recorded; None for a model
+    # that generates one position after another). ``pos`` is the first
+    # position of the block in progress; ``generated`` grows at a commit.
+    prefill_end: Optional[int] = None
+    block: Optional[object] = None
+    revealed_at: Optional[List[int]] = None
 
     @property
     def prompt_len(self) -> int:
         return int(self.input_ids.shape[0])
 
     @property
+    def prefill_target(self) -> int:
+        return self.prompt_len if self.prefill_end is None else self.prefill_end
+
+    @property
     def prefilling(self) -> bool:
-        return not self.finished and self.prefill_pos < self.prompt_len
+        return not self.finished and self.prefill_pos < self.prefill_target
 
     @property
     def last_token(self) -> int:
@@ -208,6 +221,7 @@ class ServingSession:
         self._watchdog_fired = False
         self._prefilled_total = 0  # monotone: prompt tokens written
         self._committed_total = 0  # monotone: tokens committed to requests
+        self._revealed_total = 0  # monotone: block passes fetched (a block-step model)
         self._terminal_total = 0  # monotone: requests reaching a terminal state
         self._last_dispatch_error: Optional[str] = None
         if not tc.is_continuous_batching:
@@ -343,6 +357,13 @@ class ServingSession:
         self.slot_state_kind = getattr(state, "KIND", None)
         self.slot_state_bytes = state.nbytes if self.slot_state else 0
         self.expert_layers = app.builder.expert_layers()
+        # a model whose builder declares a block step generates block by
+        # block: its rows' blocks, plans and commits (runtime/block_step.py)
+        self.blocks = None
+        if app.spec.block_step is not None:
+            from neuronx_distributed_inference_tpu.runtime.block_step import BlockRows
+
+            self.blocks = BlockRows(app.spec.block_step, app._pos_limit())
         self.tel.pool_gauges(0, self.kv_pool_bytes, self.kv_free_bytes)
 
     @property
@@ -403,6 +424,7 @@ class ServingSession:
             eos_token_id=eos_token_id,
             deadline_s=deadline_s if deadline_s is not None else self.deadline_s,
             t_submit=self._clock(),
+            revealed_at=None if self.blocks is None else [],
         )
 
     def _front_door(self, req: Request) -> Optional[AdmissionResult]:
@@ -566,6 +588,8 @@ class ServingSession:
         req.preempted = False
         req.prefill_pos = 0
         req.pos = 0
+        if self.blocks is not None:
+            req.prefill_end, req.block = self.blocks.prefill_end(req.prompt_len), None
         if self.prefix_caching:
             req.prefill_pos = self.allocator.match_prefix(slot, req.input_ids)
             req.pos = req.prefill_pos
@@ -771,6 +795,7 @@ class ServingSession:
             self._committed_total,
             self._terminal_total,
             self._prefilled_total,
+            self._revealed_total,
         )
 
     def _watchdog_tick(self, progressed: bool):
@@ -1109,7 +1134,7 @@ class ServingSession:
         (admission-time prefill)."""
         rows = []
         for req in reqs:
-            n = min(chunk_size, req.prompt_len - req.prefill_pos)
+            n = min(chunk_size, req.prefill_target - req.prefill_pos)
             if n <= 0:
                 continue
             try:
@@ -1211,10 +1236,18 @@ class ServingSession:
                 for group, tokens in fetched:
                     for row, (req, n) in enumerate(group):
                         req.prefill_pos += n
-                        if req.prefill_pos >= req.prompt_len:
+                        if req.prefill_pos < req.prefill_target:
+                            continue
+                        if self.blocks is None:
                             # the last prompt token's output IS the first
                             # generated token
                             self._finish_prefill(req, int(tokens[row, n - 1]))
+                        else:
+                            # nothing is generated yet: the first block
+                            # opens here, on what is left of the prompt
+                            req.pos = req.prefill_pos
+                            if int(tokens[row, n - 1]) < 0:
+                                self._quarantine(req)
         return True
 
     def _finish(self, req: Request, reason: Optional[str] = None, scrub: bool = False):
@@ -1345,9 +1378,13 @@ class ServingSession:
             # synchronous path (async_mode=False debugging): dispatch + fetch
             # every step
             if active:
-                out, snap = self._dispatch_decode([(r, r.pos) for r in active])
+                rows = (
+                    [(r, r.pos) for r in active] if self.blocks is None
+                    else self.blocks.plan(active)[0]
+                )
+                out, snap = self._dispatch_decode(rows)
                 if out is not None:
-                    self._consume((out.tokens[:, -1:], snap), results)
+                    self._consume((self._step_ids(out), snap), results)
             return results
 
         # async 1-ahead (reference modules/async_execution.py:190): dispatch
@@ -1366,7 +1403,7 @@ class ServingSession:
         pend_pos = (
             {
                 id(req): p
-                for req, p, _s, e in pend[1]
+                for req, p, _s, e, *_ in pend[1]
                 if e == req.epoch and not req.finished and not req.preempted
             }
             if pend
@@ -1374,17 +1411,23 @@ class ServingSession:
         )
         rows: List = []
         chained_slots: List[int] = []
-        for r in active:
-            if id(r) in pend_pos:
-                rows.append((r, pend_pos[id(r)] + 1))
-                chained_slots.append(r.slot)
-            else:
-                rows.append((r, r.pos))
+        if self.blocks is not None:
+            # which pass of which block, and whose ids are still on the
+            # device, is the blocks' own to say (a block that was preempted
+            # re-opens from the host's state)
+            rows, chained_slots = self.blocks.plan(active)
+        else:
+            for r in active:
+                if id(r) in pend_pos:
+                    rows.append((r, pend_pos[id(r)] + 1))
+                    chained_slots.append(r.slot)
+                else:
+                    rows.append((r, r.pos))
         if rows:
             last_override = (pend[0], chained_slots) if chained_slots else None
             out2, snap2 = self._dispatch_decode(rows, last_override)
             if out2 is not None:
-                self._pending = (out2.tokens[:, -1:], snap2)
+                self._pending = (self._step_ids(out2), snap2)
         if pend is not None:
             self._consume(pend, results)
         return results
@@ -1725,23 +1768,28 @@ class ServingSession:
     def _dispatch_decode(self, rows, last_override=None):
         """Dispatch ONE batched decode pass for ``rows`` = [(req, pos), ...]
         without waiting for its result. ``last_override``: (device tokens
-        (B, 1) from the pending step, chained slot list) — those rows' input
+        (B, K) from the pending step, chained slot list) — those rows' input
         tokens come straight from the device (no host round-trip).
         Returns (StepOutput, snapshot rows) — StepOutput.tokens is an
-        UNFETCHED device array."""
+        UNFETCHED device array. K is 1, or a block-step model's block: its
+        rows carry their block's ids (``pos`` its first position), whichever
+        pass of the block each is in."""
         import jax.numpy as jnp
 
         B = self.num_slots
         tel = self.tel
         tkg = self.app.token_generation_model
+        K = tkg.n_active_tokens
         with tel.span("serving.decode", rows=len(rows)) as decode_span:
             with tel.span("serving.decode.prepare"):
-                last = np.zeros((B, 1), np.int32)
-                pos = np.zeros((B, 1), np.int32)
+                last = np.zeros((B, K), np.int32)
+                pos = np.zeros((B, K), np.int32)
                 seq_ids = np.full((B,), -1, np.int32)
+                offsets = np.arange(K)
                 for r, p in rows:
-                    last[r.slot, 0] = r.last_token
-                    pos[r.slot, 0] = p
+                    if self.blocks is None:
+                        last[r.slot, 0] = r.last_token
+                    pos[r.slot] = p + offsets
                     seq_ids[r.slot] = r.slot
                 block_table = kv_blocks = None
                 if self.block_mode:
@@ -1751,7 +1799,7 @@ class ServingSession:
                     block_table = np.zeros((B, mb), np.int32)
                     for r, p in list(rows):
                         try:
-                            self._alloc(r.slot, p + 1)
+                            self._alloc(r.slot, p + K)
                         except RuntimeError:
                             # pool exhausted mid-decode: preempt this request
                             # so the others keep running (vLLM-style
@@ -1765,7 +1813,7 @@ class ServingSession:
                     if not rows:
                         return None, []
                     if tel.enabled:
-                        live = [-(-(p + 1) // bs) for _, p in rows]
+                        live = [-(-(p + K) // bs) for _, p in rows]
                         kv_blocks = (sum(live), self._kv_blocks_walked(live, mb))
                     # no host slot mapping: decode writes derive their slots
                     # IN-GRAPH from the block table
@@ -1773,7 +1821,12 @@ class ServingSession:
                     # generate_tokengen_slot_mapping)
                 else:
                     width = int(pos.max()) + 1
-                mask = (np.arange(width)[None, :] <= pos).astype(np.int32)
+                mask = (np.arange(width)[None, :] <= pos[:, -1:]).astype(np.int32)
+                block_rows = None
+                if self.blocks is not None:
+                    last = self.blocks.ids(rows, B)
+                    commit = sum(r.block.dispatched == r.block.denoise for r, _ in rows)
+                    block_rows = (len(rows) - commit, commit)
                 last_arr = last
                 if last_override is not None:
                     pend_tokens, chained = last_override
@@ -1788,6 +1841,8 @@ class ServingSession:
                     block_table=block_table,
                 )
             decode_span.note(rows=len(rows), kv_bucket=tkg.last_bucket)
+            if block_rows is not None:
+                decode_span.note(denoise_rows=block_rows[0], commit_rows=block_rows[1])
 
             def dispatch():
                 with tel.span("serving.decode.dispatch"):
@@ -1800,19 +1855,33 @@ class ServingSession:
         tel.step("decode")
         tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
         tel.decode_pass(len(rows), B)
-        self._count_pass("decode", len(rows), len(rows), 1, kv_blocks=kv_blocks)
+        self._count_pass("decode", len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
+                         block_rows=block_rows)
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
-        return out, [(r, p, r.slot, r.epoch) for r, p in rows]
+        snap = [(r, p, r.slot, r.epoch) for r, p in rows]
+        if self.blocks is not None:
+            # a block row's entry also says which pass of which block it was
+            snap = [entry + extra for entry, extra in zip(snap, self.blocks.dispatched(rows))]
+        return out, snap
+
+    def _step_ids(self, out):
+        """What of a dispatched decode step the session chains on and fetches:
+        (B, K) on the device. The last token a row, or a block-step model's
+        ids of the row's next pass."""
+        return out.tokens[:, -1:] if self.blocks is None else out.next_ids
 
     def _count_pass(self, program: str, rows: int, tokens: int, dispatches: int,
-                    resets: int = 0, kv_blocks=None) -> None:
+                    resets: int = 0, kv_blocks=None, block_rows=None) -> None:
         """What a pass of the split serving step ("decode" or "chunk") did
         to per-slot state and routed experts, from what the step already
         knows: ``rows`` live rows over ``tokens`` real token positions in
         ``dispatches`` dispatches, ``resets`` of the rows from position 0;
-        ``kv_blocks``: a decode pass's (live, walked) pool blocks."""
+        ``kv_blocks``: a decode pass's (live, walked) pool blocks;
+        ``block_rows``: a block step's (denoise, commit) rows."""
         if kv_blocks is not None:
             self.tel.decode_kv_blocks(*kv_blocks)
+        if block_rows is not None:
+            self.tel.block_pass(*block_rows, positions=tokens)
         if self.slot_state_kind == "ssm":
             self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets)
         elif self.slot_state_kind == "latent_carry":
@@ -1828,13 +1897,18 @@ class ServingSession:
         discarded; rows carrying the non-finite sentinel are quarantined."""
         tel = self.tel
         with tel.span("serving.fetch_wait") as wait:
-            tokens = np.asarray(pend[0])[:, -1]  # the only device sync per step
+            tokens = np.asarray(pend[0])  # the only device sync per step
         self._step_fetch_wait_s += wait.dur_s
+        if self.blocks is None:
+            tokens = tokens[:, -1]
         with tel.span("serving.commit"):
             if self.faults is not None:
                 tokens = self.faults.corrupt_tokens(self, tokens)
-            for req, p, slot, epoch in pend[1]:
+            for req, p, slot, epoch, *block in pend[1]:
                 if req.finished or req.preempted or req.epoch != epoch:
+                    continue
+                if block:
+                    self._consume_block(req, p, block, tokens[slot], results)
                     continue
                 tok = int(tokens[slot])
                 if tok < 0:
@@ -1846,6 +1920,36 @@ class ServingSession:
                 results[req.req_id] = tok
                 if self._is_done(req, tok):
                     self._finish(req)
+
+    def _consume_block(self, req: Request, start: int, block, next_ids, results):
+        """One fetched pass of a block-step row: a denoise pass reveals, the
+        commit appends the block's new tokens to ``generated`` (in position
+        order, cut at the budget and after an EOS) and moves ``pos`` to the
+        next block."""
+        if int(next_ids.min()) < 0:
+            self._quarantine(req)
+            return
+        self._revealed_total += 1
+        new = self.blocks.consume(req, *block, next_ids)
+        if new is None:
+            return
+        if not req.generated:
+            # the first block's first token is the request's first token
+            self.tel.request_first_token(req.req_id)
+            self._committed_total += 1
+            self._commit_tokens(req, len(new) - 1)
+        else:
+            self._commit_tokens(req, len(new))
+        req.generated.extend(new)
+        self.tel.block_commit(len(new))
+        req.pos = start + self.blocks.length
+        results[req.req_id] = new[-1]
+        if (
+            (req.eos_token_id is not None and new[-1] == req.eos_token_id)
+            or len(req.generated) >= req.max_new_tokens
+            or req.pos + self.blocks.length > self.blocks.pos_limit
+        ):
+            self._finish(req)
 
     def run_to_completion(self, decode_chunk_size: int = 16) -> Dict[str, List[int]]:
         """Drain the session. When every active request is decoding (no
@@ -1878,6 +1982,9 @@ class ServingSession:
                 # slots MID-stream (slot = pos mod W); generate()'s surplus
                 # is safe only because it is terminal — stay per-step
                 or ring_cache
+                # a block step reveals and commits pass by pass: no chained
+                # multi-step program carries its schedule
+                or self.blocks is not None
                 or decode_chunk_size <= 1
                 or not self.decoding
             ):

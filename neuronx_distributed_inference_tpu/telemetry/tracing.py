@@ -385,6 +385,23 @@ class TelemetrySession:
             "top-1 of 16 hits 15.3 on average); not a count of the distinct "
             "experts with a live row, which only the device knows",
             labels=("program",))
+        self._block_row_passes = r.counter(
+            "nxdi_block_row_passes_total",
+            "a block-step model (runtime/block_step.py): live rows x passes "
+            "of the block step, kind=denoise (predicts at the masked "
+            "positions and reveals the most confident) or kind=commit (runs "
+            "the finished block once more; its K and V stay)",
+            labels=("kind",))
+        self._block_positions = r.counter(
+            "nxdi_block_positions_total",
+            "positions the block step ran: live rows x block length, a pass")
+        self._block_blocks = r.counter(
+            "nxdi_block_blocks_committed_total", "blocks a commit pass finished")
+        self._block_tokens = r.counter(
+            "nxdi_block_tokens_committed_total",
+            "tokens the commits appended to their requests' generated tokens "
+            "(a block's positions the prompt did not fill, cut at the budget "
+            "and after an EOS)")
         self._decode_kv_blocks = r.counter(
             "nxdi_decode_kv_blocks_total",
             "pool blocks of the decoding rows per decode dispatch of the "
@@ -1103,6 +1120,22 @@ class TelemetrySession:
             return
         self._moe_rows.child((program,)).inc(rows_routed)
         self._moe_experts.child((program,)).inc(experts)
+
+    def block_pass(self, denoise_rows: int, commit_rows: int, positions: int) -> None:
+        """One dispatch of a block-step model's decode step: its live rows
+        by the kind of pass each was in, and the positions it ran."""
+        if not self.enabled:
+            return
+        self._block_row_passes.child(("denoise",)).inc(denoise_rows)
+        self._block_row_passes.child(("commit",)).inc(commit_rows)
+        self._block_positions.inc(positions)
+
+    def block_commit(self, tokens: int) -> None:
+        """A fetched commit pass of one row: one block, ``tokens`` appended."""
+        if not self.enabled:
+            return
+        self._block_blocks.inc()
+        self._block_tokens.inc(tokens)
 
     def decode_kv_blocks(self, live: int, walked: int) -> None:
         """One decode dispatch over a paged cache: the pool blocks its rows'

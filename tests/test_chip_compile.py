@@ -501,3 +501,62 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# sdar-30b-a3b: a decode step that fills a block of 4 positions, top-8 of 128 experts
+# ---------------------------------------------------------------------------
+
+
+def _abstract_paged_app(mesh, config):
+    """A benchmark configuration with a plain paged cache
+    (benchmark/configs/<config>.json: the published widths, its slots and
+    pool) over a described chip, params and pool as ShapeDtypeStructs."""
+    import json
+    import os
+
+    from benchmark.harness import system
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import init_block_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
+        file = json.load(f)
+    # the auto gates ask jax.default_backend(), which is the CPU here
+    file["tpu_config"].update(attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True)
+    app = system.build_app(file, mesh.devices.ravel().tolist(), 0)
+    tc, b = app.config.tpu_config, app.builder
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, P()))
+
+    def cache():
+        return init_block_cache(app.paged_layers, tc.pa_num_blocks, tc.pa_block_size,
+                                b.gqa.kv_heads, b.head_dim, dtype=jnp.bfloat16)
+
+    params = jax.tree.map(place, jax.eval_shape(b.random_params))
+    return app, params, jax.tree.map(place, jax.eval_shape(cache))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, program):
+    """sdar-30b-a3b at the benchmark's widths (benchmark/configs/sdar-30b-a3b.json:
+    128 experts, the whole vocabulary, 6 of 48 layers, 48 slots, 2048 blocks),
+    both step programs compiled for a described v5e at kv bucket 2048: the
+    block step is (48, 4) and holds ``paged_tkg_decode_attention`` (K = 4: 32
+    query rows a KV head), the 8-row chunk program ``paged_flash_attention``
+    (under the block frontier); neither copies the pool, and each plans
+    under 14.75 GiB of the chip's 15.75."""
+    app, params, cache = _abstract_paged_app(chip_mesh(1), "sdar-30b-a3b")
+    assert cache.k.shape == (6, 2049, 4, 32, 128) and app.spec.block_step.block_length == 4
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(2048, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((48, 4) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
+    assert kernel in text and _custom_calls(compiled) >= 1
+    assert _pool_copies(compiled, cache.k.shape)[0] == 0
+    mem = compiled.memory_analysis()
+    print(f"\nsdar-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
