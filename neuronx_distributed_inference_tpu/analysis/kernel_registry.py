@@ -221,29 +221,33 @@ def _ssm_update_case(rows):
     return build
 
 
-def _paged_flash_case(Sq, MB, bs, cache_dtype):
+def _paged_flash_case(B, Sq, MB, bs, cache_dtype, m=_1B):
     def build():
         import jax.numpy as jnp
 
         from neuronx_distributed_inference_tpu.ops import paged_flash_attention as pf
 
-        m = _1B
         quant = jnp.dtype(cache_dtype) == jnp.int8
-        q = _sds((1, Sq, m["Hq"], m["D"]), jnp.bfloat16)
-        cache = _sds((65, m["Hkv"], bs, m["D"]), jnp.dtype(cache_dtype))
-        bt = _sds((1, MB), jnp.int32)
-        pos = _sds((1, Sq), jnp.int32)
-        lim = _sds((1,), jnp.int32)
+        q = _sds((B, Sq, m["Hq"], m["D"]), jnp.bfloat16)
+        cache = _sds((m["L"], 65, m["Hkv"], bs, m["D"]), jnp.dtype(cache_dtype))
+        li = _sds((), jnp.int32)
+        bt = _sds((B, MB), jnp.int32)
+        pos = _sds((B, Sq), jnp.int32)
+        lim = _sds((B,), jnp.int32)
         raw = _unjit(pf.paged_flash_attention)
         kw = dict(scale=m["D"] ** -0.5, n_rep=m["Hq"] // m["Hkv"])
         if quant:
             scale = _sds((m["Hkv"],), jnp.float32)
 
-            def fn(q, k, v, bt, pos, lim, ks, vs):
-                return raw(q, k, v, bt, pos, lim, k_scale=ks, v_scale=vs, **kw)
+            def fn(q, k, v, li, bt, pos, lim, ks, vs):
+                return raw(q, k, v, bt, pos, lim, layer_idx=li, k_scale=ks, v_scale=vs, **kw)
 
-            return fn, (q, cache, cache, bt, pos, lim, scale, scale)
-        return functools.partial(raw, **kw), (q, cache, cache, bt, pos, lim)
+            return fn, (q, cache, cache, li, bt, pos, lim, scale, scale)
+
+        def fn(q, k, v, li, bt, pos, lim):
+            return raw(q, k, v, bt, pos, lim, layer_idx=li, **kw)
+
+        return fn, (q, cache, cache, li, bt, pos, lim)
 
     return build
 
@@ -412,17 +416,44 @@ REGISTRY: Tuple[KernelSpec, ...] = (
     ),
     KernelSpec(
         name="paged_flash_attention",
-        site=("paged_flash_attention.py", "paged_flash_attention"),
+        site=("decode_attention.py", "_common_call"),
         entry="paged_flash_attention",
         fallback=f"{_ATTN}:attention_decode",
         parity_test="tests/test_chunked_prefill.py",
-        tile_params=("tq",),
-        sweep=(("tq", (64, 128, 256, 512)),),
+        # as the paged decode kernel: the shape class is the pool block's
+        # shape a chip ("blk<Hkv>x<bs>x<D>"); ``pages`` is the group of
+        # blocks a pass of the kernel's loop copies and attends
+        # (ops/paged_flash_attention.py::blocks_per_group), ``tq`` the q tile
+        tile_params=("tq", "pages"),
+        sweep=(("tq", (64, 128, 256, 512)), ("pages", (8, 16, 32))),
+        # at head_dim 128 a pass fills ONE of the two slots of K's and V's
+        # VMEM scratch (its first two entries; the rest is the statistics of
+        # every (KV head, part)) by hand; at head_dim 64 the blocks are windows
+        step_copy_bytes=lambda inst: (
+            sum(b for _, _, b in inst.scratch[:2]) // 2 if len(inst.grid) == 1 else 0
+        ),
         cases=(
+            # head_dim 64 (Llama-3.2-1B, granite): one block a grid step
             KernelCase(
-                "sq512", "bfloat16", _paged_flash_case(512, 16, 128, "bfloat16")
+                "blk8x128x64", "bfloat16", _paged_flash_case(1, 512, 16, 128, "bfloat16")
             ),
-            KernelCase("sq512", "int8", _paged_flash_case(512, 16, 128, "int8")),
+            KernelCase("blk8x128x64", "int8", _paged_flash_case(1, 512, 16, 128, "int8")),
+            # the benchmark's chunk programs (8 rows of 128, blocks of 32):
+            # Qwen3-1.7B at kv 8192, 2 KV heads a chip (ZAYA1-8B; Qwen3-14B
+            # at tp = 4 alike) and SDAR's 8 q heads a KV head, taken in parts
+            KernelCase(
+                "blk8x32x128", "bfloat16",
+                _paged_flash_case(8, 128, 256, 32, "bfloat16", _QWEN3_1P7B),
+            ),
+            KernelCase(
+                "blk8x32x128", "int8", _paged_flash_case(8, 128, 256, 32, "int8", _QWEN3_1P7B)
+            ),
+            KernelCase(
+                "blk2x32x128", "bfloat16", _paged_flash_case(8, 128, 64, 32, "bfloat16", _KV2)
+            ),
+            KernelCase(
+                "blk4x32x128", "bfloat16", _paged_flash_case(8, 128, 64, 32, "bfloat16", _BLOCK4)
+            ),
         ),
     ),
     KernelSpec(
@@ -493,7 +524,11 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
         "masked": {"bq": 128, "bkv": 128},
     },
     "tkg_decode_attention": {"*": {"bs": 512}},
-    "paged_flash_attention": {"*": {"tq": 128}},
+    # the q tile, and pages_per_step's rule under this kernel's name
+    "paged_flash_attention": {
+        "blk8x128x64": {"tq": 128, "pages": 1},
+        "*": {"tq": 128, "pages": 16},
+    },
     # what pages_per_step's rule gives at each registered block shape (at
     # most 1 MiB a stream and 512 tokens a group; 1 off the 128 lanes)
     "paged_tkg_decode_attention": {

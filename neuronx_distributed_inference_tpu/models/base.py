@@ -493,10 +493,11 @@ def paged_attend(
         and _use_paged_flash(aspec, Sq)
     ):
         # chunked/prefix prefill rides the paged flash kernel: blocks are
-        # DMA'd straight from the cache via the block table — no gather
-        # materialization (reference flash_pa_with_schedule.py:157). A
-        # quantized cache hands the kernel this layer's code blocks plus
-        # per-head dequant factors — the prior-KV path reads narrow tiles
+        # DMA'd straight from the STACKED cache via the layer index and the
+        # block table — no gather materialization, no layer's slice
+        # (reference flash_pa_with_schedule.py:157). A quantized cache hands
+        # the kernel the code blocks plus this layer's per-head dequant
+        # factors — the prior-KV path reads narrow tiles
         ks = vs = None
         if isinstance(k_cache, QuantizedKV):
             ks = layer_dequant_factors(k_cache, layer_idx)
@@ -504,14 +505,12 @@ def paged_attend(
             k_arr, v_arr = k_cache.data, v_cache.data
         else:
             k_arr, v_arr = k_cache, v_cache
-        k_l = jax.lax.dynamic_index_in_dim(k_arr, layer_idx, axis=0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(v_arr, layer_idx, axis=0, keepdims=False)
         if spec.block_step is not None:
             # block-causal is the kernel's own rule (kv <= q position, under
             # kv_limit) with each query's frontier at its block's end
             positions = masks.block_frontier(positions, spec.block_step.block_length)
         attn_out = dispatch_paged_flash(
-            q, k_l, v_l, block_table, positions, kv_limit,
+            q, k_arr, v_arr, layer_idx, block_table, positions, kv_limit,
             scale=aspec.softmax_scale,
             n_rep=aspec.num_heads // aspec.num_kv_heads,
             k_scale=ks, v_scale=vs,

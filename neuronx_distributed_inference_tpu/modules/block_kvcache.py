@@ -213,26 +213,38 @@ def update_block_cache_at_layer(
     custom calls, which take only the default row-major layout. With the
     head in the window (``(H, D)``: token-major ``{4,2,3,1,0}``) whatever a
     kernel reads is relaid first: the WHOLE stacked pool once per layer
-    where the kernel takes the stacked cache (paged TKG decode), one layer's
-    slice where it takes a slice (paged flash, ragged), and the pool twice
-    more at the program's entry and exit either way. With the head an
-    INDEXED dim (window ``(D,)``, already minor-most in the head-major
-    layout) the carry stays row-major and nothing is relaid, but the scatter
-    has H times the index rows, and on a v5e a row costs ~60 ns whatever its
-    width. So the form is selected here and nowhere else, on the static ``S``
-    of ``slot_mapping`` and on the heads ONE device holds (``H`` over the
-    ambient mesh's head shards):
+    where the kernel takes the stacked cache (paged TKG decode, and paged
+    flash at head_dim 128, which copy their blocks by hand out of it), one
+    layer's slice where it takes a slice (ragged; paged flash at head_dim
+    64), and the pool twice more at the program's entry and exit either way.
+    With the head an INDEXED dim (window ``(D,)``, already minor-most in the
+    head-major layout) the carry stays row-major and nothing is relaid, but
+    the scatter has H times the index rows, and on a v5e a row costs ~70 ns
+    whatever its width. So the form is selected here and nowhere else, on
+    the static ``S`` of ``slot_mapping``, on ``D`` and on the heads ONE
+    device holds (``H`` over the ambient mesh's head shards):
 
     * ``S <= TKG_MAX_Q_LEN`` (16: decode and speculation widths, the widths
       the stacked-cache decode kernel serves) — per-head form. The served
       Qwen3-1.7B decode step (48 rows, 28 layers, pool 2 x 1.94 GB) compiles
       to 0 pool-shaped copies and 0 GB of temporaries against 6 and 3.89 GB,
       and runs in 56 ms against 406 ms (v5e, kv bucket 1024, PERF.md PR 24).
-    * ``S > TKG_MAX_Q_LEN`` (prefill chunks of 32-128 tokens) and at least
-      ``WINDOW_MIN_HEADS`` heads a device — window form. There only a
-      layer's slice and the entry/exit pair are relaid (~20 ms a pass), less
-      than 8x the rows cost: the chunk program ran in 153 / 187 / 303 ms at
-      q 32 / 64 / 128 against 170 / 246 / 445 ms per-head (same chip run).
+    * ``D`` a multiple of the 128 lanes, at every ``S`` — per-head form:
+      the prefill chunk's kernel reads the stacked pool too (PR 41). The
+      chunk program is 8 rows wide (``ops/kernel_mode.CHUNK_ROWS``), so a
+      chunk of 128 tokens is 8 x 128 x 8 index rows a stream a layer: on the
+      1.7B the write reads 32 ms a dispatch where the window form read 4,
+      but the window form's four entry/exit pool copies (23 ms) and the
+      layer's slice relaid twice a layer are gone, and the program plans
+      7.69 GiB where it planned 9.33 (v5e: a 28-layer scan of write + kernel
+      read 46.1 ms per-head against 52.6 in the window form, PERF.md PR 41).
+      When the program was as wide as the slot count (48 rows) the window
+      form won: 153 / 187 / 303 ms at q 32 / 64 / 128 against 170 / 246 /
+      445 ms per-head (PERF.md PR 24).
+    * ``S > TKG_MAX_Q_LEN`` at a ``D`` off the lanes (64: Llama-3.2-1B,
+      granite), at least ``WINDOW_MIN_HEADS`` heads a device — window form:
+      there the chip's own layout of the pool is not row-major in either
+      form, and the chunk's kernel takes a layer's slice.
     * Fewer heads a device than the tile has sublanes (Qwen3-14B at tp = 4:
       2 of 8) — per-head form at every ``S``. Under a window of 2 or 4 heads
       the compiler re-lays the whole pool FOUR times around every layer's
@@ -243,8 +255,8 @@ def update_block_cache_at_layer(
       heads.
     * The ragged mixed step says ``packed``: its ``S`` is the packed token
       axis of every row and its kernel takes a layer's slice, so it skips
-      the test on ``S`` (one form at every packed width keeps its bucket
-      programs one structure: analysis/graph_audit GRAPH205).
+      the tests on ``S`` and ``D`` (one form at every packed width keeps its
+      bucket programs one structure: analysis/graph_audit GRAPH205).
     * Head axis sharded (``block_cache_spec`` over a tp/ep/cp > 1 mesh) —
       the same selection: the paged kernels run there too, once per head
       shard (``parallel/sharding.shard_over_heads``), and demand the same
@@ -269,7 +281,7 @@ def update_block_cache_at_layer(
     offs = jnp.where(slots >= 0, slots % bs, 0)
     per_head = not _batch_sharded() and (
         H // head_shard_degree() < WINDOW_MIN_HEADS
-        or (S <= TKG_MAX_Q_LEN and not packed)
+        or ((S <= TKG_MAX_Q_LEN or D % 128 == 0) and not packed)
     )
 
     def scatter_per_head(data, rows, layer_idx, blocks, offs):

@@ -204,8 +204,11 @@ def _mask_tiles(mask: jax.Array, nkv: int, bs: int):
 
 def _common_call(
     kernel, grid, in_specs, out_specs, operands, out_shape, scratch, interpret, name,
-    semantics=("parallel", "arbitrary"),
+    semantics=("parallel", "arbitrary"), vmem_limit_bytes=None,
 ):
+    """The launch of every paged and decode attention kernel (this module's
+    and ``ops/paged_flash_attention.py``'s): ``operands`` is (scalar
+    prefetch, tensors)."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(operands[0]),
         grid=grid,
@@ -217,7 +220,9 @@ def _common_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes
+        ),
         interpret=interpret,
         name=name,
     )(*operands[0], *operands[1])
@@ -315,12 +320,17 @@ GROUP_BYTES = 1024 * 1024
 GROUP_TOKENS = 512
 
 
-def pages_per_step(n_kv: int, bs: int, head_dim: int, cache_dtype, max_blocks: int) -> int:
+def pages_per_step(
+    n_kv: int, bs: int, head_dim: int, cache_dtype, max_blocks: int,
+    kernel: str = "paged_tkg_decode_attention",
+) -> int:
     """Pool blocks the paged decode kernel fetches and attends per step (its
     ``P``): a power of two that follows the block's shape through the tuning
     table, never more than the block table is wide (a table no multiple of
     it wide is padded with dead entries). Host code calls this too
-    (``ServingSession`` counts the blocks the kernel walks)."""
+    (``ServingSession`` counts the blocks the kernel walks). The paged prefill
+    kernel walks a row by the same rule under its own name in the table
+    (``kernel``)."""
     dt = jnp.dtype(cache_dtype)
     if head_dim % 128:
         return 1  # blocks come through a BlockSpec, one a step: _paged_by_block
@@ -328,23 +338,22 @@ def pages_per_step(n_kv: int, bs: int, head_dim: int, cache_dtype, max_blocks: i
     p = 1
     while 2 * p * block_bytes <= GROUP_BYTES and 2 * p * bs <= GROUP_TOKENS:
         p *= 2
-    p = tile_default(
-        "paged_tkg_decode_attention", f"blk{n_kv}x{bs}x{head_dim}", dt.name, "pages", p
-    )
+    p = tile_default(kernel, f"blk{n_kv}x{bs}x{head_dim}", dt.name, "pages", p)
     p = max(1, min(p, max_blocks))
     return 1 << (p.bit_length() - 1)  # the kernel takes a group's live count apart by bits
 
 
 def kv_blocks_walked(
-    live_blocks, max_blocks: int, *, n_kv: int, bs: int, head_dim: int, cache_dtype
+    live_blocks, max_blocks: int, *, n_kv: int, bs: int, head_dim: int, cache_dtype,
+    kernel: str = "paged_tkg_decode_attention",
 ) -> int:
-    """Block-table entries the paged decode kernel's kv axis attends for rows
-    whose contexts hold ``live_blocks`` (one count a row) blocks of a table
+    """Block-table entries a paged kernel's kv axis attends for rows whose
+    contexts hold ``live_blocks`` (one count a row) blocks of a table
     ``max_blocks`` wide: whole groups up to a row's last live block; every
     entry of the table where blocks come one a grid step (``_paged_by_block``)."""
     if head_dim % 128:
         return max_blocks * len(live_blocks)
-    P = pages_per_step(n_kv, bs, head_dim, cache_dtype, max_blocks)
+    P = pages_per_step(n_kv, bs, head_dim, cache_dtype, max_blocks, kernel)
     return sum(-(-n // P) * P for n in live_blocks)
 
 
@@ -389,27 +398,16 @@ def _dot_tile(x, tile, contract_tile_dim: int):
     return y[:R] + y[R : 2 * R] + y[2 * R :]
 
 
-def _paged_group_kernel(
-    li_ref, bt_ref, lo_ref, end_ref, live_from_ref, *rest,
-    scale, n_kv, P, has_sink, q_dtype,
-):
-    """One ROW per grid step; inside, a loop over the row's live block groups
-    only. K and V stay in HBM: each group's ``P`` blocks are copied into one
-    of two VMEM slots while the group before it (of this row or of the last
-    live row) is attended, so a row with no live block starts no copy and
-    runs no arithmetic."""
-    if has_sink:
-        q_ref, mask_ref, sink_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
-    else:
-        q_ref, mask_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
-        sink_ref = None
-    b = pl.program_id(0)
-    B = pl.num_programs(0)
-    layer = li_ref[0]
-    bs = k_buf.shape[2] // P
-    R = q_ref.shape[2]  # a head group's query rows, padded to the sublane tile
-
-    streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+def _group_copies(bt_ref, end_ref, streams, sems, *, layer, P):
+    """``(start, wait)`` of a paged kernel that copies its K and V by hand:
+    ``start(row, group, slot)`` begins the copies of the ``group``-th ``P``
+    blocks of ``row``'s table into ``slot`` of each stream's buffer, and
+    ``wait(row, group, slot)`` returns when they have landed. ``streams`` is
+    ``((hbm, buf), ...)``: the stacked pool ``(L, NB+1, Hkv, bs, D)`` left in
+    HBM and its two-slot buffer ``(2, Hkv, P * bs, D)``; ``sems`` is a DMA
+    semaphore per (stream, slot); ``end_ref[row]`` is one past the row's last
+    live block, and nothing past it is copied."""
+    bs = streams[0][1].shape[2] // P
 
     def live_blocks(row, group):
         """Blocks of ``group`` up to ``row``'s last live one: what is copied.
@@ -441,6 +439,31 @@ def _paged_group_kernel(
                     pltpu.make_async_copy(run, run, sems.at[stream, slot]).wait()
 
             width //= 2
+
+    return start, wait
+
+
+def _paged_group_kernel(
+    li_ref, bt_ref, lo_ref, end_ref, live_from_ref, *rest,
+    scale, n_kv, P, has_sink, q_dtype,
+):
+    """One ROW per grid step; inside, a loop over the row's live block groups
+    only. K and V stay in HBM: each group's ``P`` blocks are copied into one
+    of two VMEM slots while the group before it (of this row or of the last
+    live row) is attended, so a row with no live block starts no copy and
+    runs no arithmetic."""
+    if has_sink:
+        q_ref, mask_ref, sink_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
+    else:
+        q_ref, mask_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
+        sink_ref = None
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    R = q_ref.shape[2]  # a head group's query rows, padded to the sublane tile
+
+    start, wait = _group_copies(
+        bt_ref, end_ref, ((k_hbm, k_buf), (v_hbm, v_buf)), sems, layer=li_ref[0], P=P
+    )
 
     @pl.when(b == 0)
     def _first():

@@ -34,7 +34,10 @@ Grid: ``(Hq, q_tiles, kv_blocks)`` — the KV BlockSpec index map reads the
 per-row ``block_table`` through ``tile_row`` to DMA the right cache block
 per step (no gather materialization); tiles above the causal frontier or
 beyond a row's populated length are skipped via ``pl.when`` on scalar-
-prefetched per-tile maxima, exactly like ``ops/paged_flash_attention.py``.
+prefetched per-tile maxima, as ``ops/paged_flash_attention.py`` still does
+at a head_dim off the 128 lanes (at head_dim 128 that kernel walks a row's
+live groups of blocks in a loop and takes no step past its frontier; this
+one has not been moved to that plan: no benchmark cell serves it).
 
 Quantized caches reuse the int8/fp8 code/scale convention of the paged
 flash kernel: the K dequant factor folds into q before the launch (scaling

@@ -265,17 +265,24 @@ class ServingSession:
                 app.spec.attn.head_dim,
                 tc.kv_dtype,
             )
-            # what the paged decode kernel attends for a row (host-known: it
-            # follows from the pool's shape a head shard, as the kernel's does)
-            from neuronx_distributed_inference_tpu.ops.decode_attention import (
-                kv_blocks_walked,
+            # what the paged kernels attend for a row (host-known: it follows
+            # from the pool's shape a head shard, as the kernels' does): the
+            # decode kernel's walk and the prefill kernel's
+            from neuronx_distributed_inference_tpu.ops import (
+                decode_attention,
+                paged_flash_attention,
             )
 
             pool = app.kv_cache.k
-            self._kv_blocks_walked = functools.partial(
-                kv_blocks_walked,
+            shape = dict(
                 n_kv=pool.shape[2] // app.spec.attn.model_parallel,
                 bs=pool.shape[3], head_dim=pool.shape[4], cache_dtype=pool.dtype,
+            )
+            self._kv_blocks_walked = functools.partial(
+                decode_attention.kv_blocks_walked, **shape
+            )
+            self._chunk_kv_blocks_walked = functools.partial(
+                paged_flash_attention.kv_blocks_walked, **shape
             )
         # async 1-ahead decode (reference modules/async_execution.py:190):
         # the decode step dispatched last step(), not yet fetched —
@@ -1220,9 +1227,15 @@ class ServingSession:
             tel.prefill_pass(
                 ran_real, len(flights) * R * qb - ran_real, dispatches=len(flights)
             )
+            kv_blocks = None
+            if tel.enabled:
+                # the blocks a row's causal context holds once this chunk is in
+                live = [-(-(r.prefill_pos + n) // bs) for r, n in ran]
+                kv_blocks = (sum(live), self._chunk_kv_blocks_walked(live, mb))
             self._count_pass(
                 "chunk", len(ran), ran_real, len(flights),
                 resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
+                kv_blocks=kv_blocks,
             )
             for req, n in ran:
                 self._note_prefill(req, n)
@@ -1876,10 +1889,10 @@ class ServingSession:
         to per-slot state and routed experts, from what the step already
         knows: ``rows`` live rows over ``tokens`` real token positions in
         ``dispatches`` dispatches, ``resets`` of the rows from position 0;
-        ``kv_blocks``: a decode pass's (live, walked) pool blocks;
+        ``kv_blocks``: the pass's (live, walked) pool blocks;
         ``block_rows``: a block step's (denoise, commit) rows."""
         if kv_blocks is not None:
-            self.tel.decode_kv_blocks(*kv_blocks)
+            self.tel.kv_blocks(program, *kv_blocks)
         if block_rows is not None:
             self.tel.block_pass(*block_rows, positions=tokens)
         if self.slot_state_kind == "ssm":

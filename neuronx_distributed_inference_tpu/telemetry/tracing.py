@@ -410,6 +410,15 @@ class TelemetrySession:
             "kv axis attends for them (whole groups of blocks up to a row's "
             "last live one). live / walked = the share of attended KV that "
             "was live", labels=("kind",))
+        self._chunk_kv_blocks = r.counter(
+            "nxdi_chunk_kv_blocks_total",
+            "pool blocks of the prefilling rows per chunk pass of the split "
+            "serving step: kind=live, the blocks their causal contexts hold "
+            "with the chunk in; kind=walked, the block-table entries the "
+            "paged prefill kernel attends for them (whole groups of blocks up "
+            "to a row's frontier; every entry of the table where the kernel "
+            "keeps a block a grid step). walked / live = how far the walk is "
+            "from the context", labels=("kind",))
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -1137,13 +1146,15 @@ class TelemetrySession:
         self._block_blocks.inc()
         self._block_tokens.inc(tokens)
 
-    def decode_kv_blocks(self, live: int, walked: int) -> None:
-        """One decode dispatch over a paged cache: the pool blocks its rows'
-        contexts hold, and the block-table entries the kernel attends."""
+    def kv_blocks(self, program: str, live: int, walked: int) -> None:
+        """One pass ("decode" or "chunk") of the split serving step over a
+        paged cache: the pool blocks its rows' contexts hold, and the
+        block-table entries the program's paged kernel attends."""
         if not self.enabled:
             return
-        self._decode_kv_blocks.child(("live",)).inc(live)
-        self._decode_kv_blocks.child(("walked",)).inc(walked)
+        counter = self._decode_kv_blocks if program == "decode" else self._chunk_kv_blocks
+        counter.child(("live",)).inc(live)
+        counter.child(("walked",)).inc(walked)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

@@ -42,54 +42,103 @@ def _block_app(sd=None, **tpu_over):
 # ---------------------------------------------------------------------------
 
 
-def test_paged_flash_kernel_parity():
+def _flash_reference(q, k_pool, v_pool, block_table, positions, kv_limit, n_rep, scale):
+    """Native attention over the blocks a table names, gathered: (B, Sq, Hq, D)."""
+    B, MB = block_table.shape
+    _, Hkv, bs, D = k_pool.shape
+
+    def gathered(pool):  # (B, Hq, MB * bs, D)
+        x = np.nan_to_num(pool)[block_table].transpose(0, 2, 1, 3, 4)
+        return np.repeat(x.reshape(B, Hkv, MB * bs, D), n_rep, axis=1)
+
+    s = np.einsum("bqhd,bhkd->bhqk", q, gathered(k_pool)) * scale
+    kv_pos = np.arange(MB * bs)
+    mask = (kv_pos <= positions[:, None, :, None]) & (kv_pos < kv_limit[:, None, None, None])
+    s = np.where(mask, s, -1e30)
+    p = np.where(mask, np.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    p = p / np.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    return np.einsum("bhqk,bhkd->bqhd", p, gathered(v_pool))
+
+
+# name: (B, Sq, Hq, Hkv, D, bs, MB, tq, prior context per row, what else)
+#   "pages": the kernel's group of blocks (through the tuning table's
+#   override, as a committed entry would give it); "new": real tokens of the
+#   chunk per row (kv_limit = prior + new; 0 with prior 0 = a padded row);
+#   "frontier": block length of a block-step model's positions; "nan": pool
+#   blocks past every row's frontier and the null block hold NaN
+FLASH_CASES = {
+    # head_dim 64: a block a grid step through the BlockSpec
+    "d64_by_block": (2, 16, 4, 2, 64, 8, 6, 8, [20, 5], {}),
+    # the n_rep q heads of a KV head stacked on the query axis
+    "n_rep1": (2, 16, 2, 2, 128, 8, 6, 8, [20, 5], {}),
+    "n_rep2": (2, 16, 4, 2, 128, 8, 6, 8, [20, 5], {}),
+    "n_rep4": (2, 16, 8, 2, 128, 8, 6, 8, [20, 5], {}),
+    # ... and taken in parts where n_rep x tq passes Q_ROWS: 5 parts of 1, 4 of 2
+    "n_rep5_in_parts": (1, 128, 5, 1, 128, 8, 20, 128, [19], {}),
+    "n_rep8_in_parts": (1, 128, 8, 1, 128, 8, 20, 128, [19], {}),
+    # a padded row between live rows: one empty step, its output zeros
+    "empty_row_between": (3, 16, 4, 2, 128, 8, 6, 8, [20, 0, 5], {"new": [16, 0, 16]}),
+    # a context that ends mid-block and mid-group (groups of 2 blocks)
+    "ends_mid_block_mid_group": (2, 16, 4, 2, 128, 8, 8, 16, [21, 3], {"pages": 2, "new": [14, 9]}),
+    # a table narrower than one group, and one no multiple of it
+    "table_under_a_group": (2, 16, 4, 2, 128, 8, 3, 8, [5, 0], {"pages": 4, "new": [16, 12]}),
+    "table_no_multiple": (2, 16, 4, 2, 128, 8, 7, 8, [37, 11], {"pages": 4}),
+    # several q tiles whose frontiers lie in different groups
+    "q_tiles_across_groups": (2, 32, 4, 2, 128, 8, 8, 8, [30, 2], {"pages": 2}),
+    # a block-step model: each query's frontier at its block's end
+    "block_frontier": (2, 16, 4, 2, 128, 8, 6, 8, [20, 4], {"frontier": 4, "pages": 2}),
+    # nothing past a row's frontier reaches a product
+    "nan_past_frontier": (2, 16, 4, 2, 128, 8, 8, 8, [20, 5], {"nan": True, "pages": 2}),
+    "nan_past_frontier_d64": (2, 16, 4, 2, 64, 8, 8, 8, [20, 5], {"nan": True}),
+}
+
+
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_paged_flash_kernel_parity(case):
+    from neuronx_distributed_inference_tpu.modules import masks
     from neuronx_distributed_inference_tpu.ops.paged_flash_attention import (
         paged_flash_attention,
     )
+    from neuronx_distributed_inference_tpu.ops.tile_defaults import tile_overrides
     import jax.numpy as jnp
 
+    B, Sq, Hq, Hkv, D, bs, MB, tq, prior, extra = FLASH_CASES[case]
     rng = np.random.RandomState(0)
-    B, Sq, Hq, Hkv, D, bs, MB = 2, 16, 4, 2, 64, 8, 6
-    NB = 12
     n_rep = Hq // Hkv
     q = (rng.randn(B, Sq, Hq, D) * 0.3).astype(np.float32)
-    # head-major paged layout (NB+1, Hkv, bs, D)
+    # head-major paged layout (NB+1, Hkv, bs, D), block 0 the null block
+    NB = B * MB
     k_cache = (rng.randn(NB + 1, Hkv, bs, D) * 0.3).astype(np.float32)
     v_cache = (rng.randn(NB + 1, Hkv, bs, D) * 0.3).astype(np.float32)
-    # row 0: ctx 20 prior + 16 new (positions 20..35); row 1: 5 prior + 16 new
-    starts = np.array([20, 5])
+    starts = np.array(prior)
     positions = starts[:, None] + np.arange(Sq)[None, :]
-    kv_limit = starts + Sq
+    kv_limit = starts + np.array(extra.get("new", [Sq] * B))
+    if "frontier" in extra:
+        positions = np.asarray(masks.block_frontier(jnp.asarray(positions), extra["frontier"]))
+        kv_limit = positions[:, -1] + 1
+    # a row's blocks are its own, in order, up to its context; null past it
     block_table = np.zeros((B, MB), np.int32)
-    block_table[0] = [1, 2, 3, 4, 5, 6]
-    block_table[1] = [7, 8, 9, 10, 11, 0]
-
-    out = paged_flash_attention(
-        jnp.asarray(q), jnp.asarray(k_cache), jnp.asarray(v_cache),
-        jnp.asarray(block_table), jnp.asarray(positions), jnp.asarray(kv_limit),
-        scale=D**-0.5, n_rep=n_rep, tq=8, interpret=True,
-    )
-
-    # native reference: gather blocks, masked softmax
-    ref = np.zeros_like(q)
     for b in range(B):
-        kv = np.concatenate(
-            [k_cache[i].transpose(1, 0, 2) for i in block_table[b]], axis=0
-        )  # (MB*bs, Hkv, D)
-        vv = np.concatenate(
-            [v_cache[i].transpose(1, 0, 2) for i in block_table[b]], axis=0
+        n = -(-int(kv_limit[b]) // bs)
+        block_table[b, :n] = 1 + b * MB + np.arange(n)
+    if extra.get("nan"):
+        dead = np.setdiff1d(np.arange(NB + 1), block_table[block_table > 0])
+        k_cache[dead] = np.nan
+        v_cache[dead] = np.nan
+        block_table[1, -1] = dead[-1]  # a dead entry need not be the null block
+
+    raw = paged_flash_attention.__wrapped__  # the override is read at trace time
+    with tile_overrides("paged_flash_attention", {"pages": extra["pages"]} if "pages" in extra else {}):
+        out = raw(
+            jnp.asarray(q), jnp.asarray(k_cache), jnp.asarray(v_cache),
+            jnp.asarray(block_table), jnp.asarray(positions), jnp.asarray(kv_limit),
+            scale=D**-0.5, n_rep=n_rep, tq=tq, interpret=True,
         )
-        kv = np.repeat(kv, n_rep, axis=1)
-        vv = np.repeat(vv, n_rep, axis=1)
-        for t in range(Sq):
-            for h in range(Hq):
-                s = (q[b, t, h] @ kv[:, h].T) * (D**-0.5)
-                pos_idx = np.arange(MB * bs)
-                mask = (pos_idx <= positions[b, t]) & (pos_idx < kv_limit[b])
-                s = np.where(mask, s, -1e30)
-                p = np.exp(s - s.max())
-                p = p / p.sum()
-                ref[b, t, h] = p @ vv[:, h]
+
+    ref = _flash_reference(
+        q, k_cache, v_cache, block_table, positions, kv_limit, n_rep, D**-0.5
+    )
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
 
 

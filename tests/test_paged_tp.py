@@ -106,9 +106,10 @@ def test_tp2_both_dispatches_launch_their_kernel_per_shard(state_dict, monkeypat
         seen["decode"].add((q.shape[0], q.shape[2], k_cache.shape[2], kw["n_kv"]))
         return tkg(q, k_cache, *a, **kw)
 
-    def counting_flash(q, k_l, *a, **kw):
-        seen["chunk"].add((q.shape[0], q.shape[2], k_l.shape[1]))
-        return flash(q, k_l, *a, **kw)
+    def counting_flash(q, k_pool, *a, **kw):
+        # the stacked pool (L, NB+1, Hkv, bs, D): the kv heads a launch holds
+        seen["chunk"].add((q.shape[0], q.shape[2], k_pool.shape[2]))
+        return flash(q, k_pool, *a, **kw)
 
     def counting_gather(*a, **kw):
         seen["native"] += 1

@@ -325,6 +325,66 @@ def test_chunked_prefill_queue_wait_and_chunk_count():
     assert prefilled["samples"][0]["value"] == 40
 
 
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_chunk_kv_block_counter_reads_what_the_host_knows(head_dim):
+    """``nxdi_chunk_kv_blocks_total``: per chunk pass the pool blocks the
+    prefilling rows' causal contexts hold with the chunk in (``live``) and
+    the block-table entries the paged prefill kernel attends for them
+    (``walked``: whole groups of ``blocks_per_group`` blocks up to a row's
+    frontier at head_dim 128; rows x table width where the kernel keeps a
+    block a grid step). Rows of KNOWN contexts: prompts of 600 and 6 tokens
+    in chunks of 16 and blocks of 8, so the long row's passes end at
+    16, 32, ..., 592, 600 tokens. Nothing is counted with telemetry off."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.ops.paged_flash_attention import (
+        blocks_per_group,
+    )
+
+    cfg = make_tiny_config(
+        hidden_size=2 * head_dim, num_attention_heads=2, num_key_value_heads=1,
+        tpu=dict(
+            is_continuous_batching=True, batch_size=2, ctx_batch_size=1,
+            is_block_kv_layout=True, pa_block_size=8, pa_num_blocks=120,
+            is_chunked_prefill=True, seq_len=1024, token_generation_buckets=[1024],
+            chunked_prefill_config=ChunkedPrefillConfig(
+                max_num_seqs=2, kernel_q_tile_size=16
+            ),
+        ),
+    )
+    app = TpuModelForCausalLM(None, cfg).load(
+        state_dict=make_random_hf_state_dict(cfg)
+    )
+    long_prompt = [(i * 37) % 100 + 2 for i in range(600)]
+
+    def drive(tel):
+        app.init_kv_cache()
+        sess = ServingSession(app, telemetry=tel)
+        assert sess.add_request("long", long_prompt, max_new_tokens=2)
+        assert sess.add_request("short", [5, 17, 92, 41, 33, 88], max_new_tokens=2)
+        while sess.active:
+            sess.step()
+
+    off = TelemetrySession(enabled=False)
+    drive(off)
+    assert off.registry.snapshot() == {}
+
+    tel = TelemetrySession(enabled=True)
+    drive(tel)
+    bs, MB = 8, 1024 // 8
+    contexts = [16 * k for k in range(1, 38)] + [600, 6]
+    live = [-(-n // bs) for n in contexts]
+    P = blocks_per_group(1, bs, head_dim, cfg.tpu_config.kv_dtype, MB)
+    assert P == (64 if head_dim == 128 else 1)  # 512 tokens a group
+    walked = [-(-n // P) * P if head_dim == 128 else MB for n in live]
+    snap = tel.registry.snapshot()["nxdi_chunk_kv_blocks_total"]["samples"]
+    got = {x["labels"]["kind"]: x["value"] for x in snap}
+    assert got == {"live": sum(live), "walked": sum(walked)}
+    assert got["live"] == 2 * sum(range(1, 38)) + 75 + 1
+    # one group up to 512 tokens, two past it; the table's 128 a row-pass off the lanes
+    assert got["walked"] == (64 * 32 + 128 * 6 + 64 if head_dim == 128 else 128 * 39)
+    tel.close()
+
+
 def test_double_finish_counts_once(cb_app):
     """_finish and _preempt can both legitimately run twice for one request
     (an already-dispatched row's token is consumed a step later and may hit

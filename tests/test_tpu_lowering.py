@@ -201,6 +201,39 @@ def test_lower_paged_flash(B, Hkv):
     lower_tpu(lambda q, k, v, b, p, l: fn(q, k, v, b, p, l), q, cache, cache, bt, pos, lim)
 
 
+# the served shapes of the group launch (head_dim 128, blocks of 32, 8 rows
+# of 128): (q heads, kv heads) a chip of Qwen3-1.7B, Qwen3-14B at tp = 4,
+# ZAYA1-8B and SDAR-30B-A3B: n_rep 2 / 5 / 4 / 8, the last two in parts
+@pytest.mark.parametrize("Hq,Hkv", [(16, 8), (10, 2), (8, 2), (32, 4)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+def test_lower_paged_flash_by_group(Hq, Hkv, dtype):
+    L, NB, bs, MB, B, Sq, D = 2, 64, 32, 64, 8, 128, 128
+    q = sds((B, Sq, Hq, D), jnp.bfloat16)
+    cache = sds((L, NB + 1, Hkv, bs, D), dtype)
+    li = sds((), jnp.int32)
+    bt = sds((B, MB), jnp.int32)
+    pos = sds((B, Sq), jnp.int32)
+    lim = sds((B,), jnp.int32)
+    scale = sds((Hkv,), jnp.float32)
+    fn = functools.partial(
+        paged_flash_attention, scale=D**-0.5, n_rep=Hq // Hkv, interpret=False
+    )
+    if dtype == jnp.int8:
+        exported = lower_tpu(
+            lambda q, k, v, l, b, p, m, ks, vs: fn(
+                q, k, v, b, p, m, layer_idx=l, k_scale=ks, v_scale=vs
+            ),
+            q, cache, cache, li, bt, pos, lim, scale, scale,
+        )
+    else:
+        exported = lower_tpu(
+            lambda q, k, v, l, b, p, m: fn(q, k, v, b, p, m, layer_idx=l),
+            q, cache, cache, li, bt, pos, lim,
+        )
+    # the name the benchmark's roofline reader finds the traced op by
+    assert "paged_flash_attention" in exported.mlir_module()
+
+
 # ---------------------------------------------------------------------------
 # the 1B program set — the kernel shapes chip_smoke.py drives
 # (llama-3.2-1B: Hq=32, Hkv=8, D=64; prefill 128/512; decode buckets 512/1024)
