@@ -771,6 +771,15 @@ def run_inference(args) -> int:
                 )
             else:
                 out = app.generate(input_ids, attention_mask, **gen_kwargs)
+    if args.profile_dir:
+        from neuronx_distributed_inference_tpu.utils.profiling import summarize_trace
+
+        # device time under the program's own names, where the run wrote the
+        # tables beside its trace (a serving session's step programs)
+        for module, scopes in summarize_trace(args.profile_dir).get("by_scope", {}).items():
+            for scope, t in scopes.items():
+                print(f"[inference_demo] {module} {scope or '(no scope)'}: "
+                      f"{t['seconds'] * 1e3:.3f} ms ({t['share']:.1%})", file=sys.stderr)
     if capture_hook is not None:
         print(f"[inference_demo] captured {len(capture_hook.saved)} input snapshots",
               file=sys.stderr)

@@ -562,11 +562,13 @@ def paged_attend(
 def _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn):
     """post-attention norm + MLP + residual."""
     residual = hidden
-    hidden = apply_norm(
-        hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps,
-        spec.norm_type,
-    )
-    return residual_add(residual, mlp_fn(layer_params["mlp"], hidden, spec), spec)
+    with jax.named_scope("layer.norm"):
+        hidden = apply_norm(
+            hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps,
+            spec.norm_type,
+        )
+    with jax.named_scope("layer.mlp"):
+        return residual_add(residual, mlp_fn(layer_params["mlp"], hidden, spec), spec)
 
 
 def decoder_layer(
@@ -607,12 +609,14 @@ def decoder_layer(
     """
     aspec = spec.attn
     residual = hidden
-    hidden = apply_norm(
-        hidden, layer_params["input_layernorm"]["weight"], spec.rms_eps, spec.norm_type
-    )
-    q, k, v = qkv_project(
-        layer_params["self_attn"], hidden, cos, sin, aspec, adapter_ids=adapter_ids
-    )
+    with jax.named_scope("layer.norm"):
+        hidden = apply_norm(
+            hidden, layer_params["input_layernorm"]["weight"], spec.rms_eps, spec.norm_type
+        )
+    with jax.named_scope("layer.qkv"):
+        q, k, v = qkv_project(
+            layer_params["self_attn"], hidden, cos, sin, aspec, adapter_ids=adapter_ids
+        )
 
     # write-then-attend: scatter new KV into this layer's cache first
     # (reference updates via kv_mgr.update_cache per layer, model_base.py:1449)
@@ -632,129 +636,132 @@ def decoder_layer(
         k_prior, v_prior = read_cache_at_layer(
             k_cache, v_cache, layer_idx, q.shape[0], W
         )
-    if interleaved:
-        k_full, k_ring = k_cache
-        v_full, v_ring = v_cache
-        full_i, ring_i, is_sliding = layer_idx
-        W = spec.ring_window
-        if phase != PHASE_CONTEXT_ENCODING:
-            # prior ring window read BEFORE writes (same hazard as `bounded`);
-            # for global layers ring_i clamps to a real slice whose values are
-            # never used (the lax.cond below takes the full-cache branch)
-            k_prior, v_prior = read_cache_at_layer(
-                k_ring, v_ring, ring_i, q.shape[0], W
+    with jax.named_scope("layer.kv_write"):
+        if interleaved:
+            k_full, k_ring = k_cache
+            v_full, v_ring = v_cache
+            full_i, ring_i, is_sliding = layer_idx
+            W = spec.ring_window
+            if phase != PHASE_CONTEXT_ENCODING:
+                # prior ring window read BEFORE writes (same hazard as `bounded`);
+                # for global layers ring_i clamps to a real slice whose values are
+                # never used (the lax.cond below takes the full-cache branch)
+                k_prior, v_prior = read_cache_at_layer(
+                    k_ring, v_ring, ring_i, q.shape[0], W
+                )
+            ring_pos = jnp.where(positions >= 0, positions % W, W)
+            k_full, v_full = update_cache_at_layer(
+                k_full, v_full, k, v, full_i, slot_ids, positions
             )
-        ring_pos = jnp.where(positions >= 0, positions % W, W)
-        k_full, v_full = update_cache_at_layer(
-            k_full, v_full, k, v, full_i, slot_ids, positions
-        )
-        k_ring, v_ring = update_cache_at_layer(
-            k_ring, v_ring, k, v, ring_i, slot_ids, ring_pos
-        )
-        k_cache, v_cache = (k_full, k_ring), (v_full, v_ring)
-    elif is_block:
-        from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-            update_block_cache_at_layer,
-        )
+            k_ring, v_ring = update_cache_at_layer(
+                k_ring, v_ring, k, v, ring_i, slot_ids, ring_pos
+            )
+            k_cache, v_cache = (k_full, k_ring), (v_full, v_ring)
+        elif is_block:
+            from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+                update_block_cache_at_layer,
+            )
 
-        k_cache, v_cache = update_block_cache_at_layer(
-            k_cache, v_cache, k, v, layer_idx, block_inputs[0],
-            packed=ragged_rows is not None,
-        )
-    else:
-        if bounded:
-            # slot = position mod W; sentinel (negative) positions map out of
-            # range and are DROPPED (padded prompt tails must not wrap into
-            # live ring slots)
-            W = spec.bounded_window
-            write_positions = jnp.where(positions >= 0, positions % W, W)
+            k_cache, v_cache = update_block_cache_at_layer(
+                k_cache, v_cache, k, v, layer_idx, block_inputs[0],
+                packed=ragged_rows is not None,
+            )
         else:
-            write_positions = positions
-        k_cache, v_cache = update_cache_at_layer(
-            k_cache, v_cache, k, v, layer_idx, slot_ids, write_positions,
-            dp=spec.attention_dp * spec.data_parallel,
-        )
+            if bounded:
+                # slot = position mod W; sentinel (negative) positions map out of
+                # range and are DROPPED (padded prompt tails must not wrap into
+                # live ring slots)
+                W = spec.bounded_window
+                write_positions = jnp.where(positions >= 0, positions % W, W)
+            else:
+                write_positions = positions
+            k_cache, v_cache = update_cache_at_layer(
+                k_cache, v_cache, k, v, layer_idx, slot_ids, write_positions,
+                dp=spec.attention_dp * spec.data_parallel,
+            )
 
     sink = layer_params["self_attn"].get("sink", {}).get("weight") if aspec.has_sink else None
-    if phase == PHASE_CONTEXT_ENCODING:
-        if spec.cp_enabled:
-            # CP prefill: Q keeps its seq stripe; KV constrained replicated so
-            # GSPMD all-gathers it over the cp axis (reference all-gather-KV
-            # CP, attention_base.py:614-627)
-            from neuronx_distributed_inference_tpu.parallel import context_parallel as cpx
+    with jax.named_scope("layer.attn"):
+        if phase == PHASE_CONTEXT_ENCODING:
+            if spec.cp_enabled:
+                # CP prefill: Q keeps its seq stripe; KV constrained replicated so
+                # GSPMD all-gathers it over the cp axis (reference all-gather-KV
+                # CP, attention_base.py:614-627)
+                from neuronx_distributed_inference_tpu.parallel import context_parallel as cpx
 
-            q = cpx.shard_q(q)
-            k = cpx.gather_kv(k)
-            v = cpx.gather_kv(v)
-        if flavor_select is not None:
-            uniq, fl = flavor_select
+                q = cpx.shard_q(q)
+                k = cpx.gather_kv(k)
+                v = cpx.gather_kv(v)
+            if flavor_select is not None:
+                uniq, fl = flavor_select
 
-            def _mk(wc):
-                w, c = wc
-                return lambda _: attention_prefill(
+                def _mk(wc):
+                    w, c = wc
+                    return lambda _: attention_prefill(
+                        q, k, v, mask, aspec, sink=sink, key_valid=key_valid,
+                        window=w, chunk=c,
+                    )
+
+                attn_out = jax.lax.switch(fl, [_mk(wc) for wc in uniq], None)
+            else:
+                attn_out = attention_prefill(
                     q, k, v, mask, aspec, sink=sink, key_valid=key_valid,
-                    window=w, chunk=c,
+                    window=window, chunk=chunk,
+                )
+            if spec.cp_enabled:
+                attn_out = cpx.shard_attn_out(attn_out)
+        elif ragged_rows is not None:
+            # ragged mixed step: prefill-chunk AND decode rows in ONE attention
+            # launch off the paged cache, masks derived in-kernel from the
+            # (row_start, row_len, ctx_len) descriptors (PAPERS.md ragged paged
+            # attention); native gather fallback keeps every config on CPU
+            from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
+                ragged_attention,
+            )
+
+            rs, rl, cl = ragged_rows
+            attn_out = ragged_attention(
+                q, k_cache, v_cache, layer_idx, block_inputs[1], positions,
+                rs, rl, cl, aspec, interpret=kernel_interpret(),
+            )
+        elif is_block:
+            attn_out = paged_attend(
+                q, k_cache, v_cache, layer_idx, mask, block_inputs[1], block_inputs[2],
+                positions, spec, sink,
+            )
+        elif bounded:
+            attn_out = ring_attention(
+                q, k, v, k_prior, v_prior, positions, spec.bounded_window, aspec, sink
+            )
+        elif interleaved:
+            # decode: sliding layers attend [prior ring | chunk]; global layers
+            # attend their full-length cache line. lax.cond executes only the
+            # taken branch, so sliding layers never pay the full-cache read
+            B = q.shape[0]
+            bucket = mask.shape[-1]
+
+            def _global_attend(_):
+                k_r, v_r = read_cache_at_layer(k_full, v_full, full_i, B, bucket)
+                return attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
+
+            def _ring_attend(_):
+                return ring_attention(
+                    q, k, v, k_prior, v_prior, positions, spec.ring_window, aspec, sink
                 )
 
-            attn_out = jax.lax.switch(fl, [_mk(wc) for wc in uniq], None)
+            attn_out = jax.lax.cond(is_sliding == 1, _ring_attend, _global_attend, None)
         else:
-            attn_out = attention_prefill(
-                q, k, v, mask, aspec, sink=sink, key_valid=key_valid,
-                window=window, chunk=chunk,
+            attn_out = contiguous_decode_attend(
+                q, k_cache, v_cache, layer_idx, mask, spec, aspec, sink
             )
-        if spec.cp_enabled:
-            attn_out = cpx.shard_attn_out(attn_out)
-    elif ragged_rows is not None:
-        # ragged mixed step: prefill-chunk AND decode rows in ONE attention
-        # launch off the paged cache, masks derived in-kernel from the
-        # (row_start, row_len, ctx_len) descriptors (PAPERS.md ragged paged
-        # attention); native gather fallback keeps every config on CPU
-        from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
-            ragged_attention,
-        )
-
-        rs, rl, cl = ragged_rows
-        attn_out = ragged_attention(
-            q, k_cache, v_cache, layer_idx, block_inputs[1], positions,
-            rs, rl, cl, aspec, interpret=kernel_interpret(),
-        )
-    elif is_block:
-        attn_out = paged_attend(
-            q, k_cache, v_cache, layer_idx, mask, block_inputs[1], block_inputs[2],
-            positions, spec, sink,
-        )
-    elif bounded:
-        attn_out = ring_attention(
-            q, k, v, k_prior, v_prior, positions, spec.bounded_window, aspec, sink
-        )
-    elif interleaved:
-        # decode: sliding layers attend [prior ring | chunk]; global layers
-        # attend their full-length cache line. lax.cond executes only the
-        # taken branch, so sliding layers never pay the full-cache read
-        B = q.shape[0]
-        bucket = mask.shape[-1]
-
-        def _global_attend(_):
-            k_r, v_r = read_cache_at_layer(k_full, v_full, full_i, B, bucket)
-            return attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
-
-        def _ring_attend(_):
-            return ring_attention(
-                q, k, v, k_prior, v_prior, positions, spec.ring_window, aspec, sink
-            )
-
-        attn_out = jax.lax.cond(is_sliding == 1, _ring_attend, _global_attend, None)
-    else:
-        attn_out = contiguous_decode_attend(
-            q, k_cache, v_cache, layer_idx, mask, spec, aspec, sink
-        )
 
     if not interleaved:
         from neuronx_distributed_inference_tpu.modules import tensor_taps
 
         attn_out = tensor_taps.tap("attn_out", attn_out, layer_idx)
-    hidden = o_project(layer_params["self_attn"], attn_out, aspec, adapter_ids=adapter_ids)
-    hidden = residual_add(residual, hidden, spec)
+    with jax.named_scope("layer.o_proj"):
+        hidden = o_project(layer_params["self_attn"], attn_out, aspec, adapter_ids=adapter_ids)
+        hidden = residual_add(residual, hidden, spec)
 
     hidden = _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn)
     if spec.cp_enabled and phase == PHASE_CONTEXT_ENCODING:
@@ -1252,7 +1259,8 @@ def run_decoder_layers(
 
         hidden = cpx.shard_seq(hidden)
 
-    hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
+    with jax.named_scope("head"):
+        hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
     hidden = tensor_taps.tap("final_hidden", hidden)
     if capture_layers is not None:
         # (C, B, S, H) -> (B, S, C*H) concat in tap order
@@ -1296,9 +1304,12 @@ def model_logits(
     if inputs.inputs_embeds is not None:
         hidden = inputs.inputs_embeds
     else:
-        hidden = embed(params, inputs.input_ids)
-        if spec.embedding_multiplier != 1.0:
-            hidden = (hidden.astype(jnp.float32) * spec.embedding_multiplier).astype(hidden.dtype)
+        with jax.named_scope("embed"):
+            hidden = embed(params, inputs.input_ids)
+            if spec.embedding_multiplier != 1.0:
+                hidden = (hidden.astype(jnp.float32) * spec.embedding_multiplier).astype(
+                    hidden.dtype
+                )
     hidden = tensor_taps.tap("embed", hidden)
     aux = ()
     if capture_layers is not None:
@@ -1317,7 +1328,8 @@ def model_logits(
         hidden = gather_last_token(hidden, inputs.attention_mask)
     # TKG: all n_active positions produce logits
 
-    logits = lm_head(params, hidden, spec)[..., : spec.vocab_size]  # (B, K, V)
+    with jax.named_scope("head"):
+        logits = lm_head(params, hidden, spec)[..., : spec.vocab_size]  # (B, K, V)
     logits = tensor_taps.tap("logits", logits)
     if return_hidden:
         return logits, new_cache, full_hidden
@@ -1701,18 +1713,22 @@ def forward(
         and phase == PHASE_TOKEN_GENERATION
         and inputs.input_ids.shape[1] == spec.block_step.block_length
     ):
-        tokens, confidence, next_ids = block_reveal(logits, inputs.input_ids, spec.block_step)
-    elif spec.on_device_sampling:
-        tokens = sample_tokens(
-            logits,
-            inputs.sampling_params,
-            rng if spec.do_sample else None,
-            spec.max_topk,
-            spec.do_sample,
-        )
+        with jax.named_scope("reveal"):
+            tokens, confidence, next_ids = block_reveal(logits, inputs.input_ids, spec.block_step)
     else:
-        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tokens = mark_non_finite_tokens(tokens, logits)
+        with jax.named_scope("sample"):
+            if spec.on_device_sampling:
+                tokens = sample_tokens(
+                    logits,
+                    inputs.sampling_params,
+                    rng if spec.do_sample else None,
+                    spec.max_topk,
+                    spec.do_sample,
+                )
+            else:
+                tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        tokens = mark_non_finite_tokens(tokens, logits)
     if next_ids is not None:
         # the session fetches next_ids alone: a row with a non-finite
         # position carries the sentinel there too

@@ -133,53 +133,55 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
     d_inner, conv_dim = sspec.d_inner, sspec.conv_dim
     f32 = jnp.float32
     m = lp["mixer"]
-    x = rms_norm(hidden, lp["input_layernorm"]["weight"], spec.rms_eps)
-    # the published in_proj, 2048 -> [z | xBC | dt] = 4096 + 4352 + 64, held as
-    # two matrices: 8512 columns are not a whole number of 128-lane tiles, and
-    # the compiler then copies the STACKED weight (1.26 GB) into another layout
-    # on every step; 8448 and 64 it takes as they are
-    proj = linear(m["in_proj"], x)
-    z, xBC = proj[..., :d_inner], proj[..., d_inner:]
-    dt = jax.nn.softplus(linear(m["dt_proj"], x).astype(f32) + m["dt_bias"].astype(f32))
-    A = -jnp.exp(m["A_log"].astype(f32))
+    with jax.named_scope("layer.norm"):
+        x = rms_norm(hidden, lp["input_layernorm"]["weight"], spec.rms_eps)
+    with jax.named_scope("layer.ssm"):
+        # the published in_proj, 2048 -> [z | xBC | dt] = 4096 + 4352 + 64, held as
+        # two matrices: 8512 columns are not a whole number of 128-lane tiles, and
+        # the compiler then copies the STACKED weight (1.26 GB) into another layout
+        # on every step; 8448 and 64 it takes as they are
+        proj = linear(m["in_proj"], x)
+        z, xBC = proj[..., :d_inner], proj[..., d_inner:]
+        dt = jax.nn.softplus(linear(m["dt_proj"], x).astype(f32) + m["dt_bias"].astype(f32))
+        A = -jnp.exp(m["A_log"].astype(f32))
 
-    tails = jax.lax.dynamic_index_in_dim(state.conv, li, 0, keepdims=False)
-    tail = tails if slots is None else jnp.take(tails, slots, axis=1, mode="fill", fill_value=0)
-    tail = jnp.where(reset[None, :, None], jnp.zeros((), tail.dtype), tail)
-    n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
-    xBC, tail = ssm.causal_conv(xBC, tail, m["conv1d"]["weight"], m["conv1d"]["bias"], n_valid)
-    if slots is not None:
-        tail = tails.at[:, slots].set(tail, mode="drop", unique_indices=True)
-    conv = jax.lax.dynamic_update_index_in_dim(state.conv, tail, li, 0)
-    xBC = xBC.astype(hidden.dtype)
-    xs = xBC[..., :d_inner].reshape(R, Q, Hn, Pd)
-    Bm = xBC[..., d_inner : d_inner + G * N].reshape(R, Q, G, N)
-    Cm = xBC[..., d_inner + G * N :].reshape(R, Q, G, N)
+        tails = jax.lax.dynamic_index_in_dim(state.conv, li, 0, keepdims=False)
+        tail = tails if slots is None else jnp.take(tails, slots, axis=1, mode="fill", fill_value=0)
+        tail = jnp.where(reset[None, :, None], jnp.zeros((), tail.dtype), tail)
+        n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+        xBC, tail = ssm.causal_conv(xBC, tail, m["conv1d"]["weight"], m["conv1d"]["bias"], n_valid)
+        if slots is not None:
+            tail = tails.at[:, slots].set(tail, mode="drop", unique_indices=True)
+        conv = jax.lax.dynamic_update_index_in_dim(state.conv, tail, li, 0)
+        xBC = xBC.astype(hidden.dtype)
+        xs = xBC[..., :d_inner].reshape(R, Q, Hn, Pd)
+        Bm = xBC[..., d_inner : d_inner + G * N].reshape(R, Q, G, N)
+        Cm = xBC[..., d_inner + G * N :].reshape(R, Q, G, N)
 
-    if Q == 1 and G == 1 and slots is None:
-        from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
+        if Q == 1 and G == 1 and slots is None:
+            from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
 
-        y, new_ssm = ssm_state_update(
-            state.ssm, li, xs[:, 0], Bm[:, 0, 0], Cm[:, 0, 0], dt[:, 0], A,
-            valid[:, 0], reset, interpret=kernel_interpret(),
-        )
-        y = y[:, None]
-    else:
-        if slots is None:
-            s = jax.lax.dynamic_index_in_dim(state.ssm, li, 0, keepdims=False)
+            y, new_ssm = ssm_state_update(
+                state.ssm, li, xs[:, 0], Bm[:, 0, 0], Cm[:, 0, 0], dt[:, 0], A,
+                valid[:, 0], reset, interpret=kernel_interpret(),
+            )
+            y = y[:, None]
         else:
-            # straight from / into the stacked state: R x 2 MiB a layer,
-            # never a layer's whole (slots, ...) slice
-            s = state.ssm.at[li, slots].get(mode="fill", fill_value=0.0)
-        s = jnp.where(reset[:, None, None, None], 0.0, s)
-        y, s = ssm.mamba2_chunk(xs, Bm, Cm, dt, A, s, valid, chunk_size=sspec.chunk_size)
-        if slots is None:
-            new_ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, s, li, 0)
-        else:
-            new_ssm = state.ssm.at[li, slots].set(s, mode="drop", unique_indices=True)
-    y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
-    gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps)
-    hidden = residual_add(hidden, linear(m["out_proj"], gated), spec)
+            if slots is None:
+                s = jax.lax.dynamic_index_in_dim(state.ssm, li, 0, keepdims=False)
+            else:
+                # straight from / into the stacked state: R x 2 MiB a layer,
+                # never a layer's whole (slots, ...) slice
+                s = state.ssm.at[li, slots].get(mode="fill", fill_value=0.0)
+            s = jnp.where(reset[:, None, None, None], 0.0, s)
+            y, s = ssm.mamba2_chunk(xs, Bm, Cm, dt, A, s, valid, chunk_size=sspec.chunk_size)
+            if slots is None:
+                new_ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, s, li, 0)
+            else:
+                new_ssm = state.ssm.at[li, slots].set(s, mode="drop", unique_indices=True)
+        y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
+        gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps)
+        hidden = residual_add(hidden, linear(m["out_proj"], gated), spec)
     hidden = _decoder_layer_mlp(lp, hidden, spec, mlp_fn)
     return hidden, ssm.RecurrentState(conv=conv, ssm=new_ssm)
 

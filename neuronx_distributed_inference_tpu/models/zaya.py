@@ -150,7 +150,8 @@ class ZayaStack(LayerStack):
             h, r, k_cache, v_cache, last = carry
             lp, li = xs
             sa = lp["self_attn"]
-            x = rms_norm(h, lp["input_layernorm"]["weight"], spec.rms_eps)
+            with jax.named_scope("layer.norm"):
+                x = rms_norm(h, lp["input_layernorm"]["weight"], spec.rms_eps)
             if slots is None:
                 rows = jax.lax.dynamic_index_in_dim(last, li, 0, keepdims=False)
             else:
@@ -161,21 +162,27 @@ class ZayaStack(LayerStack):
                 last = jax.lax.dynamic_update_index_in_dim(last, rows, li, 0)
             else:
                 last = last.at[li, slots].set(rows, mode="drop", unique_indices=True)
-            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-            k_cache, v_cache = update_block_cache_at_layer(
-                k_cache, v_cache, k, v, li, slot_mapping
-            )
-            attn = paged_attend(
-                q, k_cache, v_cache, li, mask, block_table, kv_limit, positions, spec
-            )
-            h = residual_add(h, linear(sa["o_proj"], attn.reshape(B, S, -1)), spec)
+            with jax.named_scope("layer.qkv"):
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            with jax.named_scope("layer.kv_write"):
+                k_cache, v_cache = update_block_cache_at_layer(
+                    k_cache, v_cache, k, v, li, slot_mapping
+                )
+            with jax.named_scope("layer.attn"):
+                attn = paged_attend(
+                    q, k_cache, v_cache, li, mask, block_table, kv_limit, positions, spec
+                )
+            with jax.named_scope("layer.o_proj"):
+                h = residual_add(h, linear(sa["o_proj"], attn.reshape(B, S, -1)), spec)
 
-            x = rms_norm(h, lp["post_attention_layernorm"]["weight"], spec.rms_eps)
+            with jax.named_scope("layer.norm"):
+                x = rms_norm(h, lp["post_attention_layernorm"]["weight"], spec.rms_eps)
             aff, selected, r, choice = carried_mlp_router(
                 lp["mlp"]["router"], x.reshape(B * S, H), r, spec.rms_eps
             )
-            out = moe_layer(lp["mlp"], x, moe, router=lambda *_: (aff, selected))
-            h = residual_add(h, out, spec)
+            with jax.named_scope("layer.mlp"):
+                out = moe_layer(lp["mlp"], x, moe, router=lambda *_: (aff, selected))
+                h = residual_add(h, out, spec)
             return (h, r, k_cache, v_cache, last), (
                 choice.reshape(B, S) if spec.output_choices else None
             )

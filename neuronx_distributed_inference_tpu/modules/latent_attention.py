@@ -121,39 +121,40 @@ def cca_qkv(
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """q (R, Q, H_q, d), k and v (R, Q, H_kv, d) before the rotation, and
     each row's carry after its valid positions."""
-    R, Q, _ = x.shape
-    Hq, Hkv, d = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    G, C = Hq // Hkv, spec.channels
-    f32, dtype = jnp.float32, x.dtype
-    u_prev, a_prev, v2_prev = carry[:, :C], carry[:, C : 2 * C], carry[:, 2 * C :]
+    with jax.named_scope("layer.qkv"):
+        R, Q, _ = x.shape
+        Hq, Hkv, d = spec.num_heads, spec.num_kv_heads, spec.head_dim
+        G, C = Hq // Hkv, spec.channels
+        f32, dtype = jnp.float32, x.dtype
+        u_prev, a_prev, v2_prev = carry[:, :C], carry[:, C : 2 * C], carry[:, 2 * C :]
 
-    qt, kt = linear(params["q_proj"], x), linear(params["k_proj"], x)
-    u = jnp.concatenate([qt, kt], axis=-1)  # (R, Q, C)
-    conv0 = params["conv0"]
-    a, u_tail = causal_conv(u, u_prev[None], conv0["weight"], conv0["bias"], n_valid,
-                            activation=None)
-    a = a.astype(dtype)  # what the next token is handed is what this one's second stage takes
-    window = carried_window(a, a_prev[None])  # (R, 1 + Q, C)
-    a_tail = tail_after(window, n_valid, 1, dtype)
-    heads = window.reshape(R, 1 + Q, spec.groups, d)
-    # both taps in ONE product per head: [a_{t-1} ; a_t] (2d) against the taps
-    # stacked on the contraction, accumulated in float32 by the unit
-    pairs = jnp.concatenate([heads[:, :Q], heads[:, 1:]], axis=-1)  # (R, Q, groups, 2d)
-    w1 = params["conv1"]["weight"].astype(dtype)  # (2, groups, d, d)
-    c = jnp.einsum("rqgi,gio->rqgo", pairs, jnp.concatenate([w1[0], w1[1]], axis=1))
-    c = (c.astype(f32) + params["conv1"]["bias"].astype(f32).reshape(spec.groups, d)).astype(dtype)
+        qt, kt = linear(params["q_proj"], x), linear(params["k_proj"], x)
+        u = jnp.concatenate([qt, kt], axis=-1)  # (R, Q, C)
+        conv0 = params["conv0"]
+        a, u_tail = causal_conv(u, u_prev[None], conv0["weight"], conv0["bias"], n_valid,
+                                activation=None)
+        a = a.astype(dtype)  # what the next token is handed is what this one's second stage takes
+        window = carried_window(a, a_prev[None])  # (R, 1 + Q, C)
+        a_tail = tail_after(window, n_valid, 1, dtype)
+        heads = window.reshape(R, 1 + Q, spec.groups, d)
+        # both taps in ONE product per head: [a_{t-1} ; a_t] (2d) against the taps
+        # stacked on the contraction, accumulated in float32 by the unit
+        pairs = jnp.concatenate([heads[:, :Q], heads[:, 1:]], axis=-1)  # (R, Q, groups, 2d)
+        w1 = params["conv1"]["weight"].astype(dtype)  # (2, groups, d, d)
+        c = jnp.einsum("rqgi,gio->rqgo", pairs, jnp.concatenate([w1[0], w1[1]], axis=1))
+        c = (c.astype(f32) + params["conv1"]["bias"].astype(f32).reshape(spec.groups, d)).astype(dtype)
 
-    qt = qt.reshape(R, Q, Hq, d).astype(f32)
-    kt = kt.reshape(R, Q, Hkv, d).astype(f32)
-    q = c[:, :, :Hq].astype(f32) + (qt + jnp.repeat(kt, G, axis=2)) / 2
-    k = c[:, :, Hq:].astype(f32) + (qt.reshape(R, Q, Hkv, G, d).mean(axis=3) + kt) / 2
-    scale = jnp.sqrt(jnp.asarray(d, f32))
-    temp = jnp.exp(params["key_temp"].astype(f32))[None, None, :, None]
-    q = (scale * _unit(q.astype(dtype))).astype(dtype)
-    k = (temp * scale * _unit(k.astype(dtype))).astype(dtype)
+        qt = qt.reshape(R, Q, Hq, d).astype(f32)
+        kt = kt.reshape(R, Q, Hkv, d).astype(f32)
+        q = c[:, :, :Hq].astype(f32) + (qt + jnp.repeat(kt, G, axis=2)) / 2
+        k = c[:, :, Hq:].astype(f32) + (qt.reshape(R, Q, Hkv, G, d).mean(axis=3) + kt) / 2
+        scale = jnp.sqrt(jnp.asarray(d, f32))
+        temp = jnp.exp(params["key_temp"].astype(f32))[None, None, :, None]
+        q = (scale * _unit(q.astype(dtype))).astype(dtype)
+        k = (temp * scale * _unit(k.astype(dtype))).astype(dtype)
 
-    v1, v2 = linear(params["v1_proj"], x), linear(params["v2_proj"], x)
-    shifted = carried_window(v2, v2_prev[None])  # (R, 1 + Q, value_half): position q holds x_{q-1}'s
-    v2_tail = tail_after(shifted, n_valid, 1, dtype)
-    v = jnp.concatenate([v1, shifted[:, :Q]], axis=-1).reshape(R, Q, Hkv, d)
-    return q, k, v, jnp.concatenate([u_tail[0], a_tail[0], v2_tail[0]], axis=-1)
+        v1, v2 = linear(params["v1_proj"], x), linear(params["v2_proj"], x)
+        shifted = carried_window(v2, v2_prev[None])  # (R, 1 + Q, value_half): position q holds x_{q-1}'s
+        v2_tail = tail_after(shifted, n_valid, 1, dtype)
+        v = jnp.concatenate([v1, shifted[:, :Q]], axis=-1).reshape(R, Q, Hkv, d)
+        return q, k, v, jnp.concatenate([u_tail[0], a_tail[0], v2_tail[0]], axis=-1)

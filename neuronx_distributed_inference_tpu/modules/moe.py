@@ -404,25 +404,28 @@ def carried_mlp_router(
     the norm; the chosen expert (T,) int32)."""
     from neuronx_distributed_inference_tpu.modules.norm import rms_norm
 
-    f32 = jnp.float32
-    hi = jax.lax.Precision.HIGHEST
-    dense = lambda a, name: (
-        jnp.matmul(a, params[name]["weight"].astype(f32), precision=hi)
-        + params[name]["bias"].astype(f32)
-    )
-    gelu = lambda a: jax.nn.gelu(a, approximate=False)
-    down = params["down_proj"]
-    # x and W_d as they are stored: a product of bf16 operands is exact in
-    # the float32 it accumulates in, whatever the precision asked for
-    r = jnp.matmul(x, down["weight"], preferred_element_type=f32,
-                   precision=hi if x.dtype == f32 else None)
-    r = r + down["bias"].astype(f32) + params["gamma"].astype(f32) * carry
-    z = rms_norm(r, params["norm"]["weight"], eps)
-    t = gelu(dense(gelu(dense(z, "fc1")), "fc2"))
-    p = jax.nn.softmax(jnp.matmul(t, params["fc3"]["weight"].astype(f32), precision=hi), axis=-1)
-    choice = jnp.argmax(p + params["balance_bias"].astype(f32), axis=-1).astype(jnp.int32)
-    selected = jax.nn.one_hot(choice, p.shape[-1], dtype=bool)
-    return jnp.where(selected, p, 0.0), selected, r, choice
+    with jax.named_scope("layer.moe.router"):
+        f32 = jnp.float32
+        hi = jax.lax.Precision.HIGHEST
+        dense = lambda a, name: (
+            jnp.matmul(a, params[name]["weight"].astype(f32), precision=hi)
+            + params[name]["bias"].astype(f32)
+        )
+        gelu = lambda a: jax.nn.gelu(a, approximate=False)
+        down = params["down_proj"]
+        # x and W_d as they are stored: a product of bf16 operands is exact in
+        # the float32 it accumulates in, whatever the precision asked for
+        r = jnp.matmul(x, down["weight"], preferred_element_type=f32,
+                       precision=hi if x.dtype == f32 else None)
+        r = r + down["bias"].astype(f32) + params["gamma"].astype(f32) * carry
+        z = rms_norm(r, params["norm"]["weight"], eps)
+        t = gelu(dense(gelu(dense(z, "fc1")), "fc2"))
+        p = jax.nn.softmax(
+            jnp.matmul(t, params["fc3"]["weight"].astype(f32), precision=hi), axis=-1
+        )
+        choice = jnp.argmax(p + params["balance_bias"].astype(f32), axis=-1).astype(jnp.int32)
+        selected = jax.nn.one_hot(choice, p.shape[-1], dtype=bool)
+        return jnp.where(selected, p, 0.0), selected, r, choice
 
 
 def moe_layer(
@@ -446,7 +449,8 @@ def moe_layer(
     x = hidden.reshape(B * S, H)
     n_active = S  # gate on SEQUENCE length: decode (S=1..spec_len) stays
     # dense however large the batch is; prefill buckets/chunks go sparse
-    affinities, selected = router(params, x, spec)  # (T, E) fp32, (T, E) bool
+    with jax.named_scope("layer.moe.router"):
+        affinities, selected = router(params, x, spec)  # (T, E) fp32, (T, E) bool
     # dispatch strategy: decode (tiny T) and EP-sharded experts stay on the
     # dense all-experts path (reference moe_token_gen_all_experts); large-T
     # prefill takes a sparse dispatch — dropless grouped matmuls, or
@@ -502,29 +506,30 @@ def moe_layer(
         use_moe_tkg_kernel,
     )
 
-    if sparse_ok and spec.capacity_factor is not None:
-        out = expert_mlps_capacity(expert_params, x, affinities, spec)
-    elif sparse_ok:
-        out = expert_mlps_grouped(expert_params, x, affinities, spec)
-    elif not prefill_sized and use_moe_tkg_kernel(spec, params["experts"], x.shape[0]):
-        # decode: DMA only the SELECTED experts' weights (k/E of the dense
-        # path's HBM traffic; reference fused MoE TKG kernels, §2.10)
-        from neuronx_distributed_inference_tpu.ops.kernel_mode import (
-            kernel_interpret,
-        )
+    with jax.named_scope("layer.moe.experts"):
+        if sparse_ok and spec.capacity_factor is not None:
+            out = expert_mlps_capacity(expert_params, x, affinities, spec)
+        elif sparse_ok:
+            out = expert_mlps_grouped(expert_params, x, affinities, spec)
+        elif not prefill_sized and use_moe_tkg_kernel(spec, params["experts"], x.shape[0]):
+            # decode: DMA only the SELECTED experts' weights (k/E of the dense
+            # path's HBM traffic; reference fused MoE TKG kernels, §2.10)
+            from neuronx_distributed_inference_tpu.ops.kernel_mode import (
+                kernel_interpret,
+            )
 
-        w_topk, e_topk = jax.lax.top_k(affinities, spec.top_k)
-        out = fused_moe_decode(
-            x, e_topk.astype(jnp.int32), w_topk,
-            params["experts"]["gate_proj"]["weight"],
-            params["experts"]["up_proj"]["weight"],
-            params["experts"]["down_proj"]["weight"],
-            act=spec.act, act_scale=spec.act_scale, act_bias=spec.act_bias,
-            swiglu_limit=spec.swiglu_limit,
-            interpret=kernel_interpret(),
-        )
-    else:
-        out = expert_mlps_dense(expert_params, x, affinities, spec, selected)
+            w_topk, e_topk = jax.lax.top_k(affinities, spec.top_k)
+            out = fused_moe_decode(
+                x, e_topk.astype(jnp.int32), w_topk,
+                params["experts"]["gate_proj"]["weight"],
+                params["experts"]["up_proj"]["weight"],
+                params["experts"]["down_proj"]["weight"],
+                act=spec.act, act_scale=spec.act_scale, act_bias=spec.act_bias,
+                swiglu_limit=spec.swiglu_limit,
+                interpret=kernel_interpret(),
+            )
+        else:
+            out = expert_mlps_dense(expert_params, x, affinities, spec, selected)
     if shared_mlp_fn is not None:
         out = out + shared_mlp_fn(params["shared_experts"], x)
     out = out.reshape(B, S, H).astype(hidden.dtype)

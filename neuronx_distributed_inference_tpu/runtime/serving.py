@@ -205,6 +205,10 @@ class ServingSession:
         deterministically."""
         self.app = app
         self.tel = telemetry if telemetry is not None else default_session()
+        # the (program, q, kv) of the split step's dispatches while the
+        # session records: what its stop() writes the device scope tables of
+        self._programs_noted = set()
+        self.tel.add_scope_source(self)
         tc = app.config.tpu_config
         # --- fault containment (docs/SERVING.md "Failure containment") ----
         self.faults = fault_injector
@@ -1204,8 +1208,10 @@ class ServingSession:
                         slot_mapping=slot_mapping, block_table=block_table,
                     )
 
-                def dispatch(inputs=inputs):
-                    with tel.span("serving.prefill_chunk.dispatch"):
+                fields = self._program_fields("chunk", qb, tkg.last_bucket)
+
+                def dispatch(inputs=inputs, fields=fields):
+                    with tel.span("serving.prefill_chunk.dispatch", **fields):
                         return tkg(self.app.params, self.app.kv_cache, inputs, None)
 
                 out = self._guarded_dispatch(
@@ -1853,12 +1859,13 @@ class ServingSession:
                     last_arr, mask, pos, seq_ids, self._session_sampling_params(),
                     block_table=block_table,
                 )
-            decode_span.note(rows=len(rows), kv_bucket=tkg.last_bucket)
+            decode_span.note(rows=len(rows))
             if block_rows is not None:
                 decode_span.note(denoise_rows=block_rows[0], commit_rows=block_rows[1])
+            fields = self._program_fields("decode", K, tkg.last_bucket)
 
             def dispatch():
-                with tel.span("serving.decode.dispatch"):
+                with tel.span("serving.decode.dispatch", **fields):
                     return tkg(self.app.params, self.app.kv_cache, inputs, None)
 
             out = self._guarded_dispatch("decode", [r for r, _ in rows], dispatch)
@@ -1876,6 +1883,40 @@ class ServingSession:
             # a block row's entry also says which pass of which block it was
             snap = [entry + extra for entry, extra in zip(snap, self.blocks.dispatched(rows))]
         return out, snap
+
+    def _program_fields(self, program: str, q: int, kv: int) -> dict:
+        """What a dispatch span of the split step says AT ENTRY (so it is on
+        the ``TraceAnnotation``): which step program it launches, ``decode``
+        or ``chunk`` by the pass that dispatches, at which query and kv
+        bucket. A recording session also notes the program for
+        :meth:`device_scope_tables`; a stopped one does nothing."""
+        if not self.tel.enabled:
+            return {}
+        q, kv = int(q), int(kv)
+        self._programs_noted.add((program, q, kv))
+        return {"program": program, "q": q, "kv": kv}
+
+    def device_scope_tables(self) -> Dict[str, dict]:
+        """``{"<program>:q<q>:kv<kv>": {"module", "ops": {instruction:
+        scope}}}`` for the step programs dispatched while the session
+        recorded, and forgets them (telemetry/device_scopes.py; what
+        ``TelemetrySession.stop()`` writes beside a trace). The lowering is
+        the one the dispatch compiled: it comes from the compile cache."""
+        from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+        tkg = self.app.token_generation_model
+        # a replica's step thread may be noting while the table is asked for
+        noted = sorted(tuple(self._programs_noted))
+        self._programs_noted.difference_update(noted)
+        tables = {}
+        with tkg.seal_suspended():
+            for program, q, kv in noted:
+                inputs = tkg.example_inputs(kv, q_len=q if program == "chunk" else None)
+                compiled = tkg.trace_program(self.app.params, self.app.kv_cache, inputs, None)[2]
+                tables[device_scopes.table_key(program, q, kv)] = device_scopes.scope_table(
+                    compiled.as_text()
+                )
+        return tables
 
     def _step_ids(self, out):
         """What of a dispatched decode step the session chains on and fetches:

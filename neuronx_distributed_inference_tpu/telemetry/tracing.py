@@ -44,6 +44,7 @@ import re
 import threading
 import time
 import warnings
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -51,6 +52,7 @@ from typing import Dict, List, Optional
 import jax
 
 from neuronx_distributed_inference_tpu.analysis import retrace_guard
+from neuronx_distributed_inference_tpu.telemetry import device_scopes
 from neuronx_distributed_inference_tpu.telemetry import metrics as metrics_mod
 from neuronx_distributed_inference_tpu.telemetry import spans as spans_mod
 
@@ -261,6 +263,9 @@ class TelemetrySession:
         self._max_spans = max_events
         #: directory of the profiler trace this session started (None: none)
         self._profile_dir: Optional[str] = None
+        #: who dispatches step programs (``device_scope_tables()``), held
+        #: weakly: asked once, when a profile this session started stops
+        self._scope_sources = weakref.WeakSet()
         self._span_stack = _SpanStack()
         self.enabled = False
         if enabled:
@@ -635,22 +640,39 @@ class TelemetrySession:
         it and return its trace file (``*.xplane.pb``; None without a
         profile). In-flight request traces are dropped and every open span
         is closed at the stop time, so nothing dangles and a later
-        :meth:`start` treats those requests as admitted while stopped."""
-        trace_path = None
+        :meth:`start` treats those requests as admitted while stopped.
+        Last, beside a trace this call stopped, the device scope tables of
+        the step programs dispatched while recording
+        (:meth:`add_scope_source`): after the profiler, so that the
+        lowerings are in no trace."""
+        trace_path = profile_dir = None
         with self._lock:
             if self._profile_dir is not None:
                 profile_dir, self._profile_dir = self._profile_dir, None
                 jax.profiler.stop_trace()
                 trace_path = newest_xplane(profile_dir)
-            if not self.enabled:
-                return trace_path
-            self.enabled = False
-            if self._listener is not None:
-                retrace_guard.remove_trace_listener(self._listener)
-                self._listener = None
-            self.traces.clear()
-            self.spans.close_all(self.clock(), reason="telemetry_stopped")
+            if self.enabled:
+                self.enabled = False
+                if self._listener is not None:
+                    retrace_guard.remove_trace_listener(self._listener)
+                    self._listener = None
+                self.traces.clear()
+                self.spans.close_all(self.clock(), reason="telemetry_stopped")
+            if profile_dir is not None:
+                tables = {}
+                for source in list(self._scope_sources):
+                    tables.update(source.device_scope_tables())
+                device_scopes.write_tables(profile_dir, tables)
         return trace_path
+
+    def add_scope_source(self, source) -> None:
+        """``source.device_scope_tables() -> {key: table}`` names the device
+        ops of the step programs it dispatched while this session recorded
+        (a serving session registers itself). :meth:`stop` writes them to
+        ``<profile_dir>/device_scopes.json`` when it stops a profile it
+        started (telemetry/device_scopes.py)."""
+        with self._lock:
+            self._scope_sources.add(source)
 
     def close(self) -> None:
         """Release what the session holds outside itself: the retrace

@@ -9,6 +9,12 @@ Rule (ISSUE 21):
   one fixed directory inside the checkout, ``.bench_cache/xla``
   (git-ignored). Never a path made from a temporary name, a pid or the time.
 
+An entry is keyed WITH the program's metadata (``op_name``, source
+positions). JAX's default leaves it out, and an executable taken from the
+cache then carries the metadata of whoever compiled it first: a tree
+without the device scopes (telemetry/device_scopes.py), where two trees share
+a directory. The scope table a recording session writes reads that metadata.
+
 ``load()``/``compile()``, ``benchmark/harness/system.py`` and ``chip_smoke.py`` all call
 :func:`configure_compile_cache`; it is the only ``set_cache_dir`` call site.
 """
@@ -33,6 +39,9 @@ def configure_compile_cache(override: Optional[str] = None) -> str:
     that directory. Idempotent; errors (an unwritable directory, a cache
     already initialised elsewhere) propagate — a run that believes it
     caches and does not is a wrong measurement of compile time."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(ENV_VAR)
     if env:
         return env

@@ -16,6 +16,7 @@ from typing import Callable, Dict, Optional
 
 import jax
 
+from neuronx_distributed_inference_tpu.telemetry import device_scopes
 from neuronx_distributed_inference_tpu.telemetry.tracing import (
     TelemetrySession,
     default_session,
@@ -70,7 +71,11 @@ def summarize_trace(out_dir: str, top: int = 25) -> Dict:
     ``jax.profiler.ProfileData`` (what the benchmark's reduction trusts):
     {"ops": [{"name", "total_us", "count"}...], "total_us": N} over the
     ``XLA Ops`` lines of the device planes; {"trace_dir", "ops": []} when
-    no trace is there."""
+    no trace is there. Where the session that recorded the trace wrote its
+    device scope tables beside it (``device_scopes.json``: a serving session
+    recorded), also ``"by_scope"``: {module: {scope: {"seconds", "share"}}}
+    for the step programs on the first device plane, the ops under the
+    program's own names (telemetry/device_scopes.py; "" = under no scope)."""
     path = newest_xplane(out_dir)
     if path is None:
         return {"trace_dir": out_dir, "ops": []}
@@ -87,7 +92,7 @@ def summarize_trace(out_dir: str, top: int = 25) -> Dict:
             if line.name != "XLA Ops":
                 continue
             for e in line.events:
-                name = e.name.split(" = ", 1)[0].lstrip("%")
+                name = device_scopes.short_name(e.name)
                 rec = ops.setdefault(name, {"name": name, "total_us": 0.0, "count": 0})
                 rec["total_us"] += e.duration_ns / 1e3
                 rec["count"] += 1
@@ -95,7 +100,17 @@ def summarize_trace(out_dir: str, top: int = 25) -> Dict:
     ranked = sorted(ops.values(), key=lambda r: -r["total_us"])[:top]
     for r in ranked:
         r["total_us"] = round(r["total_us"], 1)
-    return {"total_us": round(total_us, 1), "ops": ranked}
+    summary = {"total_us": round(total_us, 1), "ops": ranked}
+    tables = device_scopes.read_tables(out_dir)
+    if tables:
+        summary["by_scope"] = {
+            module: {
+                scope: {"seconds": s, "share": s / (sum(sums.values()) or 1.0)}
+                for scope, s in sorted(sums.items(), key=lambda kv: -kv[1])
+            }
+            for module, sums in device_scopes.time_by_scope(data, tables).items()
+        }
+    return summary
 
 
 def save_summary(summary: Dict, out_path: str):

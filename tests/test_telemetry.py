@@ -1143,7 +1143,10 @@ def test_span_tree_of_the_split_step(paged_app):
     assert [(e["req_id"], e["verdict"]) for e in admits] == [
         ("r0", "admitted"), ("r1", "admitted"), ("r2", "admitted")]
     decode = next(e for e in spans if e["name"] == "serving.decode")
-    assert decode["rows"] >= 1 and decode["kv_bucket"] in paged_app.token_generation_model.buckets
+    assert decode["rows"] >= 1
+    launch = next(e for e in spans if e["name"] == "serving.decode.dispatch")
+    assert launch["program"] == "decode" and launch["q"] == 1
+    assert launch["kv"] in paged_app.token_generation_model.buckets
     snap = tel.registry.snapshot()
     host, wait = (snap[n]["samples"][0] for n in ("nxdi_step_host_ms", "nxdi_step_fetch_wait_ms"))
     assert host["count"] == wait["count"] == len(steps)
