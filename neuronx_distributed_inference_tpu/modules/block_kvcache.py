@@ -13,17 +13,17 @@ dims (Mosaic's (8, 128) divisibility rule would reject a ``(1, d)`` slice over
 a token-major ``(block_size, H_kv, d)`` block for H_kv > 1).
 
 Device side (pure functions used inside the jitted step):
-- writes scatter token K/V through a flat ``slot_mapping`` (block *
+- writes place token K/V through a flat ``slot_mapping`` (block *
   block_size + offset); invalid slots (< 0) are dropped, idle rows' slots
   land in the reserved garbage block 0 (reference's reserved block,
-  block_kv_cache_manager.py:11-80). The scatter has two forms that write the
-  same bytes: the head as an INDEXED dim (window ``(D,)``) at decode and
-  speculation widths, so that the layer scan's cache carry keeps the
-  row-major layout a Pallas operand takes and the step holds no copy of the
-  pool; the head in the WINDOW (``(H, D)``, 8x fewer index rows) for prefill
-  chunks where a device holds at least 8 heads
-  (:func:`update_block_cache_at_layer` says which and why). On a
-  head-sharded mesh the first form runs per shard, each writing its own heads.
+  block_kv_cache_manager.py:11-80). Three forms write the same bytes
+  (:func:`update_block_cache_at_layer` says which and why): WHOLE BLOCKS
+  (window ``(H, bs, D)``) for prefill chunks at a head_dim on the 128 lanes,
+  PER HEAD (window ``(D,)``) at decode and speculation widths, both leaving
+  the layer scan's cache carry in the row-major layout a Pallas operand
+  takes so that the step holds no copy of the pool; a TOKEN WINDOW
+  (``(H, D)``) off the lanes and for the ragged step's packed axis. On a
+  head-sharded mesh the first two run per shard, each writing its own heads.
 - decode reads gather blocks by the per-sequence ``block_table`` and view
   them as a contiguous (B, max_blocks*block_size) cache — logical position
   order is preserved, so the normal decode masks apply unchanged.
@@ -63,10 +63,11 @@ from neuronx_distributed_inference_tpu.parallel.sharding import (
 
 GARBAGE_BLOCK = 0  # block id 0 reserved for invalid-slot writes
 
-#: fewest heads a device must hold for the paged KV write to put the head in
-#: the scatter's WINDOW: the chip's tile is (8, 128), and under a window of
+#: fewest heads a device must hold for the paged KV write to take its TOKEN
+#: WINDOW ``(H, D)``: the chip's tile is (8, 128), and under a window of
 #: fewer heads than sublanes the TPU compiler re-lays the whole pool around
-#: every layer's scatter (update_block_cache_at_layer)
+#: every layer's scatter (update_block_cache_at_layer). Reached at a
+#: head_dim off the lanes and on the ragged step's packed axis
 WINDOW_MIN_HEADS = 8
 
 
@@ -190,6 +191,107 @@ def _batch_sharded() -> bool:
     )
 
 
+def takes_block_form(
+    q_len: int, head_dim: int, *, packed: bool = False, batch_sharded: bool = False
+) -> bool:
+    """Whether :func:`update_block_cache_at_layer` writes a pass of this
+    width whole blocks at a time (its docstring says why and when)."""
+    return (
+        q_len > TKG_MAX_Q_LEN and head_dim % 128 == 0
+        and not packed and not batch_sharded
+    )
+
+
+def chunk_write_blocks(
+    rows, q_len: int, *, block_size: int, head_dim: int, batch_sharded: bool = False
+) -> Tuple[int, int]:
+    """(whole, merged) of a pass ``q_len`` wide over ``rows`` of ``(start,
+    n)``: of the pool blocks that positions ``start .. start + n - 1`` of a
+    sequence touch, those the block form stores as they are and the edge
+    blocks it reads and merges; ``(0, 0)`` where the write does not take the
+    block form. Host code (``ServingSession`` counts it per chunk pass)."""
+    whole = merged = 0
+    if not takes_block_form(q_len, head_dim, batch_sharded=batch_sharded):
+        return whole, merged
+    for start, n in rows:
+        if n <= 0:
+            continue
+        end = start + n
+        inside = max(0, end // block_size - -(-start // block_size))
+        whole += inside
+        merged += -(-end // block_size) - start // block_size - inside
+    return whole, merged
+
+
+def check_block_form_rows(slot_mapping: np.ndarray, block_size: int) -> None:
+    """Raise ``ValueError`` unless every row of a host-built ``slot_mapping``
+    keeps the block form's contract: its valid slots (>= 0) a prefix of the
+    row, at consecutive positions of one sequence (consecutive offsets, one
+    block between two block boundaries). For slot mappings that come from
+    outside the serving path (``TpuModelForCausalLM.forward``)."""
+    slots = np.asarray(slot_mapping)
+    valid = slots >= 0
+    prefix = valid == (np.arange(slots.shape[1])[None, :] < valid.sum(axis=1, keepdims=True))
+    # the next token is the next slot of its block, or opens a block
+    before, after = slots[:, :-1], slots[:, 1:]
+    follows = np.where((before + 1) % block_size != 0, after == before + 1, after % block_size == 0)
+    if not (prefix.all() and follows[valid[:, 1:]].all()):
+        raise ValueError(
+            "slot_mapping: wider than a decode step the paged KV write moves whole "
+            "blocks, so a row's valid slots are a prefix of the row at consecutive "
+            "positions of one sequence (block_kvcache.update_block_cache_at_layer)"
+        )
+
+
+def _row_segments(slot_mapping: jax.Array, bs: int, dropped: int):
+    """A chunk row's write, cut at the pool's block boundaries. The row's
+    valid slots (>= 0) are a prefix of ``n`` tokens at consecutive positions
+    of one sequence, so token ``t`` sits ``(first_off + t) % bs`` into its
+    block and the row touches at most ``ceil(S / bs) + 1`` blocks. Returns
+    ``blocks (B, nseg)``: the pool block of each segment (``slot // bs`` at
+    the segment's first token), ``dropped`` for a segment with no valid
+    token; ``first_off (B,)``: the first token's offset in its block;
+    ``covered (B, nseg, bs)``: the offsets the row's tokens fill."""
+    B, S = slot_mapping.shape
+    nseg = -(-S // bs) + 1
+    n = jnp.sum(slot_mapping >= 0, axis=1)
+    first_off = jnp.where(n > 0, slot_mapping[:, 0] % bs, 0)
+    first = jnp.maximum(jnp.arange(nseg)[None, :] * bs - first_off[:, None], 0)
+    slot_at = jnp.take_along_axis(slot_mapping, jnp.minimum(first, S - 1), axis=1)
+    blocks = jnp.where(first < n[:, None], slot_at // bs, dropped)
+    grid = jnp.arange(nseg * bs)[None, :]
+    covered = (grid >= first_off[:, None]) & (grid < (first_off + n)[:, None])
+    return blocks, first_off, covered.reshape(B, nseg, bs)
+
+
+def _write_blocks(data, new, layer_idx, blocks, first_off, covered):
+    """The block form: ``new (B, S, h, D)`` laid on the rows' block grid
+    ``(B, nseg, h, bs, D)`` (a row's tokens start ``first_off`` into its first
+    segment), merged with what the pool holds at the offsets a row does not
+    cover, and scattered with the whole block ``(h, bs, D)`` in the window:
+    the pool's minor-most dims, so the carry stays row-major. Under a
+    head-sharded mesh ``data`` and ``new`` are one shard's heads."""
+    B, S, h, D = new.shape
+    nseg, bs = covered.shape[1:]
+    padded = jnp.pad(new, ((0, 0), (bs, nseg * bs - S), (0, 0), (0, 0)))
+    laid = jax.vmap(
+        lambda row, off: jax.lax.dynamic_slice_in_dim(row, bs - off, nseg * bs)
+    )(padded, first_off)
+    laid = laid.reshape(B, nseg, bs, h, D).transpose(0, 1, 3, 2, 4)
+    held = data[layer_idx, blocks]
+    merged = jnp.where(covered[:, :, None, :, None], laid, held)
+    return data.at[layer_idx, blocks].set(merged, mode="drop")
+
+
+def _scatter_per_head(data, rows, layer_idx, blocks, offs):
+    """The per-head form: head indexed, window ``(D,)``. Under a head-sharded
+    mesh ``data`` and ``rows (B * S, h, D)`` are one shard's heads."""
+    heads = jnp.arange(data.shape[2])[None, :]
+    return data.at[layer_idx, blocks[:, None], heads, offs[:, None]].set(
+        rows, mode="drop"
+    )
+
+
 def update_block_cache_at_layer(
     k_cache: jax.Array,  # (L, NB+1, H, bs, D)
     v_cache: jax.Array,
@@ -199,7 +301,7 @@ def update_block_cache_at_layer(
     slot_mapping: jax.Array,  # (B, S) global slots; < 0 -> garbage block
     packed: bool = False,  # the rows are the mixed step's ONE packed token axis
 ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter token K/V into the paged cache at one layer (reference
+    """Write token K/V into the paged cache at one layer (reference
     scatter-by-slot, block_kv_cache_manager.py). The full stacked cache is
     carried through the layer scan and updated in place (see
     kvcache.update_cache_at_layer for why). Negative slots are DROPPED by
@@ -207,100 +309,89 @@ def update_block_cache_at_layer(
     out-of-range indices; -1 would WRAP to the last real block and corrupt
     it) — same net effect as the reference's garbage-block writes.
 
-    Which scatter, and why. The TPU compiler lays a scatter's operand out
-    with the update WINDOW's dims minor-most, and the layer scan's cache
-    carry takes that layout. The paged kernels that read the cache are Pallas
-    custom calls, which take only the default row-major layout. With the
-    head in the window (``(H, D)``: token-major ``{4,2,3,1,0}``) whatever a
-    kernel reads is relaid first: the WHOLE stacked pool once per layer
-    where the kernel takes the stacked cache (paged TKG decode, and paged
-    flash at head_dim 128, which copy their blocks by hand out of it), one
-    layer's slice where it takes a slice (ragged; paged flash at head_dim
-    64), and the pool twice more at the program's entry and exit either way.
-    With the head an INDEXED dim (window ``(D,)``, already minor-most in the
-    head-major layout) the carry stays row-major and nothing is relaid, but
-    the scatter has H times the index rows, and on a v5e a row costs ~70 ns
-    whatever its width. So the form is selected here and nowhere else, on
-    the static ``S`` of ``slot_mapping``, on ``D`` and on the heads ONE
-    device holds (``H`` over the ambient mesh's head shards):
+    Three forms write the same bytes. What decides between them: the TPU
+    compiler lays a scatter's operand out with the update WINDOW's dims
+    minor-most, the layer scan's cache carry takes that layout, and the
+    paged kernels (Pallas custom calls) read the stacked pool row-major; a
+    window that is not the pool's own minor-most dims has the WHOLE pool
+    relaid once a layer and twice more at the program's entry and exit
+    (328 of a 375 ms decode dispatch: PERF.md PR 24). And on a v5e an index
+    row of a scatter costs ~70 ns whatever its width. The form is chosen
+    here and nowhere else, on what the call shows: the static ``S`` of
+    ``slot_mapping``, ``D``, ``packed``, the heads ONE device holds.
 
-    * ``S <= TKG_MAX_Q_LEN`` (16: decode and speculation widths, the widths
-      the stacked-cache decode kernel serves) — per-head form. The served
-      Qwen3-1.7B decode step (48 rows, 28 layers, pool 2 x 1.94 GB) compiles
-      to 0 pool-shaped copies and 0 GB of temporaries against 6 and 3.89 GB,
-      and runs in 56 ms against 406 ms (v5e, kv bucket 1024, PERF.md PR 24).
-    * ``D`` a multiple of the 128 lanes, at every ``S`` — per-head form:
-      the prefill chunk's kernel reads the stacked pool too (PR 41). The
-      chunk program is 8 rows wide (``ops/kernel_mode.CHUNK_ROWS``), so a
-      chunk of 128 tokens is 8 x 128 x 8 index rows a stream a layer: on the
-      1.7B the write reads 32 ms a dispatch where the window form read 4,
-      but the window form's four entry/exit pool copies (23 ms) and the
-      layer's slice relaid twice a layer are gone, and the program plans
-      7.69 GiB where it planned 9.33 (v5e: a 28-layer scan of write + kernel
-      read 46.1 ms per-head against 52.6 in the window form, PERF.md PR 41).
-      When the program was as wide as the slot count (48 rows) the window
-      form won: 153 / 187 / 303 ms at q 32 / 64 / 128 against 170 / 246 /
-      445 ms per-head (PERF.md PR 24).
-    * ``S > TKG_MAX_Q_LEN`` at a ``D`` off the lanes (64: Llama-3.2-1B,
-      granite), at least ``WINDOW_MIN_HEADS`` heads a device — window form:
-      there the chip's own layout of the pool is not row-major in either
-      form, and the chunk's kernel takes a layer's slice.
-    * Fewer heads a device than the tile has sublanes (Qwen3-14B at tp = 4:
-      2 of 8) — per-head form at every ``S``. Under a window of 2 or 4 heads
-      the compiler re-lays the whole pool FOUR times around every layer's
-      scatter, sharded or not (described-chip compiles at 1 / 2 / 4 / 8 / 16
-      heads, bf16 and int8, PERF.md PR 33: none from 8 heads up); on the
-      chip the tp = 4 chunk dispatch read ~360 ms with it where native
-      attention had read ~130. And the rows are few: 8 x 128 tokens x 2
-      heads.
-    * The ragged mixed step says ``packed``: its ``S`` is the packed token
-      axis of every row and its kernel takes a layer's slice, so it skips
-      the tests on ``S`` and ``D`` (one form at every packed width keeps its
-      bucket programs one structure: analysis/graph_audit GRAPH205).
-    * Head axis sharded (``block_cache_spec`` over a tp/ep/cp > 1 mesh) —
-      the same selection: the paged kernels run there too, once per head
-      shard (``parallel/sharding.shard_over_heads``), and demand the same
-      layout of each shard's slice of the pool. The per-head form INDEXES
-      the sharded dim, which leaves GSPMD free to gather operand and updates
-      (two all-gathers a scatter when nothing else pinned the carry), so it
-      runs under the same ``shard_over_heads``: each shard writes its own
-      ``H / degree`` heads by shard-local head index, the token indices are
-      replicated, and no collective can appear. The window form has the head
-      in the window and partitions as it stands. Only where the BATCH is
-      sharded around the attention (attention-DP; no kernel runs there) does
-      the window form serve every call.
+    * WHOLE BLOCKS (window ``(H, bs, D)``, the pool's minor-most dims) at
+      ``S > TKG_MAX_Q_LEN`` and ``D`` on the 128 lanes: prefill chunks and the
+      whole-prompt paged prefill. **The rows' contract**: a row's valid slots
+      (>= 0) are a PREFIX of the row, at consecutive positions of one
+      sequence. Every caller that reaches this width without ``packed``
+      passes such rows (``ServingSession._prefill_chunks`` and
+      ``._full_prefill``, held by tests/test_block_kv.py; the benchmark's
+      probe); speculation widths, token trees among them, stay at or under
+      ``TKG_MAX_Q_LEN`` and the ragged mixed step says ``packed``. A row then
+      touches ``S / bs + 1`` blocks at most: :func:`_write_blocks` gathers
+      them, merges a row's first and last block with what the pool holds at
+      the offsets the row does not cover, and scatters 8 x 5 index rows a
+      stream a layer where a (token, head) row a write has 8 x 128 x 8. On
+      the 1.7B's pool a 28-layer scan of K and V reads 1.65 ms against 31.8
+      (v5e, PERF.md PR 43), at 1 live row of 8 as at 8.
+    * PER HEAD (window ``(D,)``, the head an indexed dim: minor-most
+      already, H times the index rows) at ``S <= TKG_MAX_Q_LEN`` (decode and
+      speculation widths, 48 rows: 2.3 ms a dispatch on the 1.7B, where the
+      block form moves 64 KB to place 2 KB and reads 2.9), and wherever a
+      device holds fewer than ``WINDOW_MIN_HEADS`` heads.
+    * TOKEN WINDOW (window ``(H, D)``, one index row a token, the carry
+      token-major) at a ``D`` off the lanes (64: Llama-3.2-1B, granite: the
+      chip's own layout of that pool is not row-major in any form, and the
+      chunk's kernel takes a layer's slice), for the ragged mixed step's
+      ``packed`` token axis (its kernel takes a layer's slice too; one form
+      at every packed width keeps its bucket programs one structure:
+      analysis/graph_audit GRAPH205), and wherever the BATCH is sharded
+      around the attention (attention-DP: no kernel runs there). Under a
+      window of fewer heads than the tile has sublanes the compiler re-lays
+      the whole pool FOUR times around every layer's scatter (PERF.md
+      PR 33), hence ``WINDOW_MIN_HEADS``.
 
-    Quantized caches quantize fused into this scatter with the running
+    On a head-sharded mesh (``block_cache_spec`` over tp/ep/cp > 1) the first
+    two forms run once per head shard (``parallel/sharding.shard_over_heads``,
+    as the paged kernels do): each shard writes its own ``H / degree`` heads,
+    the indices are replicated, and no collective can appear (an INDEXED
+    sharded dim leaves GSPMD free to gather operand and updates). The token
+    window has the head in the window and partitions as it stands.
+
+    Quantized caches quantize fused into this write with the running
     per-(layer, head) absmax (see kvcache.update_cache_at_layer); invalid
     (garbage) slots are excluded from the scale update. The code streams are
-    written by the same scatter; the scales never pass through it."""
+    written in the same form; the scales never pass through it."""
     L, NB1, H, bs, D = k_cache.shape
     B, S = slot_mapping.shape
-    slots = slot_mapping.reshape(B * S)
-    blocks = jnp.where(slots >= 0, slots // bs, NB1)
-    offs = jnp.where(slots >= 0, slots % bs, 0)
-    per_head = not _batch_sharded() and (
+    batch_sharded = _batch_sharded()
+    per_head = not batch_sharded and (
         H // head_shard_degree() < WINDOW_MIN_HEADS
-        or ((S <= TKG_MAX_Q_LEN or D % 128 == 0) and not packed)
+        or (S <= TKG_MAX_Q_LEN and not packed)
     )
+    if takes_block_form(S, D, packed=packed, batch_sharded=batch_sharded):
+        segments = _row_segments(slot_mapping, bs, NB1)
 
-    def scatter_per_head(data, rows, layer_idx, blocks, offs):
-        # head indexed, window (D,): the carry stays row-major. Under a
-        # head-sharded mesh ``data`` and ``rows`` are one shard's heads
-        heads = jnp.arange(data.shape[2])[None, :]
-        return data.at[layer_idx, blocks[:, None], heads, offs[:, None]].set(
-            rows, mode="drop"
-        )
-
-    def write(data, new):
-        rows = new.reshape(B * S, H, D).astype(data.dtype)
-        if per_head:
+        def write(data, new):
             return shard_over_heads(
-                scatter_per_head, (data, rows, layer_idx, blocks, offs),
-                in_heads=(2, 1, None, None, None), out_heads=2,
+                _write_blocks, (data, new.astype(data.dtype), layer_idx, *segments),
+                in_heads=(2, 2, None, None, None, None), out_heads=2,
             )
-        # window (H, D): one index row per token, the carry token-major
-        return data.at[layer_idx, blocks, :, offs].set(rows, mode="drop")
+    else:
+        slots = slot_mapping.reshape(B * S)
+        blocks = jnp.where(slots >= 0, slots // bs, NB1)
+        offs = jnp.where(slots >= 0, slots % bs, 0)
+
+        def write(data, new):
+            rows = new.reshape(B * S, H, D).astype(data.dtype)
+            if per_head:
+                return shard_over_heads(
+                    _scatter_per_head, (data, rows, layer_idx, blocks, offs),
+                    in_heads=(2, 1, None, None, None), out_heads=2,
+                )
+            # window (H, D): one index row per token, the carry token-major
+            return data.at[layer_idx, blocks, :, offs].set(rows, mode="drop")
 
     if isinstance(k_cache, QuantizedKV):
         # scale-update mask: negative (dropped) slots AND garbage-block

@@ -161,8 +161,8 @@ def use_packed(spec) -> bool:
 
 
 #: widest query the TKG decode kernels serve (decode and speculation widths).
-#: The paged one reads the STACKED block cache, so up to this width the paged
-#: KV write keeps the scan's cache carry in the kernel's layout
+#: Up to this width the paged KV write goes a (token, head) row at a time,
+#: above it, on the 128 lanes, a whole pool block at a time
 #: (modules/block_kvcache.update_block_cache_at_layer selects on it).
 TKG_MAX_Q_LEN = 16
 

@@ -828,7 +828,10 @@ class TpuModelForCausalLM:
         ``input_ids``/``position_ids``: (B, S). ``seq_ids``: (B,) cache-line
         ids; -1 marks an inactive row (writes land in the garbage line).
         ``slot_mapping``: (B, S) flat block-cache write slots for prefill on
-        the paged cache (-1 drops the write); decode on the paged cache
+        the paged cache (-1 drops the write; wider than a decode step the
+        write moves whole blocks, so a row's valid slots are a prefix of the
+        row at consecutive positions of one sequence, else ``ValueError``:
+        block_kvcache.update_block_cache_at_layer); decode on the paged cache
         derives slots in-graph from ``block_table`` (B, max_blocks), exactly
         like the serving path. ``attention_mask``: (B, width) cache
         occupancy; defaults to "everything up to the max position".
@@ -881,6 +884,14 @@ class TpuModelForCausalLM:
                         "puts them: the slot mapping given names other slots"
                     )
                 slot_mapping = None
+        if slot_mapping is not None:
+            from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+                check_block_form_rows,
+                takes_block_form,
+            )
+
+            if takes_block_form(S, self.spec.attn.head_dim):
+                check_block_form_rows(slot_mapping, self.config.tpu_config.pa_block_size)
         R = runner.chunk_rows
         if runner.is_paged_chunk(slot_mapping, block_table) and B > R:
             per_row = [input_ids, position_ids, seq_ids, attention_mask, sampling_params,

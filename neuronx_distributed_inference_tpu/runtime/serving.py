@@ -245,6 +245,7 @@ class ServingSession:
             from neuronx_distributed_inference_tpu.modules.block_kvcache import (
                 BlockAllocator,
                 PrefixCachingAllocator,
+                chunk_write_blocks,
                 kv_block_bytes,
             )
 
@@ -287,6 +288,12 @@ class ServingSession:
             )
             self._chunk_kv_blocks_walked = functools.partial(
                 paged_flash_attention.kv_blocks_walked, **shape
+            )
+            # and what the paged KV write of a chunk pass moves, where it
+            # moves whole blocks
+            self._chunk_write_blocks = functools.partial(
+                chunk_write_blocks, block_size=pool.shape[3], head_dim=pool.shape[4],
+                batch_sharded=app.spec.attention_dp * app.spec.data_parallel > 1,
             )
         # async 1-ahead decode (reference modules/async_execution.py:190):
         # the decode step dispatched last step(), not yet fetched —
@@ -1238,6 +1245,9 @@ class ServingSession:
                 # the blocks a row's causal context holds once this chunk is in
                 live = [-(-(r.prefill_pos + n) // bs) for r, n in ran]
                 kv_blocks = (sum(live), self._chunk_kv_blocks_walked(live, mb))
+                tel.kv_write_blocks(
+                    *self._chunk_write_blocks([(r.prefill_pos, n) for r, n in ran], qb)
+                )
             self._count_pass(
                 "chunk", len(ran), ran_real, len(flights),
                 resets=sum(1 for r, _ in ran if r.prefill_pos == 0),

@@ -424,6 +424,14 @@ class TelemetrySession:
             "to a row's frontier; every entry of the table where the kernel "
             "keeps a block a grid step). walked / live = how far the walk is "
             "from the context", labels=("kind",))
+        self._chunk_kv_write_blocks = r.counter(
+            "nxdi_chunk_kv_write_blocks_total",
+            "pool blocks the paged KV write of a chunk pass moved, where it "
+            "moves whole blocks (modules/block_kvcache: chunk widths, head_dim "
+            "on the 128 lanes): kind=whole, blocks a row's tokens cover and "
+            "the write stores as they are; kind=merged, a row's first or last "
+            "block that it read and merged with what the pool held. Counted a "
+            "layer and a stream once", labels=("kind",))
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -1177,6 +1185,14 @@ class TelemetrySession:
         counter = self._decode_kv_blocks if program == "decode" else self._chunk_kv_blocks
         counter.child(("live",)).inc(live)
         counter.child(("walked",)).inc(walked)
+
+    def kv_write_blocks(self, whole: int, merged: int) -> None:
+        """One chunk pass whose paged KV write moved whole blocks: the blocks
+        it stored as they are, and the edge blocks it read and merged."""
+        if not self.enabled:
+            return
+        self._chunk_kv_write_blocks.child(("whole",)).inc(whole)
+        self._chunk_kv_write_blocks.child(("merged",)).inc(merged)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

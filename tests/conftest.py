@@ -171,3 +171,34 @@ def drain(session, limit=200):
             return
         session.step()
     raise AssertionError("the session did not drain")
+
+
+def paged_write_instructions(text: str, pool_shape, rows: int, segments: int) -> list:
+    """The instructions of a compiled step program's text that hold the
+    block form of the paged KV write (modules/block_kvcache._write_blocks):
+    a scatter into the stacked pool, or the gather of the ``rows x
+    segments`` whole pool blocks ``(H, bs, D)`` the rows touch, bare or as
+    the fusion the compiler put it in."""
+    import re
+
+    held_by = {}  # computation -> the kinds of op it holds
+    for comp in re.split(r"\n(?=%|ENTRY )", text):
+        held_by[comp.split(" ", 1)[0].lstrip("%")] = {
+            kind for kind in ("scatter", "gather") if f" {kind}(" in comp
+        }
+    pool = ",".join(str(d) for d in pool_shape)
+    block = ",".join(str(d) for d in pool_shape[2:])
+    gathered = rows * segments * int(np.prod(pool_shape[2:]))
+    found = []
+    for name, shape, opcode, rest in re.findall(
+        r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* (fusion|scatter|gather)\((.*)", text
+    ):
+        kinds = {opcode}
+        if opcode == "fusion":
+            kinds = held_by[re.search(r"calls=%([\w.\-]+)", rest).group(1)]
+        if ("scatter" in kinds and shape == pool) or (
+            "gather" in kinds and shape.endswith(block)
+            and np.prod([int(d) for d in shape.split(",")]) == gathered
+        ):
+            found.append(name)
+    return found

@@ -52,20 +52,10 @@ def test_update_drops_negative_slots():
     np.testing.assert_array_equal(k_up, k0)  # NOTHING else (esp. last block)
 
 
-def _write_case(dtype, S, seed=0):
-    """A 3-layer cache with junk in it, a (B, S) write whose slots cover every
-    kind: real blocks, the garbage block (slot >= 0 inside block 0), negative
-    (dropped), and the LAST block's last offset (where a wrapped -1 would
-    land)."""
+def _with_junk(cache, rng):
+    """The cache with junk in every stream (and plausible running scales)."""
     import jax
     import jax.numpy as jnp
-
-    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-        init_block_cache,
-    )
-    L, NB, bs, H, D, B = 3, 12, 32, 4, 16, 3
-    rng = np.random.default_rng(seed)
-    cache = init_block_cache(L, NB, bs, H, D, dtype=dtype)
 
     def junk(x):
         if x.ndim == 2:  # (L, H) running scales
@@ -74,7 +64,22 @@ def _write_case(dtype, S, seed=0):
             return jnp.asarray(rng.integers(-100, 100, x.shape), x.dtype)
         return jnp.asarray(rng.normal(size=x.shape), jnp.float32).astype(x.dtype)
 
-    cache = jax.tree.map(junk, cache)
+    return jax.tree.map(junk, cache)
+
+
+def _write_case(dtype, S, seed=0):
+    """A 3-layer cache with junk in it, a (B, S) write whose slots cover every
+    kind: real blocks, the garbage block (slot >= 0 inside block 0), negative
+    (dropped), and the LAST block's last offset (where a wrapped -1 would
+    land)."""
+    import jax.numpy as jnp
+
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        init_block_cache,
+    )
+    L, NB, bs, H, D, B = 3, 12, 32, 4, 16, 3
+    rng = np.random.default_rng(seed)
+    cache = _with_junk(init_block_cache(L, NB, bs, H, D, dtype=dtype), rng)
     slots = np.full((B, S), -1, np.int64)
     n = max(1, (3 * S) // 4)  # the tail of every row stays negative
     slots[0, :n] = 2 * bs + 5 + np.arange(n)  # real blocks, crossing borders
@@ -108,15 +113,60 @@ def _bits(x):
     return x.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
-@pytest.mark.parametrize("S", [1, 128], ids=["decode_S1", "chunk_S128"])
+def _chunk_write_case(dtype, S, H, seed=0):
+    """A chunk pass as the serving path builds it, at the pool's real block
+    shape (blocks of 32 x 128 lanes): 8 rows of ``S`` tokens, each row the
+    consecutive positions ``start .. start + n - 1`` of its own sequence
+    whose blocks lie anywhere in the pool, the tail of the row negative.
+    The rows cover: a whole chunk from a block boundary; a start 8 tokens
+    into a block that earlier tokens part filled, ending mid-block; one
+    token at a block's last offset; ``n`` = 0; an idle row INTO the garbage
+    block; a whole chunk from an offset (one block more than ``S / bs``);
+    100 tokens from a boundary; a row that ends on the pool's last slot."""
+    import jax.numpy as jnp
+
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        init_block_cache,
+    )
+    L, NB, bs, D, B = 3, 40, 32, 128, 8
+    rng = np.random.default_rng(seed)
+    cache = _with_junk(init_block_cache(L, NB, bs, H, D, dtype=dtype), rng)
+    free = list(rng.permutation(np.arange(1, NB)))  # block NB is row 7's last
+    slots = np.full((B, S), -1, np.int64)
+    rows = {0: (0, S), 1: (40, min(100, S)), 2: (95, 1), 3: (64, 0),
+            5: (17, S), 6: (32, min(100, S)), 7: (5 * bs - min(40, S), min(40, S))}
+    for row, (start, n) in rows.items():
+        blocks = [free.pop() for _ in range(-(-(start + n) // bs))]
+        if row == 7:
+            blocks[-1] = NB
+        pos = start + np.arange(n)
+        slots[row, :n] = np.asarray(blocks, np.int64)[pos // bs] * bs + pos % bs
+    slots[4, : min(S, bs)] = np.arange(min(S, bs))  # block 0, each offset once
+    k_new = jnp.asarray(rng.normal(size=(B, S, H, D)) * 2.0, jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(B, S, H, D)) * 0.5, jnp.float32)
+    return cache, jnp.asarray(slots, jnp.int32), k_new, v_new
+
+
+WRITE_CASES = {
+    "decode_S1": lambda dt: _write_case(dt, 1),
+    "chunk_S128": lambda dt: _write_case(dt, 128),
+    "blocks_8x128_H8": lambda dt: _chunk_write_case(dt, 128, 8),
+    "blocks_8x32_H8": lambda dt: _chunk_write_case(dt, 32, 8),
+    "blocks_8x128_H4": lambda dt: _chunk_write_case(dt, 128, 4),
+    "blocks_8x128_H2": lambda dt: _chunk_write_case(dt, 128, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(WRITE_CASES))
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8", "fp8"])
-def test_paged_write_matches_plain_loop(dtype, S):
-    """Both scatter forms of update_block_cache_at_layer (per-head at S=1,
-    token window at S=128) against a NumPy loop, BIT-equal: real slots,
-    garbage-block slots, negative slots dropped, last block intact. A
-    quantized cache's codes are placed the same way and its scales are
-    exactly what the shared quantizer returns — the write never touches
-    them."""
+def test_paged_write_matches_plain_loop(dtype, case):
+    """Every form of update_block_cache_at_layer against a NumPy loop, the
+    WHOLE pool BIT-equal (per-head scatter at S=1, token window at S=128 off
+    the lanes, whole blocks at chunk widths on them): real slots,
+    garbage-block slots, negative slots dropped, untouched offsets of a
+    part-written block and the last block intact. A quantized cache's codes
+    are placed the same way and its scales are exactly what the shared
+    quantizer returns — the write never touches them."""
     import jax.numpy as jnp
 
     from neuronx_distributed_inference_tpu.config import to_dtype
@@ -129,7 +179,7 @@ def test_paged_write_matches_plain_loop(dtype, S):
         _quantized_update,
     )
 
-    cache, slots, k_new, v_new = _write_case(to_dtype(dtype), S)
+    cache, slots, k_new, v_new = WRITE_CASES[case](to_dtype(dtype))
     layer = 1
     k_up, v_up = update_block_cache_at_layer(
         cache.k, cache.v, k_new, v_new, jnp.int32(layer), slots
@@ -151,12 +201,13 @@ def test_paged_write_matches_plain_loop(dtype, S):
         np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
-@pytest.mark.parametrize("S", [1, 128], ids=["decode_S1", "chunk_S128"])
-def test_paged_write_on_head_sharded_cache_has_no_collective(S):
+@pytest.mark.parametrize("case", ["decode_S1", "chunk_S128", "blocks_8x128_H8"])
+def test_paged_write_on_head_sharded_cache_has_no_collective(case):
     """tp=4 with the cache head-sharded (block_cache_spec): the compiled
-    write holds NO collective at either width — each shard writes its own
-    heads (the window form is kept there; with the head in the scatter's
-    indices GSPMD gathers the updates) — and writes what the loop writes."""
+    write holds NO collective in any form — each shard writes its own heads
+    (per-head and whole-block forms run per shard; with the head in a
+    scatter's indices GSPMD gathers the updates) — and writes what the loop
+    writes."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -167,7 +218,7 @@ def test_paged_write_on_head_sharded_cache_has_no_collective(S):
     )
     from neuronx_distributed_inference_tpu.parallel.mesh import MODEL_AXES, build_mesh
 
-    cache, slots, k_new, v_new = _write_case(jnp.bfloat16, S)
+    cache, slots, k_new, v_new = WRITE_CASES[case](jnp.bfloat16)
     mesh = build_mesh(tp_degree=4)
     spec = block_cache_spec()
     cache_sh = NamedSharding(mesh, spec.k)
@@ -187,6 +238,94 @@ def test_paged_write_on_head_sharded_cache_has_no_collective(S):
         assert op not in hlo, op
     want = _loop_write(cache.k, k_new.astype(jnp.bfloat16), 1, np.asarray(slots))
     np.testing.assert_array_equal(_bits(k_up), _bits(want))
+
+
+def test_serving_rows_are_consecutive_positions_of_one_sequence(monkeypatch):
+    """The contract of the paged write's block form
+    (update_block_cache_at_layer): every row a ServingSession hands a step
+    program wider than a decode step — a chunk pass (``_prefill_chunks``,
+    prefix reuse included) and the whole-prompt paged prefill
+    (``_full_prefill``) — has its valid slots as a PREFIX of the row, at
+    consecutive positions of one sequence: slot ``block * bs + position %
+    bs``, the block constant inside a block of positions."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.runtime.model_runner import SubModelRunner
+
+    seen = []
+    prepare = SubModelRunner.prepare
+
+    def spy(self, *args, **kwargs):
+        inputs, bucket = prepare(self, *args, **kwargs)
+        if inputs.slot_mapping is not None and inputs.slot_mapping.shape[1] > 1:
+            seen.append((np.asarray(inputs.position_ids), np.asarray(inputs.slot_mapping)))
+        return inputs, bucket
+
+    monkeypatch.setattr(SubModelRunner, "prepare", spy)
+    bs = 16
+    paged = dict(is_continuous_batching=True, batch_size=2, ctx_batch_size=1,
+                 is_block_kv_layout=True, pa_block_size=bs, pa_num_blocks=24)
+    chunked = dict(paged, is_chunked_prefill=True, is_prefix_caching=True, seq_len=128,
+                   chunked_prefill_config=ChunkedPrefillConfig(
+                       max_num_seqs=2, kernel_q_tile_size=32))
+    long_prompt = [(7 * i) % 90 + 3 for i in range(75)]
+    widths = []
+    for tpu, prompts in ((chunked, [long_prompt, long_prompt[:24] + [5, 6, 7] * 9]),
+                         (paged, [long_prompt[:41]])):
+        cfg = make_tiny_config(tpu=tpu)
+        app = TpuModelForCausalLM(None, cfg).load(state_dict=make_random_hf_state_dict(cfg))
+        sess = ServingSession(app)
+        for i, prompt in enumerate(prompts):
+            assert sess.add_request(f"r{i}", prompt, max_new_tokens=2)
+            sess.run_to_completion()
+        assert seen
+        for positions, slots in seen:
+            widths.append(slots.shape[1])
+            for pos, row in zip(positions, slots):
+                n = int((row >= 0).sum())
+                assert (row[:n] >= 0).all() and (row[n:] < 0).all()  # a prefix
+                np.testing.assert_array_equal(pos[:n], pos[0] + np.arange(n))
+                np.testing.assert_array_equal(row[:n] % bs, pos[:n] % bs)
+                blocks = dict(zip(pos[:n] // bs, row[:n] // bs))  # one block a block of positions
+                np.testing.assert_array_equal(row[:n] // bs, [blocks[p // bs] for p in pos[:n]])
+        seen.clear()
+    # chunk passes of 32 (the second prompt resumes at 16, past the block it
+    # shares with the first) and a context program over the whole prompt
+    assert 32 in widths and max(widths) >= 64
+
+
+def test_forward_refuses_rows_the_block_form_cannot_write():
+    """A slot mapping handed to the public ``forward`` at a width the write
+    takes a block at a time (more than 16 tokens a row, head_dim on the 128
+    lanes) is checked on the host: a hole in a row, slots out of order or a
+    block that changes between two block boundaries raise ``ValueError``
+    before anything is dispatched; the rows the serving path builds pass."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        check_block_form_rows,
+    )
+
+    _, slots, _, _ = _chunk_write_case("bfloat16", 128, 2)
+    slots = np.asarray(slots)
+    check_block_form_rows(slots, 32)
+    hole, swapped, moved = slots.copy(), slots.copy(), slots.copy()
+    hole[0, 3] = -1
+    swapped[0, [1, 2]] = swapped[0, [2, 1]]
+    moved[5, 20] += 3 * 32  # row 5 starts 17 into a block: token 15 opened this one
+    for bad in (hole, swapped, moved):
+        with pytest.raises(ValueError, match="consecutive positions"):
+            check_block_form_rows(bad, 32)
+
+    cfg = make_tiny_config(
+        hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
+        tpu=dict(is_continuous_batching=True, batch_size=2, ctx_batch_size=1,
+                 is_block_kv_layout=True, pa_block_size=32, pa_num_blocks=8, seq_len=128),
+    )
+    app = TpuModelForCausalLM(None, cfg).load(state_dict=make_random_hf_state_dict(cfg))
+    ids = np.arange(1, 41, dtype=np.int32)[None, :]
+    pos = np.arange(40, dtype=np.int32)[None, :]
+    sm = 32 + np.arange(40, dtype=np.int32)[None, :]
+    sm[0, 7] = -1
+    with pytest.raises(ValueError, match="consecutive positions"):
+        app.forward(ids, pos, np.zeros(1, np.int32), slot_mapping=sm, phase="cte")
 
 
 def _session_apps():

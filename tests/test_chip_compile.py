@@ -264,21 +264,66 @@ def _planned_bytes(compiled) -> int:
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
 
 
+def _scatter_index_rows(compiled):
+    """Index rows of every ``scatter`` in the optimized HLO: the elements of
+    its indices operand over the length of an index vector. The per-head
+    paged KV write has ``B * S * H`` of them, the block form a row's few
+    blocks."""
+    text = compiled.as_text()
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    rows = []
+    for indices, vector_dim in re.findall(
+        r" scatter\(%[\w.\-]+, %([\w.\-]+), .*?index_vector_dim=(\d+)", text
+    ):
+        dims = [int(d) for d in shapes[indices].split(",") if d]
+        vector = dims[int(vector_dim)] if int(vector_dim) < len(dims) else 1
+        rows.append(int(np.prod(dims)) // vector)
+    return rows
+
+
+def _assert_chunk_write_moves_blocks(compiled, rows, q, heads, pool_shape):
+    """The chunk program's paged KV write in its block form
+    (modules/block_kvcache._write_blocks): no scatter walks an index row a
+    (token, head) — each of the two has ``rows x (q / bs + 1)`` — and the
+    instructions that hold the write's scatters and its gathers of pool
+    blocks are under ``layer.kv_write`` in the program's scope table
+    (telemetry/device_scopes), which is what ``chunk.kv_write_dev_ms`` sums."""
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    from tests.conftest import paged_write_instructions
+
+    bs = pool_shape[3]
+    index_rows = _scatter_index_rows(compiled)
+    assert rows * q * heads not in index_rows
+    assert index_rows.count(rows * (q // bs + 1)) == 2  # K and V
+    text = compiled.as_text()
+    table = device_scopes.scope_table(text)["ops"]
+    segments = q // bs + 1
+    writes = [
+        n for n in paged_write_instructions(text, pool_shape, rows, segments) if n in table
+    ]
+    assert len(writes) >= 4  # K and V: a gather of the held blocks, a scatter
+    assert {table[name] for name in writes} == {"layer.kv_write"}
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
     """The paged KV write leaves the layer scan's cache carry in the layout
     the kernel reads (modules/block_kvcache.update_block_cache_at_layer), at
     the benchmark's widths: 48 slots, 1056 blocks x 32 tokens, 28 layers.
 
-    Both programs (per-head scatter at head_dim 128, whatever the width of
-    the pass): NO pool-shaped copy anywhere — with the head in the scatter's
-    window the decode program held 6 (two per layer in the scan, two at
-    entry, two at exit) and 3.89 GB of temporaries, and the chunk program
-    (CHUNK_ROWS x 128 = 8 x 128 whatever the slot count, its rows addressed
-    by slot) the entry/exit pair for K and for V and a layer's slice relaid
-    for the kernel in every layer. chunk plans 7.69 GiB where it planned
-    9.33 with the window scatter (and 11.17 at 48 rows): two VMEM slots of
-    a group of blocks cost the device's memory nothing."""
+    Both programs: NO pool-shaped copy anywhere. decode (48 x 1) writes by
+    the per-head scatter (window ``(D,)``); with the head in the scatter's
+    window it held 6 copies (two per layer in the scan, two at entry, two at
+    exit) and 3.89 GB of temporaries. chunk (CHUNK_ROWS x 128 = 8 x 128
+    whatever the slot count, its rows addressed by slot) writes whole blocks
+    (window ``(H, bs, D)``, the pool's minor-most dims: 40 index rows a
+    stream a layer where the per-head form had 8192); with the token window
+    ``(H, D)`` it held the entry/exit pair for K and for V and a layer's
+    slice relaid for the kernel in every layer. chunk plans 7.69 GiB where
+    it planned 9.33 with the window scatter (and 11.17 at 48 rows): a row's
+    gathered blocks are 2.5 MB and two VMEM slots of a group of blocks cost
+    the device's memory nothing."""
     from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
 
     app, params, cache = _abstract_app(
@@ -308,6 +353,7 @@ def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
         assert outside == 0
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
         assert _planned_bytes(compiled) < 7.9 * 2**30
+        _assert_chunk_write_moves_blocks(compiled, 8, 128, 8, cache.k.shape)
 
 
 # Qwen3-14B's geometry (40/8 heads of 128, 40 layers, hidden 5120, vocab
@@ -332,20 +378,21 @@ def _cache_gathers(compiled, pool_shape):
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_tp4_paged_serving_step_runs_its_kernels_per_shard(chip_mesh, program):
     """The four-chip cell's two step programs (qwen3-14b-tp4.chat) on a 2x2
-    mesh: the paged kernels and the per-head KV write run once per head
-    shard (parallel/sharding.shard_over_heads), so the PARTITIONED
-    executable holds the custom call, each chip's 2 of the 8 kv heads stay
-    where they are (no all-gather of anything pool-shaped), and the layer
-    scan's cache carry is in the kernel's layout.
+    mesh: the paged kernels and the paged KV write run once per head shard
+    (parallel/sharding.shard_over_heads), so the PARTITIONED executable holds
+    the custom call, each chip's 2 of the 8 kv heads stay where they are (no
+    all-gather of anything pool-shaped), and the layer scan's cache carry is
+    in the kernel's layout.
 
     Both programs: NO copy of a chip's pool slice anywhere, in any shape,
-    and next to no temporaries. decode (64 x 1): native attention over 64
-    rows x the kv bucket planned 12.13 GiB a chip (PERF.md, PR 26), this
-    plans under 8.5; with the write in its window form (what a sharded head
-    axis took before) the scan's body held two such copies per layer. chunk
-    (8 x 128): a chip holds 2 kv heads, fewer than the tile's 8 sublanes, so
-    its write is per-head too (block_kvcache.WINDOW_MIN_HEADS); in the
-    window form the scan's body re-laid the pool FOUR times per layer as
+    and next to no temporaries. decode (64 x 1, per-head write): native
+    attention over 64 rows x the kv bucket planned 12.13 GiB a chip (PERF.md,
+    PR 26), this plans under 8.5; with the write in its token-window form
+    (what a sharded head axis took before) the scan's body held two such
+    copies per layer. chunk (8 x 128): each shard writes its 2 heads of a
+    row's blocks whole (window ``(2, bs, D)``); under a token window of 2
+    heads, fewer than the tile's 8 sublanes (block_kvcache.WINDOW_MIN_HEADS),
+    the scan's body re-laid the pool FOUR times per layer as
     ``bf16[L*(NB+1)*bs, 2, 128]`` (~360 ms a dispatch on the chip, PERF.md
     PR 33), which a search for the pool's own shape does not see."""
     from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
@@ -370,6 +417,8 @@ def test_tp4_paged_serving_step_runs_its_kernels_per_shard(chip_mesh, program):
     assert _pool_copies(compiled, (L, nb1, heads // 4, bs, d)) == (0, 0)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
     assert _planned_bytes(compiled) < 8.5 * 2**30
+    if program == "chunk":
+        _assert_chunk_write_moves_blocks(compiled, 8, 128, heads // 4, (L, nb1, heads // 4, bs, d))
 
 
 def test_tp4_contiguous_decode_step_runs_its_kernel_per_shard(chip_mesh):
@@ -494,9 +543,9 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     step programs compiled for a described v5e: the pool spans all 20 layers
     at (2, 128) a token beside a (20, 48, 2688) carry; the decode program
     holds ``paged_tkg_decode_attention`` and the 8-row chunk program
-    ``paged_flash_attention`` (two KV heads a device: the per-head KV write,
-    no copy of the pool in the layer scan), and each plans under 14.75 GiB of
-    the chip's 15.75."""
+    ``paged_flash_attention`` (two KV heads a device; its KV write moves
+    whole blocks, the decode program's is per-head: no copy of the pool in
+    the layer scan), and each plans under 14.75 GiB of the chip's 15.75."""
     app, params, cache = _abstract_hybrid_app(chip_mesh(1), "zaya1-8b")
     assert cache.k.shape == (20, 2049, 2, 32, 128) and cache.state.last.shape == (20, 48, 2688)
     tkg = app.token_generation_model
@@ -507,6 +556,8 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
     assert kernel in text and _custom_calls(compiled) >= 1
     assert _pool_copies(compiled, cache.k.shape)[0] == 0
+    if program == "chunk":
+        _assert_chunk_write_moves_blocks(compiled, 8, 128, 2, cache.k.shape)
     mem = compiled.memory_analysis()
     print(f"\nzaya1-8b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
@@ -554,8 +605,8 @@ def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, pro
     both step programs compiled for a described v5e at kv bucket 2048: the
     block step is (48, 4) and holds ``paged_tkg_decode_attention`` (K = 4: 32
     query rows a KV head), the 8-row chunk program ``paged_flash_attention``
-    (under the block frontier); neither copies the pool, and each plans
-    under 14.75 GiB of the chip's 15.75."""
+    (under the block frontier; its KV write moves whole blocks); neither
+    copies the pool, and each plans under 14.75 GiB of the chip's 15.75."""
     app, params, cache = _abstract_paged_app(chip_mesh(1), "sdar-30b-a3b")
     assert cache.k.shape == (6, 2049, 4, 32, 128) and app.spec.block_step.block_length == 4
     tkg = app.token_generation_model
@@ -566,6 +617,8 @@ def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, pro
     kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
     assert kernel in text and _custom_calls(compiled) >= 1
     assert _pool_copies(compiled, cache.k.shape)[0] == 0
+    if program == "chunk":
+        _assert_chunk_write_moves_blocks(compiled, 8, 128, 4, cache.k.shape)
     mem = compiled.memory_analysis()
     print(f"\nsdar-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "

@@ -239,6 +239,37 @@ ENTRY %main.9 (x: f32[4]) -> f32[4] {
     assert ops["gather.1"] == "embed" and ops["dot.3"] == "head"
 
 
+def test_the_block_form_of_the_kv_write_is_under_its_scope():
+    """At chunk widths on the 128 lanes the paged KV write gathers a row's
+    pool blocks, merges and scatters them whole
+    (modules/block_kvcache._write_blocks). Every instruction of the chunk
+    program that holds one of those scatters or gathers is tabled under
+    ``layer.kv_write``: ``chunk.kv_write_dev_ms`` keeps reading the write,
+    and neither ``layer.other`` nor the unscoped share grows by it."""
+    from tests.conftest import paged_write_instructions
+
+    cfg = dict(
+        dict(DENSE, head_dim=128),
+        tpu_config=dict(
+            dtype="float32", batch_size=4, seq_len=256, enable_bucketing=True,
+            context_encoding_buckets=[256], token_generation_buckets=[128, 256],
+            is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
+            pa_block_size=16, pa_num_blocks=48, is_chunked_prefill=True, fused_qkv=True,
+        ),
+        chunked_prefill=dict(max_num_seqs=4, kernel_q_tile_size=2 * CHUNK),
+    )
+    app = system.build_app(cfg, jax.devices()[:1], SEED).load(random_weights=True)
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(128, q_len=2 * CHUNK)
+    text = tkg.trace_program(app.params, app.kv_cache, inputs, None)[2].as_text()
+    table = device_scopes.scope_table(text)["ops"]
+    writes = [
+        n for n in paged_write_instructions(text, app.kv_cache.k.shape, 4, 3) if n in table
+    ]
+    assert len(writes) >= 4  # K and V: a gather of the held blocks, a scatter
+    assert {table[n] for n in writes} == {"layer.kv_write"}
+
+
 class Annotations:
     """What ``jax.profiler.TraceAnnotation`` is handed at entry."""
 

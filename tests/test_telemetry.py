@@ -13,6 +13,7 @@ The load-bearing assertions:
 - the retrace-guard bridge surfaces traces/sealed-retraces as counters.
 """
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -383,6 +384,56 @@ def test_chunk_kv_block_counter_reads_what_the_host_knows(head_dim):
     # one group up to 512 tokens, two past it; the table's 128 a row-pass off the lanes
     assert got["walked"] == (64 * 32 + 128 * 6 + 64 if head_dim == 128 else 128 * 39)
     tel.close()
+
+
+def test_chunk_kv_write_block_counter_counts_whole_and_merged_blocks():
+    """``nxdi_chunk_kv_write_blocks_total``: per chunk pass whose paged KV
+    write moves whole blocks (chunk widths over ``TKG_MAX_Q_LEN``, head_dim
+    on the 128 lanes), the blocks a row's tokens cover whole and the edge
+    blocks the write reads and merges, from each row's ``(start, n)``. A
+    hand-made pass at blocks of 32: positions 0-127 are 4 whole blocks;
+    40-139 are 2 whole (64-127) and 2 merged (40-63, 128-139); ``n`` = 0 is
+    nothing. Then a served prompt of 100 tokens in chunks of 64: passes
+    (0, 64) and (64, 36) are 3 whole blocks and 1 merged; at head_dim 64 the
+    write goes token by token and nothing is counted."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import chunk_write_blocks
+
+    count = functools.partial(chunk_write_blocks, q_len=128, block_size=32, head_dim=128)
+    assert count([(0, 128)]) == (4, 0)
+    assert count([(40, 100)]) == (2, 2)
+    assert count([(64, 0)]) == (0, 0)
+    assert count([(0, 128)], head_dim=64) == count([(0, 16)], q_len=16) == (0, 0)
+    tel = TelemetrySession(enabled=True)
+    tel.kv_write_blocks(*count([(0, 128), (40, 100), (64, 0)]))
+    snap = tel.registry.snapshot()["nxdi_chunk_kv_write_blocks_total"]["samples"]
+    assert {x["labels"]["kind"]: x["value"] for x in snap} == {"whole": 6, "merged": 2}
+    tel.close()
+
+    for head_dim, want in ((128, {"whole": 3, "merged": 1}), (64, {"whole": 0, "merged": 0})):
+        cfg = make_tiny_config(
+            hidden_size=2 * head_dim, num_attention_heads=2, num_key_value_heads=1,
+            tpu=dict(
+                is_continuous_batching=True, batch_size=2, ctx_batch_size=1,
+                is_block_kv_layout=True, pa_block_size=32, pa_num_blocks=8,
+                is_chunked_prefill=True, seq_len=128, token_generation_buckets=[128],
+                chunked_prefill_config=ChunkedPrefillConfig(
+                    max_num_seqs=2, kernel_q_tile_size=64
+                ),
+            ),
+        )
+        app = TpuModelForCausalLM(None, cfg).load(
+            state_dict=make_random_hf_state_dict(cfg)
+        )
+        tel = TelemetrySession(enabled=True)
+        sess = ServingSession(app, telemetry=tel)
+        assert sess.add_request("r", [(i * 37) % 100 + 2 for i in range(100)], max_new_tokens=2)
+        while sess.active:
+            sess.step()
+        snap = tel.registry.snapshot()["nxdi_chunk_kv_write_blocks_total"]
+        got = {x["labels"]["kind"]: x["value"] for x in snap["samples"]}
+        assert got == want
+        tel.close()
 
 
 def test_double_finish_counts_once(cb_app):
