@@ -252,6 +252,38 @@ def _paged_flash_case(B, Sq, MB, bs, cache_dtype, m=_1B):
     return build
 
 
+#: Kimi-VL-A3B's language model (benchmark/configs/kimi-vl-a3b.json): 16 q
+#: heads over ONE latent of 512 and one rotary key of 64 a token, 7 layers
+_MLA = dict(Hq=16, r=512, rope=64, L=7)
+
+
+def _latent_case(B, Sq, MB, bs, m=_MLA):
+    """A latent-attention kernel of ops/latent_attention.py over the packed
+    pool: the decode kernel at ``Sq`` 1, the chunk kernel above decode widths."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import latent_attention as la
+
+        pack = 128 // m["rope"]
+        q_c = _sds((B, Sq, m["Hq"], m["r"]), jnp.bfloat16)
+        q_pe = _sds((B, Sq, m["Hq"], m["rope"]), jnp.bfloat16)
+        c = _sds((m["L"], 65, 1, bs, m["r"]), jnp.bfloat16)
+        kr = _sds((m["L"], 65, 1, bs // pack, 128), jnp.bfloat16)
+        li, bt = _sds((), jnp.int32), _sds((B, MB), jnp.int32)
+        scale = (128 + m["rope"]) ** -0.5
+        if Sq == 1:
+            raw = _unjit(la.paged_latent_decode_attention)
+            mask = _sds((B, 1, 1, MB * bs), jnp.bool_)
+            return functools.partial(raw, scale=scale), (q_c, q_pe, c, kr, li, bt, mask)
+        raw = _unjit(la.paged_latent_flash_attention)
+        pos, lim = _sds((B, Sq), jnp.int32), _sds((B,), jnp.int32)
+        return functools.partial(raw, scale=scale), (q_c, q_pe, c, kr, li, bt, pos, lim)
+
+    return build
+
+
 def _ragged_case(T, R, MB, bs, cache_dtype):
     def build():
         import jax.numpy as jnp
@@ -455,6 +487,31 @@ REGISTRY: Tuple[KernelSpec, ...] = (
                 "blk4x32x128", "bfloat16", _paged_flash_case(8, 128, 64, 32, "bfloat16", _BLOCK4)
             ),
         ),
+    ),
+    # the latent pool's two kernels (ops/latent_attention.py): the shape
+    # class is the latent block a chip, as the paged kernels'; ``pages``
+    # follows pages_per_step's rule under the GQA kernels' names (one rule,
+    # so the session's count of walked blocks holds for every pool)
+    KernelSpec(
+        name="paged_latent_decode_attention",
+        site=("decode_attention.py", "_common_call"),
+        entry="paged_latent_decode_attention",
+        fallback="neuronx_distributed_inference_tpu.ops.latent_attention:native_latent_attention",
+        parity_test="tests/test_deepseek_reference.py",
+        lowering_test="tests/test_chip_compile.py",
+        # a step fills ONE of the two slots of both streams' VMEM scratch by hand
+        step_copy_bytes=lambda inst: sum(b for _, _, b in inst.scratch[:2]) // 2,
+        cases=(KernelCase("blk1x32x512", "bfloat16", _latent_case(64, 1, 256, 32)),),
+    ),
+    KernelSpec(
+        name="paged_latent_flash_attention",
+        site=("decode_attention.py", "_common_call"),
+        entry="paged_latent_flash_attention",
+        fallback="neuronx_distributed_inference_tpu.ops.latent_attention:native_latent_attention",
+        parity_test="tests/test_deepseek_reference.py",
+        lowering_test="tests/test_chip_compile.py",
+        step_copy_bytes=lambda inst: sum(b for _, _, b in inst.scratch[:2]) // 2,
+        cases=(KernelCase("blk1x32x512", "bfloat16", _latent_case(8, 128, 256, 32)),),
     ),
     KernelSpec(
         name="ragged_paged_attention",

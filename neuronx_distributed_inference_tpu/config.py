@@ -1007,6 +1007,46 @@ def validate_slot_state_serving(tc: "TpuConfig", what: str = "state-space layers
             raise SlotStateServingError(f"a model with {what} cannot be served with {why}")
 
 
+class LatentAttentionError(NotImplementedError):
+    """An option that cannot run a model whose attention caches one
+    compressed latent and one rotary key a token (MLA: models/deepseek.py)
+    was set for one."""
+
+
+def validate_latent_attention(tc: "TpuConfig") -> None:
+    """Refuse, for a model whose builder declares a latent cache stream,
+    every option that would run it wrongly rather than not at all: on any
+    path what the layer does not write, and on the paged serving path what
+    the latent pool cannot do yet. One line each."""
+    speculation = (
+        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
+        or tc.enable_eagle_speculation or tc.serving_spec_ragged
+    )
+    paged = tc.is_block_kv_layout
+    refusals = (
+        (tc.cp_degree > 1, "cp_degree > 1: the latent cache is not sequence-sharded"),
+        (tc.attention_dp_degree > 1, "attention_dp_degree > 1: the latent cache is not batch-sharded"),
+        (tc.data_parallel_degree > 1, "data_parallel_degree > 1: the latent cache is not batch-sharded"),
+        (tc.fused_qkv, "fused_qkv: the layer has no q, k and v of one width to fuse"),
+        (tc.lora_config is not None, "lora_config: no adapter reaches the latent projections"),
+        (paged and tc.is_prefix_caching,
+         "is_prefix_caching on the paged cache: shared latent blocks are held to no reference"),
+        (paged and tc.serving_ragged,
+         "serving_ragged: the ragged mixed step attends (H_kv, D) keys and values only"),
+        (paged and speculation,
+         "speculation on the paged cache: a draft's width is not held to a reference over latents"),
+        (paged and tc.kv_quantized,
+         "kv_cache_dtype quantisation on the paged cache: one scale a head cannot serve "
+         "a latent every head reads"),
+        (paged and tc.tp_degree * tc.ep_degree > 1,
+         "tp/ep degree > 1 on the paged cache: the latent pool would be replicated and its "
+         "kernels launched per shard, which nothing measures"),
+    )
+    for flag, why in refusals:
+        if flag:
+            raise LatentAttentionError(f"a model with latent attention (MLA) cannot run with {why}")
+
+
 class BlockStepServingError(NotImplementedError):
     """An option that cannot serve a model whose decode step fills a block of
     positions (models/sdar.py) was set for one, or its block does not fit

@@ -474,6 +474,17 @@ class DecoderModelBuilder:
 
         return (PAGED_KV,) * self.config.num_hidden_layers
 
+    def cache_streams(self):
+        """The two streams a token leaves in each ``PAGED_KV`` layer
+        (modules/block_kvcache.CacheStream: heads, width, tokens a pool row):
+        K and V at ``(kv_heads, head_dim)`` unless a builder says otherwise
+        (models/deepseek.py: one compressed latent and one rotary key). The
+        application builds the pool from this and the session counts its
+        bytes from it."""
+        from neuronx_distributed_inference_tpu.modules.block_kvcache import kv_streams
+
+        return kv_streams(self.gqa.kv_heads, self.head_dim)
+
     def init_slot_state(self, num_slots: int):
         """(state pytree, its PartitionSpec tree) of the ``SLOT_STATE``
         layers for ``num_slots`` serving slots; None for a model whose layers

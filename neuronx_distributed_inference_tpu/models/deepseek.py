@@ -18,6 +18,14 @@ Tensor parallel: heads shard over the model axes; the latent cache is
 replicated (the standard MLA TP layout). Dense-first layers
 (first_k_dense_replace) run as a separate layer group (models/base.py
 LayerGroupSpec).
+
+The serving path (paged cache, chunked prefill): the builder declares the
+pool's two streams (``cache_streams``: the latent, and the rotary key packed
+two tokens a 128-lane row, modules/block_kvcache.CacheStream), a token's
+``kv_lora_rank + qk_rope_head_dim`` numbers are written once a layer, and
+both step programs attend in the absorbed form over the block table
+(ops/latent_attention.py). Options that cannot serve it are refused by type
+at config time (config.validate_latent_attention).
 """
 
 from __future__ import annotations
@@ -32,10 +40,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from neuronx_distributed_inference_tpu.config import InferenceConfig, to_dtype
-from neuronx_distributed_inference_tpu.models.base import LayerGroupSpec, gated_mlp
+from neuronx_distributed_inference_tpu.config import (
+    InferenceConfig,
+    to_dtype,
+    validate_latent_attention,
+)
+from neuronx_distributed_inference_tpu.models.base import (
+    PHASE_CONTEXT_ENCODING,
+    LayerGroupSpec,
+    gated_mlp,
+)
 from neuronx_distributed_inference_tpu.models.builder import DecoderModelBuilder
 from neuronx_distributed_inference_tpu.models.registry import register_model
+from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+    CacheStream,
+    update_latent_cache_at_layer,
+)
 from neuronx_distributed_inference_tpu.modules.kvcache import (
     kv_batch_size,
     read_cache_at_layer,
@@ -44,6 +64,11 @@ from neuronx_distributed_inference_tpu.modules.kvcache import (
 from neuronx_distributed_inference_tpu.modules.moe import MoESpec, moe_layer
 from neuronx_distributed_inference_tpu.modules.norm import rms_norm
 from neuronx_distributed_inference_tpu.modules.rope import apply_rope, yarn_mscale
+from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
+from neuronx_distributed_inference_tpu.ops.latent_attention import (
+    latent_attend,
+    native_latent_attention,
+)
 from neuronx_distributed_inference_tpu.ops.quant import linear
 from neuronx_distributed_inference_tpu.parallel.sharding import TENSOR
 
@@ -65,6 +90,10 @@ class DeepseekV3InferenceConfig(InferenceConfig):
     def add_derived_config(self):
         # rope tables are built for the rope sub-dimension only
         self.rope_dim = self.qk_rope_head_dim
+
+    def validate_config(self):
+        super().validate_config()
+        validate_latent_attention(self.tpu_config)
 
 
 @dataclass(frozen=True)
@@ -114,65 +143,78 @@ def mla_decoder_layer(
     "head" of dim kv_lora_rank; V stream holds the shared rope key ``k_pe``
     (one head of dim qk_rope_head_dim).
     """
-    if block_inputs is not None:
-        raise NotImplementedError("MLA with the paged cache is not implemented")
     sa = layer_params["self_attn"]
     residual = hidden
-    hidden = rms_norm(hidden, layer_params["input_layernorm"]["weight"], spec.rms_eps)
+    with jax.named_scope("layer.norm"):
+        hidden = rms_norm(hidden, layer_params["input_layernorm"]["weight"], spec.rms_eps)
     B, S, _ = hidden.shape
     H = mla.num_heads
 
     # --- q path: low-rank (or direct) projection, split nope/rope ---------
-    if mla.q_lora_rank:
-        q = linear(sa["q_a_proj"], hidden)
-        q = rms_norm(q, sa["q_a_layernorm"]["weight"], mla.rms_eps)
-        q = linear(sa["q_b_proj"], q)
-    else:
-        q = linear(sa["q_proj"], hidden)
-    q = q.reshape(B, S, H, mla.q_head_dim)
-    q_nope = q[..., : mla.qk_nope_head_dim]
-    q_pe = apply_rope(q[..., mla.qk_nope_head_dim :], cos, sin)
+    with jax.named_scope("layer.qkv"):
+        if mla.q_lora_rank:
+            q = linear(sa["q_a_proj"], hidden)
+            q = rms_norm(q, sa["q_a_layernorm"]["weight"], mla.rms_eps)
+            q = linear(sa["q_b_proj"], q)
+        else:
+            q = linear(sa["q_proj"], hidden)
+        q = q.reshape(B, S, H, mla.q_head_dim)
+        q_nope = q[..., : mla.qk_nope_head_dim]
+        q_pe = apply_rope(q[..., mla.qk_nope_head_dim :], cos, sin)
 
-    # --- compressed kv + rope key ----------------------------------------
-    ckv = linear(sa["kv_a_proj"], hidden)  # (B, S, r_kv + d_rope)
-    c = rms_norm(ckv[..., : mla.kv_lora_rank], sa["kv_a_layernorm"]["weight"], mla.rms_eps)
-    k_pe = apply_rope(ckv[..., None, mla.kv_lora_rank :].reshape(
-        B, S, 1, mla.qk_rope_head_dim
-    ), cos, sin)
+    # --- compressed kv + rope key: what the token leaves behind -----------
+    with jax.named_scope("layer.latent_proj"):
+        ckv = linear(sa["kv_a_proj"], hidden)  # (B, S, r_kv + d_rope)
+        c = rms_norm(ckv[..., : mla.kv_lora_rank], sa["kv_a_layernorm"]["weight"], mla.rms_eps)
+        k_pe = apply_rope(ckv[..., None, mla.kv_lora_rank :].reshape(
+            B, S, 1, mla.qk_rope_head_dim
+        ), cos, sin)
 
-    # q_nope absorbed into latent space: (B,S,H,d_nope)·(H,d_nope,r) -> (B,S,H,r)
-    q_c = jnp.einsum("bshd,hdr->bshr", q_nope, sa["k_absorb"]["weight"].astype(q.dtype))
+    with jax.named_scope("layer.absorb"):
+        # q_nope absorbed into latent space: (B,S,H,d_nope)·(H,d_nope,r) -> (B,S,H,r)
+        q_c = jnp.einsum("bshd,hdr->bshr", q_nope, sa["k_absorb"]["weight"].astype(q.dtype))
 
     # --- write-then-attend on the latent cache ----------------------------
-    k_cache, v_cache = update_cache_at_layer(
-        k_cache, v_cache, c[:, :, None, :], k_pe, layer_idx, slot_ids, positions
-    )
-    W = mask.shape[-1]
-    c_all, pe_all = read_cache_at_layer(k_cache, v_cache, layer_idx, B, W)
-    c_all = c_all[:, :, 0, :]  # (B, W, r)
-    pe_all = pe_all[:, :, 0, :]  # (B, W, d_rope)
+    if block_inputs is not None:
+        slot_mapping, block_table, kv_limit = block_inputs
+        with jax.named_scope("layer.kv_write"):
+            k_cache, v_cache = update_latent_cache_at_layer(
+                k_cache, v_cache, c, k_pe[:, :, 0], layer_idx, slot_mapping
+            )
+        with jax.named_scope("layer.attn"):
+            if phase == PHASE_CONTEXT_ENCODING:
+                # a whole prompt: the pass's own latents are its whole context
+                latent = native_latent_attention(q_c, q_pe, c, k_pe[:, :, 0], mask, mla.scale)
+            else:
+                latent = latent_attend(
+                    q_c, q_pe, k_cache, v_cache, layer_idx, mask, block_table, kv_limit,
+                    positions, scale=mla.scale, interpret=kernel_interpret(),
+                )
+    else:
+        with jax.named_scope("layer.kv_write"):
+            k_cache, v_cache = update_cache_at_layer(
+                k_cache, v_cache, c[:, :, None, :], k_pe, layer_idx, slot_ids, positions
+            )
+        with jax.named_scope("layer.attn"):
+            c_all, pe_all = read_cache_at_layer(k_cache, v_cache, layer_idx, B, mask.shape[-1])
+            latent = native_latent_attention(
+                q_c, q_pe, c_all[:, :, 0, :], pe_all[:, :, 0, :], mask, mla.scale
+            )
+    with jax.named_scope("layer.absorb"):
+        out = jnp.einsum(
+            "bshr,hrd->bshd", latent.astype(hidden.dtype),
+            sa["v_absorb"]["weight"].astype(hidden.dtype),
+        )
 
-    scores = (
-        jnp.einsum("bshr,bwr->bhsw", q_c, c_all.astype(q.dtype),
-                   preferred_element_type=jnp.float32)
-        + jnp.einsum("bshd,bwd->bhsw", q_pe, pe_all.astype(q.dtype),
-                     preferred_element_type=jnp.float32)
-    ) * mla.scale
-    scores = jnp.where(mask, scores.astype(jnp.float32), jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-
-    latent = jnp.einsum(
-        "bhsw,bwr->bshr", probs.astype(c_all.dtype), c_all,
-        preferred_element_type=jnp.float32,
-    ).astype(hidden.dtype)
-    out = jnp.einsum("bshr,hrd->bshd", latent, sa["v_absorb"]["weight"].astype(hidden.dtype))
-
-    out = linear(sa["o_proj"], out.reshape(B, S, H * mla.v_head_dim))
-    hidden = residual + out
+    with jax.named_scope("layer.o_proj"):
+        out = linear(sa["o_proj"], out.reshape(B, S, H * mla.v_head_dim))
+        hidden = residual + out
 
     residual = hidden
-    hidden = rms_norm(hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps)
-    hidden = residual + mlp_fn(layer_params["mlp"], hidden, spec)
+    with jax.named_scope("layer.norm"):
+        hidden = rms_norm(hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps)
+    with jax.named_scope("layer.mlp"):
+        hidden = residual + mlp_fn(layer_params["mlp"], hidden, spec)
     return hidden, k_cache, v_cache
 
 
@@ -184,17 +226,6 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
 
     def __init__(self, config):
         super().__init__(config)
-        tc = config.tpu_config
-        for flag, why in (
-            (tc.is_block_kv_layout, "paged cache"),
-            (tc.cp_degree > 1, "context parallelism"),
-            (tc.attention_dp_degree > 1, "attention-DP"),
-            (tc.data_parallel_degree > 1, "whole-model DP"),
-            (tc.fused_qkv, "fused_qkv"),
-            (tc.lora_config is not None, "LoRA serving"),
-        ):
-            if flag:
-                raise NotImplementedError(f"DeepSeek-V3 MLA with {why} is not implemented")
         cfg = config
         # pad q heads to the model-parallel degree (MLA has no GQA groups)
         self.q_heads = math.ceil(cfg.num_attention_heads / self.degree) * self.degree
@@ -253,6 +284,27 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
             groups.append(LayerGroupSpec(num_layers=L - self.first_dense, fn_idx=1))
         return dataclasses.replace(spec, layer_groups=tuple(groups))
 
+    def expert_layers(self):
+        """(expert layers, experts each holds, experts per token): what the
+        session's ``nxdi_moe_*`` counters count."""
+        n_moe = self.config.num_hidden_layers - self.first_dense
+        if n_moe <= 0:
+            return None
+        return n_moe, self.num_experts, self.moe_spec().top_k
+
+    def cache_streams(self):
+        """What a token leaves in a layer of the pool: the compressed latent
+        and ONE rotary key, ``kv_lora_rank + qk_rope_head_dim`` numbers
+        whatever the head count. The rotary key is narrower than the chip's
+        128 lanes, so as many tokens as fill them share a pool row."""
+        cfg = self.config
+        d_rope, bs = cfg.qk_rope_head_dim, cfg.tpu_config.pa_block_size
+        pack = 128 // d_rope if 128 % d_rope == 0 and bs % (128 // d_rope) == 0 else 1
+        return (
+            CacheStream(1, cfg.kv_lora_rank, name="latent"),
+            CacheStream(1, d_rope, pack=pack, name="rope_key"),
+        )
+
     def mlp_fn(self):
         mspec = self.moe_spec()
         has_shared = bool(getattr(self.config, "n_shared_experts", 0))
@@ -267,6 +319,7 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
                 shared_mlp_fn=(
                     (lambda p, x: shared_expert_mlp(p, x, act)) if has_shared else None
                 ),
+                return_choices=model_spec.output_choices,
             )
 
         return [gated_mlp, moe_mlp_fn]

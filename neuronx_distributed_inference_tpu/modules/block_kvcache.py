@@ -137,42 +137,84 @@ class HybridBlockCache(BlockKVCache):
     SLOT_FIELDS = ("state",)
 
 
+@dataclass(frozen=True)
+class CacheStream:
+    """One stream of the block pool, as a model's builder declares it
+    (``builder.cache_streams()``): a token leaves ``heads x width`` numbers
+    in it a layer. ``pack`` tokens of a block share one pool row, which is
+    then ``pack x width`` lanes wide: a stream narrower than the chip's 128
+    lanes (an MLA layer's one rotary key of 64) would otherwise be padded to
+    them in device memory, and no kernel could copy its blocks by hand. Token
+    ``o`` of a block lies in row ``o % (block_size // pack)`` at lanes
+    ``[o // (block_size // pack) * width, ...)``: a block's first
+    ``block_size // pack`` tokens fill the first lane group, row by row."""
+
+    heads: int
+    width: int
+    pack: int = 1
+    #: what the stream holds, for counters and documents ("latent": an MLA
+    #: layer's compressed latent, counted by ``nxdi_latent_*``)
+    name: str = "kv"
+
+    def pool_shape(self, num_layers: int, num_blocks: int, block_size: int):
+        if block_size % self.pack:
+            raise ValueError(f"block_size {block_size} does not hold whole rows of {self.pack} tokens")
+        return (
+            num_layers, num_blocks + 1, self.heads, block_size // self.pack,
+            self.width * self.pack,
+        )
+
+
+def kv_streams(num_kv_heads: int, head_dim: int) -> Tuple[CacheStream, CacheStream]:
+    """The two streams of a layer that pages K and V at ``(H_kv, D)``."""
+    return (CacheStream(num_kv_heads, head_dim),) * 2
+
+
 def init_block_cache(
     num_layers: int,
     num_blocks: int,
     block_size: int,
-    num_kv_heads: int,
-    head_dim: int,
+    num_kv_heads: int = None,
+    head_dim: int = None,
     dtype=jnp.bfloat16,
+    streams: Optional[Tuple[CacheStream, CacheStream]] = None,
 ) -> BlockKVCache:
-    shape = (num_layers, num_blocks + 1, num_kv_heads, block_size, head_dim)
-    if is_kv_quant_dtype(dtype):
-        def stream():
-            return QuantizedKV(
-                data=jnp.zeros(shape, dtype),
-                scale=jnp.zeros((num_layers, num_kv_heads), jnp.float32),
-            )
+    """The pool over ``num_layers``: K and V at ``(num_kv_heads, head_dim)``,
+    or the two ``streams`` a builder declares (an MLA layer: the compressed
+    latent in ``k``, the rotary key, packed, in ``v``). A quantised pool keeps
+    a float32 scale a (layer, head) beside each stream's codes."""
+    streams = streams or kv_streams(num_kv_heads, head_dim)
 
-        return BlockKVCache(k=stream(), v=stream())
-    return BlockKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    def stream(s: CacheStream):
+        data = jnp.zeros(s.pool_shape(num_layers, num_blocks, block_size), dtype)
+        if is_kv_quant_dtype(dtype):
+            return QuantizedKV(data=data, scale=jnp.zeros((num_layers, s.heads), jnp.float32))
+        return data
+
+    return BlockKVCache(k=stream(streams[0]), v=stream(streams[1]))
 
 
 def kv_block_bytes(
-    num_layers: int, block_size: int, num_kv_heads: int, head_dim: int, dtype
+    num_layers: int, block_size: int, num_kv_heads: int = None, head_dim: int = None,
+    dtype=jnp.bfloat16, streams: Optional[Tuple[CacheStream, ...]] = None,
 ) -> int:
-    """True per-block HBM cost of K+V for ONE block, in the CACHE dtype —
-    what sizes the serving block pool (a quantized cache fits ~2x the blocks
-    of bf16 in the same budget; the (L, H) scales are amortized over the
-    whole pool and excluded here)."""
-    return int(
-        2 * num_layers * num_kv_heads * block_size * head_dim
-        * jnp.dtype(dtype).itemsize
-    )
+    """True per-block HBM cost of ONE block over its streams (K+V at
+    ``(num_kv_heads, head_dim)``, or what a builder declares), in the CACHE
+    dtype — what sizes the serving block pool (a quantized cache fits ~2x the
+    blocks of bf16 in the same budget; the (L, H) scales are amortized over
+    the whole pool and excluded here)."""
+    streams = streams or kv_streams(num_kv_heads, head_dim)
+    per_token = sum(s.heads * s.width for s in streams)
+    return int(num_layers * block_size * per_token * jnp.dtype(dtype).itemsize)
 
 
-def block_cache_spec(quantized: bool = False):
+def block_cache_spec(quantized: bool = False, streams=None):
     from jax.sharding import PartitionSpec as P
 
+    if streams is not None and all(s.heads == 1 for s in streams):
+        # one "head" shared by every q head (an MLA layer's latent and rotary
+        # key): replicated over the model axes, as the q heads shard
+        return BlockKVCache(k=P(), v=P())
     spec = P(None, None, MODEL_AXES, None, None)
     if quantized:
         stream = QuantizedKV(data=spec, scale=P(None, MODEL_AXES))
@@ -264,6 +306,17 @@ def _row_segments(slot_mapping: jax.Array, bs: int, dropped: int):
     return blocks, first_off, covered.reshape(B, nseg, bs)
 
 
+def _lay_on_blocks(new, first_off, nseg: int, bs: int):
+    """``new (B, S, h, D)`` on the rows' block grid ``(B, nseg, bs, h, D)``:
+    a row's tokens start ``first_off`` into its first segment."""
+    B, S, h, D = new.shape
+    padded = jnp.pad(new, ((0, 0), (bs, nseg * bs - S), (0, 0), (0, 0)))
+    laid = jax.vmap(
+        lambda row, off: jax.lax.dynamic_slice_in_dim(row, bs - off, nseg * bs)
+    )(padded, first_off)
+    return laid.reshape(B, nseg, bs, h, D)
+
+
 def _write_blocks(data, new, layer_idx, blocks, first_off, covered):
     """The block form: ``new (B, S, h, D)`` laid on the rows' block grid
     ``(B, nseg, h, bs, D)`` (a row's tokens start ``first_off`` into its first
@@ -271,13 +324,7 @@ def _write_blocks(data, new, layer_idx, blocks, first_off, covered):
     cover, and scattered with the whole block ``(h, bs, D)`` in the window:
     the pool's minor-most dims, so the carry stays row-major. Under a
     head-sharded mesh ``data`` and ``new`` are one shard's heads."""
-    B, S, h, D = new.shape
-    nseg, bs = covered.shape[1:]
-    padded = jnp.pad(new, ((0, 0), (bs, nseg * bs - S), (0, 0), (0, 0)))
-    laid = jax.vmap(
-        lambda row, off: jax.lax.dynamic_slice_in_dim(row, bs - off, nseg * bs)
-    )(padded, first_off)
-    laid = laid.reshape(B, nseg, bs, h, D).transpose(0, 1, 3, 2, 4)
+    laid = _lay_on_blocks(new, first_off, *covered.shape[1:]).transpose(0, 1, 3, 2, 4)
     held = data[layer_idx, blocks]
     merged = jnp.where(covered[:, :, None, :, None], laid, held)
     return data.at[layer_idx, blocks].set(merged, mode="drop")
@@ -363,7 +410,28 @@ def update_block_cache_at_layer(
     per-(layer, head) absmax (see kvcache.update_cache_at_layer); invalid
     (garbage) slots are excluded from the scale update. The code streams are
     written in the same form; the scales never pass through it."""
-    L, NB1, H, bs, D = k_cache.shape
+    write = _stream_writer(k_cache.shape, slot_mapping, layer_idx, packed)
+    if isinstance(k_cache, QuantizedKV):
+        # scale-update mask: negative (dropped) slots AND garbage-block
+        # writes are excluded — idle serving rows carry all-zero block
+        # tables whose slots map INTO block 0 with slot >= 0, and the
+        # monotone pool-wide scale could never un-learn their junk
+        bs = k_cache.shape[3]
+        valid = (slot_mapping >= 0) & (slot_mapping // bs != GARBAGE_BLOCK)
+        k_codes, k_scale = _quantized_update(k_cache, k_new, layer_idx, valid)
+        v_codes, v_scale = _quantized_update(v_cache, v_new, layer_idx, valid)
+        return (
+            QuantizedKV(write(k_cache.data, k_codes), k_scale),
+            QuantizedKV(write(v_cache.data, v_codes), v_scale),
+        )
+    return write(k_cache, k_new), write(v_cache, v_new)
+
+
+def _stream_writer(pool_shape, slot_mapping: jax.Array, layer_idx, packed: bool = False):
+    """``write(data, new (B, S, H, D))`` of one pass into a pool stream of
+    ``pool_shape (L, NB+1, H, bs, D)``, in the form
+    :func:`update_block_cache_at_layer` says the call takes."""
+    L, NB1, H, bs, D = pool_shape
     B, S = slot_mapping.shape
     batch_sharded = _batch_sharded()
     per_head = not batch_sharded and (
@@ -393,19 +461,87 @@ def update_block_cache_at_layer(
             # window (H, D): one index row per token, the carry token-major
             return data.at[layer_idx, blocks, :, offs].set(rows, mode="drop")
 
-    if isinstance(k_cache, QuantizedKV):
-        # scale-update mask: negative (dropped) slots AND garbage-block
-        # writes are excluded — idle serving rows carry all-zero block
-        # tables whose slots map INTO block 0 with slot >= 0, and the
-        # monotone pool-wide scale could never un-learn their junk
-        valid = (slot_mapping >= 0) & (slot_mapping // bs != GARBAGE_BLOCK)
-        k_codes, k_scale = _quantized_update(k_cache, k_new, layer_idx, valid)
-        v_codes, v_scale = _quantized_update(v_cache, v_new, layer_idx, valid)
-        return (
-            QuantizedKV(write(k_cache.data, k_codes), k_scale),
-            QuantizedKV(write(v_cache.data, v_codes), v_scale),
+    return write
+
+
+def _write_packed(data, new, layer_idx, slot_mapping, width: int):
+    """The write of a PACKED stream (:class:`CacheStream`, ``pack`` > 1):
+    ``data (L, NB+1, 1, rows, pack * width)``, ``new (B, S, width)``. Wider
+    than a decode step the block form, under the rows' contract of
+    :func:`update_block_cache_at_layer`: a row's blocks are gathered, its
+    tokens laid in their rows and lane groups, merged and scattered whole.
+    At decode widths a token's pool row is read, its lane group replaced and
+    the row stored, one token of a row after another (two tokens of one pass
+    may share a pool row). The window is the pool's minor-most dim in both."""
+    L, NB1, _, rows, lanes = data.shape
+    pack = lanes // width
+    bs = rows * pack
+    B, S = slot_mapping.shape
+    new = new.astype(data.dtype)
+
+    def in_rows(x):  # (..., bs, w) token order -> (..., rows, pack * w) as the pool holds it
+        lead, w = x.shape[:-2], x.shape[-1]
+        x = x.reshape(*lead, pack, rows, w)
+        return jnp.swapaxes(x, -3, -2).reshape(*lead, rows, pack * w)
+
+    if S > TKG_MAX_Q_LEN and not _batch_sharded():
+        blocks, first_off, covered = _row_segments(slot_mapping, bs, NB1)
+        laid = _lay_on_blocks(new[:, :, None, :], first_off, *covered.shape[1:])[:, :, :, 0]
+        laid = in_rows(laid)[:, :, None]  # (B, nseg, 1, rows, lanes)
+        cover = in_rows(jnp.broadcast_to(covered[..., None], covered.shape + (width,)))
+        merged = jnp.where(cover[:, :, None], laid, data[layer_idx, blocks])
+        return data.at[layer_idx, blocks].set(merged, mode="drop")
+    group = jnp.arange(lanes, dtype=jnp.int32) // width
+    for s in range(S):
+        slots = slot_mapping[:, s]
+        blocks = jnp.where(slots >= 0, slots // bs, NB1)
+        offs = jnp.where(slots >= 0, slots % bs, 0)
+        held = data[layer_idx, jnp.minimum(blocks, NB1 - 1), 0, offs % rows]  # (B, lanes)
+        mine = group[None, :] == (offs // rows)[:, None]
+        row = jnp.where(mine, jnp.tile(new[:, s], (1, pack)), held)
+        data = data.at[layer_idx, blocks, 0, offs % rows].set(row, mode="drop")
+    return data
+
+
+def update_latent_cache_at_layer(
+    c_cache: jax.Array,  # (L, NB+1, 1, bs, r): the compressed latents
+    kr_cache: jax.Array,  # (L, NB+1, 1, bs // pack, pack * d_rope): the rotary keys, packed
+    c_new: jax.Array,  # (B, S, r)
+    kr_new: jax.Array,  # (B, S, d_rope)
+    layer_idx: jax.Array,
+    slot_mapping: jax.Array,  # (B, S)
+) -> Tuple[jax.Array, jax.Array]:
+    """What an MLA layer leaves behind, written once: the latent through
+    :func:`update_block_cache_at_layer`'s forms (whole blocks in the chunk
+    program, a row at decode widths), the rotary key into its packed stream
+    (:func:`_write_packed`)."""
+    write = _stream_writer(c_cache.shape, slot_mapping, layer_idx)
+    c_cache = write(c_cache, c_new[:, :, None, :])
+    if kr_cache.shape[3] == c_cache.shape[3]:  # pack 1: a stream like any other
+        return c_cache, _stream_writer(kr_cache.shape, slot_mapping, layer_idx)(
+            kr_cache, kr_new[:, :, None, :]
         )
-    return write(k_cache, k_new), write(v_cache, v_new)
+    return c_cache, _write_packed(kr_cache, kr_new, layer_idx, slot_mapping, kr_new.shape[-1])
+
+
+def read_latent_cache_at_layer(
+    c_cache: jax.Array, kr_cache: jax.Array, layer_idx: jax.Array, block_table: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """Gather one layer's blocks by the table into per-row views in token
+    order: latents ``(B, MB * bs, r)`` and rotary keys ``(B, MB * bs,
+    d_rope)`` (the native path; the kernels copy blocks as they lie).
+    Garbage-block reads are zeroed, as :func:`read_block_cache_at_layer`'s."""
+    B, MB = block_table.shape
+    bs = c_cache.shape[3]
+    rows, lanes = kr_cache.shape[3:]
+    pack = bs // rows
+    valid = (block_table != GARBAGE_BLOCK)[:, :, None, None]
+    c = jax.lax.dynamic_index_in_dim(c_cache, layer_idx, axis=0, keepdims=False)[block_table]
+    kr = jax.lax.dynamic_index_in_dim(kr_cache, layer_idx, axis=0, keepdims=False)[block_table]
+    c = jnp.where(valid, c[:, :, 0], jnp.zeros((), c.dtype))
+    kr = jnp.where(valid, kr[:, :, 0], jnp.zeros((), kr.dtype))
+    kr = kr.reshape(B, MB, rows, pack, lanes // pack).swapaxes(2, 3)
+    return c.reshape(B, MB * bs, -1), kr.reshape(B, MB * bs, lanes // pack)
 
 
 def slot_mapping_from_block_table(

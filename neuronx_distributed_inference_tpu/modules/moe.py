@@ -531,7 +531,8 @@ def moe_layer(
         else:
             out = expert_mlps_dense(expert_params, x, affinities, spec, selected)
     if shared_mlp_fn is not None:
-        out = out + shared_mlp_fn(params["shared_experts"], x)
+        with jax.named_scope("layer.shared_mlp"):
+            out = out + shared_mlp_fn(params["shared_experts"], x)
     out = out.reshape(B, S, H).astype(hidden.dtype)
     if not return_choices:
         return out

@@ -318,6 +318,8 @@ class TpuModelForCausalLM:
             # constant-size state per slot (builder.cache_layers) costs no
             # block, and its state is built beside the pool below
             paged = self.paged_layers
+            # what a token leaves in a paging layer is the builder's to say
+            streams = self.builder.cache_streams()
             if tc.pa_num_blocks is None and tc.pa_pool_bytes is not None:
                 # byte-budgeted pool: the block count follows the TRUE
                 # per-block cost in the cache dtype — a quantized cache
@@ -325,23 +327,12 @@ class TpuModelForCausalLM:
                 tc.pa_num_blocks = max(
                     1,
                     tc.pa_pool_bytes
-                    // kv_block_bytes(
-                        paged,
-                        tc.pa_block_size,
-                        self.spec.attn.num_kv_heads,
-                        self.spec.attn.head_dim,
-                        dt,
-                    ),
+                    // kv_block_bytes(paged, tc.pa_block_size, dtype=dt, streams=streams),
                 )
             cache = init_block_cache(
-                paged,
-                tc.pa_num_blocks,
-                tc.pa_block_size,
-                self.spec.attn.num_kv_heads,
-                self.spec.attn.head_dim,
-                dtype=dt,
+                paged, tc.pa_num_blocks, tc.pa_block_size, dtype=dt, streams=streams
             )
-            self._cache_pspecs = block_cache_spec(quantized=tc.kv_quantized)
+            self._cache_pspecs = block_cache_spec(quantized=tc.kv_quantized, streams=streams)
             slot_state = self.builder.init_slot_state(
                 tc.kv_cache_batch_size or tc.max_batch_size
             )

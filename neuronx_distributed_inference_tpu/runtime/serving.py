@@ -241,6 +241,7 @@ class ServingSession:
         self.max_prefill_seqs = cpc.max_num_seqs if cpc else 8
         self.allocator = None
         self.block_bytes = 0
+        self.latent_layers = 0
         if self.block_mode:
             from neuronx_distributed_inference_tpu.modules.block_kvcache import (
                 BlockAllocator,
@@ -261,15 +262,16 @@ class ServingSession:
             # true per-block HBM cost in the CACHE dtype (NOT a hardcoded
             # bf16 itemsize): quantized caches admit ~2x the blocks for the
             # same pool budget, and this is what capacity reporting uses
+            # the layers that page (a hybrid model's state-space layers keep
+            # a per-slot state and cost no block) and what a token leaves in
+            # each of them: the builder's to say
+            paged_layers = getattr(app, "paged_layers", app.spec.num_layers)
+            streams = app.builder.cache_streams()
             self.block_bytes = kv_block_bytes(
-                # the layers that page (a hybrid model's state-space layers
-                # keep a per-slot state and cost no block)
-                getattr(app, "paged_layers", app.spec.num_layers),
-                tc.pa_block_size,
-                app.spec.attn.num_kv_heads,
-                app.spec.attn.head_dim,
-                tc.kv_dtype,
+                paged_layers, tc.pa_block_size, dtype=tc.kv_dtype, streams=streams
             )
+            # layers whose pool stream is a compressed latent (nxdi_latent_*)
+            self.latent_layers = paged_layers if streams[0].name == "latent" else 0
             # what the paged kernels attend for a row (host-known: it follows
             # from the pool's shape a head shard, as the kernels' does): the
             # decode kernel's walk and the prefill kernel's
@@ -1950,6 +1952,8 @@ class ServingSession:
             self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets)
         elif self.slot_state_kind == "latent_carry":
             self.tel.carry_pass(program, rows)
+        if self.latent_layers:
+            self.tel.latent_pass(program, tokens * self.latent_layers)
         if self.expert_layers is not None:
             layers, experts, top_k = self.expert_layers
             self.tel.moe_pass(program, tokens * layers * top_k, dispatches * layers * experts)

@@ -32,12 +32,15 @@ DEVICE_SCOPES = (
     "embed",
     "layer.norm",
     "layer.qkv",
+    "layer.latent_proj",
+    "layer.absorb",
     "layer.kv_write",
     "layer.attn",
     "layer.o_proj",
     "layer.mlp",
     "layer.moe.router",
     "layer.moe.experts",
+    "layer.shared_mlp",
     "layer.ssm",
     "layer.other",
     "head",

@@ -377,6 +377,12 @@ class TelemetrySession:
             "the last token's conv inputs and shifted value half, every "
             "layer) a dispatch of the split serving step advanced",
             labels=("program",))
+        self._latent_tokens = r.counter(
+            "nxdi_latent_tokens_written_total",
+            "compressed latents (with their one rotary key: what a token "
+            "leaves in an MLA layer) a pass of the split serving step wrote to "
+            "the pool: real token positions x latent-attention layers",
+            labels=("program",))
         self._moe_rows = r.counter(
             "nxdi_moe_rows_routed_total",
             "token rows the split serving step routed to an expert: real "
@@ -1149,6 +1155,13 @@ class TelemetrySession:
         if not self.enabled:
             return
         self._carry_rows.child((program,)).inc(rows)
+
+    def latent_pass(self, program: str, latents: int) -> None:
+        """One pass of the split serving step over a latent pool: the
+        latents it wrote (token positions x latent-attention layers)."""
+        if not self.enabled:
+            return
+        self._latent_tokens.child((program,)).inc(latents)
 
     def moe_pass(self, program: str, rows_routed: int, experts: int) -> None:
         """One pass of the split serving step over a model with routed

@@ -83,6 +83,8 @@ MAIN_PATH_KERNELS = [
     ("paged_flash_attention", "blk8x32x128", "int8"),
     ("paged_flash_attention", "blk2x32x128", "bfloat16"),  # 2 kv heads a chip, 4 q heads each
     ("paged_flash_attention", "blk4x32x128", "bfloat16"),  # SDAR: 8 q heads a kv head, in parts
+    ("paged_latent_decode_attention", "blk1x32x512", "bfloat16"),  # Kimi-VL: 64 rows, kv 8192, 512 + 64 lanes
+    ("paged_latent_flash_attention", "blk1x32x512", "bfloat16"),  # its chunk: 8 rows of 128, 16 heads a latent
     ("ragged_paged_attention", "mixed", "bfloat16"),  # ragged mixed step
     ("ragged_paged_attention", "mixed", "int8"),
     ("quant_matmul", "k4096_n14336", "bfloat16"),  # 8B int4 weights
@@ -592,7 +594,7 @@ def _abstract_paged_app(mesh, config):
 
     def cache():
         return init_block_cache(app.paged_layers, tc.pa_num_blocks, tc.pa_block_size,
-                                b.gqa.kv_heads, b.head_dim, dtype=jnp.bfloat16)
+                                dtype=jnp.bfloat16, streams=b.cache_streams())
 
     params = jax.tree.map(place, jax.eval_shape(b.random_params))
     return app, params, jax.tree.map(place, jax.eval_shape(cache))
@@ -621,6 +623,43 @@ def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, pro
         _assert_chunk_write_moves_blocks(compiled, 8, 128, 4, cache.k.shape)
     mem = compiled.memory_analysis()
     print(f"\nsdar-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# kimi-vl-a3b: a pool of compressed latents, a dense layer before top-6 of 64 experts
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_kimi_serving_step_runs_the_latent_kernels_and_fits_the_chip(chip_mesh, program, monkeypatch):
+    """kimi-vl-a3b at the benchmark's widths (benchmark/configs/kimi-vl-a3b.json:
+    64 experts, the whole vocabulary, 1 dense + 6 expert layers, 64 slots,
+    12288 blocks), both step programs compiled for a described v5e at kv
+    bucket 8192: the pool is a latent stream of 512 and a rotary-key stream
+    packed two tokens a 128-lane row, 1152 B a token a layer and no lane of
+    padding; the decode program (64 x 1) holds ``paged_latent_decode_attention``
+    and the 8-row chunk program ``paged_latent_flash_attention``, one call a
+    layer group; neither copies the pool, and each plans under 14.75 GiB of
+    the chip's 15.75."""
+    from neuronx_distributed_inference_tpu.ops import latent_attention
+
+    # the gate asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    app, params, cache = _abstract_paged_app(chip_mesh(1), "kimi-vl-a3b")
+    assert cache.k.shape == (7, 12289, 1, 32, 512) and cache.v.shape == (7, 12289, 1, 16, 128)
+    assert (cache.k.size + cache.v.size) * 2 == 7 * 12289 * 32 * 1152
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((64, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
+    assert kernel in text and _custom_calls(compiled) == 2  # the dense group's scan and the expert group's
+    assert _pool_copies(compiled, cache.k.shape)[0] == 0 and _pool_copies(compiled, cache.v.shape)[0] == 0
+    mem = compiled.memory_analysis()
+    print(f"\nkimi-vl-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30

@@ -322,3 +322,83 @@ def test_slot_state_model_refuses_the_unpaged_paths():
                          chunked_prefill_config=None)
     with pytest.raises(NotImplementedError, match="paged, chunked path"):
         get_model_builder("granitemoehybrid")(cfg)
+
+
+# ---------------------------------------------------------------------------
+# a model whose attention caches a compressed latent (MLA: models/deepseek.py)
+# ---------------------------------------------------------------------------
+
+_MLA_ATTRS = dict(
+    model_type="deepseek_v3", hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+    num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=4, num_key_value_heads=4,
+    q_lora_rank=None, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+    n_routed_experts=4, num_experts_per_tok=2, n_shared_experts=1, vocab_size=512,
+)
+
+
+def _mla_config(paged=True, **tpu):
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.models import get_model_builder
+
+    opts = dict(batch_size=2, seq_len=128)
+    if paged:
+        opts.update(
+            is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
+            pa_block_size=16, pa_num_blocks=16, is_chunked_prefill=True,
+            chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=2, kernel_q_tile_size=16),
+        )
+    opts.update(tpu)
+    cls = get_model_builder("deepseek_v3").config_cls
+    return cls(TpuConfig(**opts),
+               load_config=lambda c: [setattr(c, k, v) for k, v in _MLA_ATTRS.items()])
+
+
+def test_latent_attention_accepts_the_paged_chunked_path_and_declares_its_streams():
+    from neuronx_distributed_inference_tpu.models import get_model_builder
+
+    streams = get_model_builder("deepseek_v3")(_mla_config()).cache_streams()
+    # one latent and one rotary key a token, the key packed 8 tokens a 128-lane row
+    assert [(s.heads, s.width, s.pack, s.name) for s in streams] == [
+        (1, 32, 1, "latent"), (1, 16, 8, "rope_key")]
+    assert _mla_config(paged=False, tp_degree=2).tpu_config.tp_degree == 2  # contiguous: tp stays
+
+
+@pytest.mark.parametrize("paged,tpu,match", [
+    (False, dict(fused_qkv=True), "fused_qkv"),
+    (False, dict(cp_degree=2, tp_degree=2), "cp_degree"),
+    (False, dict(attention_dp_degree=2, tp_degree=2, is_continuous_batching=True),
+     "attention_dp_degree"),
+    (True, dict(is_prefix_caching=True), "is_prefix_caching"),
+    (True, dict(serving_ragged=True), "serving_ragged"),
+    (True, dict(speculation_length=4), "speculation"),
+    (True, dict(kv_cache_dtype="int8"), "kv_cache_dtype"),
+    (True, dict(tp_degree=2), "degree > 1"),
+], ids=["fused_qkv", "cp", "attention_dp", "prefix_caching", "ragged", "speculation", "kv_quant",
+        "paged_tp2"])
+def test_latent_attention_refuses_by_type_what_cannot_run_it(paged, tpu, match):
+    """What ``DeepseekV3ModelBuilder.__init__`` raised as NotImplementedError
+    is a typed refusal at config time, one line an option."""
+    from neuronx_distributed_inference_tpu.config import LatentAttentionError
+
+    with pytest.raises(LatentAttentionError, match=match):
+        _mla_config(paged=paged, **tpu)
+
+
+def test_latent_attention_refuses_lora_and_whole_model_dp():
+    from neuronx_distributed_inference_tpu.config import LatentAttentionError, validate_latent_attention
+
+    class Options:  # the fields validate_latent_attention reads, one set that TpuConfig itself refuses earlier
+        cp_degree = attention_dp_degree = tp_degree = ep_degree = 1
+        data_parallel_degree = 1
+        fused_qkv = is_block_kv_layout = is_prefix_caching = serving_ragged = kv_quantized = False
+        speculation_length = medusa_speculation_length = 0
+        enable_fused_speculation = enable_eagle_speculation = serving_spec_ragged = False
+        lora_config = None
+
+    validate_latent_attention(Options())
+    for field, value, match in (("lora_config", object(), "lora_config"),
+                                ("data_parallel_degree", 2, "data_parallel_degree")):
+        opts = Options()
+        setattr(opts, field, value)
+        with pytest.raises(LatentAttentionError, match=match):
+            validate_latent_attention(opts)
