@@ -350,6 +350,24 @@ def _moe_case(T, k, E):
     return build
 
 
+def _grouped_mm_case(T, k, E, K, N, L):
+    """One product of a chunk program's expert layer: ``T * k`` sorted rows
+    over the ``(L, E, K, N)`` stack the layer scan holds."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import grouped_matmul as gm
+
+        x = _sds((T * k, K), jnp.bfloat16)
+        w = _sds((L, E, K, N), jnp.bfloat16)
+        sizes = _sds((E,), jnp.int32)
+        layer = _sds((), jnp.int32)
+        return _unjit(gm.grouped_matmul), (x, w, sizes, layer)
+
+    return build
+
+
 # ---------------------------------------------------------------------------
 # the registry
 # ---------------------------------------------------------------------------
@@ -542,6 +560,26 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         cases=(KernelCase("h2048_i8192", "bfloat16", _moe_case(4, 2, 8)),),
     ),
     KernelSpec(
+        name="grouped_matmul",
+        site=("grouped_matmul.py", "grouped_matmul"),
+        entry="grouped_matmul",
+        fallback="neuronx_distributed_inference_tpu.modules.moe:expert_mlps_dense",
+        parity_test="tests/test_moe_dispatch.py",
+        lowering_test="tests/test_chip_compile.py",
+        # the row tile; the output tile follows from the shapes (_tiles)
+        tile_params=("tm",),
+        sweep=(("tm", (64, 128, 256)),),
+        cases=(
+            # the benchmark's chunk programs at 8 rows x 128: zaya1-8b's
+            # products (16 experts, top-1), sdar-30b-a3b's gate and down
+            # (128, top-8), kimi-vl-a3b's gate (64, top-6: 11 lane groups)
+            KernelCase("k2048_n2048", "bfloat16", _grouped_mm_case(1024, 1, 16, 2048, 2048, 20)),
+            KernelCase("k2048_n768", "bfloat16", _grouped_mm_case(1024, 8, 128, 2048, 768, 6)),
+            KernelCase("k768_n2048", "bfloat16", _grouped_mm_case(1024, 8, 128, 768, 2048, 6)),
+            KernelCase("k2048_n1408", "bfloat16", _grouped_mm_case(1024, 6, 64, 2048, 1408, 6)),
+        ),
+    ),
+    KernelSpec(
         name="ssm_state_update",
         site=("ssm_state_update.py", "ssm_state_update"),
         entry="ssm_state_update",
@@ -596,6 +634,7 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     },
     "ragged_paged_attention": {"*": {"tq": 16}},
     "fused_moe_decode": {"*": {"ti_cap": 512}},
+    "grouped_matmul": {"*": {"tm": 128}},
     "quant_matmul": {"*": {"bn": 256}},
 }
 

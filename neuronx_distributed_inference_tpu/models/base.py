@@ -30,7 +30,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from neuronx_distributed_inference_tpu.modules import masks
+from neuronx_distributed_inference_tpu.modules import masks, moe
 from neuronx_distributed_inference_tpu.modules.attention import (
     AttnSpec,
     attention_decode,
@@ -1204,11 +1204,22 @@ def run_decoder_layers(
                     f"layer_groups mismatch: spec says {gspec.num_layers} layers, "
                     f"params carry {num_layers}"
                 )
+            # an expert layer whose pass takes the grouped-matmul kernel reads
+            # its experts from the group's stacks in place: they stay out of
+            # the scanned operands (modules/moe.hoist_expert_stacks)
+            expert_stacks = None
+            if isinstance(g_mlp, moe.ExpertMlp):
+                B, S = hidden.shape[:2]
+                group_params, expert_stacks = moe.hoist_expert_stacks(
+                    group_params, g_mlp.spec, S, B * S, hidden.dtype
+                )
 
             def scan_body(carry, xs, g_mlp=g_mlp, g_layer=g_layer, mask=mask,
-                          key_valid=key_valid, window=window, chunk=chunk):
+                          key_valid=key_valid, window=window, chunk=chunk,
+                          expert_stacks=expert_stacks, offset=offset):
                 h, k_c, v_c, cap = carry
                 layer_params, li = xs
+                layer_params = moe.place_expert_stacks(layer_params, expert_stacks, li - offset)
                 chose = []
                 if spec.output_choices:
                     # an MLP that chooses (modules/moe.moe_layer: an expert

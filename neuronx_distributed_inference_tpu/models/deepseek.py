@@ -61,7 +61,7 @@ from neuronx_distributed_inference_tpu.modules.kvcache import (
     read_cache_at_layer,
     update_cache_at_layer,
 )
-from neuronx_distributed_inference_tpu.modules.moe import MoESpec, moe_layer
+from neuronx_distributed_inference_tpu.modules.moe import ExpertMlp, MoESpec
 from neuronx_distributed_inference_tpu.modules.norm import rms_norm
 from neuronx_distributed_inference_tpu.modules.rope import apply_rope, yarn_mscale
 from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
@@ -306,23 +306,13 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
         )
 
     def mlp_fn(self):
-        mspec = self.moe_spec()
         has_shared = bool(getattr(self.config, "n_shared_experts", 0))
 
         from neuronx_distributed_inference_tpu.modules.moe import shared_expert_mlp
 
         act = getattr(self.config, "hidden_act", "silu")
-
-        def moe_mlp_fn(mlp_params, hidden, model_spec):
-            return moe_layer(
-                mlp_params, hidden, mspec,
-                shared_mlp_fn=(
-                    (lambda p, x: shared_expert_mlp(p, x, act)) if has_shared else None
-                ),
-                return_choices=model_spec.output_choices,
-            )
-
-        return [gated_mlp, moe_mlp_fn]
+        shared = (lambda p, x: shared_expert_mlp(p, x, act)) if has_shared else None
+        return [gated_mlp, ExpertMlp(self.moe_spec(), shared)]
 
     def layer_fn(self):
         import functools

@@ -46,9 +46,9 @@ from neuronx_distributed_inference_tpu.modules.kvcache import (
     update_cache_at_layer,
 )
 from neuronx_distributed_inference_tpu.modules.moe import (
+    ExpertMlp,
     MoESpec,
     fuse_shared_expert_params,
-    moe_layer,
     shared_expert_mlp,
     shared_expert_pspecs,
     shared_expert_shapes,
@@ -256,16 +256,8 @@ class Llama4TextModelBuilder(DecoderModelBuilder):
         )
 
     def mlp_fn(self):
-        mspec = self.moe_spec()
-
         act = getattr(self.config, "hidden_act", "silu")
-
-        def moe_mlp_fn(mlp_params, hidden, model_spec):
-            return moe_layer(
-                mlp_params, hidden, mspec,
-                shared_mlp_fn=lambda p, x: shared_expert_mlp(p, x, act),
-            )
-
+        moe_mlp_fn = ExpertMlp(self.moe_spec(), lambda p, x: shared_expert_mlp(p, x, act))
         # fn_idx layout: 0/1 dense (rope/nope), 2/3 moe (rope/nope)
         return [gated_mlp, gated_mlp, moe_mlp_fn, moe_mlp_fn]
 

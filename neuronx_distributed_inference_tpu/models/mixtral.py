@@ -15,7 +15,7 @@ from jax.sharding import PartitionSpec as P
 from neuronx_distributed_inference_tpu.config import InferenceConfig
 from neuronx_distributed_inference_tpu.models.builder import DecoderModelBuilder
 from neuronx_distributed_inference_tpu.models.registry import register_model
-from neuronx_distributed_inference_tpu.modules.moe import MoESpec, moe_layer
+from neuronx_distributed_inference_tpu.modules.moe import ExpertMlp, MoESpec
 from neuronx_distributed_inference_tpu.parallel.sharding import TENSOR
 
 
@@ -192,14 +192,7 @@ class MoEDecoderModelBuilder(DecoderModelBuilder):
         return params
 
     def mlp_fn(self):
-        mspec = self.moe_spec()
-
-        def moe_mlp_fn(mlp_params, hidden, model_spec):
-            return moe_layer(
-                mlp_params, hidden, mspec, return_choices=model_spec.output_choices
-            )
-
-        return moe_mlp_fn
+        return ExpertMlp(self.moe_spec())
 
 
 @register_model("mixtral")

@@ -72,7 +72,9 @@ from neuronx_distributed_inference_tpu.modules.latent_attention import (
 from neuronx_distributed_inference_tpu.modules.moe import (
     MoESpec,
     carried_mlp_router,
+    hoist_expert_stacks,
     moe_layer,
+    place_expert_stacks,
 )
 from neuronx_distributed_inference_tpu.modules.norm import rms_norm
 from neuronx_distributed_inference_tpu.modules.rope import (
@@ -146,9 +148,16 @@ class ZayaStack(LayerStack):
         cos, sin = rope_cos_sin(positions, params["rope"]["inv_freq"], spec.attention_scaling)
         cca, moe = self.cca, self.moe
 
+        # a pass that takes the grouped-matmul kernel reads the experts from
+        # the stacks in place: they stay out of the scanned operands
+        layers, expert_stacks = hoist_expert_stacks(
+            params["layers"], moe, S, B * S, hidden.dtype
+        )
+
         def layer(carry, xs):
             h, r, k_cache, v_cache, last = carry
             lp, li = xs
+            lp = place_expert_stacks(lp, expert_stacks, li)
             sa = lp["self_attn"]
             with jax.named_scope("layer.norm"):
                 x = rms_norm(h, lp["input_layernorm"]["weight"], spec.rms_eps)
@@ -192,7 +201,7 @@ class ZayaStack(LayerStack):
         (hidden, _, k, v, last), chosen = jax.lax.scan(
             layer,
             (hidden, r0, cache.k, cache.v, cache.state.last),
-            (params["layers"], jnp.arange(num_layers, dtype=jnp.int32)),
+            (layers, jnp.arange(num_layers, dtype=jnp.int32)),
         )
         new_cache = HybridBlockCache(k=k, v=v, state=TokenCarry(last=last))
         if not spec.output_choices:

@@ -11,10 +11,12 @@ Design:
   decode / EP-sharded experts run DENSE over all experts — the reference's
   decode strategy (``moe_token_gen_all_experts`` kernel, §2.10): a
   (E, T, I) batched einsum keeps the MXU busy at tiny T. Large-T prefill
-  runs the DROPLESS sorted-token grouped path (``jax.lax.ragged_dot``
-  Megablox-style GMM — T·k rows of work instead of E·T), or the
-  CAPACITY-FACTOR dropping dispatch when ``capacity_factor`` is set
-  (reference MoENeuronConfig.capacity_factor / BlockwiseMatmulConfig).
+  runs the DROPLESS sorted-token grouped path (T·k rows of work instead of
+  E·T: the Pallas grouped matmul of ops/grouped_matmul.py on the experts'
+  stack IN PLACE where it can serve the entry, ``jax.lax.ragged_dot``
+  elsewhere), or the CAPACITY-FACTOR dropping dispatch when
+  ``capacity_factor`` is set (reference MoENeuronConfig.capacity_factor /
+  BlockwiseMatmulConfig). :func:`expert_path` is the rule.
 - Expert parallelism: expert dim sharded over the ``ep`` mesh axis, expert
   ffn dim over ``(cp, tp)`` — the combine over experts becomes a psum over
   ``ep``, emitted by GSPMD (reference moe_tp×moe_ep process groups,
@@ -24,7 +26,7 @@ Design:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -211,14 +213,38 @@ def _sorted_dispatch(affinities: jax.Array, k: int):
     return st, se, sw, group_sizes
 
 
+class LayerOfStack(NamedTuple):
+    """An expert weight as the layer scan holds it: the stack of every
+    layer's ``(L, E, in, out)`` and this layer's index into it. The grouped
+    matmul kernel reads the layer's experts from the stack where they lie;
+    a scan that handed the kernel its own slice would copy it first
+    (:func:`hoist_expert_stacks`)."""
+
+    stack: jax.Array
+    layer: jax.Array
+
+
+_EXPERT_PROJS = ("gate_proj", "up_proj", "down_proj")
+
+
 def _grouped_mm(entry: dict, x_rows: jax.Array, row_expert: jax.Array,
-                group_sizes: jax.Array) -> jax.Array:
+                group_sizes: jax.Array, kernel: bool = False) -> jax.Array:
     """Ragged grouped matmul over expert-sorted rows — the Megablox-style GMM
     (reference BlockwiseMatmulConfig / nxd ExpertMLPsV2 blockwise path).
-    x_rows (R, in) sorted by expert; weight (E, in, out) -> (R, out)."""
-    entry = _expert_entry(entry, x_rows)
-    w = entry["weight"]
-    y = jax.lax.ragged_dot(x_rows, w.astype(x_rows.dtype), group_sizes)
+    x_rows (R, in) sorted by expert; weight (E, in, out) -> (R, out).
+    ``kernel``: through ops/grouped_matmul.py (a plain entry, whose weight may
+    be a :class:`LayerOfStack`); else ``jax.lax.ragged_dot``."""
+    if kernel:
+        from neuronx_distributed_inference_tpu.ops.grouped_matmul import grouped_matmul
+        from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
+
+        w = entry["weight"]
+        stack, layer = w if isinstance(w, LayerOfStack) else (w, None)
+        y = grouped_matmul(x_rows, stack, group_sizes, layer, interpret=kernel_interpret())
+    else:
+        entry = _expert_entry(entry, x_rows)
+        w = entry["weight"]
+        y = jax.lax.ragged_dot(x_rows, w.astype(x_rows.dtype), group_sizes)
     s = entry.get("scale")
     if s is not None:
         y = y * s.astype(y.dtype)[row_expert]
@@ -232,19 +258,21 @@ def expert_mlps_grouped(
     x: jax.Array,  # (T, H)
     affinities: jax.Array,  # (T, E)
     spec: MoESpec,
+    kernel: bool = False,
 ) -> jax.Array:
     """Dropless sorted-token grouped dispatch: T·k rows of expert work
     instead of the dense path's E·T (a ~E/k FLOP reduction at prefill;
-    VERDICT r2 weak #1). Reference: nxd ExpertMLPsV2 blockwise matmuls."""
+    VERDICT r2 weak #1). Reference: nxd ExpertMLPsV2 blockwise matmuls.
+    ``kernel``: the three products through the grouped-matmul kernel."""
     glu = _glu_fn(spec)
     st, se, sw, group_sizes = _sorted_dispatch(affinities, spec.top_k)
     xs = x[st]  # (R, H) gathered token rows
     sww = sw.astype(x.dtype)[:, None]
     if spec.early_affinity_modulation:
         xs = xs * sww
-    g = _grouped_mm(params["gate_proj"], xs, se, group_sizes)
-    u = _grouped_mm(params["up_proj"], xs, se, group_sizes)
-    y = _grouped_mm(params["down_proj"], glu(g, u), se, group_sizes)  # (R, H)
+    g = _grouped_mm(params["gate_proj"], xs, se, group_sizes, kernel)
+    u = _grouped_mm(params["up_proj"], xs, se, group_sizes, kernel)
+    y = _grouped_mm(params["down_proj"], glu(g, u), se, group_sizes, kernel)  # (R, H)
     if not spec.early_affinity_modulation:
         y = y * sww
     return jnp.zeros_like(x).at[st].add(y)
@@ -428,6 +456,137 @@ def carried_mlp_router(
         return jnp.where(selected, p, 0.0), selected, r, choice
 
 
+def expert_path(spec: MoESpec, experts: dict, q_len: int, rows: int, dtype) -> str:
+    """The strategy :func:`moe_layer` takes for a pass of ``rows`` token
+    positions, ``q_len`` of them a row, from the shapes and the entry alone
+    (a host-side function: the serving session counts its passes by it,
+    ``nxdi_moe_grouped_rows_total``). ``experts``: one layer's expert entries
+    or the stack of every layer's. One of
+
+    - ``"dense"``: every expert over every row, one batched product. Decode
+      (``q_len`` under ``sparse_dispatch_threshold``, however large the
+      batch: the reference's moe_token_gen_all_experts), experts divided
+      over ``ep`` (the dispatch rides the einsum's GSPMD partitioning),
+      blockwise-quantised experts, ``top_k == num_experts``, and every
+      prefill-sized pass that the rule below does not send elsewhere;
+    - ``"fused"``: decode through ops/moe_decode.py, where forced;
+    - ``"capacity"``: a configured ``capacity_factor``, at prefill-sized
+      passes (unsupported combinations are refused at config validation);
+    - ``"kernel"``: dropless grouped, its three products through
+      ops/grouped_matmul.py. Where the kernel can serve the entry
+      (kernel_mode.use_grouped_matmul: plain experts on one shard, on the
+      chip) the line against dense is kernel_mode.grouped_beats_dense, drawn
+      from ``rows``, ``num_experts`` and ``top_k`` and the chip's operations
+      a weight byte. Read alone on a v5e (PERF.md section 6, PR 45: ms a
+      layer, the three products and their dispatch):
+
+      ========================  =====  =====  ==========  ======  ========  ======
+      experts / top_k, widths   rows   dense  ragged_dot  kernel  in place  stream
+      ========================  =====  =====  ==========  ======  ========  ======
+      16 / 1, 2048 x 2048       1024   2.55   2.73        2.08    **0.87**  0.49
+      (zaya1-8b)                256    0.68   2.06        1.82    0.60
+      128 / 8, 2048 x 768       1024   8.28   9.37        6.68    **3.01**  1.47
+      (sdar-30b-a3b)            256    2.32   8.28        5.59    1.93
+      64 / 6, 2048 x 1408       1024   7.30   10.16       6.12    **2.77**  1.35
+      (kimi-vl-a3b)             256    1.89   8.91        5.10    1.78
+      ========================  =====  =====  ==========  ======  ========  ======
+
+      ``ragged_dot`` and ``kernel``: inside a layer scan, on the scan's own
+      slice of the stack (a 134-400 MB copy a product); ``in place``: the
+      kernel on the stacked weights with the layer's index; ``stream``: the
+      layer's expert weights once at 819 GB/s. At 1024 rows (8 x 128) dense
+      is bound by arithmetic and grouped in place takes a third of it; at
+      256 (8 x 32, under ``sparse_dispatch_threshold``) the two are level
+      and the pass stays dense.
+
+    - ``"ragged_dot"``: dropless grouped through ``jax.lax.ragged_dot``, for
+      an entry or a mesh the kernel does not serve (quantised experts, the
+      hybrid prefill re-constraint, off the chip). That lowering runs at
+      2.9-5.6 x the experts' stream (PERF.md section 6, PR 45), so it is
+      taken only where it saves 16 x the arithmetic, ``num_experts >= 16 *
+      top_k``, as before the kernel.
+    """
+    prefill_sized = q_len >= spec.sparse_dispatch_threshold
+    if not prefill_sized:
+        from neuronx_distributed_inference_tpu.ops.moe_decode import use_moe_tkg_kernel
+
+        return "fused" if use_moe_tkg_kernel(spec, experts, rows) else "dense"
+    # hybrid prefill is logically ep=1 (experts replicated over ep after the
+    # constraint), so the token-sorted sparse paths apply
+    ep_ok = spec.ep_degree == 1 or spec.hybrid_cte_full_tp
+    if not ep_ok or spec.top_k >= spec.num_experts or _has_blockwise_scales(experts):
+        return "dense"
+    if spec.capacity_factor is not None:
+        return "capacity"
+    from neuronx_distributed_inference_tpu.ops.kernel_mode import (
+        grouped_beats_dense,
+        use_grouped_matmul,
+    )
+
+    if use_grouped_matmul(spec, experts, dtype):
+        return "kernel" if grouped_beats_dense(spec.num_experts, spec.top_k, rows) else "dense"
+    return "ragged_dot" if spec.num_experts >= 16 * spec.top_k else "dense"
+
+
+def stacked_experts(layers) -> dict:
+    """The expert entries of a model's stacked layer parameters
+    (``params["layers"]``: one stack, or a list of groups' stacks of which
+    one holds the expert layers), every leaf led by its layer axis."""
+    groups = layers if isinstance(layers, (list, tuple)) else [layers]
+    return next(g["mlp"]["experts"] for g in groups if "experts" in g.get("mlp", {}))
+
+
+def _map_expert_projs(layers: dict, fn) -> dict:
+    """``layers`` with ``fn(name, entry)`` in place of each expert projection."""
+    experts = layers["mlp"]["experts"]
+    mapped = {name: fn(name, experts[name]) for name in _EXPERT_PROJS}
+    return dict(layers, mlp=dict(layers["mlp"], experts=dict(experts, **mapped)))
+
+
+def hoist_expert_stacks(layers: dict, spec: MoESpec, q_len: int, rows: int, dtype):
+    """For a scan over ``layers`` (every leaf led by the layer axis): where
+    the pass takes the grouped-matmul kernel, ``(layers without the three
+    expert weights, the three stacks)``, else ``(layers, None)``. The stacks
+    stay out of the scanned operands, loop-invariant, and
+    :func:`place_expert_stacks` hands each layer its index into them: the
+    scan's own slice of a stack would reach the kernel, a custom call, as a
+    copy of ``(E, in, out)`` a product a layer."""
+    experts = layers.get("mlp", {}).get("experts") if isinstance(layers, dict) else None
+    if experts is None or expert_path(spec, experts, q_len, rows, dtype) != "kernel":
+        return layers, None
+    stacks = {name: experts[name]["weight"] for name in _EXPERT_PROJS}
+    scanned = _map_expert_projs(
+        layers, lambda _, entry: {k: v for k, v in entry.items() if k != "weight"}
+    )
+    return scanned, stacks
+
+
+def place_expert_stacks(layer_params: dict, stacks: Optional[dict], layer: jax.Array) -> dict:
+    """One scanned layer's parameters with the hoisted expert weights back in
+    place as :class:`LayerOfStack` (``layer``: its index into the stacks)."""
+    if stacks is None:
+        return layer_params
+    return _map_expert_projs(
+        layer_params,
+        lambda name, entry: dict(entry, weight=LayerOfStack(stacks[name], layer)),
+    )
+
+
+class ExpertMlp:
+    """A decoder layer's ``mlp_fn`` that is an expert layer. It carries the
+    :class:`MoESpec`, so that the layer scan (models/base.run_decoder_layers)
+    can ask :func:`hoist_expert_stacks` which passes read the stacks in place."""
+
+    def __init__(self, spec: MoESpec, shared_mlp_fn=None):
+        self.spec, self.shared_mlp_fn = spec, shared_mlp_fn
+
+    def __call__(self, mlp_params, hidden, model_spec):
+        return moe_layer(
+            mlp_params, hidden, self.spec, shared_mlp_fn=self.shared_mlp_fn,
+            return_choices=model_spec.output_choices,
+        )
+
+
 def moe_layer(
     params: dict,
     hidden: jax.Array,  # (B, S, H)
@@ -447,22 +606,10 @@ def moe_layer(
     before and an MLP). The expert strategy by shape below is shared."""
     B, S, H = hidden.shape
     x = hidden.reshape(B * S, H)
-    n_active = S  # gate on SEQUENCE length: decode (S=1..spec_len) stays
-    # dense however large the batch is; prefill buckets/chunks go sparse
     with jax.named_scope("layer.moe.router"):
         affinities, selected = router(params, x, spec)  # (T, E) fp32, (T, E) bool
-    # dispatch strategy: decode (tiny T) and EP-sharded experts stay on the
-    # dense all-experts path (reference moe_token_gen_all_experts); large-T
-    # prefill takes a sparse dispatch — dropless grouped matmuls, or
-    # capacity-factor dropping when configured (VERDICT r2 weak #1)
-    # E/k gate: measured on a v5e (PERF.md), the sorted/capacity paths carry
-    # ~1.1-1.3x dispatch overhead while jax's ragged_dot lowers at dense-like
-    # cost — the sparse FLOP cut only pays off when it is large. An explicit
-    # capacity_factor is honored at prefill shapes (S >= threshold); decode
-    # stays dense-dropless by design (the reference's all-experts decode).
-    # Unsupported capacity combinations (EP sharding, blockwise-quantized
-    # experts) are rejected at config validation, not silently ignored.
-    prefill_sized = n_active >= spec.sparse_dispatch_threshold
+    path = expert_path(spec, params["experts"], S, B * S, x.dtype)
+    prefill_sized = S >= spec.sparse_dispatch_threshold
     expert_params = params["experts"]
     if spec.hybrid_cte_full_tp and prefill_sized:
         # hybrid sharding, prefill side: constrain the expert weights to full
@@ -490,33 +637,18 @@ def moe_layer(
         expert_params["up_proj"] = _cte_constrain(expert_params["up_proj"], True)
         expert_params["down_proj"] = _cte_constrain(expert_params["down_proj"], False)
 
-    big_ratio = spec.num_experts >= 16 * spec.top_k or spec.capacity_factor is not None
-    # hybrid prefill is logically ep=1 (experts replicated over ep after the
-    # constraint), so the token-sorted sparse paths apply
-    ep_ok = spec.ep_degree == 1 or (spec.hybrid_cte_full_tp and prefill_sized)
-    sparse_ok = (
-        prefill_sized
-        and big_ratio
-        and ep_ok
-        and spec.top_k < spec.num_experts
-        and not _has_blockwise_scales(params["experts"])
-    )
-    from neuronx_distributed_inference_tpu.ops.moe_decode import (
-        fused_moe_decode,
-        use_moe_tkg_kernel,
-    )
-
     with jax.named_scope("layer.moe.experts"):
-        if sparse_ok and spec.capacity_factor is not None:
+        if path == "capacity":
             out = expert_mlps_capacity(expert_params, x, affinities, spec)
-        elif sparse_ok:
-            out = expert_mlps_grouped(expert_params, x, affinities, spec)
-        elif not prefill_sized and use_moe_tkg_kernel(spec, params["experts"], x.shape[0]):
+        elif path in ("kernel", "ragged_dot"):
+            out = expert_mlps_grouped(expert_params, x, affinities, spec, path == "kernel")
+        elif path == "fused":
             # decode: DMA only the SELECTED experts' weights (k/E of the dense
             # path's HBM traffic; reference fused MoE TKG kernels, §2.10)
             from neuronx_distributed_inference_tpu.ops.kernel_mode import (
                 kernel_interpret,
             )
+            from neuronx_distributed_inference_tpu.ops.moe_decode import fused_moe_decode
 
             w_topk, e_topk = jax.lax.top_k(affinities, spec.top_k)
             out = fused_moe_decode(

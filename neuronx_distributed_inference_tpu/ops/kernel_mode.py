@@ -45,6 +45,9 @@ flash / packed prefill        single model-parallel shard, TPU backend
 paged flash prefill           TPU, q_len >= 64, heads divide the degree
 TKG decode (contig + paged)   TPU, kv_width >= 512, heads divide the degree
 fused MoE decode              OFF (force-only pending hardware wins)
+grouped expert matmul         TPU, plain experts in the rows' dtype on the
+                              lanes, ep = 1, single shard (see
+                              :func:`use_grouped_matmul`)
 ragged mixed-step             TPU backend, heads divide the degree
 int4 quant matmul             TPU backend + single shard (see
                               :func:`use_quant_matmul`)
@@ -247,6 +250,82 @@ def use_moe_tkg(spec, params: dict, n_tokens: int) -> bool:
             "modulation); falling back to the dense all-experts path"
         )
     return ok
+
+
+#: operations a weight byte at which a v5e's arithmetic catches its weight
+#: stream (197 TFLOP/s / 819 GB/s): a product of more rows than this an
+#: expert is bound by arithmetic, one of fewer by the stream
+OPS_PER_WEIGHT_BYTE = 240
+
+#: rows a visit of the grouped-matmul kernel multiplies by one expert's
+#: weights (ops/grouped_matmul.py: the MXU's 128 rows)
+GROUPED_ROW_TILE = 128
+
+
+def use_grouped_matmul(spec, experts: dict, dtype) -> bool:
+    """Gate for the grouped-matmul kernel (ops/grouped_matmul.py; ``spec``
+    is a MoESpec, ``experts`` one layer's expert entries or the stack of
+    every layer's, ``dtype`` the activations'). It serves plain experts: a
+    ``weight`` in the rows' dtype with both widths on the 128 lanes and at
+    most a ``bias``, which stays outside the product (no ``scale``, no
+    blockwise scales, no packed codes), on the chip, where the experts are
+    not divided over a mesh axis (``ep_degree`` 1, one model-parallel shard:
+    a ``pallas_call`` has no partitioning rule). No option forces it: what
+    it cannot serve keeps ``jax.lax.ragged_dot``."""
+
+    def plain(entry):
+        if not isinstance(entry, dict) or not set(entry) <= {"weight", "bias"}:
+            return False
+        w = entry.get("weight")
+        w = getattr(w, "stack", w)  # modules/moe.LayerOfStack
+        return (
+            w is not None
+            and w.dtype == dtype
+            and w.shape[-1] % 128 == 0
+            and w.shape[-2] % 128 == 0
+        )
+
+    return (
+        all(plain(experts.get(k)) for k in ("gate_proj", "up_proj", "down_proj"))
+        and spec.ep_degree == 1
+        and single_shard(spec)
+        and on_tpu()
+    )
+
+
+def grouped_beats_dense(num_experts: int, top_k: int, rows: int) -> bool:
+    """Where the grouped-matmul kernel serves: grouped or dense, from the
+    shapes, both reckoned in passes over the layer's expert weights. The
+    dense form multiplies all ``rows`` tokens by every expert, ``rows``
+    operations a weight byte: one pass while the stream bounds it, ``rows /
+    OPS_PER_WEIGHT_BYTE`` once arithmetic does. The grouped form multiplies
+    ``rows * top_k / num_experts`` rows an expert, under that line at every
+    shape that reaches here, and costs a pass of an expert's weights a VISIT
+    (a row tile of GROUPED_ROW_TILE x one expert whose rows it holds: at most
+    ``rows * top_k / tile + num_experts - 1`` of them). Grouped is taken where
+    it makes fewer passes: where dense is bound by arithmetic and the routing
+    leaves the grouped form well under it.
+
+    ==========================  =====  ======  =======  ========
+    experts / top_k             rows   dense   grouped  taken
+    ==========================  =====  ======  =======  ========
+    16 / 1 (zaya1-8b)           1024   4.3     1.4      grouped
+    16 / 1                      512    2.1     1.2      grouped
+    128 / 8 (sdar-30b-a3b)      1024   4.3     1.5      grouped
+    64 / 6 (kimi-vl-a3b)        1024   4.3     1.7      grouped
+    64 / 6                      512    2.1     1.4      grouped
+    8 / 2 (Mixtral)             1024   4.3     2.9      grouped
+    8 / 2                       512    2.1     1.9      grouped
+    any                         <=240  1       >= 1     dense
+    ==========================  =====  ======  =======  ========
+
+    Read on a v5e the products alone take 1.52, 1.55 and 1.66 x their stream
+    at the first, third and fourth row (PERF.md section 6, PR 45): the
+    passes this reckons, each at about what its fetch costs.
+    """
+    dense = max(1.0, rows / OPS_PER_WEIGHT_BYTE)
+    visits = -(-rows * top_k // GROUPED_ROW_TILE) + num_experts - 1
+    return max(1.0, visits / num_experts) < dense
 
 
 def use_ragged(spec, total_q: int, ragged_q_tile: int = 16) -> bool:

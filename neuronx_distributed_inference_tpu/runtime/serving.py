@@ -377,6 +377,16 @@ class ServingSession:
         self.slot_state_kind = getattr(state, "KIND", None)
         self.slot_state_bytes = state.nbytes if self.slot_state else 0
         self.expert_layers = app.builder.expert_layers()
+        if self.expert_layers is not None:
+            # the strategy a step program's expert layers were traced with,
+            # from its shape (nxdi_moe_grouped_rows_total)
+            from neuronx_distributed_inference_tpu.config import to_dtype
+            from neuronx_distributed_inference_tpu.modules import moe
+
+            self._expert_path = functools.lru_cache(maxsize=None)(functools.partial(
+                moe.expert_path, app.builder.moe_spec(),
+                moe.stacked_experts(app.params["layers"]), dtype=to_dtype(tc.dtype),
+            ))
         # a model whose builder declares a block step generates block by
         # block: its rows' blocks, plans and commits (runtime/block_step.py)
         self.blocks = None
@@ -1251,7 +1261,7 @@ class ServingSession:
                     *self._chunk_write_blocks([(r.prefill_pos, n) for r, n in ran], qb)
                 )
             self._count_pass(
-                "chunk", len(ran), ran_real, len(flights),
+                "chunk", (R, qb), len(ran), ran_real, len(flights),
                 resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
                 kv_blocks=kv_blocks,
             )
@@ -1887,7 +1897,7 @@ class ServingSession:
         tel.step("decode")
         tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
         tel.decode_pass(len(rows), B)
-        self._count_pass("decode", len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
+        self._count_pass("decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
                          block_rows=block_rows)
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
         snap = [(r, p, r.slot, r.epoch) for r, p in rows]
@@ -1936,12 +1946,13 @@ class ServingSession:
         ids of the row's next pass."""
         return out.tokens[:, -1:] if self.blocks is None else out.next_ids
 
-    def _count_pass(self, program: str, rows: int, tokens: int, dispatches: int,
+    def _count_pass(self, program: str, shape, rows: int, tokens: int, dispatches: int,
                     resets: int = 0, kv_blocks=None, block_rows=None) -> None:
         """What a pass of the split serving step ("decode" or "chunk") did
         to per-slot state and routed experts, from what the step already
-        knows: ``rows`` live rows over ``tokens`` real token positions in
-        ``dispatches`` dispatches, ``resets`` of the rows from position 0;
+        knows: the program's ``shape`` (rows, positions a row), ``rows`` live
+        rows over ``tokens`` real token positions in ``dispatches``
+        dispatches, ``resets`` of the rows from position 0;
         ``kv_blocks``: the pass's (live, walked) pool blocks;
         ``block_rows``: a block step's (denoise, commit) rows."""
         if kv_blocks is not None:
@@ -1956,7 +1967,10 @@ class ServingSession:
             self.tel.latent_pass(program, tokens * self.latent_layers)
         if self.expert_layers is not None:
             layers, experts, top_k = self.expert_layers
-            self.tel.moe_pass(program, tokens * layers * top_k, dispatches * layers * experts)
+            self.tel.moe_pass(
+                program, tokens * layers * top_k, dispatches * layers * experts,
+                self._expert_path(shape[1], shape[0] * shape[1]),
+            )
 
     def _consume(self, pend, results: Dict[str, int]):
         """Fetch a dispatched decode step and apply termination bookkeeping.

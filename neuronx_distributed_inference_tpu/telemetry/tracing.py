@@ -396,6 +396,15 @@ class TelemetrySession:
             "top-1 of 16 hits 15.3 on average); not a count of the distinct "
             "experts with a live row, which only the device knows",
             labels=("program",))
+        self._moe_grouped_rows = r.counter(
+            "nxdi_moe_grouped_rows_total",
+            "the routed token rows of nxdi_moe_rows_routed_total by the expert "
+            "strategy the pass's program was traced with (modules/moe.expert_path): "
+            "kernel = dropless grouped through ops/grouped_matmul.py on the "
+            "stacked weights in place, ragged_dot = grouped through "
+            "jax.lax.ragged_dot, dense = every expert over every row (capacity, "
+            "fused: the two configured strategies)",
+            labels=("program", "path"))
         self._block_row_passes = r.counter(
             "nxdi_block_row_passes_total",
             "a block-step model (runtime/block_step.py): live rows x passes "
@@ -1163,15 +1172,17 @@ class TelemetrySession:
             return
         self._latent_tokens.child((program,)).inc(latents)
 
-    def moe_pass(self, program: str, rows_routed: int, experts: int) -> None:
+    def moe_pass(self, program: str, rows_routed: int, experts: int, path: str) -> None:
         """One pass of the split serving step over a model with routed
-        experts: token rows routed (x layers x experts per token) and the
+        experts: token rows routed (x layers x experts per token), the
         experts whose weights its dispatches streamed (every held expert of
-        every expert layer, per dispatch)."""
+        every expert layer, per dispatch), and the expert strategy its
+        program holds (``path``: modules/moe.expert_path)."""
         if not self.enabled:
             return
         self._moe_rows.child((program,)).inc(rows_routed)
         self._moe_experts.child((program,)).inc(experts)
+        self._moe_grouped_rows.child((program, path)).inc(rows_routed)
 
     def block_pass(self, denoise_rows: int, commit_rows: int, positions: int) -> None:
         """One dispatch of a block-step model's decode step: its live rows

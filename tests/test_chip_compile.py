@@ -89,6 +89,10 @@ MAIN_PATH_KERNELS = [
     ("ragged_paged_attention", "mixed", "int8"),
     ("quant_matmul", "k4096_n14336", "bfloat16"),  # 8B int4 weights
     ("ssm_state_update", "rows48", "float32"),  # granite-4.0-h-micro decode, 48 slots
+    ("grouped_matmul", "k2048_n2048", "bfloat16"),  # zaya1-8b's chunk program: 1024 rows, 16 experts of a 20-layer stack
+    ("grouped_matmul", "k2048_n768", "bfloat16"),  # sdar-30b-a3b's gate and up: 8192 rows, 128 experts
+    ("grouped_matmul", "k768_n2048", "bfloat16"),  # its down
+    ("grouped_matmul", "k2048_n1408", "bfloat16"),  # kimi-vl-a3b's gate: 6144 rows, 64 experts, 11 lane groups
 ]
 
 
@@ -258,6 +262,41 @@ def _pool_copies(compiled, pool_shape):
     return count(called), count(entry)
 
 
+def _stack_shaped(compiled, stack_shapes):
+    """Instructions of the optimized HLO whose result has the shape of one
+    layer's expert stack ``(E, in, out)``: what a scan that sliced the
+    ``(L, E, in, out)`` weights for a custom call would hold, a copy a
+    product a layer."""
+    want = {"[" + ",".join(str(d) for d in sh) + "]" for sh in stack_shapes}
+    return [
+        line.strip()[:160] for line in compiled.as_text().splitlines()
+        if (m := re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+ = \w+(\[[\d,]+\])", line)) and m.group(1) in want
+    ]
+
+
+def _assert_expert_products(compiled, program, stack_shapes):
+    """The expert layer of a step program compiled with the grouped-matmul
+    gate on (ops/kernel_mode.use_grouped_matmul). chunk: its three products
+    are the kernel, reading the stacked weights in place: no ``ragged-dot``,
+    NO instruction of the shape of a layer's expert stack, and the kernel's
+    instructions (the compiler names a Pallas call by the kernel's ``name``)
+    stand under ``layer.moe.experts`` in the scope table. decode (and a block
+    step): the batched dense products over every expert, no grouped kernel."""
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    table = device_scopes.scope_table(text)["ops"]
+    calls = [name for name in table if name.startswith("grouped_matmul")]
+    if program == "chunk":
+        assert not _stack_shaped(compiled, stack_shapes), _stack_shaped(compiled, stack_shapes)[:3]
+        assert len(calls) == 3 and {table[c] for c in calls} == {"layer.moe.experts"}
+    else:
+        assert not calls
+        E = stack_shapes[0][0]
+        assert re.search(r"= \w+\[" + str(E) + r",\d+,\d+\]\S* (?:fusion|convolution|dot)\(", text)
+
+
 def _planned_bytes(compiled) -> int:
     """What the executable plans on the device: arguments, outputs that are
     not aliased to one, temporaries."""
@@ -309,7 +348,7 @@ def _assert_chunk_write_moves_blocks(compiled, rows, q, heads, pool_shape):
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
+def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program, monkeypatch):
     """The paged KV write leaves the layer scan's cache carry in the layout
     the kernel reads (modules/block_kvcache.update_block_cache_at_layer), at
     the benchmark's widths: 48 slots, 1056 blocks x 32 tokens, 28 layers.
@@ -327,7 +366,9 @@ def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
     gathered blocks are 2.5 MB and two VMEM slots of a group of blocks cost
     the device's memory nothing."""
     from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
 
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)  # as on the chip
     app, params, cache = _abstract_app(
         QWEN3_1P7B_GEOMETRY, chip_mesh(1), batch_size=48, seq_len=8192,
         context_encoding_buckets=[8192], token_generation_buckets=[1024, 8192],
@@ -340,7 +381,10 @@ def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program):
     inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((48, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
-    assert _custom_calls(compiled) >= 1
+    # a model without an expert layer holds what it held before the layer scan
+    # learnt to keep expert stacks out of its operands (PR 45): the one
+    # attention kernel and every layer's weights as the scan's slices
+    assert _custom_calls(compiled) == 1 and "grouped_matmul" not in compiled.as_text()
     in_scan, outside = _pool_copies(compiled, cache.k.shape)
     assert in_scan == 0
     if program == "decode":
@@ -539,7 +583,7 @@ def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, pro
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, program):
+def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, program, monkeypatch):
     """zaya1-8b at the benchmark's widths (benchmark/configs/zaya1-8b.json: 16
     experts, the whole vocabulary, 20 layers, 48 slots, 2048 blocks), both
     step programs compiled for a described v5e: the pool spans all 20 layers
@@ -547,7 +591,14 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     holds ``paged_tkg_decode_attention`` and the 8-row chunk program
     ``paged_flash_attention`` (two KV heads a device; its KV write moves
     whole blocks, the decode program's is per-head: no copy of the pool in
-    the layer scan), and each plans under 14.75 GiB of the chip's 15.75."""
+    the layer scan), and each plans under 14.75 GiB of the chip's 15.75. The
+    chunk program's expert products are ``grouped_matmul`` on the scanned
+    stacks in place (no ``ragged-dot``, nothing of the shape of a layer's 16
+    x 2048 x 2048 stack); the decode program keeps its batched products."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
+
+    # the gate asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
     app, params, cache = _abstract_hybrid_app(chip_mesh(1), "zaya1-8b")
     assert cache.k.shape == (20, 2049, 2, 32, 128) and cache.state.last.shape == (20, 48, 2688)
     tkg = app.token_generation_model
@@ -560,6 +611,7 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     assert _pool_copies(compiled, cache.k.shape)[0] == 0
     if program == "chunk":
         _assert_chunk_write_moves_blocks(compiled, 8, 128, 2, cache.k.shape)
+    _assert_expert_products(compiled, program, [(16, 2048, 2048)])
     mem = compiled.memory_analysis()
     print(f"\nzaya1-8b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
@@ -601,14 +653,21 @@ def _abstract_paged_app(mesh, config):
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, program):
+def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, program, monkeypatch):
     """sdar-30b-a3b at the benchmark's widths (benchmark/configs/sdar-30b-a3b.json:
     128 experts, the whole vocabulary, 6 of 48 layers, 48 slots, 2048 blocks),
     both step programs compiled for a described v5e at kv bucket 2048: the
     block step is (48, 4) and holds ``paged_tkg_decode_attention`` (K = 4: 32
     query rows a KV head), the 8-row chunk program ``paged_flash_attention``
     (under the block frontier; its KV write moves whole blocks); neither
-    copies the pool, and each plans under 14.75 GiB of the chip's 15.75."""
+    copies the pool, and each plans under 14.75 GiB of the chip's 15.75. The
+    chunk program's expert products are ``grouped_matmul`` on the scanned
+    stacks in place (8192 sorted rows over 128 experts); the block step (S =
+    4) keeps its batched products over every expert."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
+
+    # the gate asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
     app, params, cache = _abstract_paged_app(chip_mesh(1), "sdar-30b-a3b")
     assert cache.k.shape == (6, 2049, 4, 32, 128) and app.spec.block_step.block_length == 4
     tkg = app.token_generation_model
@@ -621,6 +680,7 @@ def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, pro
     assert _pool_copies(compiled, cache.k.shape)[0] == 0
     if program == "chunk":
         _assert_chunk_write_moves_blocks(compiled, 8, 128, 4, cache.k.shape)
+    _assert_expert_products(compiled, program, [(128, 2048, 768), (128, 768, 2048)])
     mem = compiled.memory_analysis()
     print(f"\nsdar-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
@@ -642,11 +702,15 @@ def test_kimi_serving_step_runs_the_latent_kernels_and_fits_the_chip(chip_mesh, 
     padding; the decode program (64 x 1) holds ``paged_latent_decode_attention``
     and the 8-row chunk program ``paged_latent_flash_attention``, one call a
     layer group; neither copies the pool, and each plans under 14.75 GiB of
-    the chip's 15.75."""
-    from neuronx_distributed_inference_tpu.ops import latent_attention
+    the chip's 15.75. The chunk program's six expert layers take the grouped
+    strategy (64 / 6: dense before PR 45), their products ``grouped_matmul``
+    on the expert group's stacks in place, indexed from the group's first
+    layer; the decode program keeps its batched products."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode, latent_attention
 
-    # the gate asks jax.default_backend(), which is the CPU here
+    # the gates ask jax.default_backend(), which is the CPU here
     monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
     app, params, cache = _abstract_paged_app(chip_mesh(1), "kimi-vl-a3b")
     assert cache.k.shape == (7, 12289, 1, 32, 512) and cache.v.shape == (7, 12289, 1, 16, 128)
     assert (cache.k.size + cache.v.size) * 2 == 7 * 12289 * 32 * 1152
@@ -656,7 +720,9 @@ def test_kimi_serving_step_runs_the_latent_kernels_and_fits_the_chip(chip_mesh, 
     compiled = _compile_step(app, tkg, inputs, params, cache)
     text = compiled.as_text()
     kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
-    assert kernel in text and _custom_calls(compiled) == 2  # the dense group's scan and the expert group's
+    # the dense group's scan and the expert group's, whose chunk program adds the three products
+    assert kernel in text and _custom_calls(compiled) == (5 if program == "chunk" else 2)
+    _assert_expert_products(compiled, program, [(64, 2048, 1408), (64, 1408, 2048)])
     assert _pool_copies(compiled, cache.k.shape)[0] == 0 and _pool_copies(compiled, cache.v.shape)[0] == 0
     mem = compiled.memory_analysis()
     print(f"\nkimi-vl-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "

@@ -150,6 +150,40 @@ def test_use_moe_tkg_force_only_with_structural_guards():
     )
 
 
+def test_use_grouped_matmul_serves_plain_experts_on_one_shard(monkeypatch):
+    """The grouped-matmul kernel's gate: no option forces it; it rests on the
+    entry (plain weights in the rows' dtype, widths on the lanes, at most a
+    bias), the mesh (ep = 1, one model-parallel shard) and the backend."""
+    bf16 = np.dtype("float32")  # any dtype: the gate compares, it does not compute
+    w = lambda *s: {"weight": np.ones(s, bf16)}
+    plain = {"gate_proj": w(4, 128, 256), "up_proj": w(4, 128, 256), "down_proj": w(4, 256, 128)}
+    assert not km.use_grouped_matmul(_moe_spec(), plain, bf16)  # the CPU harness
+    monkeypatch.setattr(km, "on_tpu", lambda: True)
+    assert km.use_grouped_matmul(_moe_spec(), plain, bf16)
+    stacked = {k: w(6, *v["weight"].shape) for k, v in plain.items()}  # (L, E, in, out)
+    assert km.use_grouped_matmul(_moe_spec(), stacked, bf16)
+    biased = {k: dict(v, bias=np.ones((4, v["weight"].shape[-1]), bf16)) for k, v in plain.items()}
+    assert km.use_grouped_matmul(_moe_spec(), biased, bf16)
+    assert not km.use_grouped_matmul(_moe_spec(), plain, np.dtype("float16"))  # a cast would copy
+    scaled = dict(plain, up_proj=dict(plain["up_proj"], scale=np.ones((4, 256))))
+    assert not km.use_grouped_matmul(_moe_spec(), scaled, bf16)
+    narrow = dict(plain, down_proj=w(4, 256, 48))  # off the 128 lanes
+    assert not km.use_grouped_matmul(_moe_spec(), narrow, bf16)
+    assert not km.use_grouped_matmul(_moe_spec(ep_degree=2), plain, bf16)
+    assert not km.use_grouped_matmul(_moe_spec(model_parallel=2), plain, bf16)
+
+
+def test_grouped_beats_dense_from_the_shapes():
+    """Passes over a layer's expert weights: dense ``rows / 240`` once
+    arithmetic bounds it, grouped a pass an expert a visit."""
+    assert km.grouped_beats_dense(16, 1, 1024) and km.grouped_beats_dense(16, 1, 512)
+    assert km.grouped_beats_dense(128, 8, 1024) and km.grouped_beats_dense(64, 6, 512)
+    assert km.grouped_beats_dense(8, 2, 1024)
+    assert not km.grouped_beats_dense(16, 1, 240)  # the stream bounds dense: one pass
+    assert not km.grouped_beats_dense(8, 4, 512)  # half the experts a token: 2.9 passes to 2.1
+    assert not km.grouped_beats_dense(4, 2, 1024)  # 4.8 passes to 4.3
+
+
 def test_use_ragged_allows_sharded_meshes():
     """The ISSUE 17 gate change: NO single-shard condition — the dispatch
     launches per head shard — but head counts must divide the

@@ -388,6 +388,11 @@ def test_moe_and_carry_counters_count_what_the_step_knows(app):
     assert experts["decode"] == steps["decode"] * 3 * 8  # every held expert of every layer, a dispatch
     assert experts["chunk"] == snap["nxdi_prefill_chunk_dispatches_total"]["samples"][0]["value"] * 3 * 8
     assert "nxdi_ssm_rows_advanced_total" not in snap or not snap["nxdi_ssm_rows_advanced_total"]["samples"]
+    # the routed rows again, by the strategy each pass's program holds: off the
+    # chip no pass takes the kernel, and chunks of under 64 positions are dense
+    grouped = {(x["labels"]["program"], x["labels"]["path"]): x["value"]
+               for x in snap["nxdi_moe_grouped_rows_total"]["samples"]}
+    assert grouped == {("chunk", "dense"): rows["chunk"], ("decode", "dense"): rows["decode"]}
 
 
 @pytest.mark.parametrize("tpu,match", [
