@@ -18,4 +18,5 @@ from neuronx_distributed_inference_tpu.models import dbrx  # noqa: F401
 from neuronx_distributed_inference_tpu.models import llama4  # noqa: F401
 from neuronx_distributed_inference_tpu.models import granite_hybrid  # noqa: F401
 from neuronx_distributed_inference_tpu.models import zaya  # noqa: F401
+from neuronx_distributed_inference_tpu.models import nemotron_h  # noqa: F401
 from neuronx_distributed_inference_tpu.models import sdar  # noqa: F401

@@ -334,6 +334,8 @@ def act_fn(name: str) -> Callable:
         "gelu": jax.nn.gelu,
         "gelu_pytorch_tanh": partial(jax.nn.gelu, approximate=True),
         "relu": jax.nn.relu,
+        # relu(x)^2 (the published nemotron_h ``relu2``)
+        "relu2": lambda x: jnp.square(jax.nn.relu(x)),
     }[name]
 
 
@@ -763,7 +765,8 @@ def decoder_layer(
         hidden = o_project(layer_params["self_attn"], attn_out, aspec, adapter_ids=adapter_ids)
         hidden = residual_add(residual, hidden, spec)
 
-    hidden = _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn)
+    if mlp_fn is not None:  # None: a block that is the attention part alone
+        hidden = _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn)
     if spec.cp_enabled and phase == PHASE_CONTEXT_ENCODING:
         from neuronx_distributed_inference_tpu.parallel import context_parallel as cpx
 

@@ -44,6 +44,7 @@ from neuronx_distributed_inference_tpu.config import (
     validate_slot_state_serving,
 )
 from neuronx_distributed_inference_tpu.models.base import (
+    EXPERT_CHOICES,
     PHASE_TOKEN_GENERATION,
     LayerStack,
     ModelSpec,
@@ -66,7 +67,7 @@ from neuronx_distributed_inference_tpu.modules.norm import rms_norm
 from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
 from neuronx_distributed_inference_tpu.ops.quant import linear
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION, MOE = "mamba", "attention", "moe"
 
 
 class GraniteHybridInferenceConfig(InferenceConfig):
@@ -99,20 +100,76 @@ class GraniteHybridInferenceConfig(InferenceConfig):
             validate_slot_state_serving(self.tpu_config)
 
 
+def _runs(kinds: Tuple[str, ...], seen: Dict[str, int]) -> List[Tuple[str, int, int]]:
+    """``kinds`` cut into runs of like layers: (kind, rank of the run's first
+    layer among the layers of that kind, counted on in ``seen``, length)."""
+    runs = []
+    for kind in kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1], runs[-1][2] + 1)
+        else:
+            runs.append((kind, seen.get(kind, 0), 1))
+        seen[kind] = seen.get(kind, 0) + 1
+    return runs
+
+
+def _counts(kinds) -> Dict[str, int]:
+    return {k: kinds.count(k) for k in set(kinds)}
+
+
 def layer_runs(kinds: Tuple[str, ...]) -> Tuple[int, List[Tuple[str, int, int]]]:
     """(number of periods, runs of one period): the shortest prefix that
     repeats to give ``kinds``, cut into runs of like layers — (kind, rank of
     the run's first layer among the period's layers of that kind, length)."""
     n = len(kinds)
     period = next(p for p in range(1, n + 1) if n % p == 0 and kinds == kinds[:p] * (n // p))
-    runs, seen = [], {MAMBA: 0, ATTENTION: 0}
-    for kind in kinds[:period]:
-        if runs and runs[-1][0] == kind:
-            runs[-1] = (kind, runs[-1][1], runs[-1][2] + 1)
-        else:
-            runs.append((kind, seen[kind], 1))
-        seen[kind] += 1
-    return n // period, runs
+    return n // period, _runs(kinds[:period], {})
+
+
+def layer_plan(kinds: Tuple[str, ...]) -> List[Tuple[int, List[Tuple[str, int, int]], Dict[str, int]]]:
+    """The stack as segments ``(repeats, runs of one unit, layers of each
+    kind a unit)``, in model order: each segment is one ``lax.scan`` over its
+    ``repeats`` of the unit (inline where 1), a run's first layer of repeat
+    ``p`` has rank ``first + p * a unit's layers of its kind`` among ALL the
+    stack's layers of its kind. A list that is a whole repeat is one segment
+    (:func:`layer_runs`: granite-4.0-h-micro, 4 periods of 10). Any other is
+    cut greedily: at each layer the unit that, repeated at least twice,
+    covers most (the shorter of two that cover alike), else the layer joins
+    an inline segment. ``MEMEM*EMEMEM*`` is (ME) x 2, M *, (EM) x 3, *: seven
+    compiled bodies for thirteen blocks, and for ANY depth of such a pattern
+    a number set by its units, not by its length."""
+    kinds = tuple(kinds)
+    periods, runs = layer_runs(kinds)
+    if periods > 1:
+        return [(periods, runs, _counts(kinds[: len(kinds) // periods]))]
+    plan, seen, inline, i, n = [], {}, [], 0, len(kinds)
+
+    def flush():
+        if inline:
+            plan.append((1, _runs(tuple(inline), seen), _counts(inline)))
+            inline.clear()
+
+    while i < n:
+        best = (0, 0, 0)  # (covered, -unit, repeats)
+        for u in range(1, (n - i) // 2 + 1):
+            unit, r = kinds[i : i + u], 1
+            while kinds[i + r * u : i + (r + 1) * u] == unit:
+                r += 1
+            if r > 1:
+                best = max(best, (u * r, -u, r))
+        covered, neg_u, r = best
+        if not covered:
+            inline.append(kinds[i])
+            i += 1
+            continue
+        flush()
+        unit = kinds[i : i - neg_u]
+        plan.append((r, _runs(unit, dict(seen)), _counts(unit)))
+        for k in unit:
+            seen[k] = seen.get(k, 0) + r
+        i += covered
+    flush()
+    return plan
 
 
 def _take(tree, i):
@@ -158,11 +215,11 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
         Bm = xBC[..., d_inner : d_inner + G * N].reshape(R, Q, G, N)
         Cm = xBC[..., d_inner + G * N :].reshape(R, Q, G, N)
 
-        if Q == 1 and G == 1 and slots is None:
+        if Q == 1 and slots is None:
             from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
 
             y, new_ssm = ssm_state_update(
-                state.ssm, li, xs[:, 0], Bm[:, 0, 0], Cm[:, 0, 0], dt[:, 0], A,
+                state.ssm, li, xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], A,
                 valid[:, 0], reset, interpret=kernel_interpret(),
             )
             y = y[:, None]
@@ -180,24 +237,42 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
             else:
                 new_ssm = state.ssm.at[li, slots].set(s, mode="drop", unique_indices=True)
         y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
-        gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps)
+        gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps,
+                                   groups=sspec.norm_groups)
         hidden = residual_add(hidden, linear(m["out_proj"], gated), spec)
-    hidden = _decoder_layer_mlp(lp, hidden, spec, mlp_fn)
+    if "mlp" in lp:  # a layer of two parts (granite); a single-part block ends here
+        hidden = _decoder_layer_mlp(lp, hidden, spec, mlp_fn)
     return hidden, ssm.RecurrentState(conv=conv, ssm=new_ssm)
 
 
-class HybridStack(LayerStack):
-    """Runs a stack of ``layer_types`` over ``HybridBlockCache``."""
+def moe_block(lp, hidden, spec: ModelSpec, expert_mlp, valid=None):
+    """A block that is an expert layer alone: norm, router, experts (and the
+    shared expert), residual. ``valid`` (B, S): the real positions (a padded
+    position of a chunk pass is routed to no expert). Returns (hidden, the
+    experts each position chose (B, S, k) under ``spec.output_choices``, else
+    None)."""
+    with jax.named_scope("layer.norm"):
+        x = rms_norm(hidden, lp["input_layernorm"]["weight"], spec.rms_eps)
+    with jax.named_scope("layer.mlp"):
+        out = expert_mlp(lp["mlp"], x, spec, valid=valid)
+        out, picked = out if spec.output_choices else (out, None)
+        return residual_add(hidden, out, spec), picked
 
-    def __init__(self, layer_types: Tuple[str, ...], sspec: ssm.SSMSpec):
+
+class HybridStack(LayerStack):
+    """Runs a stack of ``layer_types`` over ``HybridBlockCache``: blocks of
+    kind MAMBA (a state-space mixer), ATTENTION (paged K/V) and MOE (an
+    expert layer alone; ``expert_mlp`` a modules/moe.ExpertMlp). A MAMBA or
+    ATTENTION block whose parameters hold an ``mlp`` is a layer of two parts
+    (granite: the mixer, then the MLP, in one body); one that holds none is
+    the mixer alone. Each kind's weights stay stacked over ITS layers and a
+    block reads its own with a computed index (:func:`layer_plan`)."""
+
+    def __init__(self, layer_types: Tuple[str, ...], sspec: ssm.SSMSpec, expert_mlp=None):
         self.layer_types = tuple(layer_types)
         self.sspec = sspec
-        self.periods, self.runs = layer_runs(self.layer_types)
-        per_period = len(self.layer_types) // self.periods
-        self.per_period = {
-            kind: sum(1 for k in self.layer_types[:per_period] if k == kind)
-            for kind in (MAMBA, ATTENTION)
-        }
+        self.expert_mlp = expert_mlp
+        self.plan = layer_plan(self.layer_types)
 
     def __call__(self, params, hidden, cache, inputs, *, spec, phase, mlp_fn):
         if phase != PHASE_TOKEN_GENERATION or inputs.block_table is None:
@@ -212,43 +287,84 @@ class HybridStack(LayerStack):
         block_inputs = paged_block_inputs(inputs, cache.block_size)
         mask = build_mask(inputs, spec, phase)
         layers = params["layers"]
+        # the carry: hidden, pool, state and, where the step returns its
+        # choices, every expert layer's (L_moe, B, S, k)
+        n_moe = self.layer_types.count(MOE)
+        choices = spec.output_choices and n_moe > 0
 
         def attention(carry, li):
-            h, k, v, st = carry
+            h, k, v, *rest = carry
+            lp = _take(layers[ATTENTION], li)
             h, k, v = decoder_layer(
-                _take(layers[ATTENTION], li), h, None, None, k, v, li, mask,
-                inputs.seq_ids, positions, spec, phase, mlp_fn, block_inputs=block_inputs,
+                lp, h, None, None, k, v, li, mask,
+                inputs.seq_ids, positions, spec, phase, mlp_fn if "mlp" in lp else None,
+                block_inputs=block_inputs,
             )
-            return (h, k, v, st), None
+            return (h, k, v, *rest), None
 
         def mamba(carry, li):
-            h, k, v, st = carry
+            h, k, v, st, *rest = carry
             h, st = mamba_layer(
                 _take(layers[MAMBA], li), h, st, li, valid, reset, spec, self.sspec, mlp_fn,
                 slots=slots,
             )
-            return (h, k, v, st), None
+            return (h, k, v, st, *rest), None
 
         bodies = {MAMBA: mamba, ATTENTION: attention}
+        if n_moe:
+            from neuronx_distributed_inference_tpu.modules.moe import (
+                hoist_expert_stacks,
+                place_expert_stacks,
+            )
 
-        def period(carry, p):
-            for kind, first, count in self.runs:
-                base = p * self.per_period[kind] + first
-                if count == 1:
-                    carry, _ = bodies[kind](carry, base)
-                else:
-                    carry, _ = jax.lax.scan(
-                        bodies[kind], carry, base + jnp.arange(count, dtype=jnp.int32)
-                    )
-            return carry, None
+            B, S, _ = hidden.shape
+            # a pass that takes the grouped-matmul kernel reads the experts
+            # from the stacks in place (never a layer's slice of them)
+            moe_layers, expert_stacks = hoist_expert_stacks(
+                layers[MOE], self.expert_mlp.spec, S, B * S, hidden.dtype
+            )
+
+            def moe(carry, li):
+                h, *rest = carry
+                lp = place_expert_stacks(_take(moe_layers, li), expert_stacks, li)
+                h, picked = moe_block(lp, h, spec, self.expert_mlp, valid)
+                if choices:
+                    rest[-1] = jax.lax.dynamic_update_index_in_dim(rest[-1], picked, li, 0)
+                return (h, *rest), None
+
+            bodies[MOE] = moe
+
+        def unit(runs, per_unit):
+            def run(carry, p):
+                for kind, first, count in runs:
+                    base = p * per_unit[kind] + first
+                    if count == 1:
+                        carry, _ = bodies[kind](carry, base)
+                    else:
+                        carry, _ = jax.lax.scan(
+                            bodies[kind], carry, base + jnp.arange(count, dtype=jnp.int32)
+                        )
+                return carry, None
+
+            return run
 
         carry = (hidden, cache.k, cache.v, cache.state)
-        if self.periods == 1:
-            carry, _ = period(carry, jnp.int32(0))
-        else:
-            carry, _ = jax.lax.scan(period, carry, jnp.arange(self.periods, dtype=jnp.int32))
-        hidden, k, v, state = carry
-        return hidden, HybridBlockCache(k=k, v=v, state=state)
+        if choices:
+            top_k = self.expert_mlp.spec.top_k
+            carry += (jnp.zeros((n_moe,) + hidden.shape[:2] + (top_k,), jnp.int32),)
+        for repeats, runs, per_unit in self.plan:
+            if repeats == 1:
+                carry, _ = unit(runs, per_unit)(carry, jnp.int32(0))
+            else:
+                carry, _ = jax.lax.scan(
+                    unit(runs, per_unit), carry, jnp.arange(repeats, dtype=jnp.int32)
+                )
+        hidden, k, v, state, *rest = carry
+        new_cache = HybridBlockCache(k=k, v=v, state=state)
+        if not choices:
+            return hidden, new_cache
+        # (L_moe, B, S, k) -> (B, S, L_moe, k)
+        return hidden, new_cache, {EXPERT_CHOICES: jnp.transpose(rest[0], (1, 2, 0, 3))}
 
 
 @register_model("granitemoehybrid")

@@ -87,6 +87,77 @@ class MoESpec:
     # are a Neuron notion; on TPU one physical layout + an in-program
     # constraint is the equivalent lever)
     hybrid_cte_full_tp: bool = False
+    # a HELD SHARE of the routed experts (one rank of an expert-parallel
+    # group, served without its exchange): the layer holds the
+    # ``held_experts`` experts from ``first_expert`` on. The router keeps its
+    # ``num_experts`` columns and its ``top_k`` choices, normalised over the
+    # token's choices as published; the layer computes the part of the result
+    # its held experts give (plus the shared expert) and nothing stands in
+    # for the others. None = every expert (``held == num_experts``)
+    held_experts: Optional[int] = None
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.held_experts is None else self.held_experts
+
+    @property
+    def holds_share(self) -> bool:
+        return self.held < self.num_experts
+
+
+class ExpertLayerError(NotImplementedError):
+    """An option that would run an expert layer wrongly rather than not at
+    all was set for it (:func:`validate_expert_layer`)."""
+
+
+def two_matrix(experts: dict) -> bool:
+    """An expert of TWO matrices, ``down(act(up x))``, no gate (the published
+    ``nemotron_h`` relu2 experts), told by what the parameter tree holds. Both
+    are held ``(E, width, hidden)``: ``up_proj`` as published ``(out, in)``,
+    ``down_proj`` ``(in, out)``, so that the hidden size lies on the lanes
+    whatever the expert width (1856 is 14.5 lane tiles: on the minor axis the
+    stack would be padded in device memory and copied for a kernel)."""
+    return "gate_proj" not in experts
+
+
+def expert_projs(experts: dict) -> Tuple[str, ...]:
+    return tuple(name for name in _EXPERT_PROJS if name in experts)
+
+
+def validate_expert_layer(spec: MoESpec, experts: dict, quantized: bool = False) -> None:
+    """Refuse at config time what the two-matrix form or a held share cannot
+    serve (``experts``: one layer's expert entries or their shapes). One line
+    each: none is a silent wrong answer."""
+    refusals = []
+    if two_matrix(experts):
+        what = "two-matrix experts"
+        refusals += [
+            (spec.capacity_factor is not None, what,
+             "capacity_factor: the dropping dispatch is written for gated experts"),
+            (spec.moe_fused_kernel, what,
+             "moe_fused_kernel_enabled: ops/moe_decode.py computes gate, up and down"),
+            (quantized, what, "quantised experts: the two products take plain weights"),
+            (spec.early_affinity_modulation, what, "early_affinity_modulation"),
+        ]
+    if spec.holds_share:
+        if not 0 <= spec.first_expert <= spec.num_experts - spec.held or spec.held < 1:
+            raise ValueError(
+                f"held experts [{spec.first_expert}, {spec.first_expert + spec.held}) "
+                f"are not a share of {spec.num_experts}")
+        what = "a held share of the experts"
+        refusals += [
+            (spec.ep_degree > 1, what,
+             "ep_degree > 1: a held share is one rank's; the exchange is not built"),
+            (spec.capacity_factor is not None, what,
+             "capacity_factor: capacities are reckoned over every expert"),
+            (spec.moe_fused_kernel, what,
+             "moe_fused_kernel_enabled: the fused kernel indexes every expert"),
+            (spec.hybrid_cte_full_tp, what, "hybrid_sharding_config: there is no ep axis to fold"),
+        ]
+    for flag, what, why in refusals:
+        if flag:
+            raise ExpertLayerError(f"an expert layer with {what} cannot run with {why}")
 
 
 def router_top_k(
@@ -171,7 +242,7 @@ def _glu_fn(spec: MoESpec):
 def _has_blockwise_scales(params: dict) -> bool:
     from neuronx_distributed_inference_tpu.ops.quant_matmul import is_int4_entry
 
-    for name in ("gate_proj", "up_proj", "down_proj"):
+    for name in expert_projs(params):
         entry = params[name]
         if is_int4_entry(entry):
             # packed int4 experts dequantize at the matmul site in every
@@ -199,17 +270,29 @@ def _expert_entry(entry: dict, x_in: jax.Array) -> dict:
     return maybe_dequantize_int4(entry, x_in.shape[-1], x_in.dtype)
 
 
-def _sorted_dispatch(affinities: jax.Array, k: int):
+def _sorted_dispatch(affinities: jax.Array, k: int, first: int = 0, held: Optional[int] = None,
+                     valid: Optional[jax.Array] = None):
     """(T, E) affinity matrix -> token-replica rows sorted by expert:
-    (row_token (T*k,), row_expert, row_weight, group_sizes (E,))."""
+    (row_token (T*k,), row_expert, row_weight, group_sizes (E,)). Under a
+    held share (``held`` experts from ``first``) the experts are numbered
+    among the held and ``group_sizes`` is over them. A (row, choice) pair of
+    an expert outside the held, or of a token that is not ``valid`` ((T,)
+    bool: a padded position of a chunk pass), carries the number of groups,
+    sorts last and belongs to no group: the sizes may sum to less than T*k."""
     T, E = affinities.shape
     w_topk, e_topk = jax.lax.top_k(affinities, k)  # (T, k)
     flat_e = e_topk.reshape(T * k)
     flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
     flat_w = w_topk.reshape(T * k)
+    if held is not None and held < E:
+        E = held
+        flat_e = flat_e - first
+        flat_e = jnp.where((flat_e >= 0) & (flat_e < held), flat_e, held)
+    if valid is not None:
+        flat_e = jnp.where(jnp.repeat(valid, k), flat_e, E)
     order = jnp.argsort(flat_e)
     se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1, mode="drop")
     return st, se, sw, group_sizes
 
 
@@ -228,22 +311,27 @@ _EXPERT_PROJS = ("gate_proj", "up_proj", "down_proj")
 
 
 def _grouped_mm(entry: dict, x_rows: jax.Array, row_expert: jax.Array,
-                group_sizes: jax.Array, kernel: bool = False) -> jax.Array:
+                group_sizes: jax.Array, kernel: bool = False,
+                out_in: bool = False) -> jax.Array:
     """Ragged grouped matmul over expert-sorted rows — the Megablox-style GMM
     (reference BlockwiseMatmulConfig / nxd ExpertMLPsV2 blockwise path).
     x_rows (R, in) sorted by expert; weight (E, in, out) -> (R, out).
     ``kernel``: through ops/grouped_matmul.py (a plain entry, whose weight may
-    be a :class:`LayerOfStack`); else ``jax.lax.ragged_dot``."""
+    be a :class:`LayerOfStack`); else ``jax.lax.ragged_dot``. ``out_in``: the
+    weight is held (E, out, in) (:func:`two_matrix`)."""
     if kernel:
         from neuronx_distributed_inference_tpu.ops.grouped_matmul import grouped_matmul
         from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
 
         w = entry["weight"]
         stack, layer = w if isinstance(w, LayerOfStack) else (w, None)
-        y = grouped_matmul(x_rows, stack, group_sizes, layer, interpret=kernel_interpret())
+        y = grouped_matmul(x_rows, stack, group_sizes, layer, out_in=out_in,
+                           interpret=kernel_interpret())
     else:
         entry = _expert_entry(entry, x_rows)
         w = entry["weight"]
+        if out_in:
+            w = jnp.swapaxes(w, -1, -2)
         y = jax.lax.ragged_dot(x_rows, w.astype(x_rows.dtype), group_sizes)
     s = entry.get("scale")
     if s is not None:
@@ -259,22 +347,36 @@ def expert_mlps_grouped(
     affinities: jax.Array,  # (T, E)
     spec: MoESpec,
     kernel: bool = False,
+    valid: Optional[jax.Array] = None,  # (T,) bool: False = a padded position
 ) -> jax.Array:
     """Dropless sorted-token grouped dispatch: T·k rows of expert work
     instead of the dense path's E·T (a ~E/k FLOP reduction at prefill;
     VERDICT r2 weak #1). Reference: nxd ExpertMLPsV2 blockwise matmuls.
-    ``kernel``: the three products through the grouped-matmul kernel."""
-    glu = _glu_fn(spec)
-    st, se, sw, group_sizes = _sorted_dispatch(affinities, spec.top_k)
+    ``kernel``: the products through the grouped-matmul kernel (three, or the
+    two of a :func:`two_matrix` expert). Under a held share the rows of an
+    expert held elsewhere, and with ``valid`` the rows of a padded position,
+    are in no group: they cost no visit and add nothing."""
+    st, se, sw, group_sizes = _sorted_dispatch(
+        affinities, spec.top_k, spec.first_expert, spec.held_experts, valid)
     xs = x[st]  # (R, H) gathered token rows
     sww = sw.astype(x.dtype)[:, None]
     if spec.early_affinity_modulation:
         xs = xs * sww
-    g = _grouped_mm(params["gate_proj"], xs, se, group_sizes, kernel)
-    u = _grouped_mm(params["up_proj"], xs, se, group_sizes, kernel)
-    y = _grouped_mm(params["down_proj"], glu(g, u), se, group_sizes, kernel)  # (R, H)
+    if two_matrix(params):
+        from neuronx_distributed_inference_tpu.models.base import act_fn
+
+        u = _grouped_mm(params["up_proj"], xs, se, group_sizes, kernel, out_in=True)
+        y = _grouped_mm(params["down_proj"], act_fn(spec.act)(u), se, group_sizes, kernel)
+    else:
+        glu = _glu_fn(spec)
+        g = _grouped_mm(params["gate_proj"], xs, se, group_sizes, kernel)
+        u = _grouped_mm(params["up_proj"], xs, se, group_sizes, kernel)
+        y = _grouped_mm(params["down_proj"], glu(g, u), se, group_sizes, kernel)  # (R, H)
     if not spec.early_affinity_modulation:
         y = y * sww
+    if spec.holds_share or valid is not None:
+        # a row in no group was never computed: whatever stands there is not a number of ours
+        y = jnp.where((se < spec.held)[:, None], y, jnp.zeros((), y.dtype))
     return jnp.zeros_like(x).at[st].add(y)
 
 
@@ -371,8 +473,18 @@ def expert_mlps_dense(
             y = y + entry["bias"].astype(y.dtype)[:, None, :]
         return y
 
-    glu = _glu_fn(spec)
+    if spec.holds_share:  # the held columns: a choice held elsewhere adds nothing here
+        held = slice(spec.first_expert, spec.first_expert + spec.held)
+        affinities = affinities[:, held]
+        selected = None if selected is None else selected[:, held]
     aff = affinities.astype(x.dtype)
+    if two_matrix(params):
+        from neuronx_distributed_inference_tpu.models.base import act_fn
+
+        u = expert_mm(params["up_proj"], x, "th,eih->eti")
+        y = expert_mm(params["down_proj"], act_fn(spec.act)(u), "eti,eih->eth")  # (E, T, H)
+        return jnp.einsum("te,eth->th", aff, y)
+    glu = _glu_fn(spec)
     if spec.early_affinity_modulation:
         # scale expert inputs, combine unweighted over the SELECTED experts
         # (reference early_expert_affinity_modulation). The selection mask
@@ -523,15 +635,20 @@ def expert_path(spec: MoESpec, experts: dict, q_len: int, rows: int, dtype) -> s
         use_grouped_matmul,
     )
 
+    # under a held share: the experts held, and the rows EXPECTED here
+    share = spec.held / spec.num_experts
     if use_grouped_matmul(spec, experts, dtype):
-        return "kernel" if grouped_beats_dense(spec.num_experts, spec.top_k, rows) else "dense"
+        return "kernel" if grouped_beats_dense(spec.held, spec.top_k, rows, share) else "dense"
     return "ragged_dot" if spec.num_experts >= 16 * spec.top_k else "dense"
 
 
 def stacked_experts(layers) -> dict:
     """The expert entries of a model's stacked layer parameters
-    (``params["layers"]``: one stack, or a list of groups' stacks of which
-    one holds the expert layers), every leaf led by its layer axis."""
+    (``params["layers"]``: one stack, or a list of groups' stacks, or a dict
+    of kinds' stacks, of which one holds the expert layers), every leaf led
+    by its layer axis."""
+    if isinstance(layers, dict) and "mlp" not in layers:
+        layers = list(layers.values())
     groups = layers if isinstance(layers, (list, tuple)) else [layers]
     return next(g["mlp"]["experts"] for g in groups if "experts" in g.get("mlp", {}))
 
@@ -539,7 +656,7 @@ def stacked_experts(layers) -> dict:
 def _map_expert_projs(layers: dict, fn) -> dict:
     """``layers`` with ``fn(name, entry)`` in place of each expert projection."""
     experts = layers["mlp"]["experts"]
-    mapped = {name: fn(name, experts[name]) for name in _EXPERT_PROJS}
+    mapped = {name: fn(name, experts[name]) for name in expert_projs(experts)}
     return dict(layers, mlp=dict(layers["mlp"], experts=dict(experts, **mapped)))
 
 
@@ -554,7 +671,7 @@ def hoist_expert_stacks(layers: dict, spec: MoESpec, q_len: int, rows: int, dtyp
     experts = layers.get("mlp", {}).get("experts") if isinstance(layers, dict) else None
     if experts is None or expert_path(spec, experts, q_len, rows, dtype) != "kernel":
         return layers, None
-    stacks = {name: experts[name]["weight"] for name in _EXPERT_PROJS}
+    stacks = {name: experts[name]["weight"] for name in expert_projs(experts)}
     scanned = _map_expert_projs(
         layers, lambda _, entry: {k: v for k, v in entry.items() if k != "weight"}
     )
@@ -580,10 +697,10 @@ class ExpertMlp:
     def __init__(self, spec: MoESpec, shared_mlp_fn=None):
         self.spec, self.shared_mlp_fn = spec, shared_mlp_fn
 
-    def __call__(self, mlp_params, hidden, model_spec):
+    def __call__(self, mlp_params, hidden, model_spec, valid=None):
         return moe_layer(
             mlp_params, hidden, self.spec, shared_mlp_fn=self.shared_mlp_fn,
-            return_choices=model_spec.output_choices,
+            return_choices=model_spec.output_choices, valid=valid,
         )
 
 
@@ -594,6 +711,7 @@ def moe_layer(
     shared_mlp_fn=None,
     router=linear_router,
     return_choices: bool = False,
+    valid: Optional[jax.Array] = None,  # (B, S) bool
 ) -> jax.Array:
     """Full MoE block (reference initialize_moe_module product, moe_v2.py:23).
     With ``return_choices`` (a builder passes ``ModelSpec.output_choices``):
@@ -603,7 +721,11 @@ def moe_layer(
     ``router(params, x (T, H), spec) -> (affinities (T, E) float32, selected
     (T, E) bool)`` is the builder's: the linear router unless a model brings
     its own (models/zaya.py: a down-projection, a carry from the layer
-    before and an MLP). The expert strategy by shape below is shared."""
+    before and an MLP). The expert strategy by shape below is shared.
+    ``valid``: the positions that are real (False: a padded position of a
+    chunk pass, whose output nobody reads); a grouped strategy then leaves
+    their rows out of the sort. None (every caller but the hybrid stack's
+    single-part expert block): every position is routed, as before."""
     B, S, H = hidden.shape
     x = hidden.reshape(B * S, H)
     with jax.named_scope("layer.moe.router"):
@@ -641,7 +763,8 @@ def moe_layer(
         if path == "capacity":
             out = expert_mlps_capacity(expert_params, x, affinities, spec)
         elif path in ("kernel", "ragged_dot"):
-            out = expert_mlps_grouped(expert_params, x, affinities, spec, path == "kernel")
+            out = expert_mlps_grouped(expert_params, x, affinities, spec, path == "kernel",
+                                      None if valid is None else valid.reshape(B * S))
         elif path == "fused":
             # decode: DMA only the SELECTED experts' weights (k/E of the dense
             # path's HBM traffic; reference fused MoE TKG kernels, §2.10)
@@ -723,6 +846,9 @@ def shared_expert_mlp(params: dict, x: jax.Array, act_name: str = "silu") -> jax
     from neuronx_distributed_inference_tpu.ops.quant import linear
 
     act = act_fn(act_name)
+    if "gate_proj" not in params and "gate_up_proj" not in params:
+        # a two-matrix shared expert (the form of its routed ones: two_matrix)
+        return linear(params["down_proj"], act(linear(params["up_proj"], x)))
     if "gate_up_proj" in params:
         gu = linear(params["gate_up_proj"], x)
         g, u = jnp.split(gu, 2, axis=-1)

@@ -53,6 +53,9 @@ class SSMSpec:
     conv_kernel: int = 4
     chunk_size: int = 256
     rms_eps: float = 1e-5
+    #: equal parts of ``d_inner`` the gated norm divides each by its own root
+    #: mean square (Granite-4.0-H: 1 whatever ``n_groups``; nemotron_h: ``n_groups``)
+    norm_groups: int = 1
 
     @property
     def d_inner(self) -> int:
@@ -261,9 +264,17 @@ def mamba2_chunk(
     return y, new
 
 
-def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    """``rmsnorm_w(y * silu(z))`` over the last axis (one group): computed in
-    float32, returned in ``z``'s dtype."""
+def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array, eps: float,
+                   groups: int = 1) -> jax.Array:
+    """``rmsnorm_w(y * silu(z))`` over the last axis, each of its ``groups``
+    equal parts divided by its own root mean square (the published
+    ``nemotron_h`` gated norm has ``n_groups`` of them; Granite-4.0-H one):
+    computed in float32, returned in ``z``'s dtype."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-    return (g * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(z.dtype)
+    if groups == 1:
+        var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+        return (g * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(z.dtype)
+    parts = _grouped(g, g.ndim - 1, groups)
+    var = jnp.mean(jnp.square(parts), axis=-1, keepdims=True)
+    normed = (parts * jax.lax.rsqrt(var + eps)).reshape(g.shape)
+    return (normed * weight.astype(jnp.float32)).astype(z.dtype)

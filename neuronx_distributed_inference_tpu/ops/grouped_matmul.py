@@ -95,6 +95,7 @@ def _gmm_kernel(
     *scratch,  # (tm, tn) float32 where the contraction is tiled
     tm: int,
     tiles_k: int,
+    out_in: bool = False,  # the weight block is (tn, tk)
 ):
     del layer_ref
     v = pl.program_id(1)
@@ -102,7 +103,7 @@ def _gmm_kernel(
 
     def product():
         return jax.lax.dot_general(
-            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            x_ref[...], w_ref[...], (((1,), (1 if out_in else 0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -156,28 +157,42 @@ def _tiles(m: int, K: int, N: int, itemsize: int):
     return tm, tk, tn
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("out_in", "interpret"))
 def grouped_matmul(
     x_rows: jax.Array,  # (R, in) rows sorted by group
     weight: jax.Array,  # (E, in, out), or (L, E, in, out) with ``layer``
-    group_sizes: jax.Array,  # (E,) int32, summing to R
+    group_sizes: jax.Array,  # (E,) int32, summing to R or less
     layer: Optional[jax.Array] = None,  # () int32 index into the stack
     *,
+    out_in: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
     """``out[r] = x_rows[r] @ weight[(layer,) group of r]`` -> (R, out) in the
     rows' dtype. The weights are read where they lie: one ``(in, tn)`` tile of
-    one expert a fetch, no expert of an empty group, nothing copied."""
+    one expert a fetch, no expert of an empty group, nothing copied. Rows past
+    the last group (``group_sizes`` may sum to less than R: a held share of
+    the experts) are in no group and are never written. ``out_in``: the
+    weight is held ``(..., out, in)`` and multiplied transposed, one block of
+    a group's whole ``(out, in)`` (an ``out`` off the lanes, 1856, cannot be
+    tiled) or, over the budget, of ``(out, tk)``."""
     if weight.ndim == 3:
         weight, layer = weight[None], jnp.zeros((), jnp.int32)
     R, K = x_rows.shape
-    _, E, Kw, N = weight.shape
+    if out_in:
+        _, E, N, Kw = weight.shape
+    else:
+        _, E, Kw, N = weight.shape
     if Kw != K or weight.dtype != x_rows.dtype:
         raise ValueError(
             f"grouped_matmul: rows {x_rows.shape} {x_rows.dtype} against weights "
             f"{weight.shape} {weight.dtype}"
         )
-    tm, tk, tn = _tiles(R, K, N, weight.dtype.itemsize)
+    if out_in:
+        tm, tn, tk = _tiles(R, K, N, weight.dtype.itemsize)[0], N, K
+        while tk % 256 == 0 and tk * tn * weight.dtype.itemsize > 2 * _WEIGHT_BLOCK_BYTES:
+            tk //= 2
+    else:
+        tm, tk, tn = _tiles(R, K, N, weight.dtype.itemsize)
     tm = -(-tm // 16) * 16
     m = -(-R // tm) * tm
     if m != R:  # the padding belongs to no group: never stored, cut off below
@@ -190,6 +205,8 @@ def grouped_matmul(
         in_specs=[
             pl.BlockSpec((tm, tk), lambda n, v, k, li, off, g, t, nv: (t[v], k)),
             pl.BlockSpec(
+                (None, None, tn, tk), lambda n, v, k, li, off, g, t, nv: (li[0], g[v], n, k)
+            ) if out_in else pl.BlockSpec(
                 (None, None, tk, tn), lambda n, v, k, li, off, g, t, nv: (li[0], g[v], k, n)
             ),
         ],
@@ -197,7 +214,7 @@ def grouped_matmul(
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] if tiles_k > 1 else [],
     )
     out = pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm, tiles_k=tiles_k),
+        functools.partial(_gmm_kernel, tm=tm, tiles_k=tiles_k, out_in=out_in),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, N), x_rows.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -282,18 +282,22 @@ def use_grouped_matmul(spec, experts: dict, dtype) -> bool:
             w is not None
             and w.dtype == dtype
             and w.shape[-1] % 128 == 0
-            and w.shape[-2] % 128 == 0
+            # a two-matrix expert (modules/moe.two_matrix) holds its width, any
+            # multiple of a packed sublane tile, on the second-minor axis
+            and w.shape[-2] % (128 if gated else 16) == 0
         )
 
+    gated = "gate_proj" in experts
+    names = ("gate_proj", "up_proj", "down_proj") if gated else ("up_proj", "down_proj")
     return (
-        all(plain(experts.get(k)) for k in ("gate_proj", "up_proj", "down_proj"))
+        all(plain(experts.get(k)) for k in names)
         and spec.ep_degree == 1
         and single_shard(spec)
         and on_tpu()
     )
 
 
-def grouped_beats_dense(num_experts: int, top_k: int, rows: int) -> bool:
+def grouped_beats_dense(num_experts: int, top_k: int, rows: int, share: float = 1.0) -> bool:
     """Where the grouped-matmul kernel serves: grouped or dense, from the
     shapes, both reckoned in passes over the layer's expert weights. The
     dense form multiplies all ``rows`` tokens by every expert, ``rows``
@@ -322,9 +326,14 @@ def grouped_beats_dense(num_experts: int, top_k: int, rows: int) -> bool:
     Read on a v5e the products alone take 1.52, 1.55 and 1.66 x their stream
     at the first, third and fourth row (PERF.md section 6, PR 45): the
     passes this reckons, each at about what its fetch costs.
+
+    ``share``: under a held share of the experts (modules/moe.MoESpec
+    ``held_experts``) ``num_experts`` is the count held and ``share`` =
+    held / published of the ``rows * top_k`` routed rows is expected here
+    (64 of 128, top-6, 1024 rows: 24 + 63 visits = 1.4 passes against 4.3).
     """
     dense = max(1.0, rows / OPS_PER_WEIGHT_BYTE)
-    visits = -(-rows * top_k // GROUPED_ROW_TILE) + num_experts - 1
+    visits = -(-int(rows * top_k * share) // GROUPED_ROW_TILE) + num_experts - 1
     return max(1.0, visits / num_experts) < dense
 
 

@@ -27,8 +27,11 @@ Validity: ``dt = 0`` for an invalid row (decay 1, increment 0), so its state
 is rewritten bit for bit. ``reset`` rows start from zero (a select on the
 loaded tile, not a product: a non-finite state must not survive it).
 
-One group of B/C only (``mamba_n_groups`` 1); the caller keeps
-``modules/ssm.mamba2_step`` for anything else.
+Groups of B/C (``n_groups`` G; Granite-4.0-H 1, ``nemotron_h`` 8): head
+``h`` reads group ``h // (heads / G)``. A head block lies inside one group,
+or covers whole groups (``hb = 16`` over two groups of 8 heads: two lane
+vectors of B and of C a tile), so a tile's B and C are one block of
+``(groups a block, N)``. Held to ``modules/ssm.mamba2_step``.
 """
 
 from __future__ import annotations
@@ -45,16 +48,18 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_HEADS_PER_BLOCK = 16
 
 
-def _kernel(li_ref, reset_ref, coef_ref, b_ref, c_ref, s_ref, y_ref, out_ref, *, hb):
+def _kernel(li_ref, reset_ref, coef_ref, b_ref, c_ref, s_ref, y_ref, out_ref, *, hb, hpg):
     r = pl.program_id(0)
     P, N = s_ref.shape[-2], s_ref.shape[-1]
     coef = coef_ref[...]  # (P, 2 hb)
-    b = b_ref[...]  # (1, N)
-    c = c_ref[...]
+    bs = b_ref[...]  # (groups a block, N)
+    cs = c_ref[...]
     from_zero = jnp.full((P, N), reset_ref[r], jnp.int32) != 0
     lane = jax.lax.broadcasted_iota(jnp.int32, (P, hb), 1)
     y = jnp.zeros((P, hb), jnp.float32)
     for i in range(hb):
+        g = i // hpg  # the block's group that head i reads
+        b, c = (bs, cs) if bs.shape[0] == 1 else (bs[g : g + 1], cs[g : g + 1])
         s = jnp.where(from_zero, 0.0, s_ref[i])
         new = s * coef[:, hb + i : hb + i + 1] + coef[:, i : i + 1] * b
         out_ref[i] = new
@@ -62,9 +67,13 @@ def _kernel(li_ref, reset_ref, coef_ref, b_ref, c_ref, s_ref, y_ref, out_ref, *,
     y_ref[...] = y
 
 
-def pick_heads_per_block(num_heads: int, want: int = DEFAULT_HEADS_PER_BLOCK) -> int:
+def pick_heads_per_block(num_heads: int, want: int = DEFAULT_HEADS_PER_BLOCK,
+                         groups: int = 1) -> int:
+    """The most heads a tile, at most ``want``, that divide ``num_heads`` and
+    lie inside one group of B/C or cover whole groups."""
+    hpg = num_heads // groups
     hb = min(want, num_heads)
-    while num_heads % hb:
+    while num_heads % hb or (hb % hpg and hpg % hb):
         hb -= 1
     return hb
 
@@ -74,8 +83,8 @@ def ssm_state_update(
     state: jax.Array,  # (L, R, H, P, N) float32: EVERY layer's state
     layer_idx: jax.Array,  # int32 scalar
     x: jax.Array,  # (R, H, P)
-    B: jax.Array,  # (R, N)
-    C: jax.Array,  # (R, N)
+    B: jax.Array,  # (R, G, N), or (R, N): one group
+    C: jax.Array,  # as B
     dt: jax.Array,  # (R, H) after softplus
     A: jax.Array,  # (H,) negative
     valid: jax.Array,  # (R,) bool: False leaves the row's state as it is
@@ -87,9 +96,18 @@ def ssm_state_update(
     """Returns (y (R, H, P) float32 without the D skip, the stacked state with
     layer ``layer_idx`` advanced)."""
     L, R, H, P, N = state.shape
-    hb = heads_per_block or pick_heads_per_block(H)
-    assert H % hb == 0, (H, hb)
+    G = B.shape[1] if B.ndim == 3 else 1
+    hpg = H // G  # heads a group
+    hb = heads_per_block or pick_heads_per_block(H, groups=G)
+    assert H % hb == 0 and H % G == 0 and (hb % hpg == 0 or hpg % hb == 0), (H, G, hb)
     J = H // hb
+    gpb = max(1, hb // hpg)  # groups a head block covers
+    n_gb = G // gpb  # group blocks a row
+
+    def bc_block(r, j, li, rs):
+        # rows and group blocks on one axis: G = gpb (one group block a row) is row r
+        return (r if n_gb == 1 else r * n_gb + (j * hb) // (hpg * gpb), 0, 0)
+
     f32 = jnp.float32
     dt = jnp.where(valid[:, None], dt.astype(f32), 0.0)
     dA = jnp.exp(dt * A.astype(f32)[None, :])  # (R, H)
@@ -109,8 +127,8 @@ def ssm_state_update(
         grid=(R, J),
         in_specs=[
             pl.BlockSpec((None, None, P, 2 * hb), lambda r, j, li, rs: (r, j, 0, 0)),
-            pl.BlockSpec((None, 1, N), lambda r, j, li, rs: (r, 0, 0)),
-            pl.BlockSpec((None, 1, N), lambda r, j, li, rs: (r, 0, 0)),
+            pl.BlockSpec((None, gpb, N), bc_block),
+            pl.BlockSpec((None, gpb, N), bc_block),
             tile,
         ],
         out_specs=[
@@ -119,7 +137,7 @@ def ssm_state_update(
         ],
     )
     y, new = pl.pallas_call(
-        functools.partial(_kernel, hb=hb),
+        functools.partial(_kernel, hb=hb, hpg=hpg),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((R, J, P, hb), f32),
@@ -132,5 +150,6 @@ def ssm_state_update(
         ),
         interpret=interpret,
         name="ssm_state_update",
-    )(li, flags, coef, B.astype(f32).reshape(R, 1, N), C.astype(f32).reshape(R, 1, N), state)
+    )(li, flags, coef, B.astype(f32).reshape(R * n_gb, gpb, N),
+      C.astype(f32).reshape(R * n_gb, gpb, N), state)
     return jnp.transpose(y, (0, 1, 3, 2)).reshape(R, H, P), new

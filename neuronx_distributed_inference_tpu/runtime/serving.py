@@ -383,10 +383,13 @@ class ServingSession:
             from neuronx_distributed_inference_tpu.config import to_dtype
             from neuronx_distributed_inference_tpu.modules import moe
 
+            moe_spec = app.builder.moe_spec()
             self._expert_path = functools.lru_cache(maxsize=None)(functools.partial(
-                moe.expert_path, app.builder.moe_spec(),
+                moe.expert_path, moe_spec,
                 moe.stacked_experts(app.params["layers"]), dtype=to_dtype(tc.dtype),
             ))
+            # a reader tells a held share from a whole by this gauge
+            self.tel.moe_held(self.expert_layers[1], moe_spec.num_experts)
         # a model whose builder declares a block step generates block by
         # block: its rows' blocks, plans and commits (runtime/block_step.py)
         self.blocks = None

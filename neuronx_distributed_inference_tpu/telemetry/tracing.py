@@ -386,16 +386,27 @@ class TelemetrySession:
         self._moe_rows = r.counter(
             "nxdi_moe_rows_routed_total",
             "token rows the split serving step routed to an expert: real "
-            "token positions x expert layers x experts per token",
+            "token positions x expert layers x experts per token; choices "
+            "MADE, wherever the expert lies: under a held share "
+            "(nxdi_moe_experts_held) only the device knows which land here",
             labels=("program",))
         self._moe_experts = r.counter(
             "nxdi_moe_experts_hit_total",
             "experts whose weights a dispatch of the split serving step "
-            "streamed, summed over expert layers: EVERY expert a layer holds, "
+            "streamed, summed over expert layers: EVERY expert a layer holds "
+            "here (the held share's, where the layer holds one), "
             "since the decode strategy computes all of them (and at 48 rows "
             "top-1 of 16 hits 15.3 on average); not a count of the distinct "
             "experts with a live row, which only the device knows",
             labels=("program",))
+        self._moe_held = r.gauge(
+            "nxdi_moe_experts_held",
+            "routed experts of an expert layer whose weights this program "
+            "holds, of the published count in the label: less than it under a "
+            "held share (one rank of an expert-parallel group served without "
+            "its exchange: modules/moe.MoESpec.held_experts), else equal. Set "
+            "once, when a session is built",
+            labels=("of",))
         self._moe_grouped_rows = r.counter(
             "nxdi_moe_grouped_rows_total",
             "the routed token rows of nxdi_moe_rows_routed_total by the expert "
@@ -1171,6 +1182,13 @@ class TelemetrySession:
         if not self.enabled:
             return
         self._latent_tokens.child((program,)).inc(latents)
+
+    def moe_held(self, held: int, published: int) -> None:
+        """A session over a model with routed experts: how many of each
+        layer's published experts the program holds."""
+        if not self.enabled:
+            return
+        self._moe_held.child((str(int(published)),)).set(held)
 
     def moe_pass(self, program: str, rows_routed: int, experts: int, path: str) -> None:
         """One pass of the split serving step over a model with routed

@@ -729,3 +729,81 @@ def test_kimi_serving_step_runs_the_latent_kernels_and_fits_the_chip(chip_mesh, 
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# nemotron-3-nano-30b-a3b: single-part blocks of three kinds, 64 of 128 experts held
+# ---------------------------------------------------------------------------
+
+
+def _work_under_no_scope(compiled):
+    """Kernels and matrix products of a layer loop's body that stand under no
+    scope of the vocabulary (``layer.other`` is the loops' own bookkeeping:
+    tuple elements, index arithmetic, the rows' state gathered and put back)."""
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    table = device_scopes.scope_table(compiled.as_text())["ops"]
+    work = ("convolution", "dot", "grouped_matmul", "ssm_state_update", "paged_", "reduce")
+    return sorted(name for name, scope in table.items()
+                  if scope == device_scopes.LAYER_OTHER and name.startswith(work))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_nemotron_serving_step_runs_its_kernels_in_place_and_fits_the_chip(
+        chip_mesh, program, monkeypatch):
+    """nemotron-3-nano-30b-a3b at the benchmark's widths (benchmark/configs/
+    nemotron-3-nano-30b-a3b.json: MEMEM*EMEMEM*, 64 of 128 experts held, the
+    whole vocabulary, 64 slots, 12288 blocks over the 2 attention blocks),
+    both step programs compiled for a described v5e at kv bucket 8192.
+
+    decode (64 x 1): ``ssm_state_update`` with 8 groups of B/C under
+    ``layer.ssm`` (state aliased in and out: no copy of the state's shape,
+    whole or one block's) and ``paged_tkg_decode_attention``; the experts are
+    the batched products over the 64 held. chunk (8 x 128):
+    ``paged_flash_attention`` and the TWO grouped products of a two-matrix
+    expert as ``grouped_matmul`` on the stacks in place under
+    ``layer.moe.experts``; no ``ragged-dot``, nothing of the shape of a block's
+    (64, 1856, 2688) stack. Seven block bodies for thirteen blocks
+    (granite_hybrid.layer_plan). Each plans under 14.75 GiB."""
+    from neuronx_distributed_inference_tpu.models.granite_hybrid import layer_plan
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    # the gate asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
+    app, params, cache = _abstract_hybrid_app(chip_mesh(1), "nemotron-3-nano-30b-a3b")
+    assert cache.k.shape == (2, 12289, 2, 32, 128)
+    assert cache.state.ssm.shape == (6, 64, 64, 64, 128) and cache.state.conv.shape == (6, 3, 64, 6144)
+    experts = params["layers"]["moe"]["mlp"]["experts"]
+    assert experts["up_proj"]["weight"].shape == experts["down_proj"]["weight"].shape == (5, 64, 1856, 2688)
+    assert params["layers"]["moe"]["mlp"]["router"]["weight"].shape == (5, 2688, 128)
+    assert sum(len(runs) for _, runs, _ in layer_plan(app.builder.layer_types)) == 7
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((64, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    table = device_scopes.scope_table(text)["ops"]
+    assert "ragged-dot" not in text
+    assert not _copies_of(compiled, "f32", cache.state.ssm.shape)
+    assert not _copies_of(compiled, "f32", cache.state.ssm.shape[1:])
+    assert _pool_copies(compiled, cache.k.shape)[0] == 0
+    gmm = [name for name in table if name.startswith("grouped_matmul")]
+    ssm = [name for name in table if name.startswith("ssm_state_update")]
+    if program == "decode":
+        assert "paged_tkg_decode_attention" in text and not gmm
+        # one kernel call a state-space body of the plan: (ME) x 2, M *, (EM) x 3
+        assert len(ssm) == 3 and {table[c] for c in ssm} == {"layer.ssm"}
+    else:
+        assert "paged_flash_attention" in text and not ssm
+        assert not _stack_shaped(compiled, [(64, 1856, 2688)]), _stack_shaped(compiled, [(64, 1856, 2688)])[:3]
+        # two products a routed-expert body: (ME) x 2, (EM) x 3
+        assert len(gmm) == 4 and {table[c] for c in gmm} == {"layer.moe.experts"}
+    assert not _work_under_no_scope(compiled), _work_under_no_scope(compiled)[:5]
+    # decode 9.513 GiB (arguments 9.484: weights 7.97 + state 0.76 + pool 0.75), chunk 9.777
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (0.1 if program == "decode" else 0.4) * 2**30
+    print(f"\nnemotron-3-nano-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30

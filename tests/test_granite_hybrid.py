@@ -258,3 +258,39 @@ def test_bf16_serving_stays_within_the_twins_noise():
         twin = ref.twin_logits(bapp.params, geo, tokens, positions)
         err, floor = np.abs(got - want).max(), np.abs(twin - want).max()
         assert err <= correct.K * floor, (err, floor)
+
+
+NEMOTRON_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+@pytest.mark.parametrize("kinds,bodies", [
+    (tuple(NEMOTRON_PATTERN), 14),  # 52 single-part blocks of three kinds
+    (tuple(NEMOTRON_PATTERN[:13]), 7),  # the benchmark's prefix: (ME) x 2, M *, (EM) x 3, *
+    (tuple(NEMOTRON_PATTERN[:9]), 7),
+    (tuple(ATTRS["layer_types"]), None),
+    ((["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 4, 3),  # granite-4.0-h-micro
+    (tuple("MEM*E"), 5),
+])
+def test_layer_plan_visits_every_layer_once_in_order_with_bounded_bodies(kinds, bodies):
+    """The segments of granite_hybrid.layer_plan, unrolled, are the stack:
+    every layer once, in model order, each at its rank among its kind; the
+    compiled bodies (runs of all segments) do not grow with the repeats."""
+    from neuronx_distributed_inference_tpu.models.granite_hybrid import layer_plan, layer_runs
+
+    kinds = tuple(kinds)
+    plan = layer_plan(kinds)
+    visited = []
+    for repeats, runs, per_unit in plan:
+        for p in range(repeats):
+            for kind, first, count in runs:
+                visited += [(kind, p * per_unit[kind] + first + j) for j in range(count)]
+    seen, want = {}, []
+    for kind in kinds:
+        want.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    assert visited == want
+    if bodies is not None:
+        assert sum(len(runs) for _, runs, _ in plan) == bodies
+    periods, runs = layer_runs(kinds)
+    if periods > 1:  # a whole repeat is one segment, the one HybridStack scanned before
+        assert [(r, u) for r, u, _ in plan] == [(periods, runs)]

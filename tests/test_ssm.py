@@ -140,3 +140,53 @@ def test_pallas_state_update_is_the_step(heads_per_block):
     assert np.array_equal(np.asarray(new[li, 1]), np.asarray(stacked[li, 1]))  # the idle row
     for other in (0, 2):  # the other layers are not touched
         assert np.array_equal(np.asarray(new[other]), np.asarray(stacked[other]))
+
+
+@pytest.mark.parametrize("groups,heads_per_block", [(1, 4), (2, 2), (2, 4), (8, 1), (8, 2), (8, 8)])
+def test_pallas_state_update_with_groups_is_the_step(groups, heads_per_block):
+    """B and C of (rows, G, N): head h reads group h // (heads / G), whether a
+    head block lies inside one group or covers whole groups; an invalid row's
+    state is rewritten bit for bit, a reset row starts from zero."""
+    from neuronx_distributed_inference_tpu.ops.ssm_state_update import (
+        pick_heads_per_block,
+        ssm_state_update,
+    )
+
+    rows, heads, p, n, L, li = 4, 8, 8, 16, 2, 1
+    rng = np.random.default_rng(60 + groups)
+    x = jnp.asarray(rng.standard_normal((rows, heads, p)), jnp.float32)
+    B = jnp.asarray(rng.standard_normal((rows, groups, n)), jnp.float32)
+    C = jnp.asarray(rng.standard_normal((rows, groups, n)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.5, (rows, heads)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(0.5, 2.0, (heads,)), jnp.float32)
+    stacked = jnp.asarray(rng.standard_normal((L, rows, heads, p, n)), jnp.float32)
+    valid = jnp.asarray([True, False, True, True])
+    reset = jnp.asarray([False, False, True, False])
+    y, new = ssm_state_update(stacked, jnp.int32(li), x, B, C, dt, A, valid, reset,
+                              heads_per_block=heads_per_block, interpret=True)
+    start = jnp.where(reset[:, None, None, None], 0.0, stacked[li])
+    y_ref, s_ref = ssm.mamba2_step(x, B, C, dt, A, start, valid)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[li]), np.asarray(s_ref), rtol=1e-6, atol=1e-6)
+    assert np.array_equal(np.asarray(new[li, 1]), np.asarray(stacked[li, 1]))  # the invalid row
+    assert np.array_equal(np.asarray(new[0]), np.asarray(stacked[0]))  # the other layer
+    # the block the kernel picks for itself lies inside a group or covers whole ones
+    hb = pick_heads_per_block(64, groups=groups)
+    assert 64 % hb == 0 and (hb % (64 // groups) == 0 or (64 // groups) % hb == 0)
+    assert pick_heads_per_block(64, groups=1) == pick_heads_per_block(64) == 16
+
+
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_gated_norm_by_group_is_its_equation(groups):
+    rng = np.random.default_rng(7)
+    d = 64
+    y = jnp.asarray(rng.standard_normal((3, 5, d)), jnp.float32)
+    z = jnp.asarray(rng.standard_normal((3, 5, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((d,)), jnp.float32)
+    got = np.asarray(ssm.gated_rms_norm(y, z, w, 1e-5, groups=groups))
+    v = np.asarray(y) * np.asarray(z) / (1 + np.exp(-np.asarray(z)))
+    parts = v.reshape(3, 5, groups, d // groups)
+    want = (parts / np.sqrt(np.mean(parts ** 2, -1, keepdims=True) + 1e-5)).reshape(3, 5, d) * np.asarray(w)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    if groups > 1:  # and it is not the one-group norm
+        assert np.abs(got - np.asarray(ssm.gated_rms_norm(y, z, w, 1e-5))).max() > 1e-2
