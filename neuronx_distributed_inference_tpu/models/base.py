@@ -878,6 +878,18 @@ def gather_last_token(hidden: jax.Array, attention_mask: jax.Array) -> jax.Array
     return jnp.take_along_axis(hidden, idx[:, None, None], axis=1)
 
 
+def is_paged_chunk(phase: str, slot_mapping, block_table) -> bool:
+    """Whether a pass with these fields is a PAGED CHUNK pass (chunked and
+    prefix prefill). Field presence is the serving paths' convention: context
+    encoding a slot mapping only; decode, a block step and a speculation
+    verify a block table only; a chunk pass both."""
+    return (
+        phase != PHASE_CONTEXT_ENCODING
+        and slot_mapping is not None
+        and block_table is not None
+    )
+
+
 def paged_block_inputs(inputs: StepInputs, block_size: int):
     """(slot_mapping (B,S), block_table (B,MB), kv_limit (B,)) of a step on
     the paged cache."""
@@ -1304,7 +1316,16 @@ def model_logits(
     """Backbone + lm head, no sampling: returns (logits (B, K, V), new cache)
     [, full-sequence hidden states when ``return_hidden``; or, with
     ``return_aux``, what a :class:`LayerStack` returned beside its cache
-    (``spec.output_choices``: the layers' choices), None where nothing did].
+    (``spec.output_choices``: the layers' choices), None where nothing did,
+    and the logits at every position, None where ``logits`` are those].
+
+    The head runs where a token can leave the program. Context encoding:
+    each row's last valid position (K = 1). A paged chunk pass
+    (:func:`is_paged_chunk`): each row's last fed position (K = 1); under
+    ``spec.output_logits`` the head also runs over all S positions, an extra
+    output beside the (B, 1, V) the pass's token is taken from. Every other
+    token-generation pass (decode, a block step, a speculation verify): all
+    its positions (K = S).
 
     ``capture_layers``: EAGLE3 — with ``return_hidden``, the returned hidden
     is the (B, S, C*H) multi-layer capture concat instead of the final hidden
@@ -1338,17 +1359,27 @@ def model_logits(
         )
         full_hidden = hidden
 
+    def head(h):
+        return lm_head(params, h, spec)[..., : spec.vocab_size]  # (B, K, V)
+
+    every_position = None
     if phase == PHASE_CONTEXT_ENCODING:
         hidden = gather_last_token(hidden, inputs.attention_mask)
-    # TKG: all n_active positions produce logits
-
     with jax.named_scope("head"):
-        logits = lm_head(params, hidden, spec)[..., : spec.vocab_size]  # (B, K, V)
+        if is_paged_chunk(phase, inputs.slot_mapping, inputs.block_table):
+            if spec.output_logits:
+                every_position = head(hidden)
+            # a row's fed positions are those with a slot, a prefix of the
+            # row; one that sits out (no slot) clamps to position 0: garbage
+            # the host never reads (the ragged step's ``rows_h``)
+            hidden = gather_last_token(hidden, inputs.slot_mapping >= 0)
+        # any other TKG pass: all n_active positions produce logits
+        logits = head(hidden)
     logits = tensor_taps.tap("logits", logits)
     if return_hidden:
         return logits, new_cache, full_hidden
     if return_aux:
-        return logits, new_cache, (aux[0] if aux else None)
+        return logits, new_cache, (aux[0] if aux else None), every_position
     return logits, new_cache
 
 
@@ -1712,7 +1743,7 @@ def forward(
     layer_fn: Optional[Callable] = None,
 ) -> StepOutput:
     """The traced step function (reference NeuronBaseModel.forward, model_base.py:732)."""
-    logits, new_cache, aux = model_logits(
+    logits, new_cache, aux, every_position = model_logits(
         params, cache, inputs, spec=spec, phase=phase, mlp_fn=mlp_fn, layer_fn=layer_fn,
         return_aux=True,
     )
@@ -1726,6 +1757,7 @@ def forward(
         spec.block_step is not None
         and phase == PHASE_TOKEN_GENERATION
         and inputs.input_ids.shape[1] == spec.block_step.block_length
+        and not is_paged_chunk(phase, inputs.slot_mapping, inputs.block_table)
     ):
         with jax.named_scope("reveal"):
             tokens, confidence, next_ids = block_reveal(logits, inputs.input_ids, spec.block_step)
@@ -1748,7 +1780,9 @@ def forward(
         # position carries the sentinel there too
         next_ids = jnp.where(jnp.any(tokens < 0, axis=1, keepdims=True), NON_FINITE_TOKEN, next_ids)
 
-    out_logits = logits if spec.output_logits else None
+    out_logits = None
+    if spec.output_logits:
+        out_logits = logits if every_position is None else every_position
     return StepOutput(
         tokens=tokens, logits=out_logits, cache=new_cache, aux=aux,
         confidence=confidence, next_ids=next_ids,

@@ -835,10 +835,16 @@ class TpuModelForCausalLM:
         ``runner.chunk_rows`` wide and addresses its rows by slot: row ``i``
         belongs to slot ``seq_ids[i]`` whatever ``i`` is, more rows than
         the program is wide are run in groups, and the results come back in
-        the caller's row order.
+        the caller's row order. Its head runs where a token can leave it: it
+        returns ``tokens (B, 1)``, the token after each row's last fed
+        position (a row's fed positions are those with a slot in
+        ``slot_mapping``, a prefix of the row; a row that sits out returns
+        garbage), and, under ``TpuConfig.output_logits``
+        alone, logits at every position, ``(B, S, V)``.
 
         Returns (tokens (B, K) np.ndarray, logits (B, K, V) np.ndarray or
-        None) and, under ``TpuConfig.output_choices``, a third value: the
+        None; a paged chunk pass as above) and, under
+        ``TpuConfig.output_choices``, a third value: the
         choices the pass made, ``name -> int np.ndarray (B, S, ...)`` (an
         expert layer: ``(B, S, L_moe, k)``). Updates the app's KV cache in
         place; all scheduling state stays with the caller.

@@ -33,6 +33,7 @@ from neuronx_distributed_inference_tpu.models.base import (
     ModelSpec,
     StepInputs,
     forward,
+    is_paged_chunk,
 )
 from neuronx_distributed_inference_tpu.modules.autobucketing import get_target_bucket
 from neuronx_distributed_inference_tpu.modules.kvcache import KVCache, cache_spec
@@ -120,13 +121,9 @@ class SubModelRunner:
 
     def is_paged_chunk(self, slot_mapping, block_table) -> bool:
         """Whether a call with these fields runs the paged chunk program
-        (field presence is the serving paths' convention: CTE slot mapping
-        only; decode block table only; chunk / prefix prefill both)."""
-        return (
-            self.phase != PHASE_CONTEXT_ENCODING
-            and slot_mapping is not None
-            and block_table is not None
-        )
+        (models/base.is_paged_chunk: the test the step itself makes on its
+        inputs)."""
+        return is_paged_chunk(self.phase, slot_mapping, block_table)
 
     def program_for(self, inputs: StepInputs):
         """The jitted callable that serves these inputs."""

@@ -1195,10 +1195,14 @@ class ServingSession:
         real = sum(n for _, n in rows)
         tel = self.tel
         sampling = self._session_sampling_params()[:R]
+        # the chunk program projects each row's last fed position and returns
+        # tokens (R, 1); an application that returns logits keeps the head at
+        # every position beside it (models/base.model_logits)
+        head_q = qb if self.app.spec.output_logits else 1
         with tel.span(
             "serving.prefill_chunk", rows=len(rows), real_tokens=real,
             padded_tokens=len(groups) * R * qb - real, q_bucket=qb, kv_bucket=width,
-            dispatches=len(groups),
+            dispatches=len(groups), head_positions=len(groups) * R * head_q,
         ):
             # every group is dispatched before the first fetch is waited on
             # (the dispatches are asynchronous); a failed dispatch fails its
@@ -1282,15 +1286,17 @@ class ServingSession:
                         req.prefill_pos += n
                         if req.prefill_pos < req.prefill_target:
                             continue
+                        # tokens (R, 1): the output at the row's last fed
+                        # position, the one the program projects
                         if self.blocks is None:
                             # the last prompt token's output IS the first
                             # generated token
-                            self._finish_prefill(req, int(tokens[row, n - 1]))
+                            self._finish_prefill(req, int(tokens[row, 0]))
                         else:
                             # nothing is generated yet: the first block
                             # opens here, on what is left of the prompt
                             req.pos = req.prefill_pos
-                            if int(tokens[row, n - 1]) < 0:
+                            if int(tokens[row, 0]) < 0:
                                 self._quarantine(req)
         return True
 

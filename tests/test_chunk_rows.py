@@ -237,7 +237,8 @@ def test_the_warmed_chunk_program_is_the_served_one(model_type, caplog):
 def test_external_forward_addresses_chunk_rows_by_seq_id(served):
     """``app.forward(phase="tkg")`` with a slot mapping and a block table:
     row i belongs to slot ``seq_ids[i]`` whatever i is, rows beyond the
-    program's width run in groups, results come back in row order."""
+    program's width run in groups, results come back in row order: one
+    token a row, the chunk program's ``(B, 1)``."""
     app, _ = served
     tc = app.config.tpu_config
     bs, width, n = tc.pa_block_size, 64, 11
@@ -260,5 +261,5 @@ def test_external_forward_addresses_chunk_rows_by_seq_id(served):
     straight = run(np.arange(n))
     perm = np.random.default_rng(4).permutation(n)
     shuffled = run(perm)
-    assert straight.shape == (n, CHUNK)
+    assert straight.shape == (n, 1)  # the token after each row's last fed position
     np.testing.assert_array_equal(shuffled, straight[perm])
