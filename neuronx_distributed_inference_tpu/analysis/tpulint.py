@@ -160,7 +160,11 @@ SERVING_STEP_HOT_PATH = {
     "_note_acceptance",
     "_dispatch_decode",
     "_consume",
+    # a chunk pass and its two halves: the step dispatches it, dispatches the
+    # decode pass behind it, and only then waits for its tokens
     "_prefill_chunks",
+    "_dispatch_chunks",
+    "_commit_chunks",
     "_decode_drain",
     "_decode_chunk_pass",
 }

@@ -82,7 +82,11 @@ class BlockRows:
                 # first block: what is left of the prompt opens it
                 block = req.block = self._open(req, req.pos, req.input_ids[req.pos:])
             elif block.commit_dispatched:
-                ahead = len(req.generated) + self.length - block.known
+                # the commit's tokens are in flight, or (a session that
+                # consumes what it dispatches, async_mode off) already fetched
+                # and in ``generated``
+                in_flight = block.consumed <= block.denoise
+                ahead = len(req.generated) + (self.length - block.known if in_flight else 0)
                 start = block.start + self.length
                 if ahead >= req.max_new_tokens or start + self.length > self.pos_limit:
                     continue  # the commit in flight ends the request

@@ -1154,16 +1154,30 @@ class ServingSession:
         ):
             self._finish(req)
 
-    def _prefill_chunks(
+    def _prefill_chunks(self, reqs: List[Request], chunk_size: int) -> bool:
+        """One chunk pass at admission time (a prefix hit's uncached suffix):
+        dispatched and committed back to back. False when a request cannot
+        get KV blocks: nothing was dispatched and the caller drops it."""
+        flights = self._dispatch_chunks(reqs, chunk_size)
+        if flights is None:
+            return False
+        self._commit_chunks(flights, {})
+        return True
+
+    def _dispatch_chunks(
         self, reqs: List[Request], chunk_size: int, preempt: bool = False
-    ) -> bool:
-        """One batched prior-KV prefill pass: each request advances by up to
-        ``chunk_size`` prompt tokens (2-D (q_bucket, kv_bucket) program,
-        ``chunk_rows`` wide: one dispatch per group of that many requests).
+    ) -> Optional[list]:
+        """The dispatching half of one batched prior-KV prefill pass: each
+        request advances by up to ``chunk_size`` prompt tokens (2-D
+        (q_bucket, kv_bucket) program, ``chunk_rows`` wide: one dispatch per
+        group of that many requests). Nothing here waits for the device.
+        Returns the pass's flights for :meth:`_commit_chunks`, one
+        ``(rows, unfetched tokens)`` a dispatch with ``rows`` =
+        [(req, tokens fed, req.epoch)].
 
         A request that cannot get KV blocks is preempted when ``preempt``
         (step()-driven chunked serving — never stalls the session); otherwise
-        the pass returns False and the caller drops the request
+        the pass returns None and the caller drops the request
         (admission-time prefill)."""
         rows = []
         for req in reqs:
@@ -1174,12 +1188,12 @@ class ServingSession:
                 self._alloc(req.slot, req.prefill_pos + n)
             except RuntimeError:
                 if not preempt:
-                    return False
+                    return None
                 self._preempt(req)
                 continue
             rows.append((req, n))
         if not rows:
-            return True
+            return []
 
         # the chunk program is R rows wide and its rows are addressed by slot
         # (block table, slot mapping, seq_ids): the pass packs the requests
@@ -1204,9 +1218,7 @@ class ServingSession:
             padded_tokens=len(groups) * R * qb - real, q_bucket=qb, kv_bucket=width,
             dispatches=len(groups), head_positions=len(groups) * R * head_q,
         ):
-            # every group is dispatched before the first fetch is waited on
-            # (the dispatches are asynchronous); a failed dispatch fails its
-            # own rows and the other groups go on
+            # a failed dispatch fails its own rows and the other groups go on
             flights = []
             for group in groups:
                 with tel.span("serving.prefill_chunk.prepare"):
@@ -1248,10 +1260,10 @@ class ServingSession:
                 self._start_fetch(out.tokens)
                 self.app.kv_cache = out.cache
                 tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
-                flights.append((group, out.tokens))
+                flights.append(([(r, n, r.epoch) for r, n in group], out.tokens))
             if not flights:
-                return True
-            ran = [row for group, _ in flights for row in group]
+                return []
+            ran = [(r, n) for group, _ in flights for r, n, _ in group]
             ran_real = sum(n for _, n in ran)
             tel.step("prefill")
             # what the program really ran over: R rows at the q bucket a
@@ -1277,28 +1289,52 @@ class ServingSession:
             tel.pool_gauges(
                 len(self.active), self.kv_pool_bytes, self.kv_free_bytes
             )
-            with tel.span("serving.prefill_chunk.fetch_wait") as wait:
-                fetched = [(group, np.asarray(tokens)) for group, tokens in flights]
-            self._step_fetch_wait_s += wait.dur_s
-            with tel.span("serving.prefill_chunk.commit"):
-                for group, tokens in fetched:
-                    for row, (req, n) in enumerate(group):
+            if self.blocks is not None:
+                # a block-step row generates nothing at its prompt's end:
+                # what is left of the prompt opens its first block, and the
+                # host knows that without the pass's token. So the row opens
+                # here and takes its first decode pass in THIS step, behind
+                # the chunk pass it reads; the commit only looks for the
+                # non-finite sentinel
+                for req, n in ran:
+                    req.prefill_pos += n
+                    if not req.prefilling:
+                        req.pos = req.prefill_pos
+        return flights
+
+    def _commit_chunks(self, flights: list, results: Dict[str, int]) -> None:
+        """The committing half of a chunk pass: waits for the tokens of its
+        ``flights`` (:meth:`_dispatch_chunks`), advances the prompts and
+        commits a finished prompt's first token, into ``results`` too. A row
+        evicted or failed since its dispatch (stale epoch: a decode pass
+        dispatched in between could not get its blocks) is skipped; it
+        prefills again from position 0."""
+        if not flights:
+            return
+        tel = self.tel
+        with tel.span("serving.prefill_chunk.fetch_wait") as wait:
+            fetched = [(group, np.asarray(tokens)) for group, tokens in flights]
+        self._step_fetch_wait_s += wait.dur_s
+        with tel.span("serving.prefill_chunk.commit"):
+            for group, tokens in fetched:
+                for row, (req, n, epoch) in enumerate(group):
+                    if req.finished or req.epoch != epoch:
+                        continue
+                    if self.blocks is None:
                         req.prefill_pos += n
-                        if req.prefill_pos < req.prefill_target:
-                            continue
-                        # tokens (R, 1): the output at the row's last fed
-                        # position, the one the program projects
-                        if self.blocks is None:
-                            # the last prompt token's output IS the first
-                            # generated token
-                            self._finish_prefill(req, int(tokens[row, 0]))
-                        else:
-                            # nothing is generated yet: the first block
-                            # opens here, on what is left of the prompt
-                            req.pos = req.prefill_pos
-                            if int(tokens[row, 0]) < 0:
-                                self._quarantine(req)
-        return True
+                    if req.prefill_pos < req.prefill_target:
+                        continue
+                    # tokens (R, 1): the output at the row's last fed
+                    # position, the one the program projects
+                    first = int(tokens[row, 0])
+                    if self.blocks is None:
+                        # the last prompt token's output IS the first
+                        # generated token
+                        self._finish_prefill(req, first)
+                        if first >= 0:
+                            results[req.req_id] = first
+                    elif first < 0:
+                        self._quarantine(req)
 
     def _finish(self, req: Request, reason: Optional[str] = None, scrub: bool = False):
         # _finish can legitimately run twice for one request (an already-
@@ -1356,6 +1392,21 @@ class ServingSession:
         decode step for every decoding request. Returns {req_id: token} for
         tokens produced this step.
 
+        Order of a split step that holds both kinds of row: dispatch the
+        chunk pass, dispatch the decode pass behind it, THEN wait for the
+        chunk pass's tokens and commit them, then consume the decode pass
+        that is due. Every pass is queued on the device before the host waits
+        for any, so the device does not idle through the commit and the
+        decode rows' preparation. The decode rows need no token of the chunk
+        pass: a request whose prompt ends in it starts decoding next step
+        (its first token, this step's entry in the result, is the chunk
+        pass's), and the device runs the two programs in dispatch order with
+        the cache threaded between them. What the order costs: blocks freed
+        by a request that FINISHES at the chunk commit (one output token, an
+        EOS first) are free only after this step's decode rows took theirs,
+        so under an exhausted pool a decode row can be preempted a step
+        earlier; it resumes byte-identically.
+
         Containment wrapper (docs/SERVING.md "Failure containment"): each
         step also expires deadlines, re-admits preempted requests (aged
         ahead of new arrivals), fires any armed fault injections, and feeds
@@ -1410,32 +1461,21 @@ class ServingSession:
         if self.ragged:
             return self._ragged_step()
         results: Dict[str, int] = {}
-        prefill_finished: set = set()
+        # every pass the step holds is DISPATCHED before the step waits for
+        # any pass's tokens, and the waits come in dispatch order: the device
+        # runs chunk pass then decode pass back to back (the cache is threaded
+        # from one to the other without a fetch) while the host commits
+        flights: list = []
         if self.chunked and self.prefilling:
             batch = self.prefilling[: self.max_prefill_seqs]
-            before = {r.req_id: len(r.generated) for r in batch}
-            self._prefill_chunks(batch, self.chunk_size, preempt=True)
-            for r in batch:
-                if len(r.generated) > before.get(r.req_id, 0):
-                    results[r.req_id] = r.generated[-1]
-                    prefill_finished.add(r.req_id)
+            flights = self._dispatch_chunks(batch, self.chunk_size, preempt=True)
 
-        # requests that finished prefill THIS step start decoding next step,
-        # so their prefill-completion token isn't overwritten in results
-        active = [r for r in self.decoding if r.req_id not in prefill_finished]
-
-        if not self.async_decode:
-            # synchronous path (async_mode=False debugging): dispatch + fetch
-            # every step
-            if active:
-                rows = (
-                    [(r, r.pos) for r in active] if self.blocks is None
-                    else self.blocks.plan(active)[0]
-                )
-                out, snap = self._dispatch_decode(rows)
-                if out is not None:
-                    self._consume((self._step_ids(out), snap), results)
-            return results
+        # the decode rows need no token of the chunk pass: a request whose
+        # prompt ends in it is still ``prefilling`` until the commit below and
+        # starts decoding NEXT step (its first token is the chunk pass's, and
+        # stays this step's entry in results); a block-step row generates
+        # nothing there and was opened by the dispatch
+        active = self.decoding
 
         # async 1-ahead (reference modules/async_execution.py:190): dispatch
         # step k+1 CHAINED on step k's still-on-device tokens BEFORE fetching
@@ -1443,7 +1483,9 @@ class ServingSession:
         # executing k+1. The fetch gates only termination: rows whose request
         # terminates at step k ran one speculative step whose writes land in
         # masked/overwritten slots and whose token is discarded at the next
-        # consume.
+        # consume. The synchronous mode (async_mode=False debugging) takes the
+        # same order with nothing pending: what it dispatches it consumes
+        # below, at the end of this step.
         pend = self._pending
         self._pending = None
         # chain only rows whose pending entry is still CURRENT: a row that
@@ -1473,11 +1515,21 @@ class ServingSession:
                     chained_slots.append(r.slot)
                 else:
                     rows.append((r, r.pos))
+        ahead = None
         if rows:
             last_override = (pend[0], chained_slots) if chained_slots else None
-            out2, snap2 = self._dispatch_decode(rows, last_override)
-            if out2 is not None:
-                self._pending = (self._step_ids(out2), snap2)
+            out, snap = self._dispatch_decode(rows, last_override)
+            if out is not None:
+                ahead = (self._step_ids(out), snap)
+        if flights:
+            # the step holds a chunk pass: was a decode pass queued behind it
+            # while its tokens were still unfetched?
+            self.tel.chunk_step(decode_behind=ahead is not None)
+            self._commit_chunks(flights, results)
+        if self.async_decode:
+            self._pending = ahead
+        else:
+            pend = ahead
         if pend is not None:
             self._consume(pend, results)
         return results

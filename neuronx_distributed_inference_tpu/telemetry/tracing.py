@@ -336,6 +336,15 @@ class TelemetrySession:
             "nxdi_tokens_prefilled_total", "prompt tokens written to KV")
         self._steps = r.counter(
             "nxdi_steps_total", "model dispatches", labels=("kind",))
+        self._chunk_steps = r.counter(
+            "nxdi_chunk_steps_total",
+            "steps of the split serving path that held a chunk pass")
+        self._chunk_steps_behind = r.counter(
+            "nxdi_chunk_steps_decode_behind_total",
+            "of those, the steps whose decode pass was dispatched behind the "
+            "chunk pass while its tokens were still unfetched (over "
+            "nxdi_chunk_steps_total: the share of chunk-holding steps in "
+            "which the device had its next program queued)")
         self._bucket = r.counter(
             "nxdi_bucket_dispatch_total",
             "compiled-program census: which (model, bucket) served",
@@ -1147,6 +1156,19 @@ class TelemetrySession:
         self._prefill_real.inc(real_tokens)
         self._prefill_padded.inc(padded_tokens)
         self._prefill_dispatches.inc(dispatches)
+
+    def chunk_step(self, decode_behind: bool) -> None:
+        """One step of the split serving path that holds a chunk pass, told
+        before the pass's tokens are waited for: ``decode_behind`` says that
+        the step's decode pass is already dispatched behind it. Also a field
+        of the enclosing span, the step's."""
+        if not self.enabled:
+            return
+        self._chunk_steps.inc()
+        if decode_behind:
+            self._chunk_steps_behind.inc()
+        if self._span_stack.open:
+            self._span_stack.open[-1].note(decode_behind_chunk=decode_behind)
 
     def decode_pass(self, rows: int, slots: int) -> None:
         """One decode dispatch of the split serving step: its live rows and

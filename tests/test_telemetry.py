@@ -1166,8 +1166,9 @@ def test_span_tree_of_the_split_step(paged_app):
         "serving.prefill_chunk": "serving.step",
         "serving.prefill_chunk.prepare": "serving.prefill_chunk",
         "serving.prefill_chunk.dispatch": "serving.prefill_chunk",
-        "serving.prefill_chunk.fetch_wait": "serving.prefill_chunk",
-        "serving.prefill_chunk.commit": "serving.prefill_chunk",
+        # the committing half runs after the step's decode pass is dispatched
+        "serving.prefill_chunk.fetch_wait": "serving.step",
+        "serving.prefill_chunk.commit": "serving.step",
         "serving.decode": "serving.step",
         "serving.decode.prepare": "serving.decode",
         "serving.decode.dispatch": "serving.decode",
