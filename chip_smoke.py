@@ -395,17 +395,14 @@ def kernel_census(app, *, chunk_q=None, mixed_buckets=()):
             )
     B = tkg.batch_size
     sds = jax.ShapeDtypeStruct
-    for (steps, b, has_adapter, has_table), fn in tkg._decode_fns.items():
-        kwargs = {}
+    for (steps, b, has_adapter), fn in tkg._decode_fns.items():
         if has_adapter:
             continue
-        if has_table:
-            kwargs["block_table"] = sds((B, b // tkg.block_size), jnp.int32)
         with jax.set_mesh(tkg.mesh), tkg.seal_suspended():
             traced = fn.trace(
                 app.params, app.kv_cache, sds((B, 1), jnp.int32),
                 sds((B, 1), jnp.int32), sds((B,), jnp.int32),
-                sds((B, 3), jnp.float32), None, **kwargs,  # greedy: no rng
+                sds((B, 3), jnp.float32), None,  # greedy: no rng
             )
             compiled = traced.lower().compile()
         rows[f"tkg_decode[{steps}x,{b}]"] = _program_row(
@@ -518,7 +515,7 @@ def drain(session, prompts, budgets):
                                       max_new_tokens=budgets[nxt]),
                   f"request {nxt} refused")
             nxt += 1
-    session.run_to_completion(decode_chunk_size=16)
+    session.run_to_completion()
     return {rid: list(r.generated) for rid, r in session.requests.items()}
 
 

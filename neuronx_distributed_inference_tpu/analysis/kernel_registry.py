@@ -331,25 +331,6 @@ def _qmm_case(B, model):
     return build
 
 
-def _moe_case(T, k, E):
-    def build():
-        import jax.numpy as jnp
-
-        from neuronx_distributed_inference_tpu.ops import moe_decode as md
-
-        m = _1B
-        H, I = m["H"], m["I"]
-        x = _sds((T, H), jnp.bfloat16)
-        idx = _sds((T, k), jnp.int32)
-        w = _sds((T, k), jnp.float32)
-        wg = _sds((E, H, I), jnp.bfloat16)
-        wd = _sds((E, I, H), jnp.bfloat16)
-        fn = _unjit(md.fused_moe_decode)
-        return fn, (x, idx, w, wg, wg, wd)
-
-    return build
-
-
 def _grouped_mm_case(T, k, E, K, N, L):
     """One product of a chunk program's expert layer: ``T * k`` sorted rows
     over the ``(L, E, K, N)`` stack the layer scan holds."""
@@ -550,16 +531,6 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         ),
     ),
     KernelSpec(
-        name="fused_moe_decode",
-        site=("moe_decode.py", "fused_moe_decode"),
-        entry="fused_moe_decode",
-        fallback="neuronx_distributed_inference_tpu.modules.moe:expert_mlps_dense",
-        parity_test="tests/test_moe_dispatch.py",
-        tile_params=("ti_cap",),
-        sweep=(("ti_cap", (128, 256, 512)),),
-        cases=(KernelCase("h2048_i8192", "bfloat16", _moe_case(4, 2, 8)),),
-    ),
-    KernelSpec(
         name="grouped_matmul",
         site=("grouped_matmul.py", "grouped_matmul"),
         entry="grouped_matmul",
@@ -633,7 +604,6 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
         "blk4x32x128": {"pages": 16},
     },
     "ragged_paged_attention": {"*": {"tq": 16}},
-    "fused_moe_decode": {"*": {"ti_cap": 512}},
     "grouped_matmul": {"*": {"tm": 128}},
     "quant_matmul": {"*": {"bn": 256}},
 }

@@ -165,8 +165,6 @@ SERVING_STEP_HOT_PATH = {
     "_prefill_chunks",
     "_dispatch_chunks",
     "_commit_chunks",
-    "_decode_drain",
-    "_decode_chunk_pass",
 }
 
 #: ServingRouter per-tick functions (runtime/router.py): the placement /

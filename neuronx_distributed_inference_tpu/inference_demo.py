@@ -76,8 +76,6 @@ def build_parser() -> argparse.ArgumentParser:
     onoff("attention-softmax-fp32", True)
     run.add_argument("--seed", type=int, default=0)
     onoff("async-mode", True, help="chained decode chunks, one sync per call")
-    run.add_argument("--logical-nc-config", type=int, default=1)
-    run.add_argument("--scratchpad-page-size", type=int, default=None)
 
     # parallelism (reference config.py:333-361)
     run.add_argument("--tp-degree", type=int, default=1)
@@ -303,7 +301,6 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--modules-to-not-convert", nargs="+", default=None)
 
     # MoE (reference MoENeuronConfig flags)
-    run.add_argument("--capacity-factor", type=float, default=None)
     run.add_argument("--router-dtype", default="float32")
     run.add_argument("--early-expert-affinity-modulation", action="store_true")
     onoff("normalize-top-k-affinities", True)
@@ -455,8 +452,6 @@ def create_tpu_config(args) -> TpuConfig:
         attention_softmax_fp32=args.attention_softmax_fp32,
         seed=args.seed,
         async_mode=args.async_mode,
-        logical_nc_config=args.logical_nc_config,
-        scratchpad_page_size=args.scratchpad_page_size,
         compilation_cache_dir=args.compilation_cache_dir,
         save_sharded_checkpoint=args.save_sharded_checkpoint,
         tp_degree=args.tp_degree,
@@ -536,8 +531,7 @@ def create_tpu_config(args) -> TpuConfig:
         ),
     )
     moe = (
-        args.capacity_factor is not None
-        or args.early_expert_affinity_modulation
+        args.early_expert_affinity_modulation
         or args.router_dtype != "float32"
         or args.hidden_act_scaling_factor != 1.0
         or args.hidden_act_bias != 0.0
@@ -547,7 +541,6 @@ def create_tpu_config(args) -> TpuConfig:
     )
     if moe:
         return MoETpuConfig(
-            capacity_factor=args.capacity_factor,
             router_dtype=args.router_dtype,
             early_expert_affinity_modulation=args.early_expert_affinity_modulation,
             normalize_top_k_affinities=args.normalize_top_k_affinities,

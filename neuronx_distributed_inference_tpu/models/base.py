@@ -1398,7 +1398,6 @@ def decode_steps(
     mlp_fn: Callable = gated_mlp,
     layer_fn: Optional[Callable] = None,
     adapter_ids: Optional[jax.Array] = None,
-    block_table: Optional[jax.Array] = None,
 ):
     """Run ``num_steps`` whole decode iterations in ONE compiled program.
 
@@ -1407,13 +1406,6 @@ def decode_steps(
     steps keeps tokens, positions, masks, and the donated KV cache entirely
     device-resident, so the host pays one dispatch per CHUNK instead of per
     token — this is what async/1-ahead execution approximates on Neuron.
-
-    ``block_table`` (B, MB) extends the chunk to the PAGED cache: each
-    scan step derives its write slot in-graph from the table and the
-    advancing position (slot_mapping_from_block_table), so multi-step
-    serving drains work on block layouts too — the caller must have
-    allocated blocks covering positions up to pos + num_steps. ``bucket``
-    must equal MB * block_size.
 
     Returns (tokens (B, num_steps), logits (B, num_steps, V) | None, cache).
     """
@@ -1428,7 +1420,6 @@ def decode_steps(
             seq_ids=seq_ids,
             sampling_params=sampling_params,
             adapter_ids=adapter_ids,
-            block_table=block_table,
         )
         logits, cache = model_logits(
             params, cache, inputs, spec=spec, phase=PHASE_TOKEN_GENERATION,

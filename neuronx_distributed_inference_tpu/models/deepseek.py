@@ -266,10 +266,8 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
             routed_scaling_factor=float(getattr(cfg, "routed_scaling_factor", 1.0)),
             n_group=getattr(cfg, "n_group", 1),
             topk_group=getattr(cfg, "topk_group", 1),
-            capacity_factor=getattr(tc, "capacity_factor", None),
             ep_degree=tc.ep_degree,
             hybrid_cte_full_tp=bool(getattr(tc, "hybrid_sharding_config", None)),
-            moe_fused_kernel=getattr(tc, "moe_fused_kernel_enabled", None),
             model_parallel=self.degree,
         )
 

@@ -168,10 +168,8 @@ class GptOssModelBuilder(DecoderModelBuilder):
             act_scale=1.702,
             act_bias=1.0,
             swiglu_limit=float(getattr(cfg, "swiglu_limit", 7.0) or 7.0),
-            capacity_factor=getattr(tc, "capacity_factor", None),
             ep_degree=tc.ep_degree,
             hybrid_cte_full_tp=bool(getattr(tc, "hybrid_sharding_config", None)),
-            moe_fused_kernel=getattr(tc, "moe_fused_kernel_enabled", None),
             model_parallel=self.degree,
         )
 

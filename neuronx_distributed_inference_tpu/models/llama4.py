@@ -248,10 +248,8 @@ class Llama4TextModelBuilder(DecoderModelBuilder):
             normalize_top_k_affinities=False,
             early_affinity_modulation=True,
             act=getattr(cfg, "hidden_act", "silu"),
-            capacity_factor=getattr(tc, "capacity_factor", None),
             ep_degree=tc.ep_degree,
             hybrid_cte_full_tp=bool(getattr(tc, "hybrid_sharding_config", None)),
-            moe_fused_kernel=getattr(tc, "moe_fused_kernel_enabled", None),
             model_parallel=self.degree,
         )
 

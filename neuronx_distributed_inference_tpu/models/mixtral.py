@@ -96,10 +96,8 @@ class MoEDecoderModelBuilder(DecoderModelBuilder):
             # (reference MoENeuronConfig, config.py:679-680)
             act_scale=float(getattr(tc, "hidden_act_scaling_factor", 1.0)),
             act_bias=float(getattr(tc, "hidden_act_bias", 0.0)),
-            capacity_factor=getattr(tc, "capacity_factor", None),
             ep_degree=tc.ep_degree,
             hybrid_cte_full_tp=bool(getattr(tc, "hybrid_sharding_config", None)),
-            moe_fused_kernel=getattr(tc, "moe_fused_kernel_enabled", None),
             model_parallel=self.degree,
         )
 

@@ -199,10 +199,8 @@ class NemotronHModelBuilder(DecoderModelBuilder):
             act=cfg.hidden_act,
             scoring_func="sigmoid",
             routed_scaling_factor=float(getattr(cfg, "routed_scaling_factor", 1.0)),
-            capacity_factor=getattr(tc, "capacity_factor", None),
             ep_degree=tc.ep_degree,
             hybrid_cte_full_tp=bool(getattr(tc, "hybrid_sharding_config", None)),
-            moe_fused_kernel=getattr(tc, "moe_fused_kernel_enabled", None),
             model_parallel=self.degree,
             held_experts=(
                 cfg.n_routed_experts if cfg.n_routed_experts < cfg.published_experts else None),

@@ -14,9 +14,7 @@ Design:
   runs the DROPLESS sorted-token grouped path (T·k rows of work instead of
   E·T: the Pallas grouped matmul of ops/grouped_matmul.py on the experts'
   stack IN PLACE where it can serve the entry, ``jax.lax.ragged_dot``
-  elsewhere), or the CAPACITY-FACTOR dropping dispatch when
-  ``capacity_factor`` is set (reference MoENeuronConfig.capacity_factor /
-  BlockwiseMatmulConfig). :func:`expert_path` is the rule.
+  elsewhere). :func:`expert_path` is the rule.
 - Expert parallelism: expert dim sharded over the ``ep`` mesh axis, expert
   ffn dim over ``(cp, tp)`` — the combine over experts becomes a psum over
   ``ep``, emitted by GSPMD (reference moe_tp×moe_ep process groups,
@@ -59,10 +57,6 @@ class MoESpec:
     # moe_normalize_expert_weights); None = plain sum when
     # normalize_top_k_affinities
     norm_weights_p: Optional[float] = None
-    # capacity-factor (dropping) dispatch for prefill (reference
-    # MoENeuronConfig.capacity_factor + BlockwiseMatmulConfig); None = the
-    # dropless sorted-token grouped path
-    capacity_factor: Optional[float] = None
     # expert-parallel degree: > 1 keeps the dense all-experts path (the
     # grouped paths are token-sorted on one shard; EP dispatch rides the
     # dense einsum's GSPMD partitioning)
@@ -71,13 +65,8 @@ class MoESpec:
     # decode (S = 1..spec_len, any batch) stays dense (reference
     # moe_token_gen_all_experts)
     sparse_dispatch_threshold: int = 64
-    # fused selected-experts decode kernel (reference
-    # moe_fused_nki_kernel_enabled): None/False = native all-experts decode,
-    # True = force the Pallas kernel (ops/moe_decode.py) — structural guards
-    # still apply and fall back with a warning
-    moe_fused_kernel: Optional[bool] = None
     # full model-parallel degree (see AttnSpec.model_parallel: pallas_call
-    # has no GSPMD rule, so the fused kernel requires one shard)
+    # has no GSPMD rule, so the grouped-matmul kernel requires one shard)
     model_parallel: int = 1
     # hybrid CTE/TKG expert sharding (reference HybridShardingConfig,
     # models/config.py:694 + moe_v2.py:135-144): decode keeps the persistent
@@ -133,10 +122,6 @@ def validate_expert_layer(spec: MoESpec, experts: dict, quantized: bool = False)
     if two_matrix(experts):
         what = "two-matrix experts"
         refusals += [
-            (spec.capacity_factor is not None, what,
-             "capacity_factor: the dropping dispatch is written for gated experts"),
-            (spec.moe_fused_kernel, what,
-             "moe_fused_kernel_enabled: ops/moe_decode.py computes gate, up and down"),
             (quantized, what, "quantised experts: the two products take plain weights"),
             (spec.early_affinity_modulation, what, "early_affinity_modulation"),
         ]
@@ -149,10 +134,6 @@ def validate_expert_layer(spec: MoESpec, experts: dict, quantized: bool = False)
         refusals += [
             (spec.ep_degree > 1, what,
              "ep_degree > 1: a held share is one rank's; the exchange is not built"),
-            (spec.capacity_factor is not None, what,
-             "capacity_factor: capacities are reckoned over every expert"),
-            (spec.moe_fused_kernel, what,
-             "moe_fused_kernel_enabled: the fused kernel indexes every expert"),
             (spec.hybrid_cte_full_tp, what, "hybrid_sharding_config: there is no ep axis to fold"),
         ]
     for flag, what, why in refusals:
@@ -380,58 +361,6 @@ def expert_mlps_grouped(
     return jnp.zeros_like(x).at[st].add(y)
 
 
-def expert_mlps_capacity(
-    params: dict,
-    x: jax.Array,  # (T, H)
-    affinities: jax.Array,  # (T, E)
-    spec: MoESpec,
-) -> jax.Array:
-    """Capacity-factor (DROPPING) dispatch: each expert processes at most
-    ``C = ceil(T·k/E · capacity_factor)`` tokens; overflow token-replicas are
-    dropped (contribute zero), exactly the reference capacity_factor
-    semantics (MoENeuronConfig, config.py:665-713; nxd ExpertMLPsV2
-    capacity-factor path). Static (E, C, H) buffers keep the MXU batched."""
-    import math
-
-    T, H = x.shape
-    E, k = spec.num_experts, spec.top_k
-    C = max(1, math.ceil(T * k / E * spec.capacity_factor))
-    glu = _glu_fn(spec)
-    st, se, sw, group_sizes = _sorted_dispatch(affinities, k)
-    R = st.shape[0]
-    # position of each sorted row within its expert group
-    starts = jnp.cumsum(group_sizes) - group_sizes  # (E,)
-    pos = jnp.arange(R, dtype=jnp.int32) - starts[se]
-    keep = pos < C
-    # scatter kept rows into (E*C, H) buffers; dropped rows -> OOB (drop mode)
-    slot = jnp.where(keep, se * C + pos, E * C)
-    buf = jnp.zeros((E * C, H), x.dtype).at[slot].set(x[st], mode="drop")
-    xe = buf.reshape(E, C, H)
-    sww = sw.astype(x.dtype)[:, None]
-
-    def mm(entry, x_in, eq):
-        entry = _expert_entry(entry, x_in)
-        y = jnp.einsum(eq, x_in, entry["weight"].astype(x_in.dtype))
-        s = entry.get("scale")
-        if s is not None:
-            y = y * s.astype(y.dtype)[:, None, :]
-        if "bias" in entry:
-            y = y + entry["bias"].astype(y.dtype)[:, None, :]
-        return y
-
-    if spec.early_affinity_modulation:
-        w_buf = jnp.zeros((E * C, 1), x.dtype).at[slot].set(sww, mode="drop")
-        xe = xe * w_buf.reshape(E, C, 1)
-    g = mm(params["gate_proj"], xe, "ech,ehi->eci")
-    u = mm(params["up_proj"], xe, "ech,ehi->eci")
-    y = mm(params["down_proj"], glu(g, u), "eci,eih->ech").reshape(E * C, H)
-    rows = y[jnp.where(keep, slot, E * C - 1)]  # gather back (dropped: masked)
-    contrib = jnp.where(keep[:, None], rows, 0.0)
-    if not spec.early_affinity_modulation:
-        contrib = contrib * sww
-    return jnp.zeros_like(x).at[st].add(contrib)
-
-
 def expert_mlps_dense(
     params: dict,
     x: jax.Array,  # (T, H)
@@ -581,9 +510,6 @@ def expert_path(spec: MoESpec, experts: dict, q_len: int, rows: int, dtype) -> s
       over ``ep`` (the dispatch rides the einsum's GSPMD partitioning),
       blockwise-quantised experts, ``top_k == num_experts``, and every
       prefill-sized pass that the rule below does not send elsewhere;
-    - ``"fused"``: decode through ops/moe_decode.py, where forced;
-    - ``"capacity"``: a configured ``capacity_factor``, at prefill-sized
-      passes (unsupported combinations are refused at config validation);
     - ``"kernel"``: dropless grouped, its three products through
       ops/grouped_matmul.py. Where the kernel can serve the entry
       (kernel_mode.use_grouped_matmul: plain experts on one shard, on the
@@ -618,18 +544,13 @@ def expert_path(spec: MoESpec, experts: dict, q_len: int, rows: int, dtype) -> s
       taken only where it saves 16 x the arithmetic, ``num_experts >= 16 *
       top_k``, as before the kernel.
     """
-    prefill_sized = q_len >= spec.sparse_dispatch_threshold
-    if not prefill_sized:
-        from neuronx_distributed_inference_tpu.ops.moe_decode import use_moe_tkg_kernel
-
-        return "fused" if use_moe_tkg_kernel(spec, experts, rows) else "dense"
+    if q_len < spec.sparse_dispatch_threshold:
+        return "dense"
     # hybrid prefill is logically ep=1 (experts replicated over ep after the
     # constraint), so the token-sorted sparse paths apply
     ep_ok = spec.ep_degree == 1 or spec.hybrid_cte_full_tp
     if not ep_ok or spec.top_k >= spec.num_experts or _has_blockwise_scales(experts):
         return "dense"
-    if spec.capacity_factor is not None:
-        return "capacity"
     from neuronx_distributed_inference_tpu.ops.kernel_mode import (
         grouped_beats_dense,
         use_grouped_matmul,
@@ -760,29 +681,9 @@ def moe_layer(
         expert_params["down_proj"] = _cte_constrain(expert_params["down_proj"], False)
 
     with jax.named_scope("layer.moe.experts"):
-        if path == "capacity":
-            out = expert_mlps_capacity(expert_params, x, affinities, spec)
-        elif path in ("kernel", "ragged_dot"):
+        if path in ("kernel", "ragged_dot"):
             out = expert_mlps_grouped(expert_params, x, affinities, spec, path == "kernel",
                                       None if valid is None else valid.reshape(B * S))
-        elif path == "fused":
-            # decode: DMA only the SELECTED experts' weights (k/E of the dense
-            # path's HBM traffic; reference fused MoE TKG kernels, §2.10)
-            from neuronx_distributed_inference_tpu.ops.kernel_mode import (
-                kernel_interpret,
-            )
-            from neuronx_distributed_inference_tpu.ops.moe_decode import fused_moe_decode
-
-            w_topk, e_topk = jax.lax.top_k(affinities, spec.top_k)
-            out = fused_moe_decode(
-                x, e_topk.astype(jnp.int32), w_topk,
-                params["experts"]["gate_proj"]["weight"],
-                params["experts"]["up_proj"]["weight"],
-                params["experts"]["down_proj"]["weight"],
-                act=spec.act, act_scale=spec.act_scale, act_bias=spec.act_bias,
-                swiglu_limit=spec.swiglu_limit,
-                interpret=kernel_interpret(),
-            )
         else:
             out = expert_mlps_dense(expert_params, x, affinities, spec, selected)
     if shared_mlp_fn is not None:

@@ -33,7 +33,7 @@ call at degree 1), so their gates rest on what they can observe of the call —
 shape, ``head_dim``, kv width, backend — and on both head counts dividing the
 degree (:func:`heads_divide`; ``GQASharding`` guarantees it). The kernels with
 no such dispatch (contiguous flash prefill, whose q may be sequence-sharded
-under context parallelism; the fused MoE decode; the int4 matmul) keep
+under context parallelism; the grouped expert matmul; the int4 matmul) keep
 :func:`single_shard` in their auto condition.
 
 Gate summary (auto path):
@@ -44,7 +44,6 @@ kernel                        auto condition beyond the shape guards
 flash / packed prefill        single model-parallel shard, TPU backend
 paged flash prefill           TPU, q_len >= 64, heads divide the degree
 TKG decode (contig + paged)   TPU, kv_width >= 512, heads divide the degree
-fused MoE decode              OFF (force-only pending hardware wins)
 grouped expert matmul         TPU, plain experts in the rows' dtype on the
                               lanes, ep = 1, single shard (see
                               :func:`use_grouped_matmul`)
@@ -217,39 +216,6 @@ def use_paged_flash(spec, q_len: int) -> bool:
     if spec.use_flash_kernel:
         return True
     return q_len >= 64 and on_tpu()
-
-
-def use_moe_tkg(spec, params: dict, n_tokens: int) -> bool:
-    """Gate for the fused MoE decode kernel (``spec`` is a MoESpec). Plain
-    unquantized bias-free GLU experts, decode-sized token counts, single
-    model-parallel shard. AUTO stays OFF pending hardware wins; force-enable
-    still honors these structural guards but WARNS on fallback (the
-    flash-kernel convention)."""
-    enabled = spec.moe_fused_kernel
-    if not enabled:  # None (auto) stays OFF pending broader hardware wins
-        return False
-    plain = all(
-        isinstance(params.get(k), dict)
-        and "weight" in params[k]
-        and "scale" not in params[k]
-        and "bias" not in params[k]
-        for k in ("gate_proj", "up_proj", "down_proj")
-    )
-    ok = (
-        plain
-        and n_tokens * spec.top_k <= 64
-        and spec.ep_degree == 1
-        and single_shard(spec)
-        and not spec.early_affinity_modulation
-    )
-    if not ok:
-        log.warning(
-            "moe_fused_kernel_enabled=True but this configuration is "
-            "unsupported (needs plain unquantized bias-free experts, "
-            "T*k <= 64, ep=1, model_parallel=1, no early affinity "
-            "modulation); falling back to the dense all-experts path"
-        )
-    return ok
 
 
 #: operations a weight byte at which a v5e's arithmetic catches its weight

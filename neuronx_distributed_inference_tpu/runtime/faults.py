@@ -20,8 +20,7 @@ module carries the pieces the session uses to prove that:
   byte-identical to a run without one.
 
 Injection model: faults are armed per SESSION STEP (``session.step()``
-increments the index; multi-step drain chunks inside ``run_to_completion``
-count as the step that launched them). Every hook is a no-op unless a fault
+increments the index). Every hook is a no-op unless a fault
 is armed for the current step, and each armed fault fires exactly once —
 schedules built from the seed via :meth:`FaultInjector.random_schedule` are
 reproducible run-to-run.

@@ -303,12 +303,10 @@ class SubModelRunner:
         num_steps: int,
         bucket: int,
         adapter_ids: Optional[np.ndarray] = None,
-        block_table: Optional[np.ndarray] = None,
     ):
-        """Multi-step decode: num_steps tokens in one device dispatch
-        (models/base.py decode_steps). Host pays one call per chunk.
-        ``block_table`` (B, bucket//block_size) routes the chunk through the
-        PAGED cache — blocks must be pre-allocated for pos+num_steps."""
+        """Multi-step decode on the contiguous cache: num_steps tokens in one
+        device dispatch (models/base.py decode_steps). Host pays one call per
+        chunk."""
         from neuronx_distributed_inference_tpu.models.base import decode_steps
 
         self.last_bucket = bucket
@@ -327,7 +325,7 @@ class SubModelRunner:
             },
             B,
         )
-        key = (num_steps, bucket, adapter_ids is not None, block_table is not None)
+        key = (num_steps, bucket, adapter_ids is not None)
         fn = self._decode_fns.get(key)
         if fn is None:
             from neuronx_distributed_inference_tpu.analysis import retrace_guard
@@ -363,14 +361,6 @@ class SubModelRunner:
         kwargs = {}
         if adapter_ids is not None:
             kwargs["adapter_ids"] = jnp.asarray(arrs["adapter_ids"])
-        if block_table is not None:
-            # paged multi-step decode: the (padded) table must cover
-            # bucket // block_size blocks per row
-            kwargs["block_table"] = jnp.asarray(
-                self._pad_batch({"block_table": np.asarray(block_table, np.int32)}, B)[
-                    "block_table"
-                ]
-            )
         with jax.set_mesh(self.mesh):
             return fn(
                 params,

@@ -1420,8 +1420,7 @@ class ServingSession:
         (each terminating request runs one extra speculative device step
         whose writes land in masked slots and whose token is discarded).
         Per-step-latency-sensitive callers should construct the session's app
-        with ``async_mode=False`` for dispatch+fetch-per-step behavior;
-        :meth:`run_to_completion` always uses the fastest chained modes.
+        with ``async_mode=False`` for dispatch+fetch-per-step behavior.
         """
         self._step_index += 1
         tel = self.tel
@@ -2094,298 +2093,12 @@ class ServingSession:
         ):
             self._finish(req)
 
-    def run_to_completion(self, decode_chunk_size: int = 16) -> Dict[str, List[int]]:
-        """Drain the session. When every active request is decoding (no
-        prefill pending), decode runs in MULTI-STEP device chunks
-        (models/base.decode_steps) — one host sync per ``decode_chunk_size``
-        tokens instead of per token — on the contiguous AND the paged cache
-        (paged chunks derive per-step write slots in-graph from the block
-        table; blocks are pre-allocated per chunk, vLLM-style multi-step
-        scheduling). Requests that hit EOS mid-chunk overshoot by up to a
-        chunk of discarded tokens (causality makes them independent; they
-        are truncated on consume). Per-step semantics (step()) are unchanged
-        for interactive callers."""
-        spec = self.app.spec
-        if self.ragged:
-            # the ragged mode's whole point is ONE mixed dispatch per step;
-            # the multi-step TKG drain paths would reintroduce the split
-            while self.active or self._readmit:
-                self.step()
-            return {rid: r.generated for rid, r in self.requests.items()}
-        ring_cache = bool(spec.bounded_window or spec.ring_window)
+    def run_to_completion(self) -> Dict[str, List[int]]:
+        """Drain the session by :meth:`step` and return every request's
+        generated tokens."""
         while self.active or self._readmit:
-            self._expire_deadlines()
-            if not self.active:
-                # only evicted requests remain: step() re-admits (aging)
-                self.step()
-                continue
-            if (
-                self.prefilling
-                # ring caches: pow2 surplus steps would overwrite live ring
-                # slots MID-stream (slot = pos mod W); generate()'s surplus
-                # is safe only because it is terminal — stay per-step
-                or ring_cache
-                # a block step reveals and commits pass by pass: no chained
-                # multi-step program carries its schedule
-                or self.blocks is not None
-                or decode_chunk_size <= 1
-                or not self.decoding
-            ):
-                self.step()
-                continue
-            if all(r.eos_token_id is None for r in self.decoding):
-                # no EOS to observe: every remaining token count is known
-                # host-side — chain ALL chunks with device-resident tokens
-                # and fetch ONCE (generate()'s chained-decode structure)
-                self._decode_drain()
-            else:
-                self._decode_chunk_pass(decode_chunk_size)
-        return {rid: r.generated for rid, r in self.requests.items()}
-
-    def _chunk_block_table(self, rows, chunk: int, bucket: int):
-        """Paged-cache chunk prep: allocate blocks covering every row's next
-        NEEDED positions (min(chunk, remaining) — lockstep surplus steps for
-        rows that finish early write to table-zero entries, i.e. the
-        reserved garbage block, so they need no real blocks) and build the
-        (B, bucket//bs) table the in-graph slot mapping reads. Returns None
-        when the chunk can't run paged (bucket not block-aligned, or pool
-        exhausted — the per-step path preempts) —
-        ``rows`` = [(slot, pos, remaining_tokens), ...]."""
-        bs = self.allocator.block_size
-        if bucket % bs:
-            return None
-        mb = bucket // bs
-        table = np.zeros((self.num_slots, mb), np.int32)
-        for slot, pos, remaining in rows:
-            try:
-                # clamp to the row's COMMITTED end: drain passes advance
-                # `pos` in lockstep, so a finished row (remaining <= 0)
-                # arrives with pos past its last real token by -remaining —
-                # flooring the delta at 0 would allocate real blocks for its
-                # pure-garbage surplus positions (ADVICE r5)
-                self.allocator.alloc_seq(slot, pos + min(chunk, remaining))
-            except RuntimeError:
-                return None
-            # no _bt_sync here: the block-table matrix cache exists only on
-            # the ragged path, whose run_to_completion never reaches the
-            # multi-step drain (it would reintroduce the split)
-            table[slot] = self.allocator.block_table(slot, mb)
-        return table
-
-    def _decode_drain(self):
-        """Drain all decoding requests (no EOS) in chained multi-step chunks
-        with a single host sync at the end: rows that finish early keep
-        computing masked/discarded tokens — trading bounded waste for one
-        round trip total."""
-        if self._pending is not None:
-            self._consume(self._pending, {})
-            self._pending = None
-        active = self.decoding
-        if not active:
-            return
-        import jax.numpy as jnp
-
-        B = self.num_slots
-        last = np.zeros((B, 1), np.int32)
-        pos0 = np.zeros((B, 1), np.int32)
-        seq_ids = np.full((B,), -1, np.int32)
-        pos_limit = self.app._pos_limit()
-        need = {}
-        for r in list(active):
-            n = min(r.max_new_tokens - len(r.generated), pos_limit - 1 - r.pos)
-            if n < 1:
-                self._finish(r)  # at the sequence/length bound already
-                active.remove(r)
-                continue
-            last[r.slot, 0] = r.last_token
-            pos0[r.slot, 0] = r.pos
-            seq_ids[r.slot] = r.slot
-            need[r.slot] = n
-        if not active:
-            return
-        total = max(need.values())
-        last_dev = jnp.asarray(last)
-        pos = pos0.copy()
-        chunks = []
-        done = 0
-        while done < total:
-            headroom = pos_limit - 1 - int(pos.max())
-            chunk = pow2_bucket(min(total - done, 32))
-            if chunk > headroom:
-                # no surplus headroom: run the exact remainder (bounded by
-                # headroom; need[] already respects pos_limit per row)
-                chunk = min(total - done, headroom)
-            if chunk < 1:
-                break
-            bucket = self.app._decode_bucket(int(pos.max()) + chunk)
-            block_table = None
-            if self.block_mode:
-                block_table = self._chunk_block_table(
-                    [
-                        (r.slot, int(pos[r.slot, 0]), need[r.slot] - done)
-                        for r in active
-                    ],
-                    chunk, bucket,
-                )
-                if block_table is None:
-                    # pool exhausted mid-drain: consume what ran; with no
-                    # progress at all, one per-step pass preempts a request
-                    # so the next drain attempt can make headway
-                    if not chunks:
-                        self.step()
-                        return
-                    break
-            def dispatch(last_dev=last_dev, pos=pos, chunk=chunk, bucket=bucket,
-                         block_table=block_table):
-                with self.tel.span("serving.decode_chunk", steps=chunk):
-                    return self.app.token_generation_model.decode_chunk(
-                        self.app.params, self.app.kv_cache, last_dev, pos,
-                        seq_ids, self._session_sampling_params(), None,
-                        num_steps=chunk, bucket=bucket, block_table=block_table,
-                    )
-
-            res = self._guarded_dispatch("decode_chunk", active, dispatch)
-            if res is None:
-                # in-flight rows terminally FAILED(dispatch_error); commit
-                # nothing past their last consumed state
-                if not chunks:
-                    return
-                break
-            tokens_c, _, cache = res
-            self.app.kv_cache = cache
-            self.tel.step("decode")
-            self.tel.bucket_dispatch(self.app.token_generation_model.tag, bucket)
-            take = min(chunk, total - done)
-            chunks.append((tokens_c, take))
-            last_dev = tokens_c[:, take - 1 : take]
-            pos = pos + take
-            done += take
-        toks = np.concatenate(
-            [np.asarray(c)[:, :take] for c, take in chunks], axis=1
-        )  # ONE sync
-        if self.faults is not None:
-            toks = self.faults.corrupt_tokens(self, toks)
-        done = toks.shape[1]  # == sum of committed takes (early break safe)
-        # rows advance in LOCKSTEP, so the highest-position row's headroom
-        # caps this pass at `done` steps; rows needing more loop back through
-        # run_to_completion (the capped row finishes at its bound first and
-        # frees the headroom) — never silently under-generate
-        for r in active:
-            if r.finished or r.preempted:
-                continue  # failed mid-drain (dispatch_error) or evicted
-            n = min(need[r.slot], done)
-            row_toks = toks[r.slot, :n]
-            neg = np.flatnonzero(row_toks < 0)
-            if neg.size:
-                # non-finite sentinel mid-chunk: commit the finite prefix,
-                # quarantine the row — co-batched rows are untouched
-                m = int(neg[0])
-                r.generated.extend(int(t) for t in row_toks[:m])
-                self._commit_tokens(r, m)
-                r.pos += m
-                self._quarantine(r)
-                continue
-            r.generated.extend(int(t) for t in row_toks)
-            self._commit_tokens(r, n)
-            r.pos += n
-            if len(r.generated) >= r.max_new_tokens:
-                self._finish(r)
-        self.tel.pool_gauges(
-            len(self.active), self.kv_pool_bytes, self.kv_free_bytes
-        )
-
-    def _decode_chunk_pass(self, chunk: int):
-        """One multi-step decode dispatch for all decoding requests — on the
-        contiguous AND the paged cache (paged chunks allocate per-row block
-        coverage via :meth:`_chunk_block_table` and fall back to the per-step
-        path when the pool is exhausted). The 1-ahead pending step is flushed
-        first so chunk inputs start from consistent host state."""
-        if self._pending is not None:
-            self._consume(self._pending, {})
-            self._pending = None
-        active = self.decoding
-        if not active:
-            return
-        pos_limit = self.app._pos_limit()
-        max_pos = max(r.pos for r in active)
-        take = min(
-            chunk,
-            min(r.max_new_tokens - len(r.generated) for r in active),
-            pos_limit - 1 - max_pos,
-        )
-        if take < 1:
             self.step()
-            return
-        # round the compiled step count up to a power of two and discard the
-        # surplus host-side (the generate() chunk-reuse trick) so the jit
-        # cache stays O(log n) programs instead of one per odd remainder.
-        # Safe mid-stream ONLY for full-length caches (run_to_completion
-        # gates ring caches to the per-step path)
-        chunk = pow2_bucket(take)
-        if chunk > pos_limit - 1 - max_pos:
-            chunk = take  # no headroom for surplus steps
-        B = self.num_slots
-        last = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B, 1), np.int32)
-        seq_ids = np.full((B,), -1, np.int32)
-        for r in active:
-            last[r.slot, 0] = r.last_token
-            pos[r.slot, 0] = r.pos
-            seq_ids[r.slot] = r.slot
-        bucket = self.app._decode_bucket(int(pos.max()) + chunk)
-        block_table = None
-        if self.block_mode:
-            block_table = self._chunk_block_table(
-                [
-                    (r.slot, r.pos, r.max_new_tokens - len(r.generated))
-                    for r in active
-                ],
-                chunk, bucket,
-            )
-            if block_table is None:
-                self.step()  # pool exhausted: the per-step path preempts
-                return
-        def dispatch():
-            with self.tel.span("serving.decode_chunk", steps=chunk):
-                return self.app.token_generation_model.decode_chunk(
-                    self.app.params, self.app.kv_cache, last, pos, seq_ids,
-                    self._session_sampling_params(), None, num_steps=chunk,
-                    bucket=bucket, block_table=block_table,
-                )
-
-        res = self._guarded_dispatch("decode_chunk", active, dispatch)
-        if res is None:
-            return  # in-flight rows terminally FAILED(dispatch_error)
-        tokens_c, _, cache = res
-        self.app.kv_cache = cache
-        self.tel.step("decode")
-        self.tel.bucket_dispatch(self.app.token_generation_model.tag, bucket)
-        toks = np.asarray(tokens_c)  # ONE sync per chunk tokens
-        if self.faults is not None:
-            toks = self.faults.corrupt_tokens(self, toks)
-        for r in active:
-            n_obs = 0
-            finished = False
-            quarantined = False
-            for j in range(take):
-                tok = int(toks[r.slot, j])
-                if tok < 0:
-                    quarantined = True  # non-finite sentinel mid-chunk
-                    break
-                r.generated.append(tok)
-                n_obs += 1
-                r.pos += 1
-                if self._is_done(r, tok):
-                    finished = True
-                    break
-            self._commit_tokens(r, n_obs)
-            if quarantined:
-                self._quarantine(r)
-            elif finished:
-                self._finish(r)
-        self.tel.pool_gauges(
-            len(self.active), self.kv_pool_bytes, self.kv_free_bytes
-        )
+        return {rid: r.generated for rid, r in self.requests.items()}
 
 
 class SpeculativeServingSession(ServingSession):
@@ -3127,8 +2840,3 @@ class SpeculativeServingSession(ServingSession):
             ):
                 self._finish(r)
         return results
-
-    def run_to_completion(self, decode_chunk_size: int = 16) -> Dict[str, List[int]]:
-        while self.active or self._readmit:
-            self.step()
-        return {rid: r.generated for rid, r in self.requests.items()}

@@ -23,10 +23,11 @@ Two consequences, documented rather than hidden:
 
 - under async 1-ahead decode, a token's timestamp is its *observation* time
   (one step() late), not its device-completion time;
-- multi-step decode chunks observe N tokens in one fetch, so ITL is
-  amortized — the elapsed time since the previous observation divided by N,
-  observed N times (sum and count stay exact; per-token jitter inside a
-  chunk is invisible by construction, the chunk IS the latency unit there).
+- a pass that commits N tokens of a request in one fetch (a block step, a
+  speculation verify) observes them together, so ITL is amortized — the
+  elapsed time since the previous observation divided by N, observed N times
+  (sum and count stay exact; per-token jitter inside the pass is invisible
+  by construction).
 
 The retrace-guard bridge: an enabled session registers a listener with
 ``analysis.retrace_guard`` so every jit trace increments

@@ -92,20 +92,14 @@ class InputCaptureHook:
         save_inputs_snapshot(inputs, path, step=idx, tag=tag)
         self.saved.append(path)
 
-    def chunk(self, tag, last, pos, seq_ids, sampling_params, num_steps, bucket,
-              block_table=None):
-        """Capture a multi-step decode-chunk dispatch (decode_steps program;
-        paged chunks carry their block table so replay takes the same
-        cache-layout path)."""
+    def chunk(self, tag, last, pos, seq_ids, sampling_params, num_steps, bucket):
+        """Capture a multi-step decode-chunk dispatch (decode_steps program)."""
         idx = self.count
         self.count += 1
         if self.capture_indices is not None and idx not in self.capture_indices:
             return
         path = os.path.join(self.save_dir, f"{idx:05d}_{tag}.chunk.npz")
         os.makedirs(self.save_dir, exist_ok=True)
-        extra = {}
-        if block_table is not None:
-            extra["block_table"] = np.asarray(block_table)
         np.savez(
             path,
             __chunk=np.int64(1),
@@ -116,7 +110,6 @@ class InputCaptureHook:
             pos=np.asarray(pos),
             seq_ids=np.asarray(seq_ids),
             sampling_params=np.asarray(sampling_params),
-            **extra,
         )
         logger.info("saved chunk snapshot %s (steps=%s bucket=%s)", path, num_steps, bucket)
         self.saved.append(path)
@@ -146,7 +139,6 @@ def install_input_capture(app, save_dir: str, capture_indices=None) -> InputCapt
             hook.chunk(
                 _tag, args[2], args[3], args[4], args[5],
                 kwargs.get("num_steps"), kwargs.get("bucket"),
-                block_table=kwargs.get("block_table"),
             )
             return _orig(*args, **kwargs)
 
@@ -183,7 +175,6 @@ def replay_snapshot(app, path: str):
         if is_chunk:
             tag = bytes(z["__meta_tag"]).decode()
             payload = {k: z[k] for k in ("last", "pos", "seq_ids", "sampling_params")}
-            block_table = z["block_table"] if "block_table" in z.files else None
             num_steps = int(z["__num_steps"])
             bucket = int(z["__bucket"])
     if is_chunk:
@@ -192,7 +183,7 @@ def replay_snapshot(app, path: str):
                 return runner.decode_chunk(
                     app.params, replay_cache, payload["last"], payload["pos"],
                     payload["seq_ids"], payload["sampling_params"], None,
-                    num_steps=num_steps, bucket=bucket, block_table=block_table,
+                    num_steps=num_steps, bucket=bucket,
                 )
         raise ValueError(f"no runner with tag {tag!r} (snapshot {path})")
     inputs, meta = load_inputs_snapshot(path)

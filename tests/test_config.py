@@ -4,6 +4,7 @@ import pytest
 
 from neuronx_distributed_inference_tpu.config import (
     InferenceConfig,
+    MoETpuConfig,
     OnDeviceSamplingConfig,
     TpuConfig,
 )
@@ -49,6 +50,37 @@ def test_fault_containment_knob_defaults():
     tc2 = TpuConfig.from_dict(d)
     assert tc2.admission_validation is True
     assert tc2.watchdog_no_progress_steps == 256
+
+
+#: options that went: the two expert strategies no measurement chose (PR 50),
+#: the six reference-only names that were fields only so that setting them
+#: raised, and the two hardware knobs that did nothing
+GONE_OPTIONS = {
+    "capacity_factor": 1.5,
+    "moe_fused_kernel_enabled": True,
+    "is_eagle_target": True,
+    "is_eagle_draft": True,
+    "k_cache_transposed": True,
+    "rpl_reduce_dtype": "float32",
+    "kv_cache_padding_size": 2,
+    "weights_to_skip_layout_optimization": ["lm_head"],
+    "logical_nc_config": 2,
+    "scratchpad_page_size": 1024,
+}
+
+
+@pytest.mark.parametrize("how", ["constructor", "from_dict"])
+@pytest.mark.parametrize("name", list(GONE_OPTIONS))
+def test_an_option_that_went_is_refused_by_name(name, how):
+    """A name that is no field is refused BY NAME, live and from a file:
+    never accepted and ignored."""
+    kwargs = {name: GONE_OPTIONS[name]}
+    if how == "constructor":
+        with pytest.raises(TypeError, match=name):
+            MoETpuConfig(**kwargs)
+    else:
+        with pytest.raises(ValueError, match=name):
+            MoETpuConfig.from_dict(dict(MoETpuConfig().to_dict(), **kwargs))
 
 
 @pytest.mark.parametrize(

@@ -1,9 +1,9 @@
 """No silently-ignored feature flags (VERDICT r1 weak #4).
 
-Every TpuConfig field must be (a) consumed outside config.py, (b) raise when
-set to a non-inert value (UNIMPLEMENTED_FLAGS contract), or (c) sit on an
-explicit allowlist with a written justification. A field in none of the three
-buckets is config-surface padding and fails this test.
+Every TpuConfig field must be (a) consumed outside config.py or (b) sit on an
+explicit allowlist with a written justification. A field in neither bucket is
+config-surface padding and fails this test. (A name that is no field is
+refused by name: tests/test_config.py.)
 
 The scan itself lives in ``analysis/flag_audit.py`` (rule FLAG301) and shares
 the finding/allowlist format of the static-analysis subsystem; this test
@@ -15,18 +15,13 @@ audit as a CLI gate).
 import pytest
 
 from neuronx_distributed_inference_tpu.analysis import flag_audit
-from neuronx_distributed_inference_tpu.config import (
-    MoETpuConfig,
-    TpuConfig,
-    UNIMPLEMENTED_FLAGS,
-    UNIMPLEMENTED_MOE_FLAGS,
-)
+from neuronx_distributed_inference_tpu.config import TpuConfig
 
 
 def test_every_flag_used_raising_or_allowlisted():
     findings = flag_audit.run()
     assert findings == [], (
-        "TpuConfig fields neither consumed outside config.py, raising, nor "
+        "TpuConfig fields neither consumed outside config.py nor "
         "allowlisted (silently ignored):\n"
         + "\n".join(f.render() for f in findings)
     )
@@ -34,61 +29,14 @@ def test_every_flag_used_raising_or_allowlisted():
 
 def test_flag_audit_detects_orphans(tmp_path):
     """The audit must actually fire: scanning a tree that consumes nothing
-    reports every non-raising, non-allowlisted field."""
+    reports every non-allowlisted field."""
     (tmp_path / "empty.py").write_text("# consumes no flags\n")
     findings = flag_audit.run(root=tmp_path)
     names = {f.key for f in findings}
     assert "async_mode" in names  # a real consumed-elsewhere field
     assert all(f.rule == "FLAG301" for f in findings)
-    # allowlisted / raising fields stay exempt even in the empty tree
+    # allowlisted fields stay exempt even in the empty tree
     assert "pp_degree" not in names
-    assert not (set(UNIMPLEMENTED_FLAGS) & names)
-
-
-@pytest.mark.parametrize("name", sorted(UNIMPLEMENTED_FLAGS))
-def test_unimplemented_flag_raises(name):
-    inert, _ = UNIMPLEMENTED_FLAGS[name]
-    # a non-inert trigger value matching the field's type (dict literals keyed
-    # on values collide: False == 0, 1.0 == True)
-    if inert is False:
-        trigger = True
-    elif inert is None:
-        trigger = {"dummy": 1} if name.endswith("_config") else True
-    else:  # ints
-        trigger = inert + 2
-    if name == "rpl_reduce_dtype":
-        trigger = "float32"
-    if name == "weights_to_skip_layout_optimization":
-        trigger = ["lm_head"]
-    kwargs = {name: trigger}
-    # satisfy interaction validations that run before the unimplemented check
-    if name in ("is_chunked_prefill", "is_prefix_caching"):
-        kwargs["is_block_kv_layout"] = True
-    if name in ("enable_eagle_speculation",):
-        kwargs["enable_fused_speculation"] = True
-        kwargs["speculation_length"] = 4
-    if name == "medusa_speculation_length":
-        kwargs["num_medusa_heads"] = 2
-    if name == "attention_dp_degree":
-        kwargs["is_continuous_batching"] = True
-        kwargs["batch_size"] = 6  # divisible by the trigger dp degree
-    with pytest.raises(NotImplementedError):
-        TpuConfig(**kwargs)
-
-
-@pytest.mark.parametrize("name", sorted(UNIMPLEMENTED_MOE_FLAGS))
-def test_unimplemented_moe_flag_raises(name):
-    inert, _ = UNIMPLEMENTED_MOE_FLAGS[name]
-    if inert is False or inert is None:
-        trigger = True
-    else:  # floats
-        trigger = inert + 1.0
-    if name == "capacity_factor":
-        trigger = 1.5
-    if name == "hybrid_sharding_config":
-        trigger = {"dummy": 1}
-    with pytest.raises(NotImplementedError):
-        MoETpuConfig(**{name: trigger})
 
 
 def test_flash_decoding_requires_cp():

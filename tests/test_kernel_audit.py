@@ -466,4 +466,4 @@ def test_kernel_suite_run_inprocess_clean():
     assert report["n_registered"] == len(kr.REGISTRY)
     assert len(report["instances"]) == sum(len(s.cases) for s in kr.REGISTRY)
     text = ka.render_breakdown(report)
-    assert "fused_moe_decode/h2048_i8192/bfloat16" in text
+    assert "grouped_matmul/k2048_n2048/bfloat16" in text

@@ -129,27 +129,6 @@ def _moe_spec(**kw):
     return MoESpec(num_experts=4, top_k=2, **kw)
 
 
-def _plain_params():
-    w = {"weight": np.ones((4, 8, 16))}
-    return {"gate_proj": dict(w), "up_proj": dict(w), "down_proj": dict(w)}
-
-
-def test_use_moe_tkg_force_only_with_structural_guards():
-    params = _plain_params()
-    assert not km.use_moe_tkg(_moe_spec(), params, 4)  # auto stays OFF
-    assert km.use_moe_tkg(_moe_spec(moe_fused_kernel=True), params, 4)
-    # quantized/biased/int4 experts are structurally excluded
-    q = _plain_params()
-    q["up_proj"]["scale"] = np.ones((4, 16))
-    assert not km.use_moe_tkg(_moe_spec(moe_fused_kernel=True), q, 4)
-    assert not km.use_moe_tkg(
-        _moe_spec(moe_fused_kernel=True), params, 64
-    )  # T*k > 64
-    assert not km.use_moe_tkg(
-        _moe_spec(moe_fused_kernel=True, model_parallel=2), params, 4
-    )
-
-
 def test_use_grouped_matmul_serves_plain_experts_on_one_shard(monkeypatch):
     """The grouped-matmul kernel's gate: no option forces it; it rests on the
     entry (plain weights in the rows' dtype, widths on the lanes, at most a

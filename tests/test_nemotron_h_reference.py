@@ -256,8 +256,6 @@ def test_the_strategy_is_chosen_from_the_held_count_and_the_expected_rows():
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(capacity_factor=1.0), "capacity_factor"),
-    (dict(moe_fused_kernel=True), "moe_fused_kernel_enabled"),
     (dict(held=4, ep_degree=2), "ep_degree > 1"),
     (dict(held=4, hybrid_cte_full_tp=True), "hybrid_sharding_config"),
     (dict(quantized=True), "quantised experts"),
@@ -272,7 +270,7 @@ def test_what_two_matrix_experts_or_a_held_share_cannot_serve_is_refused_by_name
         moe.validate_expert_layer(_spec(held=4, first=5), params["experts"])
     # gated experts are held to none of the two-matrix rules
     gated = dict(params["experts"], gate_proj=params["experts"]["up_proj"])
-    moe.validate_expert_layer(_spec(capacity_factor=1.0), gated)
+    moe.validate_expert_layer(_spec(early_affinity_modulation=True), gated)
 
 
 @pytest.mark.parametrize("change,error,what", [

@@ -357,7 +357,7 @@ def test_run_to_completion_steps_pass_by_pass_and_the_counters_follow(served):
     rng = np.random.default_rng(16)
     assert s.add_request("a", rng.integers(0, VOCAB - 1, size=8), max_new_tokens=8)
     assert s.add_request("b", rng.integers(0, VOCAB - 1, size=10), max_new_tokens=6)
-    out = s.run_to_completion(decode_chunk_size=16)
+    out = s.run_to_completion()
     assert [len(out[k]) for k in "ab"] == [8, 6]
     snap = tel.registry.snapshot()
     total = lambda name, **labels: sum(

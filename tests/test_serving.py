@@ -100,8 +100,8 @@ def test_async_one_ahead_matches_sync():
 
 def test_drain_mixed_positions_no_eos():
     """Mixed-position no-EOS drain: a row near the position bound must not
-    cap other rows' token counts (the lockstep chunk headroom caps one PASS;
-    the loop continues after the bounded row finishes)."""
+    cap other rows' token counts (it finishes at its bound and the drain
+    goes on without it)."""
     cfg = make_tiny_config(
         tpu=dict(is_continuous_batching=True, batch_size=4, ctx_batch_size=1,
                  seq_len=64)
@@ -392,92 +392,95 @@ def test_gpt_oss_class_serving_session():
     assert results["long"] == golden["long"]
 
 
+def _drain_config(paged: bool, num_blocks: int = 16):
+    tpu = dict(
+        is_continuous_batching=True, batch_size=2, ctx_batch_size=1, seq_len=64,
+    )
+    if paged:
+        tpu.update(
+            is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=num_blocks
+        )
+    return make_tiny_config(tpu=tpu)
+
+
+def _drain_session(cfg, sd, prompts, budget, eos=None):
+    app = TpuModelForCausalLM(None, cfg).load(state_dict=sd)
+    sess = ServingSession(app)
+    for rid, p in prompts.items():
+        assert sess.add_request(
+            rid, p, max_new_tokens=budget, eos_token_id=(eos or {}).get(rid)
+        )
+    return app, sess
+
+
 @pytest.mark.slow
 def test_paged_chunked_drain_matches_per_step():
-    """Multi-step decode on the PAGED cache (vLLM-style multi-step
-    scheduling, r5): run_to_completion's chunked drains must emit exactly
-    the per-step path's tokens, with and without EOS observation."""
-    def _mk():
-        return make_tiny_config(
-            tpu=dict(
-                is_continuous_batching=True, batch_size=2, ctx_batch_size=1,
-                is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=16,
-                seq_len=64,
-            )
-        )
-
-    sd = make_random_hf_state_dict(_mk())
+    """run_to_completion on the PAGED cache over 20 tokens a row crosses a
+    block boundary: the tokens of a hand-written step loop, with and without
+    an EOS observed mid-stream."""
+    cfg = _drain_config(paged=True)
+    sd = make_random_hf_state_dict(cfg)
     prompts = {"r1": [5, 17, 92, 41], "r2": [64, 3, 27, 9, 14, 33]}
 
-    # per-step oracle
-    app1 = TpuModelForCausalLM(None, _mk()).load(state_dict=sd)
-    s1 = ServingSession(app1)
-    for rid, p in prompts.items():
-        assert s1.add_request(rid, p, max_new_tokens=20)
+    _, s1 = _drain_session(cfg, sd, prompts, 20)
     while s1.active:
         s1.step()
     golden = {rid: r.generated for rid, r in s1.requests.items()}
     assert all(len(v) == 20 for v in golden.values())
 
-    # chunked drain (no EOS -> _decode_drain chained chunks)
-    app2 = TpuModelForCausalLM(None, _mk()).load(state_dict=sd)
-    s2 = ServingSession(app2)
-    for rid, p in prompts.items():
-        assert s2.add_request(rid, p, max_new_tokens=20)
-    assert s2.run_to_completion(decode_chunk_size=8) == golden
+    _, s2 = _drain_session(cfg, sd, prompts, 20)
+    assert s2.run_to_completion() == golden
 
-    # EOS mid-stream -> _decode_chunk_pass with truncation on consume
     eos = golden["r1"][9]
     stop = golden["r1"].index(eos)  # first occurrence is where EOS stops
-    app3 = TpuModelForCausalLM(None, _mk()).load(state_dict=sd)
-    s3 = ServingSession(app3)
-    assert s3.add_request("r1", prompts["r1"], max_new_tokens=20, eos_token_id=eos)
-    assert s3.add_request("r2", prompts["r2"], max_new_tokens=20)
-    out = s3.run_to_completion(decode_chunk_size=8)
+    _, s3 = _drain_session(cfg, sd, prompts, 20, eos={"r1": eos})
+    out = s3.run_to_completion()
     assert out["r1"] == golden["r1"][: stop + 1]
     assert out["r2"] == golden["r2"]
 
 
-def test_chunk_block_table_no_alloc_for_finished_rows():
-    """ADVICE r5 (low): a drain chunk must not allocate real blocks for the
-    pure-garbage surplus positions of rows that already finished — the
-    allocation target is clamped to each row's committed end, so finished
-    rows ride the reserved garbage block and the pool stays flat."""
-    from types import SimpleNamespace
+@pytest.mark.parametrize(
+    "case", ["paged_eos", "paged_no_eos", "contiguous", "paged_small_pool"]
+)
+def test_run_to_completion_is_the_step_loop(case):
+    """run_to_completion drains by step(): its tokens are those of a
+    hand-written ``while s.active: s.step()`` session on the same requests
+    (an EOS mid-stream, none, the contiguous cache, and a pool too small for
+    both rows, where a row is preempted and re-admitted during the drain),
+    and the token-generation runner has compiled no multi-step program."""
+    paged = case != "contiguous"
+    small = case == "paged_small_pool"
+    cfg = _drain_config(paged, num_blocks=3 if small else 16)
+    sd = make_random_hf_state_dict(cfg)
+    if small:
+        # one block a prompt of the three the pool has: decoding past the
+        # block boundary runs the pool out for one of the two rows
+        prompts = {"r1": list(range(1, 16)), "r2": list(range(2, 17))}
+        budget = 6
+    else:
+        prompts = {"r1": [5, 17, 92, 41], "r2": [64, 3, 27, 9, 14, 33]}
+        budget = 8
 
-    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-        BlockAllocator,
-    )
+    _, by_hand = _drain_session(cfg, sd, prompts, budget)
+    while by_hand.active or by_hand._readmit:
+        by_hand.step()
+    golden = {rid: r.generated for rid, r in by_hand.requests.items()}
+    assert all(len(v) == budget for v in golden.values())
 
-    bs = 16
-    alloc = BlockAllocator(num_blocks=32, block_size=bs)
-    stub = SimpleNamespace(allocator=alloc, num_slots=4)
-    table_fn = ServingSession._chunk_block_table
+    eos = None
+    if case == "paged_eos":
+        tok = golden["r1"][3]
+        eos = {"r1": tok}
+        golden["r1"] = golden["r1"][: golden["r1"].index(tok) + 1]
+        # the hand-written loop observes the same EOS
+        _, by_hand = _drain_session(cfg, sd, prompts, budget, eos=eos)
+        while by_hand.active or by_hand._readmit:
+            by_hand.step()
+        assert {r: q.generated for r, q in by_hand.requests.items()} == golden
 
-    # two live rows at pos 32, one row that finished 24 steps ago (its
-    # lockstep pos has advanced to 56 but its committed end is 56-24=32)
-    alloc.alloc_seq(0, 32)
-    alloc.alloc_seq(1, 32)
-    alloc.alloc_seq(2, 32)
-    free_before = len(alloc.free)
-    blocks_finished_before = len(alloc.seq_blocks[2])
-
-    chunk = 16
-    rows = [(0, 32, 100), (1, 32, 8), (2, 56, -24)]
-    table = table_fn(stub, rows, chunk, bucket=128)
-    assert table is not None
-
-    # live rows got exactly the blocks their NEEDED positions cover
-    assert len(alloc.seq_blocks[0]) == -(-(32 + chunk) // bs)  # full chunk
-    assert len(alloc.seq_blocks[1]) == -(-(32 + 8) // bs)  # remaining < chunk
-    # the finished row allocated NOTHING
-    assert len(alloc.seq_blocks[2]) == blocks_finished_before
-    used = (
-        len(alloc.seq_blocks[0]) + len(alloc.seq_blocks[1])
-        + len(alloc.seq_blocks[2])
-    )
-    assert len(alloc.free) == free_before - (used - 3 * blocks_finished_before)
-
-    # its surplus positions resolve to table-zero entries (garbage block 0)
-    committed_blocks = -(-32 // bs)
-    assert (table[2][committed_blocks:] == 0).all()
+    app, sess = _drain_session(cfg, sd, prompts, budget, eos=eos)
+    assert sess.run_to_completion() == golden
+    assert sess._step_index == by_hand._step_index
+    if small:
+        assert max(r.preemptions for r in sess.requests.values()) >= 1
+    assert app.token_generation_model._decode_fns == {}

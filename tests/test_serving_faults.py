@@ -344,36 +344,6 @@ def test_nan_tokens_host_boundary_quarantine(paged_apps):
     )
 
 
-def test_sentinel_in_multistep_chunk_commits_finite_prefix(paged_apps):
-    """The multi-step drain paths scan fetched chunks for the sentinel:
-    the finite prefix commits, the row quarantines, co-batched rows keep
-    their full chunks."""
-    legacy, _ = paged_apps
-    legacy.init_kv_cache()
-    golden_sess = ServingSession(legacy)
-    eos_probe = {"a": [5, 17, 92, 41], "b": [64, 3, 27, 9]}
-    for rid, p in eos_probe.items():
-        assert golden_sess.add_request(rid, p, max_new_tokens=12)
-    golden = golden_sess.run_to_completion(decode_chunk_size=4)
-
-    from neuronx_distributed_inference_tpu.runtime import faults as faults_mod
-
-    legacy.init_kv_cache()
-    sess = ServingSession(legacy)
-    for rid, p in eos_probe.items():
-        assert sess.add_request(rid, p, max_new_tokens=12)
-    # a few committed tokens first, then poison row 0 mid-flight and let the
-    # chunked drain discover the sentinel inside a fetched chunk
-    sess.step()
-    sess.step()
-    faults_mod._poison_row(sess, 0)
-    out = sess.run_to_completion(decode_chunk_size=4)
-    assert sess.requests["a"].fail_reason == "non_finite"
-    assert out["a"] == golden["a"][: len(out["a"])]
-    assert len(out["a"]) < 12
-    assert out["b"] == golden["b"]
-
-
 # ---------------------------------------------------------------------------
 # forced pool exhaustion, preemption re-admission fairness
 # ---------------------------------------------------------------------------

@@ -368,37 +368,6 @@ def test_lower_model_tkg_with_kernels(B):
         lower_tpu(fn, params, cache, inputs, None)
 
 
-@pytest.mark.parametrize("T,k,H,I,E", [(1, 2, 2048, 8192, 8), (4, 8, 2048, 1024, 64)])
-def test_lower_fused_moe_decode(T, k, H, I, E):
-    from neuronx_distributed_inference_tpu.ops.moe_decode import fused_moe_decode
-
-    x = sds((T, H), jnp.bfloat16)
-    idx = sds((T, k), jnp.int32)
-    w = sds((T, k), jnp.float32)
-    wg = sds((E, H, I), jnp.bfloat16)
-    wd = sds((E, I, H), jnp.bfloat16)
-    fn = functools.partial(fused_moe_decode, act="silu", interpret=False)
-    lower_tpu(lambda *a: fn(*a), x, idx, w, wg, wg, wd)
-
-
-def test_lower_fused_moe_decode_gelu_clamped():
-    # the GPT-OSS activation flavor takes a different in-kernel branch
-    # (clamped swiglu with bias) — it must lower too, not just silu
-    from neuronx_distributed_inference_tpu.ops.moe_decode import fused_moe_decode
-
-    T, k, H, I, E = 2, 4, 2048, 8192, 8
-    x = sds((T, H), jnp.bfloat16)
-    idx = sds((T, k), jnp.int32)
-    w = sds((T, k), jnp.float32)
-    wg = sds((E, H, I), jnp.bfloat16)
-    wd = sds((E, I, H), jnp.bfloat16)
-    fn = functools.partial(
-        fused_moe_decode, act="gelu", act_scale=1.702, act_bias=1.0,
-        swiglu_limit=7.0, interpret=False,
-    )
-    lower_tpu(lambda *a: fn(*a), x, idx, w, wg, wg, wd)
-
-
 # ---------------------------------------------------------------------------
 # ragged paged attention (mixed prefill-chunk + decode, ISSUE 12)
 # ---------------------------------------------------------------------------
