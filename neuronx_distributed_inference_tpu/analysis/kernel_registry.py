@@ -45,6 +45,8 @@ _QWEN3_1P7B = dict(Hq=16, Hkv=8, D=128, L=28)
 _KV2 = dict(Hq=8, Hkv=2, D=128, L=20)
 #: a block step: 4 query positions a row over 4 KV heads (sdar-30b-a3b)
 _BLOCK4 = dict(Hq=32, Hkv=4, D=128, L=6)
+#: 16 KV heads a chip, one query head a KV head, 4 x 48 streams (ouro-2.6b)
+_KV16 = dict(Hq=16, Hkv=16, D=128, L=192)
 
 
 @dataclass(frozen=True)
@@ -443,6 +445,11 @@ REGISTRY: Tuple[KernelSpec, ...] = (
                 "blk4x32x128", "bfloat16",
                 _paged_tkg_case(48, 32, 32, "bfloat16", _BLOCK4, K=4),
             ),
+            # a looped stack's decode program: 8 slots, a block of 128 KiB a
+            # stream, so a group is 8 blocks
+            KernelCase(
+                "blk16x32x128", "bfloat16", _paged_tkg_case(8, 32, 32, "bfloat16", _KV16)
+            ),
         ),
     ),
     KernelSpec(
@@ -484,6 +491,9 @@ REGISTRY: Tuple[KernelSpec, ...] = (
             ),
             KernelCase(
                 "blk4x32x128", "bfloat16", _paged_flash_case(8, 128, 64, 32, "bfloat16", _BLOCK4)
+            ),
+            KernelCase(
+                "blk16x32x128", "bfloat16", _paged_flash_case(8, 128, 64, 32, "bfloat16", _KV16)
             ),
         ),
     ),
@@ -593,6 +603,7 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     # the q tile, and pages_per_step's rule under this kernel's name
     "paged_flash_attention": {
         "blk8x128x64": {"tq": 128, "pages": 1},
+        "blk16x32x128": {"tq": 128, "pages": 8},
         "*": {"tq": 128, "pages": 16},
     },
     # what pages_per_step's rule gives at each registered block shape (at
@@ -602,6 +613,7 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
         "blk8x32x128": {"pages": 16},
         "blk2x32x128": {"pages": 16},
         "blk4x32x128": {"pages": 16},
+        "blk16x32x128": {"pages": 8},
     },
     "ragged_paged_attention": {"*": {"tq": 16}},
     "grouped_matmul": {"*": {"tm": 128}},

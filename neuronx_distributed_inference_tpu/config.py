@@ -931,6 +931,44 @@ def validate_slot_state_serving(tc: "TpuConfig", what: str = "state-space layers
             raise SlotStateServingError(f"a model with {what} cannot be served with {why}")
 
 
+class LoopedStackError(NotImplementedError):
+    """An option that cannot run a model whose layer stack runs several times
+    over one set of weights (models/ouro.py: ``total_ut_steps`` > 1) was set
+    for one, or its config asks for an exit the stack does not take."""
+
+
+def validate_looped_stack(tc: "TpuConfig", loop_steps: int, early_exit_threshold: float) -> None:
+    """Refuse, for a model whose builder declares a looped stack, what the
+    loop does not do yet, each by name: none is a silent wrong answer. Every
+    site listed reads ONE cache entry or ONE pass a layer; the pool of a
+    looped stack has ``loop_steps`` x layers entries."""
+    speculation = (
+        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
+        or tc.enable_eagle_speculation or tc.serving_spec_ragged
+    )
+    refusals = (
+        (loop_steps < 1, f"total_ut_steps {loop_steps}: the stack runs at least once"),
+        (early_exit_threshold < 1,
+         f"early_exit_threshold {early_exit_threshold} < 1: a depth that differs by row (a position "
+         "leaving the stack at an earlier loop) is not built; every position runs every loop"),
+        (tc.lora_config is not None,
+         "lora_config: the adapters are attached by layer (runtime/application.py passes "
+         "spec.num_layers), not by layer pass"),
+        (speculation, "speculation (speculation_length, medusa, fused, EAGLE and its capture_layers, "
+                      "serving_spec_ragged): a draft's cache has one entry a layer"),
+        (tc.serving_ragged, "serving_ragged: the ragged mixed step scans the layers once "
+                            "(models/base.py mixed_forward has its own scan)"),
+        (tc.kv_quantized, "kv_cache_dtype quantisation: one scale a (layer, head) is not held to a "
+                          "reference over loop_steps streams a layer"),
+        (tc.tp_degree * tc.ep_degree * tc.cp_degree * tc.attention_dp_degree
+         * tc.data_parallel_degree > 1,
+         "tp/ep/cp/dp degree > 1: the looped scan is not held to a reference on a mesh"),
+    )
+    for flag, why in refusals:
+        if flag:
+            raise LoopedStackError(f"a model with a looped layer stack cannot run with {why}")
+
+
 class LatentAttentionError(NotImplementedError):
     """An option that cannot run a model whose attention caches one
     compressed latent and one rotary key a token (MLA: models/deepseek.py)

@@ -20,3 +20,4 @@ from neuronx_distributed_inference_tpu.models import granite_hybrid  # noqa: F40
 from neuronx_distributed_inference_tpu.models import zaya  # noqa: F401
 from neuronx_distributed_inference_tpu.models import nemotron_h  # noqa: F401
 from neuronx_distributed_inference_tpu.models import sdar  # noqa: F401
+from neuronx_distributed_inference_tpu.models import ouro  # noqa: F401

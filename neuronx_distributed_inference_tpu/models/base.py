@@ -157,6 +157,11 @@ class ModelSpec:
     # a decode step that fills a block of positions (BlockStepSpec); None =
     # one position after another
     block_step: Optional[BlockStepSpec] = None
+    # how often the whole stack runs over ONE set of layer weights
+    # (models/ouro.py: ``total_ut_steps``). Loop t of layer l keeps its own
+    # K/V stream, cache index ``t * L + l``, and the final norm is applied
+    # after every loop. 1 = the plain decoder; nothing is emitted for it.
+    loop_steps: int = 1
 
 
 #: the name, in ``StepOutput.aux``, of the experts a pass's expert layers
@@ -570,7 +575,19 @@ def _decoder_layer_mlp(layer_params, hidden, spec, mlp_fn):
             spec.norm_type,
         )
     with jax.named_scope("layer.mlp"):
-        return residual_add(residual, mlp_fn(layer_params["mlp"], hidden, spec), spec)
+        update = mlp_fn(layer_params["mlp"], hidden, spec)
+        if "post_attention_layernorm_2" in layer_params:
+            update = _post_norm(update, layer_params["post_attention_layernorm_2"], spec)
+        return residual_add(residual, update, spec)
+
+
+def _post_norm(update, norm_params, spec):
+    """A norm on a sub-block's OUTPUT, before the residual add (models/ouro.py:
+    ``input_layernorm_2`` after attention, ``post_attention_layernorm_2``
+    after the MLP). Taken at trace time from the keys of ``layer_params``: a
+    layer without them emits nothing."""
+    with jax.named_scope("layer.post_norm"):
+        return apply_norm(update, norm_params["weight"], spec.rms_eps, spec.norm_type)
 
 
 def decoder_layer(
@@ -763,6 +780,8 @@ def decoder_layer(
         attn_out = tensor_taps.tap("attn_out", attn_out, layer_idx)
     with jax.named_scope("layer.o_proj"):
         hidden = o_project(layer_params["self_attn"], attn_out, aspec, adapter_ids=adapter_ids)
+        if "input_layernorm_2" in layer_params:
+            hidden = _post_norm(hidden, layer_params["input_layernorm_2"], spec)
         hidden = residual_add(residual, hidden, spec)
 
     if mlp_fn is not None:  # None: a block that is the attention part alone
@@ -871,6 +890,17 @@ def lm_head(params: dict, hidden: jax.Array, spec: ModelSpec) -> jax.Array:
     return mask_padded_logits(logits, spec.vocab_size)
 
 
+def exit_gate(params: dict, hidden: jax.Array) -> jax.Array:
+    """(B, S): a looped stack's exit gate after one loop's final norm,
+    ``sigmoid(hidden w + b)`` (models/ouro.py ``early_exit_gate``, a
+    ``Linear(H, 1)``). The serving step takes its logits from the last loop
+    for every position (``early_exit_threshold`` 1) and never computes it:
+    it is emitted where a tensor tap (``exit_gate``) asks for it."""
+    gate = params["early_exit_gate"]
+    logit = hidden @ gate["weight"] + gate["bias"]
+    return jax.nn.sigmoid(logit[..., 0])
+
+
 def gather_last_token(hidden: jax.Array, attention_mask: jax.Array) -> jax.Array:
     """(B, S, H) -> (B, 1, H) at the last valid position per row
     (reference last-token gather, model_base.py:1038-1060)."""
@@ -936,6 +966,13 @@ def run_decoder_layers(
     ``capture_layers``: EAGLE3 multi-layer hidden capture (reference
     model_base.py:1444-1447) — returns a third value, the (B, S, C*H) concat
     of the named layers' outputs, accumulated in-scan (uniform stacks only).
+
+    A looped stack (``spec.loop_steps`` = T > 1, models/ouro.py): the groups'
+    scans run inside an outer ``lax.scan`` over the loops with the same
+    weights at every loop; loop t of layer l writes and attends cache stream
+    ``t * L + l`` (the cache has T x L entries: ``builder.cache_layers()``),
+    and the final norm (scope ``loop.norm``) is applied to the carry after
+    every loop.
     """
     if isinstance(layer_fn, LayerStack):
         from neuronx_distributed_inference_tpu.modules import tensor_taps
@@ -1203,75 +1240,115 @@ def run_decoder_layers(
                 )
             captured = jnp.zeros((len(capture_layers),) + hidden.shape, hidden.dtype)
             cap_idx = jnp.asarray(capture_layers, jnp.int32)
-        offset = 0
-        for group_params, gspec in zip(groups, group_specs):
-            window = gspec.sliding_window
-            chunk = gspec.attention_chunk_size
-            g_mlp = mlp_fns[gspec.fn_idx if len(mlp_fns) > 1 else 0]
-            g_layer = layer_fns[gspec.fn_idx if len(layer_fns) > 1 else 0] or decoder_layer
 
-            mask = finalize_mask(build_mask(inputs, spec, phase, window=window, chunk=chunk))
-            key_valid = group_key_valid(window, chunk)
+        def layer_pass(hidden, k_cache, v_cache, captured, stream_base=None):
+            """Every group's scan, once. ``stream_base``: a looped stack's
+            (``spec.loop_steps`` > 1) first cache index of this pass, ``t * L``:
+            layer l then writes and attends stream ``t * L + l`` with the
+            weights of layer l. None = a layer's stream is its index."""
+            offset = 0
+            for group_params, gspec in zip(groups, group_specs):
+                window = gspec.sliding_window
+                chunk = gspec.attention_chunk_size
+                g_mlp = mlp_fns[gspec.fn_idx if len(mlp_fns) > 1 else 0]
+                g_layer = layer_fns[gspec.fn_idx if len(layer_fns) > 1 else 0] or decoder_layer
 
-            num_layers = jax.tree.leaves(group_params)[0].shape[0]
-            if spec.layer_groups is not None and gspec.num_layers != num_layers:
-                raise ValueError(
-                    f"layer_groups mismatch: spec says {gspec.num_layers} layers, "
-                    f"params carry {num_layers}"
+                mask = finalize_mask(build_mask(inputs, spec, phase, window=window, chunk=chunk))
+                key_valid = group_key_valid(window, chunk)
+
+                num_layers = jax.tree.leaves(group_params)[0].shape[0]
+                if spec.layer_groups is not None and gspec.num_layers != num_layers:
+                    raise ValueError(
+                        f"layer_groups mismatch: spec says {gspec.num_layers} layers, "
+                        f"params carry {num_layers}"
+                    )
+                # an expert layer whose pass takes the grouped-matmul kernel reads
+                # its experts from the group's stacks in place: they stay out of
+                # the scanned operands (modules/moe.hoist_expert_stacks)
+                expert_stacks = None
+                if isinstance(g_mlp, moe.ExpertMlp):
+                    B, S = hidden.shape[:2]
+                    group_params, expert_stacks = moe.hoist_expert_stacks(
+                        group_params, g_mlp.spec, S, B * S, hidden.dtype
+                    )
+
+                def scan_body(carry, xs, g_mlp=g_mlp, g_layer=g_layer, mask=mask,
+                              key_valid=key_valid, window=window, chunk=chunk,
+                              expert_stacks=expert_stacks, offset=offset):
+                    h, k_c, v_c, cap = carry
+                    layer_params, li = xs
+                    layer_params = moe.place_expert_stacks(layer_params, expert_stacks, li - offset)
+                    chose = []
+                    if spec.output_choices:
+                        # an MLP that chooses (modules/moe.moe_layer: an expert
+                        # layer's selection) returns (update, choices) under
+                        # spec.output_choices; the choices ride the scan ys
+                        def g_mlp(p, x, s, inner=g_mlp):
+                            out = inner(p, x, s)
+                            if isinstance(out, tuple):
+                                out, picked = out
+                                chose.append(picked)
+                            return out
+
+                    h, k_c, v_c = g_layer(
+                        layer_params, h, cos, sin, k_c, v_c,
+                        li if stream_base is None else stream_base + li,
+                        mask, slot_ids, positions,
+                        spec, phase, g_mlp, key_valid=key_valid, block_inputs=block_inputs,
+                        adapter_ids=inputs.adapter_ids, window=window, chunk=chunk,
+                    )
+                    if cap is not None:
+                        hit = (cap_idx == li)[:, None, None, None]
+                        cap = jnp.where(hit, h[None].astype(cap.dtype), cap)
+                    # per-layer tensor-tap captures ride the scan ys (stacked to
+                    # (L, ...) — modules/tensor_taps)
+                    return (h, k_c, v_c, cap), (
+                        tensor_taps.collect_layer_taps(taps_ctx), chose[0] if chose else None
+                    )
+
+                # the full cache rides the CARRY (updated in place per layer); only
+                # the layer params are scanned xs — no stacked-ys cache rebuild
+                (hidden, k_cache, v_cache, captured), (tap_ys, chose_ys) = jax.lax.scan(
+                    scan_body,
+                    (hidden, k_cache, v_cache, captured),
+                    (group_params, offset + jnp.arange(num_layers, dtype=jnp.int32)),
                 )
-            # an expert layer whose pass takes the grouped-matmul kernel reads
-            # its experts from the group's stacks in place: they stay out of
-            # the scanned operands (modules/moe.hoist_expert_stacks)
-            expert_stacks = None
-            if isinstance(g_mlp, moe.ExpertMlp):
-                B, S = hidden.shape[:2]
-                group_params, expert_stacks = moe.hoist_expert_stacks(
-                    group_params, g_mlp.spec, S, B * S, hidden.dtype
-                )
+                tensor_taps.merge_layer_taps(taps_ctx, tap_ys)
+                if chose_ys is not None:
+                    choices.append(chose_ys)  # (layers of the group, B, S, k)
+                offset += num_layers
+            return hidden, k_cache, v_cache, captured
 
-            def scan_body(carry, xs, g_mlp=g_mlp, g_layer=g_layer, mask=mask,
-                          key_valid=key_valid, window=window, chunk=chunk,
-                          expert_stacks=expert_stacks, offset=offset):
-                h, k_c, v_c, cap = carry
-                layer_params, li = xs
-                layer_params = moe.place_expert_stacks(layer_params, expert_stacks, li - offset)
-                chose = []
-                if spec.output_choices:
-                    # an MLP that chooses (modules/moe.moe_layer: an expert
-                    # layer's selection) returns (update, choices) under
-                    # spec.output_choices; the choices ride the scan ys
-                    def g_mlp(p, x, s, inner=g_mlp):
-                        out = inner(p, x, s)
-                        if isinstance(out, tuple):
-                            out, picked = out
-                            chose.append(picked)
-                        return out
-
-                h, k_c, v_c = g_layer(
-                    layer_params, h, cos, sin, k_c, v_c, li, mask, slot_ids, positions,
-                    spec, phase, g_mlp, key_valid=key_valid, block_inputs=block_inputs,
-                    adapter_ids=inputs.adapter_ids, window=window, chunk=chunk,
+        if spec.loop_steps == 1:
+            hidden, k_cache, v_cache, captured = layer_pass(hidden, k_cache, v_cache, captured)
+        else:
+            # a looped stack: the layers' scan inside a scan over the loops,
+            # the SAME weights at every loop, the cache on the carry of both;
+            # the final norm is applied to the carry after every loop and its
+            # output starts the next (so the head below applies none)
+            if capture_layers is not None or per_layer_taps or spec.output_choices:
+                raise NotImplementedError(
+                    "a looped stack (loop_steps > 1) runs without capture_layers, "
+                    "per-layer tensor taps and output_choices"
                 )
-                if cap is not None:
-                    hit = (cap_idx == li)[:, None, None, None]
-                    cap = jnp.where(hit, h[None].astype(cap.dtype), cap)
-                # per-layer tensor-tap captures ride the scan ys (stacked to
-                # (L, ...) — modules/tensor_taps)
-                return (h, k_c, v_c, cap), (
-                    tensor_taps.collect_layer_taps(taps_ctx), chose[0] if chose else None
-                )
+            streams_a_loop = sum(jax.tree.leaves(g)[0].shape[0] for g in groups)
+            want_gates = taps_ctx is not None and "exit_gate" in taps_ctx.capture
 
-            # the full cache rides the CARRY (updated in place per layer); only
-            # the layer params are scanned xs — no stacked-ys cache rebuild
-            (hidden, k_cache, v_cache, captured), (tap_ys, chose_ys) = jax.lax.scan(
-                scan_body,
-                (hidden, k_cache, v_cache, captured),
-                (group_params, offset + jnp.arange(num_layers, dtype=jnp.int32)),
+            def loop_body(carry, t):
+                h, k_c, v_c = carry
+                h, k_c, v_c, _ = layer_pass(h, k_c, v_c, None, t * streams_a_loop)
+                with jax.named_scope("loop.norm"):
+                    h = apply_norm(h, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
+                    gate = exit_gate(params, h) if want_gates else None
+                return (h, k_c, v_c), gate
+
+            (hidden, k_cache, v_cache), gates = jax.lax.scan(
+                loop_body,
+                (hidden, k_cache, v_cache),
+                jnp.arange(spec.loop_steps, dtype=jnp.int32),
             )
-            tensor_taps.merge_layer_taps(taps_ctx, tap_ys)
-            if chose_ys is not None:
-                choices.append(chose_ys)  # (layers of the group, B, S, k)
-            offset += num_layers
+            if want_gates:
+                tensor_taps.tap("exit_gate", gates)  # (loops, B, S)
     if interleaved:
         new_cache = type(cache)(
             k_full=k_cache[0], v_full=v_cache[0], k_ring=k_cache[1], v_ring=v_cache[1]
@@ -1285,8 +1362,9 @@ def run_decoder_layers(
 
         hidden = cpx.shard_seq(hidden)
 
-    with jax.named_scope("head"):
-        hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
+    if spec.loop_steps == 1:  # a looped stack's last loop ended in the final norm
+        with jax.named_scope("head"):
+            hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
     hidden = tensor_taps.tap("final_hidden", hidden)
     if capture_layers is not None:
         # (C, B, S, H) -> (B, S, C*H) concat in tap order
@@ -1559,6 +1637,10 @@ def mixed_forward(
     if layer_fn is not None:
         raise NotImplementedError(
             "the ragged mixed step runs the standard decoder_layer only"
+        )
+    if spec.loop_steps != 1:
+        raise NotImplementedError(
+            "the ragged mixed step scans the layers once (no looped stack)"
         )
     if spec.cp_enabled or spec.attention_dp > 1 or spec.data_parallel > 1:
         raise NotImplementedError(

@@ -464,12 +464,16 @@ class DecoderModelBuilder:
         return None
 
     def cache_layers(self):
-        """What each layer keeps between steps, in model order
-        (modules/block_kvcache: ``PAGED_KV`` — a K/V stream of
-        ``(kv_heads, head_dim)`` per token, paged over the block pool — or
-        ``SLOT_STATE`` — a constant-size state per serving slot, built by
-        :meth:`init_slot_state`). The application sizes the block pool over
-        the paging layers only."""
+        """What each layer PASS keeps between steps, in the order the step
+        runs them: one entry per pass of a layer, so one per layer for a
+        stack that runs once and ``loop_steps`` x layers for one that loops
+        (models/ouro.py: loop t of layer l is entry ``t * L + l``). An entry is
+        ``PAGED_KV`` — a K/V stream of ``(kv_heads, head_dim)`` per token,
+        paged over the block pool — or ``SLOT_STATE`` — a constant-size state
+        per serving slot, built by :meth:`init_slot_state`
+        (modules/block_kvcache). The application sizes the block pool over the
+        ``PAGED_KV`` entries, and :meth:`init_kv_cache` gives the contiguous
+        cache one line per entry."""
         from neuronx_distributed_inference_tpu.modules.block_kvcache import PAGED_KV
 
         return (PAGED_KV,) * self.config.num_hidden_layers
@@ -525,7 +529,7 @@ class DecoderModelBuilder:
         # ring-bounded caches hold only W slots (see _finalize_bounded)
         cache_len = self.model_spec().bounded_window or tc.seq_len
         cache = init_cache(
-            self.config.num_hidden_layers,
+            len(self.cache_layers()),
             kv_batch,
             cache_len,
             self.gqa.kv_heads,

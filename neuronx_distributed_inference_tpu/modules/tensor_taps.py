@@ -26,6 +26,8 @@ In-tree tap points (models/base.py):
     ``layer_out``     (L, B, S, H) per-layer decoder output
     ``final_hidden``  (B, S, H)  post-final-norm hidden
     ``logits``        (B, K, V)  lm-head output
+    ``exit_gate``     (T, B, S)  a looped stack's exit gate after each loop
+                                 (models/ouro.py; computed only when captured)
 """
 
 from __future__ import annotations
@@ -36,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 #: every name the model code taps; config validation checks against this
-TAP_POINTS = ("embed", "attn_out", "layer_out", "final_hidden", "logits")
+TAP_POINTS = ("embed", "attn_out", "layer_out", "final_hidden", "logits", "exit_gate")
 PER_LAYER_POINTS = ("attn_out", "layer_out")
 
 _ACTIVE: List["TapContext"] = []

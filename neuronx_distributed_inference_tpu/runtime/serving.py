@@ -390,6 +390,11 @@ class ServingSession:
             ))
             # a reader tells a held share from a whole by this gauge
             self.tel.moe_held(self.expert_layers[1], moe_spec.num_experts)
+        # a looped stack (models/ouro.py): the layer passes a dispatch runs,
+        # each with a stream of its own in the pool (nxdi_loop_*, nxdi_kv_streams)
+        self.loop_layer_passes = (
+            app.spec.loop_steps * app.spec.num_layers if app.spec.loop_steps > 1 else 0
+        )
         # a model whose builder declares a block step generates block by
         # block: its rows' blocks, plans and commits (runtime/block_step.py)
         self.blocks = None
@@ -2025,6 +2030,8 @@ class ServingSession:
             self.tel.carry_pass(program, rows)
         if self.latent_layers:
             self.tel.latent_pass(program, tokens * self.latent_layers)
+        if self.loop_layer_passes:
+            self.tel.loop_pass(program, dispatches, self.loop_layer_passes)
         if self.expert_layers is not None:
             layers, experts, top_k = self.expert_layers
             self.tel.moe_pass(

@@ -38,11 +38,13 @@ DEVICE_SCOPES = (
     "layer.attn",
     "layer.o_proj",
     "layer.mlp",
+    "layer.post_norm",
     "layer.moe.router",
     "layer.moe.experts",
     "layer.shared_mlp",
     "layer.ssm",
     "layer.other",
+    "loop.norm",
     "head",
     "sample",
     "reveal",

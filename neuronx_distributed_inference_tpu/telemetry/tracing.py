@@ -417,6 +417,19 @@ class TelemetrySession:
             "its exchange: modules/moe.MoESpec.held_experts), else equal. Set "
             "once, when a session is built",
             labels=("of",))
+        self._loop_passes = r.counter(
+            "nxdi_loop_layer_passes_total",
+            "layer passes the dispatches of the split serving step ran over a "
+            "looped stack (models/ouro.py): dispatches x loop_steps x layers, "
+            "each pass with a K/V stream of its own; counted on the host from "
+            "what the step knows. Absent for a stack that runs once",
+            labels=("program",))
+        self._kv_streams = r.gauge(
+            "nxdi_kv_streams",
+            "K/V streams a token holds in the paged pool of a looped stack: "
+            "loop_steps x layers (builder.cache_layers()). Set at every pass "
+            "of a session over a looped stack (a session built while "
+            "recording was off sets it at its first recorded pass)")
         self._moe_grouped_rows = r.counter(
             "nxdi_moe_grouped_rows_total",
             "the routed token rows of nxdi_moe_rows_routed_total by the expert "
@@ -1205,6 +1218,15 @@ class TelemetrySession:
         if not self.enabled:
             return
         self._latent_tokens.child((program,)).inc(latents)
+
+    def loop_pass(self, program: str, dispatches: int, streams: int) -> None:
+        """One pass of the split serving step over a looped stack of
+        ``streams`` = loop_steps x layers layer passes a dispatch, each with a
+        K/V stream of its own in the pool."""
+        if not self.enabled:
+            return
+        self._loop_passes.child((program,)).inc(dispatches * streams)
+        self._kv_streams.set(streams)
 
     def moe_held(self, held: int, published: int) -> None:
         """A session over a model with routed experts: how many of each

@@ -83,6 +83,8 @@ MAIN_PATH_KERNELS = [
     ("paged_flash_attention", "blk8x32x128", "int8"),
     ("paged_flash_attention", "blk2x32x128", "bfloat16"),  # 2 kv heads a chip, 4 q heads each
     ("paged_flash_attention", "blk4x32x128", "bfloat16"),  # SDAR: 8 q heads a kv head, in parts
+    ("paged_tkg_decode_attention", "blk16x32x128", "bfloat16"),  # Ouro: 16 kv heads, 1 q head each, 8 blocks a step
+    ("paged_flash_attention", "blk16x32x128", "bfloat16"),
     ("paged_latent_decode_attention", "blk1x32x512", "bfloat16"),  # Kimi-VL: 64 rows, kv 8192, 512 + 64 lanes
     ("paged_latent_flash_attention", "blk1x32x512", "bfloat16"),  # its chunk: 8 rows of 128, 16 heads a latent
     ("ragged_paged_attention", "mixed", "bfloat16"),  # ragged mixed step
@@ -804,6 +806,54 @@ def test_nemotron_serving_step_runs_its_kernels_in_place_and_fits_the_chip(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < (0.1 if program == "decode" else 0.4) * 2**30
     print(f"\nnemotron-3-nano-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# ouro-2.6b: 48 layers run 4 times over one set of weights, a pool of 192 streams
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_ouro_serving_step_loops_one_layer_body_over_the_pool_in_place(chip_mesh, program, monkeypatch):
+    """ouro-2.6b at the benchmark's widths (benchmark/configs/ouro-2.6b.json:
+    nothing cut, 8 slots, 192 blocks), both step programs compiled for a
+    described v5e at kv bucket 2048. The pool spans 4 x 48 = 192 streams of 16
+    KV heads (48 MiB a block of 32 tokens, 9.05 GiB in all) and rides the
+    carry of BOTH scans: no copy of the pool's shape, in a loop body or at the
+    entry. One compiled layer body (the loop is a scan, not an unrolling): the
+    paged kernel is in the program ONCE: ``paged_tkg_decode_attention`` at 16
+    KV heads and one query head a KV head in the decode program (8 x 1),
+    ``paged_flash_attention`` in the chunk program (8 x 128). The norms the
+    loop adds stand under ``layer.post_norm`` and ``loop.norm``. Each plans
+    under 14.75 GiB of the chip's 15.75 (208 blocks would plan 14.77)."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    # the gate asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
+    app, params, cache = _abstract_paged_app(chip_mesh(1), "ouro-2.6b")
+    assert app.spec.loop_steps == 4 and app.paged_layers == 192
+    assert cache.k.shape == cache.v.shape == (192, 193, 16, 32, 128)
+    assert params["layers"]["mlp"]["gate_proj"]["weight"].shape == (48, 2048, 5632)  # ONE set of weights
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(2048, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((8, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
+    assert kernel in text and _custom_calls(compiled) == 1
+    assert _pool_copies(compiled, cache.k.shape) == (0, 0)
+    if program == "chunk":
+        _assert_chunk_write_moves_blocks(compiled, 8, 128, 16, cache.k.shape)
+    scopes = set(device_scopes.scope_table(text)["ops"].values())
+    assert {"layer.post_norm", "loop.norm", "layer.kv_write", "layer.attn", "layer.mlp"} <= scopes
+    assert not _work_under_no_scope(compiled), _work_under_no_scope(compiled)[:5]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1 * 2**30
+    print(f"\nouro-2.6b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
