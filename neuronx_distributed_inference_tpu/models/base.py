@@ -446,6 +446,85 @@ def contiguous_decode_attend(
     return attn_out
 
 
+def decode_kernel_runs(
+    spec: ModelSpec, q_len: int, mask_width: int, table_tokens: int, k_shape, v_shape
+) -> bool:
+    """Whether :func:`paged_attend` hands a pass ``q_len`` wide, which the
+    paged prefill kernel does not take, to the paged decode kernel: no batch
+    sharded around the attention, a mask as wide as the block table
+    (``table_tokens``), a K/V pool of two equal streams, and the kernel's own
+    gate (ops/kernel_mode.use_tkg). Static: the serving session asks it too
+    (block_kvcache.write_form)."""
+    from neuronx_distributed_inference_tpu.ops.decode_attention import use_tkg_kernel
+
+    return (
+        spec.attention_dp * spec.data_parallel == 1
+        and mask_width == table_tokens
+        and tuple(k_shape) == tuple(v_shape)
+        and use_tkg_kernel(spec.attn, q_len, mask_width)
+    )
+
+
+def paged_write_attend(
+    q: jax.Array,  # (B, Sq, Hq, D)
+    k: jax.Array,  # (B, Sq, Hkv, D): this pass's K and V, not yet in the pool
+    v: jax.Array,
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    layer_idx: jax.Array,
+    mask: jax.Array,
+    block_inputs: Tuple[jax.Array, jax.Array, jax.Array],  # paged_block_inputs
+    positions: jax.Array,
+    spec: ModelSpec,
+    sink: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The paged KV write of a pass and its attention over the pool, for any
+    layer that pages K/V at ``(H_kv, D)``: ``(attn_out, k_cache, v_cache)``.
+    Write-then-attend (``layer.kv_write``, then :func:`paged_attend` under
+    ``layer.attn``), or, where ``block_kvcache.write_form`` says ``kernel``
+    (a one-token decode pass that rides the paged decode kernel), ONE call
+    under ``layer.attn`` in which the kernel places the token in the block it
+    holds for the row anyway and attends as write-then-attend does. Called
+    under no scope of the caller's (:func:`decoder_layer`, models/zaya.py)."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        batch_is_sharded,
+        update_block_cache_at_layer,
+        write_form,
+    )
+    from neuronx_distributed_inference_tpu.parallel.sharding import head_shard_degree
+
+    slot_mapping, block_table, kv_limit = block_inputs
+    Sq, D = q.shape[1], q.shape[-1]
+    bs = k_cache.shape[3]
+    form = write_form(
+        Sq, D, k_cache.shape[2] // head_shard_degree(),
+        quantised=isinstance(k_cache, QuantizedKV), batch_sharded=batch_is_sharded(),
+        kernel_runs=decode_kernel_runs(
+            spec, Sq, mask.shape[-1], block_table.shape[1] * bs, k_cache.shape, v_cache.shape
+        ),
+    )
+    if form == "kernel":
+        from neuronx_distributed_inference_tpu.ops.decode_attention import (
+            dispatch_paged_tkg_decode,
+        )
+
+        with jax.named_scope("layer.attn"):
+            return dispatch_paged_tkg_decode(
+                q, k_cache, v_cache, layer_idx, block_table, mask, sink,
+                (k, v, slot_mapping),
+                scale=spec.attn.softmax_scale, interpret=kernel_interpret(),
+            )
+    with jax.named_scope("layer.kv_write"):
+        k_cache, v_cache = update_block_cache_at_layer(
+            k_cache, v_cache, k, v, layer_idx, slot_mapping
+        )
+    with jax.named_scope("layer.attn"):
+        attn_out = paged_attend(
+            q, k_cache, v_cache, layer_idx, mask, block_table, kv_limit, positions, spec, sink
+        )
+    return attn_out, k_cache, v_cache
+
+
 def paged_attend(
     q: jax.Array,  # (B, Sq, Hq, D)
     k_cache: jax.Array,  # the stacked block pool, this pass's K/V already written
@@ -526,16 +605,11 @@ def paged_attend(
     else:
         from neuronx_distributed_inference_tpu.ops.decode_attention import (
             dispatch_paged_tkg_decode,
-            use_tkg_kernel,
         )
 
         bs = k_cache.shape[3]  # (L, NB+1, Hkv, bs, D) head-major
-        width_ok = mask.shape[-1] == block_table.shape[1] * bs
-        if (
-            dp_shards == 1
-            and width_ok
-            and k_cache.shape == v_cache.shape
-            and use_tkg_kernel(aspec, Sq, mask.shape[-1])
+        if decode_kernel_runs(
+            spec, Sq, mask.shape[-1], block_table.shape[1] * bs, k_cache.shape, v_cache.shape
         ):
             # decode/speculation off the paged cache: blocks DMA'd via the
             # block table — no gather materialization (reference block TKG
@@ -646,6 +720,9 @@ def decoder_layer(
     # layer-index sentinel (scatter mode="drop")
     interleaved = isinstance(k_cache, tuple)
     bounded = spec.bounded_window is not None and not is_block and not interleaved
+    # a pass of the split serving step over the paged cache: written AND
+    # attended by paged_write_attend, under its own scopes
+    paged_step = is_block and phase != PHASE_CONTEXT_ENCODING and ragged_rows is None
     if bounded and phase != PHASE_CONTEXT_ENCODING:
         # ring cache: read the PRIOR window state BEFORE this chunk's writes
         # land (prior/active decomposition — reference compute_for_token_gen's
@@ -677,14 +754,17 @@ def decoder_layer(
             )
             k_cache, v_cache = (k_full, k_ring), (v_full, v_ring)
         elif is_block:
-            from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-                update_block_cache_at_layer,
-            )
+            if not paged_step:
+                # whole-prompt prefill (attended from k, v below) and the
+                # ragged mixed step's packed axis
+                from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+                    update_block_cache_at_layer,
+                )
 
-            k_cache, v_cache = update_block_cache_at_layer(
-                k_cache, v_cache, k, v, layer_idx, block_inputs[0],
-                packed=ragged_rows is not None,
-            )
+                k_cache, v_cache = update_block_cache_at_layer(
+                    k_cache, v_cache, k, v, layer_idx, block_inputs[0],
+                    packed=ragged_rows is not None,
+                )
         else:
             if bounded:
                 # slot = position mod W; sentinel (negative) positions map out of
@@ -700,79 +780,79 @@ def decoder_layer(
             )
 
     sink = layer_params["self_attn"].get("sink", {}).get("weight") if aspec.has_sink else None
-    with jax.named_scope("layer.attn"):
-        if phase == PHASE_CONTEXT_ENCODING:
-            if spec.cp_enabled:
-                # CP prefill: Q keeps its seq stripe; KV constrained replicated so
-                # GSPMD all-gathers it over the cp axis (reference all-gather-KV
-                # CP, attention_base.py:614-627)
-                from neuronx_distributed_inference_tpu.parallel import context_parallel as cpx
+    if paged_step:
+        attn_out, k_cache, v_cache = paged_write_attend(
+            q, k, v, k_cache, v_cache, layer_idx, mask, block_inputs, positions, spec, sink
+        )
+    else:
+        with jax.named_scope("layer.attn"):
+            if phase == PHASE_CONTEXT_ENCODING:
+                if spec.cp_enabled:
+                    # CP prefill: Q keeps its seq stripe; KV constrained replicated so
+                    # GSPMD all-gathers it over the cp axis (reference all-gather-KV
+                    # CP, attention_base.py:614-627)
+                    from neuronx_distributed_inference_tpu.parallel import context_parallel as cpx
 
-                q = cpx.shard_q(q)
-                k = cpx.gather_kv(k)
-                v = cpx.gather_kv(v)
-            if flavor_select is not None:
-                uniq, fl = flavor_select
+                    q = cpx.shard_q(q)
+                    k = cpx.gather_kv(k)
+                    v = cpx.gather_kv(v)
+                if flavor_select is not None:
+                    uniq, fl = flavor_select
 
-                def _mk(wc):
-                    w, c = wc
-                    return lambda _: attention_prefill(
+                    def _mk(wc):
+                        w, c = wc
+                        return lambda _: attention_prefill(
+                            q, k, v, mask, aspec, sink=sink, key_valid=key_valid,
+                            window=w, chunk=c,
+                        )
+
+                    attn_out = jax.lax.switch(fl, [_mk(wc) for wc in uniq], None)
+                else:
+                    attn_out = attention_prefill(
                         q, k, v, mask, aspec, sink=sink, key_valid=key_valid,
-                        window=w, chunk=c,
+                        window=window, chunk=chunk,
+                    )
+                if spec.cp_enabled:
+                    attn_out = cpx.shard_attn_out(attn_out)
+            elif ragged_rows is not None:
+                # ragged mixed step: prefill-chunk AND decode rows in ONE attention
+                # launch off the paged cache, masks derived in-kernel from the
+                # (row_start, row_len, ctx_len) descriptors (PAPERS.md ragged paged
+                # attention); native gather fallback keeps every config on CPU
+                from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
+                    ragged_attention,
+                )
+
+                rs, rl, cl = ragged_rows
+                attn_out = ragged_attention(
+                    q, k_cache, v_cache, layer_idx, block_inputs[1], positions,
+                    rs, rl, cl, aspec, interpret=kernel_interpret(),
+                )
+            elif bounded:
+                attn_out = ring_attention(
+                    q, k, v, k_prior, v_prior, positions, spec.bounded_window, aspec, sink
+                )
+            elif interleaved:
+                # decode: sliding layers attend [prior ring | chunk]; global layers
+                # attend their full-length cache line. lax.cond executes only the
+                # taken branch, so sliding layers never pay the full-cache read
+                B = q.shape[0]
+                bucket = mask.shape[-1]
+
+                def _global_attend(_):
+                    k_r, v_r = read_cache_at_layer(k_full, v_full, full_i, B, bucket)
+                    return attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
+
+                def _ring_attend(_):
+                    return ring_attention(
+                        q, k, v, k_prior, v_prior, positions, spec.ring_window, aspec, sink
                     )
 
-                attn_out = jax.lax.switch(fl, [_mk(wc) for wc in uniq], None)
+                attn_out = jax.lax.cond(is_sliding == 1, _ring_attend, _global_attend, None)
             else:
-                attn_out = attention_prefill(
-                    q, k, v, mask, aspec, sink=sink, key_valid=key_valid,
-                    window=window, chunk=chunk,
+                attn_out = contiguous_decode_attend(
+                    q, k_cache, v_cache, layer_idx, mask, spec, aspec, sink
                 )
-            if spec.cp_enabled:
-                attn_out = cpx.shard_attn_out(attn_out)
-        elif ragged_rows is not None:
-            # ragged mixed step: prefill-chunk AND decode rows in ONE attention
-            # launch off the paged cache, masks derived in-kernel from the
-            # (row_start, row_len, ctx_len) descriptors (PAPERS.md ragged paged
-            # attention); native gather fallback keeps every config on CPU
-            from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
-                ragged_attention,
-            )
-
-            rs, rl, cl = ragged_rows
-            attn_out = ragged_attention(
-                q, k_cache, v_cache, layer_idx, block_inputs[1], positions,
-                rs, rl, cl, aspec, interpret=kernel_interpret(),
-            )
-        elif is_block:
-            attn_out = paged_attend(
-                q, k_cache, v_cache, layer_idx, mask, block_inputs[1], block_inputs[2],
-                positions, spec, sink,
-            )
-        elif bounded:
-            attn_out = ring_attention(
-                q, k, v, k_prior, v_prior, positions, spec.bounded_window, aspec, sink
-            )
-        elif interleaved:
-            # decode: sliding layers attend [prior ring | chunk]; global layers
-            # attend their full-length cache line. lax.cond executes only the
-            # taken branch, so sliding layers never pay the full-cache read
-            B = q.shape[0]
-            bucket = mask.shape[-1]
-
-            def _global_attend(_):
-                k_r, v_r = read_cache_at_layer(k_full, v_full, full_i, B, bucket)
-                return attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
-
-            def _ring_attend(_):
-                return ring_attention(
-                    q, k, v, k_prior, v_prior, positions, spec.ring_window, aspec, sink
-                )
-
-            attn_out = jax.lax.cond(is_sliding == 1, _ring_attend, _global_attend, None)
-        else:
-            attn_out = contiguous_decode_attend(
-                q, k_cache, v_cache, layer_idx, mask, spec, aspec, sink
-            )
 
     if not interleaved:
         from neuronx_distributed_inference_tpu.modules import tensor_taps

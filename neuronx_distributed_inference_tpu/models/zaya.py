@@ -52,17 +52,14 @@ from neuronx_distributed_inference_tpu.models.base import (
     PHASE_TOKEN_GENERATION,
     LayerStack,
     build_mask,
-    paged_attend,
     paged_block_inputs,
+    paged_write_attend,
     residual_add,
     slot_state_rows,
 )
 from neuronx_distributed_inference_tpu.models.builder import DecoderModelBuilder
 from neuronx_distributed_inference_tpu.models.registry import register_model
-from neuronx_distributed_inference_tpu.modules.block_kvcache import (
-    HybridBlockCache,
-    update_block_cache_at_layer,
-)
+from neuronx_distributed_inference_tpu.modules.block_kvcache import HybridBlockCache
 from neuronx_distributed_inference_tpu.modules.latent_attention import (
     CCASpec,
     TokenCarry,
@@ -143,7 +140,7 @@ class ZayaStack(LayerStack):
         positions = inputs.position_ids
         valid, reset, slots = slot_state_rows(inputs, cache.state.num_slots)
         n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
-        slot_mapping, block_table, kv_limit = paged_block_inputs(inputs, cache.block_size)
+        block_inputs = paged_block_inputs(inputs, cache.block_size)
         mask = build_mask(inputs, spec, phase)
         cos, sin = rope_cos_sin(positions, params["rope"]["inv_freq"], spec.attention_scaling)
         cca, moe = self.cca, self.moe
@@ -173,14 +170,9 @@ class ZayaStack(LayerStack):
                 last = last.at[li, slots].set(rows, mode="drop", unique_indices=True)
             with jax.named_scope("layer.qkv"):
                 q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-            with jax.named_scope("layer.kv_write"):
-                k_cache, v_cache = update_block_cache_at_layer(
-                    k_cache, v_cache, k, v, li, slot_mapping
-                )
-            with jax.named_scope("layer.attn"):
-                attn = paged_attend(
-                    q, k_cache, v_cache, li, mask, block_table, kv_limit, positions, spec
-                )
+            attn, k_cache, v_cache = paged_write_attend(
+                q, k, v, k_cache, v_cache, li, mask, block_inputs, positions, spec
+            )
             with jax.named_scope("layer.o_proj"):
                 h = residual_add(h, linear(sa["o_proj"], attn.reshape(B, S, -1)), spec)
 

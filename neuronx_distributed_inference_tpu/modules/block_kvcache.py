@@ -16,14 +16,17 @@ Device side (pure functions used inside the jitted step):
 - writes place token K/V through a flat ``slot_mapping`` (block *
   block_size + offset); invalid slots (< 0) are dropped, idle rows' slots
   land in the reserved garbage block 0 (reference's reserved block,
-  block_kv_cache_manager.py:11-80). Three forms write the same bytes
-  (:func:`update_block_cache_at_layer` says which and why): WHOLE BLOCKS
-  (window ``(H, bs, D)``) for prefill chunks at a head_dim on the 128 lanes,
-  PER HEAD (window ``(D,)``) at decode and speculation widths, both leaving
-  the layer scan's cache carry in the row-major layout a Pallas operand
-  takes so that the step holds no copy of the pool; a TOKEN WINDOW
-  (``(H, D)``) off the lanes and for the ragged step's packed axis. On a
-  head-sharded mesh the first two run per shard, each writing its own heads.
+  block_kv_cache_manager.py:11-80). Four forms write the same bytes
+  (:func:`write_form` decides, :func:`update_block_cache_at_layer` says
+  why): IN KERNEL for a one-token decode pass that rides the paged decode
+  kernel (the kernel places the token in the block it holds for the row;
+  nothing is written here), WHOLE BLOCKS (window ``(H, bs, D)``) for prefill
+  chunks at a head_dim on the 128 lanes, PER HEAD (window ``(D,)``) at the
+  other decode and speculation widths, all three leaving the layer scan's
+  cache carry in the row-major layout a Pallas operand takes so that the
+  step holds no copy of the pool; a TOKEN WINDOW (``(H, D)``) off the lanes
+  and for the ragged step's packed axis. On a head-sharded mesh the first
+  three run per shard, each writing its own heads.
 - decode reads gather blocks by the per-sequence ``block_table`` and view
   them as a contiguous (B, max_blocks*block_size) cache — logical position
   order is preserved, so the normal decode masks apply unchanged.
@@ -222,7 +225,7 @@ def block_cache_spec(quantized: bool = False, streams=None):
     return BlockKVCache(k=spec, v=spec)
 
 
-def _batch_sharded() -> bool:
+def batch_is_sharded() -> bool:
     """Whether the enclosing ``jax.set_mesh`` scope splits the BATCH around
     the attention (attention-DP, whole-model DP): there the block pool is
     replicated over those axes, the paged kernels are not run
@@ -242,6 +245,38 @@ def takes_block_form(
         q_len > TKG_MAX_Q_LEN and head_dim % 128 == 0
         and not packed and not batch_sharded
     )
+
+
+#: the forms of the paged KV write (:func:`update_block_cache_at_layer`)
+WRITE_FORMS = ("kernel", "blocks", "per_head", "window")
+
+
+def write_form(
+    q_len: int, head_dim: int, heads: int, *, quantised: bool = False, packed: bool = False,
+    batch_sharded: bool = False, kernel_runs: bool = False,
+) -> str:
+    """Which of :data:`WRITE_FORMS` the paged KV write of a pass takes
+    (:func:`update_block_cache_at_layer` says why), from what a call shows
+    and nothing else: ``q_len`` positions a row, ``head_dim``, ``heads`` ONE
+    device holds, a ``quantised`` pool, the ragged step's ``packed`` axis, a
+    batch sharded around the attention, and whether the pass's attention is
+    the paged decode kernel on a K/V pool of two equal streams
+    (``kernel_runs``: models/base.decode_kernel_runs). The one decision: the
+    model's call sites (models/base.paged_write_attend), the writer below
+    and the serving session's counter
+    (``nxdi_decode_kv_write_rows_total{form}``) read it."""
+    if (
+        q_len == 1 and head_dim % 128 == 0 and kernel_runs
+        and not (quantised or packed or batch_sharded)
+    ):
+        return "kernel"
+    if takes_block_form(q_len, head_dim, packed=packed, batch_sharded=batch_sharded):
+        return "blocks"
+    if not batch_sharded and (
+        heads < WINDOW_MIN_HEADS or (q_len <= TKG_MAX_Q_LEN and not packed)
+    ):
+        return "per_head"
+    return "window"
 
 
 def chunk_write_blocks(
@@ -356,7 +391,10 @@ def update_block_cache_at_layer(
     out-of-range indices; -1 would WRAP to the last real block and corrupt
     it) — same net effect as the reference's garbage-block writes.
 
-    Three forms write the same bytes. What decides between them: the TPU
+    Four forms write the same bytes; three are written here, and the first
+    by the pass's attention kernel (:func:`write_form` is the one decision;
+    models/base.paged_write_attend asks it and calls this function for
+    every form but ``kernel``). What decides between them: the TPU
     compiler lays a scatter's operand out with the update WINDOW's dims
     minor-most, the layer scan's cache carry takes that layout, and the
     paged kernels (Pallas custom calls) read the stacked pool row-major; a
@@ -364,9 +402,25 @@ def update_block_cache_at_layer(
     relaid once a layer and twice more at the program's entry and exit
     (328 of a 375 ms decode dispatch: PERF.md PR 24). And on a v5e an index
     row of a scatter costs ~70 ns whatever its width. The form is chosen
-    here and nowhere else, on what the call shows: the static ``S`` of
-    ``slot_mapping``, ``D``, ``packed``, the heads ONE device holds.
+    in :func:`write_form` and nowhere else, on what the call shows: the
+    static ``S`` of ``slot_mapping``, ``D``, ``packed``, the heads ONE device
+    holds, the pool's dtype, whether the pass's attention is the kernel.
 
+    * IN KERNEL at ``S == 1`` and ``D`` on the 128 lanes, an unquantised
+      K/V pool, no ``packed`` axis, the batch not sharded, and an attention
+      that IS the paged decode kernel (models/base.decode_kernel_runs):
+      every decode program of the split serving step on the chip. The kernel
+      (ops/decode_attention._paged_group_kernel) already runs one grid step
+      a row and holds that row's LAST live block in VMEM for all of a
+      device's heads at once, and a decode row's token belongs in exactly
+      that block: it lays the token's K and V rows over the block's 16-row
+      tile with a select, attends as write-then-attend does, and sends the
+      tile back with ONE copy a stream under the next rows' arithmetic, the
+      pools aliased in and out of the custom call. No launch, no scatter and
+      no gather of its own, where the per-head scatter's ``B x H`` index rows
+      a stream a layer were the second largest thing on the chip (2.2 of
+      11.9 ms a dispatch on the 1.7B, 4.9 of 48.5 on a looped stack of 192
+      layer passes: PERF.md PR 52). This function is not called.
     * WHOLE BLOCKS (window ``(H, bs, D)``, the pool's minor-most dims) at
       ``S > TKG_MAX_Q_LEN`` and ``D`` on the 128 lanes: prefill chunks and the
       whole-prompt paged prefill. **The rows' contract**: a row's valid slots
@@ -383,10 +437,13 @@ def update_block_cache_at_layer(
       the 1.7B's pool a 28-layer scan of K and V reads 1.65 ms against 31.8
       (v5e, PERF.md PR 43), at 1 live row of 8 as at 8.
     * PER HEAD (window ``(D,)``, the head an indexed dim: minor-most
-      already, H times the index rows) at ``S <= TKG_MAX_Q_LEN`` (decode and
-      speculation widths, 48 rows: 2.3 ms a dispatch on the 1.7B, where the
-      block form moves 64 KB to place 2 KB and reads 2.9), and wherever a
-      device holds fewer than ``WINDOW_MIN_HEADS`` heads.
+      already, H times the index rows) at ``S <= TKG_MAX_Q_LEN`` where the
+      kernel form does not apply (a block step and every speculation width,
+      ``S`` of 2-16; a head_dim off the lanes; a quantised pool, whose scale
+      update is fused into this write; a run with the kernels off; 48 rows
+      x 1: 2.3 ms a dispatch on the 1.7B, where the block form moves 64 KB
+      to place 2 KB and reads 2.9), and wherever a device holds fewer than
+      ``WINDOW_MIN_HEADS`` heads.
     * TOKEN WINDOW (window ``(H, D)``, one index row a token, the carry
       token-major) at a ``D`` off the lanes (64: Llama-3.2-1B, granite: the
       chip's own layout of that pool is not row-major in any form, and the
@@ -399,8 +456,8 @@ def update_block_cache_at_layer(
       the whole pool FOUR times around every layer's scatter (PERF.md
       PR 33), hence ``WINDOW_MIN_HEADS``.
 
-    On a head-sharded mesh (``block_cache_spec`` over tp/ep/cp > 1) the first
-    two forms run once per head shard (``parallel/sharding.shard_over_heads``,
+    On a head-sharded mesh (``block_cache_spec`` over tp/ep/cp > 1) the
+    kernel form and the next two run once per head shard (``parallel/sharding.shard_over_heads``,
     as the paged kernels do): each shard writes its own ``H / degree`` heads,
     the indices are replicated, and no collective can appear (an INDEXED
     sharded dim leaves GSPMD free to gather operand and updates). The token
@@ -433,12 +490,11 @@ def _stream_writer(pool_shape, slot_mapping: jax.Array, layer_idx, packed: bool 
     :func:`update_block_cache_at_layer` says the call takes."""
     L, NB1, H, bs, D = pool_shape
     B, S = slot_mapping.shape
-    batch_sharded = _batch_sharded()
-    per_head = not batch_sharded and (
-        H // head_shard_degree() < WINDOW_MIN_HEADS
-        or (S <= TKG_MAX_Q_LEN and not packed)
+    # asked to write, so the pass's attention kernel does not (kernel_runs False)
+    form = write_form(
+        S, D, H // head_shard_degree(), packed=packed, batch_sharded=batch_is_sharded()
     )
-    if takes_block_form(S, D, packed=packed, batch_sharded=batch_sharded):
+    if form == "blocks":
         segments = _row_segments(slot_mapping, bs, NB1)
 
         def write(data, new):
@@ -453,7 +509,7 @@ def _stream_writer(pool_shape, slot_mapping: jax.Array, layer_idx, packed: bool 
 
         def write(data, new):
             rows = new.reshape(B * S, H, D).astype(data.dtype)
-            if per_head:
+            if form == "per_head":
                 return shard_over_heads(
                     _scatter_per_head, (data, rows, layer_idx, blocks, offs),
                     in_heads=(2, 1, None, None, None), out_heads=2,
@@ -484,7 +540,7 @@ def _write_packed(data, new, layer_idx, slot_mapping, width: int):
         x = x.reshape(*lead, pack, rows, w)
         return jnp.swapaxes(x, -3, -2).reshape(*lead, rows, pack * w)
 
-    if S > TKG_MAX_Q_LEN and not _batch_sharded():
+    if S > TKG_MAX_Q_LEN and not batch_is_sharded():
         blocks, first_off, covered = _row_segments(slot_mapping, bs, NB1)
         laid = _lay_on_blocks(new[:, :, None, :], first_off, *covered.shape[1:])[:, :, :, 0]
         laid = in_rows(laid)[:, :, None]  # (B, nseg, 1, rows, lanes)

@@ -39,6 +39,19 @@ hand copy of a block (a 64-lane slice of an HBM ref), and the launch keeps
 one block a grid step through a ``BlockSpec`` on the block table
 (``_paged_by_block``, the contiguous kernel's body).
 
+The paged kernel is also the paged KV WRITE of a one-token decode pass
+(``new_kv``; modules/block_kvcache.write_form says when). A decode row's new
+token belongs in the row's LAST live block, which the row's last group has in
+VMEM for every KV head at once: the kernel lays the token's K and V rows over
+that block's 16-row tile with a select against an iota (the chip's compiler
+refuses a single-row store into a packed bfloat16 tile), attends the group as
+ever, which is what write-then-attend attends, and sends the tile
+``(Hkv, 16, D)`` back to the pool with one copy a stream from a scratch of
+its own, waited for a row later, so that it runs under the next row's
+arithmetic. The pools are aliased in and out (``input_output_aliases``) and
+stay the layer scan's carry, in place. The step then holds no scatter, whose
+``B x Hkv`` index rows a stream a layer cost more than the bytes they placed.
+
 Masking is taken from the SAME (B, 1, K, S_kv) boolean mask the native path
 uses — window/chunk/speculation decode masks all work unchanged — re-tiled to
 (B, kv_tiles, K, bs), plus per-(row, tile) any() maxima as scalar prefetch so
@@ -204,11 +217,12 @@ def _mask_tiles(mask: jax.Array, nkv: int, bs: int):
 
 def _common_call(
     kernel, grid, in_specs, out_specs, operands, out_shape, scratch, interpret, name,
-    semantics=("parallel", "arbitrary"), vmem_limit_bytes=None,
+    semantics=("parallel", "arbitrary"), vmem_limit_bytes=None, aliases=None,
 ):
     """The launch of every paged and decode attention kernel (this module's
     and ``ops/paged_flash_attention.py``'s): ``operands`` is (scalar
-    prefetch, tensors)."""
+    prefetch, tensors); ``aliases`` maps an operand (counted over both) to
+    the output that is the same buffer."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(operands[0]),
         grid=grid,
@@ -223,6 +237,7 @@ def _common_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes
         ),
+        input_output_aliases=aliases or {},
         interpret=interpret,
         name=name,
     )(*operands[0], *operands[1])
@@ -443,20 +458,96 @@ def _group_copies(bt_ref, end_ref, streams, sems, *, layer, P):
     return start, wait
 
 
+#: rows of a block the in-kernel KV write reads, merges and stores for ONE
+#: new token: bfloat16's sublane tile, the least the chip stores whole
+WRITE_TILE_ROWS = 16
+
+
+def _token_placer(layer, page, off, pools, bufs, tiles, news, sems, pending_ref):
+    """``(place, settle, fetch)`` of the in-kernel KV write of a row's ONE new
+    token, which belongs at row ``off`` of pool block ``page`` (< 0: nowhere).
+
+    ``place(held, into)`` merges the token's K and V rows (``news``, a
+    ``(n_kv, 1, D)`` block each) into the ``T`` rows around ``off`` that
+    ``held(stream)`` gives as the pool holds them, a select against an iota
+    (no single-row store into a packed tile), leaves the merged rows in the
+    stream's ``tiles`` scratch and, where ``into = (slot, first_row)`` says
+    so, in the group buffer the row is attended from; then starts ONE copy a
+    stream of the tile ``(n_kv, T, D)`` to its place in the pool. The copy
+    runs under the rows that follow: ``settle()`` waits for it, before the
+    tiles are written again and before the kernel returns."""
+    n_kv, T, D = tiles[0].shape
+    rows = pl.ds(pl.multiple_of(off // T * T, T), T)
+
+    def copy(stream, to_pool: bool, page):
+        there = pools[stream].at[layer, page, :, rows, :]
+        src, dst = (tiles[stream], there) if to_pool else (there, tiles[stream])
+        return pltpu.make_async_copy(src, dst, sems.at[stream])
+
+    def settle():
+        @pl.when(pending_ref[0] > 0)
+        def _():
+            for stream in range(len(pools)):
+                copy(stream, True, 0).wait()  # a wait takes the bytes and the semaphore
+            pending_ref[0] = 0
+
+    def place(held, into=None):
+        mine = jax.lax.broadcasted_iota(jnp.int32, (n_kv, T, D), 1) == off % T
+        for stream, (tile, new_ref) in enumerate(zip(tiles, news)):
+            merged = jnp.where(mine, new_ref[0], held(stream))
+            tile[...] = merged
+            if into is not None:
+                slot, first = into
+                bufs[stream][slot, :, pl.ds(first, T), :] = merged
+            copy(stream, True, page).start()
+        pending_ref[0] = 1
+
+    def fetch(stream):
+        """The token's tile as the pool holds it, for a token whose block the
+        row's attention has not brought in."""
+        held = copy(stream, False, page)
+        held.start()
+        held.wait()
+        return tiles[stream][...]
+
+    return place, settle, fetch
+
+
 def _paged_group_kernel(
     li_ref, bt_ref, lo_ref, end_ref, live_from_ref, *rest,
-    scale, n_kv, P, has_sink, q_dtype,
+    scale, n_kv, P, has_sink, q_dtype, writes=False,
 ):
     """One ROW per grid step; inside, a loop over the row's live block groups
     only. K and V stay in HBM: each group's ``P`` blocks are copied into one
     of two VMEM slots while the group before it (of this row or of the last
     live row) is attended, so a row with no live block starts no copy and
-    runs no arithmetic."""
-    if has_sink:
-        q_ref, mask_ref, sink_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
+    runs no arithmetic.
+
+    ``writes``: the kernel also PLACES the row's one new token (the paged KV
+    write's in-kernel form, modules/block_kvcache.write_form). The pools are
+    aliased in and out and read and written through the output refs; the
+    token's ``(block, offset)`` rides scalar prefetch (a negative block: no
+    write). A decode row's token lies in the row's LAST live block (the mask
+    admits the token's own position and nothing after it): in the row's last
+    group, once its blocks have landed, the token's K and V rows are laid
+    over the block's tile in the slot, the group is attended as ever (what
+    write-then-attend attends), and the tile goes back to the pool under the
+    next rows' arithmetic (:func:`_token_placer`). A token whose block the
+    row does not end in (no live block, another page) is stored all the same,
+    through a read of its tile, and is not attended."""
+    rest = list(rest)
+    if writes:
+        page_ref, off_ref = rest[:2]
+        del rest[:2]
+    q_ref, mask_ref = rest[:2]
+    del rest[:2]
+    sink_ref = rest.pop(0) if has_sink else None
+    if writes:
+        # the pools' input refs are the output refs' own buffers: unused
+        (k_new_ref, v_new_ref, _, _, o_ref, k_hbm, v_hbm, k_buf, v_buf, sems, slot_ref,
+         k_tile, v_tile, tile_sems, pending_ref) = rest
     else:
-        q_ref, mask_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
-        sink_ref = None
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = rest
     b = pl.program_id(0)
     B = pl.num_programs(0)
     R = q_ref.shape[2]  # a head group's query rows, padded to the sublane tile
@@ -468,6 +559,8 @@ def _paged_group_kernel(
     @pl.when(b == 0)
     def _first():
         slot_ref[0] = 0
+        if writes:
+            pending_ref[0] = 0
         # a masked token's probability is 0, and 0 x what the slot was born
         # with need not be 0: V's slots start from zeros
         v_buf[...] = jnp.zeros_like(v_buf)
@@ -478,6 +571,14 @@ def _paged_group_kernel(
             start(row, lo_ref[row], 0)
 
     lo, hi = lo_ref[b], (end_ref[b] + P - 1) // P
+    if writes:
+        page, off = page_ref[b], off_ref[b]
+        place, settle, fetch = _token_placer(
+            li_ref[0], page, off, (k_hbm, v_hbm), (k_buf, v_buf), (k_tile, v_tile),
+            (k_new_ref, v_new_ref), tile_sems, pending_ref,
+        )
+        last_block = end_ref[b] - 1
+        attended = (page >= 0) & (last_block >= 0) & (bt_ref[b, jnp.maximum(last_block, 0)] == page)
 
     def group(g, carry):
         slot = slot_ref[0]
@@ -490,6 +591,18 @@ def _paged_group_kernel(
 
         wait(b, g, slot)
         slot_ref[0] = 1 - slot
+
+        if writes:
+            @pl.when(last & attended)
+            def _place():
+                T = k_tile.shape[1]
+                bs = k_buf.shape[2] // P
+                first = pl.multiple_of((last_block - g * P) * bs + off // T * T, T)
+                settle()
+                place(
+                    lambda stream: (k_buf, v_buf)[stream][slot, :, pl.ds(first, T), :],
+                    into=(slot, first),
+                )
 
         # (1, G) at one query token, (R, G) laid out per query row otherwise
         row_mask = jnp.broadcast_to(mask_ref[0, g] > 0, (R, k_buf.shape[2]))
@@ -527,6 +640,16 @@ def _paged_group_kernel(
             m2 = jnp.maximum(m, sink)
             alpha = jnp.exp(m - m2)
             o_ref[0, h] = (acc * alpha / (l * alpha + jnp.exp(sink - m2))).astype(o_ref.dtype)
+
+    if writes:
+        @pl.when((page >= 0) & jnp.logical_not(attended))
+        def _store_unattended():
+            settle()
+            place(fetch)
+
+        @pl.when(b == B - 1)
+        def _last():
+            settle()
 
 
 def _paged_by_block(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, n_kv, interpret):
@@ -574,8 +697,12 @@ def _paged_by_block(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, 
     )
 
 
-def _paged_by_group(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, n_kv, P, interpret):
-    """The launch of :func:`_paged_group_kernel`. (B, K, Hq, D) -> (B, Hq*K, D)."""
+def _paged_by_group(
+    q, k_cache, v_cache, li, block_table, mask, sink, new=None, *, scale, n_kv, P, interpret
+):
+    """The launch of :func:`_paged_group_kernel`. (B, K, Hq, D) -> (B, Hq*K, D);
+    with ``new = (k_new, v_new (B, Hkv, 1, D), page, off (B,))``, one token a
+    row to place, -> that and the two pools, updated in place."""
     B, K, Hq, D = q.shape
     bs = k_cache.shape[3]
     MB = block_table.shape[1]
@@ -614,6 +741,34 @@ def _paged_by_group(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, 
     if sink is not None:
         in_specs.append(pl.BlockSpec((n_kv, R, 1), lambda b, *_: (0, 0, 0)))
         tensors.append(rows(jnp.repeat(sink.astype(jnp.float32), K)[None, :, None])[0])
+    prefetch = [li, block_table, lo, end, live_from]
+    out_specs = row_spec((n_kv, R, D))
+    out_shape = jax.ShapeDtypeStruct((B, n_kv, R, D), q.dtype)
+    scratch = [
+        pltpu.VMEM((2, n_kv, G, D), k_cache.dtype),
+        pltpu.VMEM((2, n_kv, G, D), v_cache.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    aliases = None
+    if new is not None:
+        k_new, v_new, page, off = new
+        prefetch += [page, off]
+        in_specs += [row_spec((n_kv, 1, D))] * 2
+        tensors += [k_new, v_new]
+        # the pools are the kernel's outputs too, in place
+        first_pool = len(prefetch) + len(tensors)
+        aliases = {first_pool: 1, first_pool + 1: 2}
+        pool = pl.BlockSpec(memory_space=pl.ANY)
+        out_specs = [out_specs, pool, pool]
+        out_shape = [out_shape] + [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in (k_cache, v_cache)]
+        T = WRITE_TILE_ROWS if bs % WRITE_TILE_ROWS == 0 else bs
+        scratch += [
+            pltpu.VMEM((n_kv, T, D), k_cache.dtype),
+            pltpu.VMEM((n_kv, T, D), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     tensors += [k_cache, v_cache]
 
@@ -621,24 +776,23 @@ def _paged_by_group(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, 
         functools.partial(
             _paged_group_kernel, scale=scale, n_kv=n_kv, P=P, has_sink=sink is not None,
             q_dtype=jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32,
+            writes=new is not None,
         ),
         grid=(B,),
         in_specs=in_specs,
-        out_specs=row_spec((n_kv, R, D)),
-        operands=([li, block_table, lo, end, live_from], tensors),
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, R, D), q.dtype),
-        scratch=[
-            pltpu.VMEM((2, n_kv, G, D), k_cache.dtype),
-            pltpu.VMEM((2, n_kv, G, D), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+        out_specs=out_specs,
+        operands=(prefetch, tensors),
+        out_shape=out_shape,
+        scratch=scratch,
         interpret=interpret,
         name="paged_tkg_decode_attention",
         # rows in order: a row's last group starts the next live row's copies
         semantics=("arbitrary",),
+        aliases=aliases,
     )
-    return out[:, :, :rk].reshape(B, Hq * K, D)
+    if new is None:
+        return out[:, :, :rk].reshape(B, Hq * K, D)
+    return out[0][:, :, :rk].reshape(B, Hq * K, D), out[1], out[2]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "n_kv", "interpret"))
@@ -650,20 +804,29 @@ def paged_tkg_decode_attention(
     block_table: jax.Array,  # (B, MB) int32
     mask: jax.Array,  # (B, 1, K, MB*bs) bool decode mask over the block view
     sink: jax.Array = None,
+    new_kv=None,  # (k_new (B, 1, Hkv, D), v_new, slot_mapping (B, 1)): placed by the kernel
     *,
     scale: float,
     n_kv: int,
     interpret: bool = False,
-) -> jax.Array:
+):
     """Paged decode attention: cache blocks are DMA'd straight via the block
     table (scalar prefetch), :func:`pages_per_step` of them a step and none
     past a row's last live block — kills the materializing
     read_block_cache_at_layer gather on the serving decode path
     (reference attention_block_tokengen kernel, attention_base.py:1609).
     Quantized caches DMA the code blocks and dequantize in-register.
-    Returns (B, K, Hq, D)."""
+    Returns (B, K, Hq, D).
+
+    With ``new_kv`` the kernel is also the paged KV write of the pass
+    (modules/block_kvcache.write_form says when: one token a row, ``D`` on
+    the lanes, an unquantised pool): the caller has NOT written this pass's
+    K and V; the kernel places a row's token at its ``slot_mapping`` entry (a
+    slot < 0 or past the pool: dropped, as the scatter drops it), attends as
+    write-then-attend does, and returns ``(out, k_cache, v_cache)`` with the
+    pools updated in place (``input_output_aliases``)."""
     B, K, Hq, D = q.shape
-    _, _, Hkv, bs, _ = k_cache.shape
+    _, NB1, Hkv, bs, _ = k_cache.shape
     MB = block_table.shape[1]
     assert mask.shape[-1] == MB * bs, (mask.shape, MB, bs)
     n_rep = Hq // n_kv
@@ -676,6 +839,21 @@ def paged_tkg_decode_attention(
     li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
     operands = (q, k_cache, v_cache, li, block_table.astype(jnp.int32), mask, sink)
     P = pages_per_step(n_kv, bs, D, k_cache.dtype, MB)
+    if new_kv is not None:
+        assert K == 1 and D % 128 == 0 and not quantized, (K, D, quantized)
+        k_new, v_new, slot_mapping = new_kv
+        slots = slot_mapping.reshape(B).astype(jnp.int32)
+        kept = (slots >= 0) & (slots // bs < NB1)
+        new = (
+            k_new.astype(k_cache.dtype).transpose(0, 2, 1, 3),
+            v_new.astype(v_cache.dtype).transpose(0, 2, 1, 3),
+            jnp.where(kept, slots // bs, -1),
+            jnp.where(kept, slots % bs, 0),
+        )
+        out, k_cache, v_cache = _paged_by_group(
+            *operands, new, scale=scale, n_kv=n_kv, P=P, interpret=interpret
+        )
+        return _unprep_out(out, B, K, Hq, D), k_cache, v_cache
     if D % 128:
         out = _paged_by_block(*operands, scale=scale, n_kv=n_kv, interpret=interpret)
     else:
@@ -720,23 +898,28 @@ def dispatch_tkg_decode(
 
 
 def dispatch_paged_tkg_decode(
-    q, k_cache, v_cache, layer_idx, block_table, mask, sink=None, *, scale, interpret
+    q, k_cache, v_cache, layer_idx, block_table, mask, sink=None, new_kv=None,
+    *, scale, interpret
 ):
     """:func:`paged_tkg_decode_attention` once per head shard: the stacked
     block pool ``(L, NB+1, Hkv, bs, D)`` splits on the kv heads exactly as
     the layer scan carries it (block_kvcache.block_cache_spec), block table
     and mask are replicated. At tp = 4 a chip's kernel reads its own 2 of
-    Qwen3-14B's 8 kv heads for its own 10 of 40 q heads."""
+    Qwen3-14B's 8 kv heads for its own 10 of 40 q heads. With ``new_kv``
+    (``k_new, v_new (B, 1, Hkv, D)``, ``slot_mapping``) each shard's kernel
+    also places its own heads of the pass's one token a row, and the two
+    pools come back with the output, each shard's heads in place."""
     from neuronx_distributed_inference_tpu.parallel.sharding import shard_over_heads
 
-    def per_shard(q_s, k_s, v_s, li, bt, m, sink_s):
+    def per_shard(q_s, k_s, v_s, li, bt, m, sink_s, new_s):
         return paged_tkg_decode_attention(
-            q_s, k_s, v_s, li, bt, m, sink_s,
+            q_s, k_s, v_s, li, bt, m, sink_s, new_s,
             scale=scale, n_kv=k_s.shape[2], interpret=interpret,
         )
 
     heads = _cache_heads(k_cache, 2)
     return shard_over_heads(
-        per_shard, (q, k_cache, v_cache, layer_idx, block_table, mask, sink),
-        in_heads=(2, heads, heads, None, None, None, 0), out_heads=2,
+        per_shard, (q, k_cache, v_cache, layer_idx, block_table, mask, sink, new_kv),
+        in_heads=(2, heads, heads, None, None, None, 0, (2, 2, None)),
+        out_heads=2 if new_kv is None else (2, heads, heads),
     )

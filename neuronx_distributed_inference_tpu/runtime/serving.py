@@ -248,7 +248,10 @@ class ServingSession:
                 PrefixCachingAllocator,
                 chunk_write_blocks,
                 kv_block_bytes,
+                write_form,
             )
+            from neuronx_distributed_inference_tpu.models.base import decode_kernel_runs
+            from neuronx_distributed_inference_tpu.modules.kvcache import QuantizedKV
 
             if tc.pa_num_blocks is None:
                 # pa_pool_bytes configs resolve the count at cache init
@@ -293,9 +296,23 @@ class ServingSession:
             )
             # and what the paged KV write of a chunk pass moves, where it
             # moves whole blocks
+            batch_sharded = app.spec.attention_dp * app.spec.data_parallel > 1
             self._chunk_write_blocks = functools.partial(
                 chunk_write_blocks, block_size=pool.shape[3], head_dim=pool.shape[4],
-                batch_sharded=app.spec.attention_dp * app.spec.data_parallel > 1,
+                batch_sharded=batch_sharded,
+            )
+            # and the form a decode pass's write takes at a kv bucket, asked
+            # as the step program asks it (models/base.paged_write_attend)
+            # (shapes and flags only: the pool itself is donated every step)
+            spec, k_shape, v_shape = app.spec, pool.shape, app.kv_cache.v.shape
+            form_of = functools.partial(
+                write_form, head_dim=pool.shape[4], heads=shape["n_kv"],
+                quantised=isinstance(pool, QuantizedKV), batch_sharded=batch_sharded,
+            )
+            self._decode_write_form = lambda q_len, kv_width: form_of(
+                q_len, kernel_runs=decode_kernel_runs(
+                    spec, q_len, kv_width, kv_width, k_shape, v_shape
+                ),
             )
         # async 1-ahead decode (reference modules/async_execution.py:190):
         # the decode step dispatched last step(), not yet fetched —
@@ -1962,6 +1979,8 @@ class ServingSession:
         tel.step("decode")
         tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
         tel.decode_pass(len(rows), B)
+        if self.block_mode and tel.enabled:
+            tel.kv_write_rows(self._decode_write_form(K, width), len(rows))
         self._count_pass("decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
                          block_rows=block_rows)
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)

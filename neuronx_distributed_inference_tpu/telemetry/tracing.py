@@ -481,6 +481,15 @@ class TelemetrySession:
             "the write stores as they are; kind=merged, a row's first or last "
             "block that it read and merged with what the pool held. Counted a "
             "layer and a stream once", labels=("kind",))
+        self._decode_kv_write_rows = r.counter(
+            "nxdi_decode_kv_write_rows_total",
+            "live rows of the decode passes of the split serving step over a "
+            "paged cache, by the form their paged KV write took "
+            "(modules/block_kvcache.write_form, asked by the session as the "
+            "program asks it): form=kernel, the paged decode kernel placed "
+            "the row's token in the block it attends; form=per_head, a "
+            "scatter with an index row a (row, position, head); form=window, "
+            "a scatter with the heads in its window", labels=("form",))
         self._occupancy = r.gauge(
             "nxdi_batch_occupancy", "live rows in the last decode dispatch")
         self._kv_pool = r.gauge(
@@ -1280,6 +1289,13 @@ class TelemetrySession:
             return
         self._chunk_kv_write_blocks.child(("whole",)).inc(whole)
         self._chunk_kv_write_blocks.child(("merged",)).inc(merged)
+
+    def kv_write_rows(self, form: str, rows: int) -> None:
+        """One decode pass over a paged cache: its live rows, under the form
+        their paged KV write took."""
+        if not self.enabled:
+            return
+        self._decode_kv_write_rows.child((form,)).inc(rows)
 
     def pool_gauges(self, occupancy: int, kv_pool_bytes: int, kv_free_bytes: int) -> None:
         if not self.enabled:

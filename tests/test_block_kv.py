@@ -240,6 +240,46 @@ def test_paged_write_on_head_sharded_cache_has_no_collective(case):
     np.testing.assert_array_equal(_bits(k_up), _bits(want))
 
 
+# (q_len, head_dim, heads a device, what else the call shows) -> the form
+WRITE_FORM_TABLE = {
+    "decode_on_the_kernel": ((1, 128, 8, dict(kernel_runs=True)), "kernel"),
+    "decode_2_heads_a_device": ((1, 128, 2, dict(kernel_runs=True)), "kernel"),
+    "kernels_off": ((1, 128, 8, dict(kernel_runs=False)), "per_head"),
+    "block_step_S4": ((4, 128, 4, dict(kernel_runs=True)), "per_head"),
+    "speculation_S16": ((16, 128, 8, dict(kernel_runs=True)), "per_head"),
+    "head_dim_64": ((1, 64, 8, dict(kernel_runs=True)), "per_head"),
+    "quantised": ((1, 128, 8, dict(kernel_runs=True, quantised=True)), "per_head"),
+    "packed": ((1, 128, 8, dict(kernel_runs=True, packed=True)), "window"),
+    "packed_few_heads": ((1, 128, 2, dict(kernel_runs=True, packed=True)), "per_head"),
+    "batch_sharded": ((1, 128, 8, dict(kernel_runs=True, batch_sharded=True)), "window"),
+    "chunk_S128": ((128, 128, 8, dict(kernel_runs=False)), "blocks"),
+    "chunk_S128_2_heads": ((128, 128, 2, dict()), "blocks"),
+    "chunk_head_dim_64": ((128, 64, 8, dict()), "window"),
+    "chunk_head_dim_64_few_heads": ((128, 64, 4, dict()), "per_head"),
+    "chunk_batch_sharded": ((128, 128, 8, dict(batch_sharded=True)), "window"),
+}
+
+
+@pytest.mark.parametrize("case", list(WRITE_FORM_TABLE))
+def test_write_form_is_decided_by_what_a_call_shows(case):
+    """``block_kvcache.write_form``: the one decision among the four forms of
+    the paged KV write. ``kernel`` only for one token a row at a head_dim on
+    the 128 lanes, an unquantised pool, no packed axis, no sharded batch, and
+    an attention that IS the paged decode kernel; every other call keeps the
+    form it had (``takes_block_form`` and the writer read the same table)."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        WRITE_FORMS,
+        takes_block_form,
+        write_form,
+    )
+
+    (q_len, head_dim, heads, shows), want = WRITE_FORM_TABLE[case]
+    got = write_form(q_len, head_dim, heads, **shows)
+    assert got == want and got in WRITE_FORMS
+    shows = {k: v for k, v in shows.items() if k in ("packed", "batch_sharded")}
+    assert (got == "blocks") == takes_block_form(q_len, head_dim, **shows)
+
+
 def test_serving_rows_are_consecutive_positions_of_one_sequence(monkeypatch):
     """The contract of the paged write's block form
     (update_block_cache_at_layer): every row a ServingSession hands a step

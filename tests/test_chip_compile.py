@@ -349,16 +349,33 @@ def _assert_chunk_write_moves_blocks(compiled, rows, q, heads, pool_shape):
     assert {table[name] for name in writes} == {"layer.kv_write"}
 
 
+def _assert_decode_write_is_the_kernels(compiled):
+    """A one-token decode program whose paged KV write is the decode kernel's
+    (block_kvcache.write_form ``kernel``): no scatter in the program, nothing
+    under ``layer.kv_write``, and the kernel, by the name the rooflines match,
+    under ``layer.attn``."""
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    assert _scatter_index_rows(compiled) == []
+    table = device_scopes.scope_table(compiled.as_text())["ops"]
+    assert "layer.kv_write" not in set(table.values())
+    kernels = [name for name in table if name.startswith("paged_tkg_decode_attention")]
+    assert kernels and {table[name] for name in kernels} == {"layer.attn"}
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program, monkeypatch):
     """The paged KV write leaves the layer scan's cache carry in the layout
     the kernel reads (modules/block_kvcache.update_block_cache_at_layer), at
     the benchmark's widths: 48 slots, 1056 blocks x 32 tokens, 28 layers.
 
-    Both programs: NO pool-shaped copy anywhere. decode (48 x 1) writes by
-    the per-head scatter (window ``(D,)``); with the head in the scatter's
-    window it held 6 copies (two per layer in the scan, two at entry, two at
-    exit) and 3.89 GB of temporaries. chunk (CHUNK_ROWS x 128 = 8 x 128
+    Both programs: NO pool-shaped copy anywhere. decode (48 x 1): the paged
+    decode kernel places the row's token itself, the pools aliased in and out
+    of the custom call (block_kvcache.write_form ``kernel``): NO scatter, no
+    op under ``layer.kv_write``, the kernel under ``layer.attn``, and the plan
+    what the per-head scatter (window ``(D,)``) planned, 7.40 GiB; with the
+    head in a scatter's window it held 6 copies (two per layer in the scan,
+    two at entry, two at exit) and 3.89 GB of temporaries. chunk (CHUNK_ROWS x 128 = 8 x 128
     whatever the slot count, its rows addressed by slot) writes whole blocks
     (window ``(H, bs, D)``, the pool's minor-most dims: 40 index rows a
     stream a layer where the per-head form had 8192); with the token window
@@ -392,6 +409,8 @@ def test_paged_serving_step_does_not_relay_the_block_pool(chip_mesh, program, mo
     if program == "decode":
         assert outside == 0
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+        assert _planned_bytes(compiled) < 7.41 * 2**30
+        _assert_decode_write_is_the_kernels(compiled)
     else:
         # the paged flash kernel copies its blocks by hand out of the
         # STACKED pool (ops/paged_flash_attention.py): no layer's slice is
@@ -433,7 +452,8 @@ def test_tp4_paged_serving_step_runs_its_kernels_per_shard(chip_mesh, program):
     in the kernel's layout.
 
     Both programs: NO copy of a chip's pool slice anywhere, in any shape,
-    and next to no temporaries. decode (64 x 1, per-head write): native
+    and next to no temporaries. decode (64 x 1, the write in each shard's
+    kernel, a chip's 2 heads of the pools aliased through it): native
     attention over 64 rows x the kv bucket planned 12.13 GiB a chip (PERF.md,
     PR 26), this plans under 8.5; with the write in its token-window form
     (what a sharded head axis took before) the scan's body held two such
@@ -467,6 +487,8 @@ def test_tp4_paged_serving_step_runs_its_kernels_per_shard(chip_mesh, program):
     assert _planned_bytes(compiled) < 8.5 * 2**30
     if program == "chunk":
         _assert_chunk_write_moves_blocks(compiled, 8, 128, heads // 4, (L, nb1, heads // 4, bs, d))
+    else:
+        _assert_decode_write_is_the_kernels(compiled)
 
 
 def test_tp4_contiguous_decode_step_runs_its_kernel_per_shard(chip_mesh):
@@ -592,8 +614,8 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     at (2, 128) a token beside a (20, 48, 2688) carry; the decode program
     holds ``paged_tkg_decode_attention`` and the 8-row chunk program
     ``paged_flash_attention`` (two KV heads a device; its KV write moves
-    whole blocks, the decode program's is per-head: no copy of the pool in
-    the layer scan), and each plans under 14.75 GiB of the chip's 15.75. The
+    whole blocks, the decode program's is the decode kernel's own: no copy of
+    the pool in the layer scan), and each plans under 14.75 GiB of the chip's 15.75. The
     chunk program's expert products are ``grouped_matmul`` on the scanned
     stacks in place (no ``ragged-dot``, nothing of the shape of a layer's 16
     x 2048 x 2048 stack); the decode program keeps its batched products."""
@@ -613,6 +635,8 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     assert _pool_copies(compiled, cache.k.shape)[0] == 0
     if program == "chunk":
         _assert_chunk_write_moves_blocks(compiled, 8, 128, 2, cache.k.shape)
+    else:
+        _assert_decode_write_is_the_kernels(compiled)
     _assert_expert_products(compiled, program, [(16, 2048, 2048)])
     mem = compiled.memory_analysis()
     print(f"\nzaya1-8b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
@@ -682,6 +706,8 @@ def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, pro
     assert _pool_copies(compiled, cache.k.shape)[0] == 0
     if program == "chunk":
         _assert_chunk_write_moves_blocks(compiled, 8, 128, 4, cache.k.shape)
+    else:  # a block step (S = 4) keeps the per-head scatter: 48 x 4 x 4 index rows a stream
+        assert _scatter_index_rows(compiled) == [768, 768]
     _assert_expert_products(compiled, program, [(128, 2048, 768), (128, 768, 2048)])
     mem = compiled.memory_analysis()
     print(f"\nsdar-30b-a3b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
@@ -822,8 +848,9 @@ def test_ouro_serving_step_loops_one_layer_body_over_the_pool_in_place(chip_mesh
     nothing cut, 8 slots, 192 blocks), both step programs compiled for a
     described v5e at kv bucket 2048. The pool spans 4 x 48 = 192 streams of 16
     KV heads (48 MiB a block of 32 tokens, 9.05 GiB in all) and rides the
-    carry of BOTH scans: no copy of the pool's shape, in a loop body or at the
-    entry. One compiled layer body (the loop is a scan, not an unrolling): the
+    carry of BOTH scans, also through the decode kernel that now writes it
+    (aliased in and out at cache index ``t * L + l``): no copy of the pool's
+    shape, in a loop body or at the entry. One compiled layer body (the loop is a scan, not an unrolling): the
     paged kernel is in the program ONCE: ``paged_tkg_decode_attention`` at 16
     KV heads and one query head a KV head in the decode program (8 x 1),
     ``paged_flash_attention`` in the chunk program (8 x 128). The norms the
@@ -849,7 +876,11 @@ def test_ouro_serving_step_loops_one_layer_body_over_the_pool_in_place(chip_mesh
     if program == "chunk":
         _assert_chunk_write_moves_blocks(compiled, 8, 128, 16, cache.k.shape)
     scopes = set(device_scopes.scope_table(text)["ops"].values())
-    assert {"layer.post_norm", "loop.norm", "layer.kv_write", "layer.attn", "layer.mlp"} <= scopes
+    assert {"layer.post_norm", "loop.norm", "layer.attn", "layer.mlp"} <= scopes
+    if program == "chunk":
+        assert "layer.kv_write" in scopes
+    else:
+        _assert_decode_write_is_the_kernels(compiled)
     assert not _work_under_no_scope(compiled), _work_under_no_scope(compiled)[:5]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.1 * 2**30

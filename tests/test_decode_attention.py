@@ -326,3 +326,149 @@ def test_decode_kv_block_counter_reads_what_the_host_knows(head_dim):
     got = {x["labels"]["kind"]: x["value"] for x in snap}
     assert got == {"live": sum(blocks), "walked": sum(walked)}
     assert got["live"] == 3 * 8 + 4 * 1 + 1 * 4  # 41-48 tokens are 3 blocks, 49 is 4; 7-10 are 1
+
+
+# the in-kernel KV write: ONE new token a row, placed by the paged decode
+# kernel in the block it holds for the row anyway (block_kvcache.write_form).
+# ``valid`` = context tokens a row WITH the new token in (so the token sits at
+# position valid - 1, offset (valid - 1) % 32 of its block); ``slots``: "tok" =
+# that position's slot, an int = that slot whatever the context, -1 = dropped.
+_W = 32  # a block's tokens here: offsets 0 / 15 / 16 / 31 are the tile's edges
+FUSED_WRITE_CASES = {
+    "offsets_0_15_16_31": dict(valid=[2 * _W + 1, _W + 16, 17, 2 * _W]),
+    "row_opens_a_block": dict(valid=[_W + 1, 1, 3 * _W + 1]),
+    "no_live_block_and_negative_slots": dict(
+        valid=[0, 40, 0, 70], slots=[-1, -1, 3 * _W + 7, "tok"]),
+    "token_not_in_the_last_block": dict(valid=[70, 40], slots=["tok", 5]),
+    "slot_past_the_pool": dict(valid=[33, 20], slots=[10**6, "tok"]),
+    "8kv_rep1": dict(HKV=8, HQ=8, valid=[3 * _W + 5, _W]),
+    "16kv_rep1_bf16": dict(HKV=16, HQ=16, dtype="bfloat16", valid=[2 * _W + 16, 7, 0], slots=["tok", "tok", -1]),
+    "2kv_rep5": dict(HKV=2, HQ=10, valid=[4 * _W - 1, 2 * _W + 1]),
+    "8kv_rep2_bf16_q_bf16": dict(HKV=8, HQ=16, dtype="bfloat16", q_dtype="bfloat16", valid=[100, 64, 17]),
+    "table_no_multiple_of_p": dict(MB=12, valid=[12 * _W, 9 * _W - 2, 3]),
+    "second_and_third_group": dict(MB=24, valid=[8 * _W + 1, 17 * _W + 16, 24 * _W, 5]),
+    "sink": dict(sink=True, valid=[2 * _W + 3, 0, _W], slots=["tok", -1, "tok"]),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSED_WRITE_CASES))
+def test_paged_kernel_places_the_new_token_as_write_then_attend_does(case):
+    """The fused call (``new_kv``) against the per-head write followed by the
+    same kernel: the attention output AND the whole pool afterwards, bit for
+    bit (the tile written back carries what the pool held at its other 15
+    rows). A row whose slot is negative or past the pool writes nothing; a
+    token whose block is not the row's last live one is stored and not
+    attended (the write-then-attend side attends nothing there either: the
+    mask does not admit it)."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        update_block_cache_at_layer,
+    )
+    from neuronx_distributed_inference_tpu.ops.decode_attention import pages_per_step
+
+    c = dict(sink=False, HQ=4, HKV=2, MB=8, dtype="float32", q_dtype="float32", slots=None)
+    c.update(FUSED_WRITE_CASES[case])
+    hq, hkv, MB, bs, d, layer = c["HQ"], c["HKV"], c["MB"], _W, 128, 1
+    valid = c["valid"]
+    want_slots = c["slots"] or ["tok"] * len(valid)
+    rng = np.random.RandomState(len(case))
+    B = len(valid)
+    NB = sum(-(-v // bs) for v in valid) + 5
+    P = pages_per_step(hkv, bs, d, c["dtype"], MB)
+    if case == "table_no_multiple_of_p":
+        assert MB % P
+    q = _rand(rng, B, 1, hq, d).astype(c["q_dtype"])
+    shape = (L, NB + 1, hkv, bs, d)
+    k_cache, v_cache = (_rand(rng, *shape).astype(c["dtype"]) for _ in range(2))
+    k_new, v_new = (_rand(rng, B, 1, hkv, d).astype(c["dtype"]) for _ in range(2))
+    bt = np.zeros((B, MB), np.int32)
+    pages = iter(rng.permutation(np.arange(1, NB + 1)))
+    slots = np.full((B, 1), -1, np.int64)
+    for b, v in enumerate(valid):
+        n = -(-v // bs)
+        bt[b, :n] = [next(pages) for _ in range(n)]
+        if want_slots[b] == "tok":
+            slots[b, 0] = bt[b, (v - 1) // bs] * bs + (v - 1) % bs
+            # the contract of a decode pass: the token's block is the row's last live one
+            assert slots[b, 0] // bs == bt[b, n - 1]
+        else:
+            slots[b, 0] = want_slots[b]
+    block_table, slot_mapping = jnp.asarray(bt), jnp.asarray(slots, jnp.int32)
+    mask, _ = _decode_mask(rng, B, 1, MB * bs, valid)
+    sink_w = _rand(rng, hq) if c["sink"] else None
+    spec = AttnSpec(num_heads=hq, num_kv_heads=hkv, head_dim=d, has_sink=c["sink"])
+    kw = dict(scale=spec.softmax_scale, n_kv=hkv, interpret=True)
+    li = jnp.int32(layer)
+
+    k_want, v_want = update_block_cache_at_layer(k_cache, v_cache, k_new, v_new, li, slot_mapping)
+    want = paged_tkg_decode_attention(q, k_want, v_want, li, block_table, mask, sink_w, **kw)
+    got, k_got, v_got = paged_tkg_decode_attention(
+        q, k_cache, v_cache, li, block_table, mask, sink_w, (k_new, v_new, slot_mapping), **kw
+    )
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32)))
+    for g, w, held in ((k_got, k_want, k_cache), (v_got, v_want, v_cache)):
+        assert g.dtype == held.dtype
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32)))
+        # and the write is the rows' tokens and nothing else: one row a kept slot a head
+        kept = [s for s in slots[:, 0] if 0 <= s < (NB + 1) * bs]
+        changed = np.asarray((g != held).any(axis=-1))  # (L, NB+1, Hkv, bs)
+        assert changed.sum() == len(kept) * hkv and not changed[[0, 2]].any()
+
+
+def test_serving_decode_writes_in_kernel_and_commits_the_same_tokens(monkeypatch):
+    """A short closed loop on the paged cache at head_dim 128 with the paged
+    decode kernel forced on (interpret mode): every decode row's KV write is
+    the kernel's (``nxdi_decode_kv_write_rows_total{form="kernel"}``; no
+    scatter is traced in the decode program), and the tokens are those of the
+    same loop with the decision patched off (the per-head scatter, then the
+    same kernel)."""
+    import sys, os
+    sys.path.insert(0, os.path.dirname(__file__))
+    from conftest import make_tiny_config, make_random_hf_state_dict
+
+    from neuronx_distributed_inference_tpu.modules import block_kvcache as bk
+    from neuronx_distributed_inference_tpu.runtime.application import TpuModelForCausalLM
+    from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+
+    def loop(tel):
+        cfg = make_tiny_config(
+            hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
+            tpu=dict(
+                seq_len=128, token_generation_buckets=[128], is_continuous_batching=True,
+                is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=64, batch_size=3,
+                ctx_batch_size=1, attn_block_tkg_kernel_enabled=True,
+            ),
+        )
+        app = TpuModelForCausalLM(None, cfg)
+        app.load(state_dict=make_random_hf_state_dict(cfg))
+        sess = ServingSession(app, telemetry=tel)
+        assert sess.add_request("long", list(range(1, 31)), max_new_tokens=6)
+        assert sess.add_request("short", [5, 17, 92, 41, 33, 88], max_new_tokens=12)
+        return sess.run_to_completion()
+
+    def rows_by_form(tel):
+        samples = tel.registry.snapshot()["nxdi_decode_kv_write_rows_total"]["samples"]
+        return {x["labels"]["form"]: x["value"] for x in samples if x["value"]}
+
+    scatters = []
+    per_head = bk._scatter_per_head
+    monkeypatch.setattr(
+        bk, "_scatter_per_head", lambda data, rows, *a: (scatters.append(rows.shape), per_head(data, rows, *a))[1]
+    )
+    jax.clear_caches()
+    tel = TelemetrySession(enabled=True)
+    fused = loop(tel)
+    assert all(len(v) > 0 for v in fused.values())
+    counted = rows_by_form(tel)
+    assert set(counted) == {"kernel"} and counted["kernel"] >= 12
+    assert scatters == []  # the prompts go in whole blocks, the decode rows in the kernel
+
+    form = bk.write_form
+    monkeypatch.setattr(bk, "write_form", lambda *a, **kw: form(*a, **{**kw, "kernel_runs": False}))
+    jax.clear_caches()
+    scatters.clear()
+    tel = TelemetrySession(enabled=True)
+    assert loop(tel) == fused
+    assert rows_by_form(tel) == {"per_head": counted["kernel"]}
+    assert {shape[0] for shape in scatters} == {3}  # the decode program's 3 rows x 1

@@ -194,3 +194,65 @@ def test_kv_write_under_a_sharded_head_axis(S, H, packed, form, quantized):
     assert got[0].data.sharding.spec == specs.k.data if quantized else (
         got[0].sharding.spec == specs.k
     )
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_paged_kernel_places_the_token_per_head_shard(tp):
+    """The in-kernel KV write on a head-sharded mesh
+    (``dispatch_paged_tkg_decode`` with ``new_kv``): each shard's kernel
+    places its own heads of the row's one token, the two pools come back in
+    the layout the layer scan carries them (``block_cache_spec``), no
+    collective is compiled, and output and WHOLE pool are bit for bit what
+    the unsharded write-then-attend leaves."""
+    from jax.sharding import NamedSharding
+
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        block_cache_spec,
+        update_block_cache_at_layer,
+    )
+    from neuronx_distributed_inference_tpu.ops.decode_attention import (
+        dispatch_paged_tkg_decode,
+        paged_tkg_decode_attention,
+    )
+    from neuronx_distributed_inference_tpu.parallel.mesh import build_mesh
+
+    L, NB, bs, D, B, MB, hq, hkv = 2, 12, 16, 128, 4, 4, 8, 4
+    rng = np.random.default_rng(52)
+    k_pool, v_pool = (
+        jnp.asarray(rng.standard_normal((L, NB + 1, hkv, bs, D)), jnp.bfloat16) for _ in range(2)
+    )
+    q = jnp.asarray(rng.standard_normal((B, 1, hq, D)) * 0.3, jnp.bfloat16)
+    k_new, v_new = (jnp.asarray(rng.standard_normal((B, 1, hkv, D)), jnp.bfloat16) for _ in range(2))
+    valid = [3 * bs + 1, bs, 0, 2 * bs + 9]  # offsets 0 and 15, a dead row, mid-tile
+    bt = np.zeros((B, MB), np.int32)
+    pages = iter(rng.permutation(np.arange(1, NB + 1)))
+    slots = np.full((B, 1), -1, np.int32)
+    for b, n in enumerate(valid):
+        bt[b, : -(-n // bs)] = [next(pages) for _ in range(-(-n // bs))]
+        if n:
+            slots[b, 0] = bt[b, (n - 1) // bs] * bs + (n - 1) % bs
+    mask = jnp.asarray(np.arange(MB * bs)[None, :] < np.asarray(valid)[:, None])[:, None, None, :]
+    bt, slots, li = jnp.asarray(bt), jnp.asarray(slots), jnp.int32(1)
+    kw = dict(scale=D**-0.5, interpret=True)
+
+    k_want, v_want = update_block_cache_at_layer(k_pool, v_pool, k_new, v_new, li, slots)
+    want = paged_tkg_decode_attention(q, k_want, v_want, li, bt, mask, n_kv=hkv, **kw)
+
+    def fused(q, k, v, kn, vn):
+        return dispatch_paged_tkg_decode(q, k, v, li, bt, mask, None, (kn, vn, slots), **kw)
+
+    mesh = build_mesh(tp_degree=tp, devices=jax.devices()[:tp])
+    spec = block_cache_spec()
+    k_sh, v_sh = (jax.device_put(x, NamedSharding(mesh, spec.k)) for x in (k_pool, v_pool))
+    with jax.set_mesh(mesh):
+        got, k_got, v_got = jax.jit(fused)(q, k_sh, v_sh, k_new, v_new)
+        assert "shard_map" in str(jax.make_jaxpr(fused)(q, k_sh, v_sh, k_new, v_new))
+        hlo = jax.jit(fused).lower(q, k_sh, v_sh, k_new, v_new).compile().as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute", "reduce-scatter"):
+        assert op not in hlo, op
+    for pool in (k_got, v_got):  # each chip keeps its own heads, as the scan carries them
+        assert pool.sharding.is_equivalent_to(NamedSharding(mesh, spec.k), pool.ndim)
+    for g, w in ((got, want), (k_got, k_want), (v_got, v_want)):
+        np.testing.assert_array_equal(
+            np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32))
+        )
