@@ -30,7 +30,9 @@ first copies), and no block past a row's last live one is copied. A row with
 no live block costs one empty grid step; the cost of a dispatch follows the
 live context, not slots x bucket. One online-softmax update per KV head runs
 over a whole group, on query rows padded to the 8-sublane tile, the
-statistics carried in registers. Both products take the cache tile as it is
+statistics carried in registers; the heads' updates are written stage by
+stage ACROSS the heads, so that their chains of dependent operations overlap
+(``_attend_group``). Both products take the cache tile as it is
 stored: a float32 operand goes to the matrix unit as three bfloat16 parts
 that sum to it exactly (``_split3``), every product exact in the float32
 accumulator, so nothing is rounded that the float32 form would keep. At a
@@ -330,8 +332,11 @@ def tkg_decode_attention(
 #: small enough that two slots of both streams sit in VMEM beside q, the
 #: row's mask and the accumulators (4 x this)
 GROUP_BYTES = 1024 * 1024
-#: and the most tokens a group may span: a row's last group is computed
-#: whole, so a wide group of small blocks wastes arithmetic on a short row
+#: and the most tokens a group may span: the width ``pages`` was measured at
+#: (analysis/tuning_table.json). Not for the arithmetic's sake: a group's
+#: update costs by its stages, not by its tokens, and hides under the copies
+#: (PERF.md, PR 53: attending only the live sub-tiles of a row's last group
+#: moved nothing)
 GROUP_TOKENS = 512
 
 
@@ -513,6 +518,49 @@ def _token_placer(layer, page, off, pools, bufs, tiles, news, sems, pending_ref)
     return place, settle, fetch
 
 
+#: vector registers' worth of scores the KV heads attended AT ONCE may hold
+#: (:func:`_attend_group`): the chip's 64. Every served shape fits whole (8
+#: heads x 8 rows x 512 tokens = 32; 4 heads x 32 rows x 512 = 64, which
+#: read faster at once, spills and all, than two heads at a time: PERF.md,
+#: PR 53); past it the heads go in chunks
+SCORE_VREGS = 64
+
+
+def _attend_group(q_ref, mask, k_ref, v_ref, carry, *, scale, q_dtype):
+    """One online-softmax update a KV head over a group of ``G`` tokens:
+    ``q_ref`` (Hkv, R, D), ``k_ref`` / ``v_ref`` (Hkv, G, D) the cache tiles as
+    they are stored, ``mask`` (1, G) bool at one query token, (R, G) laid out
+    per query row otherwise; ``carry`` and the result are ``(m, l, acc)`` a
+    head, in registers.
+
+    Written STAGE BY STAGE across the heads (every head's scores, then every
+    head's maximum, ... then every head's ``p . v``), not head by head: a
+    head's update is a chain of two products, two reductions over the lanes
+    and an exp, each waiting for the one before, and the chip runs the chains
+    of heads written one after the other one after the other (PERF.md, PR 53:
+    8 heads of the 1.7B 2.0 us a group whatever the group's width, 0.5 us
+    stage by stage). As many heads at once as ``SCORE_VREGS`` admits; the
+    values are the same, operation for operation."""
+    n_kv, R, _ = q_ref.shape
+    G = k_ref.shape[1]
+    row_mask = jnp.broadcast_to(mask, (R, G))
+    at_once = max(1, SCORE_VREGS // (-(-R // 8) * -(-G // 128)))
+    out = []
+    for first in range(0, n_kv, at_once):
+        heads = range(first, min(first + at_once, n_kv))
+        m_prev, l_prev, acc_prev = zip(*(carry[h] for h in heads))
+        # q (R, D): bfloat16 where it came so; s (R, G)
+        s = [_dot_tile(q_ref[h].astype(q_dtype), k_ref[h], 1) * scale for h in heads]
+        s = [jnp.where(row_mask, x, NEG_INF) for x in s]
+        m_new = [jnp.maximum(m, jnp.max(x, axis=1, keepdims=True)) for m, x in zip(m_prev, s)]
+        p = [jnp.where(row_mask, jnp.exp(x - m), 0.0) for x, m in zip(s, m_new)]
+        alpha = [jnp.exp(m0 - m) for m0, m in zip(m_prev, m_new)]
+        l_new = [l * a + jnp.sum(x, axis=1, keepdims=True) for l, a, x in zip(l_prev, alpha, p)]
+        pv = [_dot_tile(x, v_ref[h], 0) for x, h in zip(p, heads)]
+        out += [(m, l, acc * a + y) for m, l, acc, a, y in zip(m_new, l_new, acc_prev, alpha, pv)]
+    return tuple(out)
+
+
 def _paged_group_kernel(
     li_ref, bt_ref, lo_ref, end_ref, live_from_ref, *rest,
     scale, n_kv, P, has_sink, q_dtype, writes=False,
@@ -604,21 +652,10 @@ def _paged_group_kernel(
                     into=(slot, first),
                 )
 
-        # (1, G) at one query token, (R, G) laid out per query row otherwise
-        row_mask = jnp.broadcast_to(mask_ref[0, g] > 0, (R, k_buf.shape[2]))
-        out = []
-        for h in range(n_kv):
-            m_prev, l_prev, acc_prev = carry[h]
-            q = q_ref[0, h].astype(q_dtype)  # (R, D): bfloat16 where q came so
-            s = _dot_tile(q, k_buf[slot, h], 1) * scale  # (R, G)
-            s = jnp.where(row_mask, s, NEG_INF)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(row_mask, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc_prev * alpha + _dot_tile(p, v_buf[slot, h], 0)
-            out.append((m_new, l_new, acc))
-        return tuple(out)
+        return _attend_group(
+            q_ref.at[0], mask_ref[0, g] > 0, k_buf.at[slot], v_buf.at[slot], carry,
+            scale=scale, q_dtype=q_dtype,
+        )
 
     D = q_ref.shape[3]
     init = tuple(

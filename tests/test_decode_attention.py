@@ -93,7 +93,10 @@ def test_tkg_contiguous_windowed_mask():
 # the case's own group width (0: a row with no live block). bs = 16: 2 heads
 # of 128 give P = 32 (the token limit), 8 heads P = 16 in float32 (the byte
 # limit), a table of 8 entries P = 8 = MB; at head_dim 64 blocks come one a
-# grid step.
+# grid step. ``window``: a sliding-window mask, so a row's first live group
+# need not be group 0. The ``tile_*`` cases put a context inside the first 128
+# tokens (the score tile's lanes) of a group of 256 or 512, at a tile's edge
+# and one token past it, at 2 / 8 / 16 KV heads.
 _E = 16  # a block's tokens
 PAGED_CASES = {
     # the two cases this test had (K 1 and 4, contexts that end inside a block)
@@ -117,6 +120,19 @@ PAGED_CASES = {
     "d128_bf16_q_bf16": dict(D=128, MB=64, dtype="bfloat16", q_dtype="bfloat16", valid=[(1, 1, 4), 0, 77]),
     "d128_int8": dict(D=128, MB=64, dtype="int8", valid=[(1, 1, 4), 0, 77]),
     "d128_int8_8kv_sink": dict(D=128, HKV=8, HQ=16, dtype="int8", sink=True, valid=[6 * _E - 5, 0]),
+    "d128_tile_inside_the_first": dict(D=128, MB=64, valid=[100, 5, 127]),
+    "d128_tile_edge": dict(D=128, MB=64, valid=[128, 256, 384, (1, 0, 0), (1, 0, 128)]),
+    "d128_tile_edge_plus_one": dict(D=128, MB=64, valid=[129, 257, (1, 0, 129), (1, 0, 1)]),
+    "d128_first_live_group_not_0": dict(
+        D=128, MB=64, window=150, valid=[(1, 0, 200), (1, 0, 1), 40, (1, 0, 385)]),
+    "d128_tile_k4_sink": dict(D=128, MB=64, K=4, sink=True, valid=[130, 128, (1, 0, 131)]),
+    "d128_tile_8kv": dict(D=128, HKV=8, HQ=16, MB=32, valid=[129, 128, 1, (1, 0, 129)]),
+    "d128_tile_16kv_bf16": dict(D=128, HKV=16, HQ=16, MB=32, dtype="bfloat16", valid=[129, 255, 256, 0, 3]),
+    "d128_tile_int8": dict(D=128, MB=64, dtype="int8", valid=[129, 128, (1, 0, 1)]),
+    # 32 query rows a KV head over groups of 512 tokens: 8 heads' scores are
+    # more than SCORE_VREGS holds, so they are attended in two chunks of 4
+    "d128_8kv_k4_heads_in_two_chunks": dict(
+        D=128, HKV=8, HQ=64, K=4, MB=64, dtype="bfloat16", valid=[(1, 0, 5), 130, 0]),
     "d64_8kv_sink": dict(HKV=8, HQ=16, sink=True, valid=[6 * _E - 5, 0, 8 * _E]),
     "d64_bf16_dead_row": dict(dtype="bfloat16", valid=[0, 5 * _E]),
     "d64_int8_k4": dict(dtype="int8", K=4, valid=[6 * _E - 5, 3 * _E - 9]),
@@ -135,7 +151,7 @@ def test_tkg_paged_parity(case):
     from neuronx_distributed_inference_tpu.modules.kvcache import QuantizedKV
     from neuronx_distributed_inference_tpu.ops.decode_attention import pages_per_step
 
-    c = dict(K=1, sink=False, HQ=HQ, HKV=HKV, D=D, MB=8, dtype="float32", q_dtype="float32")
+    c = dict(K=1, sink=False, HQ=HQ, HKV=HKV, D=D, MB=8, dtype="float32", q_dtype="float32", window=None)
     c.update(PAGED_CASES[case])
     K, hq, hkv, d, MB = c["K"], c["HQ"], c["HKV"], c["D"], c["MB"]
     bs, layer = _E, 2
@@ -143,6 +159,10 @@ def test_tkg_paged_parity(case):
     limit = 2**20 // (hkv * bs * d * jnp.dtype(c["dtype"]).itemsize)  # 1 MiB a stream
     most = min(32, limit, MB)
     assert P == (1 if d == 64 else 1 << (most.bit_length() - 1))
+    if case.endswith("heads_in_two_chunks"):
+        from neuronx_distributed_inference_tpu.ops.decode_attention import SCORE_VREGS
+
+        assert SCORE_VREGS // (hq // hkv * K // 8 * (P * bs // 128)) == hkv // 2
     valid = [
         v if isinstance(v, int) else v[0] * P * bs + v[1] * bs + v[2] for v in c["valid"]
     ]
@@ -168,7 +188,10 @@ def test_tkg_paged_parity(case):
         n = -(-v // bs)
         bt[b, :n] = [next(pages) for _ in range(n)]
     block_table = jnp.asarray(bt)
-    mask, _ = _decode_mask(rng, B, K, MB * bs, valid)
+    mask, pos = _decode_mask(rng, B, K, MB * bs, valid)
+    if c["window"]:
+        cols = jnp.arange(MB * bs)[None, None, None, :]
+        mask = mask & (cols > jnp.asarray(pos)[:, None, :, None] - c["window"])
     sink_w = _rand(rng, hq) if c["sink"] else None
 
     spec = AttnSpec(num_heads=hq, num_kv_heads=hkv, head_dim=d, has_sink=c["sink"])
@@ -328,6 +351,50 @@ def test_decode_kv_block_counter_reads_what_the_host_knows(head_dim):
     assert got["live"] == 3 * 8 + 4 * 1 + 1 * 4  # 41-48 tokens are 3 blocks, 49 is 4; 7-10 are 1
 
 
+@pytest.mark.parametrize("shape", ["8kv_r8", "16kv_r8_bf16", "8kv_r32", "2kv_r16_int8_codes"])
+def test_a_group_is_attended_stage_by_stage_as_head_by_head(shape, monkeypatch):
+    """``_attend_group`` writes the KV heads' online-softmax updates stage by
+    stage across as many heads as ``SCORE_VREGS`` admits at once (all 8 of the
+    1.7B's over 512 tokens, 4 of 8 at 32 query rows). The values are those
+    of the update written head by head (``SCORE_VREGS`` = 1, the order the
+    kernel had), bit for bit, and both are the plain softmax's."""
+    from neuronx_distributed_inference_tpu.ops import decode_attention as da
+
+    n_kv, R, G, dtype, at_once = {
+        "8kv_r8": (8, 8, 512, jnp.float32, 8),
+        "16kv_r8_bf16": (16, 8, 256, jnp.bfloat16, 16),
+        "8kv_r32": (8, 32, 512, jnp.float32, 4),
+        "2kv_r16_int8_codes": (2, 16, 512, jnp.int8, 2),
+    }[shape]
+    assert min(n_kv, max(1, da.SCORE_VREGS // ((R // 8) * (G // 128)))) == at_once
+    rng = np.random.RandomState(n_kv)
+    q = _rand(rng, n_kv, R, 128)
+    if dtype == jnp.int8:
+        k, v = (jnp.asarray(rng.randint(-127, 128, size=(n_kv, G, 128)), jnp.int8) for _ in range(2))
+    else:
+        k, v = (_rand(rng, n_kv, G, 128).astype(dtype) for _ in range(2))
+    mask = jnp.asarray(rng.rand(R, G) < 0.7).at[:, 0].set(True)
+    # a carry as a second group meets it: statistics of an earlier group
+    carry = tuple(
+        (_rand(rng, R, 1), jnp.abs(_rand(rng, R, 1)) + 1.0, _rand(rng, R, 128)) for _ in range(n_kv)
+    )
+    kw = dict(scale=128**-0.5, q_dtype=jnp.float32)
+    staged = da._attend_group(q, mask, k, v, carry, **kw)
+    monkeypatch.setattr(da, "SCORE_VREGS", 1)
+    by_head = da._attend_group(q, mask, k, v, carry, **kw)
+    for got, want in zip(jax.tree_util.tree_leaves(staged), jax.tree_util.tree_leaves(by_head)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and from an empty carry the update is the masked softmax itself
+    empty = tuple(
+        (jnp.full((R, 1), da.NEG_INF), jnp.zeros((R, 1)), jnp.zeros((R, 128))) for _ in range(n_kv)
+    )
+    for h, (m, l, acc) in enumerate(da._attend_group(q, mask, k, v, empty, **kw)):
+        s = jnp.where(mask, q[h] @ k[h].astype(jnp.float32).T * kw["scale"], -jnp.inf)
+        want = jax.nn.softmax(s, axis=-1) @ v[h].astype(jnp.float32)
+        tol = 2e-3 if dtype == jnp.int8 else 2e-5
+        np.testing.assert_allclose(np.asarray(acc / l), np.asarray(want), atol=tol, rtol=tol)
+
+
 # the in-kernel KV write: ONE new token a row, placed by the paged decode
 # kernel in the block it holds for the row anyway (block_kvcache.write_form).
 # ``valid`` = context tokens a row WITH the new token in (so the token sits at
@@ -348,6 +415,12 @@ FUSED_WRITE_CASES = {
     "table_no_multiple_of_p": dict(MB=12, valid=[12 * _W, 9 * _W - 2, 3]),
     "second_and_third_group": dict(MB=24, valid=[8 * _W + 1, 17 * _W + 16, 24 * _W, 5]),
     "sink": dict(sink=True, valid=[2 * _W + 3, 0, _W], slots=["tok", -1, "tok"]),
+    # a context that ends inside a group's first 128 tokens, at their edge,
+    # and one token past it (the new token opens a score tile AND a block)
+    "tile_edges": dict(valid=[100, 128, 129, 256]),
+    "tile_edges_in_the_second_group": dict(MB=24, valid=[16 * _W + 129, 16 * _W + 128, 16 * _W + 1, 129]),
+    "tile_edges_8kv": dict(HKV=8, HQ=16, valid=[129, 128, 3]),
+    "tile_edges_16kv_bf16": dict(HKV=16, HQ=16, dtype="bfloat16", valid=[129, 128, 256, 0], slots=["tok", "tok", "tok", -1]),
 }
 
 
