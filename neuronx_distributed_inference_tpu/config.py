@@ -1009,6 +1009,32 @@ def validate_latent_attention(tc: "TpuConfig") -> None:
             raise LatentAttentionError(f"a model with latent attention (MLA) cannot run with {why}")
 
 
+class SparseAttentionError(LatentAttentionError):
+    """An option that cannot run a model whose latent attention attends the
+    keys a learned indexer selects (models/glm_moe_dsa.py) was set for one."""
+
+
+def validate_sparse_attention(tc: "TpuConfig") -> None:
+    """Refuse, for a model whose layers keep an indexer's key beside the
+    latent, what the selection does not do yet and no test holds to a
+    reference. It is called after :func:`validate_latent_attention`, whose
+    refusals on the paged path (prefix caching: a chosen block is held to
+    no reference; speculation widths; the ragged step; a quantised stream,
+    the index key's included; every degree > 1) stand for it too."""
+    refusals = (
+        (not tc.is_block_kv_layout,
+         "the contiguous cache (generate() without is_block_kv_layout): it keeps two "
+         "streams a token and no indexer key"),
+        (tc.is_prefill_stage,
+         "is_prefill_stage: the hand-off carries two streams a token"),
+    )
+    for flag, why in refusals:
+        if flag:
+            raise SparseAttentionError(
+                f"a model with learned sparse attention (an indexer's top-k) cannot run with {why}"
+            )
+
+
 class BlockStepServingError(NotImplementedError):
     """An option that cannot serve a model whose decode step fills a block of
     positions (models/sdar.py) was set for one, or its block does not fit

@@ -52,16 +52,23 @@ from neuronx_distributed_inference_tpu.models.base import (
 )
 from neuronx_distributed_inference_tpu.models.builder import DecoderModelBuilder
 from neuronx_distributed_inference_tpu.models.registry import register_model
+from neuronx_distributed_inference_tpu.modules import sparse_index
 from neuronx_distributed_inference_tpu.modules.block_kvcache import (
     CacheStream,
     update_latent_cache_at_layer,
+    update_stream_at_layer,
 )
 from neuronx_distributed_inference_tpu.modules.kvcache import (
     kv_batch_size,
     read_cache_at_layer,
     update_cache_at_layer,
 )
-from neuronx_distributed_inference_tpu.modules.moe import ExpertMlp, MoESpec
+from neuronx_distributed_inference_tpu.modules.moe import (
+    ExpertMlp,
+    MoESpec,
+    held_share,
+    validate_expert_layer,
+)
 from neuronx_distributed_inference_tpu.modules.norm import rms_norm
 from neuronx_distributed_inference_tpu.modules.rope import apply_rope, yarn_mscale
 from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
@@ -132,6 +139,8 @@ def mla_decoder_layer(
     key_valid=None,
     block_inputs=None,
     adapter_ids=None,
+    indexer=None,
+    index_cache=None,
     # prefill flavor hints from run_decoder_layers; this layer's native
     # attention already encodes the flavor in `mask`
     **_flavor_hints,
@@ -142,6 +151,15 @@ def mla_decoder_layer(
     Cache streams: K stream holds the compressed latent ``c`` as a single
     "head" of dim kv_lora_rank; V stream holds the shared rope key ``k_pe``
     (one head of dim qk_rope_head_dim).
+
+    With ``indexer`` (a modules/sparse_index.IndexerSpec; models/
+    glm_moe_dsa.py's stack alone passes one): the layer also projects the
+    indexer's query, key and head weights, writes the key to ``index_cache``
+    (the pool's third stream) with the latent, and attends the keys the
+    indexer selects; it then returns ``(hidden, k_cache, v_cache,
+    index_cache, chosen)``, ``chosen`` the positions each query attended
+    under ``spec.output_choices``, else None. Nothing of this is emitted for
+    a layer without one.
     """
     sa = layer_params["self_attn"]
     residual = hidden
@@ -154,8 +172,8 @@ def mla_decoder_layer(
     with jax.named_scope("layer.qkv"):
         if mla.q_lora_rank:
             q = linear(sa["q_a_proj"], hidden)
-            q = rms_norm(q, sa["q_a_layernorm"]["weight"], mla.rms_eps)
-            q = linear(sa["q_b_proj"], q)
+            q_latent = rms_norm(q, sa["q_a_layernorm"]["weight"], mla.rms_eps)
+            q = linear(sa["q_b_proj"], q_latent)
         else:
             q = linear(sa["q_proj"], hidden)
         q = q.reshape(B, S, H, mla.q_head_dim)
@@ -174,6 +192,18 @@ def mla_decoder_layer(
         # q_nope absorbed into latent space: (B,S,H,d_nope)·(H,d_nope,r) -> (B,S,H,r)
         q_c = jnp.einsum("bshd,hdr->bshr", q_nope, sa["k_absorb"]["weight"].astype(q.dtype))
 
+    chosen = None
+    if indexer is not None:
+        if block_inputs is None:
+            raise NotImplementedError(
+                "learned sparse attention is served on the paged cache only "
+                "(config.validate_sparse_attention refuses the contiguous one)"
+            )
+        with jax.named_scope("layer.indexer"):
+            index = sparse_index.index_projections(
+                sa["indexer"], hidden, q_latent, cos, sin, indexer
+            )
+
     # --- write-then-attend on the latent cache ----------------------------
     if block_inputs is not None:
         slot_mapping, block_table, kv_limit = block_inputs
@@ -181,15 +211,25 @@ def mla_decoder_layer(
             k_cache, v_cache = update_latent_cache_at_layer(
                 k_cache, v_cache, c, k_pe[:, :, 0], layer_idx, slot_mapping
             )
-        with jax.named_scope("layer.attn"):
-            if phase == PHASE_CONTEXT_ENCODING:
-                # a whole prompt: the pass's own latents are its whole context
-                latent = native_latent_attention(q_c, q_pe, c, k_pe[:, :, 0], mask, mla.scale)
-            else:
-                latent = latent_attend(
-                    q_c, q_pe, k_cache, v_cache, layer_idx, mask, block_table, kv_limit,
-                    positions, scale=mla.scale, interpret=kernel_interpret(),
-                )
+            if indexer is not None:
+                index_cache = update_stream_at_layer(index_cache, index[1], layer_idx, slot_mapping)
+        if indexer is not None:
+            latent, chosen = sparse_index.sparse_latent_attention(
+                indexer, index, q_c, q_pe, c, k_pe[:, :, 0], (k_cache, v_cache, index_cache),
+                layer_idx, mask, block_table, kv_limit, positions,
+                whole_prompt=phase == PHASE_CONTEXT_ENCODING, scale=mla.scale,
+                want_positions=spec.output_choices,
+            )
+        else:
+            with jax.named_scope("layer.attn"):
+                if phase == PHASE_CONTEXT_ENCODING:
+                    # a whole prompt: the pass's own latents are its whole context
+                    latent = native_latent_attention(q_c, q_pe, c, k_pe[:, :, 0], mask, mla.scale)
+                else:
+                    latent = latent_attend(
+                        q_c, q_pe, k_cache, v_cache, layer_idx, mask, block_table, kv_limit,
+                        positions, scale=mla.scale, interpret=kernel_interpret(),
+                    )
     else:
         with jax.named_scope("layer.kv_write"):
             k_cache, v_cache = update_cache_at_layer(
@@ -215,6 +255,8 @@ def mla_decoder_layer(
         hidden = rms_norm(hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps)
     with jax.named_scope("layer.mlp"):
         hidden = residual + mlp_fn(layer_params["mlp"], hidden, spec)
+    if indexer is not None:
+        return hidden, k_cache, v_cache, index_cache, chosen
     return hidden, k_cache, v_cache
 
 
@@ -230,6 +272,17 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
         # pad q heads to the model-parallel degree (MLA has no GQA groups)
         self.q_heads = math.ceil(cfg.num_attention_heads / self.degree) * self.degree
         self.first_dense = getattr(cfg, "first_k_dense_replace", 0)
+        #: the router's width and the first expert held here: the published
+        #: count and 0 unless the configuration states a held share
+        #: (modules/moe.held_share; ``num_experts`` is then the count held)
+        self.published_experts, self.first_expert = (
+            held_share(cfg) if hasattr(cfg, "n_routed_experts") else (None, 0)
+        )
+        if self.first_dense < cfg.num_hidden_layers and self.published_experts is not None:
+            validate_expert_layer(
+                self.moe_spec(), self._moe_mlp_shapes(1)["experts"],
+                quantized=bool(cfg.tpu_config.quantized),
+            )
 
     @property
     def num_experts(self) -> int:
@@ -257,7 +310,7 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
         cfg = self.config
         tc = cfg.tpu_config
         return MoESpec(
-            num_experts=self.num_experts,
+            num_experts=self.published_experts,
             top_k=getattr(cfg, "num_experts_per_tok", 8),
             normalize_top_k_affinities=bool(getattr(cfg, "norm_topk_prob", True)),
             router_dtype=getattr(tc, "router_dtype", "float32"),
@@ -269,6 +322,8 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
             ep_degree=tc.ep_degree,
             hybrid_cte_full_tp=bool(getattr(tc, "hybrid_sharding_config", None)),
             model_parallel=self.degree,
+            held_experts=self.num_experts if self.num_experts < self.published_experts else None,
+            first_expert=self.first_expert,
         )
 
     def model_spec(self):
@@ -407,9 +462,10 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
         E = self.num_experts
         I = getattr(cfg, "moe_intermediate_size")
         shapes = {
+            # the router keeps its published width whatever share is held
             "router": {
-                "weight": (L, H, E),
-                "e_score_correction_bias": (L, E),
+                "weight": (L, H, self.published_experts),
+                "e_score_correction_bias": (L, self.published_experts),
             },
             "experts": {
                 "gate_proj": {"weight": (L, E, H, I)},
@@ -634,7 +690,7 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
 
         def mlp_moe(i):
             p = f"model.layers.{i}.mlp."
-            E = self.num_experts
+            held = range(self.first_expert, self.first_expert + self.num_experts)
             out = {
                 "router": {
                     "weight": lt(p + "gate.weight"),
@@ -643,17 +699,17 @@ class DeepseekV3ModelBuilder(DecoderModelBuilder):
                 "experts": {
                     "gate_proj": {
                         "weight": np.stack(
-                            [lt(p + f"experts.{e}.gate_proj.weight") for e in range(E)]
+                            [lt(p + f"experts.{e}.gate_proj.weight") for e in held]
                         )
                     },
                     "up_proj": {
                         "weight": np.stack(
-                            [lt(p + f"experts.{e}.up_proj.weight") for e in range(E)]
+                            [lt(p + f"experts.{e}.up_proj.weight") for e in held]
                         )
                     },
                     "down_proj": {
                         "weight": np.stack(
-                            [lt(p + f"experts.{e}.down_proj.weight") for e in range(E)]
+                            [lt(p + f"experts.{e}.down_proj.weight") for e in held]
                         )
                     },
                 },

@@ -77,6 +77,7 @@ from neuronx_distributed_inference_tpu.modules.block_kvcache import PAGED_KV, SL
 from neuronx_distributed_inference_tpu.modules.moe import (
     ExpertMlp,
     MoESpec,
+    held_share,
     shared_expert_mlp,
     validate_expert_layer,
 )
@@ -99,14 +100,8 @@ class NemotronHInferenceConfig(InferenceConfig):
         super().add_derived_config()
         self.rms_norm_eps = getattr(self, "layer_norm_epsilon", getattr(self, "norm_eps", 1e-5))
         self.hidden_act = getattr(self, "mlp_hidden_act", "relu2")
-        first, of = self._share()
         #: the router's width, and the first expert held here
-        self.published_experts = self.n_routed_experts * of
-        self.first_expert = first * self.n_routed_experts
-
-    def _share(self):
-        share = getattr(self, "expert_share", None) or {"first": 0, "of": 1}
-        return int(share["first"]), int(share["of"])
+        self.published_experts, self.first_expert = held_share(self)
 
     def validate_config(self):
         super().validate_config()
@@ -135,13 +130,6 @@ class NemotronHInferenceConfig(InferenceConfig):
         for flag, what in unwritten:
             if flag:
                 raise NotImplementedError(f"nemotron_h with {what} is not implemented")
-        first, of = self._share()
-        said = getattr(self, "n_routed_experts_published", self.published_experts)
-        if not 0 <= first < of or said != self.published_experts:
-            raise ValueError(
-                f"expert_share {first} of {of}, n_routed_experts={self.n_routed_experts} held: rank "
-                f"'first' of 'of' equal shares of {said} published experts"
-            )
         if "M" in pattern:
             validate_slot_state_serving(self.tpu_config)
 

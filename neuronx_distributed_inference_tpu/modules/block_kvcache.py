@@ -96,10 +96,15 @@ def prefix_chain_keys(tokens: np.ndarray, block_size: int) -> List[bytes]:
 @dataclass
 class BlockKVCache:
     """k/v: (L, num_blocks+1, H_kv, block_size, D) — head-major blocks
-    (arrays, or :class:`~.kvcache.QuantizedKV` streams of the same layout)."""
+    (arrays, or :class:`~.kvcache.QuantizedKV` streams of the same layout).
+    ``extra``: the pool streams a builder declares beyond two
+    (``builder.cache_streams()``: an indexer's key beside an MLA layer's latent
+    and rotary key), addressed by the same block ids; empty for every model
+    of two streams, whose cache then has the leaves it always had."""
 
     k: jax.Array
     v: jax.Array
+    extra: Tuple[jax.Array, ...] = ()
 
     @property
     def num_layers(self):
@@ -180,12 +185,13 @@ def init_block_cache(
     num_kv_heads: int = None,
     head_dim: int = None,
     dtype=jnp.bfloat16,
-    streams: Optional[Tuple[CacheStream, CacheStream]] = None,
+    streams: Optional[Tuple[CacheStream, ...]] = None,
 ) -> BlockKVCache:
     """The pool over ``num_layers``: K and V at ``(num_kv_heads, head_dim)``,
-    or the two ``streams`` a builder declares (an MLA layer: the compressed
-    latent in ``k``, the rotary key, packed, in ``v``). A quantised pool keeps
-    a float32 scale a (layer, head) beside each stream's codes."""
+    or the ``streams`` a builder declares (an MLA layer: the compressed
+    latent in ``k``, the rotary key, packed, in ``v``; any further stream in
+    ``extra``). A quantised pool keeps a float32 scale a (layer, head) beside
+    each stream's codes."""
     streams = streams or kv_streams(num_kv_heads, head_dim)
 
     def stream(s: CacheStream):
@@ -194,7 +200,9 @@ def init_block_cache(
             return QuantizedKV(data=data, scale=jnp.zeros((num_layers, s.heads), jnp.float32))
         return data
 
-    return BlockKVCache(k=stream(streams[0]), v=stream(streams[1]))
+    return BlockKVCache(
+        k=stream(streams[0]), v=stream(streams[1]), extra=tuple(stream(s) for s in streams[2:])
+    )
 
 
 def kv_block_bytes(
@@ -217,7 +225,7 @@ def block_cache_spec(quantized: bool = False, streams=None):
     if streams is not None and all(s.heads == 1 for s in streams):
         # one "head" shared by every q head (an MLA layer's latent and rotary
         # key): replicated over the model axes, as the q heads shard
-        return BlockKVCache(k=P(), v=P())
+        return BlockKVCache(k=P(), v=P(), extra=(P(),) * (len(streams) - 2))
     spec = P(None, None, MODEL_AXES, None, None)
     if quantized:
         stream = QuantizedKV(data=spec, scale=P(None, MODEL_AXES))
@@ -578,6 +586,28 @@ def update_latent_cache_at_layer(
             kr_cache, kr_new[:, :, None, :]
         )
     return c_cache, _write_packed(kr_cache, kr_new, layer_idx, slot_mapping, kr_new.shape[-1])
+
+
+def update_stream_at_layer(
+    data: jax.Array,  # (L, NB+1, 1, bs, w): a one-"head" stream on whole lanes
+    new: jax.Array,  # (B, S, w)
+    layer_idx: jax.Array,
+    slot_mapping: jax.Array,  # (B, S)
+) -> jax.Array:
+    """One more unpacked stream of a token (``BlockKVCache.extra``: an
+    indexer's key), written in the form the latent beside it takes."""
+    return _stream_writer(data.shape, slot_mapping, layer_idx)(data, new[:, :, None, :])
+
+
+def read_stream_at_layer(data: jax.Array, layer_idx: jax.Array, block_table: jax.Array) -> jax.Array:
+    """One layer of an unpacked one-"head" stream, gathered by the table
+    into per-row views in token order ``(B, MB * bs, w)``; garbage-block
+    reads are zeroed, as :func:`read_latent_cache_at_layer`'s."""
+    B, MB = block_table.shape
+    rows = jax.lax.dynamic_index_in_dim(data, layer_idx, axis=0, keepdims=False)[block_table]
+    valid = (block_table != GARBAGE_BLOCK)[:, :, None, None]
+    rows = jnp.where(valid, rows[:, :, 0], jnp.zeros((), rows.dtype))
+    return rows.reshape(B, MB * data.shape[3], data.shape[4])
 
 
 def read_latent_cache_at_layer(

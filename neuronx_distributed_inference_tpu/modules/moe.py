@@ -95,6 +95,25 @@ class MoESpec:
         return self.held < self.num_experts
 
 
+def held_share(config) -> Tuple[int, int]:
+    """``(published experts, first expert held)`` of a model configuration
+    that may state a held share beside its published keys: ``expert_share =
+    {"first": r, "of": n}`` makes ``n_routed_experts`` the count HELD here,
+    rank ``r`` of ``n`` equal shares of the published ``n_routed_experts *
+    n`` (``n_routed_experts_published``, if given, must say the same). No
+    ``expert_share``: every expert is held."""
+    share = getattr(config, "expert_share", None) or {"first": 0, "of": 1}
+    first, of = int(share["first"]), int(share["of"])
+    published = config.n_routed_experts * of
+    said = getattr(config, "n_routed_experts_published", published)
+    if not 0 <= first < of or said != published:
+        raise ValueError(
+            f"expert_share {first} of {of}, n_routed_experts={config.n_routed_experts} held: rank "
+            f"'first' of 'of' equal shares of {said} published experts"
+        )
+    return published, first * config.n_routed_experts
+
+
 class ExpertLayerError(NotImplementedError):
     """An option that would run an expert layer wrongly rather than not at
     all was set for it (:func:`validate_expert_layer`)."""

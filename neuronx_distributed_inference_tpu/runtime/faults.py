@@ -425,6 +425,8 @@ def fill_kv_rows(cache, row_ids: np.ndarray, value: float):
     idx = np.asarray(row_ids, np.int32)
 
     def fill(stream):
+        if isinstance(stream, tuple):  # BlockKVCache.extra: further streams of blocks
+            return tuple(fill(s) for s in stream)
         if isinstance(stream, QuantizedKV):
             if value != 0:
                 raise ValueError(

@@ -242,6 +242,7 @@ class ServingSession:
         self.allocator = None
         self.block_bytes = 0
         self.latent_layers = 0
+        self.sparse_layers = self.sparse_topk = 0
         if self.block_mode:
             from neuronx_distributed_inference_tpu.modules.block_kvcache import (
                 BlockAllocator,
@@ -275,6 +276,10 @@ class ServingSession:
             )
             # layers whose pool stream is a compressed latent (nxdi_latent_*)
             self.latent_layers = paged_layers if streams[0].name == "latent" else 0
+            # layers that keep an indexer's key and attend its top-k
+            # (nxdi_sparse_*): the builder's own to say
+            if any(s.name == "index_key" for s in streams):
+                self.sparse_layers, self.sparse_topk = paged_layers, app.builder.indexer_spec().topk
             # what the paged kernels attend for a row (host-known: it follows
             # from the pool's shape a head shard, as the kernels' does): the
             # decode kernel's walk and the prefill kernel's
@@ -1304,7 +1309,8 @@ class ServingSession:
             self._count_pass(
                 "chunk", (R, qb), len(ran), ran_real, len(flights),
                 resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
-                kv_blocks=kv_blocks,
+                kv_blocks=kv_blocks, kv_width=width,
+                spans=[(r.prefill_pos, n) for r, n in ran] if self.sparse_layers else (),
             )
             for req, n in ran:
                 self._note_prefill(req, n)
@@ -1982,7 +1988,8 @@ class ServingSession:
         if self.block_mode and tel.enabled:
             tel.kv_write_rows(self._decode_write_form(K, width), len(rows))
         self._count_pass("decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
-                         block_rows=block_rows)
+                         block_rows=block_rows, kv_width=width,
+                         spans=[(p, K) for _, p in rows] if self.sparse_layers else ())
         tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
         snap = [(r, p, r.slot, r.epoch) for r, p in rows]
         if self.blocks is not None:
@@ -2031,14 +2038,17 @@ class ServingSession:
         return out.tokens[:, -1:] if self.blocks is None else out.next_ids
 
     def _count_pass(self, program: str, shape, rows: int, tokens: int, dispatches: int,
-                    resets: int = 0, kv_blocks=None, block_rows=None) -> None:
+                    resets: int = 0, kv_blocks=None, block_rows=None, kv_width: int = 0,
+                    spans=()) -> None:
         """What a pass of the split serving step ("decode" or "chunk") did
         to per-slot state and routed experts, from what the step already
         knows: the program's ``shape`` (rows, positions a row), ``rows`` live
         rows over ``tokens`` real token positions in ``dispatches``
         dispatches, ``resets`` of the rows from position 0;
         ``kv_blocks``: the pass's (live, walked) pool blocks;
-        ``block_rows``: a block step's (denoise, commit) rows."""
+        ``block_rows``: a block step's (denoise, commit) rows; ``kv_width``
+        the pass's kv bucket and ``spans`` its rows' (first position, real
+        positions): what a selection of keys is counted from."""
         if kv_blocks is not None:
             self.tel.kv_blocks(program, *kv_blocks)
         if block_rows is not None:
@@ -2049,6 +2059,19 @@ class ServingSession:
             self.tel.carry_pass(program, rows)
         if self.latent_layers:
             self.tel.latent_pass(program, tokens * self.latent_layers)
+        if self.sparse_layers and self.tel.enabled:
+            # query t of a row has t + 1 live keys and attends min(t + 1,
+            # index_topk); at a kv width of no more than index_topk the
+            # program scores nothing (every live key is chosen)
+            k, scored, attended = self.sparse_topk, 0, 0
+            for first, n in spans:
+                live = np.arange(first + 1, first + n + 1, dtype=np.int64)
+                scored += int(live.sum()) if kv_width > k else 0
+                attended += int(np.minimum(live, k).sum())
+            self.tel.sparse_pass(
+                program, tokens * self.sparse_layers, scored * self.sparse_layers,
+                attended * self.sparse_layers,
+            )
         if self.loop_layer_passes:
             self.tel.loop_pass(program, dispatches, self.loop_layer_passes)
         if self.expert_layers is not None:

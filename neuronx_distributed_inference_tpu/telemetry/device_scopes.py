@@ -34,6 +34,8 @@ DEVICE_SCOPES = (
     "layer.qkv",
     "layer.latent_proj",
     "layer.absorb",
+    "layer.indexer",
+    "layer.select",
     "layer.kv_write",
     "layer.attn",
     "layer.o_proj",

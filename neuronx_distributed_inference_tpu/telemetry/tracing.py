@@ -393,6 +393,22 @@ class TelemetrySession:
             "leaves in an MLA layer) a pass of the split serving step wrote to "
             "the pool: real token positions x latent-attention layers",
             labels=("program",))
+        self._index_keys = r.counter(
+            "nxdi_index_keys_written_total",
+            "indexer keys (what a token leaves in the pool's third stream in "
+            "a layer of learned sparse attention) the split serving step "
+            "wrote: real token positions x layers")
+        self._sparse_scored = r.counter(
+            "nxdi_sparse_keys_scored_total",
+            "live keys an indexer scored: over the rows, layers and queries "
+            "of a pass, the keys at or before the query (0 in a program whose "
+            "kv width is no more than index_topk: every live key is chosen)",
+            labels=("program",))
+        self._sparse_attended = r.counter(
+            "nxdi_sparse_keys_attended_total",
+            "keys attended after the selection: over the rows, layers and "
+            "queries of a pass, min(live keys, index_topk)",
+            labels=("program",))
         self._moe_rows = r.counter(
             "nxdi_moe_rows_routed_total",
             "token rows the split serving step routed to an expert: real "
@@ -1227,6 +1243,16 @@ class TelemetrySession:
         if not self.enabled:
             return
         self._latent_tokens.child((program,)).inc(latents)
+
+    def sparse_pass(self, program: str, written: int, scored: int, attended: int) -> None:
+        """One pass of the split serving step over layers of learned sparse
+        attention: the indexer keys it wrote, the live keys its indexers
+        scored and the keys its attention read after the selection."""
+        if not self.enabled:
+            return
+        self._index_keys.inc(written)
+        self._sparse_scored.child((program,)).inc(scored)
+        self._sparse_attended.child((program,)).inc(attended)
 
     def loop_pass(self, program: str, dispatches: int, streams: int) -> None:
         """One pass of the split serving step over a looped stack of

@@ -888,3 +888,56 @@ def test_ouro_serving_step_loops_one_layer_body_over_the_pool_in_place(chip_mesh
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# glm-5: a pool of three streams a token, an indexer's top-2048 before latent attention
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, program, monkeypatch):
+    """glm-5 at the benchmark's widths (benchmark/configs/glm-5.json: 64
+    heads, 32 index heads, 16 of 256 experts held, an eighth of the
+    vocabulary, 1 dense + 4 expert layers, 32 slots, 16768 blocks), both step
+    programs compiled for a described v5e at the widest kv bucket, 16896:
+    the pool is three streams (latent 512, rotary key packed two tokens a
+    128-lane row, indexer key 128: 1408 B a token a layer, no lane of
+    padding); past ``index_topk`` neither program attends every live token
+    (the decode program runs the latent decode kernel under the selection's
+    predicate, the chunk program walks the row under it, row by row), the
+    three scopes of the mechanism hold work, no program copies a stream of
+    the pool, and each plans under 14.75 GiB of the chip's 15.75. At a kv
+    bucket of ``index_topk`` the same programs hold the dense latent kernels
+    and no indexer score."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode, latent_attention
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    # the gates ask jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
+    app, params, cache = _abstract_paged_app(chip_mesh(1), "glm-5")
+    assert cache.k.shape == (5, 16769, 1, 32, 512) and cache.v.shape == (5, 16769, 1, 16, 128)
+    assert [x.shape for x in cache.extra] == [(5, 16769, 1, 32, 128)]
+    assert (cache.k.size + cache.v.size + cache.extra[0].size) * 2 == 5 * 16769 * 32 * 1408
+    tkg = app.token_generation_model
+    q = 128 if program == "chunk" else None
+    inputs = tkg.example_inputs(16896, q_len=q)
+    assert inputs.input_ids.shape == ((32, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    assert "paged_latent_flash_attention" not in text
+    scopes = set(device_scopes.scope_table(text)["ops"].values())
+    assert {"layer.indexer", "layer.select", "layer.attn", "layer.kv_write"} <= scopes
+    for stream in (cache.k, cache.v, cache.extra[0]):
+        assert _pool_copies(compiled, stream.shape)[0] == 0
+    mem = compiled.memory_analysis()
+    print(f"\nglm-5 {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
+    # at index_topk the selection is everything: dense latent attention, as it was
+    dense = _compile_step(app, tkg, tkg.example_inputs(2048, q_len=q), params, cache).as_text()
+    kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
+    assert kernel in dense
+    assert "layer.select" not in set(device_scopes.scope_table(dense)["ops"].values())
