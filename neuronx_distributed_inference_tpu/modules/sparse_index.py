@@ -29,9 +29,13 @@ named scope in the layer (``layer.indexer``: projections and scores,
   ``lax.top_k`` breaks them; :func:`chosen_positions` turns the predicate
   into positions in the row (``-1`` padded), for the step's
   ``output_choices``;
-* :func:`attend_selected`: the dense walk, every live latent of the row
-  under the predicate: the same sum, nothing left out
-  (:func:`sparse_latent_attention` says which program attends how).
+* the attention over the picked keys: on the serving path the latent
+  kernels with the predicate (``ops/latent_attention.latent_attend``: the
+  decode program's through the decode kernel with the selection as its
+  mask, a chunk pass's inside the chunk kernel over the row's live block
+  groups); :func:`attend_selected`, the dense walk of every gathered latent
+  under the predicate, for what no kernel serves (a whole prompt, the CPU).
+  Either way the same sum over the picked keys, nothing left out.
 
 Invalid rows and padded positions have no live key: they choose nothing
 (their attention output is finite and unread) and write nothing (their slot
@@ -179,7 +183,10 @@ def chosen_positions(chosen: jax.Array, k: int) -> jax.Array:
 def attend_selected(q_c, q_pe, c_all, kr_all, chosen, scale, row_live=None):
     """The dense walk under the predicate: absorbed attention of ``q_c (B,
     S, H, r)`` / ``q_pe`` over every latent of the row ``c_all (B, W, r)`` /
-    ``kr_all (B, W, d_rope)`` with ``chosen (B, S, W)`` as the mask."""
+    ``kr_all (B, W, d_rope)`` with ``chosen (B, S, W)`` as the mask, float32
+    scores of the whole width. What attends where no kernel does: a
+    whole-prompt pass (its keys are the pass's own) and a call
+    ``use_latent_kernel`` refuses (off the chip, a pool off the lanes)."""
     B, S, H, _ = q_c.shape
 
     def row(qc, qp, c, kr, m):
@@ -201,14 +208,19 @@ def sparse_latent_attention(
     selection is every live key and the layer is dense latent attention (the
     existing kernels, unchanged); past it the indexer scores the row's live
     keys (scope ``layer.indexer``), :func:`select` picks (``layer.select``)
-    and the attention (``layer.attn``) runs over the picked alone: the decode
-    program's through the latent decode kernel with the selection as its
-    mask (every live block copied, a key not picked has probability 0), a
-    chunk's and a whole prompt's as :func:`attend_selected`. (The picked
-    latents GATHERED through the block table, then attended, read 3 x
-    slower in both programs on the chip, a sort for the positions and XLA's
-    gather of 1 KB rows: PERF.md, PR 54; a kernel that walks chosen tokens is
-    what both lack.) ``chosen``: under ``want_positions`` the positions each
+    and the attention (``layer.attn``) runs over the picked alone, through
+    ``latent_attend`` with the selection as a predicate, by the rule the
+    dense call follows: the decode program's through the latent decode kernel
+    with the selection as its mask, a chunk pass's inside the latent chunk
+    kernel, which walks the row's LIVE block groups in the pool (every live
+    block copied once a row, a key not picked has probability 0; no gathered
+    copy of the bucket and no float32 score array reach HBM: PERF.md, PR 55);
+    a whole prompt's, and a call the kernels' gate refuses, as
+    :func:`attend_selected`. (The picked latents GATHERED through the block
+    table, then attended, read 3 x slower in both programs on the chip, a
+    sort for the positions and XLA's gather of 1 KB rows: PERF.md, PR 54; a
+    kernel that copies only the blocks that hold a picked key is what both
+    lack.) ``chosen``: under ``want_positions`` the positions each
     query attended ``(B, S, index_topk)``, else None. ``whole_prompt``: a
     context-encoding pass, whose own keys are its whole context."""
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
@@ -218,7 +230,6 @@ def sparse_latent_attention(
     from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
     from neuronx_distributed_inference_tpu.ops.latent_attention import (
         latent_attend,
-        paged_latent_decode_attention,
         use_latent_kernel,
     )
 
@@ -254,10 +265,10 @@ def sparse_latent_attention(
     with jax.named_scope("layer.attn"):
         if whole_prompt:
             latent = attend_selected(q_c, q_pe, c, k_r, picked, scale, row_live)
-        elif S == 1 and use_latent_kernel(c_cache, kr_cache, S, W):
-            latent = paged_latent_decode_attention(
-                q_c, q_pe, c_cache, kr_cache, layer_idx, block_table, picked[:, None],
-                scale=scale, interpret=kernel_interpret(),
+        elif use_latent_kernel(c_cache, kr_cache, S, W):
+            latent = latent_attend(
+                q_c, q_pe, c_cache, kr_cache, layer_idx, mask, block_table, kv_limit,
+                positions, picked, scale=scale, interpret=kernel_interpret(),
             )
         else:
             c_all, kr_all = read_latent_cache_at_layer(c_cache, kr_cache, layer_idx, block_table)

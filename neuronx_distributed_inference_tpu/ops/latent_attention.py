@@ -35,6 +35,17 @@ of ``Q_ROWS`` rows, statistics in VMEM from group to group). Both products
 take the cache tile as it is stored (``decode_attention._dot_tile``).
 :func:`latent_attend` is what the layer calls: a kernel where its gate
 admits the call, else blocks gathered by the table and attended natively.
+
+A SELECTION (learned sparse attention, modules/sparse_index.py: the keys a
+query may attend, a predicate ``(B, Sq, W)``) rides the same kernels, by the
+same rule: the decode kernel takes it as its mask, the chunk kernel as one
+more operand, a row's slab in VMEM in the kernel's column order, made into a
+group's bias tile (0 | -inf) once for all the parts. The walk is the row's
+live groups either way: every live block copied once a row, a key not picked
+given probability 0. Under a selection the chunk kernel's probabilities go
+to the matrix unit in the pool's dtype, as the dense walk it replaces
+rounds them (``native_latent_attention``): one pass over a bf16 pool where
+the float32 probabilities of the dense call take three.
 """
 
 from __future__ import annotations
@@ -133,6 +144,15 @@ def _rope_rows(q_pe: jax.Array, pack: int) -> jax.Array:
          for j in range(pack)],
         axis=-3,
     )
+
+
+def _by_lane_group(x: jax.Array, P: int, pack: int, rows: int) -> jax.Array:
+    """``(B, S, NG * P * bs)`` over a row's tokens -> ``(B, NG, pack, S, P *
+    rows)``: a group's columns as the kernels meet them, lane group ``j``,
+    then block, then row (column ``(p, i)`` is token ``p * bs + j * rows + i``)."""
+    B, S, W = x.shape
+    x = x.reshape(B, S, W // (P * pack * rows), P, pack, rows)
+    return x.transpose(0, 2, 4, 1, 3, 5).reshape(B, -1, pack, S, P * rows)
 
 
 def _row_spec(shape):
@@ -255,8 +275,7 @@ def paged_latent_decode_attention(
     end = jnp.max(jnp.where(live, idx + 1, 0), axis=1)
     lo = jnp.min(jnp.where(live, idx, NG * P - 1), axis=1) // P
     # the mask a lane group at a time: (B, NG, pack, K, P * rows)
-    m = mask.astype(jnp.int32).reshape(B, K, NG, P, pack, rows)
-    m = m.transpose(0, 2, 4, 1, 3, 5).reshape(B, NG, pack, K, P * rows)
+    m = _by_lane_group(mask.astype(jnp.int32), P, pack, rows)
     if K > 1:  # row h * K + t reads mask row t
         m = jnp.pad(jnp.tile(m, (1, 1, 1, Hq, 1)), ((0, 0),) * 3 + ((0, R - rk), (0, 0)))
     qc, qr = q_rows(q_c), _rope_rows(q_rows(q_pe), pack)
@@ -298,9 +317,13 @@ def _chunk_kernel(
     qc_ref,  # (1, NP, R, r): R = hp heads x tq positions, head-major
     qr_ref,  # (1, NP, pack, R, lanes)
     pos_ref,  # (1, nq, tq)
-    c_hbm, kr_hbm, o_ref, c_buf, kr_buf, sems, slot_ref, m_scr, l_scr, acc_scr,
-    *, scale, P, nq, q_dtype,
+    *rest,  # the pools, out, scratch; under a selection between one more operand and scratch
+    scale, P, nq, q_dtype, selected,
 ):
+    if selected:
+        # chosen_ref (1, NG, pack, nq, tq, P * rows) int; bias_scr (nq, pack, tq, P * rows) float32
+        chosen_ref, *rest, bias_scr = rest
+    c_hbm, kr_hbm, o_ref, c_buf, kr_buf, sems, slot_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     B = pl.num_programs(0)
     _, NP, R, r = qc_ref.shape
@@ -318,6 +341,10 @@ def _chunk_kernel(
     def _first():
         slot_ref[0] = 0
         c_buf[...] = jnp.zeros_like(c_buf)  # the latent is the value too
+        if selected:
+            # a masked score meets -inf by a SUM there, which does not hide what
+            # a slot was born with (a NaN) as a select does
+            kr_buf[...] = jnp.zeros_like(kr_buf)
         row = live_from_ref[0]
 
         @pl.when(row < B)
@@ -337,6 +364,15 @@ def _chunk_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def live(kv0, iq, j):
+        """Which of group ``kv0``'s lane group ``j`` a q tile's queries may
+        attend by the kernel's own rule: ``(tq, Gj)``."""
+        q_pos = pos_ref[0, iq][:, None]  # (tq, 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (tq, Gj), 1)
+        # column (p, i) of lane group j is token p * bs + j * rows + i
+        kv_pos = kv0 + col // rows * bs + j * rows + col % rows
+        return (kv_pos <= q_pos) & (kv_pos < lim)
+
     def attend(slot, pi, kv0, iq, masked):
         kr_t, c_ts = _lane_groups(c_buf, kr_buf, slot)
         qc = qc_ref[0, pi].astype(q_dtype)
@@ -345,18 +381,23 @@ def _chunk_kernel(
                 qr_ref[0, pi, j].astype(q_dtype), kr_t, 1
             )
             s = s * scale
-            if masked:
-                q_pos = pos_ref[0, iq][:, None]  # (tq, 1)
-                col = jax.lax.broadcasted_iota(jnp.int32, (tq, Gj), 1)
-                # column (p, i) of lane group j is token p * bs + j * rows + i
-                kv_pos = kv0 + col // rows * bs + j * rows + col % rows
-                mask = (kv_pos <= q_pos) & (kv_pos < lim)
-                s = jnp.where(mask[None], s.reshape(hp, tq, Gj), -jnp.inf).reshape(R, Gj)
+            # -inf under a running maximum that starts at the finite NEG_INF: a
+            # masked score's probability is exp(-inf) = 0, also for a query
+            # that has met no key of its own yet
+            if selected:
+                s = (s.reshape(hp, tq, Gj) + bias_scr[iq, j][None]).reshape(R, Gj)
+            elif masked:
+                s = jnp.where(live(kv0, iq, j)[None], s.reshape(hp, tq, Gj), -jnp.inf).reshape(R, Gj)
             m_prev = m_scr[pi]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_scr[pi] = l_scr[pi] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            if selected:
+                # as the dense walk under a selection does (native_latent_attention):
+                # the probabilities in the latents' dtype, ONE pass of the matrix
+                # unit over a bf16 pool where float32 ones take three
+                p = p.astype(c_ts[j].dtype)
             acc_scr[pi] = acc_scr[pi] * alpha + _da._dot_tile(p, c_ts[j], 0)
             m_scr[pi] = m_new
 
@@ -372,12 +413,28 @@ def _chunk_kernel(
         wait(b, g, slot)
         slot_ref[0] = 1 - slot
         kv0 = g * G
+        if selected:
+            # what the selection leaves of the group, once for all the parts:
+            # 0 where a query attends a key, -inf where it does not
+            def bias(iq, _):
+                for j in range(pack):
+                    keep = live(kv0, iq, j) & (chosen_ref[0, g, j, iq].astype(jnp.int32) > 0)
+                    bias_scr[iq, j] = jnp.where(keep, 0.0, -jnp.inf)
+
+            jax.lax.fori_loop(0, nq, bias, None)
 
         def part(pi, _):
             iq = pi % nq if nq > 1 else 0
             # the group lies under this tile's frontier and the cache's end;
             # and wholly under both: no mask can bite
             run = kv0 <= jnp.minimum(tmax_ref[b, iq], lim - 1)
+            if selected:  # no group is clear of a selection
+
+                @pl.when(run)
+                def _():
+                    attend(slot, pi, kv0, iq, masked=True)
+
+                return
             clear = (kv0 + G - 1 <= tmin_ref[b, iq]) & (kv0 + G <= lim)
 
             @pl.when(run & clear)
@@ -410,6 +467,7 @@ def paged_latent_flash_attention(
     block_table: jax.Array,  # (B, MB)
     positions: jax.Array,  # (B, Sq) query positions
     kv_limit: jax.Array,  # (B,) valid cache length per row
+    chosen: jax.Array = None,  # (B, Sq, MB * bs) bool: the keys a query may attend
     *,
     scale: float,
     tq: int = 128,
@@ -417,8 +475,13 @@ def paged_latent_flash_attention(
 ) -> jax.Array:
     """Attention of a prefill chunk over prior latents plus itself: query
     ``t`` of row ``b`` attends positions ``p <= positions[b, t]`` with ``p <
-    kv_limit[b]`` (the chunk's own latents are already written). Returns the
-    attended latents ``(B, Sq, Hq, r)``."""
+    kv_limit[b]`` (the chunk's own latents are already written), and under a
+    selection (``chosen``: ``sparse_index.select``'s predicate) of those the
+    ones with ``chosen[b, t, p]``: the same walk of the row's live groups,
+    every group in the masked form, the predicate a row's slab in VMEM in the
+    kernel's column order. A query that picks nothing in a group adds nothing
+    there (and reads zeros if it picks nothing at all). Returns the attended
+    latents ``(B, Sq, Hq, r)``."""
     B, Sq, Hq, r = q_c.shape
     bs = c_cache.shape[3]
     rows, lanes = kr_cache.shape[3:]
@@ -454,21 +517,29 @@ def paged_latent_flash_attention(
         + NP * R * (r + 2 * 128) * 4  # accumulators, lane-padded statistics
         + 12 * R * P * rows * 4  # a part's score tile and what is made from it
     )
+    selection = []
+    if chosen is not None:
+        ch = jnp.pad(chosen, ((0, 0), (0, pad_q), (0, NG * P * bs - chosen.shape[-1])))
+        ch = _by_lane_group(ch, P, pack, rows).reshape(B, NG, pack, nq, tq, P * rows)
+        # int8 where a q tile is whole int8 sublane tiles (32 rows)
+        selection = [ch.astype(jnp.int8 if tq % 32 == 0 else jnp.int32)]
+        vmem += 2 * selection[0][0].nbytes + nq * tq * P * bs * 4  # a row's slab, pipelined; a group's bias
     li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
     out = _da._common_call(
         functools.partial(
-            _chunk_kernel, scale=scale, P=P, nq=nq,
+            _chunk_kernel, scale=scale, P=P, nq=nq, selected=bool(selection),
             q_dtype=jnp.bfloat16 if q_c.dtype == jnp.bfloat16 else jnp.float32,
         ),
         grid=(B,),
         in_specs=[
             _row_spec(qc.shape[1:]), _row_spec(qr.shape[1:]), _row_spec((nq, tq)),
+            *[_row_spec(x.shape[1:]) for x in selection],
             pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=_row_spec((NP, R, r)),
         operands=(
             [li, bt, end, _live_from(end), lim, tile_max, tile_min],
-            [qc, qr, pos, c_cache, kr_cache],
+            [qc, qr, pos, *selection, c_cache, kr_cache],
         ),
         out_shape=jax.ShapeDtypeStruct((B, NP, R, r), q_c.dtype),
         scratch=[
@@ -479,6 +550,7 @@ def paged_latent_flash_attention(
             pltpu.VMEM((NP, R, 1), jnp.float32),
             pltpu.VMEM((NP, R, 1), jnp.float32),
             pltpu.VMEM((NP, R, r), jnp.float32),
+            *[pltpu.VMEM((nq, pack, tq, P * rows), jnp.float32) for _ in selection],
         ],
         interpret=interpret,
         name=CHUNK_KERNEL,
@@ -512,22 +584,30 @@ def native_latent_attention(q_c, q_pe, c_all, kr_all, mask, scale):
 
 def latent_attend(
     q_c, q_pe, c_cache, kr_cache, layer_idx, mask, block_table, kv_limit, positions,
-    *, scale: float, interpret: bool,
+    chosen=None, *, scale: float, interpret: bool,
 ):
     """Attention of the split serving step over the latent pool, this pass's
     latents already written: a prefill chunk rides the chunk kernel, a decode
     step the decode kernel, each where :func:`use_latent_kernel` admits the
-    call; else blocks are gathered by the table and attended natively."""
+    call; else blocks are gathered by the table and attended natively. Under
+    a selection ``chosen (B, Sq, W)`` (live AND picked) the same rule picks
+    the kernel and the predicate is what a query may attend: the chunk
+    kernel's operand, the decode kernel's and the native form's mask."""
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
         read_latent_cache_at_layer,
     )
 
     Sq = q_c.shape[1]
+    if chosen is not None:
+        mask = chosen[:, None]
     if use_latent_kernel(c_cache, kr_cache, Sq, mask.shape[-1]):
-        if Sq > TKG_MAX_Q_LEN:
+        # the decode kernel's mask is a slab a (head, position) ROW: a selection
+        # over a long row at several positions (64 heads x 16 x a 16896 bucket:
+        # 69 MB) stands in no VMEM, the chunk kernel's is one a position
+        if Sq > TKG_MAX_Q_LEN or (chosen is not None and Sq > 1):
             return paged_latent_flash_attention(
                 q_c, q_pe, c_cache, kr_cache, layer_idx, block_table, positions, kv_limit,
-                scale=scale, interpret=interpret,
+                chosen, scale=scale, interpret=interpret,
             )
         return paged_latent_decode_attention(
             q_c, q_pe, c_cache, kr_cache, layer_idx, block_table, mask,

@@ -16,6 +16,7 @@ module — a TPU executable written from here cannot be read back without a
 chip, and the next run would warn on every entry.
 """
 
+import functools
 import re
 
 import jax
@@ -115,6 +116,35 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape_class, dtype):
     with force_compiled_kernels():
         compiled = jax.jit(fn).lower(*args).compile()
     assert _custom_calls(compiled) >= 1
+
+
+@pytest.mark.parametrize("q_len", [128, 32, 8])
+def test_latent_chunk_kernel_takes_a_selection_at_glm5s_widths(one_chip, q_len):
+    """``paged_latent_flash_attention`` under a selection's predicate at
+    glm-5's widths (8 rows, 64 heads over a latent of 512 + 64, the 16896
+    bucket: 32 parts of 256 score rows, a row's predicate slab 2.1 MB of int8
+    at 128 queries, int32 at a q tile under 32 rows) compiles for a v5e with
+    what it asks of VMEM (``latent_attend`` gives it every chunk width under
+    a selection), and the decode kernel is not asked for 8 positions x 64
+    heads (its mask slab would be 34 MB a row)."""
+    from neuronx_distributed_inference_tpu.ops import latent_attention as la
+
+    B, H, r, rope, bs, W = 8, 64, 512, 64, 32, 16896
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (
+        sds((B, q_len, H, r), jnp.bfloat16), sds((B, q_len, H, rope), jnp.bfloat16),
+        sds((5, 4225, 1, bs, r), jnp.bfloat16), sds((5, 4225, 1, bs // 2, 128), jnp.bfloat16),
+        sds((), jnp.int32), sds((B, 1, q_len, W), jnp.bool_), sds((B, W // bs), jnp.int32),
+        sds((B,), jnp.int32), sds((B, q_len), jnp.int32), sds((B, q_len, W), jnp.bool_),
+    )
+    with force_compiled_kernels(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(la, "on_tpu", lambda: True)  # the gate asks jax.default_backend()
+        compiled = jax.jit(
+            functools.partial(la.latent_attend, scale=256 ** -0.5, interpret=False)
+        ).lower(*args).compile()
+    text = compiled.as_text()
+    assert la.CHUNK_KERNEL in text and la.DECODE_KERNEL not in text
+    assert ("s8[8,33,2,1,128,256]" in text) == (q_len == 128)
 
 
 def test_flash_compiles_at_8b_head_dim(one_chip):
@@ -903,13 +933,16 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     programs compiled for a described v5e at the widest kv bucket, 16896:
     the pool is three streams (latent 512, rotary key packed two tokens a
     128-lane row, indexer key 128: 1408 B a token a layer, no lane of
-    padding); past ``index_topk`` neither program attends every live token
-    (the decode program runs the latent decode kernel under the selection's
-    predicate, the chunk program walks the row under it, row by row), the
-    three scopes of the mechanism hold work, no program copies a stream of
-    the pool, and each plans under 14.75 GiB of the chip's 15.75. At a kv
-    bucket of ``index_topk`` the same programs hold the dense latent kernels
-    and no indexer score."""
+    padding); past ``index_topk`` neither program attends every live token:
+    each runs its latent kernel under the selection's predicate, under
+    ``layer.attn`` (the decode program the decode kernel, the chunk program
+    the chunk kernel over a row's live block groups: no row's bucket of
+    latents is gathered, ``(8, 16896, 512)``, and its temporaries stand under
+    the 0.978 GiB the dense walk planned, the kernel's VMEM request under
+    the chip's), the three scopes of the mechanism hold work, no program
+    copies a stream of the pool, and each plans under 14.75 GiB of the
+    chip's 15.75. At a kv bucket of ``index_topk`` the same programs hold
+    the dense latent kernels and no indexer score."""
     from neuronx_distributed_inference_tpu.ops import kernel_mode, latent_attention
     from neuronx_distributed_inference_tpu.telemetry import device_scopes
 
@@ -926,18 +959,25 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     assert inputs.input_ids.shape == ((32, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
     text = compiled.as_text()
-    assert "paged_latent_flash_attention" not in text
-    scopes = set(device_scopes.scope_table(text)["ops"].values())
-    assert {"layer.indexer", "layer.select", "layer.attn", "layer.kv_write"} <= scopes
+    kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
+    table = device_scopes.scope_table(text)["ops"]
+    assert {scope for name, scope in table.items() if name.startswith(kernel)} == {"layer.attn"}
+    assert {"layer.indexer", "layer.select", "layer.attn", "layer.kv_write"} <= set(table.values())
     for stream in (cache.k, cache.v, cache.extra[0]):
         assert _pool_copies(compiled, stream.shape)[0] == 0
     mem = compiled.memory_analysis()
+    if program == "chunk":
+        # the selection rides the kernel: no gathered bucket of latents, no
+        # float32 scores of a row's bucket, less planned than the dense walk
+        assert not re.search(r"bf16\[8,16896,512\]|f32\[64,128,16896\]", text)
+        assert mem.temp_size_in_bytes < 0.978 * 2**30
+        asked = [int(x) for x in re.findall(r'"scoped_memory_configs":\[\{[^}]*"size":"([0-9]+)"', text)]
+        assert asked and max(asked) <= 100 * 2**20  # of the chip's 128 MiB of VMEM
     print(f"\nglm-5 {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
     # at index_topk the selection is everything: dense latent attention, as it was
     dense = _compile_step(app, tkg, tkg.example_inputs(2048, q_len=q), params, cache).as_text()
-    kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
     assert kernel in dense
     assert "layer.select" not in set(device_scopes.scope_table(dense)["ops"].values())

@@ -235,12 +235,12 @@ def test_absorbed_attention_is_expanded_attention():
     np.testing.assert_allclose(absorbed, expanded, rtol=0, atol=2e-5)
 
 
-def _filled_pool(rng, r, w, pack, bs, contexts, step):
+def _filled_pool(rng, r, w, pack, bs, contexts, step, MB=8):
     """A two-layer pool whose layer 1 holds ``contexts`` tokens a row (written
     in chunks of 24: the block form) and then ``step`` more (a pass of that
-    width), the block tables, and what was written, in token order."""
-    L, MB = 2, 8
-    B, NB = len(contexts), 8 * len(contexts)
+    width), the block tables ``MB`` wide, and what was written, in token order."""
+    L = 2
+    B, NB = len(contexts), MB * len(contexts)
     streams = (bk.CacheStream(1, r), bk.CacheStream(1, w, pack))
     cache = bk.init_block_cache(L, NB, bs, dtype=jnp.float32, streams=streams)
     tables = np.zeros((B, MB), np.int32)
@@ -320,6 +320,120 @@ def test_latent_kernels_are_the_native_attention(r, w, pack, bs, step):
     else:
         got = la.paged_latent_decode_attention(
             q_c, q_pe, c, kr, li, tables, mask, scale=scale, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+#: a selection over the chunk kernel (interpret mode): (pool shape ``r, w,
+#: pack, bs, MB``, q width, the rows' contexts before the pass (None: a padded
+#: row), what is made of a random selection of the live keys). At r = 64 a
+#: group is ONE block (32 tokens), at r = 128 eight (256 tokens).
+_SMALL, _WIDE = (64, 16, 8, 32, 8), (128, 64, 2, 32, 16)
+SELECTIONS = {
+    "q32": (_SMALL, 32, [3 * 32 + 5, 7, 8 * 32 - 32], None),
+    "q64": (_SMALL, 64, [3 * 32 + 5, 7, 8 * 32 - 64], None),
+    "q128_groups_of_8_blocks": (_WIDE, 128, [300, 7, 16 * 32 - 128], None),
+    # the width the rule gives the chunk kernel under a selection only
+    "q8_narrow": (_SMALL, 8, [3 * 32 + 5, 7, 8 * 32 - 8], None),
+    "rows_of_unlike_progress_two_padded": (_SMALL, 32, [None, 5 * 32 + 9, 0, None, 70], None),
+    "nothing_picked_in_the_first_groups": (_SMALL, 32, [6 * 32 + 3, 4 * 32], "not_first"),
+    "nothing_picked_in_the_last_group": (_SMALL, 32, [6 * 32 + 3, 4 * 32], "not_last"),
+    "nothing_picked_at_all": (_SMALL, 32, [3 * 32 + 5, 40], "one_query_nothing"),
+    # 300 + 128 tokens: the live keys end 172 tokens into the second group of 256
+    "live_keys_end_inside_a_group": (_WIDE, 128, [300, 130], None),
+    "every_live_key_is_no_predicate": (_SMALL, 32, [3 * 32 + 5, 7, 8 * 32 - 32], "all"),
+    # a bf16 pool: the probabilities go to the matrix unit in bf16, as the native form rounds them
+    "bf16_pool": (_WIDE, 32, [300, 7, 16 * 32 - 32], "bf16"),
+}
+
+
+@pytest.mark.parametrize("case", list(SELECTIONS))
+def test_the_chunk_kernel_attends_a_selection(case):
+    """``paged_latent_flash_attention`` under a predicate ``chosen`` (what
+    ``sparse_index.select`` returns: live AND picked) against the native
+    attention under the same predicate: every picked key, no other, at each
+    chunk width, for rows of unlike progress beside padded ones, for a query
+    that picks nothing in the groups it meets first or last (its running
+    maximum stays finite and a masked score adds exactly 0), for a row whose
+    live keys end inside a group; and a predicate of every live key IS the
+    call without one (to the last bits: 2e-7 where the others are held to 2e-6)."""
+    (r, w, pack, bs, MB), step, contexts, shape = SELECTIONS[case]
+    rng = np.random.default_rng(43)
+    live_row = np.array([n is not None for n in contexts])
+    c, kr, li, tables, _, _ = _filled_pool(
+        rng, r, w, pack, bs, [n or 0 for n in contexts], step, MB)
+    B, H, W = len(contexts), 4, MB * bs
+    q_c = jnp.asarray(rng.standard_normal((B, step, H, r)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((B, step, H, w)), jnp.float32)
+    positions = np.array([[(n or 0) + t for t in range(step)] for n in contexts])
+    positions = jnp.asarray(np.where(live_row[:, None], positions, 0), jnp.int32)
+    kv_limit = jnp.asarray(np.where(live_row, positions[:, -1] + 1, 0), jnp.int32)
+    live = (np.arange(W)[None, None, :] <= np.asarray(positions)[:, :, None]) & live_row[:, None, None]
+    chosen = live & (rng.random((B, step, W)) < 0.3)
+    group = bs * (8 if r == 128 else 1)
+    if shape == "not_first":
+        chosen[:, ::2, : 3 * group] = False  # every other query: nothing in its first three groups
+    elif shape == "not_last":
+        for b, n in enumerate(contexts):
+            chosen[b, ::2, (n + step - 1) // group * group:] = False
+    elif shape == "one_query_nothing":
+        chosen[:, 5] = False
+    elif shape == "all":
+        chosen = live
+    assert chosen[live_row].any(axis=-1).mean() > 0.9  # the cases are not empty
+    scale = 0.3 * (r + w) ** -0.5
+    atol = 2e-6
+    if shape == "bf16":
+        q_c, q_pe, c, kr = (x.astype(jnp.bfloat16) for x in (q_c, q_pe, c, kr))
+        atol = 2e-2  # of outputs of order 1: bf16's rounding of the probabilities and of the result
+    args = (q_c, q_pe, c, kr, li, tables, positions, kv_limit)
+    got = la.paged_latent_flash_attention(*args, jnp.asarray(chosen), scale=scale, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    if shape == "all":
+        # the same sums over the same keys; the CPU's compiler orders a row's
+        # sum by the fusion it sits in, so to the last bits and not bit for bit
+        plain = la.paged_latent_flash_attention(*args, scale=scale, interpret=True)
+        np.testing.assert_allclose(got, plain, rtol=0, atol=2e-7)
+    want = la.native_latent_attention(
+        q_c, q_pe, *bk.read_latent_cache_at_layer(c, kr, li, tables),
+        jnp.asarray(chosen)[:, None], scale)
+    # a query that picks nothing reads zeros (the native softmax of nothing is a mean)
+    picks = chosen.any(axis=-1)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got[picks], want[picks], rtol=0, atol=atol)
+    assert not got[~picks].any()
+
+
+@pytest.mark.parametrize("step,kernel", [(1, "decode"), (8, "chunk"), (16, "chunk"), (40, "chunk")])
+def test_one_rule_picks_the_kernel_for_a_selection(step, kernel, monkeypatch):
+    """``latent_attend`` under a selection: the decode kernel with the
+    predicate as its mask for a one-token pass, the chunk kernel with the
+    predicate as its operand at every wider pass (the decode kernel's mask is
+    a slab a (head, position) row, which no VMEM holds over a long row), and
+    either way the native attention under the predicate."""
+    monkeypatch.setattr(la, "on_tpu", lambda: True)
+    r, w, pack, bs, MB = 128, 64, 2, 32, 16
+    rng = np.random.default_rng(47)
+    contexts = [300, 7, MB * bs - step]
+    c, kr, li, tables, _, _ = _filled_pool(rng, r, w, pack, bs, contexts, step, MB)
+    q_c = jnp.asarray(rng.standard_normal((3, step, 4, r)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((3, step, 4, w)), jnp.float32)
+    positions = jnp.asarray([[n + t for t in range(step)] for n in contexts], jnp.int32)
+    mask = jnp.arange(MB * bs)[None, None, None, :] <= positions[:, None, :, None]
+    chosen = mask[:, 0] & jnp.asarray(rng.random((3, step, MB * bs)) < 0.4)
+    chosen = chosen.at[:, :, 0].set(True)  # every query picks something
+    called = []
+    for name in ("paged_latent_decode_attention", "paged_latent_flash_attention"):
+        def spy(*a, _fn=getattr(la, name), _name=name, **k):
+            called.append(_name)
+            return _fn(*a, **k)
+        monkeypatch.setattr(la, name, spy)
+    scale = 0.3 * (r + w) ** -0.5
+    got = la.latent_attend(
+        q_c, q_pe, c, kr, li, mask, tables, positions[:, -1] + 1, positions, chosen,
+        scale=scale, interpret=True)
+    assert called == [f"paged_latent_{'decode' if kernel == 'decode' else 'flash'}_attention"]
+    want = la.native_latent_attention(
+        q_c, q_pe, *bk.read_latent_cache_at_layer(c, kr, li, tables), chosen[:, None], scale)
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
 
 

@@ -62,7 +62,7 @@ WEIGHTS = [{"match": "router/e_score_correction_bias$", "std": 0.1},
            {"match": "indexer/k_norm/bias$", "std": 0.1}]
 
 
-def make_app(dtype="float32", model=None, **tpu):
+def make_app(dtype="float32", model=None, chunk=CHUNK, **tpu):
     cfg = dict(
         model or MODEL,
         tpu_config=dict(dict(
@@ -71,7 +71,7 @@ def make_app(dtype="float32", model=None, **tpu):
             is_continuous_batching=True, ctx_batch_size=1, is_block_kv_layout=True,
             pa_block_size=BLOCK, pa_num_blocks=48, is_chunked_prefill=True, output_logits=True,
         ), **tpu),
-        chunked_prefill=dict(max_num_seqs=SLOTS, kernel_q_tile_size=CHUNK),
+        chunked_prefill=dict(max_num_seqs=SLOTS, kernel_q_tile_size=chunk),
     )
     app = system.build_app(cfg, jax.devices()[:1], SEED)
     system.give_weights(app, *system.make_weights(app, SEED, WEIGHTS))
@@ -416,6 +416,109 @@ def test_rows_taken_one_after_another_skip_the_padded_ones(app, monkeypatch):
             want = ref.reference_logits(app.params, GEO, list(p) + generated[:-1], positions)
             got = np.stack([spy.at(i, q) for q in positions]).astype(np.float32)
             assert_is_the_reference(got, want)
+
+
+#: MODEL at widths the latent kernels' gate admits (a latent of whole lanes,
+#: two rotary keys a 128-lane row, a lane group of whole sublane tiles)
+KERNEL_MODEL = dict(MODEL, kv_lora_rank=128, qk_rope_head_dim=64, qk_nope_head_dim=32, index_head_dim=64)
+
+
+def test_the_chunk_program_attends_its_picked_keys_inside_the_chunk_kernel(monkeypatch):
+    """Past ``index_topk`` a chunk pass the gate admits attends through
+    ``latent_attend`` with the selection as the chunk kernel's predicate
+    (interpret mode here), walking each row's live block groups: two requests
+    of unlike lengths beside two idle rows give the reference's logits, no
+    row's bucket is gathered and ``attend_selected`` serves no chunk pass."""
+    from neuronx_distributed_inference_tpu.ops import latent_attention
+
+    monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    calls = {"with_selection": 0, "walk": 0}
+    kernel = latent_attention.paged_latent_flash_attention
+
+    def chunk_kernel(*a, **k):
+        calls["with_selection"] += len(a) > 8 and a[8] is not None
+        return kernel(*a, **k)
+
+    walk = sparse_index.attend_selected
+
+    def dense_walk(q_c, *a, **k):  # the decode step's, whose kv width the gate refuses here
+        calls["walk"] += q_c.shape[1] > 1
+        return walk(q_c, *a, **k)
+
+    monkeypatch.setattr(latent_attention, "paged_latent_flash_attention", chunk_kernel)
+    monkeypatch.setattr(sparse_index, "attend_selected", dense_walk)
+    chunk = 32
+    served = make_app(model=KERNEL_MODEL, chunk=chunk, pa_block_size=16, pa_num_blocks=40)
+    geo = ref.geometry(KERNEL_MODEL, 1)
+    rng = np.random.default_rng(19)
+    prompts = [rng.integers(0, VOCAB, size=n) for n in (3 * chunk, 2 * chunk)]
+    served.init_kv_cache()
+    with LogitSpy(served) as spy:
+        s = ServingSession(served)
+        for i, p in enumerate(prompts):
+            assert s.add_request(f"r{i}", p, max_new_tokens=3)
+        drain(s)
+        for i, p in enumerate(prompts):
+            generated = [int(t) for t in s.requests[f"r{i}"].generated]
+            positions = [len(p) - 1 + k for k in range(3)]
+            want = ref.reference_logits(served.params, geo, list(p) + generated[:-1], positions)
+            got = np.stack([spy.at(i, q) for q in positions]).astype(np.float32)
+            assert_is_the_reference(got, want)
+    # traced once a program: the chunk programs past index_topk hold the kernel under a predicate
+    assert calls["with_selection"] >= 1 and calls["walk"] == 0
+
+
+def test_the_chunk_walk_counter_is_the_kernels_walk_past_index_topk(monkeypatch):
+    """``nxdi_chunk_kv_blocks_total`` for a model with an indexer: ``live``
+    the blocks the prefilling rows' contexts hold, ``walked`` the whole groups
+    of ``blocks_per_group`` blocks up to each row's last live one, which is
+    what the chunk kernel copies and attends under a selection too (its
+    ``end`` operand, read here from the call): not the kv bucket's width."""
+    from neuronx_distributed_inference_tpu.ops import latent_attention
+    from neuronx_distributed_inference_tpu.ops.paged_flash_attention import blocks_per_group
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+
+    monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    chunk, bs = 32, 16
+    served = make_app(model=KERNEL_MODEL, chunk=chunk, pa_block_size=bs, pa_num_blocks=40)
+    served.init_kv_cache()
+    tel = TelemetrySession(enabled=True)
+    s = ServingSession(served, telemetry=tel)
+    rng = np.random.default_rng(23)
+    lengths = (3 * chunk + 8, 2 * chunk)
+    for i, n in enumerate(lengths):
+        assert s.add_request(f"r{i}", rng.integers(0, VOCAB, size=n), max_new_tokens=2)
+    drain(s)
+    snap = tel.registry.snapshot()["nxdi_chunk_kv_blocks_total"]["samples"]
+    tel.stop()
+    got = {x["labels"]["kind"]: x["value"] for x in snap}
+    # the passes: both rows at 32 and 64 tokens (kv bucket 64: a table of 4
+    # blocks, one group), then the longer row alone at 96 and 104 (bucket 128)
+    passes = [(64, [32, 32]), (64, [64, 64]), (128, [96]), (128, [104])]
+    live = [[-(-n // bs) for n in rows] for _, rows in passes]
+    assert got["live"] == sum(map(sum, live)) == 25
+    walked = 0
+    for (bucket, _), blocks in zip(passes, live):
+        P = blocks_per_group(1, bs, KERNEL_MODEL["kv_lora_rank"], jnp.float32, bucket // bs)
+        walked += sum(-(-n // P) * P for n in blocks)
+    assert got["walked"] == walked == 32
+    # and the kernel itself ends a row's walk at its last live block
+    c, kr = served.kv_cache.k, served.kv_cache.v
+    n = 96  # r0's third pass, past index_topk
+    positions = jnp.arange(n - chunk, n, dtype=jnp.int32)[None]
+    seen = {}
+    call = latent_attention._da._common_call
+
+    def common_call(kernel, *a, operands, **k):
+        seen["end"] = operands[0][2]
+        return call(kernel, *a, operands=operands, **k)
+
+    monkeypatch.setattr(latent_attention._da, "_common_call", common_call)
+    latent_attention.paged_latent_flash_attention.__wrapped__(
+        jnp.zeros((1, chunk, 4, 128)), jnp.zeros((1, chunk, 4, 64)), c, kr, jnp.int32(0),
+        jnp.zeros((1, 128 // bs), jnp.int32), positions, jnp.asarray([n], jnp.int32),
+        jnp.ones((1, chunk, 128), bool), scale=1.0, interpret=True)
+    assert int(seen["end"][0]) == -(-n // bs) == live[2][0]
 
 
 def test_the_session_counts_what_the_selection_scores_and_attends(app):
