@@ -196,7 +196,37 @@ class SubModelRunner:
         adapter_ids: Optional[np.ndarray] = None,
         inputs_embeds: Optional[np.ndarray] = None,
     ) -> Tuple[StepInputs, int]:
-        """Pad to (compiled batch, bucket) and build StepInputs."""
+        """Pad to (compiled batch, bucket) and build StepInputs: the host
+        arrays (:meth:`prepare_host`), then their copies to the device
+        (:meth:`to_device`)."""
+        arrs, B = self.prepare_host(
+            input_ids, attention_mask, position_ids, seq_ids, sampling_params,
+            slot_mapping, block_table, adapter_ids, inputs_embeds,
+        )
+        return self.to_device(arrs), B
+
+    @staticmethod
+    def to_device(arrs: Dict[str, np.ndarray]) -> StepInputs:
+        """The host-to-device half of :meth:`prepare`: one copy an array (an
+        array already on the device, a chained input, stays where it is)."""
+        return StepInputs(**{k: jnp.asarray(v) for k, v in arrs.items()})
+
+    def prepare_host(
+        self,
+        input_ids: np.ndarray,
+        attention_mask: np.ndarray,
+        position_ids: np.ndarray,
+        seq_ids: np.ndarray,
+        sampling_params: Optional[np.ndarray] = None,
+        slot_mapping: Optional[np.ndarray] = None,
+        block_table: Optional[np.ndarray] = None,
+        adapter_ids: Optional[np.ndarray] = None,
+        inputs_embeds: Optional[np.ndarray] = None,
+    ) -> Tuple[Dict[str, np.ndarray], int]:
+        """The host half of :meth:`prepare`: ``{StepInputs field: array}``
+        padded to (compiled batch, bucket), nothing copied to the device
+        yet, and the caller's batch. The serving step copies them under a
+        span of its own (``serving.h2d``)."""
         B, S = input_ids.shape
         bounded = self.spec.bounded_window
         if self.phase == PHASE_CONTEXT_ENCODING:
@@ -263,8 +293,7 @@ class SubModelRunner:
         # the paged chunk program is chunk_rows wide (rows addressed by slot);
         # every other program has one row per slot
         paged_chunk = self.is_paged_chunk(slot_mapping, block_table)
-        arrs = self._pad_batch(arrs, self.chunk_rows if paged_chunk else self.batch_size)
-        return StepInputs(**{k: jnp.asarray(v) for k, v in arrs.items()}), B
+        return self._pad_batch(arrs, self.chunk_rows if paged_chunk else self.batch_size), B
 
     def trace_program(self, params, cache: KVCache, inputs: StepInputs, rng=None):
         """Trace + lower + compile this runner's step program WITHOUT

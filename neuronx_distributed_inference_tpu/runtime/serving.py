@@ -350,6 +350,9 @@ class ServingSession:
         # accumulated blocking-fetch wait inside the current _ragged_step
         # (host-frac telemetry: step wall minus this is pure host time)
         self._step_fetch_wait_s = 0.0
+        # the span of the step that runs (``step()``), for what the split
+        # step notes on it from inside: the rows and dispatches it decided
+        self._step_span = NULL_SPAN
         # router-managed sessions carry their replica id (set by
         # ReplicaHandle) so step-timing/watchdog records land on the
         # replica's timeline track; standalone sessions stay None
@@ -1206,40 +1209,42 @@ class ServingSession:
         (step()-driven chunked serving — never stalls the session); otherwise
         the pass returns None and the caller drops the request
         (admission-time prefill)."""
-        rows = []
-        for req in reqs:
-            n = min(chunk_size, req.prefill_target - req.prefill_pos)
-            if n <= 0:
-                continue
-            try:
-                self._alloc(req.slot, req.prefill_pos + n)
-            except RuntimeError:
-                if not preempt:
-                    return None
-                self._preempt(req)
-                continue
-            rows.append((req, n))
-        if not rows:
-            return []
-
-        # the chunk program is R rows wide and its rows are addressed by slot
-        # (block table, slot mapping, seq_ids): the pass packs the requests
-        # that prefill into rows 0..n-1 in groups of R, one dispatch a group
-        tkg = self.app.token_generation_model
-        R = tkg.chunk_rows
-        groups = [rows[i : i + R] for i in range(0, len(rows), R)]
-        qb = pow2_bucket(max(n for _, n in rows))
-        bs = self.allocator.block_size
-        max_pos = max(r.prefill_pos + n for r, n in rows)
-        width = get_target_bucket(tkg.buckets, max_pos)
-        mb = width // bs
-        real = sum(n for _, n in rows)
         tel = self.tel
-        sampling = self._session_sampling_params()[:R]
-        # the chunk program projects each row's last fed position and returns
-        # tokens (R, 1); an application that returns logits keeps the head at
-        # every position beside it (models/base.model_logits)
-        head_q = qb if self.app.spec.output_logits else 1
+        with tel.span("serving.schedule"):
+            rows = []
+            for req in reqs:
+                n = min(chunk_size, req.prefill_target - req.prefill_pos)
+                if n <= 0:
+                    continue
+                try:
+                    self._alloc(req.slot, req.prefill_pos + n)
+                except RuntimeError:
+                    if not preempt:
+                        return None
+                    self._preempt(req)
+                    continue
+                rows.append((req, n))
+            if not rows:
+                return []
+
+            # the chunk program is R rows wide and its rows are addressed by
+            # slot (block table, slot mapping, seq_ids): the pass packs the
+            # requests that prefill into rows 0..n-1 in groups of R, one
+            # dispatch a group
+            tkg = self.app.token_generation_model
+            R = tkg.chunk_rows
+            groups = [rows[i : i + R] for i in range(0, len(rows), R)]
+            qb = pow2_bucket(max(n for _, n in rows))
+            bs = self.allocator.block_size
+            max_pos = max(r.prefill_pos + n for r, n in rows)
+            width = get_target_bucket(tkg.buckets, max_pos)
+            mb = width // bs
+            real = sum(n for _, n in rows)
+            sampling = self._session_sampling_params()[:R]
+            # the chunk program projects each row's last fed position and
+            # returns tokens (R, 1); an application that returns logits keeps
+            # the head at every position beside it (models/base.model_logits)
+            head_q = qb if self.app.spec.output_logits else 1
         with tel.span(
             "serving.prefill_chunk", rows=len(rows), real_tokens=real,
             padded_tokens=len(groups) * R * qb - real, q_bucket=qb, kv_bucket=width,
@@ -1268,10 +1273,12 @@ class ServingSession:
                         )
                         block_table[row] = self.allocator.block_table(s, mb)
                         seq_ids[row] = s
-                    inputs, _ = tkg.prepare(
+                    arrs, _ = tkg.prepare_host(
                         ids, mask, positions, seq_ids, sampling,
                         slot_mapping=slot_mapping, block_table=block_table,
                     )
+                    with tel.span("serving.h2d", **self._h2d_fields(arrs)):
+                        inputs = tkg.to_device(arrs)
 
                 fields = self._program_fields("chunk", qb, tkg.last_bucket)
 
@@ -1284,39 +1291,45 @@ class ServingSession:
                 )
                 if out is None:
                     continue  # this group's rows terminally FAILED(dispatch_error)
-                self._start_fetch(out.tokens)
+                with tel.span("serving.prefill_chunk.fetch_start"):
+                    self._start_fetch(out.tokens)
                 self.app.kv_cache = out.cache
-                tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
                 flights.append(([(r, n, r.epoch) for r, n in group], out.tokens))
             if not flights:
                 return []
-            ran = [(r, n) for group, _ in flights for r, n, _ in group]
-            ran_real = sum(n for _, n in ran)
-            tel.step("prefill")
-            # what the program really ran over: R rows at the q bucket a
-            # dispatch, whatever the number of rows prefilling
-            tel.prefill_pass(
-                ran_real, len(flights) * R * qb - ran_real, dispatches=len(flights)
-            )
-            kv_blocks = None
-            if tel.enabled:
-                # the blocks a row's causal context holds once this chunk is in
-                live = [-(-(r.prefill_pos + n) // bs) for r, n in ran]
-                kv_blocks = (sum(live), self._chunk_kv_blocks_walked(live, mb))
-                tel.kv_write_blocks(
-                    *self._chunk_write_blocks([(r.prefill_pos, n) for r, n in ran], qb)
+            # what the pass records of itself, after its last dispatch
+            # returned: the telemetry's own cost of a chunk pass, by name
+            with tel.span("serving.account"):
+                ran = [(r, n) for group, _ in flights for r, n, _ in group]
+                ran_real = sum(n for _, n in ran)
+                tel.step("prefill")
+                for _ in flights:
+                    tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
+                # what the program really ran over: R rows at the q bucket a
+                # dispatch, whatever the number of rows prefilling
+                tel.prefill_pass(
+                    ran_real, len(flights) * R * qb - ran_real, dispatches=len(flights),
+                    rows=(len(ran), len(flights) * R - len(ran)),
                 )
-            self._count_pass(
-                "chunk", (R, qb), len(ran), ran_real, len(flights),
-                resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
-                kv_blocks=kv_blocks, kv_width=width,
-                spans=[(r.prefill_pos, n) for r, n in ran] if self.sparse_layers else (),
-            )
-            for req, n in ran:
-                self._note_prefill(req, n)
-            tel.pool_gauges(
-                len(self.active), self.kv_pool_bytes, self.kv_free_bytes
-            )
+                kv_blocks = None
+                if tel.enabled:
+                    # the blocks a row's causal context holds once this chunk is in
+                    live = [-(-(r.prefill_pos + n) // bs) for r, n in ran]
+                    kv_blocks = (sum(live), self._chunk_kv_blocks_walked(live, mb))
+                    tel.kv_write_blocks(
+                        *self._chunk_write_blocks([(r.prefill_pos, n) for r, n in ran], qb)
+                    )
+                self._count_pass(
+                    "chunk", (R, qb), len(ran), ran_real, len(flights),
+                    resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
+                    kv_blocks=kv_blocks, kv_width=width,
+                    spans=[(r.prefill_pos, n) for r, n in ran] if self.sparse_layers else (),
+                )
+                for req, n in ran:
+                    self._note_prefill(req, n)
+                tel.pool_gauges(
+                    len(self.active), self.kv_pool_bytes, self.kv_free_bytes
+                )
             if self.blocks is not None:
                 # a block-step row generates nothing at its prompt's end:
                 # what is left of the prompt opens its first block, and the
@@ -1453,6 +1466,7 @@ class ServingSession:
         self._step_index += 1
         tel = self.tel
         with tel.span("serving.step", step=self._step_index) as step_span:
+            self._step_span = step_span
             self._step_fetch_wait_s = 0.0
             with tel.span("serving.housekeeping"):
                 # progress baseline BEFORE re-admission: a successful
@@ -1488,6 +1502,7 @@ class ServingSession:
         if self.ragged:
             return self._ragged_step()
         results: Dict[str, int] = {}
+        tel = self.tel
         # every pass the step holds is DISPATCHED before the step waits for
         # any pass's tokens, and the waits come in dispatch order: the device
         # runs chunk pass then decode pass back to back (the cache is threaded
@@ -1497,57 +1512,68 @@ class ServingSession:
             batch = self.prefilling[: self.max_prefill_seqs]
             flights = self._dispatch_chunks(batch, self.chunk_size, preempt=True)
 
-        # the decode rows need no token of the chunk pass: a request whose
-        # prompt ends in it is still ``prefilling`` until the commit below and
-        # starts decoding NEXT step (its first token is the chunk pass's, and
-        # stays this step's entry in results); a block-step row generates
-        # nothing there and was opened by the dispatch
-        active = self.decoding
+        with tel.span("serving.schedule"):
+            # the decode rows need no token of the chunk pass: a request
+            # whose prompt ends in it is still ``prefilling`` until the commit
+            # below and starts decoding NEXT step (its first token is the
+            # chunk pass's, and stays this step's entry in results); a
+            # block-step row generates nothing there and was opened by the
+            # dispatch
+            active = self.decoding
 
-        # async 1-ahead (reference modules/async_execution.py:190): dispatch
-        # step k+1 CHAINED on step k's still-on-device tokens BEFORE fetching
-        # step k — the host-side fetch + bookkeeping overlaps with the device
-        # executing k+1. The fetch gates only termination: rows whose request
-        # terminates at step k ran one speculative step whose writes land in
-        # masked/overwritten slots and whose token is discarded at the next
-        # consume. The synchronous mode (async_mode=False debugging) takes the
-        # same order with nothing pending: what it dispatches it consumes
-        # below, at the end of this step.
-        pend = self._pending
-        self._pending = None
-        # chain only rows whose pending entry is still CURRENT: a row that
-        # was preempted/quarantined since its dispatch carries a stale epoch
-        # and must restart from host state (its in-flight token is discarded
-        # and — greedy — regenerated identically after re-admission)
-        pend_pos = (
-            {
-                id(req): p
-                for req, p, _s, e, *_ in pend[1]
-                if e == req.epoch and not req.finished and not req.preempted
-            }
-            if pend
-            else {}
-        )
-        rows: List = []
-        chained_slots: List[int] = []
-        if self.blocks is not None:
-            # which pass of which block, and whose ids are still on the
-            # device, is the blocks' own to say (a block that was preempted
-            # re-opens from the host's state)
-            rows, chained_slots = self.blocks.plan(active)
-        else:
-            for r in active:
-                if id(r) in pend_pos:
-                    rows.append((r, pend_pos[id(r)] + 1))
-                    chained_slots.append(r.slot)
-                else:
-                    rows.append((r, r.pos))
+            # async 1-ahead (reference modules/async_execution.py:190):
+            # dispatch step k+1 CHAINED on step k's still-on-device tokens
+            # BEFORE fetching step k — the host-side fetch + bookkeeping
+            # overlaps with the device executing k+1. The fetch gates only
+            # termination: rows whose request terminates at step k ran one
+            # speculative step whose writes land in masked/overwritten slots
+            # and whose token is discarded at the next consume. The
+            # synchronous mode (async_mode=False debugging) takes the same
+            # order with nothing pending: what it dispatches it consumes
+            # below, at the end of this step.
+            pend = self._pending
+            self._pending = None
+            # chain only rows whose pending entry is still CURRENT: a row that
+            # was preempted/quarantined since its dispatch carries a stale
+            # epoch and must restart from host state (its in-flight token is
+            # discarded and — greedy — regenerated identically after
+            # re-admission)
+            pend_pos = (
+                {
+                    id(req): p
+                    for req, p, _s, e, *_ in pend[1]
+                    if e == req.epoch and not req.finished and not req.preempted
+                }
+                if pend
+                else {}
+            )
+            rows: List = []
+            chained_slots: List[int] = []
+            if self.blocks is not None:
+                # which pass of which block, and whose ids are still on the
+                # device, is the blocks' own to say (a block that was
+                # preempted re-opens from the host's state)
+                rows, chained_slots = self.blocks.plan(active)
+            else:
+                for r in active:
+                    if id(r) in pend_pos:
+                        rows.append((r, pend_pos[id(r)] + 1))
+                        chained_slots.append(r.slot)
+                    else:
+                        rows.append((r, r.pos))
         ahead = None
         if rows:
             last_override = (pend[0], chained_slots) if chained_slots else None
             out, snap = self._dispatch_decode(rows, last_override)
             if out is not None:
                 ahead = (self._step_ids(out), snap)
+        if self._step_span is not NULL_SPAN:
+            # what the scheduler decided this step, on the step's span event
+            self._step_span.note(
+                chunk_rows=sum(len(group) for group, _ in flights),
+                chunk_dispatches=len(flights),
+                decode_rows=len(ahead[1]) if ahead else 0,
+            )
         if flights:
             # the step holds a chunk pass: was a decode pass queued behind it
             # while its tokens were still unfetched?
@@ -1956,19 +1982,23 @@ class ServingSession:
                     last = self.blocks.ids(rows, B)
                     commit = sum(r.block.dispatched == r.block.denoise for r, _ in rows)
                     block_rows = (len(rows) - commit, commit)
-                last_arr = last
+                ch = None
                 if last_override is not None:
-                    pend_tokens, chained = last_override
                     ch = np.zeros((B, 1), bool)
-                    ch[np.asarray(chained, np.int64)] = True
-                    last_arr = jnp.where(
-                        jnp.asarray(ch), pend_tokens.astype(jnp.int32), jnp.asarray(last)
-                    )
+                    ch[np.asarray(last_override[1], np.int64)] = True
                 # inactive rows: mask garbage anyway
-                inputs, _ = tkg.prepare(
-                    last_arr, mask, pos, seq_ids, self._session_sampling_params(),
+                arrs, _ = tkg.prepare_host(
+                    last, mask, pos, seq_ids, self._session_sampling_params(),
                     block_table=block_table,
                 )
+                with tel.span("serving.h2d", **self._h2d_fields(arrs, ch)):
+                    if ch is not None:
+                        # the chained rows' tokens never leave the device
+                        arrs["input_ids"] = jnp.where(
+                            jnp.asarray(ch), last_override[0].astype(jnp.int32),
+                            jnp.asarray(arrs["input_ids"]),
+                        )
+                    inputs = tkg.to_device(arrs)
             decode_span.note(rows=len(rows))
             if block_rows is not None:
                 decode_span.note(denoise_rows=block_rows[0], commit_rows=block_rows[1])
@@ -1979,23 +2009,42 @@ class ServingSession:
                     return tkg(self.app.params, self.app.kv_cache, inputs, None)
 
             out = self._guarded_dispatch("decode", [r for r, _ in rows], dispatch)
-        if out is None:
-            return None, []  # in-flight rows terminally FAILED(dispatch_error)
-        self.app.kv_cache = out.cache
-        tel.step("decode")
-        tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
-        tel.decode_pass(len(rows), B)
-        if self.block_mode and tel.enabled:
-            tel.kv_write_rows(self._decode_write_form(K, width), len(rows))
-        self._count_pass("decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
-                         block_rows=block_rows, kv_width=width,
-                         spans=[(p, K) for _, p in rows] if self.sparse_layers else ())
-        tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
-        snap = [(r, p, r.slot, r.epoch) for r, p in rows]
-        if self.blocks is not None:
-            # a block row's entry also says which pass of which block it was
-            snap = [entry + extra for entry, extra in zip(snap, self.blocks.dispatched(rows))]
+            if out is None:
+                return None, []  # in-flight rows terminally FAILED(dispatch_error)
+            self.app.kv_cache = out.cache
+            # what the pass records of itself, after its dispatch returned
+            # (the snapshot the consume reads among it): the telemetry's own
+            # cost of a decode pass, by name
+            with tel.span("serving.account"):
+                tel.step("decode")
+                tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
+                tel.decode_pass(len(rows), B)
+                if self.block_mode and tel.enabled:
+                    tel.kv_write_rows(self._decode_write_form(K, width), len(rows))
+                self._count_pass(
+                    "decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
+                    block_rows=block_rows, kv_width=width,
+                    spans=[(p, K) for _, p in rows] if self.sparse_layers else (),
+                )
+                tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
+                snap = [(r, p, r.slot, r.epoch) for r, p in rows]
+                if self.blocks is not None:
+                    # a block row's entry also says which pass of which block it was
+                    snap = [
+                        entry + extra
+                        for entry, extra in zip(snap, self.blocks.dispatched(rows))
+                    ]
         return out, snap
+
+    def _h2d_fields(self, arrs: dict, *more) -> dict:
+        """What a ``serving.h2d`` span says at entry: how many host arrays
+        the pass copies to the device (a pass's padded inputs and whatever
+        ``more`` rides with them) and their bytes. A stopped session builds
+        nothing."""
+        if not self.tel.enabled:
+            return {}
+        host = [a for a in (*arrs.values(), *more) if isinstance(a, np.ndarray)]
+        return {"arrays": len(host), "bytes": int(sum(a.nbytes for a in host))}
 
     def _program_fields(self, program: str, q: int, kv: int) -> dict:
         """What a dispatch span of the split step says AT ENTRY (so it is on
@@ -2035,7 +2084,11 @@ class ServingSession:
         """What of a dispatched decode step the session chains on and fetches:
         (B, K) on the device. The last token a row, or a block-step model's
         ids of the row's next pass."""
-        return out.tokens[:, -1:] if self.blocks is None else out.next_ids
+        if self.blocks is not None:
+            return out.next_ids
+        # at one token a row the slice is the array itself, and JAX hands it
+        # back, after ~50 us of index arithmetic on the host a step
+        return out.tokens if out.tokens.shape[1] == 1 else out.tokens[:, -1:]
 
     def _count_pass(self, program: str, shape, rows: int, tokens: int, dispatches: int,
                     resets: int = 0, kv_blocks=None, block_rows=None, kv_width: int = 0,

@@ -162,19 +162,22 @@ class _Span:
         if "step" not in self.fields and stack and "step" in stack[-1].fields:
             self.fields["step"] = stack[-1].fields["step"]
         self._ann = jax.profiler.TraceAnnotation(self.name, **self.fields)
+        self._ann.__enter__()
         stack.append(self)
         self.t0 = self.tel.clock()
-        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        self._ann.__exit__(*exc)
+        # the annotation closes LAST: what recording this span costs (its
+        # event) lies under its own name in a trace, not in the self time of
+        # the span around it; ``dur_s`` is the block alone
         t1 = self.tel.clock()
         self.dur_s = t1 - self.t0
         self._stack.pop()
         parent = self._stack[-1].name if self._stack else None
         self.tel.event("span", name=self.name, t0=self.t0, t1=t1,
                        dur_ms=self.dur_s * 1e3, parent=parent, **self.fields)
+        self._ann.__exit__(*exc)
         return None
 
 
@@ -362,6 +365,13 @@ class TelemetrySession:
             "nxdi_prefill_chunk_dispatches_total",
             "dispatches of the chunk program by the split path's chunk "
             "passes (over nxdi_steps_total{kind=prefill}: dispatches a pass)")
+        self._chunk_rows = r.counter(
+            "nxdi_chunk_rows_total",
+            "rows of the chunk program over the split path's chunk "
+            "dispatches: kind=live held a prefilling request, kind=empty ran "
+            "with none (live + empty = dispatches x chunk rows; live over "
+            "both: the share of the program's rows that did work)",
+            labels=("kind",))
         self._decode_rows = r.counter(
             "nxdi_decode_rows_total",
             "live rows in the split path's decode dispatches")
@@ -1185,16 +1195,21 @@ class TelemetrySession:
             return
         self._bucket.child((model, str(int(bucket)))).inc()
 
-    def prefill_pass(self, real_tokens: int, padded_tokens: int, dispatches: int = 1) -> None:
+    def prefill_pass(self, real_tokens: int, padded_tokens: int, dispatches: int = 1,
+                     rows=(0, 0)) -> None:
         """One chunk pass of the split serving step: the prompt tokens it
-        advanced, the padded positions the program ran besides, and the
+        advanced, the padded positions the program ran besides, the
         dispatches of the chunk program it took
-        (real + padded == dispatches x chunk rows x q_bucket)."""
+        (real + padded == dispatches x chunk rows x q_bucket), and of the
+        program's ``rows`` those that held a request and those that ran
+        empty (live + empty == dispatches x chunk rows)."""
         if not self.enabled:
             return
         self._prefill_real.inc(real_tokens)
         self._prefill_padded.inc(padded_tokens)
         self._prefill_dispatches.inc(dispatches)
+        self._chunk_rows.child(("live",)).inc(rows[0])
+        self._chunk_rows.child(("empty",)).inc(rows[1])
 
     def chunk_step(self, decode_behind: bool) -> None:
         """One step of the split serving path that holds a chunk pass, told
@@ -1727,24 +1742,6 @@ class TelemetrySession:
         if sealed:
             self._sealed_retrace.child((tag,)).inc()
             self.event("sealed_retrace", tag=tag)
-
-    # ---- summaries -------------------------------------------------------
-
-    def percentile(self, values: List[float], q: float) -> Optional[float]:
-        if not values:
-            return None
-        s = sorted(values)
-        k = min(len(s) - 1, int(round(q * (len(s) - 1))))
-        return s[k]
-
-    def ttft_values_s(self) -> List[float]:
-        return [t.ttft_s for t in self.completed if t.ttft_s is not None]
-
-    def itl_values_s(self) -> List[float]:
-        out: List[float] = []
-        for t in self.completed:
-            out.extend(t.itl_s)
-        return out
 
 
 def load_events(jsonl_path: str) -> List[dict]:

@@ -292,15 +292,16 @@ def test_serving_rows_are_consecutive_positions_of_one_sequence(monkeypatch):
     from neuronx_distributed_inference_tpu.runtime.model_runner import SubModelRunner
 
     seen = []
-    prepare = SubModelRunner.prepare
+    prepare_host = SubModelRunner.prepare_host  # the host half of prepare(): every pass builds its rows here
 
     def spy(self, *args, **kwargs):
-        inputs, bucket = prepare(self, *args, **kwargs)
-        if inputs.slot_mapping is not None and inputs.slot_mapping.shape[1] > 1:
-            seen.append((np.asarray(inputs.position_ids), np.asarray(inputs.slot_mapping)))
-        return inputs, bucket
+        arrs, batch = prepare_host(self, *args, **kwargs)
+        slots = arrs.get("slot_mapping")
+        if slots is not None and slots.shape[1] > 1:
+            seen.append((np.asarray(arrs["position_ids"]), np.asarray(slots)))
+        return arrs, batch
 
-    monkeypatch.setattr(SubModelRunner, "prepare", spy)
+    monkeypatch.setattr(SubModelRunner, "prepare_host", spy)
     bs = 16
     paged = dict(is_continuous_batching=True, batch_size=2, ctx_batch_size=1,
                  is_block_kv_layout=True, pa_block_size=bs, pa_num_blocks=24)

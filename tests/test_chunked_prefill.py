@@ -379,14 +379,13 @@ def test_warmup_covers_chunk_prefill_programs():
     # warmed program is never reused
     ex = tkg.example_inputs(tkg.buckets[-1], q_len=16)
     captured = {}
-    orig_prepare = tkg.prepare
+    to_device = tkg.to_device  # the device half of prepare(): what a pass dispatches
 
-    def spy(*a, **k):
-        out = orig_prepare(*a, **k)
-        captured["inputs"] = out[0]
-        return out
+    def spy(arrs):
+        captured["inputs"] = to_device(arrs)
+        return captured["inputs"]
 
-    tkg.prepare = spy
+    tkg.to_device = spy
     sess = ServingSession(app)
     assert sess.add_request("r", PROMPT_LONG[:30], max_new_tokens=2)
     sess.step()  # chunk pass: q=16 at the largest kv bucket

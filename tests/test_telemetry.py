@@ -1153,44 +1153,111 @@ def _span_events(tel):
     return [e for e in tel.events if e["type"] == "span"]
 
 
-def test_span_tree_of_the_split_step(paged_app):
+@pytest.fixture(scope="module")
+def block_app():
+    """A block-step model (sdar: block 4) on the split serving path."""
+    from tests import test_sdar_reference as sdar
+
+    return sdar.make_app()[0]
+
+
+def _drive_block(app, tel):
+    """Three requests through the block step, all admitted before the first
+    step: one chunk pass feeds the three, the block passes follow."""
+    sess = ServingSession(app, telemetry=tel)
+    rng = np.random.default_rng(0)
+    for i, n in enumerate((40, 7, 33)):
+        assert sess.add_request(f"r{i}", rng.integers(1, 500, n).tolist(), max_new_tokens=8)
+    for _ in range(200):
+        if not sess.active:
+            break
+        sess.step()
+    assert not sess.active
+
+
+#: parent of every span of the split step; a session that never prefills in
+#: a step (dense: contiguous cache, whole prompts at admission) has no chunk
+#: pass, and prefills under its admission
+SPLIT_PARENTS = {
+    "serving.housekeeping": "serving.step",
+    "serving.schedule": "serving.step",
+    "serving.prefill_chunk": "serving.step",
+    "serving.prefill_chunk.prepare": "serving.prefill_chunk",
+    "serving.prefill_chunk.dispatch": "serving.prefill_chunk",
+    "serving.prefill_chunk.fetch_start": "serving.prefill_chunk",
+    # the committing half runs after the step's decode pass is dispatched
+    "serving.prefill_chunk.fetch_wait": "serving.step",
+    "serving.prefill_chunk.commit": "serving.step",
+    "serving.decode": "serving.step",
+    "serving.decode.prepare": "serving.decode",
+    "serving.decode.dispatch": "serving.decode",
+    "serving.fetch_wait": "serving.step",
+    "serving.commit": "serving.step",
+    "serving.step": None,
+    "serving.admit": None,
+}
+#: the three spans of ISSUE 56, each under the one or two parents it may have
+NEW_PARENTS = {
+    "serving.h2d": {"serving.decode.prepare", "serving.prefill_chunk.prepare"},
+    "serving.account": {"serving.decode", "serving.prefill_chunk"},
+}
+
+
+@pytest.mark.parametrize("kind", ["chunked", "dense", "block"])
+def test_span_tree_of_the_split_step(kind, request):
     """Every span of a split step names its parent and carries the step's
-    index; the fetch waits lie inside the step; ``nxdi_step_host_ms`` and
+    index; the fetch waits lie inside the step; the host-to-device copies lie
+    inside the pass's preparation and what a pass records of itself inside
+    the pass, after its dispatch; ``nxdi_step_host_ms`` and
     ``nxdi_step_fetch_wait_ms`` are the step span minus / the fetch-wait
-    spans, one observation per step."""
+    spans, one observation per step. On a chunked, a dense and a block-step
+    session."""
+    app = request.getfixturevalue({"chunked": "paged_app", "dense": "cb_app", "block": "block_app"}[kind])
     with TelemetrySession() as tel:
-        _drive_split(paged_app, tel)
+        # dense: the contiguous cache prefills at admission, every step is a decode pass alone
+        {"chunked": _drive_split, "dense": _run_workload, "block": _drive_block}[kind](app, tel)
     spans = _span_events(tel)
-    parents = {
-        "serving.housekeeping": "serving.step",
-        "serving.prefill_chunk": "serving.step",
-        "serving.prefill_chunk.prepare": "serving.prefill_chunk",
-        "serving.prefill_chunk.dispatch": "serving.prefill_chunk",
-        # the committing half runs after the step's decode pass is dispatched
-        "serving.prefill_chunk.fetch_wait": "serving.step",
-        "serving.prefill_chunk.commit": "serving.step",
-        "serving.decode": "serving.step",
-        "serving.decode.prepare": "serving.decode",
-        "serving.decode.dispatch": "serving.decode",
-        "serving.fetch_wait": "serving.step",
-        "serving.commit": "serving.step",
-        "serving.step": None,
-        "serving.admit": None,
-    }
-    assert {e["name"] for e in spans} == set(parents)
+    parents = dict(SPLIT_PARENTS)
+    if kind == "dense":
+        parents = {k: v for k, v in parents.items() if "prefill_chunk" not in k}
+        parents["serving.prefill"] = "serving.admit"
+    assert {e["name"] for e in spans} == set(parents) | set(NEW_PARENTS)
     for e in spans:
-        assert e["parent"] == parents[e["name"]], e
+        if e["name"] in NEW_PARENTS:
+            assert e["parent"] in NEW_PARENTS[e["name"]], e
+        else:
+            assert e["parent"] == parents[e["name"]], e
         assert e["t0"] <= e["t1"]
     steps = {e["step"]: e for e in spans if e["name"] == "serving.step"}
     assert sorted(steps) == list(range(1, len(steps) + 1))
+    for e in spans:
+        if e["name"] not in ("serving.step", "serving.admit", "serving.prefill"):
+            outer = steps[e["step"]]
+            assert outer["t0"] <= e["t0"] and e["t1"] <= outer["t1"], e
+    # one copy span a dispatch, inside its preparation; one account span a
+    # pass, after the pass's last dispatch returned
+    by_name = lambda name: [e for e in spans if e["name"] == name]
+    launches = by_name("serving.decode.dispatch") + by_name("serving.prefill_chunk.dispatch")
+    assert len(by_name("serving.h2d")) == len(launches)
+    assert len(by_name("serving.account")) == len(by_name("serving.decode")) + len(
+        by_name("serving.prefill_chunk"))
+    for e in by_name("serving.h2d"):
+        prep = next(p for p in by_name(e["parent"]) if p["t0"] <= e["t0"] and e["t1"] <= p["t1"])
+        assert prep["step"] == e["step"]
+        assert e["arrays"] >= 5 and e["bytes"] >= 4 * e["arrays"]
+    for e in by_name("serving.account"):
+        owner = next(p for p in by_name(e["parent"]) if p["t0"] <= e["t0"] and e["t1"] <= p["t1"])
+        inside = [d for d in launches if owner["t0"] <= d["t0"] and d["t1"] <= owner["t1"]]
+        assert inside and max(d["t1"] for d in inside) <= e["t0"], e
+    # the planning of a decode pass lies before it, a chunk pass's before its first dispatch
+    for e in by_name("serving.decode"):
+        assert any(p["step"] == e["step"] and p["t1"] <= e["t0"] for p in by_name("serving.schedule"))
+    if kind != "chunked":
+        return
     both = [k for k in steps
             if {"serving.prefill_chunk", "serving.decode", "serving.fetch_wait"}
             <= {e["name"] for e in spans if e.get("step") == k}]
     assert both, "no step held a chunk pass, a decode dispatch and a consume"
-    for e in spans:
-        if e["name"] not in ("serving.step", "serving.admit"):
-            outer = steps[e["step"]]
-            assert outer["t0"] <= e["t0"] and e["t1"] <= outer["t1"], e
     admits = [e for e in spans if e["name"] == "serving.admit"]
     assert [(e["req_id"], e["verdict"]) for e in admits] == [
         ("r0", "admitted"), ("r1", "admitted"), ("r2", "admitted")]
@@ -1198,7 +1265,7 @@ def test_span_tree_of_the_split_step(paged_app):
     assert decode["rows"] >= 1
     launch = next(e for e in spans if e["name"] == "serving.decode.dispatch")
     assert launch["program"] == "decode" and launch["q"] == 1
-    assert launch["kv"] in paged_app.token_generation_model.buckets
+    assert launch["kv"] in app.token_generation_model.buckets
     snap = tel.registry.snapshot()
     host, wait = (snap[n]["samples"][0] for n in ("nxdi_step_host_ms", "nxdi_step_fetch_wait_ms"))
     assert host["count"] == wait["count"] == len(steps)
@@ -1206,6 +1273,74 @@ def test_span_tree_of_the_split_step(paged_app):
     assert wait["sum"] == pytest.approx(waited, rel=1e-6)
     assert host["sum"] + wait["sum"] == pytest.approx(
         sum(e["dur_ms"] for e in steps.values()), rel=1e-6)
+
+
+def test_step_span_says_what_the_scheduler_decided(paged_app):
+    """``chunk_rows``, ``chunk_dispatches`` and ``decode_rows`` of every
+    ``serving.step`` against the schedule counted by hand: prompts of 40, 4
+    and 22 tokens arriving before steps 1, 3 and 6, a chunk of 16 tokens, a
+    request decoding from the step after its prompt's last chunk."""
+    with TelemetrySession() as tel:
+        _drive_split(paged_app, tel)
+    spans = _span_events(tel)
+    steps = sorted((e for e in spans if e["name"] == "serving.step"), key=lambda e: e["step"])
+    decided = [(e["chunk_rows"], e["chunk_dispatches"], e["decode_rows"]) for e in steps]
+    assert decided[:9] == [
+        (1, 1, 0),  # r0: 16 of 40
+        (1, 1, 0),  # r0: 32 of 40
+        (2, 1, 0),  # r0: its last 8; r1: its 4
+        (0, 0, 2),  # both decode
+        (0, 0, 2),
+        (1, 1, 2),  # r2: 16 of 22
+        (1, 1, 2),  # r2: its last 6
+        (0, 0, 3),
+        (0, 0, 3),
+    ]
+    rows_of = lambda name, k: sum(e["rows"] for e in spans if e["name"] == name and e["step"] == k)
+    for e in steps:
+        assert e["chunk_rows"] == rows_of("serving.prefill_chunk", e["step"])
+        assert e["decode_rows"] == rows_of("serving.decode", e["step"])
+        assert e["chunk_dispatches"] == -(-e["chunk_rows"] // 4)  # four slots: a 4-row program
+    assert sum(e["chunk_rows"] for e in steps) == 3 + 1 + 2  # the chunks of 40, 4 and 22 tokens
+
+
+def test_chunk_rows_counter_counts_live_and_empty_rows():
+    """``nxdi_chunk_rows_total{kind}`` against a schedule: 3 requests
+    prefilling at once are 3 live rows of the 8-row program and 5 empty in
+    one dispatch; 11 at once are 11 live of 16 in two. The step's span says
+    the same."""
+    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
+
+    cfg = _paged_config(tpu=dict(
+        batch_size=16, pa_num_blocks=40,
+        chunked_prefill_config=ChunkedPrefillConfig(max_num_seqs=16, kernel_q_tile_size=16)))
+    app = TpuModelForCausalLM(None, cfg).load(state_dict=make_random_hf_state_dict(cfg))
+    assert app.token_generation_model.chunk_rows == 8
+    tel = TelemetrySession()
+    sess = ServingSession(app, telemetry=tel)
+
+    def rows():
+        family = tel.registry.snapshot()["nxdi_chunk_rows_total"]["samples"]
+        return {s["labels"]["kind"]: s["value"] for s in family}
+
+    for i in range(3):
+        assert sess.add_request(f"a{i}", [7 + i, 3, 11, 2], max_new_tokens=12)
+    sess.step()
+    assert rows() == {"live": 3, "empty": 5}
+    for i in range(11):
+        assert sess.add_request(f"b{i}", [20 + i, 5, 9], max_new_tokens=2)
+    sess.step()
+    assert rows() == {"live": 3 + 11, "empty": 5 + 5}
+    sess.run_to_completion()
+    tel.close()
+    assert rows() == {"live": 14, "empty": 10}  # every prompt took one chunk
+    steps = sorted((e for e in _span_events(tel) if e["name"] == "serving.step"),
+                   key=lambda e: e["step"])
+    assert [(e["chunk_rows"], e["chunk_dispatches"]) for e in steps[:3]] == [(3, 1), (11, 2), (0, 0)]
+    assert steps[1]["decode_rows"] == 3
+    value = lambda name: tel.registry.snapshot()[name]["samples"][0]["value"]
+    assert value("nxdi_prefill_chunk_dispatches_total") == 3
+    assert value("nxdi_chunk_steps_total") == 2
 
 
 def test_padding_counters_of_the_split_step(paged_app):
@@ -1249,7 +1384,7 @@ def test_stopped_session_span_is_the_shared_null_context():
     assert tel.span("a", rows=1) is tel.span("b") is tel_tracing.NULL_SPAN
     with tel.span("a") as sp:
         assert sp.dur_s == 0.0
-    tel.prefill_pass(3, 5)
+    tel.prefill_pass(3, 5, rows=(1, 7))
     tel.decode_pass(1, 4)
     tel.step_timing(1.0, 1.0)
     assert tel.registry.snapshot() == {} and not tel.events and tel.spans is None
@@ -1305,3 +1440,23 @@ def test_step_programs_and_kernels_carry_stable_names(q_len, module, kernel):
     assert f'kernel_name = "{kernel}"' in text
     other = "paged_flash_attention" if q_len is None else "paged_tkg_decode_attention"
     assert f'kernel_name = "{other}"' not in text
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 56: the readers of the three spans and the row counter, held in
+# tier-1 (benchmark/selftest is run by hand): the stack nesting equal to
+# program_span's pairwise one, the phases adding up to the host's time and
+# the three idle metrics to the slice's idle time, on the recorded traces
+# ---------------------------------------------------------------------------
+
+from benchmark.selftest.test_span_phase import (  # noqa: E402,F401
+    fake,
+    test_counter_share,
+    test_idle_under_the_waits_between_the_steps_and_under_the_host,
+    test_recorded_traces_nest_equally_and_add_up,
+    test_self_time_by_phase_and_what_no_leaf_names,
+    test_span_and_module_counts_that_differ_fail_loudly,
+    test_the_recorded_trace_holds_the_three_spans_under_their_parents,
+    test_the_stack_nests_as_the_pairwise_comparison_does,
+    test_what_a_trace_does_not_hold_reads_as_nothing,
+)
