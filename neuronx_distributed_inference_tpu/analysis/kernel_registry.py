@@ -100,6 +100,9 @@ class KernelInstance:
     flops_per_step: int
     dot_stats: List[Tuple[int, int, int]]  # (flops, contract_depth, out_lanes)
     copy_bytes: int = 0  # hand copies a step (KernelSpec.step_copy_bytes)
+    # the scoped-VMEM limit the call asks the compiler for (``vmem_limit_bytes``);
+    # None: the compiler's default, which is the device model's budget
+    vmem_limit: Optional[int] = None
 
     @property
     def key(self) -> str:
@@ -498,9 +501,12 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         ),
     ),
     # the latent pool's two kernels (ops/latent_attention.py): the shape
-    # class is the latent block a chip, as the paged kernels'; ``pages``
-    # follows pages_per_step's rule under the GQA kernels' names (one rule,
-    # so the session's count of walked blocks holds for every pool)
+    # class is the latent block a chip, as the paged kernels'. The decode
+    # kernel's ``pages`` follows pages_per_step's rule under the paged decode
+    # kernel's name; the chunk kernel's tiles are its OWN, under its own name
+    # (latent_attention.blocks_per_group and Q_ROWS: every head shares the one
+    # latent, so the row cap sets the part, which ``n_rep`` does for the GQA
+    # kernel), and the session counts a latent pool's walked blocks by them
     KernelSpec(
         name="paged_latent_decode_attention",
         site=("decode_attention.py", "_common_call"),
@@ -519,6 +525,10 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         fallback="neuronx_distributed_inference_tpu.ops.latent_attention:native_latent_attention",
         parity_test="tests/test_deepseek_reference.py",
         lowering_test="tests/test_chip_compile.py",
+        # ``rows``: the most query rows of a part (heads x one q tile);
+        # ``pages``: the blocks of a group
+        tile_params=("rows", "pages"),
+        sweep=(("rows", (256, 512, 1024)), ("pages", (16, 32, 64))),
         step_copy_bytes=lambda inst: sum(b for _, _, b in inst.scratch[:2]) // 2,
         cases=(KernelCase("blk1x32x512", "bfloat16", _latent_case(8, 128, 256, 32)),),
     ),
@@ -615,6 +625,8 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
         "blk4x32x128": {"pages": 16},
         "blk16x32x128": {"pages": 8},
     },
+    # latent_attention.Q_ROWS, and GROUP_TOKENS (1024) over the block's 32 tokens
+    "paged_latent_flash_attention": {"blk1x32x512": {"rows": 512, "pages": 32}},
     "ragged_paged_attention": {"*": {"tq": 16}},
     "grouped_matmul": {"*": {"tm": 128}},
     "quant_matmul": {"*": {"bn": 256}},
@@ -792,6 +804,8 @@ def instantiate(
     )
     if spec.step_copy_bytes is not None:
         inst.copy_bytes = int(spec.step_copy_bytes(inst))
+    mosaic = dict(eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    inst.vmem_limit = getattr(mosaic, "vmem_limit_bytes", None)
     return inst
 
 

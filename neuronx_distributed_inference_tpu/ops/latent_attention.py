@@ -25,14 +25,18 @@ in which order it meets its keys.
 Two kernels on the plan of ``ops/decode_attention.py``'s paged decode kernel
 and ``ops/paged_flash_attention.py``'s (grid = one ROW a step, an in-kernel
 loop over the row's live groups of ``P`` pool blocks, two VMEM slots, no
-block past a row's last live one copied; ``decode_attention.pages_per_step``
-gives ``P`` for both, so the session's count of the blocks a kernel walks
-holds here too): :func:`paged_latent_decode_attention` for decode widths
-(the mask the native path uses, float32 statistics in registers) and
-:func:`paged_latent_flash_attention` for a prefill chunk (causal by
-position under ``kv_limit``, the q heads stacked on the query axis in parts
-of ``Q_ROWS`` rows, statistics in VMEM from group to group). Both products
-take the cache tile as it is stored (``decode_attention._dot_tile``).
+block past a row's last live one copied): :func:`paged_latent_decode_attention`
+for decode widths (the mask the native path uses, float32 statistics in
+registers; its ``P`` is ``decode_attention.pages_per_step``'s, as the paged
+decode kernel's) and :func:`paged_latent_flash_attention` for a prefill chunk
+(causal by position under ``kv_limit``, the q heads stacked on the query
+axis in parts, statistics in VMEM from group to group). The chunk kernel's
+tiles are its OWN (:data:`Q_ROWS`, :func:`blocks_per_group`, under its name
+in the tuning table): every q head shares the one latent, so the row cap
+alone sets how many heads a part stacks, where the GQA prefill kernel's is
+bounded by ``n_rep``; :func:`kv_blocks_walked` is what the session counts
+for a latent pool. Both products take the cache tile as it is stored
+(``decode_attention._dot_tile``).
 :func:`latent_attend` is what the layer calls: a kernel where its gate
 admits the call, else blocks gathered by the table and attended natively.
 
@@ -57,8 +61,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from neuronx_distributed_inference_tpu.ops import decode_attention as _da
-from neuronx_distributed_inference_tpu.ops import paged_flash_attention as _pf
 from neuronx_distributed_inference_tpu.ops.kernel_mode import TKG_MAX_Q_LEN, on_tpu
+from neuronx_distributed_inference_tpu.ops.tile_defaults import tile_default
 
 try:  # pallas TPU backend
     from jax.experimental.pallas import tpu as pltpu
@@ -71,6 +75,46 @@ NEG_INF = _da.NEG_INF
 #: by them), the tuning table's and the kernel registry's
 DECODE_KERNEL = "paged_latent_decode_attention"
 CHUNK_KERNEL = "paged_latent_flash_attention"
+
+#: the chunk kernel's tiles where the tuning table has no entry under its
+#: name for the pool's block: the most query rows of one product (a part
+#: stacks as many of the q heads as divide ``Hq`` and fit, x one q tile: every
+#: head shares the one latent, so this cap and nothing else sets the part)
+#: and the tokens a group of pool blocks holds. The matrix unit binds the
+#: kernel, and a key tile it has loaded serves a part's rows: PERF.md, PR 57,
+#: has the sweep (256 / 512 / 1024 rows x 512 / 1024 / 2048 tokens, at 64 and
+#: at 16 heads a latent)
+Q_ROWS = 512
+GROUP_TOKENS = 1024
+
+
+def _tile(param: str, fallback: int, n_kv: int, bs: int, head_dim: int, cache_dtype) -> int:
+    """A tile of the chunk kernel under its own name in the tuning table, by
+    the pool block's shape a chip (``blk1x32x512``: one latent of 512 a token)."""
+    shape_class = f"blk{n_kv}x{bs}x{head_dim}"
+    return tile_default(CHUNK_KERNEL, shape_class, jnp.dtype(cache_dtype).name, param, fallback)
+
+
+def blocks_per_group(n_kv: int, bs: int, head_dim: int, cache_dtype, max_blocks: int) -> int:
+    """Pool blocks the chunk kernel copies and attends per pass of its loop
+    (its ``P``): a power of two (the kernel takes a group's live count apart
+    by bits), never more than the block table is wide. The signature is
+    ``paged_flash_attention.blocks_per_group``'s (``n_kv`` is 1 a chip: one
+    latent a token), the rule the latent kernel's own."""
+    p = _tile("pages", GROUP_TOKENS // bs, n_kv, bs, head_dim, cache_dtype)
+    p = max(1, min(p, max_blocks))
+    return 1 << (p.bit_length() - 1)
+
+
+def kv_blocks_walked(
+    live_blocks, max_blocks: int, *, n_kv: int, bs: int, head_dim: int, cache_dtype
+) -> int:
+    """Block-table entries the chunk kernel attends for the rows of a chunk
+    pass whose causal contexts hold ``live_blocks`` (one count a row) blocks
+    of a table ``max_blocks`` wide: whole groups up to each row's frontier.
+    Host code calls this (``ServingSession`` counts it for a latent pool)."""
+    P = blocks_per_group(n_kv, bs, head_dim, cache_dtype, max_blocks)
+    return sum(-(-n // P) * P for n in live_blocks)
 
 
 def use_latent_kernel(c_cache, kr_cache, q_len: int, kv_width: int) -> bool:
@@ -487,12 +531,13 @@ def paged_latent_flash_attention(
     rows, lanes = kr_cache.shape[3:]
     pack = bs // rows
     MB = block_table.shape[1]
-    P = _pf.blocks_per_group(1, bs, r, c_cache.dtype, MB)
+    P = blocks_per_group(1, bs, r, c_cache.dtype, MB)
     NG = -(-MB // P)
     tq = -(-min(tq, Sq) // 8) * 8  # whole sublane tiles
     nq = -(-Sq // tq)
-    # the q heads a part stacks: the most that divide Hq and keep it within Q_ROWS
-    hp = max(d for d in range(1, Hq + 1) if Hq % d == 0 and (d == 1 or d * tq <= _pf.Q_ROWS))
+    # the q heads a part stacks: the most that divide Hq and keep it within its row cap
+    q_rows = _tile("rows", Q_ROWS, 1, bs, r, c_cache.dtype)
+    hp = max(d for d in range(1, Hq + 1) if Hq % d == 0 and (d == 1 or d * tq <= q_rows))
     R, NP = hp * tq, Hq // hp * nq
 
     pad_q = nq * tq - Sq

@@ -282,9 +282,11 @@ class ServingSession:
                 self.sparse_layers, self.sparse_topk = paged_layers, app.builder.indexer_spec().topk
             # what the paged kernels attend for a row (host-known: it follows
             # from the pool's shape a head shard, as the kernels' does): the
-            # decode kernel's walk and the prefill kernel's
+            # decode kernel's walk and that of the chunk kernel the pool
+            # serves (a latent pool's picks its own groups)
             from neuronx_distributed_inference_tpu.ops import (
                 decode_attention,
+                latent_attention,
                 paged_flash_attention,
             )
 
@@ -296,8 +298,9 @@ class ServingSession:
             self._kv_blocks_walked = functools.partial(
                 decode_attention.kv_blocks_walked, **shape
             )
+            chunk_kernel = latent_attention if self.latent_layers else paged_flash_attention
             self._chunk_kv_blocks_walked = functools.partial(
-                paged_flash_attention.kv_blocks_walked, **shape
+                chunk_kernel.kv_blocks_walked, **shape
             )
             # and what the paged KV write of a chunk pass moves, where it
             # moves whole blocks

@@ -122,11 +122,12 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape_class, dtype):
 def test_latent_chunk_kernel_takes_a_selection_at_glm5s_widths(one_chip, q_len):
     """``paged_latent_flash_attention`` under a selection's predicate at
     glm-5's widths (8 rows, 64 heads over a latent of 512 + 64, the 16896
-    bucket: 32 parts of 256 score rows, a row's predicate slab 2.1 MB of int8
-    at 128 queries, int32 at a q tile under 32 rows) compiles for a v5e with
-    what it asks of VMEM (``latent_attend`` gives it every chunk width under
-    a selection), and the decode kernel is not asked for 8 positions x 64
-    heads (its mask slab would be 34 MB a row)."""
+    bucket) at the kernel's own tiles (16 parts of 512 score rows, 17 groups
+    of 32 blocks; a row's predicate slab 2.1 MB of int8 at 128 queries, int32
+    at a q tile under 32 rows) compiles for a v5e with what it asks of VMEM
+    (``latent_attend`` gives it every chunk width under a selection), and the
+    decode kernel is not asked for 8 positions x 64 heads (its mask slab
+    would be 34 MB a row)."""
     from neuronx_distributed_inference_tpu.ops import latent_attention as la
 
     B, H, r, rope, bs, W = 8, 64, 512, 64, 32, 16896
@@ -144,7 +145,8 @@ def test_latent_chunk_kernel_takes_a_selection_at_glm5s_widths(one_chip, q_len):
         ).lower(*args).compile()
     text = compiled.as_text()
     assert la.CHUNK_KERNEL in text and la.DECODE_KERNEL not in text
-    assert ("s8[8,33,2,1,128,256]" in text) == (q_len == 128)
+    # the predicate in the kernel's column order: 17 groups x 2 lane groups x (32 blocks x 16 rows)
+    assert ("s8[8,17,2,1,128,512]" in text) == (q_len == 128)
 
 
 def test_flash_compiles_at_8b_head_dim(one_chip):

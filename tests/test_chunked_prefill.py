@@ -142,6 +142,32 @@ def test_paged_flash_kernel_parity(case):
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
 
 
+#: the registered pool blocks of the GQA prefill kernel (analysis/kernel_registry)
+#: and its group of blocks at each, a table of 256 blocks wide
+GQA_GROUPS = [
+    (8, 128, 64, "bfloat16", 1), (8, 128, 64, "int8", 1), (8, 32, 128, "bfloat16", 16),
+    (8, 32, 128, "int8", 16), (2, 32, 128, "bfloat16", 16), (4, 32, 128, "bfloat16", 16),
+    (16, 32, 128, "bfloat16", 8),
+    # a latent pool's block under THIS kernel's name: what the latent chunk
+    # kernel borrowed until it got a rule of its own (ops/latent_attention.py)
+    (1, 32, 512, "bfloat16", 16),
+]
+
+
+@pytest.mark.parametrize("n_kv,bs,head_dim,dtype,pages", GQA_GROUPS)
+def test_the_gqa_prefill_kernels_tiles_are_its_own(n_kv, bs, head_dim, dtype, pages):
+    """The paged prefill kernel's group (512 tokens, at most 1 MiB a stream)
+    and its part's row cap (``Q_ROWS`` 256: bounded by ``n_rep``) stand as they
+    stood, whatever the latent chunk kernel takes for itself."""
+    from neuronx_distributed_inference_tpu.ops import paged_flash_attention as pf
+
+    assert pf.blocks_per_group(n_kv, bs, head_dim, dtype, 256) == pages
+    assert pf.Q_ROWS == 256
+    live = [3, 40, 256]
+    walked = pf.kv_blocks_walked(live, 256, n_kv=n_kv, bs=bs, head_dim=head_dim, cache_dtype=dtype)
+    assert walked == (3 * 256 if head_dim % 128 else sum(-(-n // pages) * pages for n in live))
+
+
 # ---------------------------------------------------------------------------
 # prefix caching
 # ---------------------------------------------------------------------------

@@ -14,6 +14,8 @@ from ``system.make_weights``. ``benchmark/selftest/test_correct_kimi.py``
 proves the benchmark's RULE on the bf16 model with faults planted.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -401,6 +403,126 @@ def test_the_chunk_kernel_attends_a_selection(case):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     np.testing.assert_allclose(got[picks], want[picks], rtol=0, atol=atol)
     assert not got[~picks].any()
+
+
+@functools.lru_cache(maxsize=None)
+def _random_pool(r, w, bs, MB, B, dtype):
+    """A two-layer pool of random latents and rotary keys, every block filled,
+    and tables that scatter ``B`` rows of ``MB`` blocks over it."""
+    rng = np.random.default_rng(53)
+    pack, NB = 128 // w, B * MB
+    c = jnp.asarray(rng.standard_normal((2, NB + 1, 1, bs, r)), dtype)
+    kr = jnp.asarray(rng.standard_normal((2, NB + 1, 1, bs // pack, pack * w)), dtype)
+    return c, kr, jnp.asarray(1 + rng.permutation(NB).reshape(B, MB), jnp.int32)
+
+
+#: the chunk kernel at its OWN tiles (a part of up to 512 query rows, a group
+#: of 32 blocks = 1024 tokens: what ``la.Q_ROWS`` / ``la.GROUP_TOKENS`` give a
+#: pool of blocks of 32, and the tuning table the registered shape): the
+#: rows' contexts BEFORE the pass as a function of the q width (None: a padded
+#: row) and the table's width in blocks
+_TILE_ROWS = {
+    # 37+ live blocks (no multiple of the group) | padded, between two live
+    # rows | ends inside its first group | ends with the table, 1.5 groups wide
+    "table_of_48_blocks": (48, lambda q: [36 * 32 + 7, None, 200 - q, 48 * 32 - q]),
+    # narrower than one group: the group IS the table, 8 blocks
+    "table_of_8_blocks": (8, lambda q: [100, None, 8 * 32 - q]),
+}
+_TILE_CASES = [
+    (heads, q, selected, "table_of_48_blocks")
+    for heads in (64, 16) for q in (8, 16, 64, 128) for selected in (False, True)
+] + [(heads, 64, selected, "table_of_8_blocks") for heads in (64, 16) for selected in (False, True)]
+
+
+@pytest.mark.parametrize(
+    "heads,q,selected,rows", _TILE_CASES,
+    ids=[f"h{h}-q{q}-{'selection' if s else 'dense'}-{rows}" for h, q, s, rows in _TILE_CASES],
+)
+def test_the_chunk_kernel_at_its_own_tiles_is_the_native_attention(heads, q, selected, rows):
+    """``paged_latent_flash_attention`` (interpret mode) at the tiles its own
+    rule gives, against the native attention over the gathered table: at 64
+    and at 16 heads a latent (parts of 4 heads x 128 positions, of all the
+    heads x 8), with and without a predicate, over a row whose live blocks are
+    no multiple of the group, one that ends inside its first group, a padded
+    row between two live ones, a table no multiple of the group wide or
+    narrower than one group, and under a predicate queries that pick nothing
+    in the first group they meet."""
+    r, w, bs = 128, 64, 32
+    MB, contexts = _TILE_ROWS[rows]
+    contexts = contexts(q)
+    B, W = len(contexts), MB * bs
+    # the tiles are the registered shape's: the fallback and the table agree
+    assert la.blocks_per_group(1, bs, r, jnp.float32, 528) == la.blocks_per_group(
+        1, bs, 512, jnp.bfloat16, 528) == la.GROUP_TOKENS // bs == 32
+    assert la.blocks_per_group(1, bs, r, jnp.float32, MB) == min(32, MB)
+    c, kr, tables = _random_pool(r, w, bs, MB, B, jnp.float32)
+    rng = np.random.default_rng(59)
+    q_c = jnp.asarray(rng.standard_normal((B, q, heads, r)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((B, q, heads, w)), jnp.float32)
+    live_row = np.array([n is not None for n in contexts])
+    positions = np.array([[(n or 0) + t for t in range(q)] for n in contexts])
+    positions = np.where(live_row[:, None], positions, 0)
+    kv_limit = jnp.asarray(np.where(live_row, positions[:, -1] + 1, 0), jnp.int32)
+    live = (np.arange(W)[None, None, :] <= positions[:, :, None]) & live_row[:, None, None]
+    chosen = live
+    if selected:
+        chosen = live & (rng.random((B, q, W)) < 0.3)
+        group = min(32, MB) * bs
+        if W > group:  # every other query of row 0: nothing in the first group it meets
+            chosen[0, ::2, :group] = False
+        chosen[0, :, contexts[0]] = True  # and something after it
+    scale = 0.3 * (r + w) ** -0.5
+    li = jnp.int32(1)
+    got = la.paged_latent_flash_attention(
+        q_c, q_pe, c, kr, li, tables, jnp.asarray(positions, jnp.int32), kv_limit,
+        jnp.asarray(chosen) if selected else None, scale=scale, interpret=True)
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    c_all, kr_all = bk.read_latent_cache_at_layer(c, kr, li, tables)
+    picks = chosen.any(axis=-1)
+    for b in np.flatnonzero(live_row):  # a row at a time: (heads, q, W) float32 scores
+        want = la.native_latent_attention(
+            q_c[b:b + 1], q_pe[b:b + 1], c_all[b:b + 1], kr_all[b:b + 1],
+            jnp.asarray(chosen[b:b + 1])[:, None], scale)
+        np.testing.assert_allclose(got[b][picks[b]], np.asarray(want)[0][picks[b]], rtol=0, atol=2e-6)
+    assert not got[~picks].any()  # a padded row, a query that picks nothing: zeros
+
+
+def test_the_chunk_kernel_reads_its_tiles_from_the_table_at_the_registered_shape():
+    """The registered shape class (``blk1x32x512``, bfloat16: both cells' pool)
+    takes its tiles from the tuning table under the kernel's own name, and at
+    them the kernel is the native attention to bf16's rounding: 16 heads x 32
+    positions = one part of 512 rows, groups of 32 blocks over a table of 40."""
+    from neuronx_distributed_inference_tpu.ops.tile_defaults import table_entry
+
+    r, w, bs, MB, heads, q = 512, 64, 32, 40, 16, 32
+    tiles = table_entry(la.CHUNK_KERNEL, f"blk1x{bs}x{r}", "bfloat16")["tiles"]
+    assert tiles == {"rows": la.Q_ROWS, "pages": la.GROUP_TOKENS // bs}
+    contexts = [33 * bs + 5, 40]
+    c, kr, tables = _random_pool(r, w, bs, MB, len(contexts), jnp.bfloat16)
+    rng = np.random.default_rng(61)
+    q_c = jnp.asarray(rng.standard_normal((2, q, heads, r)) * 0.5, jnp.bfloat16)
+    q_pe = jnp.asarray(rng.standard_normal((2, q, heads, w)), jnp.bfloat16)
+    positions = jnp.asarray([[n + t for t in range(q)] for n in contexts], jnp.int32)
+    mask = jnp.arange(MB * bs)[None, None, None, :] <= positions[:, None, :, None]
+    scale = (r + w) ** -0.5
+    li = jnp.int32(1)
+    seen = {}
+    call = la._da._common_call
+
+    def common_call(kernel, *a, **k):
+        seen["P"], seen["parts"] = kernel.keywords["P"], k["out_shape"].shape[1:3]
+        return call(kernel, *a, **k)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(la._da, "_common_call", common_call)
+        got = la.paged_latent_flash_attention.__wrapped__(
+            q_c, q_pe, c, kr, li, tables, positions, positions[:, -1] + 1, scale=scale, interpret=True)
+    assert seen == {"P": tiles["pages"], "parts": (1, tiles["rows"])}
+    want = la.native_latent_attention(
+        q_c, q_pe, *bk.read_latent_cache_at_layer(c, kr, li, tables), mask, scale)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=0, atol=2e-2)
 
 
 @pytest.mark.parametrize("step,kernel", [(1, "decode"), (8, "chunk"), (16, "chunk"), (40, "chunk")])

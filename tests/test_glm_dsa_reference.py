@@ -15,6 +15,8 @@ cache, no line of the program's code). Small size, CPU, weights from
 the benchmark's RULE on the bf16 model with faults planted.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -471,11 +473,14 @@ def test_the_chunk_program_attends_its_picked_keys_inside_the_chunk_kernel(monke
 def test_the_chunk_walk_counter_is_the_kernels_walk_past_index_topk(monkeypatch):
     """``nxdi_chunk_kv_blocks_total`` for a model with an indexer: ``live``
     the blocks the prefilling rows' contexts hold, ``walked`` the whole groups
-    of ``blocks_per_group`` blocks up to each row's last live one, which is
-    what the chunk kernel copies and attends under a selection too (its
-    ``end`` operand, read here from the call): not the kv bucket's width."""
+    of the LATENT chunk kernel's own ``blocks_per_group`` blocks up to each
+    row's last live one, which is what the kernel copies and attends under a
+    selection too: the session asks the kernel its pool serves (one function,
+    two callers), and the call itself is read here for its ``P`` and its
+    ``end`` operand. Not the kv bucket's width, and not the GQA prefill
+    kernel's group."""
     from neuronx_distributed_inference_tpu.ops import latent_attention
-    from neuronx_distributed_inference_tpu.ops.paged_flash_attention import blocks_per_group
+    from neuronx_distributed_inference_tpu.ops.latent_attention import blocks_per_group
     from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
 
     monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
@@ -484,6 +489,7 @@ def test_the_chunk_walk_counter_is_the_kernels_walk_past_index_topk(monkeypatch)
     served.init_kv_cache()
     tel = TelemetrySession(enabled=True)
     s = ServingSession(served, telemetry=tel)
+    assert s._chunk_kv_blocks_walked.func is latent_attention.kv_blocks_walked
     rng = np.random.default_rng(23)
     lengths = (3 * chunk + 8, 2 * chunk)
     for i, n in enumerate(lengths):
@@ -510,7 +516,7 @@ def test_the_chunk_walk_counter_is_the_kernels_walk_past_index_topk(monkeypatch)
     call = latent_attention._da._common_call
 
     def common_call(kernel, *a, operands, **k):
-        seen["end"] = operands[0][2]
+        seen["P"], seen["end"] = kernel.keywords["P"], operands[0][2]
         return call(kernel, *a, operands=operands, **k)
 
     monkeypatch.setattr(latent_attention._da, "_common_call", common_call)
@@ -519,6 +525,47 @@ def test_the_chunk_walk_counter_is_the_kernels_walk_past_index_topk(monkeypatch)
         jnp.zeros((1, 128 // bs), jnp.int32), positions, jnp.asarray([n], jnp.int32),
         jnp.ones((1, chunk, 128), bool), scale=1.0, interpret=True)
     assert int(seen["end"][0]) == -(-n // bs) == live[2][0]
+    # the session's count of that pass is NG_live x P of the call's own P
+    groups = -(-int(seen["end"][0]) // seen["P"])
+    assert s._chunk_kv_blocks_walked(live[2], 128 // bs) == groups * seen["P"] == 8
+
+
+#: (blocks of a pool, latent width, pool dtype, table width): the registered
+#: pool of both latent cells at glm-5's two wide buckets and kimi's widest,
+#: and tier-1's float32 pools
+@pytest.mark.parametrize("bs,r,dtype,MB", [
+    (32, 512, "bfloat16", 528), (32, 512, "bfloat16", 384), (32, 512, "bfloat16", 256),
+    (32, 128, "float32", 48), (16, 128, "float32", 8),
+])
+def test_the_sessions_chunk_walk_is_the_latent_kernels_own_group(bs, r, dtype, MB):
+    """For a latent pool the walked-block count of a chunk pass is ``NG_live x
+    P`` of the ``P`` the kernel call computes for the same pool and table
+    (``latent_attention.kv_blocks_walked`` over ``blocks_per_group``: the
+    kernel's launch reads the same function), whatever the GQA prefill
+    kernel's group is at that block shape."""
+    from neuronx_distributed_inference_tpu.ops import latent_attention as la
+
+    B, q, heads = 3, 16, 2
+    live = [MB, 1, -(-MB // 3)]  # blocks a row's context holds with the chunk in
+    seen = {}
+
+    def common_call(kernel, *, operands, out_shape, **k):
+        seen["P"], seen["table"] = kernel.keywords["P"], operands[0][1].shape
+        return jnp.zeros(out_shape.shape, out_shape.dtype)
+
+    sds = jax.ShapeDtypeStruct
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(la._da, "_common_call", common_call)
+        jax.eval_shape(
+            functools.partial(la.paged_latent_flash_attention.__wrapped__, scale=1.0),
+            sds((B, q, heads, r), dtype), sds((B, q, heads, 64), dtype),
+            sds((2, 9, 1, bs, r), dtype), sds((2, 9, 1, bs // 2, 128), dtype), sds((), jnp.int32),
+            sds((B, MB), jnp.int32), sds((B, q), jnp.int32), sds((B,), jnp.int32))
+    P = seen["P"]
+    assert P == la.blocks_per_group(1, bs, r, dtype, MB) == min(1024 // bs, 1 << (MB.bit_length() - 1))
+    assert seen["table"] == (B, -(-MB // P) * P)  # whole groups
+    walked = la.kv_blocks_walked(live, MB, n_kv=1, bs=bs, head_dim=r, cache_dtype=dtype)
+    assert walked == sum(-(-n // P) * P for n in live)
 
 
 def test_the_session_counts_what_the_selection_scores_and_attends(app):

@@ -72,6 +72,15 @@ def test_kern701_silent_within_budget():
     assert ka.vmem_findings("k/s/bf16", "ops/x.py", 16 * 1024**2, 16 * 1024**2) == []
 
 
+@pytest.mark.parametrize("asked,fires", [(None, True), (16 * 1024**2 + 1, True), (60 * 1024**2, False)])
+def test_kern701_holds_a_call_to_the_scoped_limit_it_asks_for(asked, fires):
+    """A call that asks the compiler for its own ``vmem_limit_bytes`` (the
+    latent chunk kernel: its q, out and accumulators of every part stand in
+    VMEM) is budgeted against that, not against the default."""
+    fs = ka.vmem_findings("k/s/bf16", "ops/x.py", 17 * 1024**2, 16 * 1024**2, asked)
+    assert [f.rule for f in fs] == (["KERN701"] if fires else [])
+
+
 def test_kern701_census_drift_and_missing():
     census = {
         "a/p/bf16": {"location": "ops/a", "vmem_bytes": 10, "grid": [1],
@@ -273,6 +282,29 @@ def test_committed_table_covers_registry():
             if entry["provenance"] == "hand_picked" and hand:
                 for p, v in hand.items():
                     assert entry["tiles"][p] == v, (s.name, c.shape_class, p)
+
+
+def test_the_latent_chunk_kernels_tiles_are_pinned_in_code_registry_and_table(monkeypatch):
+    """The latent chunk kernel's tiles under its OWN name: the in-code
+    fallback (``latent_attention.Q_ROWS``, ``GROUP_TOKENS``), the registry's
+    ``HAND_PICKED`` mirror and the committed ``measured`` entry agree, so a
+    bare checkout (no table) launches the kernel the table's readers measured;
+    and nothing of it is the GQA prefill kernel's."""
+    from neuronx_distributed_inference_tpu.analysis import kernel_registry as kr
+    from neuronx_distributed_inference_tpu.ops import latent_attention as la
+    from neuronx_distributed_inference_tpu.ops import paged_flash_attention as pf
+    from neuronx_distributed_inference_tpu.ops import tile_defaults
+
+    hand = kr.hand_picked_tiles(la.CHUNK_KERNEL, "blk1x32x512")
+    assert hand == {"rows": la.Q_ROWS, "pages": la.GROUP_TOKENS // 32} == {"rows": 512, "pages": 32}
+    entry = ka.load_tuning_table()["kernels"][la.CHUNK_KERNEL]["blk1x32x512"]["bfloat16"]
+    assert entry == {"provenance": "measured", "tiles": hand}
+    (spec,) = [s for s in kr.REGISTRY if s.name == la.CHUNK_KERNEL]
+    assert spec.tile_params == ("rows", "pages")
+    with_table = la.blocks_per_group(1, 32, 512, "bfloat16", 528)
+    monkeypatch.setattr(tile_defaults, "_load_table", lambda: {})
+    assert la.blocks_per_group(1, 32, 512, "bfloat16", 528) == with_table == 32
+    assert pf.blocks_per_group(1, 32, 512, "bfloat16", 528) == 16 and pf.Q_ROWS == 256
 
 
 # ---------------------------------------------------------------------------

@@ -673,7 +673,8 @@ def test_cli_full_json_schema(capsys):
     assert kern["n_sites"] > 0 and kern["n_registered"] >= kern["n_sites"]
     for key, row in kern["instances"].items():
         assert key.count("/") == 2, key  # kernel/shape_class/dtype
-        assert 0 < row["vmem_bytes"] <= kern["vmem_budget"]
+        # the default scoped budget, or the limit the call itself asks for
+        assert 0 < row["vmem_bytes"] <= max(kern["vmem_budget"], row["vmem_limit"] or 0)
         assert row["flops_per_step"] > 0
         assert row["bound"] in ("compute", "memory")
 
