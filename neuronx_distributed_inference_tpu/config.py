@@ -691,8 +691,11 @@ class TpuConfig:
                 )
             if self.sliding_window or self.attention_chunk_size:
                 raise NotImplementedError(
-                    "serving_ragged implements the plain causal+prefix mask "
-                    "only (no sliding-window/chunked attention)"
+                    "serving_ragged: the ragged paged kernel "
+                    "(ops/ragged_paged_attention.py) implements the plain "
+                    "causal+prefix mask, with no lower frontier and no chunk "
+                    "rule; a window rides the split step's two paged kernels "
+                    "(models/base.paged_attend)"
                 )
             if (
                 self.attention_dp_degree > 1
@@ -1032,6 +1035,62 @@ def validate_sparse_attention(tc: "TpuConfig") -> None:
         if flag:
             raise SparseAttentionError(
                 f"a model with learned sparse attention (an indexer's top-k) cannot run with {why}"
+            )
+
+
+class TwoLifetimeCacheError(NotImplementedError):
+    """An option that cannot serve a model whose paged cache has two
+    lifetimes (layers that attend a window keep a ring of blocks a slot,
+    layers that attend the whole context keep the context: models/mellum.py)
+    was set for one."""
+
+
+def validate_two_lifetime_cache(tc: "TpuConfig") -> None:
+    """Refuse, for a model whose builder declares ``WINDOW_KV`` layers beside
+    ``PAGED_KV`` ones (``cache_layers()``), what is not built for a cache of
+    two lifetimes, each naming its site: none is a silent wrong answer."""
+    speculation = (
+        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
+        or tc.enable_eagle_speculation or tc.serving_spec_ragged
+    )
+    refusals = (
+        (not (tc.is_block_kv_layout and tc.is_chunked_prefill and tc.is_continuous_batching),
+         "a contiguous cache or whole-prompt prefill (generate(), is_block_kv_layout / "
+         "is_chunked_prefill / is_continuous_batching unset): a window layer's ring holds "
+         "window + one prefill chunk and no whole prompt; the contiguous cache's own "
+         "interleaved full + ring form (ModelSpec.ring_window, models/gpt_oss.py) is a third "
+         "cache and is not wired to this stack"),
+        (tc.is_prefix_caching,
+         "is_prefix_caching: a cached prefix has the full layers' blocks "
+         "(modules/block_kvcache.PrefixCachingAllocator) and nothing of a window layer's ring, "
+         "which another request's slot has overwritten since"),
+        (speculation,
+         "speculation (speculation_length, medusa, fused, EAGLE, serving_spec_ragged): a ring "
+         "sized for one prefill chunk is not held to a reference at a draft's width, and a "
+         "rejected draft's writes may have wrapped over keys the row still attends"),
+        (tc.serving_ragged,
+         "serving_ragged: the ragged mixed step (models/base.py mixed_forward, "
+         "ops/ragged_paged_attention.py) has one table a row and no lower frontier"),
+        (tc.kv_quantized,
+         "kv_cache_dtype quantisation: the ring is kept unquantised beside the pool "
+         "(modules/block_kvcache.WindowRing) and the decode kernel's in-kernel write serves "
+         "an unquantised pool alone"),
+        (tc.tp_degree * tc.ep_degree * tc.cp_degree * tc.attention_dp_degree
+         * tc.data_parallel_degree > 1,
+         "tp/ep/cp/dp degree > 1: the ring is replicated (window_ring_pspecs) and its "
+         "kernels are not launched per head shard"),
+        (tc.is_prefill_stage,
+         "is_prefill_stage: the prefill hand-off (runtime/disaggregated.py) carries one "
+         "contiguous line a layer and no ring"),
+        (tc.sliding_window or tc.attention_chunk_size,
+         "TpuConfig.sliding_window / attention_chunk_size: the layers' windows are the "
+         "model's own (layer_types), one mask rule a layer"),
+    )
+    for flag, why in refusals:
+        if flag:
+            raise TwoLifetimeCacheError(
+                f"a model with window and full attention layers (a paged cache of two "
+                f"lifetimes) cannot be served with {why}"
             )
 
 

@@ -23,6 +23,7 @@ The KV cache is donated by the runner so XLA updates it in place
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 from typing import Callable, Optional, Tuple
@@ -477,6 +478,8 @@ def paged_write_attend(
     positions: jax.Array,
     spec: ModelSpec,
     sink: Optional[jax.Array] = None,
+    window: Optional[int] = None,
+    kind: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The paged KV write of a pass and its attention over the pool, for any
     layer that pages K/V at ``(H_kv, D)``: ``(attn_out, k_cache, v_cache)``.
@@ -485,7 +488,13 @@ def paged_write_attend(
     (a one-token decode pass that rides the paged decode kernel), ONE call
     under ``layer.attn`` in which the kernel places the token in the block it
     holds for the row anyway and attends as write-then-attend does. Called
-    under no scope of the caller's (:func:`decoder_layer`, models/zaya.py)."""
+    under no scope of the caller's (:func:`decoder_layer`, models/zaya.py).
+
+    ``window``: the layer's OWN window, static (None: it attends its whole
+    causal context); ``mask`` already holds it, and the paged prefill kernel
+    takes it as a lower frontier (:func:`paged_attend`). ``kind``: a stack
+    that mixes kinds of attention layer names each call's kind
+    (telemetry/device_scopes.ATTN_KINDS), a scope inside ``layer.attn``."""
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
         batch_is_sharded,
         update_block_cache_at_layer,
@@ -508,7 +517,7 @@ def paged_write_attend(
             dispatch_paged_tkg_decode,
         )
 
-        with jax.named_scope("layer.attn"):
+        with _attn_scope(kind):
             return dispatch_paged_tkg_decode(
                 q, k_cache, v_cache, layer_idx, block_table, mask, sink,
                 (k, v, slot_mapping),
@@ -518,11 +527,26 @@ def paged_write_attend(
         k_cache, v_cache = update_block_cache_at_layer(
             k_cache, v_cache, k, v, layer_idx, slot_mapping
         )
-    with jax.named_scope("layer.attn"):
+    with _attn_scope(kind):
         attn_out = paged_attend(
-            q, k_cache, v_cache, layer_idx, mask, block_table, kv_limit, positions, spec, sink
+            q, k_cache, v_cache, layer_idx, mask, block_table, kv_limit, positions, spec, sink,
+            window=window,
         )
     return attn_out, k_cache, v_cache
+
+
+@contextmanager
+def _attn_scope(kind: Optional[str]):
+    """``layer.attn``, and inside it the scope of one KIND of attention layer
+    where a stack names it (``layer.attn.window`` / ``layer.attn.full``:
+    telemetry/device_scopes.ATTN_KINDS). None: ``layer.attn`` alone, the
+    names every other model's ops always had."""
+    with jax.named_scope("layer.attn"):
+        if kind is None:
+            yield
+        else:
+            with jax.named_scope(f"layer.attn.{kind}"):
+                yield
 
 
 def paged_attend(
@@ -536,6 +560,7 @@ def paged_attend(
     positions: jax.Array,
     spec: ModelSpec,
     sink: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention of the split serving step over the paged cache, for any
     layer that pages K/V at ``(H_kv, D)``: a prefill chunk rides the paged
@@ -543,7 +568,14 @@ def paged_attend(
     where its gate admits the call (ops/kernel_mode); else blocks are
     gathered by the table and attended natively. Shared by
     :func:`decoder_layer` and the stacks that compute q, k, v their own way
-    (models/zaya.py)."""
+    (models/zaya.py).
+
+    A layer that attends a WINDOW says so with ``window`` (static, its own):
+    ``mask`` holds the window already (:func:`build_mask`), the decode kernel
+    reads a row's first and last live block off the mask and walks the
+    groups between them, and the prefill kernel takes ``window`` as a lower
+    frontier beside ``kv_limit``: neither copies a block group that lies
+    wholly behind a row's window."""
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
         read_block_cache_at_layer,
     )
@@ -558,23 +590,19 @@ def paged_attend(
     # inside); attention-DP shards the BATCH around the attention
     # instead, and keeps the native path
     dp_shards = spec.attention_dp * spec.data_parallel
-    # the paged kernel implements the plain causal+prefix mask only: the
-    # MODEL must have no windowed/chunked attention anywhere, including
-    # inside layer groups (a group's mask never reaches the kernel)
-    plain_model = (
-        not spec.sliding_window
-        and not spec.attention_chunk_size
-        and (
-            spec.layer_groups is None
-            or all(
-                g.sliding_window is None and g.attention_chunk_size is None
-                for g in spec.layer_groups
-            )
-        )
-    )
+    # the paged prefill kernel's mask is causal + prefix, under a lower
+    # frontier where the CALL names its layer's window. It has no chunked
+    # attention; and a stack that declares windows in its spec but runs
+    # layers that do not say theirs (the prestacked form selects a flavor's
+    # MASK in the scan: no static window reaches this call) keeps the
+    # native path, which attends by the mask
+    groups = spec.layer_groups or ()
+    chunked = spec.attention_chunk_size or any(g.attention_chunk_size for g in groups)
+    windowed = spec.sliding_window or any(g.sliding_window for g in groups)
+    frontier_known = not chunked and (window is not None or not windowed)
     if (
         sink is None
-        and plain_model
+        and frontier_known
         and dp_shards == 1
         and _use_paged_flash(aspec, Sq)
     ):
@@ -600,7 +628,7 @@ def paged_attend(
             scale=aspec.softmax_scale,
             n_rep=aspec.num_heads // aspec.num_kv_heads,
             k_scale=ks, v_scale=vs,
-            interpret=kernel_interpret(),
+            interpret=kernel_interpret(), window=window,
         )
     else:
         from neuronx_distributed_inference_tpu.ops.decode_attention import (
@@ -693,6 +721,9 @@ def decoder_layer(
     # per-phase paths (phase == PHASE_MIXED; mask is unused — the kernel
     # derives it from the descriptors)
     ragged_rows: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+    # a stack that mixes kinds of attention layer names this one's
+    # (paged_write_attend: a scope inside ``layer.attn``)
+    attn_kind: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One decoder layer (reference NeuronLlamaDecoderLayer, modeling_llama.py:1188).
 
@@ -782,7 +813,8 @@ def decoder_layer(
     sink = layer_params["self_attn"].get("sink", {}).get("weight") if aspec.has_sink else None
     if paged_step:
         attn_out, k_cache, v_cache = paged_write_attend(
-            q, k, v, k_cache, v_cache, layer_idx, mask, block_inputs, positions, spec, sink
+            q, k, v, k_cache, v_cache, layer_idx, mask, block_inputs, positions, spec, sink,
+            window=window, kind=attn_kind,
         )
     else:
         with jax.named_scope("layer.attn"):

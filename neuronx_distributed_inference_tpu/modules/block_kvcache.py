@@ -121,8 +121,12 @@ class BlockKVCache:
 
 #: what a layer keeps between steps, as a model's builder declares it
 #: (``builder.cache_layers()``, one entry per layer): a paged K/V stream of
-#: ``(H_kv, D)`` per token, or a constant-size state per serving slot
+#: ``(H_kv, D)`` per token whose LIFETIME is the context (``PAGED_KV``: blocks
+#: from the allocator's pool, as many as the context is long) or a window
+#: (``WINDOW_KV``: a ring of blocks a slot, :class:`WindowRing`), or a
+#: constant-size state per serving slot
 PAGED_KV = "paged_kv"
+WINDOW_KV = "window_kv"
 SLOT_STATE = "slot_state"
 
 
@@ -143,6 +147,103 @@ class HybridBlockCache(BlockKVCache):
 
     #: the fields that are NOT streams of blocks (runtime/faults.fill_kv_rows)
     SLOT_FIELDS = ("state",)
+
+
+def window_ring_blocks(window: int, q_len: int, block_size: int) -> int:
+    """Blocks of the ring a slot holds in a layer that attends a ``window``:
+    ``window + q_len`` tokens rounded up to blocks, plus one, ``q_len`` the
+    widest pass that writes (a prefill chunk). Position ``p`` lies in ring
+    block ``(p // block_size) % R``, so a pass over positions ``p .. p + q -
+    1`` overwrites what lay ``R`` blocks back: the last token it loses is
+    ``p + q + block_size - 2 - R * block_size`` at the latest, and the oldest
+    key its first query still sees is ``p - window + 1``; ``R * block_size >=
+    window + q + block_size - 2`` keeps the two apart whatever ``p`` is."""
+    return -(-(window + q_len) // block_size) + 1
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class WindowRing:
+    """What the layers that attend a WINDOW keep (``WINDOW_KV``), as the
+    per-slot state of a :class:`HybridBlockCache`: ``k`` / ``v`` are a block
+    pool of their own, ``(L_window, num_slots * R + 1, H_kv, block_size, D)``
+    head-major as the paged kernels read it, block 0 the garbage block; slot
+    ``s`` owns blocks ``1 + s * R ... (s + 1) * R`` for as long as it lives
+    and position ``p`` of its request lies in the ``(p // block_size) % R``-th
+    of them (:func:`window_ring_blocks` says why ``R`` suffices). A slot's
+    ring is as large at token 1 as at token 10^5; nothing is allocated or
+    freed as a request grows, and the ring's "block table" is arithmetic on
+    the slot's number, made in the graph (:meth:`block_table`)."""
+
+    k: jax.Array
+    v: jax.Array
+    ring_blocks: int = field(metadata=dict(static=True), default=1)
+
+    #: which family of the serving step's counters counts this state
+    KIND = "window_ring"
+
+    @property
+    def num_slots(self) -> int:
+        return (self.k.shape[1] - 1) // self.ring_blocks
+
+    @property
+    def num_layers(self) -> int:
+        return self.k.shape[0]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.k.size * self.k.dtype.itemsize + self.v.size * self.v.dtype.itemsize)
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one slot's rings hold over every window layer."""
+        return self.nbytes // self.k.shape[1] * self.ring_blocks
+
+    def fill_slots(self, slots, value: float) -> "WindowRing":
+        """Overwrite the rings of whole slots in every layer (scrub: 0.0)."""
+        R = self.ring_blocks
+        blocks = (1 + np.asarray(slots, np.int32)[:, None] * R + np.arange(R, dtype=np.int32)).ravel()
+        return WindowRing(
+            k=self.k.at[:, blocks].set(value), v=self.v.at[:, blocks].set(value), ring_blocks=R
+        )
+
+    def _first_block(self, seq_ids: jax.Array) -> jax.Array:
+        return 1 + jnp.maximum(seq_ids, 0).astype(jnp.int32) * self.ring_blocks
+
+    def block_table(self, seq_ids: jax.Array, max_blocks: int) -> jax.Array:
+        """(B, max_blocks): the ring block that holds each LOGICAL block of a
+        row's sequence, ``seq_ids`` (B,) the rows' slots; a row that sits the
+        pass out (slot < 0) reads the garbage block. Only the entries inside
+        a row's window are distinct blocks; the paged kernels read no other."""
+        ring = jnp.arange(max_blocks, dtype=jnp.int32) % self.ring_blocks
+        table = self._first_block(seq_ids)[:, None] + ring[None, :]
+        return jnp.where((seq_ids >= 0)[:, None], table, GARBAGE_BLOCK)
+
+    def slot_mapping(self, seq_ids: jax.Array, positions: jax.Array, valid: jax.Array) -> jax.Array:
+        """(B, S) flat write slots of ``positions`` in the rows' rings;
+        -1 (dropped) where ``valid`` is False or the row sits out."""
+        bs = self.block_size
+        block = self._first_block(seq_ids)[:, None] + (positions // bs) % self.ring_blocks
+        slots = block * bs + positions % bs
+        return jnp.where(valid & (seq_ids >= 0)[:, None], slots, -1).astype(jnp.int32)
+
+
+def init_window_ring(
+    num_layers: int, num_slots: int, ring_blocks: int, block_size: int,
+    num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
+) -> WindowRing:
+    shape = (num_layers, num_slots * ring_blocks + 1, num_kv_heads, block_size, head_dim)
+    return WindowRing(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype), ring_blocks=ring_blocks)
+
+
+def window_ring_pspecs(ring_blocks: int) -> WindowRing:
+    from jax.sharding import PartitionSpec as P
+
+    return WindowRing(k=P(), v=P(), ring_blocks=ring_blocks)
 
 
 @dataclass(frozen=True)

@@ -77,6 +77,7 @@ in-register (``.astype`` in ``_body``); stats/accumulators stay fp32.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -463,6 +464,9 @@ def _group_copies(bt_ref, end_ref, streams, sems, *, layer, P):
     return start, wait
 
 
+#: what the chip's compiler gives a kernel's scoped allocations unasked
+SCOPED_VMEM_BYTES = 16 * 2**20
+
 #: rows of a block the in-kernel KV write reads, merges and stores for ONE
 #: new token: bfloat16's sublane tile, the least the chip stores whole
 WRITE_TILE_ROWS = 16
@@ -808,6 +812,15 @@ def _paged_by_group(
         ]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     tensors += [k_cache, v_cache]
+    # what a grid step holds in VMEM: the row's mask slab and its q and
+    # output, each twice (pipelined), and the two slots of K and V. Under the
+    # compiler's own scoped limit nothing is asked (every shape served before
+    # a window layer was: the call it always was); a slab of (n_rep x K) rows
+    # over a long bucket (8 q heads a KV head x 16 positions x 16384 keys:
+    # 18 MiB) asks for what it needs
+    item = jnp.dtype(k_cache.dtype).itemsize
+    held = 2 * math.prod(m.shape[1:]) * 4 + 4 * n_kv * G * D * item + 4 * n_kv * R * D * 4
+    vmem_limit = None if held <= SCOPED_VMEM_BYTES else held + 8 * 2**20
 
     out = _common_call(
         functools.partial(
@@ -826,6 +839,7 @@ def _paged_by_group(
         # rows in order: a row's last group starts the next live row's copies
         semantics=("arbitrary",),
         aliases=aliases,
+        vmem_limit_bytes=vmem_limit,
     )
     if new is None:
         return out[:, :, :rk].reshape(B, Hq * K, D)

@@ -39,6 +39,14 @@ float32 accumulator; the float32 probabilities (and a float32 q: quantized
 caches fold the K scale into it) go as three bfloat16 parts that sum to the
 value exactly, so nothing is rounded that the float32 form would keep.
 
+A layer that attends a WINDOW (``window``, static: query ``i`` sees key ``j``
+iff ``i - window < j <= i``) gives a row a LOWER frontier beside the upper
+one: the loop starts at the group that holds the first key the row's lowest
+query sees, a part skips a group that lies wholly behind its tile's window,
+and the mask of a group that crosses a window's edge takes one more compare.
+Without a window none of this is traced: no operand, no branch, the kernel
+it always was.
+
 At a head_dim that is no multiple of the 128 lanes the chip's compiler
 refuses a hand copy of a block (a 64-lane slice of an HBM ref), and the
 launch keeps one block a grid step through a ``BlockSpec`` on the block
@@ -106,28 +114,32 @@ def _paged_group_kernel(
     lim_ref,  # (B,) valid cache length per row
     tmax_ref,  # (B, nq) highest / lowest query position of a q tile
     tmin_ref,
+    *rest,  # under a window: lo_ref (B,), the row's first live group; then
     # operands
-    q_ref,  # (1, Hkv, NP, R, D): R = hp heads x tq positions, head-major
-    pos_ref,  # (1, nq, tq) query positions
-    k_hbm,  # (L, NB+1, Hkv, bs, D), left in HBM
-    v_hbm,
-    o_ref,  # (1, Hkv, NP, R, D)
-    k_buf,  # (2, Hkv, G, D): a group of blocks, two slots
-    v_buf,
-    sems,
-    slot_ref,
-    m_scr,  # (Hkv, NP, R, 1) running max / sum / (Hkv, NP, R, D) accumulator
-    l_scr,
-    acc_scr,
-    *,
+    #   q_ref (1, Hkv, NP, R, D): R = hp heads x tq positions, head-major
+    #   pos_ref (1, nq, tq) query positions
+    #   k_hbm, v_hbm (L, NB+1, Hkv, bs, D), left in HBM
+    #   o_ref (1, Hkv, NP, R, D)
+    #   k_buf, v_buf (2, Hkv, G, D): a group of blocks, two slots
+    #   sems, slot_ref
+    #   m_scr, l_scr (Hkv, NP, R, 1) running max / sum, acc_scr (Hkv, NP, R, D)
     scale: float,
     P: int,
     nq: int,
     q_dtype,
+    window: int = None,
 ):
     """One ROW per grid step; inside, a loop over the row's live block groups
     only, and for each group over the parts of a query group and the KV
-    heads. The copies are ``decode_attention._group_copies``'."""
+    heads. The copies are ``decode_attention._group_copies``'. ``window``:
+    the groups behind the row's lower frontier (``lo_ref``) are neither
+    copied nor attended (module docstring)."""
+    lo_ref = None
+    if window is not None:
+        lo_ref, *rest = rest
+    (q_ref, pos_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref,
+     m_scr, l_scr, acc_scr) = rest
+    first_group = (lambda row: 0) if lo_ref is None else (lambda row: lo_ref[row])
     b = pl.program_id(0)
     B = pl.num_programs(0)
     _, n_kv, NP, R, D = q_ref.shape
@@ -149,7 +161,7 @@ def _paged_group_kernel(
 
         @pl.when(row < B)
         def _():
-            start(row, 0, 0)
+            start(row, first_group(row), 0)
 
     hi = (end_ref[b] + P - 1) // P
     lim = lim_ref[b]
@@ -171,6 +183,8 @@ def _paged_group_kernel(
             q_pos = pos_ref[0, iq][:, None]  # (tq, 1)
             kv_pos = kv0 + jax.lax.broadcasted_iota(jnp.int32, (tq, G), 1)
             mask = (kv_pos <= q_pos) & (kv_pos < lim)
+            if window is not None:
+                mask = mask & (kv_pos > q_pos - window)
             # -inf under a running max that starts at NEG_INF: a masked
             # score's probability is exp(-inf) = 0 with no second select,
             # also for a query that has seen no valid key yet
@@ -190,7 +204,7 @@ def _paged_group_kernel(
 
         @pl.when(nrow < B)
         def _prefetch():
-            start(nrow, jnp.where(last, 0, g + 1), 1 - slot)
+            start(nrow, jnp.where(last, first_group(nrow), g + 1), 1 - slot)
 
         wait(b, g, slot)
         slot_ref[0] = 1 - slot
@@ -202,6 +216,11 @@ def _paged_group_kernel(
             # and wholly under both: no mask can bite
             run = kv0 <= jnp.minimum(tmax_ref[b, iq], lim - 1)
             clear = (kv0 + G - 1 <= tmin_ref[b, iq]) & (kv0 + G <= lim)
+            if window is not None:
+                # some key of the group lies inside the window of the tile's
+                # lowest query; and every key inside that of its highest
+                run = run & (kv0 + G - 1 > tmin_ref[b, iq] - window)
+                clear = clear & (kv0 > tmax_ref[b, iq] - window)
 
             def head(h, _):
                 @pl.when(clear)
@@ -218,7 +237,7 @@ def _paged_group_kernel(
 
         jax.lax.fori_loop(0, NP, part, None)
 
-    jax.lax.fori_loop(0, hi, group, None)
+    jax.lax.fori_loop(first_group(b), hi, group, None)
 
     @pl.when(hi > 0)
     def _finalize():
@@ -233,7 +252,7 @@ def _paged_group_kernel(
 
 
 def _paged_by_group(q, k_cache, v_cache, li, block_table, positions, kv_limit,
-                    *, scale, n_rep, tq, P, interpret):
+                    *, scale, n_rep, tq, P, interpret, window=None):
     """The launch of :func:`_paged_group_kernel`. (B, Sq, Hq, D) -> same."""
     B, Sq, Hq, D = q.shape
     _, _, n_kv, bs, _ = k_cache.shape
@@ -262,6 +281,12 @@ def _paged_by_group(q, k_cache, v_cache, li, block_table, positions, kv_limit,
     live_from = jnp.concatenate([live_from, jnp.full((1,), B, jnp.int32)])
     # a table no multiple of P wide: dead entries, never copied
     bt = jnp.pad(block_table.astype(jnp.int32), ((0, 0), (0, NG * P - MB)))
+    prefetch = [li, bt, end, live_from, lim, tile_max, tile_min]
+    if window is not None:
+        # the group of the first key the row's lowest query sees; a row with
+        # no live block keeps its loop empty (lo <= hi = 0)
+        first_key = jnp.maximum(jnp.min(tile_min, axis=-1) - window + 1, 0)
+        prefetch.append(jnp.minimum(first_key // G, jnp.maximum(-(-end // P) - 1, 0)))
 
     def parts(x):  # (B, Sq, Hq, D) -> (B, Hkv, NP, R, D)
         x = jnp.pad(x, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
@@ -281,7 +306,7 @@ def _paged_by_group(q, k_cache, v_cache, li, block_table, positions, kv_limit,
     out = _da._common_call(
         functools.partial(
             _paged_group_kernel, scale=scale, P=P, nq=nq,
-            q_dtype=jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32,
+            q_dtype=jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32, window=window,
         ),
         grid=(B,),
         in_specs=[
@@ -291,10 +316,7 @@ def _paged_by_group(q, k_cache, v_cache, li, block_table, positions, kv_limit,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=row_spec((n_kv, NP, R, D)),
-        operands=(
-            [li, bt, end, live_from, lim, tile_max, tile_min],
-            [parts(q), pos, k_cache, v_cache],
-        ),
+        operands=(prefetch, [parts(q), pos, k_cache, v_cache]),
         out_shape=jax.ShapeDtypeStruct((B, n_kv, NP, R, D), q.dtype),
         scratch=[
             pltpu.VMEM((2, n_kv, G, D), k_cache.dtype),
@@ -335,6 +357,7 @@ def _by_block_kernel(
     tq: int,
     bs: int,
     nkv: int,
+    window: int = None,
 ):
     b = pl.program_id(0)
     iq = pl.program_id(2)
@@ -361,6 +384,8 @@ def _by_block_kernel(
         q_pos = pos_ref[0, 0]  # (tq,)
         kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 1)
         mask = (kv_pos <= q_pos[:, None]) & (kv_pos < kv_limit_ref[b])
+        if window is not None:
+            mask = mask & (kv_pos > q_pos[:, None] - window)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:]
@@ -387,7 +412,7 @@ def _by_block_kernel(
 
 
 def _paged_by_block(q, k_cache, v_cache, li, block_table, positions, kv_limit,
-                    *, scale, n_rep, tq, interpret):
+                    *, scale, n_rep, tq, interpret, window=None):
     """The launch at a head_dim that is no multiple of the 128 lanes: the
     chip's compiler refuses such a slice of an HBM ref, so the blocks cannot
     be copied by hand; they come one a grid step through a ``BlockSpec`` on
@@ -411,7 +436,7 @@ def _paged_by_block(q, k_cache, v_cache, li, block_table, positions, kv_limit,
         lambda b, h, iq, j, li, bt, lim, tm: (li[0], bt[b, j], h // n_rep, 0, 0),
     )
     out = _da._common_call(
-        functools.partial(_by_block_kernel, scale=scale, tq=tq, bs=bs, nkv=MB),
+        functools.partial(_by_block_kernel, scale=scale, tq=tq, bs=bs, nkv=MB, window=window),
         grid=(B, Hq, nq, MB),
         in_specs=[
             pl.BlockSpec((1, 1, tq, D), lambda b, h, iq, j, *_: (b, h, iq, 0)),
@@ -440,7 +465,7 @@ def _paged_by_block(q, k_cache, v_cache, li, block_table, positions, kv_limit,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "n_rep", "tq", "interpret")
+    jax.jit, static_argnames=("scale", "n_rep", "tq", "interpret", "window")
 )
 def paged_flash_attention(
     q: jax.Array,  # (B, Sq, Hq, D)
@@ -457,13 +482,18 @@ def paged_flash_attention(
     k_scale: jax.Array = None,  # (Hkv,) per-head dequant factor (scale/qmax)
     v_scale: jax.Array = None,  # for int8/fp8 caches; None = plain cache
     interpret: bool = False,
+    window: int = None,  # static: the layer attends (position - window, position] only
 ) -> jax.Array:
     """Prefix/chunked-prefill attention straight off the paged cache.
 
     Returns (B, Sq, Hq, D). Query token t of row b attends cache positions
     p <= positions[b, t] with p < kv_limit[b] — prior context plus causal
     among the new tokens (KV for the new tokens must already be written;
-    write-then-attend as everywhere else).
+    write-then-attend as everywhere else) — and, under ``window``, with
+    p > positions[b, t] - window: the block groups behind the row's lowest
+    query's window are neither copied nor attended. ``window`` None is a
+    static absence: the call lowers to the kernel it lowered to before the
+    argument existed.
 
     Quantized caches pass the raw int8/fp8 code blocks plus this layer's
     per-head dequant factors: the K factor folds into q (scaling the QKᵀ
@@ -497,7 +527,7 @@ def paged_flash_attention(
     li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
     out = launch(
         q, k_cache, v_cache, li, block_table, positions, kv_limit,
-        scale=scale, n_rep=n_rep, tq=tq, interpret=interpret,
+        scale=scale, n_rep=n_rep, tq=tq, interpret=interpret, window=window,
     )
     if v_scale is not None:
         out = (out * jnp.repeat(v_scale, n_rep)[None, None, :, None]).astype(out_dtype)
@@ -506,7 +536,7 @@ def paged_flash_attention(
 
 def dispatch_paged_flash(
     q, k_cache, v_cache, layer_idx, block_table, positions, kv_limit,
-    *, scale, n_rep, k_scale=None, v_scale=None, interpret,
+    *, scale, n_rep, k_scale=None, v_scale=None, interpret, window=None,
 ):
     """:func:`paged_flash_attention` once per head shard of the ambient mesh
     (parallel/sharding.shard_over_heads): q and the output split on the q
@@ -519,7 +549,7 @@ def dispatch_paged_flash(
         return paged_flash_attention(
             q_s, k_s, v_s, bt, pos, lim,
             scale=scale, n_rep=n_rep, layer_idx=li, k_scale=ks_s, v_scale=vs_s,
-            interpret=interpret,
+            interpret=interpret, window=window,
         )
 
     return shard_over_heads(

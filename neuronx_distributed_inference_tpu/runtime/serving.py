@@ -404,6 +404,16 @@ class ServingSession:
         self.slot_state = state is not None
         self.slot_state_kind = getattr(state, "KIND", None)
         self.slot_state_bytes = state.nbytes if self.slot_state else 0
+        # a stack of window and full attention layers: the window layers' ring
+        # of blocks a slot (block_kvcache.WindowRing) beside the allocator's
+        # pool over the full layers. A slot holds its ring from admission to
+        # release; nothing of it is allocated, freed or scanned as it grows
+        self.window_layers = self.full_layers = self.window = 0
+        self.window_slot_bytes = 0
+        if self.slot_state_kind == "window_ring":
+            self.window_layers, self.full_layers = state.num_layers, app.paged_layers
+            self.window, self._ring_blocks = app.builder.window, state.ring_blocks
+            self.window_slot_bytes = state.slot_bytes
         self.expert_layers = app.builder.expert_layers()
         if self.expert_layers is not None:
             # the strategy a step program's expert layers were traced with,
@@ -438,10 +448,12 @@ class ServingSession:
 
     @property
     def kv_pool_bytes(self) -> int:
-        """Total block-pool HBM cost in the cache dtype (0 off block mode)."""
+        """Total block-pool HBM cost in the cache dtype (0 off block mode):
+        the allocator's pool and, for a cache of two lifetimes, the window
+        layers' rings."""
         if not self.block_mode:
             return 0
-        return self.allocator.num_blocks * self.block_bytes
+        return self.allocator.num_blocks * self.block_bytes + self.num_slots * self.window_slot_bytes
 
     @property
     def kv_free_bytes(self) -> int:
@@ -453,7 +465,10 @@ class ServingSession:
         if not self.block_mode:
             return 0
         reclaimable = len(getattr(self.allocator, "evictable", ()))
-        return (len(self.allocator.free) + reclaimable) * self.block_bytes
+        free = (len(self.allocator.free) + reclaimable) * self.block_bytes
+        if self.window_slot_bytes:  # a free slot's rings in the window layers
+            free += sum(r is None for r in self.slots) * self.window_slot_bytes
+        return free
 
     def add_request(
         self,
@@ -716,6 +731,8 @@ class ServingSession:
             )
         self.slots[req.slot] = None
         req.slot = -1
+        if self.window_layers and self.tel.enabled:
+            self._window_gauges()  # the slot's rings are free again
 
     def _cache_line_of_slot(self, slot: int) -> int:
         """Contiguous-cache line for a serving slot (the attention-DP layout
@@ -1326,7 +1343,10 @@ class ServingSession:
                     "chunk", (R, qb), len(ran), ran_real, len(flights),
                     resets=sum(1 for r, _ in ran if r.prefill_pos == 0),
                     kv_blocks=kv_blocks, kv_width=width,
-                    spans=[(r.prefill_pos, n) for r, n in ran] if self.sparse_layers else (),
+                    spans=(
+                        [(r.prefill_pos, n) for r, n in ran]
+                        if self.sparse_layers or self.window_layers else ()
+                    ),
                 )
                 for req, n in ran:
                     self._note_prefill(req, n)
@@ -2027,7 +2047,7 @@ class ServingSession:
                 self._count_pass(
                     "decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
                     block_rows=block_rows, kv_width=width,
-                    spans=[(p, K) for _, p in rows] if self.sparse_layers else (),
+                    spans=[(p, K) for _, p in rows] if self.sparse_layers or self.window_layers else (),
                 )
                 tel.pool_gauges(len(rows), self.kv_pool_bytes, self.kv_free_bytes)
                 snap = [(r, p, r.slot, r.epoch) for r, p in rows]
@@ -2128,6 +2148,20 @@ class ServingSession:
                 program, tokens * self.sparse_layers, scored * self.sparse_layers,
                 attended * self.sparse_layers,
             )
+        if self.window_layers and self.tel.enabled:
+            # query t of a row has t + 1 live keys and attends min(t + 1,
+            # window) of them in a window layer; a pass that enters a logical
+            # block past the ring's length writes over the ring's oldest
+            W, R, bs = self.window, self._ring_blocks, self.allocator.block_size
+            live = attended = recycled = 0
+            for first, n in spans:
+                keys = np.arange(first + 1, first + n + 1, dtype=np.int64)
+                live += int(keys.sum())
+                attended += int(np.minimum(keys, W).sum())
+                recycled += max(0, -(-(first + n) // bs) - max(-(-first // bs), R))
+            self.tel.window_pass(program, live, attended, self.full_layers, self.window_layers,
+                                 recycled * self.window_layers)
+            self._window_gauges()
         if self.loop_layer_passes:
             self.tel.loop_pass(program, dispatches, self.loop_layer_passes)
         if self.expert_layers is not None:
@@ -2136,6 +2170,15 @@ class ServingSession:
                 program, tokens * layers * top_k, dispatches * layers * experts,
                 self._expert_path(shape[1], shape[0] * shape[1]),
             )
+
+    def _window_gauges(self) -> None:
+        """The window layers' pool as live slots hold it (a recording session
+        alone asks): every ring, the rings of live slots, and of those the
+        blocks that hold a key."""
+        R, bs, L = self._ring_blocks, self.allocator.block_size, self.window_layers
+        live = [r for r in self.slots if r is not None]
+        in_use = sum(min(-(-max(r.pos, r.prefill_pos) // bs), R) for r in live)
+        self.tel.window_pool(self.num_slots * R * L, len(live) * R * L, in_use * L)
 
     def _consume(self, pend, results: Dict[str, int]):
         """Fetch a dispatched decode step and apply termination bookkeeping.

@@ -54,6 +54,16 @@ DEVICE_SCOPES = (
 LAYER_OTHER = "layer.other"
 _APPLIED = frozenset(DEVICE_SCOPES) - {LAYER_OTHER}
 
+#: the KINDS of attention layer a stack may mix (models/mellum.py: layers
+#: that attend a window beside layers that attend the whole context). A kind
+#: is a scope INSIDE ``layer.attn`` (``layer.attn.window``): an op under it
+#: is still ``layer.attn`` in a table's ``ops``, so what reads the attention
+#: of a program reads the sum, and the table names its kind beside it
+#: (``kinds``: :func:`scope_table`). A model of one kind applies none.
+ATTN_WINDOW, ATTN_FULL = "window", "full"
+ATTN_KINDS = (ATTN_WINDOW, ATTN_FULL)
+_KIND_SCOPES = frozenset(f"layer.attn.{kind}" for kind in ATTN_KINDS)
+
 #: the file a recording session writes beside the trace
 TABLE_FILE = "device_scopes.json"
 
@@ -86,9 +96,17 @@ def scope_of(op_name: str) -> str:
     return LAYER_OTHER if "while" in parts else ""
 
 
+def kind_of(op_name: str) -> str:
+    """The ``layer.attn.<kind>`` component of an instruction's ``op_name``
+    (:data:`ATTN_KINDS`), "" where it has none."""
+    return next((p for p in reversed(op_name.split("/")) if p in _KIND_SCOPES), "")
+
+
 def scope_table(hlo_text: str) -> dict:
     """``{"module": <HloModule name>, "ops": {instruction: scope}}`` of one
-    compiled program: every instruction that can run as a device op of its
+    compiled program, and, for a program whose stack names KINDS of attention
+    layer, ``"kinds": {instruction: "layer.attn.<kind>"}`` of the ops that
+    carry one (absent for every other program): every instruction that can run as a device op of its
     own (those of the entry, of loop bodies and of called computations; not
     the insides of a fusion, which run as the fusion) but the containers. A
     fusion has the metadata the compiler printed on it: its root's. Where it
@@ -99,7 +117,7 @@ def scope_table(hlo_text: str) -> dict:
     under a scope; failing that it is ``layer.other`` in a loop's own
     computation and "" elsewhere."""
     fused, loops = set(_FUSED.findall(hlo_text)), set(_LOOP.findall(hlo_text))
-    module, ops = "", {}
+    module, ops, kinds = "", {}, {}
     own, reads, in_loop = None, {}, False  # of the computation being read
 
     def close():
@@ -147,10 +165,15 @@ def scope_table(hlo_text: str) -> dict:
         scope = scope_of(op_name)
         # an op_name of the program's own is a scope or a path from its jit
         own[m.group(1)] = scope if scope in _APPLIED or "jit(" in op_name else None
+        if kind_of(op_name):
+            kinds[m.group(1)] = kind_of(op_name)
         reads[m.group(1)] = _REF.findall(line[m.end():].split(", metadata=", 1)[0])
     if own:
         close()
-    return {"module": module, "ops": ops}
+    table = {"module": module, "ops": ops}
+    if kinds:
+        table["kinds"] = {name: kind for name, kind in kinds.items() if name in ops}
+    return table
 
 
 def write_tables(profile_dir: str, tables: Dict[str, dict]) -> None:

@@ -419,6 +419,28 @@ class TelemetrySession:
             "keys attended after the selection: over the rows, layers and "
             "queries of a pass, min(live keys, index_topk)",
             labels=("program",))
+        self._attn_keys_live = r.counter(
+            "nxdi_attn_keys_live_total",
+            "a stack that mixes window and full attention layers: live keys "
+            "(at or before the query) over the rows, layers and queries of a "
+            "pass, by the layers' kind",
+            labels=("program", "layer_kind"))
+        self._attn_keys_attended = r.counter(
+            "nxdi_attn_keys_attended_total",
+            "and the keys a query attends: its live keys in a full layer, "
+            "min(live keys, sliding_window) in a window layer",
+            labels=("program", "layer_kind"))
+        self._window_recycled = r.counter(
+            "nxdi_kv_window_blocks_recycled_total",
+            "ring blocks of window layers written over a block that has left "
+            "every window (a slot's ring wrapping): blocks x window layers")
+        self._window_blocks = {
+            name: r.gauge(f"nxdi_kv_window_blocks_{name}", text) for name, text in (
+                ("total", "ring blocks of the window layers' pool: slots x ring x window layers"),
+                ("held", "ring blocks live slots hold (a slot holds its ring from admission "
+                         "to release, whatever its context)"),
+                ("in_use", "ring blocks that hold a key of a live request"),
+            )}
         self._moe_rows = r.counter(
             "nxdi_moe_rows_routed_total",
             "token rows the split serving step routed to an expert: real "
@@ -1268,6 +1290,26 @@ class TelemetrySession:
         self._index_keys.inc(written)
         self._sparse_scored.child((program,)).inc(scored)
         self._sparse_attended.child((program,)).inc(attended)
+
+    def window_pass(self, program: str, live: int, attended: int, full_layers: int,
+                    window_layers: int, recycled: int) -> None:
+        """One pass of the split serving step over a stack of window and full
+        attention layers: ``live`` keys a layer over its rows and queries,
+        ``attended`` of them inside a window layer's window, and the ring
+        blocks the pass wrote over (summed over the window layers)."""
+        if not self.enabled:
+            return
+        self._attn_keys_live.child((program, "full")).inc(live * full_layers)
+        self._attn_keys_attended.child((program, "full")).inc(live * full_layers)
+        self._attn_keys_live.child((program, "window")).inc(live * window_layers)
+        self._attn_keys_attended.child((program, "window")).inc(attended * window_layers)
+        self._window_recycled.inc(recycled)
+
+    def window_pool(self, total: int, held: int, in_use: int) -> None:
+        if not self.enabled:
+            return
+        for name, value in (("total", total), ("held", held), ("in_use", in_use)):
+            self._window_blocks[name].set(value)
 
     def loop_pass(self, program: str, dispatches: int, streams: int) -> None:
         """One pass of the split serving step over a looped stack of

@@ -983,3 +983,78 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     dense = _compile_step(app, tkg, tkg.example_inputs(2048, q_len=q), params, cache).as_text()
     assert kernel in dense
     assert "layer.select" not in set(device_scopes.scope_table(dense)["ops"].values())
+
+
+# ---------------------------------------------------------------------------
+# mellum2-12b-a2.5b: window and full attention mixed, a paged cache of two lifetimes
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_mellum_serving_step_walks_each_kinds_keys_and_fits_the_chip(chip_mesh, program, monkeypatch):
+    """mellum2-12b-a2.5b at the benchmark's widths (benchmark/configs/
+    mellum2-12b-a2.5b.json: 64 experts top-8, the whole vocabulary, 8 of 28
+    layers = two periods [window, window, window, full], 48 slots), both step
+    programs compiled for a described v5e at the widest kv bucket, 16384: the
+    cache has two lifetimes (a pool of 24576 blocks over the 2 full layers, a
+    ring of 37 blocks a slot over the 6 window layers); each RUN of like
+    layers ([W, W, W], [F], [W, W, W], [F]) is one scan over its own stacked
+    weights whose body runs the paged kernel of the program under its kind's
+    scope inside ``layer.attn`` (the decode kernel places the token
+    too: no scatter, nothing under ``layer.kv_write``; the chunk kernel under
+    a window's lower frontier: no mask of the bucket's width is built);
+    neither copies a pool, and each plans under 14.75 GiB of the chip's
+    15.75."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    # the gate asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
+    app, params, cache = _abstract_hybrid_app(chip_mesh(1), "mellum2-12b-a2.5b")
+    assert cache.k.shape == (2, 24577, 4, 32, 128)
+    assert cache.state.k.shape == cache.state.v.shape == (6, 48 * 37 + 1, 4, 32, 128)
+    assert cache.state.ring_blocks == 37 and app.builder.cache_layers().count("window_kv") == 6
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(16384, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((48, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
+    table = device_scopes.scope_table(text)
+    calls = [name for name in table["ops"] if name.startswith(kernel)]
+    # one body a run of like layers: four calls of the kernel, each named by its kind
+    assert len(calls) == 4 and {table["ops"][c] for c in calls} == {"layer.attn"}
+    assert sorted(table["kinds"][c] for c in calls) == ["layer.attn.full"] * 2 + ["layer.attn.window"] * 2
+    assert set(table["kinds"].values()) == {"layer.attn.window", "layer.attn.full"}
+    assert all(table["ops"][name] == "layer.attn" for name in table["kinds"])
+    for pool in (cache.k, cache.state.k):
+        assert _pool_copies(compiled, pool.shape)[0] == 0
+    if program == "decode":
+        _assert_decode_write_is_the_kernels(compiled)
+    else:
+        # the window rides the kernel as a frontier: no (rows, queries, bucket) mask, no
+        # gathered bucket of keys
+        assert not re.search(r"\[8,(1,)?128,16384\]|\[8,16384,4,128\]", text)
+        assert "layer.kv_write" in set(table["ops"].values())
+    stacks = [(64, 2304, 896), (64, 896, 2304)]
+    if program == "decode":
+        _assert_expert_products(compiled, program, stacks)
+    else:
+        # the grouped-matmul kernel on a run's stacked weights in place, three products a
+        # body, no instruction of the shape of a layer's expert stack
+        grouped = [name for name in table["ops"] if name.startswith("grouped_matmul")]
+        assert len(grouped) == 12 and {table["ops"][c] for c in grouped} == {"layer.moe.experts"}
+        assert not _stack_shaped(compiled, stacks) and "ragged-dot" not in text
+    if program == "chunk":
+        # the narrow chunk passes: XLA's own expert products (16 and 32 positions a row)
+        # copy no stack of expert weights, and 16 positions x 8 q heads a KV head over the
+        # widest bucket fit the decode kernel's VMEM (it asks for its 18 MiB mask slab)
+        for q in (32, 16):
+            narrow = _compile_step(app, tkg, tkg.example_inputs(16384, q_len=q), params, cache)
+            assert narrow.memory_analysis().temp_size_in_bytes < 2.5 * 2**30, q
+            assert _planned_bytes(narrow) < 14.75 * 2**30
+    mem = compiled.memory_analysis()
+    print(f"\nmellum2-12b-a2.5b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
