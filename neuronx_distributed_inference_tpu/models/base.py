@@ -190,12 +190,41 @@ class LayerStack:
         raise NotImplementedError
 
 
+def real_positions(inputs: "StepInputs") -> jax.Array:
+    """(B, S) bool: which positions of a pass are real. A position is real
+    iff its row is live and it writes K/V somewhere real: a padded chunk tail
+    and a row that sits a pass out carry slot_mapping / seq_id -1. The one
+    definition: a per-slot state advances over these (:func:`slot_state_rows`)
+    and a chunk pass routes these to its experts (:func:`expert_positions`).
+    The head of a chunk pass reads a live row's LAST of them, by the slot
+    mapping alone (:func:`model_logits`: what a row that sits out gives there
+    the host never reads)."""
+    valid = jnp.broadcast_to((inputs.seq_ids >= 0)[:, None], inputs.position_ids.shape)
+    if inputs.slot_mapping is not None:
+        valid = valid & (inputs.slot_mapping >= 0)
+    return valid
+
+
+def expert_positions(inputs: "StepInputs", phase: str) -> Optional[jax.Array]:
+    """What a pass hands its expert layers as ``valid``
+    (modules/moe.moe_layer): in a PAGED CHUNK pass (:func:`is_paged_chunk`)
+    its :func:`real_positions`, so that a padded position is routed to no
+    expert: its rows sort last, are in no group and cost the grouped products
+    no visit (its output nobody reads: it has no K/V slot and no head). Every
+    other pass hands None and routes every position: decode, a block step and
+    a speculation verify have no padded position in a live row (and the
+    ``dense`` strategy they take has no sort to leave a row out of), context
+    encoding and the ragged step are left as they were."""
+    if not is_paged_chunk(phase, inputs.slot_mapping, inputs.block_table):
+        return None
+    return real_positions(inputs)
+
+
 def slot_state_rows(inputs: "StepInputs", num_slots: int):
     """For a :class:`LayerStack` whose layers keep a constant-size state per
-    serving slot, from a pass's inputs: ``valid`` (B, S) — a position
-    advances the state iff its row is live and it writes K/V somewhere real
-    (padded chunk tails and rows that sit a pass out carry slot_mapping /
-    seq_id -1); ``reset`` (B,) — state lifetime without a host call: a row
+    serving slot, from a pass's inputs: ``valid`` (B, S) — the pass's
+    :func:`real_positions`: a position advances the state iff it is real;
+    ``reset`` (B,) — state lifetime without a host call: a row
     whose first position in this pass is 0 starts from zero state (a new
     request in a reused slot, a re-prefill after preemption, a probe's fresh
     cache); ``slots`` (B,) — whose state a row advances. The chunk program
@@ -204,9 +233,7 @@ def slot_state_rows(inputs: "StepInputs", num_slots: int):
     slot, so its write-back is dropped and the indices stay unique. The
     decode program has one row per slot: row r owns slot r, ``slots`` None."""
     positions = inputs.position_ids
-    valid = jnp.broadcast_to((inputs.seq_ids >= 0)[:, None], positions.shape)
-    if inputs.slot_mapping is not None:
-        valid = valid & (inputs.slot_mapping >= 0)
+    valid = real_positions(inputs)
     reset = valid[:, 0] & (positions[:, 0] == 0)
     slots = None
     if inputs.slot_mapping is not None:
@@ -1342,6 +1369,7 @@ def run_decoder_layers(
     else:
         captured = None
         choices = []  # per group, the choices its layers' MLPs returned
+        expert_valid = expert_positions(inputs, phase)
         if capture_layers is not None:
             # EAGLE3 multi-layer hidden capture rides the scan carry: one
             # (B, S, H) accumulator per tap, where-selected at its layer index
@@ -1383,6 +1411,8 @@ def run_decoder_layers(
                     group_params, expert_stacks = moe.hoist_expert_stacks(
                         group_params, g_mlp.spec, S, B * S, hidden.dtype
                     )
+                    # a paged chunk pass routes its real positions alone
+                    g_mlp = partial(g_mlp, valid=expert_valid)
 
                 def scan_body(carry, xs, g_mlp=g_mlp, g_layer=g_layer, mask=mask,
                               key_valid=key_valid, window=window, chunk=chunk,

@@ -43,6 +43,7 @@ from neuronx_distributed_inference_tpu.models.base import (
     EXPERT_CHOICES,
     LayerStack,
     build_mask,
+    expert_positions,
     paged_block_inputs,
 )
 from neuronx_distributed_inference_tpu.models.deepseek import (
@@ -115,6 +116,7 @@ class SparseLatentStack(LayerStack):
         block_inputs = paged_block_inputs(inputs, cache.block_size)
         mask = build_mask(inputs, spec, phase)
         B, S, _ = hidden.shape
+        expert_valid = expert_positions(inputs, phase)
         total = sum(g.num_layers for g in spec.layer_groups)
         chose_keys = jnp.zeros((total, B, S, self.topk), jnp.int32) if spec.output_choices else None
         chose_experts = []
@@ -129,6 +131,8 @@ class SparseLatentStack(LayerStack):
                 group, expert_stacks = moe.hoist_expert_stacks(
                     group, g_mlp.spec, S, B * S, hidden.dtype
                 )
+                # a paged chunk pass routes its real positions alone
+                g_mlp = functools.partial(g_mlp, valid=expert_valid)
 
             def body(carry, xs, g_mlp=g_mlp, expert_stacks=expert_stacks, offset=offset):
                 h, k, v, ik, keys = carry

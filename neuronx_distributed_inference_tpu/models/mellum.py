@@ -46,6 +46,7 @@ from neuronx_distributed_inference_tpu.models.base import (
     LayerStack,
     build_mask,
     decoder_layer,
+    expert_positions,
     paged_block_inputs,
 )
 from neuronx_distributed_inference_tpu.models.granite_hybrid import _runs
@@ -193,6 +194,8 @@ class WindowFullStack(LayerStack):
             for k, (inv, scaling) in self.ropes.items()
         }
         B, S, _ = hidden.shape
+        # a paged chunk pass routes its real positions alone
+        expert_valid = expert_positions(inputs, phase)
         choices = spec.output_choices
         chose = None
         if choices:
@@ -215,7 +218,7 @@ class WindowFullStack(LayerStack):
                 picked = []
 
                 def mlp(p, x, s):
-                    out = self.expert_mlp(p, x, s)
+                    out = self.expert_mlp(p, x, s, expert_valid)
                     if choices:
                         out, mine = out
                         picked.append(mine)

@@ -52,6 +52,7 @@ from neuronx_distributed_inference_tpu.models.base import (
     PHASE_TOKEN_GENERATION,
     LayerStack,
     build_mask,
+    expert_positions,
     paged_block_inputs,
     paged_write_attend,
     residual_add,
@@ -140,6 +141,8 @@ class ZayaStack(LayerStack):
         positions = inputs.position_ids
         valid, reset, slots = slot_state_rows(inputs, cache.state.num_slots)
         n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+        # a paged chunk pass routes its real positions alone
+        expert_valid = expert_positions(inputs, phase)
         block_inputs = paged_block_inputs(inputs, cache.block_size)
         mask = build_mask(inputs, spec, phase)
         cos, sin = rope_cos_sin(positions, params["rope"]["inv_freq"], spec.attention_scaling)
@@ -182,7 +185,8 @@ class ZayaStack(LayerStack):
                 lp["mlp"]["router"], x.reshape(B * S, H), r, spec.rms_eps
             )
             with jax.named_scope("layer.mlp"):
-                out = moe_layer(lp["mlp"], x, moe, router=lambda *_: (aff, selected))
+                out = moe_layer(lp["mlp"], x, moe, router=lambda *_: (aff, selected),
+                                valid=expert_valid)
                 h = residual_add(h, out, spec)
             return (h, r, k_cache, v_cache, last), (
                 choice.reshape(B, S) if spec.output_choices else None

@@ -23,11 +23,31 @@ Design:
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import NamedTuple, Optional, Tuple
+from typing import List, NamedTuple, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
+
+#: the sets open while a step program is traced (:func:`noting_masked_sorts`)
+_MASKED_SORTS: List[Set[Tuple[int, int]]] = []
+
+
+@contextmanager
+def noting_masked_sorts():
+    """While a step program is traced inside: the set of the ``(B, S)`` of
+    every :func:`moe_layer` whose grouped sort was handed the pass's real
+    positions (``valid``). The runner keeps it beside the program, and the
+    serving session counts a pass's padded rows as left out of the sort
+    (``nxdi_moe_sorted_rows_total{kind=padding}``) only for a shape in it: a
+    builder that does not hand its expert layer the mask reads 0."""
+    shapes: Set[Tuple[int, int]] = set()
+    _MASKED_SORTS.append(shapes)
+    try:
+        yield shapes
+    finally:
+        _MASKED_SORTS.remove(shapes)
 
 
 @dataclass(frozen=True)
@@ -701,6 +721,9 @@ def moe_layer(
 
     with jax.named_scope("layer.moe.experts"):
         if path in ("kernel", "ragged_dot"):
+            if valid is not None:
+                for shapes in _MASKED_SORTS:
+                    shapes.add((B, S))
             out = expert_mlps_grouped(expert_params, x, affinities, spec, path == "kernel",
                                       None if valid is None else valid.reshape(B * S))
         else:

@@ -37,6 +37,7 @@ from neuronx_distributed_inference_tpu.models.base import (
 )
 from neuronx_distributed_inference_tpu.modules.autobucketing import get_target_bucket
 from neuronx_distributed_inference_tpu.modules.kvcache import KVCache, cache_spec
+from neuronx_distributed_inference_tpu.modules.moe import noting_masked_sorts
 from neuronx_distributed_inference_tpu.modules.sampling import prepare_sampling_params
 from neuronx_distributed_inference_tpu.ops.kernel_mode import CHUNK_ROWS
 from neuronx_distributed_inference_tpu.utils.snapshot import debug_log_step
@@ -89,7 +90,16 @@ class SubModelRunner:
         # load()); jit follows their shardings, so no in_shardings needed —
         # and the param tree can change shape (e.g. quantization adds scale
         # leaves) without invalidating the runner
-        step = partial(forward, spec=spec, phase=phase, mlp_fn=mlp_fn, layer_fn=layer_fn)
+        # the (rows, positions a row) of the programs traced so far whose
+        # expert layers sorted the pass's real positions alone
+        # (modules/moe.noting_masked_sorts): the session's counter asks
+        self.masked_sort_shapes = set()
+
+        def step(*args):
+            with noting_masked_sorts() as shapes:
+                out = forward(*args, spec=spec, phase=phase, mlp_fn=mlp_fn, layer_fn=layer_fn)
+            self.masked_sort_shapes |= shapes
+            return out
 
         def program(name):
             return jax.jit(

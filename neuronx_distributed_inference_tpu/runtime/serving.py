@@ -2166,9 +2166,14 @@ class ServingSession:
             self.tel.loop_pass(program, dispatches, self.loop_layer_passes)
         if self.expert_layers is not None:
             layers, experts, top_k = self.expert_layers
+            # the padded positions' rows, where the program was traced with
+            # its expert layers handed the pass's real positions
+            masked = tuple(shape) in self.app.token_generation_model.masked_sort_shapes
+            padded = dispatches * shape[0] * shape[1] - tokens
             self.tel.moe_pass(
                 program, tokens * layers * top_k, dispatches * layers * experts,
                 self._expert_path(shape[1], shape[0] * shape[1]),
+                rows_left_out=padded * layers * top_k if masked else 0,
             )
 
     def _window_gauges(self) -> None:

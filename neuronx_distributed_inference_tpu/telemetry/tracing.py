@@ -487,6 +487,21 @@ class TelemetrySession:
             "jax.lax.ragged_dot, dense = every expert over every row (capacity, "
             "fused: the two configured strategies)",
             labels=("program", "path"))
+        self._moe_sorted_rows = r.counter(
+            "nxdi_moe_sorted_rows_total",
+            "the token rows a pass's grouped expert sort carried (path kernel "
+            "or ragged_dot of nxdi_moe_grouped_rows_total; a dense pass sorts "
+            "nothing and counts nothing): kind=live the rows of real positions "
+            "(what nxdi_moe_rows_routed_total counts), kind=padding the rows "
+            "of the pass's padded positions (a live row's tail, a row that "
+            "sits the pass out: x expert layers x experts per token) that the "
+            "sort put in no group, so that the grouped products never visit "
+            "them; padding is counted ONLY for a program whose expert layers "
+            "were traced with the pass's real positions (models/base."
+            "expert_positions -> modules/moe.moe_layer's valid, noted at "
+            "trace time): a program that routes its padded positions like "
+            "real ones reads 0",
+            labels=("program", "kind"))
         self._block_row_passes = r.counter(
             "nxdi_block_row_passes_total",
             "a block-step model (runtime/block_step.py): live rows x passes "
@@ -1327,17 +1342,23 @@ class TelemetrySession:
             return
         self._moe_held.child((str(int(published)),)).set(held)
 
-    def moe_pass(self, program: str, rows_routed: int, experts: int, path: str) -> None:
+    def moe_pass(self, program: str, rows_routed: int, experts: int, path: str,
+                 rows_left_out: int = 0) -> None:
         """One pass of the split serving step over a model with routed
         experts: token rows routed (x layers x experts per token), the
         experts whose weights its dispatches streamed (every held expert of
-        every expert layer, per dispatch), and the expert strategy its
-        program holds (``path``: modules/moe.expert_path)."""
+        every expert layer, per dispatch), the expert strategy its
+        program holds (``path``: modules/moe.expert_path) and, of a grouped
+        strategy, the rows of padded positions its sort left out of every
+        group (0 for a program that routes them like real ones)."""
         if not self.enabled:
             return
         self._moe_rows.child((program,)).inc(rows_routed)
         self._moe_experts.child((program,)).inc(experts)
         self._moe_grouped_rows.child((program, path)).inc(rows_routed)
+        if path != "dense":
+            self._moe_sorted_rows.child((program, "live")).inc(rows_routed)
+            self._moe_sorted_rows.child((program, "padding")).inc(rows_left_out)
 
     def block_pass(self, denoise_rows: int, commit_rows: int, positions: int) -> None:
         """One dispatch of a block-step model's decode step: its live rows

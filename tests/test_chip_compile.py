@@ -331,6 +331,14 @@ def _assert_expert_products(compiled, program, stack_shapes):
         assert re.search(r"= \w+\[" + str(E) + r",\d+,\d+\]\S* (?:fusion|convolution|dot)\(", text)
 
 
+def _assert_experts_sort_real_positions(tkg, program):
+    """Of a runner whose step program was just traced: the 8 x 128 chunk
+    program's expert layers sorted the pass's real positions alone (a padded
+    position is routed to no expert: models/base.expert_positions), a decode
+    program's (and a block step's) were handed no mask."""
+    assert tkg.masked_sort_shapes == ({(8, 128)} if program == "chunk" else set())
+
+
 def _planned_bytes(compiled) -> int:
     """What the executable plans on the device: arguments, outputs that are
     not aliased to one, temporaries."""
@@ -661,6 +669,7 @@ def test_zaya_serving_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, p
     inputs = tkg.example_inputs(2048, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((48, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
     text = compiled.as_text()
     kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
     assert kernel in text and _custom_calls(compiled) >= 1
@@ -732,6 +741,7 @@ def test_sdar_block_step_runs_the_paged_kernels_and_fits_the_chip(chip_mesh, pro
     inputs = tkg.example_inputs(2048, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((48, 4) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
     text = compiled.as_text()
     kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
     assert kernel in text and _custom_calls(compiled) >= 1
@@ -778,6 +788,7 @@ def test_kimi_serving_step_runs_the_latent_kernels_and_fits_the_chip(chip_mesh, 
     inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((64, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
     text = compiled.as_text()
     kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
     # the dense group's scan and the expert group's, whose chunk program adds the three products
@@ -842,6 +853,7 @@ def test_nemotron_serving_step_runs_its_kernels_in_place_and_fits_the_chip(
     inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((64, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
     text = compiled.as_text()
     table = device_scopes.scope_table(text)["ops"]
     assert "ragged-dot" not in text
@@ -960,6 +972,7 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     inputs = tkg.example_inputs(16896, q_len=q)
     assert inputs.input_ids.shape == ((32, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
     text = compiled.as_text()
     kernel = "paged_latent_decode_attention" if program == "decode" else "paged_latent_flash_attention"
     table = device_scopes.scope_table(text)["ops"]
@@ -1018,6 +1031,7 @@ def test_mellum_serving_step_walks_each_kinds_keys_and_fits_the_chip(chip_mesh, 
     inputs = tkg.example_inputs(16384, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((48, 1) if program == "decode" else (8, 128))
     compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
     text = compiled.as_text()
     kernel = "paged_tkg_decode_attention" if program == "decode" else "paged_flash_attention"
     table = device_scopes.scope_table(text)

@@ -288,3 +288,48 @@ def test_expert_path_keeps_ragged_dot_where_the_kernel_cannot_serve(monkeypatch)
     assert path(hybrid, plain) == "ragged_dot"
     biased = _expert_shapes(E, h, i, bias=jax.ShapeDtypeStruct((E, i), jnp.bfloat16))
     assert path(MoESpec(num_experts=E, top_k=k), biased) == "kernel"
+
+
+# ---------------------------------------------------------------------------
+# a chunk pass's padded positions: what routing them costs the grouped products
+# ---------------------------------------------------------------------------
+
+# a chunk dispatch is 8 rows x 128 positions; (experts, top_k, share of the
+# positions that are padding: the cell's ``prefill.padded_share``)
+PADDED_DISPATCHES = {
+    "mellum2-12b-a2.5b": (64, 8, 0.415),
+    "kimi-vl-a3b": (64, 6, 0.605),
+}
+
+
+@pytest.mark.parametrize("model", sorted(PADDED_DISPATCHES))
+def test_the_mask_takes_a_padded_dispatchs_long_groups_out_of_the_visit_plan(model):
+    """Every padded position of a chunk pass holds one token and makes one
+    choice: routed like a real one, they are ``top_k`` groups many row windows
+    long among the short groups of the live positions. With ``valid`` their
+    rows are in no group, and ``visit_plan`` (128-row tiles, the kernel's) has
+    at least 20 visits fewer a product (PERF.md section 6, PR 60: 127 -> 101
+    and 110 -> 81 on average)."""
+    from neuronx_distributed_inference_tpu.modules.moe import _sorted_dispatch
+    from neuronx_distributed_inference_tpu.ops.grouped_matmul import visit_plan
+
+    E, k, padded = PADDED_DISPATCHES[model]
+    T, saved = 8 * 128, []
+    for seed in range(6):
+        rng = np.random.RandomState(seed)
+        choice = np.argsort(rng.rand(T, E), axis=1)[:, :k]
+        aff = np.zeros((T, E), np.float32)
+        np.put_along_axis(aff, choice, rng.rand(T, k).astype(np.float32) + 0.1, axis=1)
+        real = rng.rand(T) >= padded
+        aff[~real] = aff[np.argmin(real)]  # one token, one choice
+        visits, windows = [], []
+        for valid in (None, jnp.asarray(real)):
+            sizes = _sorted_dispatch(jnp.asarray(aff), k, valid=valid)[3]
+            assert int(sizes.sum()) == (T if valid is None else int(real.sum())) * k
+            visits.append(int(visit_plan(sizes, T * k, 128)[3][0]))
+            windows.append(-(-int(sizes.sum()) // 128))
+        assert visits[0] - visits[1] >= 20, (seed, visits)
+        # the windows that hold a group's rows: all of them -> the live rows' alone
+        assert windows[0] == T * k // 128 and windows[1] <= windows[0] - 20
+        saved.append(visits[0] - visits[1])
+    assert 20 <= np.mean(saved) <= 40
