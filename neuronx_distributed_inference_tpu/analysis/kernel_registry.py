@@ -226,6 +226,24 @@ def _ssm_update_case(rows):
     return build
 
 
+def _kda_update_case(rows):
+    """Kimi-Linear's KDA geometry (12 of its layers, 32 heads of 128 x 128) at
+    ``rows`` serving slots: the benchmark's long-generation cell."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import kda_state_update as ku
+
+        L, H, D = 12, 32, 128
+        vec = _sds((rows, H, D), jnp.float32)
+        args = (_sds((L, rows, H, D, D), jnp.float32), _sds((), jnp.int32), vec, vec, vec, vec,
+                _sds((rows, H), jnp.float32), _sds((rows,), jnp.bool_), _sds((rows,), jnp.bool_))
+        return _unjit(ku.kda_state_update), args
+
+    return build
+
+
 def _paged_flash_case(B, Sq, MB, bs, cache_dtype, m=_1B):
     def build():
         import jax.numpy as jnp
@@ -582,6 +600,17 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         cases=(KernelCase("rows48", "float32", _ssm_update_case(48)),),
     ),
     KernelSpec(
+        name="kda_state_update",
+        site=("kda_state_update.py", "kda_state_update"),
+        entry="kda_state_update",
+        fallback="neuronx_distributed_inference_tpu.modules.kda:kda_step",
+        parity_test="tests/test_kimi_linear_reference.py",
+        lowering_test="tests/test_chip_compile.py",
+        # heads_per_block is a keyword of the entry (16: a 1 MiB tile), not a
+        # tuning-table entry: nothing was swept on the chip yet
+        cases=(KernelCase("rows128", "float32", _kda_update_case(128)),),
+    ),
+    KernelSpec(
         name="quant_matmul",
         site=("quant_matmul.py", "quant_matmul"),
         entry="quant_matmul",
@@ -704,7 +733,7 @@ def _dot_stats(jaxpr, out):
 
 
 #: vector-unit arithmetic counted for a kernel that has NO matrix product
-#: (ssm_state_update: a multiply-add over a float32 tile and a lane sum)
+#: (ssm_state_update, kda_state_update: multiply-adds over a float32 tile and a sum)
 _VECTOR_OPS = frozenset({"mul", "add", "sub", "reduce_sum"})
 
 

@@ -285,6 +285,27 @@ class DecoderModelBuilder:
             ]
         return jax.tree.unflatten(treedef, vals)
 
+    def random_tree_by_name(self, shapes, std_by_name: Dict[str, float], key=None, dtype=None,
+                            std: float = 0.02) -> Dict:
+        """A random pytree matching ``shapes`` whose leaves are drawn by NAME:
+        a leaf under a path with "norm" in it is ones, any other N(0, s) with
+        ``s`` the entry of ``std_by_name`` for the innermost path component
+        that has one, else ``std``."""
+        dtype = dtype or to_dtype(self.config.tpu_config.dtype)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            shapes, is_leaf=lambda x: isinstance(x, tuple)
+        )
+        key = key if key is not None else jax.random.PRNGKey(self.config.tpu_config.seed)
+        leaves = []
+        for (path, shape), k in zip(flat, jax.random.split(key, len(flat))):
+            names = [p.key for p in path]
+            if "norm" in "/".join(names):
+                leaves.append(jnp.ones(shape, dtype))
+                continue
+            s = next((v for n, v in std_by_name.items() if n in reversed(names)), std)
+            leaves.append((s * jax.random.normal(k, shape)).astype(dtype))
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
     def random_params(
         self, key: Optional[jax.Array] = None, dtype=None, on_host: bool = False
     ) -> Dict:

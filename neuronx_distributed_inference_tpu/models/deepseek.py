@@ -115,6 +115,10 @@ class MLASpec:
     v_head_dim: int
     scale: float
     rms_eps: float
+    #: False (``mla_use_nope``, models/kimi_linear.py): the ``qk_rope_head_dim``
+    #: dimensions stay in q, in ``kv_a_proj`` and in the pool, and nothing
+    #: rotates them (``cos`` and ``sin`` are not read)
+    use_rope: bool = True
 
     @property
     def q_head_dim(self) -> int:
@@ -167,6 +171,7 @@ def mla_decoder_layer(
         hidden = rms_norm(hidden, layer_params["input_layernorm"]["weight"], spec.rms_eps)
     B, S, _ = hidden.shape
     H = mla.num_heads
+    rotate = (lambda x: apply_rope(x, cos, sin)) if mla.use_rope else (lambda x: x)
 
     # --- q path: low-rank (or direct) projection, split nope/rope ---------
     with jax.named_scope("layer.qkv"):
@@ -178,15 +183,15 @@ def mla_decoder_layer(
             q = linear(sa["q_proj"], hidden)
         q = q.reshape(B, S, H, mla.q_head_dim)
         q_nope = q[..., : mla.qk_nope_head_dim]
-        q_pe = apply_rope(q[..., mla.qk_nope_head_dim :], cos, sin)
+        q_pe = rotate(q[..., mla.qk_nope_head_dim :])
 
     # --- compressed kv + rope key: what the token leaves behind -----------
     with jax.named_scope("layer.latent_proj"):
         ckv = linear(sa["kv_a_proj"], hidden)  # (B, S, r_kv + d_rope)
         c = rms_norm(ckv[..., : mla.kv_lora_rank], sa["kv_a_layernorm"]["weight"], mla.rms_eps)
-        k_pe = apply_rope(ckv[..., None, mla.kv_lora_rank :].reshape(
+        k_pe = rotate(ckv[..., None, mla.kv_lora_rank :].reshape(
             B, S, 1, mla.qk_rope_head_dim
-        ), cos, sin)
+        ))
 
     with jax.named_scope("layer.absorb"):
         # q_nope absorbed into latent space: (B,S,H,d_nope)·(H,d_nope,r) -> (B,S,H,r)
@@ -250,6 +255,8 @@ def mla_decoder_layer(
         out = linear(sa["o_proj"], out.reshape(B, S, H * mla.v_head_dim))
         hidden = residual + out
 
+    if mlp_fn is None:  # a block that is the attention part alone (HybridStack)
+        return hidden, k_cache, v_cache
     residual = hidden
     with jax.named_scope("layer.norm"):
         hidden = rms_norm(hidden, layer_params["post_attention_layernorm"]["weight"], spec.rms_eps)

@@ -68,6 +68,10 @@ from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
 from neuronx_distributed_inference_tpu.ops.quant import linear
 
 MAMBA, ATTENTION, MOE = "mamba", "attention", "moe"
+#: further kinds of single-part block (models/kimi_linear.py): a KDA mixer
+#: (modules/kda.py), a latent-attention mixer (models/deepseek.mla_decoder_layer
+#: without its MLP) and a dense gated MLP alone
+KDA, MLA, DENSE = "kda", "mla", "dense"
 
 
 class GraniteHybridInferenceConfig(InferenceConfig):
@@ -202,14 +206,8 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
         dt = jax.nn.softplus(linear(m["dt_proj"], x).astype(f32) + m["dt_bias"].astype(f32))
         A = -jnp.exp(m["A_log"].astype(f32))
 
-        tails = jax.lax.dynamic_index_in_dim(state.conv, li, 0, keepdims=False)
-        tail = tails if slots is None else jnp.take(tails, slots, axis=1, mode="fill", fill_value=0)
-        tail = jnp.where(reset[None, :, None], jnp.zeros((), tail.dtype), tail)
-        n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
-        xBC, tail = ssm.causal_conv(xBC, tail, m["conv1d"]["weight"], m["conv1d"]["bias"], n_valid)
-        if slots is not None:
-            tail = tails.at[:, slots].set(tail, mode="drop", unique_indices=True)
-        conv = jax.lax.dynamic_update_index_in_dim(state.conv, tail, li, 0)
+        xBC, conv = ssm.conv_with_carry(
+            state.conv, li, xBC, m["conv1d"]["weight"], m["conv1d"]["bias"], valid, reset, slots)
         xBC = xBC.astype(hidden.dtype)
         xs = xBC[..., :d_inner].reshape(R, Q, Hn, Pd)
         Bm = xBC[..., d_inner : d_inner + G * N].reshape(R, Q, G, N)
@@ -224,18 +222,9 @@ def mamba_layer(lp, hidden, state: ssm.RecurrentState, li, valid, reset,
             )
             y = y[:, None]
         else:
-            if slots is None:
-                s = jax.lax.dynamic_index_in_dim(state.ssm, li, 0, keepdims=False)
-            else:
-                # straight from / into the stacked state: R x 2 MiB a layer,
-                # never a layer's whole (slots, ...) slice
-                s = state.ssm.at[li, slots].get(mode="fill", fill_value=0.0)
-            s = jnp.where(reset[:, None, None, None], 0.0, s)
+            s = ssm.rows_state(state.ssm, li, reset, slots)
             y, s = ssm.mamba2_chunk(xs, Bm, Cm, dt, A, s, valid, chunk_size=sspec.chunk_size)
-            if slots is None:
-                new_ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, s, li, 0)
-            else:
-                new_ssm = state.ssm.at[li, slots].set(s, mode="drop", unique_indices=True)
+            new_ssm = ssm.put_rows_state(state.ssm, s, li, slots)
         y = (y + m["D"].astype(f32)[None, None, :, None] * xs.astype(f32)).astype(hidden.dtype)
         gated = ssm.gated_rms_norm(y.reshape(R, Q, d_inner), z, m["norm"]["weight"], sspec.rms_eps,
                                    groups=sspec.norm_groups)
@@ -266,18 +255,26 @@ class HybridStack(LayerStack):
     ATTENTION block whose parameters hold an ``mlp`` is a layer of two parts
     (granite: the mixer, then the MLP, in one body); one that holds none is
     the mixer alone. Each kind's weights stay stacked over ITS layers and a
-    block reads its own with a computed index (:func:`layer_plan`)."""
+    block reads its own with a computed index (:func:`layer_plan`).
 
-    def __init__(self, layer_types: Tuple[str, ...], sspec: ssm.SSMSpec, expert_mlp=None):
+    Three more kinds of single-part block, for a model whose every layer is
+    a mixer block and then an MLP block (models/kimi_linear.py): KDA (a
+    delta-rule mixer over the per-slot state, ``kspec`` a modules/kda.KDASpec),
+    MLA (latent attention over a pool of latents, ``mla`` a
+    models/deepseek.MLASpec) and DENSE (the gated ``mlp_fn`` alone)."""
+
+    def __init__(self, layer_types: Tuple[str, ...], sspec: ssm.SSMSpec = None, expert_mlp=None,
+                 kspec=None, mla=None):
         self.layer_types = tuple(layer_types)
         self.sspec = sspec
         self.expert_mlp = expert_mlp
+        self.kspec, self.mla = kspec, mla
         self.plan = layer_plan(self.layer_types)
 
     def __call__(self, params, hidden, cache, inputs, *, spec, phase, mlp_fn):
         if phase != PHASE_TOKEN_GENERATION or inputs.block_table is None:
             raise NotImplementedError(
-                "a stack with state-space layers runs on the paged serving path only "
+                "a stack with per-slot state runs on the paged serving path only "
                 "(chunk and decode programs of the token-generation runner)"
             )
         if not isinstance(cache, HybridBlockCache):
@@ -310,7 +307,38 @@ class HybridStack(LayerStack):
             )
             return (h, k, v, st, *rest), None
 
-        bodies = {MAMBA: mamba, ATTENTION: attention}
+        def kda(carry, li):
+            from neuronx_distributed_inference_tpu.modules.kda import kda_mixer
+
+            h, k, v, st, *rest = carry
+            lp = _take(layers[KDA], li)
+            with jax.named_scope("layer.norm"):
+                x = rms_norm(h, lp["input_layernorm"]["weight"], spec.rms_eps)
+            with jax.named_scope("layer.kda"):
+                out, st = kda_mixer(lp["mixer"], x, st, li, valid, reset, self.kspec, slots=slots)
+                h = residual_add(h, out, spec)
+            return (h, k, v, st, *rest), None
+
+        def mla(carry, li):
+            from neuronx_distributed_inference_tpu.models.deepseek import mla_decoder_layer
+
+            h, k, v, *rest = carry
+            h, k, v = mla_decoder_layer(
+                _take(layers[MLA], li), h, None, None, k, v, li, mask, inputs.seq_ids,
+                positions, spec, phase, None, mla=self.mla, block_inputs=block_inputs,
+            )
+            return (h, k, v, *rest), None
+
+        def dense(carry, li):
+            h, *rest = carry
+            lp = _take(layers[DENSE], li)
+            with jax.named_scope("layer.norm"):
+                x = rms_norm(h, lp["input_layernorm"]["weight"], spec.rms_eps)
+            with jax.named_scope("layer.mlp"):
+                h = residual_add(h, mlp_fn(lp["mlp"], x, spec), spec)
+            return (h, *rest), None
+
+        bodies = {MAMBA: mamba, ATTENTION: attention, KDA: kda, MLA: mla, DENSE: dense}
         if n_moe:
             from neuronx_distributed_inference_tpu.modules.moe import (
                 hoist_expert_stacks,

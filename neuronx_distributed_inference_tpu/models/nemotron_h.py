@@ -293,21 +293,8 @@ class NemotronHModelBuilder(DecoderModelBuilder):
         bias (0.1: it changes which experts are chosen), norm weights 1, and
         the PUBLISHED initialisation of the recurrence (granite_hybrid)."""
         dtype = dtype or to_dtype(self.config.tpu_config.dtype)
-        shapes = self.param_shapes()
         std = {"experts": 0.05, "e_score_correction_bias": 0.1, "router": 0.5}
-        flat, treedef = jax.tree_util.tree_flatten_with_path(
-            shapes, is_leaf=lambda x: isinstance(x, tuple)
-        )
-        key = key if key is not None else jax.random.PRNGKey(self.config.tpu_config.seed)
-        leaves = []
-        for (path, shape), k in zip(flat, jax.random.split(key, len(flat))):
-            names = [p.key for p in path]
-            if "norm" in "/".join(names):
-                leaves.append(jnp.ones(shape, dtype))
-                continue
-            s = next((v for n, v in std.items() if n in reversed(names)), 0.02)
-            leaves.append((s * jax.random.normal(k, shape)).astype(dtype))
-        params = jax.tree_util.tree_unflatten(treedef, leaves)
+        params = self.random_tree_by_name(self.param_shapes(), std, key, dtype)
         if self.counts[MAMBA]:
             mixer = params["layers"][MAMBA]["mixer"]
             Lm, Hn = self.counts[MAMBA], self.config.mamba_num_heads

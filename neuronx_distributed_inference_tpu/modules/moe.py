@@ -400,6 +400,22 @@ def expert_mlps_grouped(
     return jnp.zeros_like(x).at[st].add(y)
 
 
+#: rows from which the dense strategy of a HELD share hands the experts'
+#: first two products the rows broadcast over the experts (``eth,ehi->eti``)
+#: in place of ``th,ehi->eti``. Read from the compiled decode program of
+#: kimi-linear-48b-a3b for a described v5e (128 rows, 16 held experts of
+#: 2304 x 1024, 15 layers; tests/test_chip_compile.py): at 128 rows the
+#: compiler makes the weights the stationary operand of the unbatched form
+#: and re-lays the WHOLE (layers, experts, in, out) gate and up stacks with
+#: the hidden size minor, 2 x 1.05 GiB copied on every step before the layer
+#: loop; the batched form reads the stacks as stored (temporaries 2.22 ->
+#: 0.11 GiB). Under that many rows, and for a model that holds every expert,
+#: the form is what it was (the cells the benchmark had decode 32-64 rows
+#: under a share; sdar-30b-a3b's block step of 192 rows holds no share and
+#: was not read: PERF.md section 7).
+_BATCHED_ROWS = 128
+
+
 def expert_mlps_dense(
     params: dict,
     x: jax.Array,  # (T, H)
@@ -468,8 +484,13 @@ def expert_mlps_dense(
             selected if selected is not None else (affinities != 0)
         ).astype(x.dtype)  # (T, E)
         return jnp.einsum("te,eth->th", sel, y)
-    g = expert_mm(params["gate_proj"], x, "th,ehi->eti")
-    u = expert_mm(params["up_proj"], x, "th,ehi->eti")
+    xe, into = x, "th,ehi->eti"
+    if spec.holds_share and x.shape[0] >= _BATCHED_ROWS:
+        # the rows laid over the held experts: a batched product that reads the
+        # stacks as they are stored (module note at _BATCHED_ROWS)
+        xe, into = jnp.broadcast_to(x[None], (spec.held,) + x.shape), "eth,ehi->eti"
+    g = expert_mm(params["gate_proj"], xe, into)
+    u = expert_mm(params["up_proj"], xe, into)
     y = expert_mm(params["down_proj"], glu(g, u), "eti,eih->eth")  # (E, T, H)
     return jnp.einsum("te,eth->th", aff, y)
 

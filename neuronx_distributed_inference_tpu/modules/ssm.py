@@ -36,7 +36,7 @@ dtype.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +100,7 @@ class RecurrentState:
     def fill_slots(self, slots, value: float) -> "RecurrentState":
         """Overwrite the state of whole slots in every layer (scrub: 0.0)."""
         idx = jnp.asarray(slots, jnp.int32)
-        return RecurrentState(
+        return type(self)(
             conv=self.conv.at[:, :, idx].set(value), ssm=self.ssm.at[:, idx].set(value)
         )
 
@@ -145,7 +145,7 @@ def causal_conv(
     xBC: jax.Array,  # (R, Q, C) this pass's inputs
     tail: jax.Array,  # (K-1, R, C) the last K-1 VALID inputs before this pass
     weight: jax.Array,  # (K, C)
-    bias: jax.Array,  # (C,)
+    bias: Optional[jax.Array],  # (C,), or None: a conv without one
     n_valid: jax.Array,  # (R,) int32: valid positions are [0, n_valid)
     activation=jax.nn.silu,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -156,11 +156,48 @@ def causal_conv(
     Q = xBC.shape[1]
     window = carried_window(xBC, tail)
     w = weight.astype(jnp.float32)
-    acc = bias.astype(jnp.float32)[None, None, :]
+    acc = 0.0 if bias is None else bias.astype(jnp.float32)[None, None, :]
     for k in range(K):
         acc = acc + w[k][None, None, :] * window[:, k : k + Q].astype(jnp.float32)
     out = acc if activation is None else activation(acc)
     return out, tail_after(window, n_valid, K - 1, tail.dtype)
+
+
+def conv_with_carry(conv_state, li, x, weight, bias, valid, reset, slots=None):
+    """:func:`causal_conv` of one layer over the STACKED tails ``conv_state``
+    (L, K-1, slots, C): the rows' tails taken at layer ``li`` (by ``slots``
+    where the rows carry their slot, the chunk program; row r = slot r where
+    None, the decode program), zeroed for ``reset`` rows, advanced over the
+    ``valid`` (R, Q) positions and put back (an empty row's write is
+    dropped). Returns (out (R, Q, C) float32, the stacked tails)."""
+    tails = jax.lax.dynamic_index_in_dim(conv_state, li, 0, keepdims=False)
+    tail = tails if slots is None else jnp.take(tails, slots, axis=1, mode="fill", fill_value=0)
+    tail = jnp.where(reset[None, :, None], jnp.zeros((), tail.dtype), tail)
+    n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+    out, tail = causal_conv(x, tail, weight, bias, n_valid)
+    if slots is not None:
+        tail = tails.at[:, slots].set(tail, mode="drop", unique_indices=True)
+    return out, jax.lax.dynamic_update_index_in_dim(conv_state, tail, li, 0)
+
+
+def rows_state(state, li, reset, slots=None):
+    """The rows' float32 state of layer ``li`` from the STACKED ``state`` (L,
+    slots, ...): straight from the stack by ``slots`` (R x 2 MiB a layer,
+    never a layer's whole slice) or the layer's slice where row r = slot r;
+    ``reset`` rows start from zero."""
+    if slots is None:
+        s = jax.lax.dynamic_index_in_dim(state, li, 0, keepdims=False)
+    else:
+        s = state.at[li, slots].get(mode="fill", fill_value=0.0)
+    return jnp.where(reset[(slice(None),) + (None,) * (s.ndim - 1)], 0.0, s)
+
+
+def put_rows_state(state, s, li, slots=None):
+    """The stacked ``state`` with the rows' state ``s`` back at layer ``li``
+    (:func:`rows_state`'s inverse; an empty row's write is dropped)."""
+    if slots is None:
+        return jax.lax.dynamic_update_index_in_dim(state, s, li, 0)
+    return state.at[li, slots].set(s, mode="drop", unique_indices=True)
 
 
 # ---------------------------------------------------------------------------

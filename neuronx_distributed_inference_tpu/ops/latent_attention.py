@@ -55,6 +55,7 @@ the float32 probabilities of the dense call take three.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -324,6 +325,16 @@ def paged_latent_decode_attention(
         m = jnp.pad(jnp.tile(m, (1, 1, 1, Hq, 1)), ((0, 0),) * 3 + ((0, R - rk), (0, 0)))
     qc, qr = q_rows(q_c), _rope_rows(q_rows(q_pe), pack)
     li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
+    # what a grid step holds in VMEM, as decode_attention.paged_tkg_decode_attention
+    # reckons it: the row's mask slab, its queries and its output, each twice
+    # (pipelined), and the two slots of latents and keys. Under the compiler's
+    # own scoped limit nothing is asked; (heads x K) score rows over a long
+    # bucket (32 heads x 8 positions x 8192 keys: 16 MiB of slab, refused by
+    # 1.1 MiB on a described v5e) ask for what they need
+    item = jnp.dtype(c_cache.dtype).itemsize
+    held = (2 * math.prod(m.shape[1:]) * 4 + 2 * R * (2 * r + pack * lanes) * jnp.dtype(q_c.dtype).itemsize
+            + 2 * P * (bs * r + rows * lanes) * item)
+    vmem_limit = None if held <= _da.SCOPED_VMEM_BYTES else held + 8 * 2**20
     out = _da._common_call(
         functools.partial(
             _decode_kernel, scale=scale, P=P,
@@ -347,6 +358,7 @@ def paged_latent_decode_attention(
         name=DECODE_KERNEL,
         # rows in order: a row's last group starts the next live row's copies
         semantics=("arbitrary",),
+        vmem_limit_bytes=vmem_limit,
     )
     return _da._unprep_out(out[:, :rk], B, K, Hq, r)
 

@@ -398,7 +398,7 @@ class ServingSession:
         # a model whose layers keep a constant-size per-slot state
         # (HybridBlockCache.state: state-space layers, a one-token carry):
         # the scrub of a slot covers it, and the family its KIND names
-        # counts it (nxdi_ssm_*, nxdi_latent_carry_*); a model with routed
+        # counts it (nxdi_ssm_*, nxdi_kda_*, nxdi_latent_carry_*); a model with routed
         # experts that says so is counted by nxdi_moe_* (_count_pass)
         state = getattr(app.kv_cache, "state", None)
         self.slot_state = state is not None
@@ -2129,8 +2129,9 @@ class ServingSession:
             self.tel.kv_blocks(program, *kv_blocks)
         if block_rows is not None:
             self.tel.block_pass(*block_rows, positions=tokens)
-        if self.slot_state_kind == "ssm":
-            self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets)
+        if self.slot_state_kind in ("ssm", "kda"):
+            self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets,
+                              kind=self.slot_state_kind)
         elif self.slot_state_kind == "latent_carry":
             self.tel.carry_pass(program, rows)
         if self.latent_layers:

@@ -45,6 +45,7 @@ DEVICE_SCOPES = (
     "layer.moe.experts",
     "layer.shared_mlp",
     "layer.ssm",
+    "layer.kda",
     "layer.other",
     "loop.norm",
     "head",

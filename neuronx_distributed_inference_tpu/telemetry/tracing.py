@@ -391,6 +391,18 @@ class TelemetrySession:
             "nxdi_ssm_state_bytes",
             "HBM of the per-slot recurrent state (conv tails + float32 SSM "
             "state, every state-space layer, every slot)")
+        self._kda_rows = r.counter(
+            "nxdi_kda_rows_advanced_total",
+            "rows whose delta-rule (KDA linear attention) state a dispatch of "
+            "the split serving step advanced", labels=("program",))
+        self._kda_resets = r.counter(
+            "nxdi_kda_state_resets_total",
+            "rows a chunk pass started from a zero delta-rule state (first "
+            "position 0: a new request, or a re-prefill after preemption)")
+        self._kda_bytes = r.gauge(
+            "nxdi_kda_state_bytes",
+            "HBM of the per-slot delta-rule state (conv tails + the float32 "
+            "matrix state a head, every KDA layer, every slot)")
         self._carry_rows = r.counter(
             "nxdi_latent_carry_rows_advanced_total",
             "rows whose one-token carry (latent attention with conv mixing: "
@@ -1269,18 +1281,24 @@ class TelemetrySession:
         self._decode_rows.inc(rows)
         self._decode_slots.inc(slots)
 
-    def ssm_pass(self, program: str, rows: int, state_bytes: int, resets: int = 0) -> None:
-        """One dispatch of the split serving step over a model with
-        state-space layers: the rows whose recurrent state it advanced
+    def ssm_pass(self, program: str, rows: int, state_bytes: int, resets: int = 0,
+                 kind: str = "ssm") -> None:
+        """One dispatch of the split serving step over a model whose layers
+        keep a recurrent state a slot: the rows whose state it advanced
         (``program``: "decode" or "chunk"), of those the rows it started
-        from zero, and the bytes the state of all slots holds. Counted from
+        from zero, and the bytes the state of all slots holds. ``kind`` is
+        the state's ``KIND``: ``ssm`` (state-space layers, ``nxdi_ssm_*``) or
+        ``kda`` (delta-rule linear attention, ``nxdi_kda_*``). Counted from
         what the step already knows."""
         if not self.enabled:
             return
-        self._ssm_rows.child((program,)).inc(rows)
-        self._ssm_bytes.set(state_bytes)
+        rows_total, resets_total, bytes_held = (
+            (self._kda_rows, self._kda_resets, self._kda_bytes) if kind == "kda"
+            else (self._ssm_rows, self._ssm_resets, self._ssm_bytes))
+        rows_total.child((program,)).inc(rows)
+        bytes_held.set(state_bytes)
         if resets:
-            self._ssm_resets.inc(resets)
+            resets_total.inc(resets)
 
     def carry_pass(self, program: str, rows: int) -> None:
         """One pass of the split serving step over a model that keeps a

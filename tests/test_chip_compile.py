@@ -92,6 +92,7 @@ MAIN_PATH_KERNELS = [
     ("ragged_paged_attention", "mixed", "int8"),
     ("quant_matmul", "k4096_n14336", "bfloat16"),  # 8B int4 weights
     ("ssm_state_update", "rows48", "float32"),  # granite-4.0-h-micro decode, 48 slots
+    ("kda_state_update", "rows128", "float32"),  # kimi-linear-48b-a3b decode, 128 slots
     ("grouped_matmul", "k2048_n2048", "bfloat16"),  # zaya1-8b's chunk program: 1024 rows, 16 experts of a 20-layer stack
     ("grouped_matmul", "k2048_n768", "bfloat16"),  # sdar-30b-a3b's gate and up: 8192 rows, 128 experts
     ("grouped_matmul", "k768_n2048", "bfloat16"),  # its down
@@ -814,7 +815,7 @@ def _work_under_no_scope(compiled):
     from neuronx_distributed_inference_tpu.telemetry import device_scopes
 
     table = device_scopes.scope_table(compiled.as_text())["ops"]
-    work = ("convolution", "dot", "grouped_matmul", "ssm_state_update", "paged_", "reduce")
+    work = ("convolution", "dot", "grouped_matmul", "ssm_state_update", "kda_state_update", "paged_", "reduce")
     return sorted(name for name, scope in table.items()
                   if scope == device_scopes.LAYER_OTHER and name.startswith(work))
 
@@ -1070,5 +1071,118 @@ def test_mellum_serving_step_walks_each_kinds_keys_and_fits_the_chip(chip_mesh, 
     mem = compiled.memory_analysis()
     print(f"\nmellum2-12b-a2.5b {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
           f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# kimi-linear-48b-a3b: a delta-rule matrix state a head beside a latent pool
+# ---------------------------------------------------------------------------
+
+
+def _abstract_kimi_linear_app(mesh):
+    """benchmark/configs/kimi-linear-48b-a3b.json over a described chip: params,
+    the latent pool and the per-slot delta-rule state as ShapeDtypeStructs."""
+    import json
+    import os
+
+    from benchmark.harness import system
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        HybridBlockCache,
+        init_block_cache,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "kimi-linear-48b-a3b.json")) as f:
+        file = json.load(f)
+    # the auto gates ask jax.default_backend(), which is the CPU here
+    file["tpu_config"].update(attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True)
+    app = system.build_app(file, mesh.devices.ravel().tolist(), 0)
+    tc, b = app.config.tpu_config, app.builder
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, P()))
+
+    def cache():
+        pool = init_block_cache(app.paged_layers, tc.pa_num_blocks, tc.pa_block_size,
+                                dtype=jnp.bfloat16, streams=b.cache_streams())
+        return HybridBlockCache(k=pool.k, v=pool.v, state=b.init_slot_state(tc.batch_size)[0])
+
+    params = jax.tree.map(place, jax.eval_shape(b.random_params))
+    return app, params, jax.tree.map(place, jax.eval_shape(cache))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_kimi_linear_serving_step_updates_the_state_in_place_and_fits_the_chip(
+        chip_mesh, program, monkeypatch):
+    """kimi-linear-48b-a3b at the benchmark's widths (benchmark/configs/
+    kimi-linear-48b-a3b.json: 16 of 27 layers = 12 KDA + 4 MLA, one dense MLP
+    and 15 expert layers of 16 held experts of 256, an eighth of the
+    vocabulary, 128 slots, 20480 blocks over the 4 MLA layers), both step
+    programs compiled for a described v5e at kv bucket 8192.
+
+    The cache is a LATENT pool (512 + 64 numbers a token a layer, the key
+    packed two tokens a lane row) beside a per-slot state of (12, 128, 32,
+    128, 128) float32 = 3 GiB. decode (128 x 1): ``kda_state_update`` under
+    ``layer.kda`` (the state aliased in and out: no copy of the state's
+    shape, whole or one layer's) and ``paged_latent_decode_attention``; the
+    experts are the batched products over the 16 held. chunk (8 x 128):
+    ``paged_latent_flash_attention``, the chunked delta rule as XLA's own
+    products (no state kernel) and the three grouped products of an expert
+    layer as ``grouped_matmul`` on the stacks in place. Neither copies the
+    pool; each plans under 14.75 GiB."""
+    from neuronx_distributed_inference_tpu.models.granite_hybrid import layer_plan
+    from neuronx_distributed_inference_tpu.ops import kernel_mode, latent_attention
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    # the gates ask jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
+    app, params, cache = _abstract_kimi_linear_app(chip_mesh(1))
+    assert cache.k.shape == (4, 20481, 1, 32, 512) and cache.v.shape == (4, 20481, 1, 16, 128)
+    assert cache.state.ssm.shape == (12, 128, 32, 128, 128) and cache.state.conv.shape == (12, 3, 128, 12288)
+    experts = params["layers"]["moe"]["mlp"]["experts"]
+    assert experts["gate_proj"]["weight"].shape == (15, 16, 2304, 1024)
+    assert params["layers"]["moe"]["mlp"]["router"]["weight"].shape == (15, 2304, 256)
+    assert params["lm_head"]["weight"].shape == (2304, 20480)
+    bodies = sum(len(runs) for _, runs, _ in layer_plan(app.builder.layer_types))
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
+    assert inputs.input_ids.shape == ((128, 1) if program == "decode" else (8, 128))
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    _assert_experts_sort_real_positions(tkg, program)
+    text = compiled.as_text()
+    table = device_scopes.scope_table(text)["ops"]
+    assert "ragged-dot" not in text
+    assert not _copies_of(compiled, "f32", cache.state.ssm.shape)
+    assert not _copies_of(compiled, "f32", cache.state.ssm.shape[1:])
+    assert _pool_copies(compiled, cache.k.shape)[0] == 0 and _pool_copies(compiled, cache.v.shape)[0] == 0
+    gmm = [name for name in table if name.startswith("grouped_matmul")]
+    kda = [name for name in table if name.startswith("kda_state_update")]
+    stacks = [(16, 2304, 1024), (16, 1024, 2304)]
+    if program == "decode":
+        assert "paged_latent_decode_attention" in text and not gmm
+        assert kda and {table[c] for c in kda} == {"layer.kda"}
+        # 128 rows over 16 held experts: the batched form reads the stacks as stored (the
+        # unbatched one re-laid both whole stacks, 2 x 1.05 GiB, on every step: modules/moe.py)
+        assert not re.search(r"= \w+\[15,16,\d+,\d+\]\S* copy\(", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+    else:
+        assert "paged_latent_flash_attention" in text and not kda
+        assert not _stack_shaped(compiled, stacks), _stack_shaped(compiled, stacks)[:3]
+        assert gmm and len(gmm) % 3 == 0 and {table[c] for c in gmm} == {"layer.moe.experts"}
+    assert not _work_under_no_scope(compiled), _work_under_no_scope(compiled)[:5]
+    if program == "chunk":
+        # the narrowest chunk pass: 32 heads x 8 positions are 256 score rows of the latent
+        # DECODE kernel, whose mask slab over the 8192 bucket asks VMEM for what it holds
+        # (refused by 1.1 MiB under the compiler's own limit), and 64 rows of XLA's own
+        # expert products copy no stack of expert weights
+        narrow = _compile_step(app, tkg, tkg.example_inputs(8192, q_len=8), params, cache)
+        assert "paged_latent_decode_attention" in narrow.as_text()
+        assert not re.search(r"= \w+\[15,16,\d+,\d+\]\S* copy\(", narrow.as_text())
+        assert _planned_bytes(narrow) < 14.75 * 2**30
+    mem = compiled.memory_analysis()
+    print(f"\nkimi-linear-48b-a3b {program}: {bodies} block bodies, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB, temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
