@@ -206,19 +206,20 @@ def _paged_tkg_case(B, MB, bs, cache_dtype, m=_1B, K=1):
     return build
 
 
-def _ssm_update_case(rows):
-    """Granite-4.0-H-micro's state-space geometry (36 layers, 64 heads of 64,
-    state 128) at ``rows`` serving slots: the benchmark's decode cell."""
+def _ssm_update_case(rows, groups, layers):
+    """The two served Mamba-2 geometries (64 heads of 64, state 128) at the
+    benchmark's decode cells: Granite-4.0-H-micro (48 slots, one group of B/C,
+    36 layers) and ``nemotron_h`` (64 slots, eight groups, 6 layers held)."""
 
     def build():
         import jax.numpy as jnp
 
         from neuronx_distributed_inference_tpu.ops import ssm_state_update as su
 
-        L, H, P, N = 36, 64, 64, 128
-        state = _sds((L, rows, H, P, N), jnp.float32)
+        H, P, N = 64, 64, 128
+        state = _sds((layers, rows, H, P, N), jnp.float32)
         x = _sds((rows, H, P), jnp.bfloat16)
-        bc = _sds((rows, N), jnp.bfloat16)
+        bc = _sds((rows, groups, N), jnp.bfloat16)
         args = (state, _sds((), jnp.int32), x, bc, bc, _sds((rows, H), jnp.float32),
                 _sds((H,), jnp.float32), _sds((rows,), jnp.bool_), _sds((rows,), jnp.bool_))
         return _unjit(su.ssm_state_update), args
@@ -595,9 +596,16 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         fallback="neuronx_distributed_inference_tpu.modules.ssm:mamba2_step",
         parity_test="tests/test_ssm.py",
         lowering_test="tests/test_chip_compile.py",
-        # heads_per_block is a keyword of the entry (16: a 512 KiB tile),
-        # not a tuning-table entry: nothing was swept on the chip yet
-        cases=(KernelCase("rows48", "float32", _ssm_update_case(48)),),
+        # ``heads``: the heads a tile (the entry's ``heads_per_block`` keyword
+        # overrides it). Swept on the chip at both shapes (PERF.md, PR 63):
+        # 16 / 32 / 64 heads lie within 1% of each other with the read-out on
+        # the matrix unit; 32 (a 1 MiB tile) is the table's at both
+        tile_params=("heads",),
+        sweep=(("heads", (16, 32, 64)),),
+        cases=(
+            KernelCase("h64g1x64x128", "float32", _ssm_update_case(48, 1, 36)),
+            KernelCase("h64g8x64x128", "float32", _ssm_update_case(64, 8, 6)),
+        ),
     ),
     KernelSpec(
         name="kda_state_update",
@@ -657,6 +665,8 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     # latent_attention.Q_ROWS, and GROUP_TOKENS (1024) over the block's 32 tokens
     "paged_latent_flash_attention": {"blk1x32x512": {"rows": 512, "pages": 32}},
     "ragged_paged_attention": {"*": {"tq": 16}},
+    # ssm_state_update.DEFAULT_HEADS_PER_BLOCK
+    "ssm_state_update": {"*": {"heads": 16}},
     "grouped_matmul": {"*": {"tm": 128}},
     "quant_matmul": {"*": {"bn": 256}},
 }
@@ -733,7 +743,7 @@ def _dot_stats(jaxpr, out):
 
 
 #: vector-unit arithmetic counted for a kernel that has NO matrix product
-#: (ssm_state_update, kda_state_update: multiply-adds over a float32 tile and a sum)
+#: (kda_state_update: multiply-adds over a float32 tile and two sums)
 _VECTOR_OPS = frozenset({"mul", "add", "sub", "reduce_sum"})
 
 

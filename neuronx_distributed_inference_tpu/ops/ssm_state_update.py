@@ -19,9 +19,20 @@ update — ``dt x`` and the decay ``exp(dt A)`` — must then be broadcast along
 lanes, so they arrive with ``P`` on the sublanes: one packed operand
 ``coef (rows, heads/hb, P, 2 hb)``, columns ``[0, hb)`` = ``dt x`` of the
 block's heads, ``[hb, 2 hb)`` = their decays. ``B`` and ``C`` are lane
-vectors. ``y`` leaves as ``(rows, heads/hb, P, hb)`` and is transposed back
-outside. The two small operands cost ~3% of the state's bytes each at
+vectors. The two small operands cost ~3% of the state's bytes each at
 ``hb = 16``.
+
+The read-out ``y = S . C`` sums over the LANES. It is one product on the
+matrix unit, which this kernel leaves idle otherwise: ``C (groups a block,
+N)`` against the tile's new state ``(hb P, N)``, both contracted over their
+lanes at ``precision=HIGHEST`` (float32 in, float32 out), so ``y`` leaves as
+one lane-dense row ``(1, hb P)`` a tile and is ``(rows, heads, P)`` outside by
+a reshape. As 64 lane reductions and 16 selects a tile on the vector and
+cross-lane units the kernel read 56% of 819 GB/s alone at Granite's shape;
+with this read-out it reads 80%, which is what the tile copied in and out
+with no arithmetic reads (PERF.md, PR 63). The tile's size moves nothing
+between 512 KiB and 2 MiB; the heads a tile come from the tuning table by
+the call's shape (``analysis/tuning_table.json``, 32 at both served shapes).
 
 Validity: ``dt = 0`` for an invalid row (decay 1, increment 0), so its state
 is rewritten bit for bit. ``reset`` rows start from zero (a select on the
@@ -44,7 +55,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: heads per tile: 16 x (64, 128) float32 = 512 KiB in, as much out
+from neuronx_distributed_inference_tpu.ops import decode_attention as _da
+from neuronx_distributed_inference_tpu.ops.tile_defaults import tile_default
+
+KERNEL = "ssm_state_update"
+
+#: heads per tile where the tuning table has no entry for the call's shape:
+#: 16 x (64, 128) float32 = 512 KiB in, as much out
 DEFAULT_HEADS_PER_BLOCK = 16
 
 
@@ -53,17 +70,22 @@ def _kernel(li_ref, reset_ref, coef_ref, b_ref, c_ref, s_ref, y_ref, out_ref, *,
     P, N = s_ref.shape[-2], s_ref.shape[-1]
     coef = coef_ref[...]  # (P, 2 hb)
     bs = b_ref[...]  # (groups a block, N)
-    cs = c_ref[...]
     from_zero = jnp.full((P, N), reset_ref[r], jnp.int32) != 0
-    lane = jax.lax.broadcasted_iota(jnp.int32, (P, hb), 1)
-    y = jnp.zeros((P, hb), jnp.float32)
     for i in range(hb):
         g = i // hpg  # the block's group that head i reads
-        b, c = (bs, cs) if bs.shape[0] == 1 else (bs[g : g + 1], cs[g : g + 1])
+        b = bs if bs.shape[0] == 1 else bs[g : g + 1]
         s = jnp.where(from_zero, 0.0, s_ref[i])
-        new = s * coef[:, hb + i : hb + i + 1] + coef[:, i : i + 1] * b
-        out_ref[i] = new
-        y = jnp.where(lane == i, jnp.sum(new * c, axis=1, keepdims=True), y)
+        out_ref[i] = s * coef[:, hb + i : hb + i + 1] + coef[:, i : i + 1] * b
+    # y = S . C of the whole tile in one product: (groups a block, hb P)
+    y = jax.lax.dot_general(
+        c_ref[...], out_ref[...].reshape(hb * P, N), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    if y.shape[0] > 1:  # each head's lanes from the row of its own group
+        row = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+        own = (lane >= row * (hpg * P)) & (lane < (row + 1) * (hpg * P))
+        y = jnp.sum(jnp.where(own, y, 0.0), axis=0, keepdims=True)
     y_ref[...] = y
 
 
@@ -76,6 +98,15 @@ def pick_heads_per_block(num_heads: int, want: int = DEFAULT_HEADS_PER_BLOCK,
     while num_heads % hb or (hb % hpg and hpg % hb):
         hb -= 1
     return hb
+
+
+def heads_wanted(num_heads: int, groups: int, head_dim: int, state_size: int) -> int:
+    """The heads a tile the tuning table gives the call's shape under the
+    kernel's name (``h64g1x64x128``: Granite-4.0-H; ``h64g8x64x128``:
+    ``nemotron_h``), ``DEFAULT_HEADS_PER_BLOCK`` where it has no entry;
+    ``pick_heads_per_block`` then holds it to the kernel's rule."""
+    shape_class = f"h{num_heads}g{groups}x{head_dim}x{state_size}"
+    return tile_default(KERNEL, shape_class, "float32", "heads", DEFAULT_HEADS_PER_BLOCK)
 
 
 @functools.partial(jax.jit, static_argnames=("heads_per_block", "interpret"))
@@ -98,7 +129,7 @@ def ssm_state_update(
     L, R, H, P, N = state.shape
     G = B.shape[1] if B.ndim == 3 else 1
     hpg = H // G  # heads a group
-    hb = heads_per_block or pick_heads_per_block(H, groups=G)
+    hb = heads_per_block or pick_heads_per_block(H, heads_wanted(H, G, P, N), groups=G)
     assert H % hb == 0 and H % G == 0 and (hb % hpg == 0 or hpg % hb == 0), (H, G, hb)
     J = H // hb
     gpb = max(1, hb // hpg)  # groups a head block covers
@@ -122,6 +153,11 @@ def ssm_state_update(
     li = jnp.reshape(layer_idx, (1,)).astype(jnp.int32)
     flags = (reset & valid).astype(jnp.int32)
     tile = pl.BlockSpec((None, None, hb, P, N), lambda r, j, li, rs: (li[0], r, j, 0, 0))
+    # what a grid step holds in VMEM: the tile in and out and the small operands,
+    # each twice (pipelined). Under the compiler's own scoped limit nothing is
+    # asked; a tile past 2 MiB asks for what it needs, by decode_attention's rule
+    held = 2 * 4 * (2 * hb * P * N + 2 * hb * P + 2 * gpb * N + hb * P)
+    vmem_limit = None if held <= _da.SCOPED_VMEM_BYTES else held + 8 * 2**20
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(R, J),
@@ -132,7 +168,7 @@ def ssm_state_update(
             tile,
         ],
         out_specs=[
-            pl.BlockSpec((None, None, P, hb), lambda r, j, li, rs: (r, j, 0, 0)),
+            pl.BlockSpec((None, None, 1, hb * P), lambda r, j, li, rs: (r, j, 0, 0)),
             tile,
         ],
     )
@@ -140,16 +176,16 @@ def ssm_state_update(
         functools.partial(_kernel, hb=hb, hpg=hpg),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((R, J, P, hb), f32),
+            jax.ShapeDtypeStruct((R, J, 1, hb * P), f32),
             jax.ShapeDtypeStruct(state.shape, state.dtype),
         ],
         # operands: li, flags, coef, B, C, state -> outputs: y, state
         input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
-        name="ssm_state_update",
+        name=KERNEL,
     )(li, flags, coef, B.astype(f32).reshape(R * n_gb, gpb, N),
       C.astype(f32).reshape(R * n_gb, gpb, N), state)
-    return jnp.transpose(y, (0, 1, 3, 2)).reshape(R, H, P), new
+    return y.reshape(R, H, P), new

@@ -91,7 +91,8 @@ MAIN_PATH_KERNELS = [
     ("ragged_paged_attention", "mixed", "bfloat16"),  # ragged mixed step
     ("ragged_paged_attention", "mixed", "int8"),
     ("quant_matmul", "k4096_n14336", "bfloat16"),  # 8B int4 weights
-    ("ssm_state_update", "rows48", "float32"),  # granite-4.0-h-micro decode, 48 slots
+    ("ssm_state_update", "h64g1x64x128", "float32"),  # granite-4.0-h-micro decode: 48 slots, one group, 32 heads a tile
+    ("ssm_state_update", "h64g8x64x128", "float32"),  # nemotron-3-nano-30b-a3b decode: 64 slots, 8 groups of B/C
     ("kda_state_update", "rows128", "float32"),  # kimi-linear-48b-a3b decode, 128 slots
     ("grouped_matmul", "k2048_n2048", "bfloat16"),  # zaya1-8b's chunk program: 1024 rows, 16 experts of a 20-layer stack
     ("grouped_matmul", "k2048_n768", "bfloat16"),  # sdar-30b-a3b's gate and up: 8192 rows, 128 experts
@@ -614,7 +615,9 @@ def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, pro
     step programs compiled for a described v5e.
 
     decode: ``ssm_state_update`` (state aliased in and out, one layer's tiles
-    visited) is in the executable, there is NO copy of the state's shape, and
+    visited, 32 heads a tile as the tuning table gives this shape: ``y`` leaves
+    as 2 lane-dense rows of 32 x 64 a slot) is in the executable, there is NO
+    copy of the state's shape, and
     the temporaries are the four copies of the 0.27 GB block pool that the
     chip's default layout at head_dim 64 costs the paged kernels (PERF.md
     section 7) and nothing of the state's size: under 1.2 GB, where one copy
@@ -635,6 +638,7 @@ def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, pro
     mem = compiled.memory_analysis()
     if program == "decode":
         assert "ssm_state_update" in text and "paged_tkg_decode_attention" in text
+        assert "f32[48,2,1,2048]" in text  # the kernel's y at the tile taken
         assert mem.temp_size_in_bytes < 1.2e9
         assert len(_copies_of(compiled, "bf16", cache.k.shape)) <= 4
     else:
@@ -830,7 +834,8 @@ def test_nemotron_serving_step_runs_its_kernels_in_place_and_fits_the_chip(
 
     decode (64 x 1): ``ssm_state_update`` with 8 groups of B/C under
     ``layer.ssm`` (state aliased in and out: no copy of the state's shape,
-    whole or one block's) and ``paged_tkg_decode_attention``; the experts are
+    whole or one block's; 32 heads a tile, four whole groups, as the tuning
+    table gives this shape) and ``paged_tkg_decode_attention``; the experts are
     the batched products over the 64 held. chunk (8 x 128):
     ``paged_flash_attention`` and the TWO grouped products of a two-matrix
     expert as ``grouped_matmul`` on the stacks in place under
@@ -867,6 +872,7 @@ def test_nemotron_serving_step_runs_its_kernels_in_place_and_fits_the_chip(
         assert "paged_tkg_decode_attention" in text and not gmm
         # one kernel call a state-space body of the plan: (ME) x 2, M *, (EM) x 3
         assert len(ssm) == 3 and {table[c] for c in ssm} == {"layer.ssm"}
+        assert "f32[64,2,1,2048]" in text  # the kernel's y at the tile taken
     else:
         assert "paged_flash_attention" in text and not ssm
         assert not _stack_shaped(compiled, [(64, 1856, 2688)]), _stack_shaped(compiled, [(64, 1856, 2688)])[:3]

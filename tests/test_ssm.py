@@ -142,38 +142,102 @@ def test_pallas_state_update_is_the_step(heads_per_block):
         assert np.array_equal(np.asarray(new[other]), np.asarray(stacked[other]))
 
 
+def _held_to_the_step(heads, p, n, groups, rows=4, L=2, li=1, dirty=False, seed=0, bf16_bc=False, **kernel_kw):
+    """One call of the kernel against ``mamba2_step``: row 1 invalid, row 2
+    reset (``dirty``: its old state holds large and non-finite values, which a
+    select and not a product must drop); ``kernel_kw``: ``heads_per_block``."""
+    from neuronx_distributed_inference_tpu.ops.ssm_state_update import ssm_state_update
+
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    x, B, C = f(rows, heads, p), f(rows, groups, n), f(rows, groups, n)
+    if bf16_bc:  # as the serving path hands them over
+        x, B, C = (a.astype(jnp.bfloat16) for a in (x, B, C))
+    dt = jnp.asarray(rng.uniform(0.01, 0.5, (rows, heads)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(0.5, 2.0, (heads,)), jnp.float32)
+    stacked = np.asarray(rng.standard_normal((L, rows, heads, p, n)), np.float32)
+    if dirty:
+        stacked[li, 2] = np.resize(np.array([3e38, np.inf, np.nan], np.float32), stacked[li, 2].shape)
+    stacked = jnp.asarray(stacked)
+    valid = jnp.asarray([True, False, True, True][:rows])
+    reset = jnp.asarray([False, False, True, False][:rows])
+    y, new = ssm_state_update(stacked, jnp.int32(li), x, B, C, dt, A, valid, reset,
+                              interpret=True, **kernel_kw)
+    start = jnp.where(reset[:, None, None, None], 0.0, stacked[li])
+    y_ref, s_ref = ssm.mamba2_step(x, B, C, dt, A, start, valid)
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(new[li])).all()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[li]), np.asarray(s_ref), rtol=1e-6, atol=1e-6)
+    assert np.array_equal(np.asarray(new[li, 1]), np.asarray(stacked[li, 1]))  # the invalid row
+    for other in range(L):  # the other layers are not touched
+        if other != li:
+            assert np.array_equal(np.asarray(new[other]), np.asarray(stacked[other]), equal_nan=True)
+
+
 @pytest.mark.parametrize("groups,heads_per_block", [(1, 4), (2, 2), (2, 4), (8, 1), (8, 2), (8, 8)])
 def test_pallas_state_update_with_groups_is_the_step(groups, heads_per_block):
     """B and C of (rows, G, N): head h reads group h // (heads / G), whether a
     head block lies inside one group or covers whole groups; an invalid row's
     state is rewritten bit for bit, a reset row starts from zero."""
-    from neuronx_distributed_inference_tpu.ops.ssm_state_update import (
-        pick_heads_per_block,
-        ssm_state_update,
-    )
+    from neuronx_distributed_inference_tpu.ops.ssm_state_update import pick_heads_per_block
 
-    rows, heads, p, n, L, li = 4, 8, 8, 16, 2, 1
-    rng = np.random.default_rng(60 + groups)
-    x = jnp.asarray(rng.standard_normal((rows, heads, p)), jnp.float32)
-    B = jnp.asarray(rng.standard_normal((rows, groups, n)), jnp.float32)
-    C = jnp.asarray(rng.standard_normal((rows, groups, n)), jnp.float32)
-    dt = jnp.asarray(rng.uniform(0.01, 0.5, (rows, heads)), jnp.float32)
-    A = -jnp.asarray(rng.uniform(0.5, 2.0, (heads,)), jnp.float32)
-    stacked = jnp.asarray(rng.standard_normal((L, rows, heads, p, n)), jnp.float32)
-    valid = jnp.asarray([True, False, True, True])
-    reset = jnp.asarray([False, False, True, False])
-    y, new = ssm_state_update(stacked, jnp.int32(li), x, B, C, dt, A, valid, reset,
-                              heads_per_block=heads_per_block, interpret=True)
-    start = jnp.where(reset[:, None, None, None], 0.0, stacked[li])
-    y_ref, s_ref = ssm.mamba2_step(x, B, C, dt, A, start, valid)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(new[li]), np.asarray(s_ref), rtol=1e-6, atol=1e-6)
-    assert np.array_equal(np.asarray(new[li, 1]), np.asarray(stacked[li, 1]))  # the invalid row
-    assert np.array_equal(np.asarray(new[0]), np.asarray(stacked[0]))  # the other layer
+    _held_to_the_step(8, 8, 16, groups, seed=60 + groups, heads_per_block=heads_per_block)
     # the block the kernel picks for itself lies inside a group or covers whole ones
     hb = pick_heads_per_block(64, groups=groups)
     assert 64 % hb == 0 and (hb % (64 // groups) == 0 or (64 // groups) % hb == 0)
     assert pick_heads_per_block(64, groups=1) == pick_heads_per_block(64) == 16
+
+
+@pytest.mark.parametrize("dirty", [False, True], ids=["clean", "nonfinite_reset_row"])
+@pytest.mark.parametrize("groups", [1, 2, 8])
+@pytest.mark.parametrize("heads_per_block", [16, 32, 64])
+def test_pallas_state_update_is_the_step_at_every_tile_the_table_can_give(heads_per_block, groups, dirty):
+    """64 heads at each heads-a-tile the registry sweeps (16 / 32 / 64), inside
+    one group of B/C (2 groups, 16 heads a tile) or over 1, 2, 4 or 8 whole
+    groups: the state as today, ``y`` at today's tolerance; a reset row whose
+    old state holds 3e38, inf and NaN starts from zero."""
+    _held_to_the_step(64, 8, 16, groups, dirty=dirty, seed=70 + groups, heads_per_block=heads_per_block)
+
+
+@pytest.mark.parametrize("heads", [16, 32, 64])
+def test_pallas_state_update_takes_its_tile_from_the_table(heads):
+    """No ``heads_per_block``: the tile is what the tuning table gives the
+    call's shape under the kernel's name (here through ``tile_overrides``, the
+    lookup a committed entry takes), held to the kernel's rule; the result is
+    the keyword's, bit for bit."""
+    from neuronx_distributed_inference_tpu.ops import ssm_state_update as su
+    from neuronx_distributed_inference_tpu.ops.tile_defaults import tile_overrides
+
+    rng = np.random.default_rng(80)
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    rows, H, p, n, G = 2, 64, 8, 16, 8
+    args = (f(2, rows, H, p, n), jnp.int32(0), f(rows, H, p), f(rows, G, n), f(rows, G, n),
+            jnp.asarray(rng.uniform(0.01, 0.5, (rows, H)), jnp.float32), -jnp.ones((H,), jnp.float32),
+            jnp.ones((rows,), bool), jnp.zeros((rows,), bool))
+    raw = su.ssm_state_update.__wrapped__  # the jitted wrapper keys its trace on shapes alone
+    with tile_overrides(su.KERNEL, {"heads": heads}):
+        assert su.heads_wanted(H, G, p, n) == heads
+        y_t, new_t = raw(*args, interpret=True)
+    assert su.heads_wanted(H, G, p, n) == su.DEFAULT_HEADS_PER_BLOCK  # no entry for this shape
+    y_k, new_k = raw(*args, heads_per_block=heads, interpret=True)
+    assert np.array_equal(np.asarray(y_t), np.asarray(y_k)) and np.array_equal(np.asarray(new_t), np.asarray(new_k))
+    # a head count the wanted tile does not divide, or groups it would split, falls to the rule
+    assert su.pick_heads_per_block(24, 32, groups=1) == 24 and su.pick_heads_per_block(48, 32, groups=1) == 24
+    assert su.pick_heads_per_block(48, 32, groups=3) == 16 and su.pick_heads_per_block(6, 64, groups=2) == 6
+
+
+@pytest.mark.parametrize("groups,dirty", [(1, False), (8, True)], ids=["granite-4.0-h", "nemotron_h"])
+def test_pallas_state_update_at_the_published_shapes(groups, dirty):
+    """64 heads of 64 over a state of 128, one group (Granite-4.0-H) and eight
+    (``nemotron_h``), B, C and x in bfloat16 as the serving path hands them
+    over, the tile the committed table's (32 heads at both shapes: measured)."""
+    from neuronx_distributed_inference_tpu.ops import ssm_state_update as su
+    from neuronx_distributed_inference_tpu.ops.tile_defaults import table_entry
+
+    entry = table_entry(su.KERNEL, f"h64g{groups}x64x128", "float32")
+    assert entry == {"provenance": "measured", "tiles": {"heads": 32}}
+    assert su.pick_heads_per_block(64, su.heads_wanted(64, groups, 64, 128), groups=groups) == 32
+    _held_to_the_step(64, 64, 128, groups, rows=3, L=1, li=0, dirty=dirty, seed=90 + groups, bf16_bc=True)
 
 
 @pytest.mark.parametrize("groups", [1, 2, 8])
