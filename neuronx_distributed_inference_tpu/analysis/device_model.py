@@ -297,84 +297,6 @@ def prefill_projection(
     }
 
 
-#: the default draft shape of the speculative projection: a 1B-width,
-#: 4-layer truncation (the EAGLE-class "few-layer draft over the target's
-#: width" regime)
-LLAMA_1B_DRAFT4 = dict(LLAMA_1B, num_hidden_layers=4)
-
-
-def expected_accept_tokens(acceptance: float, draft_len: int) -> float:
-    """Expected tokens committed per speculation round under greedy
-    contiguous-match verification with per-draft acceptance probability
-    ``acceptance`` and ``draft_len`` drafted tokens: the leading-match
-    length of a geometric chain, 1 + a + a² + … + a^L (PERF.md
-    "acceptance-vs-tok/s"). At a = 0.8, L = 3 that is 2.95 tokens/round."""
-    a = float(acceptance)
-    L = int(draft_len)
-    if a >= 1.0:
-        return L + 1.0
-    return (1.0 - a ** (L + 1)) / (1.0 - a)
-
-
-def spec_decode_projection(
-    attrs: dict,
-    *,
-    batch: int,
-    kv_width: int,
-    acceptance: float,
-    draft_len: int,
-    draft_attrs: Optional[dict] = None,
-    weight_dtype: str = "bfloat16",
-    kv_dtype: str = "bfloat16",
-    device: Optional[DeviceSpec] = None,
-    tp: int = 1,
-) -> Dict[str, float]:
-    """Draft-assisted decode ceiling at a given ACCEPTANCE RATE.
-
-    One round = one packed verify pass over ``draft_len + 1`` query tokens
-    per row (HBM cost == a plain decode step: weights stream once, the KV
-    read is the same cache walk; FLOPs scale by the extra query tokens —
-    still far under the ridge at serving widths) + ``draft_len`` sequential
-    draft decode steps on ``draft_attrs`` (default :data:`LLAMA_1B_DRAFT4`).
-    Expected committed tokens/round follow the geometric acceptance chain
-    (:func:`expected_accept_tokens`), so::
-
-        tok_s = batch * E[tokens/round] / (t_verify + draft_len * t_draft)
-
-    At acceptance 1.0 with a free draft this recovers (draft_len+1)× the
-    plain decode ceiling; at acceptance 0 it degrades to plain decode taxed
-    by the draft — the model PERF r5's ">500 tok/s at int8+EAGLE
-    (acceptance 0.8)" figure comes from."""
-    spec = device or get_device()
-    verify = decode_projection(
-        attrs, batch=batch, kv_width=kv_width, weight_dtype=weight_dtype,
-        kv_dtype=kv_dtype, device=spec, tp=tp,
-    )
-    # the verify pass computes draft_len+1 query positions per row: same
-    # HBM traffic, (draft_len+1)x the matmul/attention FLOPs
-    t_verify = max(verify["t_hbm_s"], verify["t_flops_s"] * (draft_len + 1))
-    d_attrs = draft_attrs if draft_attrs is not None else LLAMA_1B_DRAFT4
-    draft_step = decode_projection(
-        d_attrs, batch=batch, kv_width=kv_width, weight_dtype=weight_dtype,
-        kv_dtype=kv_dtype, device=spec, tp=tp,
-    )
-    t_round = t_verify + draft_len * draft_step["t_step_s"]
-    e_tokens = expected_accept_tokens(acceptance, draft_len)
-    return {
-        "t_round_s": t_round,
-        "t_verify_s": t_verify,
-        "t_draft_s": draft_len * draft_step["t_step_s"],
-        "expected_tokens_per_round": e_tokens,
-        "acceptance": float(acceptance),
-        "draft_len": int(draft_len),
-        "tok_s": batch * e_tokens / t_round,
-        "bound": verify["bound"],
-        "weight_bytes": verify["weight_bytes"],
-        "kv_read_bytes": verify["kv_read_bytes"],
-        "device": spec.name,
-    }
-
-
 # ---------------------------------------------------------------------------
 # table renderer
 # ---------------------------------------------------------------------------

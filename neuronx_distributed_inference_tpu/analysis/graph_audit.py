@@ -249,7 +249,6 @@ def run(
             TAG_TOKEN_GENERATION_KVQ8,
             TAG_FUSED_SPECULATION_KVQ8,
             programs.TAG_MIXED_STEP,
-            programs.TAG_MIXED_STEP_SPEC,
         ):
             hits: List[Tuple[str, Optional[str]]] = []
             _walk_scan_upcasts(per_bucket[ref_bucket].jaxpr.jaxpr, hits)

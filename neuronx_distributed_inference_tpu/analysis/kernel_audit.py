@@ -23,9 +23,8 @@ Rules
 - **KERN702** Mosaic tile legality: block last dim a 128-lane multiple (or
   equal to the array dim), sublane multiples by dtype width (8/f32,
   16/bf16, 32/int8-fp8), block-vs-array divisibility per axis, plus the
-  prose packing contracts of PRs 6/12 as arithmetic (ragged q-tile divides
-  RAGGED_Q_TILE so a tile never spans rows; the speculation segment fits
-  one tile).
+  prose packing contract of PR 6 as arithmetic (ragged q-tile divides
+  RAGGED_Q_TILE so a tile never spans rows).
 - **KERN703** kernel census: every ``pl.pallas_call`` site under ``ops/``
   must be claimed by a registry entry; every entry must name an importable
   native fallback, a parity test and a TPU-lowering test that mention its
@@ -255,11 +254,11 @@ def block_legality_findings(
 
 
 def packing_contract_findings(
-    key: str, location: str, tq: int, ragged_q_tile: int, spec_width: int
+    key: str, location: str, tq: int, ragged_q_tile: int
 ) -> List[Finding]:
-    """KERN702 packing contracts (PR 6/12 prose, as arithmetic): row starts
-    are RAGGED_Q_TILE-aligned, so a q tile never spans rows iff tq divides
-    RAGGED_Q_TILE; the speculation segment must fit one tile."""
+    """KERN702 packing contract (PR 6 prose, as arithmetic): row starts are
+    RAGGED_Q_TILE-aligned, so a q tile never spans rows iff tq divides
+    RAGGED_Q_TILE."""
     out = []
     if tq > ragged_q_tile or ragged_q_tile % tq:
         out.append(
@@ -272,19 +271,6 @@ def packing_contract_findings(
                     f"{ragged_q_tile} — a tile could span two packed rows"
                 ),
                 key=f"{key}/rowspan",
-            )
-        )
-    if spec_width > tq:
-        out.append(
-            Finding(
-                rule="KERN702",
-                severity=SEV_ERROR,
-                location=location,
-                message=(
-                    f"{key}: speculation segment width {spec_width} exceeds "
-                    f"the q tile {tq} — a spec segment must fit one tile"
-                ),
-                key=f"{key}/specfit",
             )
         )
     return out
@@ -539,13 +525,12 @@ def _instance_signature(spec, case, tiles):
     if block_legality_findings(inst.key, "x", inst.blocks):
         return None
     if spec.name == "ragged_paged_attention":
-        from neuronx_distributed_inference_tpu.analysis.programs import _SPEC_WIDTH
         from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
             RAGGED_Q_TILE,
         )
 
         if packing_contract_findings(
-            inst.key, "x", tiles.get("tq", RAGGED_Q_TILE), RAGGED_Q_TILE, _SPEC_WIDTH
+            inst.key, "x", tiles.get("tq", RAGGED_Q_TILE), RAGGED_Q_TILE
         ):
             return None
     return (
@@ -628,7 +613,6 @@ def run(
     global _LAST_REPORT
     from neuronx_distributed_inference_tpu.analysis import kernel_registry as kr
     from neuronx_distributed_inference_tpu.analysis.device_model import get_device
-    from neuronx_distributed_inference_tpu.analysis.programs import _SPEC_WIDTH
     from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
         RAGGED_Q_TILE,
     )
@@ -651,8 +635,7 @@ def run(
         findings += block_legality_findings(inst.key, loc, inst.blocks)
         if inst.kernel == "ragged_paged_attention":
             findings += packing_contract_findings(
-                inst.key, loc, inst.tiles.get("tq", RAGGED_Q_TILE),
-                RAGGED_Q_TILE, _SPEC_WIDTH,
+                inst.key, loc, inst.tiles.get("tq", RAGGED_Q_TILE), RAGGED_Q_TILE
             )
 
     # KERN703 census

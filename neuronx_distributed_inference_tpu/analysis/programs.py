@@ -53,12 +53,6 @@ TAG_TOKEN_GENERATION_PAGED = "token_generation_paged"
 # MixedStepRunner) — committed so the graph/shard/memory audits cover the
 # one-dispatch serving program family from day one
 TAG_MIXED_STEP = "mixed_step"
-# the SPEC-VERIFY variant of the mixed family (serving_spec_ragged,
-# spec_width = speculation_length): spec rows pack draft tokens as extra
-# query positions, the program gathers per-row verify windows and computes
-# the greedy acceptance count on device — committed so the GRAPH/SHARD/MEM/
-# COST audits see the speculative serving program the same day it ships
-TAG_MIXED_STEP_SPEC = "mixed_step_spec"
 # the w4 family (weight_dtype="int4", ISSUE 17): decode programs whose
 # weights are packed grouped-int4 (uint8 codes + f32 group scales,
 # ops/quant_matmul) — committed so the graph/shard/memory audits cover the
@@ -76,7 +70,6 @@ COMMITTED_TAGS = (
     TAG_TOKEN_GENERATION_KVQ8,
     TAG_FUSED_SPECULATION_KVQ8,
     TAG_MIXED_STEP,
-    TAG_MIXED_STEP_SPEC,
     TAG_TOKEN_GENERATION_W4,
     TAG_MIXED_STEP_W4,
 )
@@ -354,7 +347,7 @@ def _build_causal(
         overrides.update(
             is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=18
         )
-    elif variant in ("mixed", "mixed_spec"):
+    elif variant == "mixed":
         from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
 
         overrides.update(
@@ -368,10 +361,6 @@ def _build_causal(
             ),
             serving_ragged=True,
         )
-        if variant == "mixed_spec":
-            overrides.update(
-                serving_spec_ragged=True, speculation_length=_SPEC_WIDTH
-            )
     hf_attrs = None
     if weight_dtype == "int4":
         # w4 runs the kernel-eligible tiny shape: every decode linear has
@@ -395,8 +384,6 @@ def _build_causal(
         pairs = [(TAG_TOKEN_GENERATION_W4, PHASE_TKG, app.token_generation_model)]
     elif variant == "mixed":
         pairs = [(TAG_MIXED_STEP, PHASE_TKG, app.mixed_step_model)]
-    elif variant == "mixed_spec":
-        pairs = [(TAG_MIXED_STEP_SPEC, PHASE_TKG, app.mixed_step_model)]
     elif kv_quant:
         pairs = [
             (TAG_CONTEXT_ENCODING_KVQ8, PHASE_CTE, app.context_encoding_model),
@@ -408,9 +395,7 @@ def _build_causal(
             (TAG_TOKEN_GENERATION, PHASE_TKG, app.token_generation_model),
         ]
     window = overrides.get("sliding_window", 0)
-    capacity = _cache_capacity(
-        app.kv_cache, paged=variant in ("paged", "mixed", "mixed_spec")
-    )
+    capacity = _cache_capacity(app.kv_cache, paged=variant in ("paged", "mixed"))
     B = cfg.tpu_config.batch_size
 
     def meta(tag, phase, runner, bucket) -> ShapeMeta:
@@ -420,16 +405,12 @@ def _build_causal(
             layers=cfg.num_hidden_layers,
             vocab=cfg.vocab_size,
         )
-        if tag in (TAG_MIXED_STEP, TAG_MIXED_STEP_SPEC, TAG_MIXED_STEP_W4):
+        if tag in (TAG_MIXED_STEP, TAG_MIXED_STEP_W4):
             # packed bucket = query tokens; decode rows read the widest
-            # committed kv bucket (the width example_inputs compiles at);
-            # the spec variant records its draft length (spec_width - 1) so
-            # the cost audit's tok_s upper bound counts the up-to-spec_width
-            # tokens a fully-accepted verify row commits
+            # committed kv bucket (the width example_inputs compiles at)
             return ShapeMeta(
                 rows=runner.num_rows, q_tokens=bucket,
-                kv_width=runner.kv_buckets[-1], q_tile=runner.q_tile,
-                spec_len=getattr(runner, "spec_width", 1) - 1, **base
+                kv_width=runner.kv_buckets[-1], q_tile=runner.q_tile, **base
             )
         if phase == PHASE_CTE:
             return ShapeMeta(rows=B, q_tokens=B * bucket, kv_width=0, **base)
@@ -548,9 +529,6 @@ def _build_fused(kv_quant: bool = False) -> Dict[str, Dict[int, ProgramRecord]]:
 
 _MEMO: Dict[str, Dict[int, ProgramRecord]] = {}
 
-#: spec width of the committed mixed_step_spec program (speculation_length)
-_SPEC_WIDTH = 4
-
 _BUILDERS = (
     # (tags produced together, builder thunk)
     ((TAG_CONTEXT_ENCODING, TAG_TOKEN_GENERATION), lambda: _build_causal()),
@@ -561,7 +539,6 @@ _BUILDERS = (
     ((TAG_FUSED_SPECULATION,), _build_fused),
     ((TAG_FUSED_SPECULATION_KVQ8,), lambda: _build_fused(kv_quant=True)),
     ((TAG_MIXED_STEP,), lambda: _build_causal(variant="mixed")),
-    ((TAG_MIXED_STEP_SPEC,), lambda: _build_causal(variant="mixed_spec")),
     ((TAG_TOKEN_GENERATION_W4,), lambda: _build_causal(weight_dtype="int4")),
     (
         (TAG_MIXED_STEP_W4,),

@@ -149,14 +149,6 @@ SERVING_STEP_HOT_PATH = {
     "_schedule_mixed",
     "_build_mixed_descriptors",
     "_consume_ragged",
-    # spec-ragged speculation path (serving_spec_ragged): the packed verify
-    # consume rides ONE np.asarray (deliberately not a census name — the
-    # async fetch was started at dispatch) and the chained draft must stay
-    # fetch-free (its whole point is the frontier never visiting the host)
-    "_spec_ragged_step",
-    "_schedule_spec",
-    "_consume_spec",
-    "_dispatch_chained_draft",
     "_note_acceptance",
     "_dispatch_decode",
     "_consume",

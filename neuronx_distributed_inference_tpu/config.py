@@ -311,27 +311,6 @@ class TpuConfig:
     # absmax couples whatever one dispatch co-writes, and the ragged step
     # groups writes differently — docs/SERVING.md).
     serving_ragged: bool = False
-    # async 1-ahead pipelining for the ragged mixed-step path: serving step
-    # k+1 chains on step k's still-on-device tokens (device-side chained-id
-    # gather, epoch-guarded one-step-late consume) and the step-k fetch is
-    # started non-blocking at dispatch — host bookkeeping (admission,
-    # deadlines, watchdog, telemetry) overlaps the device executing k+1.
-    # None (default) follows async_mode, mirroring the split path's 1-ahead
-    # decode; False forces dispatch+fetch-per-step (step-accurate
-    # debugging). Greedy outputs are byte-identical across sync/async
-    # (pinned). Requires serving_ragged.
-    serving_ragged_async: Optional[bool] = None
-    # speculative verification INSIDE the ragged mixed step
-    # (runtime/serving.SpeculativeServingSession over the mixed_step_spec
-    # program family): spec rows carry their draft tokens as extra query
-    # positions on the packed axis, one mixed dispatch per step serves
-    # prefill chunks + plain decode + spec-verify rows, accept/rollback
-    # commits against the paged cache, and draft length adapts per request
-    # off the acceptance EWMA. Requires serving_ragged (paged cache +
-    # continuous batching) + chunked prefill + 2 <= speculation_length <= 16
-    # (a spec segment must fit one RAGGED_Q_TILE); greedy-only (the packed
-    # verify computes contiguous-match acceptance on device).
-    serving_spec_ragged: bool = False
     # multi-replica serving front-end (runtime/router.py): how many
     # single-chip replica sessions the ServingRouter runs the demo/bench
     # serving traffic over (1 = no router layer), and the placement policy
@@ -705,41 +684,6 @@ class TpuConfig:
                 raise NotImplementedError(
                     "serving_ragged is single-shard-parallel (tp only)"
                 )
-        if self.serving_ragged_async and not self.serving_ragged:
-            raise ValueError(
-                "serving_ragged_async=True pipelines the RAGGED mixed-step "
-                "dispatch: set serving_ragged=True (the legacy split path "
-                "already pipelines via async_mode)"
-            )
-        if self.serving_spec_ragged:
-            if not self.serving_ragged:
-                raise ValueError(
-                    "serving_spec_ragged packs spec-verify rows into the "
-                    "ragged mixed step: set serving_ragged=True (paged "
-                    "cache + continuous batching)"
-                )
-            if not self.is_chunked_prefill:
-                raise ValueError(
-                    "serving_spec_ragged requires is_chunked_prefill=True: "
-                    "prompt chunks must ride the same mixed dispatch as the "
-                    "spec-verify rows (one program identity per step)"
-                )
-            # 16 == ops/ragged_paged_attention.RAGGED_Q_TILE (kept literal:
-            # config validation must not import kernel modules)
-            if not 2 <= self.speculation_length <= 16:
-                raise ValueError(
-                    "serving_spec_ragged needs 2 <= speculation_length <= "
-                    "16: a spec-verify segment (last token + drafts) must "
-                    "fit one ragged q tile"
-                )
-            ods = self.on_device_sampling_config
-            if ods is not None and getattr(ods, "do_sample", False):
-                raise NotImplementedError(
-                    "serving_spec_ragged is greedy-only: the packed verify "
-                    "computes contiguous-match acceptance on device "
-                    "(sampled accept/reject stays on the split "
-                    "SpeculativeServingSession path)"
-                )
         if (
             self.is_block_kv_layout
             and self.pa_num_blocks is None
@@ -904,6 +848,15 @@ class MoETpuConfig(TpuConfig):
 CONFIG_FILE = "tpu_config.json"  # reference: neuron_config.json (config.py:22)
 
 
+def speculation_requested(tc: "TpuConfig") -> bool:
+    """Whether any speculation option is set: what the validators below
+    refuse for a cache no draft's width is held to a reference over."""
+    return bool(
+        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
+        or tc.enable_eagle_speculation
+    )
+
+
 class SlotStateServingError(NotImplementedError):
     """An option that cannot serve a model whose layers keep a constant-size
     per-slot state (state-space layers; a one-token carry) was set for one."""
@@ -915,14 +868,10 @@ def validate_slot_state_serving(tc: "TpuConfig", what: str = "state-space layers
     (``init_slot_state()``: ``what`` keeps a ``state`` per slot), every
     option that would serve it wrongly rather than not at all. One line
     each: none is a silent wrong answer."""
-    speculation = (
-        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
-        or tc.enable_eagle_speculation or tc.serving_spec_ragged
-    )
     refusals = (
         (tc.is_prefix_caching, f"is_prefix_caching: a {state} cannot be shared block by block"),
         (tc.serving_ragged, f"serving_ragged: the ragged mixed step advances no {state}"),
-        (speculation, f"speculation: rejected drafts would need a snapshot of the {state} to roll back to"),
+        (speculation_requested(tc), f"speculation: rejected drafts would need a snapshot of the {state} to roll back to"),
         (tc.kv_quantized, f"kv_cache_dtype quantisation: the {state} is kept unquantised beside the pool "
                           "and the two are not held to a reference together"),
         (tc.tp_degree * tc.ep_degree * tc.cp_degree * tc.attention_dp_degree
@@ -945,10 +894,6 @@ def validate_looped_stack(tc: "TpuConfig", loop_steps: int, early_exit_threshold
     loop does not do yet, each by name: none is a silent wrong answer. Every
     site listed reads ONE cache entry or ONE pass a layer; the pool of a
     looped stack has ``loop_steps`` x layers entries."""
-    speculation = (
-        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
-        or tc.enable_eagle_speculation or tc.serving_spec_ragged
-    )
     refusals = (
         (loop_steps < 1, f"total_ut_steps {loop_steps}: the stack runs at least once"),
         (early_exit_threshold < 1,
@@ -957,8 +902,8 @@ def validate_looped_stack(tc: "TpuConfig", loop_steps: int, early_exit_threshold
         (tc.lora_config is not None,
          "lora_config: the adapters are attached by layer (runtime/application.py passes "
          "spec.num_layers), not by layer pass"),
-        (speculation, "speculation (speculation_length, medusa, fused, EAGLE and its capture_layers, "
-                      "serving_spec_ragged): a draft's cache has one entry a layer"),
+        (speculation_requested(tc), "speculation (speculation_length, medusa, fused, EAGLE and its "
+                      "capture_layers): a draft's cache has one entry a layer"),
         (tc.serving_ragged, "serving_ragged: the ragged mixed step scans the layers once "
                             "(models/base.py mixed_forward has its own scan)"),
         (tc.kv_quantized, "kv_cache_dtype quantisation: one scale a (layer, head) is not held to a "
@@ -983,10 +928,6 @@ def validate_latent_attention(tc: "TpuConfig") -> None:
     every option that would run it wrongly rather than not at all: on any
     path what the layer does not write, and on the paged serving path what
     the latent pool cannot do yet. One line each."""
-    speculation = (
-        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
-        or tc.enable_eagle_speculation or tc.serving_spec_ragged
-    )
     paged = tc.is_block_kv_layout
     refusals = (
         (tc.cp_degree > 1, "cp_degree > 1: the latent cache is not sequence-sharded"),
@@ -998,7 +939,7 @@ def validate_latent_attention(tc: "TpuConfig") -> None:
          "is_prefix_caching on the paged cache: shared latent blocks are held to no reference"),
         (paged and tc.serving_ragged,
          "serving_ragged: the ragged mixed step attends (H_kv, D) keys and values only"),
-        (paged and speculation,
+        (paged and speculation_requested(tc),
          "speculation on the paged cache: a draft's width is not held to a reference over latents"),
         (paged and tc.kv_quantized,
          "kv_cache_dtype quantisation on the paged cache: one scale a head cannot serve "
@@ -1049,10 +990,6 @@ def validate_two_lifetime_cache(tc: "TpuConfig") -> None:
     """Refuse, for a model whose builder declares ``WINDOW_KV`` layers beside
     ``PAGED_KV`` ones (``cache_layers()``), what is not built for a cache of
     two lifetimes, each naming its site: none is a silent wrong answer."""
-    speculation = (
-        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
-        or tc.enable_eagle_speculation or tc.serving_spec_ragged
-    )
     refusals = (
         (not (tc.is_block_kv_layout and tc.is_chunked_prefill and tc.is_continuous_batching),
          "a contiguous cache or whole-prompt prefill (generate(), is_block_kv_layout / "
@@ -1064,8 +1001,8 @@ def validate_two_lifetime_cache(tc: "TpuConfig") -> None:
          "is_prefix_caching: a cached prefix has the full layers' blocks "
          "(modules/block_kvcache.PrefixCachingAllocator) and nothing of a window layer's ring, "
          "which another request's slot has overwritten since"),
-        (speculation,
-         "speculation (speculation_length, medusa, fused, EAGLE, serving_spec_ragged): a ring "
+        (speculation_requested(tc),
+         "speculation (speculation_length, medusa, fused, EAGLE): a ring "
          "sized for one prefill chunk is not held to a reference at a draft's width, and a "
          "rejected draft's writes may have wrapped over keys the row still attends"),
         (tc.serving_ragged,
@@ -1108,15 +1045,11 @@ def validate_block_step_serving(tc: "TpuConfig", block_length: int, denoise_step
     ods = tc.on_device_sampling_config
     cpc = tc.chunked_prefill_config
     chunk = cpc.kernel_q_tile_size if cpc else 128
-    speculation = (
-        tc.speculation_length or tc.medusa_speculation_length or tc.enable_fused_speculation
-        or tc.enable_eagle_speculation or tc.serving_spec_ragged
-    )
     refusals = (
         (not (tc.is_block_kv_layout and tc.is_chunked_prefill and tc.is_continuous_batching),
          "a contiguous cache or whole-prompt prefill: it is served on the paged, chunked path "
          "only (is_block_kv_layout, is_chunked_prefill, is_continuous_batching)"),
-        (speculation, "speculation: a draft proposes one position after another"),
+        (speculation_requested(tc), "speculation: a draft proposes one position after another"),
         (tc.serving_ragged, "serving_ragged: the ragged mixed step has no block-causal mask"),
         (tc.is_prefix_caching, "is_prefix_caching: a cached prefix would have to end on a block's "
                                "edge and the match does not know blocks"),

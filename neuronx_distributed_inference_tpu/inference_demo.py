@@ -142,30 +142,6 @@ def build_parser() -> argparse.ArgumentParser:
         "(requires --is-block-kv-layout under continuous batching; "
         "docs/SERVING.md)",
     )
-    run.add_argument(
-        "--serving-ragged-async", dest="serving_ragged_async",
-        action="store_true", default=None,
-        help="async 1-ahead pipelining for the ragged mixed-step path: "
-        "step k+1 chains on step k's on-device tokens and the token fetch "
-        "is non-blocking, overlapping host bookkeeping with the device "
-        "(requires --serving-ragged; default follows async-mode)",
-    )
-    run.add_argument(
-        "--no-serving-ragged-async", dest="serving_ragged_async",
-        action="store_false",
-        help="force dispatch+fetch-per-step on the ragged path "
-        "(step-accurate debugging)",
-    )
-    run.add_argument(
-        "--serving-spec-ragged", action="store_true",
-        help="speculative verification inside the ragged mixed step "
-        "(serving-session config — the demo itself runs one generate() "
-        "session): "
-        "spec rows carry draft tokens as extra packed query positions, one "
-        "mixed dispatch per step serves prefill + decode + spec-verify rows "
-        "(requires --serving-ragged, --is-chunked-prefill and "
-        "2 <= --speculation-length <= 16; docs/SERVING.md)",
-    )
     from neuronx_distributed_inference_tpu.config import ROUTER_POLICIES
 
     run.add_argument(
@@ -489,8 +465,6 @@ def create_tpu_config(args) -> TpuConfig:
         is_chunked_prefill=args.is_chunked_prefill,
         chunked_prefill_config=cpc,
         serving_ragged=args.serving_ragged,
-        serving_ragged_async=args.serving_ragged_async,
-        serving_spec_ragged=args.serving_spec_ragged,
         serving_replicas=args.serving_replicas,
         router_policy=args.router_policy,
         router_threading=args.router_threading,

@@ -337,7 +337,7 @@ class MixedStepInputs:
     row_len: jax.Array  # (R,) int32 query tokens per row; 0 = inactive
     ctx_len: jax.Array  # (R,) int32 total kv length per row (incl. new)
     sampling_params: jax.Array  # (R, 3) float32
-    # async 1-ahead chaining (serving_ragged_async): chain_src[t] names the
+    # async 1-ahead chaining (async_mode): chain_src[t] names the
     # row whose PREVIOUS-step token supplies packed position t's input id
     # (-1 = take input_ids[t] as written by the host). chain_tokens is the
     # previous mixed step's (R, 1) token output — still on device in steady
@@ -348,17 +348,6 @@ class MixedStepInputs:
     # inputs) skips the gather entirely.
     chain_src: Optional[jax.Array] = None  # (1, T) int32; -1 = host id
     chain_tokens: Optional[jax.Array] = None  # (R, 1) int32
-    # speculative verification rows (serving_spec_ragged; mixed_forward with
-    # spec_width > 1): verify_len[r] in [1, spec_width] names how many TAIL
-    # positions of row r's packed segment are verification positions — 1 for
-    # plain decode rows and prefill chunks, draft_len+1 for spec-verify rows
-    # (the segment carries [last committed token, draft_1..draft_d]).
-    # draft_tokens is the draft app's (R, spec_width-1) proposal matrix —
-    # still on device in steady state; chain_src slots >= 0 index its
-    # FLATTENED layout (slot r*(spec_width-1)+j = row r's j-th draft), so
-    # draft proposals never round-trip the host between propose and verify.
-    verify_len: Optional[jax.Array] = None  # (R,) int32; None = all ones
-    draft_tokens: Optional[jax.Array] = None  # (R, spec_width-1) int32
 
 
 def act_fn(name: str) -> Callable:
@@ -1672,55 +1661,6 @@ def decode_steps(
     return tokens, out_logits, cache
 
 
-def draft_chain_propose(
-    params: dict,
-    cache: KVCache,
-    prev_tokens: jax.Array,  # (R, spec_width+1) previous mixed verify output
-    fallback_last: jax.Array,  # (R, 1) host last token (rows not chained)
-    fallback_pos: jax.Array,  # (R, 1) host position (rows not chained)
-    use_chain: jax.Array,  # (R, 1) int32/bool: 1 = derive frontier in-graph
-    p0_base: jax.Array,  # (R, 1) verify-window base position at dispatch
-    seq_ids: jax.Array,  # (R,) -1 = inactive
-    sampling_params: jax.Array,
-    rng: Optional[jax.Array],
-    *,
-    spec: ModelSpec,
-    num_steps: int,
-    bucket: int,
-    spec_width: int,
-    mlp_fn: Callable = gated_mlp,
-    layer_fn: Optional[Callable] = None,
-) -> Tuple[jax.Array, Optional[jax.Array], KVCache]:
-    """Draft proposal rounds chained on the previous mixed verify output.
-
-    The spec-ragged serving pipeline's draft side (serving_spec_ragged):
-    each row's frontier — its last ACCEPTED token and the position after it —
-    is derived IN-GRAPH from the verify program's (tokens, counts) output,
-    still on device, so the accepted-token frontier never round-trips the
-    host between verify k and the draft proposing for verify k+1 (the PR-8
-    chained-id gather, generalized from "+1 token" to "+counts tokens").
-    Rows whose frontier the previous verify does not carry (first round
-    after prefill, post-re-admission) chain from the host fallbacks via
-    ``use_chain``. Greedy-only (the spec-ragged session's contract); returns
-    ``decode_steps``' (proposals (R, num_steps), logits|None, cache)."""
-    counts = jnp.clip(
-        prev_tokens[:, spec_width : spec_width + 1], 1, spec_width
-    )  # (R, 1) accepted count column of the verify output
-    idx = jnp.clip(counts - 1, 0, spec_width - 1)
-    chained_last = jnp.take_along_axis(prev_tokens[:, :spec_width], idx, axis=1)
-    # a NON_FINITE frontier (-1) clamps to token 0: the poisoned row's
-    # proposals are garbage the host discards at quarantine
-    chained_last = jnp.maximum(chained_last, 0)
-    uc = use_chain.astype(bool)
-    last = jnp.where(uc, chained_last, fallback_last).astype(jnp.int32)
-    pos = jnp.where(uc, p0_base + counts, fallback_pos).astype(jnp.int32)
-    return decode_steps(
-        params, cache, last, pos, seq_ids, sampling_params, rng,
-        spec=spec, num_steps=num_steps, bucket=bucket,
-        mlp_fn=mlp_fn, layer_fn=layer_fn,
-    )
-
-
 def mixed_forward(
     params: dict,
     cache,  # BlockKVCache (donated by the runner)
@@ -1730,7 +1670,6 @@ def mixed_forward(
     spec: ModelSpec,
     mlp_fn: Callable = gated_mlp,
     layer_fn: Optional[Callable] = None,
-    spec_width: int = 1,
 ) -> StepOutput:
     """ONE traced program for a ragged mixed prefill+decode serving step.
 
@@ -1746,25 +1685,6 @@ def mixed_forward(
 
     Returns StepOutput with tokens (R, 1); inactive rows (row_len == 0)
     carry garbage tokens the host ignores.
-
-    ``spec_width > 1`` builds the SPECULATIVE-VERIFICATION variant of the
-    program (the ``mixed_step_spec`` family, serving_spec_ragged): spec
-    rows carry their draft tokens as extra query positions on the same
-    packed axis (attention-wise they are just multi-token segments — the
-    ragged kernel's prior-KV + causal math IS target verification), and the
-    program gathers each row's last ``verify_len[r]`` positions instead of
-    one, runs the lm_head over the (R, spec_width) window, and computes the
-    greedy contiguous-match acceptance count ON DEVICE against the drafted
-    input ids. Tokens come back as (R, spec_width + 1): columns
-    [0, spec_width) are the per-position greedy verification tokens of the
-    row's window (start-aligned; column 0 is THE token for plain rows, so
-    every spec_width == 1 consumer reads the same cell) and column
-    spec_width is the accepted count in [1, verify_len[r]] — the
-    accepted-token frontier a chained draft or consume reads without any
-    extra fetch. Rejected draft KV needs no rollback scatter: the writes
-    landed at positions the next round re-writes (write-then-attend, the
-    same discipline every speculation path uses), and the quantized commit
-    path's running-absmax scatter already absorbed them monotonically.
     """
     if spec.layer_groups is not None or spec.bounded_window or spec.ring_window:
         raise NotImplementedError(
@@ -1794,22 +1714,8 @@ def mixed_forward(
     from neuronx_distributed_inference_tpu.parallel.sharding import constrain
 
     input_ids = inputs.input_ids
-    if spec_width > 1 and (
-        inputs.draft_tokens is not None and inputs.chain_src is not None
-    ):
-        # device-side DRAFT-token gather (serving_spec_ragged): packed
-        # positions whose chain_src >= 0 take their input id straight from
-        # the draft app's on-device proposal matrix (flattened
-        # (R, spec_width-1); chain_src = r*(spec_width-1)+j) — the
-        # propose->verify hand-off never round-trips the host. A draft
-        # NON_FINITE sentinel (-1) clamps to token 0: a poisoned DRAFT only
-        # mis-proposes (costs acceptance length, never output correctness).
-        flat = jnp.maximum(inputs.draft_tokens.reshape(-1), 0)
-        src = inputs.chain_src
-        gathered = jnp.take(flat, jnp.clip(src, 0, flat.shape[0] - 1))
-        input_ids = jnp.where(src >= 0, gathered, input_ids)
-    elif inputs.chain_tokens is not None and inputs.chain_src is not None:
-        # device-side chained-id gather (serving_ragged_async): packed
+    if inputs.chain_tokens is not None and inputs.chain_src is not None:
+        # device-side chained-id gather (async_mode): packed
         # positions whose chain_src names a row take that row's previous-
         # step token straight off the device — the ragged analogue of the
         # split path's `last_override` chain. A previous-step NON_FINITE
@@ -1859,65 +1765,23 @@ def mixed_forward(
 
     hidden = apply_norm(hidden, params["norm"]["weight"], spec.rms_eps, spec.norm_type)
     T = hidden.shape[1]
-    if spec_width == 1:
-        # per-row last-token gather off the packed axis (the ragged analogue
-        # of gather_last_token); inactive rows clamp to slot 0 — garbage the
-        # host never reads
-        last_idx = jnp.clip(inputs.row_start + inputs.row_len - 1, 0, T - 1)
-        rows_h = jnp.take(hidden[0], last_idx, axis=0)[:, None, :]  # (R, 1, H)
-        logits = lm_head(params, rows_h, spec)[..., : spec.vocab_size]  # (R, 1, V)
-        if spec.on_device_sampling:
-            tokens = sample_tokens(
-                logits,
-                inputs.sampling_params,
-                rng if spec.do_sample else None,
-                spec.max_topk,
-                spec.do_sample,
-            )
-        else:
-            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        tokens = mark_non_finite_tokens(tokens, logits)
-        out_logits = logits if spec.output_logits else None
-        return StepOutput(tokens=tokens, logits=out_logits, cache=new_cache)
-
-    # --- spec_width > 1: per-row VERIFICATION-window gather + device-side
-    # --- greedy acceptance (serving_spec_ragged; greedy-only by config)
-    R = inputs.row_start.shape[0]
-    verify_len = (
-        inputs.verify_len
-        if inputs.verify_len is not None
-        else jnp.ones((R,), jnp.int32)
-    )
-    v = jnp.clip(verify_len, 1, spec_width)[:, None]  # (R, 1)
-    # window base = first verification position of the row's segment; column
-    # j reads base+j for j < verify_len and clamps to the row's last real
-    # position past it (garbage duplicates the host never reads)
-    base = inputs.row_start + inputs.row_len - v[:, 0]
-    j = jnp.arange(spec_width, dtype=jnp.int32)[None, :]  # (1, S)
-    win_idx = jnp.clip(base[:, None] + jnp.minimum(j, v - 1), 0, T - 1)
-    rows_h = jnp.take(hidden[0], win_idx.reshape(-1), axis=0).reshape(
-        R, spec_width, -1
-    )
-    logits = lm_head(params, rows_h, spec)[..., : spec.vocab_size]  # (R, S, V)
-    tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (R, S)
+    # per-row last-token gather off the packed axis (the ragged analogue
+    # of gather_last_token); inactive rows clamp to slot 0 — garbage the
+    # host never reads
+    last_idx = jnp.clip(inputs.row_start + inputs.row_len - 1, 0, T - 1)
+    rows_h = jnp.take(hidden[0], last_idx, axis=0)[:, None, :]  # (R, 1, H)
+    logits = lm_head(params, rows_h, spec)[..., : spec.vocab_size]  # (R, 1, V)
+    if spec.on_device_sampling:
+        tokens = sample_tokens(
+            logits,
+            inputs.sampling_params,
+            rng if spec.do_sample else None,
+            spec.max_topk,
+            spec.do_sample,
+        )
+    else:
+        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     tokens = mark_non_finite_tokens(tokens, logits)
-    # greedy contiguous-match acceptance against the DRAFTED input ids (the
-    # post-gather input_ids — position base+j+1 holds draft j+1): count =
-    # 1 + leading matches, exactly the split SpeculativeServingSession's
-    # host rule, computed where the data already lives. A NON_FINITE
-    # verification token (-1) never equals a draft id, so acceptance stops
-    # at a poisoned position and the host quarantines on sight of the
-    # sentinel inside the accepted window.
-    drafted = jnp.take(
-        input_ids[0], jnp.clip(win_idx + 1, 0, T - 1).reshape(-1)
-    ).reshape(R, spec_width)
-    match = (drafted[:, :-1] == tokens[:, :-1]) & (j[:, :-1] + 1 < v)
-    counts = 1 + jnp.sum(
-        jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1
-    )  # (R,) in [1, verify_len]
-    tokens = jnp.concatenate(
-        [tokens, counts[:, None].astype(jnp.int32)], axis=1
-    )  # (R, spec_width + 1): verify tokens + accepted-count column
     out_logits = logits if spec.output_logits else None
     return StepOutput(tokens=tokens, logits=out_logits, cache=new_cache)
 

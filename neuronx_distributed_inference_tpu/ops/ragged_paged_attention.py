@@ -16,19 +16,7 @@ Packing contract (enforced by the host packer, ``MixedStepRunner.prepare``):
   prefetched ``tile_row`` table instead of the full per-token search the
   reference kernel does in its DMA schedule);
 - padded slots between segments carry position ``-1`` (masked out of the
-  softmax, their cache writes dropped via slot ``-1``);
-- SPEC-VERIFY rows (serving_spec_ragged) are decode rows with
-  ``query_len == draft_len + 1``: the segment carries the last committed
-  token plus the draft chain at consecutive positions, and the per-token
-  ``kv_pos <= q_pos`` causal mask over prior context + the in-flight
-  segment is EXACTLY target verification of the candidate sequence — the
-  kernel needs no spec-specific path, only segments wider than one token.
-  ``draft_len`` must stay < :data:`RAGGED_Q_TILE` so a spec segment, like a
-  plain decode row, occupies one q tile (config validation fences
-  ``speculation_length <= RAGGED_Q_TILE``); the per-row draft length lives
-  in the mixed program's ``verify_len`` descriptor
-  (models/base.MixedStepInputs), not in this kernel's scalar prefetch — the
-  attention math is draft-length-blind by construction.
+  softmax, their cache writes dropped via slot ``-1``).
 
 Grid: ``(Hq, q_tiles, kv_blocks)`` — the KV BlockSpec index map reads the
 per-row ``block_table`` through ``tile_row`` to DMA the right cache block

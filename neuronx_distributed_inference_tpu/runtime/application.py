@@ -197,11 +197,6 @@ class TpuModelForCausalLM:
                 mixed_buckets.append(b)
                 b *= 2
             mixed_buckets.append(b)
-            # serving_spec_ragged widens the family to the SPEC-VERIFY
-            # variant (mixed_step_spec): spec rows pack up to
-            # speculation_length query tokens (last token + drafts) in one
-            # segment — still one q tile each (speculation_length <=
-            # RAGGED_Q_TILE is validated), so the bucket ladder is unchanged
             self.mixed_step_model = MixedStepRunner(
                 self.spec,
                 mixed_buckets,
@@ -211,11 +206,6 @@ class TpuModelForCausalLM:
                 tc.pa_block_size,
                 tkg_buckets,
                 layer_fn=layer_fn,
-                spec_width=(
-                    tc.speculation_length
-                    if getattr(tc, "serving_spec_ragged", False)
-                    else 1
-                ),
             )
 
     # ---- weights / cache -------------------------------------------------

@@ -47,7 +47,6 @@ TAG_TOKEN_GENERATION = "token_generation_model"
 TAG_SPECULATION = "speculation_model"
 TAG_FUSED_SPECULATION = "fused_speculation_model"
 TAG_MIXED_STEP = "mixed_step_model"
-TAG_MIXED_STEP_SPEC = "mixed_step_spec_model"
 
 
 class SubModelRunner:
@@ -502,27 +501,13 @@ class MixedStepRunner:
         block_size: int,
         kv_buckets: List[int],  # kv-width ladder (block-aligned TKG buckets)
         layer_fn=None,
-        spec_width: int = 1,
     ):
-        """``spec_width`` > 1 builds the SPECULATIVE-VERIFICATION variant of
-        the family (``mixed_step_spec``, serving_spec_ragged): spec-verify
-        rows pack up to ``spec_width`` query tokens (last committed token +
-        drafts), the program gathers each row's ``verify_len`` tail
-        positions, and tokens come back (R, spec_width + 1) with the
-        device-computed accepted count in the last column. spec_width == 1
-        is byte-for-byte the plain mixed_step program."""
         from neuronx_distributed_inference_tpu.models.base import mixed_forward
         from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
             RAGGED_Q_TILE,
         )
 
-        if not 1 <= spec_width <= RAGGED_Q_TILE:
-            raise ValueError(
-                f"spec_width {spec_width} out of range [1, {RAGGED_Q_TILE}]: "
-                "a spec-verify segment must fit one ragged q tile"
-            )
-        self.spec_width = spec_width
-        self.tag = TAG_MIXED_STEP_SPEC if spec_width > 1 else TAG_MIXED_STEP
+        self.tag = TAG_MIXED_STEP
         self.phase = "mixed"
         self.spec = spec
         self.buckets = sorted(buckets)
@@ -533,10 +518,7 @@ class MixedStepRunner:
         self.q_tile = RAGGED_Q_TILE
         self.last_bucket: Optional[int] = None
         self._sealed = False
-        step = partial(
-            mixed_forward, spec=spec, mlp_fn=mlp_fn, layer_fn=layer_fn,
-            spec_width=spec_width,
-        )
+        step = partial(mixed_forward, spec=spec, mlp_fn=mlp_fn, layer_fn=layer_fn)
         self._fn = jax.jit(
             trace_marker(self.tag, step, owner=self),
             donate_argnums=(1,),  # paged cache in-place (same KV aliasing)
@@ -568,8 +550,6 @@ class MixedStepRunner:
         sampling_params: Optional[np.ndarray] = None,
         chain_src: Optional[np.ndarray] = None,  # (T,) int32; -1 = host id
         chain_tokens=None,  # (R, 1) int32; may be an UNFETCHED device array
-        verify_len: Optional[np.ndarray] = None,  # (R,) int32 (spec_width>1)
-        draft_tokens=None,  # (R, spec_width-1); may be an UNFETCHED device array
     ):
         """Pad the packed axis to its total-token bucket and the block table
         to ``width // block_size`` columns; build MixedStepInputs. Returns
@@ -579,11 +559,7 @@ class MixedStepRunner:
         gather (models/base.mixed_forward): omitted, INERT values (all -1 /
         zeros) are substituted so the synchronous path dispatches the SAME
         program identity as the pipelined one — the warmed program is the
-        served program in both modes. On a ``spec_width > 1`` runner,
-        ``verify_len``/``draft_tokens`` describe the spec-verify rows (inert
-        defaults: all-ones / zeros — a step with no spec rows dispatches the
-        same program identity as one full of them; ``chain_src`` then
-        indexes the FLATTENED draft matrix, not a row)."""
+        served program in both modes."""
         from neuronx_distributed_inference_tpu.models.base import MixedStepInputs
 
         T = int(input_ids.shape[0])
@@ -593,22 +569,6 @@ class MixedStepRunner:
             chain_src = np.full(T, -1, np.int32)
         if chain_tokens is None:
             chain_tokens = np.zeros((self.num_rows, 1), np.int32)
-        spec_kwargs = {}
-        if self.spec_width > 1:
-            if verify_len is None:
-                verify_len = np.ones(self.num_rows, np.int32)
-            if draft_tokens is None:
-                draft_tokens = np.zeros(
-                    (self.num_rows, self.spec_width - 1), np.int32
-                )
-            spec_kwargs = dict(
-                verify_len=jnp.asarray(
-                    np.asarray(verify_len, np.int32)
-                ),
-                # a device-resident proposal matrix passes through untouched
-                # (same no-op-asarray contract as chain_tokens below)
-                draft_tokens=jnp.asarray(draft_tokens, dtype=jnp.int32),
-            )
         if pad:
             input_ids = np.pad(input_ids, (0, pad))
             positions = np.pad(positions, (0, pad), constant_values=-1)
@@ -645,7 +605,6 @@ class MixedStepRunner:
             # (jnp.asarray is a no-op on a committed jax.Array) — the chain
             # never forces a host round-trip
             chain_tokens=jnp.asarray(chain_tokens, dtype=jnp.int32),
-            **spec_kwargs,
         )
         return inputs, T
 

@@ -138,15 +138,8 @@ class Request:
     preemptions: int = 0  # pool-exhaustion evictions survived
     absorbed: int = 0  # generated tokens folded into input_ids (re-admission)
     epoch: int = 0  # bumped per eviction: stale in-flight rows are discarded
-    # --- spec-ragged speculation (SpeculativeServingSession, serving_spec_
-    # ragged): per-request draft state. draft_ready flips once the draft
-    # app's cache holds this request's prompt (and back off on preemption —
-    # the draft line is re-prefilled after re-admission); draft_len is the
-    # CURRENT adaptive draft length (snapped to DRAFT_LEN choices so the
-    # program/bucket identity never depends on the policy); accept_ewma is
-    # the per-request draft-acceptance-rate EWMA the policy steers by.
-    draft_ready: bool = False
-    draft_len: int = 0
+    # --- speculation (SpeculativeServingSession): the per-request
+    # draft-acceptance-rate EWMA (_note_acceptance).
     accept_ewma: float = 1.0
     # --- a block-step model (runtime/block_step.py): the prompt tokens the
     # chunk program carries (its whole blocks; None = all of the prompt), the
@@ -333,14 +326,14 @@ class ServingSession:
         # serving path (ops/ragged_paged_attention.py)
         self.ragged = bool(getattr(tc, "serving_ragged", False))
         self.mixed_runner = None
-        # async 1-ahead pipelining for the ragged path (serving_ragged_async,
-        # default follows async_mode): mixed step k+1 chains decode rows on
+        # the ragged step pipelines exactly when async_mode does (as the
+        # split path's 1-ahead decode): mixed step k+1 chains decode rows on
         # step k's still-on-device tokens (device-side chained-id gather) and
         # step k's fetch starts non-blocking at dispatch — host bookkeeping
         # overlaps the device executing k+1. Tokens are consumed one step()
         # LATE, with the same epoch-guard/speculative-extra-step semantics as
         # the split path's 1-ahead decode.
-        self.ragged_async = False
+        self.ragged_async = self.ragged and bool(tc.async_mode)
         # cached (R, 3) sampling params: constant for the session's fixed
         # slot count, hoisted out of the per-step dispatch closures
         self._sampling_cache: Optional[np.ndarray] = None
@@ -368,21 +361,9 @@ class ServingSession:
                     "mixed_step program family (build the app with the same "
                     "config that constructs this session)"
                 )
-            if getattr(self.mixed_runner, "spec_width", 1) > 1 and not isinstance(
-                self, SpeculativeServingSession
-            ):
-                raise ValueError(
-                    "this app's mixed_step family is the SPEC-VERIFY "
-                    "variant (serving_spec_ragged): construct a "
-                    "SpeculativeServingSession with a draft app — a plain "
-                    "session would misread the (R, spec_width+1) token "
-                    "layout"
-                )
             # the split-path 1-ahead machinery stays off: the ragged pipeline
             # has its own pending-step consume (`_consume_ragged`)
             self.async_decode = False
-            ra = getattr(tc, "serving_ragged_async", None)
-            self.ragged_async = bool(tc.async_mode) if ra is None else bool(ra)
             if self.block_mode:
                 mb_max = max(
                     1,
@@ -1842,31 +1823,15 @@ class ServingSession:
             )
             dchain = chain[dec]
             chain_src[dst[dchain]] = dslot[dchain]
-        verify_len = np.ones(R, np.int32)
-        n_spec = 0
-        spec_dw = getattr(self.mixed_runner, "spec_width", 1) - 1
         for i in np.flatnonzero(~dec):
-            req, kind, n, p0, _c = rows[i]
+            req, _kind, n, p0, _c = rows[i]
             s = int(starts[i])
             pr = np.arange(p0, p0 + n)
             positions[s : s + n] = pr
             slot_mapping[s : s + n] = (
                 self._bt_matrix[req.slot, pr // bs] * bs + pr % bs
             )
-            if kind == "spec":
-                # spec-verify segment: [last committed token, draft_1..d] —
-                # the host writes only the first id; the drafts are gathered
-                # ON DEVICE from the draft app's proposal matrix (chain_src
-                # indexes its flattened (R, spec_width-1) layout), so the
-                # propose->verify hand-off never round-trips the host
-                n_spec += 1
-                ids[s] = req.last_token
-                chain_src[s + 1 : s + n] = req.slot * spec_dw + np.arange(
-                    n - 1, dtype=np.int32
-                )
-                verify_len[req.slot] = n
-            else:
-                ids[s : s + n] = req.input_ids[p0 : p0 + n]
+            ids[s : s + n] = req.input_ids[p0 : p0 + n]
         width = get_target_bucket(
             self.app.token_generation_model.buckets, int((p0s + ns).max())
         )
@@ -1886,8 +1851,6 @@ class ServingSession:
             "width": width,
             "chain_src": chain_src,
             "chained": bool(chain.any()),
-            "verify_len": verify_len,
-            "spec_rows": n_spec,
         }
 
     def _consume_ragged(self, pend, results: Dict[str, int]):
@@ -2266,26 +2229,9 @@ class SpeculativeServingSession(ServingSession):
 
     Cache discipline matches runtime/assisted.py: write-then-attend on both
     apps leaves rejected candidates as masked-stale entries that the next
-    round overwrites. Two dispatch modes:
-
-    - **split path** (default): contiguous caches only (speculative writes
-      need the position==slot invariant), draft propose + target verify as
-      two dispatches per step, host-side acceptance.
-    - **spec-ragged path** (``TpuConfig.serving_spec_ragged``): verification
-      rides the ragged MIXED dispatch — spec rows carry their draft tokens
-      as extra packed query positions, so ONE ``mixed_step_spec`` program
-      launch per step serves prefill chunks + plain decode rows + spec-verify
-      rows together against the PAGED cache (accept/rollback is the
-      write-then-attend discipline over the paged scatter: accepted draft
-      positions already hold the right KV, rejected ones are re-written next
-      round). The draft app stays on its own contiguous cache; its proposals
-      chain device-side into the verify pack, and the next round's draft
-      propose derives each row's accepted-token frontier IN-GRAPH from the
-      verify output (models/base.draft_chain_propose) — the frontier never
-      round-trips the host. Draft length adapts per request off the
-      acceptance EWMA, snapped to :attr:`draft_len_choices` so the program
-      identity never depends on the policy (docs/SERVING.md "Speculation in
-      the mixed step").
+    round overwrites. Contiguous caches only (speculative writes need the
+    position==slot invariant): draft propose + target verify as two
+    dispatches per step, host-side acceptance.
     """
 
     def __init__(
@@ -2298,8 +2244,6 @@ class SpeculativeServingSession(ServingSession):
         clock=None,
         sleep_fn=None,
     ):
-        tc = app.config.tpu_config
-        self.spec_ragged = bool(getattr(tc, "serving_spec_ragged", False))
         super().__init__(
             app,
             telemetry=telemetry,
@@ -2316,27 +2260,10 @@ class SpeculativeServingSession(ServingSession):
             )
         tc_d = draft_app.config.tpu_config
         spec = app.spec
-        if self.spec_ragged:
-            mr = self.mixed_runner
-            if speculation_length != getattr(mr, "spec_width", 1):
-                raise ValueError(
-                    f"speculation_length {speculation_length} != the app's "
-                    f"compiled mixed_step_spec width "
-                    f"{getattr(mr, 'spec_width', 1)} "
-                    "(TpuConfig.speculation_length sizes the program family)"
-                )
-            if tc_d.is_block_kv_layout:
-                raise NotImplementedError(
-                    "the spec-ragged DRAFT app runs the contiguous cache "
-                    "(row == slot; its proposals chain through "
-                    "draft_chain_propose, not the paged layout)"
-                )
-        elif self.block_mode or self.chunked:
+        if self.block_mode or self.chunked:
             raise NotImplementedError(
                 "speculative serving runs on the contiguous cache (no "
-                "paged/chunked-prefill layouts) — unless "
-                "serving_spec_ragged packs verification into the ragged "
-                "mixed step"
+                "paged/chunked-prefill layouts)"
             )
         if spec.bounded_window or spec.ring_window or (
             draft_app.spec.bounded_window or draft_app.spec.ring_window
@@ -2370,19 +2297,6 @@ class SpeculativeServingSession(ServingSession):
         self.draft = draft_app
         self.k = speculation_length
         self.async_decode = False  # accept/reject is a host decision per step
-        # --- spec-ragged state (docs/SERVING.md "Speculation in the mixed
-        # --- step") ------------------------------------------------------
-        #: snapped draft-length ladder the adaptive policy moves on: a small
-        #: FIXED set (powers of two up to k-1, plus k-1) so program/bucket
-        #: identity never depends on observed acceptance — only the packed
-        #: row_len/verify_len DATA changes
-        self.draft_len_choices = tuple(sorted(
-            {d for d in (1, 2, 4, 8) if d <= speculation_length - 1}
-            | {speculation_length - 1}
-        ))
-        #: in-flight draft proposals for the NEXT verify round:
-        #: (device (R, k-1) tokens, {id(req): (slot, epoch)})
-        self._draft_prop = None
         #: session-wide acceptance-rate EWMA — the router's least_loaded
         #: placement signal (None until the first spec round)
         self.acceptance_ewma: Optional[float] = None
@@ -2392,8 +2306,8 @@ class SpeculativeServingSession(ServingSession):
         #: to accept this verify round, or None for no cap. Capping is
         #: OUTPUT-INVARIANT — the accepted window holds the target's own
         #: greedy tokens, so accepting fewer merely regenerates them in
-        #: later rounds; only measured acceptance (and with it the adaptive
-        #: draft-length policy and the router's acceptance signal) moves.
+        #: later rounds; only measured acceptance (and with it the router's
+        #: acceptance signal) moves.
         self.draft_accept_cap = None
 
     prefilled_admission = False  # see ServingSession.prefilled_admission
@@ -2402,7 +2316,7 @@ class SpeculativeServingSession(ServingSession):
         raise NotImplementedError(
             "the disaggregated prefill tier does not support speculative "
             "decode sessions: the hand-off carries TARGET KV only, and the "
-            "draft app's cache needs its own prompt prefill (draft_ready) "
+            "draft app's cache needs its own prompt prefill "
             "— route speculative traffic to non-tier replicas"
         )
 
@@ -2418,15 +2332,6 @@ class SpeculativeServingSession(ServingSession):
         return max(1, min(count, 1 + int(cap)))
 
     def _max_admissible_prompt(self) -> int:
-        if self.spec_ragged:
-            # the chunked TARGET prefill admits long prompts through the
-            # mixed dispatch, but the DRAFT prefill is still a single CTE
-            # pass on the draft app (prompt + the first generated token,
-            # hence the -1) — cap admission there
-            return min(
-                ServingSession._max_admissible_prompt(self),
-                self.draft.context_encoding_model.buckets[-1] - 1,
-            )
         # the speculative session cannot run the windowed admission path
         # (the draft prefill is a single CTE pass): cap admission at one
         # context program of BOTH apps so _full_prefill's
@@ -2444,11 +2349,6 @@ class SpeculativeServingSession(ServingSession):
         return limit
 
     def _full_prefill(self, req: Request) -> bool:
-        if self.spec_ragged:
-            # spec-ragged admission is chunked (_admit defers to step());
-            # this path is unreachable there, but stay correct if a config
-            # ever routes through it
-            return super()._full_prefill(req)
         # fail BEFORE any state mutates: the draft prefill below is a single
         # CTE pass, so prompts needing the windowed path are rejected here
         if self.app.validate_prefill_length(req.prompt_len) or (
@@ -2473,17 +2373,8 @@ class SpeculativeServingSession(ServingSession):
         draft failure must not leak the slot — past the retry budget the
         request terminally FAILs (dispatch_error, slot released) and the
         session keeps serving."""
-        ids_row = req.input_ids
-        if self.spec_ragged and req.generated:
-            # the first generated token is already committed when the spec-
-            # ragged draft prefill runs (at prompt completion): include it
-            # so the draft cache has no gap at position prompt_len — the
-            # first chained propose writes the NEXT frontier at prompt_len+1
-            ids_row = np.concatenate(
-                [ids_row, np.asarray(req.generated[-1:], np.int32)]
-            )
-        S = ids_row.shape[0]
-        ids = ids_row[None, :]
+        S = req.prompt_len
+        ids = req.input_ids[None, :]
         mask = np.ones((1, S), np.int32)
         pos = np.arange(S, dtype=np.int32)[None, :]
         seq_ids = np.array([req.slot], np.int32)
@@ -2500,241 +2391,16 @@ class SpeculativeServingSession(ServingSession):
         if out is None:
             return True  # terminal FAILED(dispatch_error); slot released
         self.draft.kv_cache = out.cache
-        if self.spec_ragged:
-            req.draft_ready = True
-            req.draft_len = self.draft_len_choices[-1]
         return True
-
-    # ---- spec-ragged path (serving_spec_ragged): verification inside the
-    # ---- mixed dispatch, acceptance-adaptive drafts ----------------------
-
-    def _preempt(self, req: Request):
-        # the draft's contiguous cache line is abandoned with the slot: the
-        # request re-prefills BOTH apps after re-admission
-        req.draft_ready = False
-        super()._preempt(req)
-
-    def _finish_prefill(self, req: Request, first_token: int):
-        super()._finish_prefill(req, first_token)
-        if (
-            self.spec_ragged
-            and not req.finished
-            and req.status == STATUS_ACTIVE
-        ):
-            # prompt complete and decoding starts: bring the draft's cache
-            # up to date so the row joins the NEXT chained draft propose
-            # (this round it runs as a plain decode row)
-            self._draft_prefill(req)
-
-    def _spec_ragged_step(self) -> Dict[str, int]:
-        """One spec-ragged serving step: consume the pending verify (the
-        fetch was started non-blocking at dispatch — one-step-late commit
-        under pipelining, epoch-guarded), schedule prefill chunks + decode
-        rows + spec-verify rows from COMMITTED state (positions depend on
-        the data-dependent accepted counts, so — unlike the plain ragged
-        pipeline — the schedule follows the consume), dispatch ONE
-        ``mixed_step_spec`` program for all of them, then dispatch the next
-        round's draft propose chained on this verify's still-on-device
-        output (the accepted-token frontier never visits the host)."""
-        results: Dict[str, int] = {}
-        t_step0 = self.tel.clock()
-        self._step_fetch_wait_s = 0.0
-        pend = self._pending
-        self._pending = None
-        if pend is not None:
-            self._consume_spec(pend, results)
-
-        rows = self._schedule_spec()
-        prop = self._draft_prop
-        self._draft_prop = None  # one-shot: this round's pack consumes it
-        if not rows:
-            self._note_step_timing(t_step0)
-            return results
-        mr = self.mixed_runner
-        d = self._build_mixed_descriptors(rows)
-        draft_dev = prop[0] if (prop is not None and d["spec_rows"]) else None
-
-        def dispatch():
-            with self.tel.span(
-                "serving.mixed_step_spec", rows=len(rows), tokens=d["T"]
-            ):
-                inputs, _ = mr.prepare(
-                    d["ids"], d["positions"], d["slot_mapping"],
-                    d["row_start"], d["row_len"], d["ctx_len"],
-                    d["block_table"], d["width"],
-                    self._session_sampling_params(),
-                    chain_src=d["chain_src"],
-                    verify_len=d["verify_len"], draft_tokens=draft_dev,
-                )
-                return mr(self.app.params, self.app.kv_cache, inputs, None)
-
-        out = self._guarded_dispatch(
-            "mixed_step_spec", [t[0] for t in rows], dispatch
-        )
-        if out is None:
-            # in-flight rows terminally FAILED(dispatch_error); the pending
-            # step was consumed at the top (sync commit order holds)
-            self._note_step_timing(t_step0)
-            return results
-        self.app.kv_cache = out.cache
-        self.tel.step("mixed")
-        self.tel.bucket_dispatch(mr.tag, mr.last_bucket)
-        n_prefill = sum(1 for t in rows if t[1] == "prefill")
-        real_tokens = int(sum(t[2] for t in rows))
-        self.tel.mixed_step(
-            prefill_rows=n_prefill,
-            decode_rows=len(rows) - n_prefill - d["spec_rows"],
-            padded_slots=mr.last_bucket - real_tokens,
-            query_tokens=real_tokens,
-            spec_rows=d["spec_rows"],
-        )
-        for req, kind, n, _p0, _c in rows:
-            if kind == "prefill":
-                self._note_prefill(req, n)
-        self.tel.pool_gauges(
-            len(self.active), self.kv_pool_bytes, self.kv_free_bytes
-        )
-        snap = [
-            (req, kind, n, p0, req.slot, req.epoch)
-            for req, kind, n, p0, _c in rows
-        ]
-        if self.ragged_async:
-            self._start_fetch(out.tokens)
-            self._pending = (out.tokens, snap)
-        else:
-            self._consume_spec((out.tokens, snap), results)
-        # chain the NEXT round's draft propose on this verify's on-device
-        # output — dispatched AFTER the verify so the device pipelines
-        # verify -> draft back-to-back while the host books keep
-        self._dispatch_chained_draft(out.tokens, snap)
-        self._note_step_timing(t_step0)
-        return results
-
-    def _schedule_spec(self) -> List[tuple]:
-        """Build the spec-ragged row list [(req, kind, n, p0, chained)]:
-        prefill chunks exactly like the base scheduler, then every decoding
-        row — as a SPEC-VERIFY segment of ``draft_len + 1`` query tokens
-        when a current draft-proposal entry exists (epoch/slot-matched, the
-        row's draft cache is live, headroom allows), else as a plain
-        single-token decode row. Scheduled from committed state: the spec
-        path consumes before it schedules."""
-        rows: List[tuple] = []
-        if self.chunked:
-            pref = [
-                r for r in self.slots
-                if r is not None and not r.finished
-                and r.prefill_pos < r.prompt_len
-            ]
-            for req in pref[: self.max_prefill_seqs]:
-                n = min(self.chunk_size, req.prompt_len - req.prefill_pos)
-                try:
-                    self._alloc(req.slot, req.prefill_pos + n)
-                except RuntimeError:
-                    self._preempt(req)
-                    continue
-                rows.append((req, "prefill", n, req.prefill_pos, False))
-        scheduled = {id(t[0]) for t in rows}
-        pos_limit = self.app._pos_limit()
-        prop_map = self._draft_prop[1] if self._draft_prop is not None else {}
-        for r in list(self.slots):
-            if (
-                r is None or r.finished or id(r) in scheduled or r.prefilling
-            ):
-                continue
-            p0 = r.pos
-            room = r.max_new_tokens - len(r.generated)
-            v = 1
-            e = prop_map.get(id(r))
-            if (
-                e == (r.slot, r.epoch)
-                and r.draft_ready
-                and r.draft_len > 0
-            ):
-                v = max(1, min(r.draft_len + 1, room, pos_limit - p0))
-            try:
-                self._alloc(r.slot, p0 + v)
-            except RuntimeError:
-                self._preempt(r)
-                continue
-            rows.append((r, "spec" if v > 1 else "decode", v, p0, False))
-        return rows
-
-    def _consume_spec(self, pend, results: Dict[str, int]):
-        """Commit one dispatched spec-ragged step — the step's ONE consumed
-        host sync ((R, k+1) verify tokens + device-computed accepted
-        counts). Per row: take the accepted window, truncate at EOS/budget
-        exactly like the split path, advance the paged-cache position by the
-        committed length (the rejected tail's KV is re-written next round —
-        write-then-attend rollback), feed the acceptance EWMA + adaptive
-        draft-length policy, and quarantine on a sentinel inside the
-        accepted window (only that row dies; co-batched rows byte-identical,
-        pinned). Stale-epoch rows are speculative leftovers — discarded."""
-        t0 = self.tel.clock()
-        tokens = np.asarray(pend[0])  # (R, k + 1)
-        self._step_fetch_wait_s += self.tel.clock() - t0
-        if self.faults is not None:
-            tokens = self.faults.corrupt_tokens(self, tokens)
-        k = self.k
-        for req, kind, n, p0, slot, epoch in pend[1]:
-            if req.finished or req.preempted or req.epoch != epoch:
-                continue
-            if kind == "prefill":
-                req.prefill_pos = p0 + n
-                if req.prefill_pos >= req.prompt_len:
-                    tok = int(tokens[slot, 0])
-                    self._finish_prefill(req, tok)
-                    if req.status != STATUS_FAILED:  # not quarantined
-                        results[req.req_id] = tok
-                continue
-            v = n  # verify-window width this row dispatched with
-            count = max(1, min(int(tokens[slot, k]), v))
-            count = self._capped_accept(req, count, v - 1)
-            window = tokens[slot, :count]
-            if (window < 0).any():
-                # non-finite sentinel inside the accepted window: a poisoned
-                # TARGET row — only this row dies (a poisoned DRAFT merely
-                # mis-proposes and costs acceptance, never correctness)
-                self._quarantine(req)
-                continue
-            row = [int(t) for t in window]
-            if req.eos_token_id is not None and req.eos_token_id in row:
-                row = row[: row.index(req.eos_token_id) + 1]
-            room = req.max_new_tokens - len(req.generated)
-            row = row[:room]
-            req.generated.extend(row)
-            # acceptance-length telemetry: committed tokens this round — the
-            # histogram's sum is exactly the decode tokens this session
-            # delivered (plain decode rows observe 1)
-            self.tel.spec_accept(len(row))
-            self._commit_tokens(req, len(row))
-            req.pos = p0 + len(row)
-            if row:
-                results[req.req_id] = row[-1]
-            if v > 1:
-                self._note_acceptance(req, accepted=count - 1, drafted=v - 1)
-            if (
-                (req.eos_token_id is not None and row
-                 and row[-1] == req.eos_token_id)
-                or len(req.generated) >= req.max_new_tokens
-                or req.pos + 1 >= self.app.config.tpu_config.seq_len
-            ):
-                self._finish(req)
 
     #: per-request acceptance-EWMA smoothing (fast: the policy must react
     #: within a few rounds when a request's text regime shifts)
     SPEC_EWMA_ALPHA = 0.5
     #: session-level smoothing for the router's placement signal
     SESSION_EWMA_ALPHA = 0.2
-    #: grow the draft above this per-draft acceptance rate, shrink below
-    #: the lower bound — hysteresis so the length doesn't thrash
-    SPEC_GROW_AT = 0.75
-    SPEC_SHRINK_AT = 0.4
-
     def _note_acceptance(self, req: Request, accepted: int, drafted: int):
         """Fold one spec round's outcome into the per-request and session
-        EWMAs and snap the request's next draft length one notch along
-        :attr:`draft_len_choices` (shrink when drafts stop paying — e.g.
-        code vs prose — grow back when acceptance recovers)."""
+        EWMAs."""
         rate = accepted / max(1, drafted)
         a = self.SPEC_EWMA_ALPHA
         req.accept_ewma = (1 - a) * req.accept_ewma + a * rate
@@ -2743,145 +2409,9 @@ class SpeculativeServingSession(ServingSession):
             rate if self.acceptance_ewma is None
             else (1 - b) * self.acceptance_ewma + b * rate
         )
-        choices = self.draft_len_choices
-        i = choices.index(req.draft_len) if req.draft_len in choices else 0
-        if req.accept_ewma >= self.SPEC_GROW_AT and i + 1 < len(choices):
-            req.draft_len = choices[i + 1]
-        elif req.accept_ewma < self.SPEC_SHRINK_AT and i > 0:
-            req.draft_len = choices[i - 1]
-        # observe the length THIS round actually drafted (the policy's new
-        # choice shows up as the next rounds' observations) — the histogram
-        # sum is then exactly the drafted-token total, which is what the
-        # bench's measured-acceptance rate divides by
+        # the histogram sum is exactly the drafted-token total, which is what
+        # a measured-acceptance rate divides by
         self.tel.spec_round(drafted, req.accept_ewma, req_id=req.req_id)
-
-    def _dispatch_chained_draft(self, verify_tokens, snap):
-        """Dispatch the draft propose for the NEXT round, chained on the
-        just-dispatched verify's (R, k+1) device output: each row's frontier
-        (last accepted token, position after it) is derived in-graph
-        (models/base.draft_chain_propose), so under pipelining the device
-        runs verify -> draft back-to-back with no host round-trip in
-        between. Rows whose request terminates/evicts at consume simply
-        leave their proposals unused (the draft's stale writes are re-
-        written next round). A draft dispatch failure is NON-fatal: the
-        next round falls back to plain decode rows and speculation resumes
-        after."""
-        import jax.numpy as jnp
-
-        R = self.num_slots
-        use_chain = np.zeros((R, 1), np.int32)
-        p0_base = np.zeros((R, 1), np.int32)
-        fallback = np.zeros((R, 1), np.int32)
-        seq_ids = np.full((R,), -1, np.int32)
-        prop_map: Dict[int, tuple] = {}
-        bases: List[int] = []
-        # near-limit fence (the split path's rows/tail split, one pipeline
-        # stage earlier): the chained propose may write draft positions up
-        # to base + counts_max + (k-2) = base + 2k - 2 and needs a mask
-        # bucket covering base + 2k - 1 — rows whose worst case exceeds the
-        # draft's reach are left out of the chain and run as plain decode
-        # rows until they terminate at the position bound
-        draft_limit = self.draft._pos_limit()
-        for req, kind, n, p0, slot, epoch in snap:
-            if (
-                req.finished or req.preempted or req.epoch != epoch
-                or not req.draft_ready
-            ):
-                continue
-            if kind == "prefill":
-                if p0 + n < req.prompt_len:
-                    continue  # still mid-prompt: no frontier yet
-                base_pos = req.prompt_len - 1  # window = the first token
-            else:
-                base_pos = p0
-            if base_pos + 2 * self.k - 1 > draft_limit:
-                continue  # frontier too close to the draft's position bound
-            use_chain[slot, 0] = 1
-            p0_base[slot, 0] = base_pos
-            seq_ids[slot] = slot
-            prop_map[id(req)] = (slot, epoch)
-            bases.append(base_pos)
-        if not prop_map:
-            self._draft_prop = None
-            return
-        # host upper bound for the draft's mask/bucket: the true positions
-        # (base + accepted count) live on device
-        upper = max(bases) + 2 * self.k - 1
-        bucket = self.draft._decode_bucket(upper)
-        fn = self._draft_chain_fn(bucket)
-        sp = self._session_sampling_params()
-
-        def dispatch():
-            with self.tel.span("serving.draft_chain", rows=len(prop_map)):
-                with jax.set_mesh(self.draft.mesh):
-                    return fn(
-                        self.draft.params, self.draft.kv_cache,
-                        verify_tokens,
-                        jnp.asarray(fallback), jnp.asarray(fallback),
-                        jnp.asarray(use_chain), jnp.asarray(p0_base),
-                        jnp.asarray(seq_ids),
-                        jnp.asarray(sp, jnp.float32), None,
-                    )
-
-        res = self._guarded_dispatch("draft_chain", [], dispatch)
-        if res is None:
-            self._draft_prop = None
-            return
-        proposals, _, d_cache = res
-        self.draft.kv_cache = d_cache
-        self.tel.bucket_dispatch("spec_draft_chain", bucket)
-        self._draft_prop = (proposals, prop_map)
-
-    def _draft_chain_fn(self, bucket: int):
-        """The jitted chained-draft program for one decode bucket. Programs
-        are cached ON THE DRAFT APP (shared across sessions, like the
-        runners) and retrace-guarded with decode_chunk's lazy-first-compile
-        semantics: the first trace per bucket is legitimate even sealed, a
-        RE-trace after that is the steady-state recompile the guard
-        forbids — sealing follows the mixed runner's seal()."""
-        from functools import partial as _partial
-
-        fns = self.draft.__dict__.setdefault("_spec_chain_fns", {})
-        key = (bucket, self.k)
-        rec = fns.get(key)
-        if rec is None:
-            import jax as _jax
-
-            from neuronx_distributed_inference_tpu.analysis import (
-                retrace_guard,
-            )
-            from neuronx_distributed_inference_tpu.models.base import (
-                draft_chain_propose,
-            )
-
-            tkg = self.draft.token_generation_model
-            inner = _partial(
-                draft_chain_propose,
-                spec=tkg.spec, num_steps=self.k - 1, bucket=bucket,
-                spec_width=self.k, mlp_fn=tkg.mlp_fn, layer_fn=tkg.layer_fn,
-            )
-            tag = f"spec_draft_chain[{bucket}]"
-            state = {"traced": False}
-            # the owner cell names the runner whose seal state judges a
-            # re-trace — updated to the CURRENT session's mixed runner on
-            # every lookup, so a draft app shared by several target
-            # sessions is always judged against the session actually
-            # dispatching (not whichever session first built the program)
-            owner = {"mr": self.mixed_runner}
-
-            def chain_step_fn(*args, **kwargs):
-                retrace_guard.note_trace(
-                    tag, sealed=state["traced"] and owner["mr"]._sealed
-                )
-                out = inner(*args, **kwargs)
-                state["traced"] = True
-                return out
-
-            rec = (_jax.jit(chain_step_fn, donate_argnums=(1,)), owner)
-            fns[key] = rec
-        fn, owner = rec
-        owner["mr"] = self.mixed_runner
-        return fn
 
     def _step_inner(self) -> Dict[str, int]:
         """One speculation round for every decoding request. Returns ALL
@@ -2889,8 +2419,6 @@ class SpeculativeServingSession(ServingSession):
         request.generated for the full stream). The containment wrapper
         (deadlines, re-admission, watchdog, fault hooks) lives in the base
         class's :meth:`ServingSession.step`."""
-        if self.spec_ragged:
-            return self._spec_ragged_step()
         import jax
 
         results: Dict[str, int] = {}
@@ -2974,13 +2502,9 @@ class SpeculativeServingSession(ServingSession):
             # truncation) tokens this round — the histogram's sum is exactly
             # the decode tokens speculation delivered for this session
             self.tel.spec_accept(len(row))
-            # acceptance EWMAs on the split path too: the per-request and
-            # session acceptance signals (the router's least_loaded
-            # placement bonus, the workload engine's tenant separation)
-            # must not depend on WHICH dispatch mode verified the drafts —
-            # split-path sessions previously never populated them. The
-            # draft-length snap inside is inert here (the split path
-            # always proposes k-1).
+            # the per-request and session acceptance signals (the router's
+            # least_loaded placement bonus, the workload engine's tenant
+            # separation)
             self._note_acceptance(r, accepted=int(counts[s]) - 1,
                                   drafted=k - 1)
             self._commit_tokens(r, len(row))

@@ -58,9 +58,9 @@ LATENCY_MS_BUCKETS = (
 )
 # speculation acceptance length (tokens per round, 1..k); k <= 16 in practice
 ACCEPT_LEN_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0)
-# adaptive draft lengths (spec-ragged policy choices, snapped powers of two)
+# tokens drafted per speculation round
 DRAFT_LEN_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0)
-# per-request acceptance-rate EWMA (spec-ragged adaptive-draft signal, 0..1)
+# per-request draft-acceptance-rate EWMA (0..1)
 SPEC_EWMA_BUCKETS = (0.1, 0.25, 0.4, 0.5, 0.6, 0.75, 0.9, 1.0)
 # prefill chunks consumed per request before the first token
 CHUNK_COUNT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)

@@ -577,14 +577,13 @@ class TelemetrySession:
             "decode tokens)", buckets=metrics_mod.ACCEPT_LEN_BUCKETS)
         self._spec_draft_len = r.histogram(
             "nxdi_spec_draft_len",
-            "adaptive draft length chosen per spec-ragged round (snapped to "
-            "the session's fixed choice ladder; shrinks when acceptance "
-            "drops)", buckets=metrics_mod.DRAFT_LEN_BUCKETS)
+            "tokens drafted per speculation round (sums to the drafted-"
+            "token total a measured acceptance rate divides by)",
+            buckets=metrics_mod.DRAFT_LEN_BUCKETS)
         self._spec_ewma = r.histogram(
             "nxdi_spec_accept_ewma",
             "per-request draft-acceptance-rate EWMA observed after each "
-            "spec-ragged round (the adaptive-draft policy's steering "
-            "signal)", buckets=metrics_mod.SPEC_EWMA_BUCKETS)
+            "speculation round", buckets=metrics_mod.SPEC_EWMA_BUCKETS)
         self._step_host_ms = r.histogram(
             "nxdi_step_host_ms",
             "host-side bookkeeping per serving step (scheduling, descriptor "
@@ -1484,11 +1483,9 @@ class TelemetrySession:
         decode_rows: int,
         padded_slots: int,
         query_tokens: int,
-        spec_rows: int = 0,
     ) -> None:
         """Composition of ONE ragged mixed dispatch (serving_ragged): rows
-        serving prefill chunks, rows serving decode, rows serving packed
-        SPEC-VERIFY segments (serving_spec_ragged), padded packed slots and
+        serving prefill chunks, rows serving decode, padded packed slots and
         real query tokens in the dispatched total-token bucket. Each label's
         observation COUNT equals the number of mixed dispatches (pinned by
         test); padded_slots/(padded_slots+query_tokens) is the padded-token
@@ -1499,7 +1496,6 @@ class TelemetrySession:
         self._mixed.child(("decode_rows",)).observe(decode_rows)
         self._mixed.child(("padded_slots",)).observe(padded_slots)
         self._mixed.child(("query_tokens",)).observe(query_tokens)
-        self._mixed.child(("spec_rows",)).observe(spec_rows)
 
     # ---- multi-replica router (runtime/router.py) ------------------------
 
@@ -1734,10 +1730,10 @@ class TelemetrySession:
         accept_ewma: float,
         req_id: Optional[str] = None,
     ) -> None:
-        """Adaptive-draft policy signals of one spec-ragged round: the
-        request's NEXT snapped draft length and its acceptance-rate EWMA
-        after the update (docs/OBSERVABILITY.md). With ``req_id`` the round
-        also lands as an instant on the request's timeline."""
+        """One speculation round of one request: the tokens it drafted and
+        the request's acceptance-rate EWMA after the update
+        (docs/OBSERVABILITY.md). With ``req_id`` the round also lands as an
+        instant on the request's timeline."""
         if not self.enabled:
             return
         self._spec_draft_len.observe(draft_len)

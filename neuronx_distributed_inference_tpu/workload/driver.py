@@ -39,7 +39,7 @@ a :class:`~.generator.WorkloadTrace`:
   ``spec_accept_rate`` profiles and the target session(s) are speculative,
   the driver installs :func:`~.generator.make_accept_gate` as
   ``session.draft_accept_cap`` — the CPU-harness draft-agreement model that
-  makes adaptive draft lengths move per tenant without changing one output
+  makes measured acceptance move per tenant without changing one output
   byte.
 
 Everything here is host bookkeeping: no device fetches (the tpulint

@@ -29,7 +29,7 @@ length. This module builds that traffic shape as data:
   round, position) agreement draw that CAPS the accepted draft count of a
   verify round. Capping acceptance is output-invariant (capped tokens are
   the target's own greedy tokens and are simply regenerated in later
-  rounds), so the adaptive draft-length machinery actually moves per tenant
+  rounds), so the acceptance EWMAs (a request's, a session's) move per tenant
   while token streams stay byte-identical.
 
 Determinism contract: :func:`generate` is a pure function of its

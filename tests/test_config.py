@@ -54,7 +54,8 @@ def test_fault_containment_knob_defaults():
 
 #: options that went: the two expert strategies no measurement chose (PR 50),
 #: the six reference-only names that were fields only so that setting them
-#: raised, and the two hardware knobs that did nothing
+#: raised, the two hardware knobs that did nothing, and the ragged step's two
+#: (PR 64: speculation inside it, and its form, which follows async_mode)
 GONE_OPTIONS = {
     "capacity_factor": 1.5,
     "moe_fused_kernel_enabled": True,
@@ -66,6 +67,8 @@ GONE_OPTIONS = {
     "weights_to_skip_layout_optimization": ["lm_head"],
     "logical_nc_config": 2,
     "scratchpad_page_size": 1024,
+    "serving_spec_ragged": True,
+    "serving_ragged_async": False,
 }
 
 
@@ -97,100 +100,6 @@ def test_fault_containment_knob_validation(kwargs, match):
     construction, never mid-serving."""
     with pytest.raises(ValueError, match=match):
         TpuConfig(**kwargs)
-
-
-def test_serving_ragged_async_knob():
-    """ISSUE 8: the pipelined-ragged knob defaults to None (follows
-    async_mode), round-trips, accepts a valid ragged config, and is
-    rejected without serving_ragged."""
-    tc = TpuConfig()
-    assert tc.serving_ragged_async is None
-    tc2 = TpuConfig.from_dict(tc.to_dict())
-    assert tc2.serving_ragged_async is None
-    ok = TpuConfig(
-        is_continuous_batching=True, is_block_kv_layout=True,
-        serving_ragged=True, serving_ragged_async=True,
-    )
-    assert ok.serving_ragged_async is True
-    off = TpuConfig(
-        is_continuous_batching=True, is_block_kv_layout=True,
-        serving_ragged=True, serving_ragged_async=False,
-    )
-    assert off.serving_ragged_async is False
-
-
-@pytest.mark.parametrize(
-    "kwargs",
-    [
-        dict(serving_ragged_async=True),  # no serving_ragged
-        dict(serving_ragged_async=True, is_block_kv_layout=True),
-    ],
-)
-def test_serving_ragged_async_rejected_without_ragged(kwargs):
-    with pytest.raises(ValueError, match="serving_ragged_async"):
-        TpuConfig(**kwargs)
-
-
-def test_serving_spec_ragged_knob():
-    """ISSUE 12: serving_spec_ragged defaults off, round-trips, and accepts
-    the full valid stack (serving_ragged + paged + continuous + chunked +
-    2 <= speculation_length <= 16)."""
-    from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
-
-    tc = TpuConfig()
-    assert tc.serving_spec_ragged is False
-    assert TpuConfig.from_dict(tc.to_dict()).serving_spec_ragged is False
-    ok = TpuConfig(
-        is_continuous_batching=True, is_block_kv_layout=True,
-        is_chunked_prefill=True,
-        chunked_prefill_config=ChunkedPrefillConfig(
-            max_num_seqs=2, kernel_q_tile_size=16
-        ),
-        serving_ragged=True, serving_spec_ragged=True, speculation_length=4,
-    )
-    assert ok.serving_spec_ragged is True
-
-
-@pytest.mark.parametrize(
-    "kwargs, match",
-    [
-        # no serving_ragged at all
-        (dict(serving_spec_ragged=True, speculation_length=4),
-         "serving_spec_ragged"),
-        # ragged but no chunked prefill: prompt chunks must ride the mixed
-        # dispatch (one program identity per step)
-        (dict(serving_spec_ragged=True, speculation_length=4,
-              serving_ragged=True, is_block_kv_layout=True,
-              is_continuous_batching=True),
-         "is_chunked_prefill"),
-        # speculation_length out of the q-tile range
-        (dict(serving_spec_ragged=True, speculation_length=0,
-              serving_ragged=True, is_block_kv_layout=True,
-              is_continuous_batching=True, is_chunked_prefill=True),
-         "speculation_length"),
-        (dict(serving_spec_ragged=True, speculation_length=17,
-              serving_ragged=True, is_block_kv_layout=True,
-              is_continuous_batching=True, is_chunked_prefill=True),
-         "speculation_length"),
-    ],
-)
-def test_serving_spec_ragged_fences(kwargs, match):
-    with pytest.raises(ValueError, match=match):
-        TpuConfig(**kwargs)
-
-
-def test_serving_spec_ragged_greedy_only():
-    from neuronx_distributed_inference_tpu.config import (
-        OnDeviceSamplingConfig,
-    )
-
-    with pytest.raises(NotImplementedError, match="greedy-only"):
-        TpuConfig(
-            is_continuous_batching=True, is_block_kv_layout=True,
-            is_chunked_prefill=True, serving_ragged=True,
-            serving_spec_ragged=True, speculation_length=4,
-            on_device_sampling_config=OnDeviceSamplingConfig(do_sample=True),
-        )
 
 
 def test_router_knob_defaults_and_roundtrip():
@@ -424,7 +333,7 @@ def test_latent_attention_refuses_lora_and_whole_model_dp():
         data_parallel_degree = 1
         fused_qkv = is_block_kv_layout = is_prefix_caching = serving_ragged = kv_quantized = False
         speculation_length = medusa_speculation_length = 0
-        enable_fused_speculation = enable_eagle_speculation = serving_spec_ragged = False
+        enable_fused_speculation = enable_eagle_speculation = False
         lora_config = None
 
     validate_latent_attention(Options())

@@ -151,13 +151,10 @@ def test_kern702_full_array_block_is_legal():
 
 def test_kern702_packing_contracts():
     # tq=32 > RAGGED_Q_TILE=16: a tile could span two packed rows
-    fs = ka.packing_contract_findings("r/m/bf16", "ops/r.py", 32, 16, 4)
+    fs = ka.packing_contract_findings("r/m/bf16", "ops/r.py", 32, 16)
     assert any(f.key.endswith("rowspan") for f in fs)
-    # spec segment wider than the tile
-    fs = ka.packing_contract_findings("r/m/bf16", "ops/r.py", 8, 16, 12)
-    assert any(f.key.endswith("specfit") for f in fs)
-    # the committed contract (tq=16 divides 16, spec width 4 fits) is clean
-    assert ka.packing_contract_findings("r/m/bf16", "ops/r.py", 16, 16, 4) == []
+    # the committed contract (tq=16 divides 16) is clean
+    assert ka.packing_contract_findings("r/m/bf16", "ops/r.py", 16, 16) == []
 
 
 # ---------------------------------------------------------------------------

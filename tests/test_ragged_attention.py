@@ -284,7 +284,7 @@ def test_lower_whole_mixed_step_program():
         row_len=_sds((R,), jnp.int32),
         ctx_len=_sds((R,), jnp.int32),
         sampling_params=_sds((R, 3), jnp.float32),
-        # chained-id gather inputs (serving_ragged_async): always present in
+        # chained-id gather inputs (async_mode): always present in
         # the SERVED program (inert in sync mode) — export what serving runs
         chain_src=_sds((1, T), jnp.int32),
         chain_tokens=_sds((R, 1), jnp.int32),

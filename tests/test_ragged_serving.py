@@ -53,8 +53,8 @@ def state_dict():
 @pytest.fixture(scope="module")
 def apps(state_dict):
     legacy = TpuModelForCausalLM(None, _cfg(False)).load(state_dict=state_dict)
-    # serving_ragged_async defaults to async_mode (True): the module's ragged
-    # app runs the PIPELINED path — every pin below covers pipelining ON
+    # the ragged step pipelines when async_mode does (the default): the
+    # module's ragged app runs the PIPELINED path
     ragged = TpuModelForCausalLM(None, _cfg(True)).load(state_dict=state_dict)
     return legacy, ragged
 
@@ -62,7 +62,7 @@ def apps(state_dict):
 @pytest.fixture(scope="module")
 def sync_ragged_app(state_dict):
     return TpuModelForCausalLM(
-        None, _cfg(True, serving_ragged_async=False)
+        None, _cfg(True, async_mode=False)
     ).load(state_dict=state_dict)
 
 
@@ -296,15 +296,18 @@ def test_session_requires_mixed_family():
 # ---------------------------------------------------------------------------
 
 
-def test_ragged_async_default_follows_async_mode(apps, sync_ragged_app):
-    """serving_ragged_async=None follows async_mode (the config default is
-    pipelining ON, mirroring the split path's 1-ahead decode); an explicit
-    False forces the synchronous dispatch+fetch-per-step mode."""
+def test_ragged_step_pipelines_iff_async_mode(apps, sync_ragged_app):
+    """The ragged step pipelines exactly when async_mode does (the config
+    default is pipelining ON, as the split path's 1-ahead decode);
+    async_mode=False is the synchronous dispatch+fetch-per-step form, and
+    the split path's own 1-ahead machinery stays off either way."""
     _, ragged = apps
     ragged.init_kv_cache()
-    assert ServingSession(ragged).ragged_async is True
+    sess = ServingSession(ragged)
+    assert sess.ragged_async is True and sess.async_decode is False
     sync_ragged_app.init_kv_cache()
-    assert ServingSession(sync_ragged_app).ragged_async is False
+    sess = ServingSession(sync_ragged_app)
+    assert sess.ragged_async is False and sess.async_decode is False
 
 
 def test_async_vs_sync_vs_legacy_byte_identical(apps, sync_ragged_app):
@@ -474,8 +477,3 @@ def test_async_slot_reuse_after_finish(apps):
     # predictably finishes them) — _pending may legitimately be None
     assert sess.add_request("probe", [42, 10, 11], max_new_tokens=4)
     assert sess.run_to_completion()["probe"] == golden
-
-
-def test_serving_ragged_async_config_validation():
-    with pytest.raises(ValueError, match="serving_ragged_async"):
-        make_tiny_config(tpu=dict(serving_ragged_async=True))

@@ -6,7 +6,7 @@ The acceptance pins:
   (the native fallback never fires) through the shard_map dispatch, with
   the head-parallel operands sharded and descriptors replicated;
 - the tp=2 kernel stream is byte-identical to the tp=2 native fallback AND
-  to the tp=1 stream for plain, int8-KV, and spec-ragged configs;
+  to the tp=1 stream for plain and int8-KV configs;
 - zero steady-state recompiles at tp=2 with the mixed runner sealed;
 - the WHOLE sharded mixed program AOT-lowers for the TPU target from this
   CPU host (shard_map + forced Mosaic kernels + fused quantized scatters).
@@ -24,17 +24,13 @@ from tests.conftest import make_tiny_config, make_random_hf_state_dict
 
 from neuronx_distributed_inference_tpu.config import ChunkedPrefillConfig
 from neuronx_distributed_inference_tpu.runtime.application import TpuModelForCausalLM
-from neuronx_distributed_inference_tpu.runtime.serving import (
-    ServingSession,
-    SpeculativeServingSession,
-)
+from neuronx_distributed_inference_tpu.runtime.serving import ServingSession
 
 PROMPTS = {
     "r1": [5, 17, 92, 41],
     "r2": list(range(30, 52)),  # 22 tokens: chunks across several steps
     "r3": [7, 7, 7],
 }
-K = 4
 
 
 def _cfg(tp=1, **extra):
@@ -64,9 +60,9 @@ def _load(cfg, sd):
     return TpuModelForCausalLM(None, cfg).load(state_dict=sd)
 
 
-def _standard_mix(app, sess_factory=None):
+def _standard_mix(app):
     app.init_kv_cache()
-    sess = sess_factory() if sess_factory else ServingSession(app)
+    sess = ServingSession(app)
     assert sess.add_request("r1", PROMPTS["r1"], max_new_tokens=6)
     sess.step()
     assert sess.add_request("r2", PROMPTS["r2"], max_new_tokens=6)
@@ -92,42 +88,6 @@ def test_tp2_kernel_matches_native_and_tp1(state_dict, extra):
     out_tp2_kernel = _standard_mix(
         _load(_cfg(2, attn_kernel_enabled=True, **extra), state_dict)
     )
-    assert all(len(v) > 0 for v in out_tp1.values())
-    assert out_tp2_native == out_tp1
-    assert out_tp2_kernel == out_tp1
-
-
-def test_tp2_spec_ragged_matches_tp1(state_dict):
-    """Spec-ragged (verification INSIDE the mixed dispatch) at tp=2 with the
-    forced kernel: byte-identical to tp=2 native and tp=1. The draft runs
-    the same weights at tp=1 (acceptance ~1.0 — the deep-chain regime)."""
-    spec_extra = dict(serving_spec_ragged=True, speculation_length=K)
-
-    def _draft_cfg(tp):
-        # the draft shares the target's mesh degree: chained device tokens
-        # hand straight from the target's step to the draft's propose
-        cfg = make_tiny_config(hidden_size=256, intermediate_size=512, tpu=dict(
-            is_continuous_batching=True, batch_size=4, ctx_batch_size=1,
-            seq_len=64,
-        ))
-        cfg.tpu_config.tp_degree = tp
-        return cfg
-
-    def run(cfg):
-        target = _load(cfg, state_dict)
-        draft = _load(_draft_cfg(cfg.tpu_config.tp_degree), state_dict)
-        target.init_kv_cache()
-        draft.init_kv_cache()
-        return _standard_mix(
-            target,
-            lambda: SpeculativeServingSession(
-                target, draft, speculation_length=K
-            ),
-        )
-
-    out_tp1 = run(_cfg(1, **spec_extra))
-    out_tp2_native = run(_cfg(2, **spec_extra))
-    out_tp2_kernel = run(_cfg(2, attn_kernel_enabled=True, **spec_extra))
     assert all(len(v) > 0 for v in out_tp1.values())
     assert out_tp2_native == out_tp1
     assert out_tp2_kernel == out_tp1

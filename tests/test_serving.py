@@ -330,6 +330,123 @@ def test_speculative_serving_near_limit_matches():
     assert out == golden
 
 
+# ---------------------------------------------------------------------------
+# the speculative session round by round, over a draft that always agrees
+# (the target's own weights) and one that seldom does (other weights)
+# ---------------------------------------------------------------------------
+
+SPEC_K = 4
+SPEC_PROMPTS = {"r1": [5, 17, 92, 41], "r2": [64, 3, 27, 9, 14, 33], "r3": [7, 8]}
+
+
+def _spec_cfg():
+    return make_tiny_config(
+        tpu=dict(is_continuous_batching=True, batch_size=2, ctx_batch_size=1)
+    )
+
+
+@pytest.fixture(scope="module")
+def spec_apps():
+    """(plain app, target, {draft's quality: draft}); plain and target share weights."""
+    sd = make_random_hf_state_dict(_spec_cfg(), seed=0)
+    load = lambda state: TpuModelForCausalLM(None, _spec_cfg()).load(state_dict=state)
+    drafts = {
+        "same_weights": load(sd),
+        "wrong_weights": load(make_random_hf_state_dict(_spec_cfg(), seed=7)),
+    }
+    return load(sd), load(sd), drafts
+
+
+def _spec_session(spec_apps, quality, **kw):
+    from neuronx_distributed_inference_tpu.runtime.serving import SpeculativeServingSession
+
+    _, target, drafts = spec_apps
+    target.init_kv_cache()
+    drafts[quality].init_kv_cache()
+    return SpeculativeServingSession(target, drafts[quality], speculation_length=SPEC_K, **kw)
+
+
+def _turnover(sess, n=8):
+    """Two requests at once and a third into the first slot that frees."""
+    assert sess.add_request("r1", SPEC_PROMPTS["r1"], max_new_tokens=n)
+    assert sess.add_request("r2", SPEC_PROMPTS["r2"], max_new_tokens=n)
+    while sess.active:
+        sess.step()
+        if "r3" not in sess.requests and sess.free_slots:
+            assert sess.add_request("r3", SPEC_PROMPTS["r3"], max_new_tokens=n)
+    return {rid: list(r.generated) for rid, r in sess.requests.items()}
+
+
+@pytest.mark.parametrize("quality", ["same_weights", "wrong_weights"])
+def test_speculative_turnover_matches_plain(spec_apps, quality):
+    """Greedy verification emits the plain session's streams whatever the
+    draft proposes, with a request joining mid-stream."""
+    plain = spec_apps[0]
+    golden = {rid: _plain_golden(plain, p, 8) for rid, p in SPEC_PROMPTS.items()}
+    assert _turnover(_spec_session(spec_apps, quality)) == golden
+
+
+@pytest.mark.parametrize("quality", ["same_weights", "wrong_weights"])
+def test_speculative_round_stops_at_eos_inside_the_window(spec_apps, quality):
+    """An EOS inside a round's accepted window ends the stream THERE: the
+    tokens the round accepted after it are dropped and the slot is freed."""
+    golden = _plain_golden(spec_apps[0], [5, 6, 7], 8)
+    eos = golden[2]  # the first round's window is golden[1:5] under a draft that agrees
+    assert eos not in golden[:2]
+    sess = _spec_session(spec_apps, quality)
+    assert sess.add_request("e", [5, 6, 7], max_new_tokens=8, eos_token_id=eos)
+    assert sess.run_to_completion()["e"] == golden[:3]
+    assert len(sess.free_slots) == sess.num_slots
+
+
+@pytest.mark.parametrize("quality", ["same_weights", "wrong_weights"])
+def test_speculative_slot_reuse_after_finish(spec_apps, quality):
+    """A request into a freed slot (the draft's cache line reused with the
+    target's, stale candidates of the last holder in both) decodes as alone."""
+    golden = _plain_golden(spec_apps[0], [42, 10, 11], 6)
+    sess = _spec_session(spec_apps, quality)
+    for i in range(sess.num_slots):
+        assert sess.add_request(f"w{i}", [1 + i, 2, 3, 4, 5, 6, 7], max_new_tokens=9)
+    sess.run_to_completion()
+    assert len(sess.free_slots) == sess.num_slots
+    assert sess.add_request("probe", [42, 10, 11], max_new_tokens=6)
+    assert sess.run_to_completion()["probe"] == golden
+
+
+def _counted_turnover(spec_apps, quality):
+    from neuronx_distributed_inference_tpu.telemetry import TelemetrySession
+
+    with TelemetrySession() as tel:
+        sess = _spec_session(spec_apps, quality, telemetry=tel)
+        out = _turnover(sess)
+    return sess, out, tel.registry.snapshot()
+
+
+@pytest.mark.parametrize("quality", ["same_weights", "wrong_weights"])
+def test_speculative_acceptance_histograms_sum_to_what_was_delivered(spec_apps, quality):
+    """The acceptance-length histogram sums to the decode tokens delivered
+    (a request's first token is its prefill's), the draft-length histogram
+    to the tokens drafted, one EWMA observation a round."""
+    _, out, snap = _counted_turnover(spec_apps, quality)
+    accepted = snap["nxdi_spec_accept_len"]["samples"][0]
+    assert accepted["sum"] == sum(len(v) for v in out.values()) - len(out)
+    drafted = snap["nxdi_spec_draft_len"]["samples"][0]
+    assert drafted["sum"] == drafted["count"] * (SPEC_K - 1) > 0
+    assert snap["nxdi_spec_accept_ewma"]["samples"][0]["count"] == drafted["count"]
+
+
+def test_speculative_acceptance_ewma_tells_the_drafts_apart(spec_apps):
+    """The session's acceptance EWMA (the router's placement signal) and the
+    tokens a round delivers separate a draft that agrees from one that does
+    not, on the same requests with the same streams."""
+    same, out_same, snap_same = _counted_turnover(spec_apps, "same_weights")
+    wrong, out_wrong, snap_wrong = _counted_turnover(spec_apps, "wrong_weights")
+    assert out_same == out_wrong
+    assert same.acceptance_ewma > wrong.acceptance_ewma + 0.2
+    rounds = lambda snap: snap["nxdi_spec_accept_len"]["samples"][0]["count"]
+    assert rounds(snap_same) < rounds(snap_wrong)  # the same tokens in fewer rounds
+
+
 @pytest.mark.slow
 def test_gpt_oss_class_serving_session():
     """ServingSession end-to-end on a GPT-OSS-class model (interleaved

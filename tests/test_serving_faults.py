@@ -5,7 +5,9 @@ classes, driven deterministically.
 
 The headline pins:
 - an injected NaN row fails ONLY that row: co-batched rows' outputs stay
-  byte-identical to a clean run on the legacy split AND the ragged paths
+  byte-identical to a clean run on the legacy split AND the ragged paths,
+  and over every kind of cache the cells serve (a per-slot state, a one-token
+  carry under an expert layer, a latent pool, a cache of two lifetimes)
   (the ROADMAP-named garbage-block coupling bug, fixed by the non-finite
   token sentinel + the block-0 read scrub + quarantine scrub-on-release);
 - injected dispatch faults retry with bounded backoff, then fail only the
@@ -16,8 +18,12 @@ The headline pins:
   AHEAD of new arrivals and resume byte-identically.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
+
+import jax
 
 from tests.conftest import make_tiny_config, make_random_hf_state_dict
 
@@ -73,9 +79,9 @@ def _paged_cfg(ragged=False, **extra):
 
 @pytest.fixture(scope="module")
 def paged_apps():
-    """(legacy split, ragged) — serving_ragged_async defaults to async_mode
-    (True), so the ragged app here exercises the PIPELINED dispatch: every
-    parametrized containment pin below covers the async ragged path."""
+    """(legacy split, ragged) — the ragged step pipelines when async_mode
+    does (the default), so the ragged app here exercises the PIPELINED
+    dispatch: every parametrized containment pin below covers it."""
     sd = make_random_hf_state_dict(_paged_cfg(False))
     legacy = TpuModelForCausalLM(None, _paged_cfg(False)).load(state_dict=sd)
     ragged = TpuModelForCausalLM(None, _paged_cfg(True)).load(state_dict=sd)
@@ -84,40 +90,70 @@ def paged_apps():
 
 @pytest.fixture(scope="module")
 def sync_ragged_app(paged_apps):
-    """Synchronous-ragged twin of paged_apps[1] (serving_ragged_async=False),
-    sharing the same weights — the sync/async fault-parity reference."""
-    cfg = _paged_cfg(True, serving_ragged_async=False)
+    """Synchronous-ragged twin of paged_apps[1] (async_mode=False), sharing
+    the same weights — the sync/async fault-parity reference."""
+    cfg = _paged_cfg(True, async_mode=False)
     sd = make_random_hf_state_dict(_paged_cfg(False))
     return TpuModelForCausalLM(None, cfg).load(state_dict=sd)
 
 
+def _reference_app(mode):
+    """The tiny preset a reference test serves, as that test builds it: the
+    cache kinds of the benchmark's cells that are no plain GQA pool."""
+    from tests import test_deepseek_reference, test_granite_hybrid
+    from tests import test_mellum_reference, test_zaya_reference
+
+    if mode == "ssm_state":  # state-space layers: a recurrent state a slot
+        cfg = test_granite_hybrid.make_config()
+    elif mode == "experts_carry":  # an expert layer over a one-token carry
+        cfg = test_zaya_reference.make_config()
+    elif mode == "latent_pool":  # MLA: one latent a token, expert layers
+        return test_deepseek_reference.make_app()
+    else:  # window and full layers: a ring a slot beside the pool
+        return test_mellum_reference.make_app()
+    return TpuModelForCausalLM(None, cfg).load(random_weights=True)
+
+
+#: the containment pins' modes: the split step pipelined and not, the ragged
+#: step likewise, and the split step over each cache kind the cells serve
+CONTAINMENT_MODES = [
+    "legacy", "legacy_sync", "ragged", "ragged_sync",
+    "ssm_state", "experts_carry", "latent_pool", "two_lifetimes",
+]
+
+
 @pytest.fixture(scope="module")
-def spec_ragged_bundle(paged_apps):
-    """(target, draft) for the SPEC-RAGGED path (ISSUE 12): verification
-    packed into the mixed dispatch, SAME target weights as the other paged
-    apps (byte-identity pins compare against the same golden streams), a
-    wrong-weights draft so rejections exercise the accept/rollback path."""
-    sd = make_random_hf_state_dict(_paged_cfg(False))
-    target = TpuModelForCausalLM(
-        None,
-        _paged_cfg(True, serving_spec_ragged=True, speculation_length=4),
-    ).load(state_dict=sd)
-    draft_cfg = make_tiny_config(tpu=dict(
-        is_continuous_batching=True, batch_size=4, ctx_batch_size=1, seq_len=64,
-    ))
-    draft = TpuModelForCausalLM(None, draft_cfg).load(
-        state_dict=make_random_hf_state_dict(draft_cfg, seed=7)
-    )
-    return target, draft
+def app_of(paged_apps, sync_ragged_app):
+    """mode -> application, each built once and only when a case asks."""
+    built = {"legacy": paged_apps[0], "ragged": paged_apps[1],
+             "ragged_sync": sync_ragged_app}
+
+    def get(mode):
+        if mode not in built:
+            if mode == "legacy_sync":
+                built[mode] = TpuModelForCausalLM(
+                    None, _paged_cfg(False, async_mode=False)
+                ).load(state_dict=make_random_hf_state_dict(_paged_cfg(False)))
+            else:
+                built[mode] = _reference_app(mode)
+        return built[mode]
+
+    return get
 
 
-def _paged_app(paged_apps, sync_ragged_app, mode, spec_ragged_bundle=None):
-    return {
-        "legacy": paged_apps[0],
-        "ragged": paged_apps[1],
-        "ragged_sync": sync_ragged_app,
-        "spec_ragged": spec_ragged_bundle,
-    }[mode]
+def _nan_outside_garbage(cache) -> bool:
+    """Whether a NaN survives where a live row could read it: any stream of
+    blocks outside the shared garbage block 0 (which the read path scrubs on
+    every gather), or any per-slot state kept beside the pool."""
+    slot_fields = getattr(cache, "SLOT_FIELDS", ())
+    for f in dataclasses.fields(cache):
+        for leaf in jax.tree_util.tree_leaves(getattr(cache, f.name)):
+            a = np.asarray(leaf)
+            if np.issubdtype(a.dtype, np.floating) and np.isnan(
+                a if f.name in slot_fields else a[:, 1:]
+            ).any():
+                return True
+    return False
 
 
 @pytest.fixture(scope="module")
@@ -155,26 +191,13 @@ def _drive(sess, max_steps=300):
 
 
 def _fresh_session(app, **kw):
-    """A fresh session over freshly-initialized caches. ``app`` may be a
-    (target, draft) tuple — then the session is the SPEC-RAGGED
-    SpeculativeServingSession (ISSUE 12)."""
-    if isinstance(app, tuple):
-        target, draft = app
-        target.init_kv_cache()
-        draft.init_kv_cache()
-        return SpeculativeServingSession(
-            target, draft, speculation_length=4, **kw
-        )
+    """A fresh session over a freshly-initialized cache."""
     app.init_kv_cache()
     return ServingSession(app, **kw)
 
 
 def _mix(app, injector=None, telemetry=None, n_tokens=6):
-    """The standard 3-request mix, per-step driven, fresh cache. ``app``
-    may be a (target, draft) tuple — then the mix runs through the
-    SPEC-RAGGED SpeculativeServingSession (ISSUE 12) instead of a plain
-    session: every containment pin below applies verbatim to the packed
-    spec-verify path."""
+    """The standard 3-request mix, per-step driven, fresh cache."""
     sess = _fresh_session(app, telemetry=telemetry, fault_injector=injector)
     for rid, prompt in PROMPTS.items():
         assert sess.add_request(rid, prompt, max_new_tokens=n_tokens)
@@ -252,19 +275,15 @@ def test_admission_validation_off_restores_legacy(plain_app):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize(
-    "mode", ["legacy", "ragged", "ragged_sync", "spec_ragged"]
-)
-def test_nan_row_quarantined_cobatch_byte_identical(
-    paged_apps, sync_ragged_app, spec_ragged_bundle, mode
-):
+@pytest.mark.parametrize("mode", CONTAINMENT_MODES)
+def test_nan_row_quarantined_cobatch_byte_identical(app_of, mode):
     """A NaN-poisoned row (device KV NaN -> non-finite logits -> sentinel
     token) fails ONLY that row: healthy co-batched rows are byte-identical
-    to a clean run on the legacy split, the ragged, AND the spec-ragged
-    (poisoned VERIFY row) dispatch paths, the poisoned blocks are scrubbed
+    to a clean run on the legacy split and the ragged dispatch paths and
+    over every cache kind, the poisoned blocks (and the slot's state) are scrubbed
     before the pool recycles them, and a new request reusing the freed
     capacity decodes byte-identically."""
-    app = _paged_app(paged_apps, sync_ragged_app, mode, spec_ragged_bundle)
+    app = app_of(mode)
     _, golden = _mix(app)
 
     inj = FaultInjector(seed=0).poison_kv_row(step=4, slot=1)  # r2's slot
@@ -284,8 +303,7 @@ def test_nan_row_quarantined_cobatch_byte_identical(
     assert len(sess.allocator.free) == sess.allocator.num_blocks
     # ...and scrubbed them: no NaN survives anywhere outside the shared
     # garbage block 0 (which the read path scrubs on every gather)
-    k = np.asarray(sess.app.kv_cache.k)
-    assert not np.isnan(k[:, 1:]).any()
+    assert not _nan_outside_garbage(sess.app.kv_cache)
     tel.close()
     snap = tel.registry.snapshot()
     assert snap["nxdi_rows_quarantined_total"]["samples"][0]["value"] == 1
@@ -306,17 +324,13 @@ def test_nan_row_quarantined_cobatch_byte_identical(
     assert out2["r4"] == golden_probe
 
 
-@pytest.mark.parametrize(
-    "mode", ["legacy", "ragged", "ragged_sync", "spec_ragged"]
-)
-def test_poisoned_garbage_block_cannot_couple_rows(
-    paged_apps, sync_ragged_app, spec_ragged_bundle, mode
-):
+@pytest.mark.parametrize("mode", CONTAINMENT_MODES)
+def test_poisoned_garbage_block_cannot_couple_rows(app_of, mode):
     """NaN written straight into SHARED garbage block 0 (the
     post-propagation state of the legacy drain's surplus lockstep writes)
     changes NO healthy row by a byte: masked reads of the garbage block are
     scrubbed to exact zeros in the gather (0*NaN=NaN is dead)."""
-    app = _paged_app(paged_apps, sync_ragged_app, mode, spec_ragged_bundle)
+    app = app_of(mode)
     _, golden = _mix(app)
     inj = FaultInjector().poison_garbage_block(step=2)
     _, out = _mix(app, injector=inj)
@@ -349,17 +363,12 @@ def test_nan_tokens_host_boundary_quarantine(paged_apps):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize(
-    "mode", ["legacy", "ragged", "ragged_sync", "spec_ragged"]
-)
-def test_injected_pool_exhaustion_resumes_byte_identical(
-    paged_apps, sync_ragged_app, spec_ragged_bundle, mode
-):
+@pytest.mark.parametrize("mode", CONTAINMENT_MODES)
+def test_injected_pool_exhaustion_resumes_byte_identical(app_of, mode):
     """exhaust_pool evicts every allocating row for one step; evictions
     re-queue, re-admit, and the final streams are byte-identical to a
-    fault-free run (rollback + greedy re-prefill regenerates exactly —
-    on the spec-ragged path the victim's DRAFT cache re-prefills too)."""
-    app = _paged_app(paged_apps, sync_ragged_app, mode, spec_ragged_bundle)
+    fault-free run (rollback + greedy re-prefill regenerates exactly)."""
+    app = app_of(mode)
     _, golden = _mix(app)
     inj = FaultInjector().exhaust_pool(3)
     tel = TelemetrySession()
@@ -1058,80 +1067,6 @@ def test_async_ragged_deadline_expiry_mid_pipeline(paged_apps):
     for _ in range(4):
         sess.step()  # r1's next step is dispatched and UNCONSUMED here
     clock.t += 5.0  # r1 expires with a pending in-flight step
-    out = _drive(sess)
-    r1 = sess.requests["r1"]
-    assert r1.status == "failed" and r1.fail_reason == "deadline_exceeded"
-    assert out["r1"] == golden["r1"][: len(out["r1"])]
-    assert len(out["r1"]) < 8
-    assert out["r2"] == golden["r2"]
-    assert out["r3"] == golden["r3"]
-    assert len(sess.free_slots) == sess.num_slots
-
-
-# ---------------------------------------------------------------------------
-# spec-ragged path (ISSUE 12): retry + deadline containment on the packed
-# verify pipeline (NaN-quarantine / garbage-block / pool-exhaustion pins run
-# through the `spec_ragged` parametrization of the shared tests above)
-# ---------------------------------------------------------------------------
-
-
-def test_spec_ragged_dispatch_retry_recovers_byte_identical(spec_ragged_bundle):
-    """A transient dispatch fault inside the spec pipeline (whichever of
-    draft-chain / packed-verify / draft-CTE dispatches first at that step)
-    retries with bounded backoff and the drained streams stay
-    byte-identical to a fault-free run."""
-    _, golden = _mix(spec_ragged_bundle)
-    inj = FaultInjector().dispatch_error(step=4, attempts=1)
-    sess, out = _mix(spec_ragged_bundle, injector=inj)
-    assert any(f["kind"] == "dispatch_error" for f in inj.log)
-    assert out == golden
-
-
-def test_spec_ragged_retry_exhaustion_fails_rows_not_session(
-    spec_ragged_bundle,
-):
-    """Past the retry budget only the in-flight rows of the failing
-    dispatch terminally FAIL (a failing DRAFT-chain dispatch fails nobody —
-    speculation just skips a round); the session keeps serving and every
-    surviving request's stream is byte-identical to the clean run."""
-    _, golden = _mix(spec_ragged_bundle)
-    inj = FaultInjector().dispatch_error(step=5, attempts=10)  # > retries
-    sess, out = _mix(spec_ragged_bundle, injector=inj)
-    assert any(f["kind"] == "dispatch_error" for f in inj.log)
-    assert len(sess.free_slots) == sess.num_slots  # nothing leaked
-    for rid, r in sess.requests.items():
-        assert r.status in ("finished", "failed"), (rid, r.status)
-        if r.status == "failed":
-            assert r.fail_reason == "dispatch_error"
-        if r.status == "finished":
-            assert out[rid] == golden[rid], rid
-        else:
-            # failed rows keep their committed clean-run prefix
-            assert out[rid] == golden[rid][: len(out[rid])], rid
-    # the session is still alive: a fresh request completes
-    probe = [42, 10, 11]
-    iso = _fresh_session(spec_ragged_bundle)
-    assert iso.add_request("iso", probe, max_new_tokens=4)
-    golden_probe = _drive(iso)["iso"]
-    assert sess.add_request("after", probe, max_new_tokens=4)
-    assert _drive(sess)["after"] == golden_probe
-
-
-def test_spec_ragged_deadline_exceeded(spec_ragged_bundle):
-    """A wall-clock deadline expiring mid-speculation terminally fails only
-    that request (its in-flight verify/draft work is discarded); requests
-    without deadlines keep their full clean-run streams."""
-    _, golden = _mix(spec_ragged_bundle, n_tokens=8)
-    clock = FakeClock()
-    inj = FaultInjector().latency(step=4, seconds=10.0)
-    sess = _fresh_session(
-        spec_ragged_bundle, fault_injector=inj,
-        clock=clock, sleep_fn=clock.sleep,
-    )
-    assert sess.add_request("r1", PROMPTS["r1"], max_new_tokens=8,
-                            deadline_s=5.0)
-    assert sess.add_request("r2", PROMPTS["r2"], max_new_tokens=8)
-    assert sess.add_request("r3", PROMPTS["r3"], max_new_tokens=8)
     out = _drive(sess)
     r1 = sess.requests["r1"]
     assert r1.status == "failed" and r1.fail_reason == "deadline_exceeded"

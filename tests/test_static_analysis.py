@@ -374,7 +374,6 @@ def test_graph_audit_clean_and_covers_tags():
         "token_generation_kvq8",
         "fused_speculation_kvq8",
         "mixed_step",
-        "mixed_step_spec",
         "token_generation_w4",
         "mixed_step_w4",
     }
@@ -743,7 +742,6 @@ def test_shard_audit_clean_and_covers_committed_tags():
         "token_generation_kvq8",
         "fused_speculation_kvq8",
         "mixed_step",
-        "mixed_step_spec",
         "token_generation_w4",
         "mixed_step_w4",
     }
@@ -951,7 +949,6 @@ def test_memory_audit_clean_and_covers_cache_variants():
         "token_generation_kvq8",
         "fused_speculation_kvq8",
         "mixed_step",
-        "mixed_step_spec",
         "token_generation_ring",
         "token_generation_paged",
         "token_generation_w4",
