@@ -445,7 +445,9 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         # at head_dim 64 the blocks are windows and the scratch is statistics
         step_copy_bytes=lambda inst: inst.scratch_bytes // 2 if len(inst.grid) == 1 else 0,
         cases=(
-            # head_dim 64 (Llama-3.2-1B, granite): one block a grid step
+            # a pool row of 64 lanes: one block a grid step (a quantised pool
+            # at head_dim 64, an odd head count; a bfloat16 pool of an even
+            # head count holds two heads a row and is a blk<H/2>x<bs>x128)
             KernelCase(
                 "blk8x128x64", "bfloat16", _paged_tkg_case(8, 8, 128, "bfloat16")
             ),
@@ -493,7 +495,9 @@ REGISTRY: Tuple[KernelSpec, ...] = (
             sum(b for _, _, b in inst.scratch[:2]) // 2 if len(inst.grid) == 1 else 0
         ),
         cases=(
-            # head_dim 64 (Llama-3.2-1B, granite): one block a grid step
+            # a pool row of 64 lanes: one block a grid step (a quantised pool
+            # at head_dim 64, an odd head count; a bfloat16 pool of an even
+            # head count holds two heads a row and is a blk<H/2>x<bs>x128)
             KernelCase(
                 "blk8x128x64", "bfloat16", _paged_flash_case(1, 512, 16, 128, "bfloat16")
             ),

@@ -519,10 +519,13 @@ def paged_write_attend(
     from neuronx_distributed_inference_tpu.parallel.sharding import head_shard_degree
 
     slot_mapping, block_table, kv_limit = block_inputs
-    Sq, D = q.shape[1], q.shape[-1]
-    bs = k_cache.shape[3]
+    Sq = q.shape[1]
+    # asked with the POOL ROW's width and head count: a pool of head_dim 64
+    # holds two heads a 128-lane row (block_kvcache.kv_streams) and is, to
+    # every writer, a head_dim-128 pool of half the heads
+    _, _, pool_heads, bs, pool_width = k_cache.shape
     form = write_form(
-        Sq, D, k_cache.shape[2] // head_shard_degree(),
+        Sq, pool_width, pool_heads // head_shard_degree(),
         quantised=isinstance(k_cache, QuantizedKV), batch_sharded=batch_is_sharded(),
         kernel_runs=decode_kernel_runs(
             spec, Sq, mask.shape[-1], block_table.shape[1] * bs, k_cache.shape, v_cache.shape
@@ -676,7 +679,7 @@ def paged_attend(
 
                 q = adp.shard_decode_q(q)
             k_r, v_r = read_block_cache_at_layer(
-                k_cache, v_cache, layer_idx, block_table
+                k_cache, v_cache, layer_idx, block_table, head_dim=q.shape[-1]
             )
             attn_out = attention_decode(q, k_r, v_r, mask, aspec, sink=sink)
             if dp_shards > 1:

@@ -508,7 +508,10 @@ class DecoderModelBuilder:
         bytes from it."""
         from neuronx_distributed_inference_tpu.modules.block_kvcache import kv_streams
 
-        return kv_streams(self.gqa.kv_heads, self.head_dim)
+        return kv_streams(
+            self.gqa.kv_heads, self.head_dim, shards=self.gqa.degree,
+            quantised=self.config.tpu_config.kv_quantized,
+        )
 
     def init_slot_state(self, num_slots: int):
         """(state pytree, its PartitionSpec tree) of the ``SLOT_STATE``

@@ -10,7 +10,11 @@ Layout here is HEAD-MAJOR ``(L, num_blocks+1, H_kv, block_size, d)`` — unlike
 the reference's token-major blocks — so a Pallas kernel can DMA one head's
 block as a ``(block_size, d)`` tile whose last-two block dims equal the array
 dims (Mosaic's (8, 128) divisibility rule would reject a ``(1, d)`` slice over
-a token-major ``(block_size, H_kv, d)`` block for H_kv > 1).
+a token-major ``(block_size, H_kv, d)`` block for H_kv > 1). Where ``d`` divides
+the chip's 128 lanes (64: granite, Llama-3.2-1B) ``g = 128 // d`` KV heads lie
+SIDE BY SIDE in one row, ``(L, num_blocks+1, H_kv / g, block_size, 128)``
+(:func:`kv_streams`, the one decision): the pool is then a head_dim-128 pool
+to every writer and kernel, and the chip's own layout of it is row-major.
 
 Device side (pure functions used inside the jitted step):
 - writes place token K/V through a flat ``slot_mapping`` (block *
@@ -69,8 +73,11 @@ GARBAGE_BLOCK = 0  # block id 0 reserved for invalid-slot writes
 #: fewest heads a device must hold for the paged KV write to take its TOKEN
 #: WINDOW ``(H, D)``: the chip's tile is (8, 128), and under a window of
 #: fewer heads than sublanes the TPU compiler re-lays the whole pool around
-#: every layer's scatter (update_block_cache_at_layer). Reached at a
-#: head_dim off the lanes and on the ragged step's packed axis
+#: every layer's scatter (update_block_cache_at_layer). Reached on the ragged
+#: step's packed axis and by a chunk pass over a pool row off the lanes: a
+#: head_dim that fills no 128-lane row with whole heads (72, 80, 96), an odd
+#: head count a device, a quantised pool at head_dim 64 (:func:`heads_a_row`
+#: folds every other head_dim under 128 onto the lanes since PR 65)
 WINDOW_MIN_HEADS = 8
 
 
@@ -256,7 +263,13 @@ class CacheStream:
     them in device memory, and no kernel could copy its blocks by hand. Token
     ``o`` of a block lies in row ``o % (block_size // pack)`` at lanes
     ``[o // (block_size // pack) * width, ...)``: a block's first
-    ``block_size // pack`` tokens fill the first lane group, row by row."""
+    ``block_size // pack`` tokens fill the first lane group, row by row.
+    ``pack`` > 1 is reached by the one-head rotary-key stream of the latent
+    pools only (models/deepseek.py and what builds on it). A K/V stream of
+    several narrow heads fills its rows the other way, HEADS side by side
+    (:func:`kv_streams`: ``heads`` and ``width`` are then the pool row's,
+    ``H_kv / g`` and ``g x D``, at ``pack`` 1), so that the head_dim-128
+    kernels and writes apply as they are."""
 
     heads: int
     width: int
@@ -274,9 +287,84 @@ class CacheStream:
         )
 
 
-def kv_streams(num_kv_heads: int, head_dim: int) -> Tuple[CacheStream, CacheStream]:
-    """The two streams of a layer that pages K and V at ``(H_kv, D)``."""
-    return (CacheStream(num_kv_heads, head_dim),) * 2
+#: the chip's lanes: the width of a pool row the paged kernels copy by hand
+LANES = 128
+
+
+def heads_a_row(num_kv_heads: int, head_dim: int, *, shards: int = 1, quantised: bool = False) -> int:
+    """KV heads that lie SIDE BY SIDE in one pool row (``g``), from shapes
+    alone: ``128 // head_dim`` where whole heads fill the 128 lanes exactly
+    (head_dim 64: two; 32: four), the heads ONE device holds (``num_kv_heads
+    / shards``) are a multiple of it (no group straddles a head shard) and
+    the pool is not ``quantised`` (a :class:`~.kvcache.QuantizedKV` scale is a
+    (layer, head)); else 1, the pool every other shape always had (head_dim
+    128: the identity; 72, 80, 96; an odd head count). THE decision of the
+    fold: :func:`kv_streams` declares the pool by it and everything that
+    meets a pool reads ``g`` back off the pool's row against the model's
+    head_dim (:func:`pool_fold`)."""
+    if quantised or head_dim >= LANES or LANES % head_dim:
+        return 1
+    g = LANES // head_dim
+    return g if num_kv_heads % (g * max(shards, 1)) == 0 else 1
+
+
+def kv_streams(
+    num_kv_heads: int, head_dim: int, *, shards: Optional[int] = None, quantised: bool = False
+) -> Tuple[CacheStream, CacheStream]:
+    """The two streams of a layer that pages K and V at ``(H_kv, D)``. At a
+    head_dim that divides the 128 lanes a stream holds ``g`` KV heads side by
+    side in one row (:func:`heads_a_row`): ``H_kv / g`` "heads" of ``g x D``
+    lanes, row ``t`` of group ``j`` holding ``[k_{gj}(t) | ... | k_{gj+g-1}(t)]``.
+    A token's new K ``(H_kv, D)`` is, row-major, already ``(H_kv / g, g x D)``,
+    so to every writer the pool IS a head_dim-128 pool of ``H_kv / g`` heads,
+    and the chip's own layout of it is the row-major one the paged kernels
+    ask for (at ``(.., bs, 64)`` it is not: four copies of the pool a step
+    program, PERF.md PR 65). The kernels attend it with the queries laid in
+    their head's lanes (:func:`fold_queries`). ``shards``: the ways the head
+    axis is split (the ambient mesh's where not given)."""
+    if shards is None:
+        shards = head_shard_degree()
+    g = heads_a_row(num_kv_heads, head_dim, shards=shards, quantised=quantised)
+    return (CacheStream(num_kv_heads // g, head_dim * g),) * 2
+
+
+def pool_fold(pool_width: int, head_dim: int) -> int:
+    """``g`` of a pool whose rows are ``pool_width`` lanes wide under a model
+    of ``head_dim``: what :func:`kv_streams` folded (1: nothing)."""
+    if pool_width % head_dim:
+        raise ValueError(f"a pool row of {pool_width} lanes holds no whole heads of {head_dim}")
+    return pool_width // head_dim
+
+
+def fold_queries(q: jax.Array, g: int, n_rep: int) -> jax.Array:
+    """``q (..., Hq, D)`` -> ``(..., Hq, g x D)`` for a pool of ``g`` KV heads
+    a row: the ``n_rep`` query heads of KV head ``g j + i`` keep their
+    numbers in lane group ``i`` and zeros in the others, so ``q' . row`` is
+    ``q . k`` of their own head exactly (the other heads' lanes multiply
+    zeros) and ``p @ [v_{gj} | ...]`` holds their head's output in lane group
+    ``i`` (:func:`unfold_outputs`). The kernels then see a GQA model of
+    ``H_kv / g`` KV heads at ``g x D`` with ``g x n_rep`` query heads each,
+    in the order they always had."""
+    if g == 1:
+        return q
+    *lead, Hq, D = q.shape
+    x = q.reshape(*lead, Hq // (g * n_rep), g, n_rep, D)
+    none = [(0, 0)] * (x.ndim - 2)
+    parts = [
+        jnp.pad(x[..., i, :, :], none + [(i * D, (g - 1 - i) * D)]) for i in range(g)
+    ]
+    return jnp.stack(parts, axis=-3).reshape(*lead, Hq, g * D)
+
+
+def unfold_outputs(out: jax.Array, g: int, n_rep: int) -> jax.Array:
+    """``(..., Hq, g x D)`` as attended with :func:`fold_queries` ->
+    ``(..., Hq, D)``: each query head's own lane group."""
+    if g == 1:
+        return out
+    *lead, Hq, W = out.shape
+    D = W // g
+    x = out.reshape(*lead, Hq // (g * n_rep), g, n_rep, g, D)
+    return jnp.stack([x[..., i, :, i, :] for i in range(g)], axis=-3).reshape(*lead, Hq, D)
 
 
 def init_block_cache(
@@ -293,7 +381,7 @@ def init_block_cache(
     latent in ``k``, the rotary key, packed, in ``v``; any further stream in
     ``extra``). A quantised pool keeps a float32 scale a (layer, head) beside
     each stream's codes."""
-    streams = streams or kv_streams(num_kv_heads, head_dim)
+    streams = streams or kv_streams(num_kv_heads, head_dim, quantised=is_kv_quant_dtype(dtype))
 
     def stream(s: CacheStream):
         data = jnp.zeros(s.pool_shape(num_layers, num_blocks, block_size), dtype)
@@ -461,6 +549,13 @@ def _lay_on_blocks(new, first_off, nseg: int, bs: int):
     return laid.reshape(B, nseg, bs, h, D)
 
 
+def pool_rows(new, data):
+    """A pass's ``new (..., h, D)`` as the pool ``data (L, NB+1, h / g, bs,
+    g x D)`` holds a token: the same numbers row-major (:func:`kv_streams`),
+    ``g`` heads a row; the identity at ``g`` = 1."""
+    return new.reshape(*new.shape[:-2], data.shape[2], data.shape[4])
+
+
 def _write_blocks(data, new, layer_idx, blocks, first_off, covered):
     """The block form: ``new (B, S, h, D)`` laid on the rows' block grid
     ``(B, nseg, h, bs, D)`` (a row's tokens start ``first_off`` into its first
@@ -468,7 +563,8 @@ def _write_blocks(data, new, layer_idx, blocks, first_off, covered):
     cover, and scattered with the whole block ``(h, bs, D)`` in the window:
     the pool's minor-most dims, so the carry stays row-major. Under a
     head-sharded mesh ``data`` and ``new`` are one shard's heads."""
-    laid = _lay_on_blocks(new, first_off, *covered.shape[1:]).transpose(0, 1, 3, 2, 4)
+    laid = _lay_on_blocks(pool_rows(new, data), first_off, *covered.shape[1:])
+    laid = laid.transpose(0, 1, 3, 2, 4)
     held = data[layer_idx, blocks]
     merged = jnp.where(covered[:, :, None, :, None], laid, held)
     return data.at[layer_idx, blocks].set(merged, mode="drop")
@@ -479,7 +575,7 @@ def _scatter_per_head(data, rows, layer_idx, blocks, offs):
     mesh ``data`` and ``rows (B * S, h, D)`` are one shard's heads."""
     heads = jnp.arange(data.shape[2])[None, :]
     return data.at[layer_idx, blocks[:, None], heads, offs[:, None]].set(
-        rows, mode="drop"
+        pool_rows(rows, data), mode="drop"
     )
 
 
@@ -501,7 +597,10 @@ def update_block_cache_at_layer(
     it) — same net effect as the reference's garbage-block writes.
 
     Four forms write the same bytes; three are written here, and the first
-    by the pass's attention kernel (:func:`write_form` is the one decision;
+    by the pass's attention kernel (:func:`write_form` is the one decision,
+    asked with the POOL ROW's width ``D`` and head count ``H``: a pool of
+    ``g`` heads a row takes ``k_new (B, S, H_kv, D_model)`` as the ``(H, D)``
+    rows it is, row-major;
     models/base.paged_write_attend asks it and calls this function for
     every form but ``kernel``). What decides between them: the TPU
     compiler lays a scatter's operand out with the update WINDOW's dims
@@ -548,15 +647,17 @@ def update_block_cache_at_layer(
     * PER HEAD (window ``(D,)``, the head an indexed dim: minor-most
       already, H times the index rows) at ``S <= TKG_MAX_Q_LEN`` where the
       kernel form does not apply (a block step and every speculation width,
-      ``S`` of 2-16; a head_dim off the lanes; a quantised pool, whose scale
+      ``S`` of 2-16; a pool row off the lanes; a quantised pool, whose scale
       update is fused into this write; a run with the kernels off; 48 rows
       x 1: 2.3 ms a dispatch on the 1.7B, where the block form moves 64 KB
       to place 2 KB and reads 2.9), and wherever a device holds fewer than
       ``WINDOW_MIN_HEADS`` heads.
     * TOKEN WINDOW (window ``(H, D)``, one index row a token, the carry
-      token-major) at a ``D`` off the lanes (64: Llama-3.2-1B, granite: the
-      chip's own layout of that pool is not row-major in any form, and the
-      chunk's kernel takes a layer's slice), for the ragged mixed step's
+      token-major) at a pool row off the lanes (a head_dim that fills no
+      128-lane row with whole heads, an odd head count, a quantised pool at
+      head_dim 64: the chip's own layout of that pool is not row-major in
+      any form, and the chunk's kernel takes a layer's slice; granite and
+      Llama-3.2-1B left it in PR 65, their pool two heads a row), for the ragged mixed step's
       ``packed`` token axis (its kernel takes a layer's slice too; one form
       at every packed width keeps its bucket programs one structure:
       analysis/graph_audit GRAPH205), and wherever the BATCH is sharded
@@ -594,9 +695,11 @@ def update_block_cache_at_layer(
 
 
 def _stream_writer(pool_shape, slot_mapping: jax.Array, layer_idx, packed: bool = False):
-    """``write(data, new (B, S, H, D))`` of one pass into a pool stream of
-    ``pool_shape (L, NB+1, H, bs, D)``, in the form
-    :func:`update_block_cache_at_layer` says the call takes."""
+    """``write(data, new (B, S, H_kv, D_model))`` of one pass into a pool
+    stream of ``pool_shape (L, NB+1, H, bs, D)``, in the form
+    :func:`update_block_cache_at_layer` says the call takes: asked with the
+    POOL ROW's width and head count (``H_kv / g`` heads of ``g x D_model``
+    where :func:`kv_streams` folded ``g`` heads a row)."""
     L, NB1, H, bs, D = pool_shape
     B, S = slot_mapping.shape
     # asked to write, so the pass's attention kernel does not (kernel_runs False)
@@ -617,14 +720,14 @@ def _stream_writer(pool_shape, slot_mapping: jax.Array, layer_idx, packed: bool 
         offs = jnp.where(slots >= 0, slots % bs, 0)
 
         def write(data, new):
-            rows = new.reshape(B * S, H, D).astype(data.dtype)
+            rows = new.reshape(B * S, *new.shape[2:]).astype(data.dtype)
             if form == "per_head":
                 return shard_over_heads(
                     _scatter_per_head, (data, rows, layer_idx, blocks, offs),
                     in_heads=(2, 1, None, None, None), out_heads=2,
                 )
             # window (H, D): one index row per token, the carry token-major
-            return data.at[layer_idx, blocks, :, offs].set(rows, mode="drop")
+            return data.at[layer_idx, blocks, :, offs].set(pool_rows(rows, data), mode="drop")
 
     return write
 
@@ -754,16 +857,20 @@ def read_block_cache_at_layer(
     v_cache: jax.Array,
     layer_idx: jax.Array,
     block_table: jax.Array,  # (B, MB) block ids; 0 for unused tail entries
+    head_dim: Optional[int] = None,  # the MODEL's; None: the pool row's own
 ) -> Tuple[jax.Array, jax.Array]:
     """Gather one layer's active blocks into a contiguous per-sequence view
-    (reference gather-by-active-block-table reads). Quantized caches
-    dequantize AFTER the gather to fp32 — the native fallback path only;
-    the paged kernels DMA the codes straight from the cache instead."""
+    ``(B, MB * bs, H_kv, head_dim)`` (reference gather-by-active-block-table
+    reads). A pool that holds ``g`` heads a row (:func:`kv_streams`) is
+    unfolded here: row-major, a token's ``(H_kv / g, g x D)`` IS its
+    ``(H_kv, D)``. Quantized caches dequantize AFTER the gather to fp32 — the
+    native fallback path only; the paged kernels DMA the codes straight from
+    the cache instead."""
     if isinstance(k_cache, QuantizedKV):
         k_s = layer_dequant_factors(k_cache, layer_idx)
         v_s = layer_dequant_factors(v_cache, layer_idx)
         k_r, v_r = read_block_cache_at_layer(
-            k_cache.data, v_cache.data, layer_idx, block_table
+            k_cache.data, v_cache.data, layer_idx, block_table, head_dim
         )
         return (
             k_r.astype(jnp.float32) * k_s[:, None],
@@ -771,6 +878,8 @@ def read_block_cache_at_layer(
         )
     B, MB = block_table.shape
     _, _, H, bs, D = k_cache.shape
+    g = pool_fold(D, head_dim or D)
+    H, D = H * g, D // g
     k_l = jax.lax.dynamic_index_in_dim(k_cache, layer_idx, axis=0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(v_cache, layer_idx, axis=0, keepdims=False)
     k = k_l[block_table]  # (B, MB, H, bs, D)

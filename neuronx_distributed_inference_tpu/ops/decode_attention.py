@@ -36,10 +36,13 @@ stage ACROSS the heads, so that their chains of dependent operations overlap
 stored: a float32 operand goes to the matrix unit as three bfloat16 parts
 that sum to it exactly (``_split3``), every product exact in the float32
 accumulator, so nothing is rounded that the float32 form would keep. At a
-head_dim that is no multiple of the 128 lanes the chip's compiler refuses a
+pool row that is no multiple of the 128 lanes the chip's compiler refuses a
 hand copy of a block (a 64-lane slice of an HBM ref), and the launch keeps
 one block a grid step through a ``BlockSpec`` on the block table
-(``_paged_by_block``, the contiguous kernel's body).
+(``_paged_by_block``, the contiguous kernel's body). A head_dim that divides
+the lanes does not come here: its pool holds ``128 // head_dim`` heads a row
+(modules/block_kvcache.kv_streams), and ``dispatch_paged_tkg_decode`` hands
+this kernel the head_dim-128 problem it then is.
 
 The paged kernel is also the paged KV WRITE of a one-token decode pass
 (``new_kv``; modules/block_kvcache.write_form says when). A decode row's new
@@ -353,7 +356,7 @@ def pages_per_step(
     kernel walks a row by the same rule under its own name in the table
     (``kernel``)."""
     dt = jnp.dtype(cache_dtype)
-    if head_dim % 128:
+    if head_dim % 128:  # the POOL ROW's width: a pool of g heads a row is asked at g x D
         return 1  # blocks come through a BlockSpec, one a step: _paged_by_block
     block_bytes = n_kv * bs * head_dim * dt.itemsize
     p = 1
@@ -694,12 +697,15 @@ def _paged_group_kernel(
 
 
 def _paged_by_block(q, k_cache, v_cache, li, block_table, mask, sink, *, scale, n_kv, interpret):
-    """The launch at a head_dim that is no multiple of the 128 lanes: the
+    """The launch at a pool row that is no multiple of the 128 lanes: the
     chip's compiler refuses such a slice of an HBM ref, so the blocks cannot
     be copied by hand; they come one a grid step through a ``BlockSpec`` on
     the block table, ``(B, MB)`` steps, as the contiguous kernel's tiles do
-    (a dead step repeats the last block index and fetches nothing).
-    (B, K, Hq, D) -> (B, Hq*K, D)."""
+    (a dead step repeats the last block index and fetches nothing). Still
+    reached by a head_dim that fills no 128-lane row with whole heads (72,
+    80, 96), an odd KV head count a device, and a quantised pool at head_dim
+    64 (block_kvcache.heads_a_row keeps those a head a row); no benchmark
+    cell since PR 65. (B, K, Hq, D) -> (B, Hq*K, D)."""
     B, K, Hq, D = q.shape
     bs = k_cache.shape[3]
     MB = block_table.shape[1]
@@ -877,9 +883,12 @@ def paged_tkg_decode_attention(
     write-then-attend does, and returns ``(out, k_cache, v_cache)`` with the
     pools updated in place (``input_output_aliases``)."""
     B, K, Hq, D = q.shape
-    _, NB1, Hkv, bs, _ = k_cache.shape
+    _, NB1, Hkv, bs, width = k_cache.shape
     MB = block_table.shape[1]
     assert mask.shape[-1] == MB * bs, (mask.shape, MB, bs)
+    # a pool of several heads a row comes through dispatch_paged_tkg_decode,
+    # which lays the queries in their head's lanes
+    assert width == D, f"q of {D} lanes against pool rows of {width}"
     n_rep = Hq // n_kv
     out_dtype = q.dtype
     quantized = isinstance(k_cache, QuantizedKV)
@@ -959,14 +968,37 @@ def dispatch_paged_tkg_decode(
     Qwen3-14B's 8 kv heads for its own 10 of 40 q heads. With ``new_kv``
     (``k_new, v_new (B, 1, Hkv, D)``, ``slot_mapping``) each shard's kernel
     also places its own heads of the pass's one token a row, and the two
-    pools come back with the output, each shard's heads in place."""
+    pools come back with the output, each shard's heads in place.
+
+    A pool that holds ``g`` KV heads side by side in a 128-lane row
+    (block_kvcache.kv_streams: head_dim 64, two) is attended as the GQA model
+    it then is, ``H_kv / g`` KV heads at ``g x D``: the queries go in laid in
+    their own head's lanes, the token's new K and V as the pool row they are,
+    and each query head's lanes are cut from the output
+    (block_kvcache.fold_queries); the kernel is the head_dim-128 one."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        fold_queries,
+        pool_fold,
+        pool_rows,
+        unfold_outputs,
+    )
     from neuronx_distributed_inference_tpu.parallel.sharding import shard_over_heads
 
     def per_shard(q_s, k_s, v_s, li, bt, m, sink_s, new_s):
-        return paged_tkg_decode_attention(
-            q_s, k_s, v_s, li, bt, m, sink_s, new_s,
-            scale=scale, n_kv=k_s.shape[2], interpret=interpret,
+        pool = k_s.data if isinstance(k_s, QuantizedKV) else k_s
+        n_kv = pool.shape[2]
+        g = pool_fold(pool.shape[4], q_s.shape[-1])
+        n_rep = q_s.shape[2] // (n_kv * g)
+        if new_s is not None:
+            k_new, v_new, slots = new_s
+            new_s = (pool_rows(k_new, pool), pool_rows(v_new, pool), slots)
+        out = paged_tkg_decode_attention(
+            fold_queries(q_s, g, n_rep), k_s, v_s, li, bt, m, sink_s, new_s,
+            scale=scale, n_kv=n_kv, interpret=interpret,
         )
+        if new_s is None:
+            return unfold_outputs(out, g, n_rep)
+        return (unfold_outputs(out[0], g, n_rep),) + tuple(out[1:])
 
     heads = _cache_heads(k_cache, 2)
     return shard_over_heads(

@@ -47,11 +47,14 @@ and the mask of a group that crosses a window's edge takes one more compare.
 Without a window none of this is traced: no operand, no branch, the kernel
 it always was.
 
-At a head_dim that is no multiple of the 128 lanes the chip's compiler
+At a pool row that is no multiple of the 128 lanes the chip's compiler
 refuses a hand copy of a block (a 64-lane slice of an HBM ref), and the
 launch keeps one block a grid step through a ``BlockSpec`` on the block
 table: grid = (B, Hq, q_tiles, kv_blocks), tiles past the frontier or the
-populated length skipped via ``pl.when`` (``_paged_by_block``).
+populated length skipped via ``pl.when`` (``_paged_by_block``). A head_dim
+that divides the lanes does not come here: its pool holds ``128 // head_dim``
+heads a row (modules/block_kvcache.kv_streams) and ``dispatch_paged_flash``
+hands this kernel the head_dim-128 problem it then is.
 """
 
 from __future__ import annotations
@@ -413,11 +416,13 @@ def _by_block_kernel(
 
 def _paged_by_block(q, k_cache, v_cache, li, block_table, positions, kv_limit,
                     *, scale, n_rep, tq, interpret, window=None):
-    """The launch at a head_dim that is no multiple of the 128 lanes: the
+    """The launch at a pool row that is no multiple of the 128 lanes: the
     chip's compiler refuses such a slice of an HBM ref, so the blocks cannot
     be copied by hand; they come one a grid step through a ``BlockSpec`` on
     the block table, ``(B, Hq, nq, MB)`` steps, one q head's tile against one
-    block, in float32 (as ``decode_attention._paged_by_block``).
+    block, in float32 (as ``decode_attention._paged_by_block``, and reached
+    by the same shapes: a head_dim of 72, 80 or 96, an odd KV head count a
+    device, a quantised pool at head_dim 64; no benchmark cell since PR 65).
     (B, Sq, Hq, D) -> same."""
     B, Sq, Hq, D = q.shape
     bs = k_cache.shape[3]
@@ -504,7 +509,10 @@ def paged_flash_attention(
     B, Sq, Hq, D = q.shape
     if k_cache.ndim == 4:  # one layer's pool: a stack of one
         k_cache, v_cache, layer_idx = k_cache[None], v_cache[None], 0
-    _, _, Hkv, bs, _ = k_cache.shape
+    _, _, Hkv, bs, width = k_cache.shape
+    # a pool of several heads a row comes through dispatch_paged_flash, which
+    # lays the queries in their head's lanes
+    assert width == D, f"q of {D} lanes against pool rows of {width}"
     if tq is None:
         # q-tile default through the tuning table (KERN704), keyed as the
         # group of blocks is: by the block's shape a chip and the cache dtype
@@ -542,15 +550,28 @@ def dispatch_paged_flash(
     (parallel/sharding.shard_over_heads): q and the output split on the q
     heads, the stacked block pool ``(L, NB+1, Hkv, bs, D)`` and the per-head
     dequant factors on the kv heads, layer index, block table, positions and
-    ``kv_limit`` replicated; no collective inside. The plain call at degree 1."""
+    ``kv_limit`` replicated; no collective inside. The plain call at degree 1.
+
+    ``n_rep`` is the MODEL's. A pool of ``g`` KV heads a 128-lane row
+    (block_kvcache.kv_streams) is attended as ``H_kv / g`` KV heads at
+    ``g x D`` with ``g x n_rep`` query heads each, the queries laid in their
+    own head's lanes and the output cut back (block_kvcache.fold_queries), as
+    ``decode_attention.dispatch_paged_tkg_decode`` does."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        fold_queries,
+        pool_fold,
+        unfold_outputs,
+    )
     from neuronx_distributed_inference_tpu.parallel.sharding import shard_over_heads
 
     def per_shard(q_s, k_s, v_s, li, bt, pos, lim, ks_s, vs_s):
-        return paged_flash_attention(
-            q_s, k_s, v_s, bt, pos, lim,
-            scale=scale, n_rep=n_rep, layer_idx=li, k_scale=ks_s, v_scale=vs_s,
+        g = pool_fold(k_s.shape[-1], q_s.shape[-1])
+        out = paged_flash_attention(
+            fold_queries(q_s, g, n_rep), k_s, v_s, bt, pos, lim,
+            scale=scale, n_rep=g * n_rep, layer_idx=li, k_scale=ks_s, v_scale=vs_s,
             interpret=interpret, window=window,
         )
+        return unfold_outputs(out, g, n_rep)
 
     return shard_over_heads(
         per_shard,

@@ -181,7 +181,10 @@ def ragged_paged_attention(
     per-token operand besides q itself.
     """
     T, Hq, D = q.shape
-    _, Hkv, bs, _ = k_cache.shape
+    _, Hkv, bs, width = k_cache.shape
+    # a pool of several heads a row comes through _dispatch_ragged_kernel,
+    # which lays the queries in their head's lanes
+    assert width == D, f"q of {D} lanes against pool rows of {width}"
     R, MB = block_table.shape
     if tq is None:
         # default through the tuning table (KERN704). The packing contract
@@ -296,16 +299,25 @@ def _dispatch_ragged_kernel(
     identical per-head math on each shard with NO cross-shard collectives
     inside, and the tp>1 stream stays byte-identical to tp=1 and to the
     native fallback (pinned in tests/test_ragged_tp.py)."""
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        fold_queries,
+        pool_fold,
+        unfold_outputs,
+    )
     from neuronx_distributed_inference_tpu.parallel.sharding import (
         shard_over_heads,
     )
 
     def per_shard(q_s, k_s, v_s, bt, rs, rl, cl, ks_s, vs_s):
-        return ragged_paged_attention(
-            q_s, k_s, v_s, bt, rs, rl, cl,
-            scale=scale, n_rep=n_rep, k_scale=ks_s, v_scale=vs_s,
+        # a pool of g KV heads a 128-lane row (block_kvcache.kv_streams): the
+        # queries laid in their own head's lanes, the output cut back
+        g = pool_fold(k_s.shape[-1], q_s.shape[-1])
+        out = ragged_paged_attention(
+            fold_queries(q_s, g, n_rep), k_s, v_s, bt, rs, rl, cl,
+            scale=scale, n_rep=g * n_rep, k_scale=ks_s, v_scale=vs_s,
             interpret=interpret,
         )
+        return unfold_outputs(out, g, n_rep)
 
     return shard_over_heads(
         per_shard,
@@ -340,7 +352,9 @@ def ragged_attention_native(
     )
 
     T = q.shape[0]
-    k_r, v_r = read_block_cache_at_layer(k_cache, v_cache, layer_idx, block_table)
+    k_r, v_r = read_block_cache_at_layer(
+        k_cache, v_cache, layer_idx, block_table, head_dim=q.shape[-1]
+    )
     W = k_r.shape[1]
     tok = jnp.arange(T, dtype=jnp.int32)
     hits = (tok[:, None] >= row_start[None, :]) & (

@@ -877,7 +877,8 @@ class TpuModelForCausalLM:
                 takes_block_form,
             )
 
-            if takes_block_form(S, self.spec.attn.head_dim):
+            # asked as the writer asks it: with the pool row's width
+            if takes_block_form(S, self.kv_cache.k.shape[-1]):
                 check_block_form_rows(slot_mapping, self.config.tpu_config.pa_block_size)
         R = runner.chunk_rows
         if runner.is_paged_chunk(slot_mapping, block_table) and B > R:

@@ -614,20 +614,26 @@ def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, pro
     float32 state, a pool of 2048 blocks over the 4 attention layers), both
     step programs compiled for a described v5e.
 
+    The pool holds its 8 KV heads of 64 two a 128-lane row, ``(4, 2049, 4, 32,
+    128)`` (``block_kvcache.kv_streams``): the chip's own layout of it is the
+    row-major one the paged kernels ask for, and NEITHER program holds a copy
+    of the pool's size (four a program at ``(.., 8, 32, 64)``, 0.83 s of a 6 s
+    slice: PERF.md PR 65), in any shape it may have been bitcast to.
+
     decode: ``ssm_state_update`` (state aliased in and out, one layer's tiles
     visited, 32 heads a tile as the tuning table gives this shape: ``y`` leaves
     as 2 lane-dense rows of 32 x 64 a slot) is in the executable, there is NO
-    copy of the state's shape, and
-    the temporaries are the four copies of the 0.27 GB block pool that the
-    chip's default layout at head_dim 64 costs the paged kernels (PERF.md
-    section 7) and nothing of the state's size: under 1.2 GB, where one copy
-    of the state is 3.6 GB. chunk (8 rows, their state gathered and written
-    back by ``seq_ids`` a layer): no copy of the state either — not of the
-    whole, not of one layer's (48, 64, 64, 128) slice — and the whole program
-    (arguments + temporaries) plans 11.59 GiB where the 48-row one planned
-    13.60 (the issue's bound for 48 slots was 14.75)."""
+    copy of the state's shape, the paged KV write is the decode kernel's own
+    (no scatter in the program, nothing under ``layer.kv_write``), and the
+    temporaries are 0.063 GB where the four copies of the 0.27 GB pool made
+    them 1.1: under 0.1 GB, where one copy of the state is 3.6 GB. chunk (8
+    rows, their state gathered and written back by ``seq_ids`` a layer): no
+    copy of the state either — not of the whole, not of one layer's (48, 64,
+    64, 128) slice — its KV write moves whole blocks, and the whole program
+    (arguments + temporaries) plans 10.31 GiB where it planned 11.59 with the
+    copies (the issue's bound for 48 slots was 14.75)."""
     app, params, cache = _abstract_hybrid_app(chip_mesh(1))
-    assert cache.k.shape[0] == 4 and cache.state.ssm.shape[:2] == (36, 48)
+    assert cache.k.shape == (4, 2049, 4, 32, 128) and cache.state.ssm.shape[:2] == (36, 48)
     tkg = app.token_generation_model
     inputs = tkg.example_inputs(1024, q_len=128 if program == "chunk" else None)
     assert inputs.input_ids.shape == ((48, 1) if program == "decode" else (8, 128))
@@ -635,15 +641,17 @@ def test_hybrid_serving_step_holds_no_copy_of_the_recurrent_state(chip_mesh, pro
     text = compiled.as_text()
     assert not _copies_of(compiled, "f32", cache.state.ssm.shape)
     assert not _copies_of(compiled, "f32", cache.state.ssm.shape[1:])
+    assert _pool_copies(compiled, cache.k.shape) == (0, 0)
     mem = compiled.memory_analysis()
     if program == "decode":
         assert "ssm_state_update" in text and "paged_tkg_decode_attention" in text
         assert "f32[48,2,1,2048]" in text  # the kernel's y at the tile taken
-        assert mem.temp_size_in_bytes < 1.2e9
-        assert len(_copies_of(compiled, "bf16", cache.k.shape)) <= 4
+        _assert_decode_write_is_the_kernels(compiled)
+        assert mem.temp_size_in_bytes < 0.1e9
     else:
         assert "paged_flash_attention" in text and "ssm_state_update" not in text
-        assert _planned_bytes(compiled) < 11.9 * 2**30
+        _assert_chunk_write_moves_blocks(compiled, 8, 128, 4, cache.k.shape)
+        assert _planned_bytes(compiled) < 10.6 * 2**30
 
 
 # ---------------------------------------------------------------------------

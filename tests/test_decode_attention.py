@@ -488,8 +488,11 @@ def test_paged_kernel_places_the_new_token_as_write_then_attend_does(case):
         assert changed.sum() == len(kept) * hkv and not changed[[0, 2]].any()
 
 
-def test_serving_decode_writes_in_kernel_and_commits_the_same_tokens(monkeypatch):
-    """A short closed loop on the paged cache at head_dim 128 with the paged
+@pytest.mark.parametrize("heads,kv_heads", [(2, 1), (4, 2)], ids=["head_dim_128", "head_dim_64_two_a_row"])
+def test_serving_decode_writes_in_kernel_and_commits_the_same_tokens(monkeypatch, heads, kv_heads):
+    """A short closed loop on the paged cache at head_dim 128, and at head_dim
+    64 over a pool of two heads a 128-lane row (``block_kvcache.kv_streams``),
+    with the paged
     decode kernel forced on (interpret mode): every decode row's KV write is
     the kernel's (``nxdi_decode_kv_write_rows_total{form="kernel"}``; no
     scatter is traced in the decode program), and the tokens are those of the
@@ -506,7 +509,7 @@ def test_serving_decode_writes_in_kernel_and_commits_the_same_tokens(monkeypatch
 
     def loop(tel):
         cfg = make_tiny_config(
-            hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
+            hidden_size=256, num_attention_heads=heads, num_key_value_heads=kv_heads,
             tpu=dict(
                 seq_len=128, token_generation_buckets=[128], is_continuous_batching=True,
                 is_block_kv_layout=True, pa_block_size=16, pa_num_blocks=64, batch_size=3,
@@ -515,6 +518,7 @@ def test_serving_decode_writes_in_kernel_and_commits_the_same_tokens(monkeypatch
         )
         app = TpuModelForCausalLM(None, cfg)
         app.load(state_dict=make_random_hf_state_dict(cfg))
+        assert app.kv_cache.k.shape[2:] == (1, 16, 128)
         sess = ServingSession(app, telemetry=tel)
         assert sess.add_request("long", list(range(1, 31)), max_new_tokens=6)
         assert sess.add_request("short", [5, 17, 92, 41, 33, 88], max_new_tokens=12)

@@ -140,40 +140,65 @@ def test_tp2_zero_steady_state_recompiles(state_dict):
     assert guard.traces == []
 
 
+# (S, H, packed) -> the form at a head a pool row: a head_dim that fills no
+# 128-lane row with whole heads (24), and any quantised pool
+HEAD_A_ROW_FORMS = {
+    (1, 16, False): "per_head",  # decode
+    (4, 16, False): "per_head",  # speculation width
+    (32, 16, False): "window",  # a chunk, 8 heads a shard: a full tile
+    (32, 4, False): "per_head",  # a chunk, 2 heads a shard (14B at tp=4)
+    (16, 16, True): "window",  # the mixed step's packed axis
+}
+# head_dim 16, bfloat16: eight heads a row, (2, 128) for 16 heads and one pool
+# head a shard (4 heads fill no row): the chunk is on the lanes and moves whole
+# blocks, the packed axis has one head a shard, under the tile
+EIGHT_A_ROW_FORMS = {**HEAD_A_ROW_FORMS, (32, 16, False): "blocks", (16, 16, True): "per_head"}
+
+
 @pytest.mark.parametrize(
-    "S,H,packed,form",
-    [
-        (1, 16, False, "per_head"),  # decode
-        (4, 16, False, "per_head"),  # speculation width
-        (32, 16, False, "window"),  # a chunk, 8 heads a shard: a full tile
-        (32, 4, False, "per_head"),  # a chunk, 2 heads a shard (14B at tp=4)
-        (16, 16, True, "window"),  # the mixed step's packed axis
-    ],
+    "S,H,packed,D,form",
+    [(*case, 24, form) for case, form in HEAD_A_ROW_FORMS.items()]
+    + [(*case, 16, form) for case, form in EIGHT_A_ROW_FORMS.items()],
 )
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_kv_write_under_a_sharded_head_axis(S, H, packed, form, quantized):
+def test_kv_write_under_a_sharded_head_axis(S, H, packed, D, form, quantized):
     """``update_block_cache_at_layer`` on a tp=2 mesh: at decode and
     speculation widths, and wherever a shard holds fewer heads than the
     tile has sublanes, the per-head scatter runs inside ``shard_map`` (each
-    shard its own heads); a chunk over 8 heads a shard keeps the window form
-    under GSPMD; either way the pool comes out bit for bit what the
-    unsharded write leaves."""
+    shard its own heads), and so does the block form of a pool whose rows
+    hold eight heads of 16 (``kv_streams``); a chunk over 8 heads a shard
+    keeps the window form under GSPMD; either way the pool comes out bit for
+    bit what the unsharded write leaves."""
     from jax.sharding import NamedSharding
 
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
         block_cache_spec,
         init_block_cache,
         update_block_cache_at_layer,
+        write_form,
     )
     from neuronx_distributed_inference_tpu.parallel.mesh import build_mesh
 
-    L, NB, bs, D, B = 2, 14, 8, 16, 3
+    L, NB, bs, B = 2, 16, 8, 3
     cache = init_block_cache(L, NB, bs, H, D, dtype=jnp.int8 if quantized else jnp.bfloat16)
+    pool = cache.k.data if quantized else cache.k
+    folded = D == 16 and H == 16 and not quantized
+    assert pool.shape[2:] == ((H // 8, bs, 128) if folded else (H, bs, D))
+    if quantized:  # keeps a head a row whatever the head_dim
+        form = HEAD_A_ROW_FORMS[S, H, packed]
+    assert write_form(S, pool.shape[4], pool.shape[2] // 2, packed=packed, quantised=quantized) == form
     rng = np.random.default_rng(33)
     k_new = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
     v_new = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-    slots = rng.permutation(np.arange(bs, (NB + 1) * bs))[: B * S].reshape(B, S)
-    slots[0, 0] = -1  # a dropped token
+    if form == "blocks":
+        # the block form's rows: a prefix of valid slots at consecutive positions
+        blocks = rng.permutation(np.arange(1, NB + 1))[: B * 5].reshape(B, 5)
+        t = 3 + np.arange(S)
+        slots = np.take_along_axis(blocks, np.broadcast_to(t // bs, (B, S)), axis=1) * bs + t % bs
+        slots[np.arange(S)[None, :] >= S - np.arange(B)[:, None]] = -1  # a dropped tail
+    else:
+        slots = rng.permutation(np.arange(bs, (NB + 1) * bs))[: B * S].reshape(B, S)
+        slots[0, 0] = -1  # a dropped token
     slots = jnp.asarray(slots, jnp.int32)
 
     def write(k, v, kn, vn, sm):
@@ -188,7 +213,7 @@ def test_kv_write_under_a_sharded_head_axis(S, H, packed, form, quantized):
     with jax.set_mesh(mesh):
         got = jax.jit(write)(k_sh, v_sh, k_new, v_new, slots)
         jaxpr = str(jax.make_jaxpr(write)(k_sh, v_sh, k_new, v_new, slots))
-    assert ("shard_map" in jaxpr) == (form == "per_head")
+    assert ("shard_map" in jaxpr) == (form in ("per_head", "blocks"))
     for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
         np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
     assert got[0].data.sharding.spec == specs.k.data if quantized else (

@@ -32,6 +32,7 @@ from neuronx_distributed_inference_tpu.modules.kvcache import (
 )
 from neuronx_distributed_inference_tpu.ops.ragged_paged_attention import (
     RAGGED_Q_TILE,
+    _dispatch_ragged_kernel,
     _use_ragged_kernel,
     ragged_attention_native,
     ragged_paged_attention,
@@ -112,7 +113,11 @@ def _kernel_vs_native(ctx, qlen, dtype, layer=1):
         k_l, v_l = kb.data[layer], vb.data[layer]
     else:
         k_l, v_l = kb[layer], vb[layer]
-    out = ragged_paged_attention(
+    # through the dispatcher: the pool holds two heads of 64 a 128-lane row
+    # (block_kvcache.kv_streams) and the queries go in laid in their head's
+    # lanes; a quantised pool keeps a head a row and is the plain call
+    assert k_l.shape[-2:] == ((BS, D) if ks is not None else (BS, 2 * D))
+    out = _dispatch_ragged_kernel(
         q, k_l, v_l, bt, row_start, row_len, ctx_len,
         scale=spec.softmax_scale, n_rep=HQ // HKV,
         k_scale=ks, v_scale=vs, interpret=True,
@@ -156,9 +161,10 @@ def test_bf16_queries():
         q.astype(jnp.bfloat16), kb, vb, jnp.int32(0), bt, positions,
         row_start, row_len, ctx_len, spec,
     )
-    out = ragged_paged_attention(
+    out = _dispatch_ragged_kernel(
         q.astype(jnp.bfloat16), kb[0], vb[0], bt, row_start, row_len, ctx_len,
-        scale=spec.softmax_scale, n_rep=HQ // HKV, interpret=True,
+        scale=spec.softmax_scale, n_rep=HQ // HKV, k_scale=None, v_scale=None,
+        interpret=True,
     )
     valid = np.asarray(positions) >= 0
     np.testing.assert_allclose(
