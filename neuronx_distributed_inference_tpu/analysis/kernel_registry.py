@@ -245,6 +245,26 @@ def _kda_update_case(rows):
     return build
 
 
+def _power_update_case(rows):
+    """Brumby-14B's power-retention geometry (8 of its layers, 8 KV heads of
+    8704 x 128 read by 5 query heads each) at ``rows`` serving slots: the
+    benchmark's generation cell."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import power_state_update as pu
+
+        L, H, G, D, d = 8, 40, 8, 8704, 128
+        f32 = jnp.float32
+        args = (_sds((L, rows, G, D, d), f32), _sds((L, rows, G, D), f32), _sds((), jnp.int32),
+                _sds((rows, H, d), f32), _sds((rows, G, d), f32), _sds((rows, G, d), f32),
+                _sds((rows, G), f32), _sds((rows,), jnp.bool_), _sds((rows,), jnp.bool_))
+        return _unjit(pu.power_state_update), args
+
+    return build
+
+
 def _paged_flash_case(B, Sq, MB, bs, cache_dtype, m=_1B):
     def build():
         import jax.numpy as jnp
@@ -621,6 +641,17 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         # heads_per_block is a keyword of the entry (16: a 1 MiB tile), not a
         # tuning-table entry: nothing was swept on the chip yet
         cases=(KernelCase("rows128", "float32", _kda_update_case(128)),),
+    ),
+    KernelSpec(
+        name="power_state_update",
+        site=("power_state_update.py", "power_state_update"),
+        entry="power_state_update",
+        fallback="neuronx_distributed_inference_tpu.modules.power_retention:power_step",
+        parity_test="tests/test_brumby_reference.py",
+        lowering_test="tests/test_chip_compile.py",
+        # the tile (34 block pairs = 2176 rows) is the kernel's constant
+        # (PAIRS_PER_TILE: 17 / 34 / 68 read on the chip), not a tuning-table entry
+        cases=(KernelCase("rows16", "float32", _power_update_case(16)),),
     ),
     KernelSpec(
         name="quant_matmul",

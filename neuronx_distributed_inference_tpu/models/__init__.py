@@ -24,3 +24,4 @@ from neuronx_distributed_inference_tpu.models import ouro  # noqa: F401
 from neuronx_distributed_inference_tpu.models import glm_moe_dsa  # noqa: F401
 from neuronx_distributed_inference_tpu.models import mellum  # noqa: F401
 from neuronx_distributed_inference_tpu.models import kimi_linear  # noqa: F401
+from neuronx_distributed_inference_tpu.models import brumby  # noqa: F401

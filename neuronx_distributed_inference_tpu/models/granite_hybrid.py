@@ -72,6 +72,11 @@ MAMBA, ATTENTION, MOE = "mamba", "attention", "moe"
 #: (modules/kda.py), a latent-attention mixer (models/deepseek.mla_decoder_layer
 #: without its MLP) and a dense gated MLP alone
 KDA, MLA, DENSE = "kda", "mla", "dense"
+#: a layer of two parts whose mixer is power retention (models/brumby.py,
+#: modules/power_retention.py): Qwen3's layer with the attention swapped
+POWER = "power"
+#: the kinds whose blocks page K/V (or latents) over the block pool
+_PAGING = (ATTENTION, MLA)
 
 
 class GraniteHybridInferenceConfig(InferenceConfig):
@@ -261,14 +266,21 @@ class HybridStack(LayerStack):
     a mixer block and then an MLP block (models/kimi_linear.py): KDA (a
     delta-rule mixer over the per-slot state, ``kspec`` a modules/kda.KDASpec),
     MLA (latent attention over a pool of latents, ``mla`` a
-    models/deepseek.MLASpec) and DENSE (the gated ``mlp_fn`` alone)."""
+    models/deepseek.MLASpec) and DENSE (the gated ``mlp_fn`` alone).
+
+    POWER (models/brumby.py) is a layer of two parts as granite's are: a
+    power-retention mixer over the per-slot state (``pspec`` a
+    modules/power_retention.PowerSpec; Qwen3's projections, QK-norm and
+    rotation before it), then the MLP. A stack with no block of a paging
+    kind reads neither the block table nor the pool: nothing of either is
+    in its two programs."""
 
     def __init__(self, layer_types: Tuple[str, ...], sspec: ssm.SSMSpec = None, expert_mlp=None,
-                 kspec=None, mla=None):
+                 kspec=None, mla=None, pspec=None):
         self.layer_types = tuple(layer_types)
         self.sspec = sspec
         self.expert_mlp = expert_mlp
-        self.kspec, self.mla = kspec, mla
+        self.kspec, self.mla, self.pspec = kspec, mla, pspec
         self.plan = layer_plan(self.layer_types)
 
     def __call__(self, params, hidden, cache, inputs, *, spec, phase, mlp_fn):
@@ -281,8 +293,9 @@ class HybridStack(LayerStack):
             raise TypeError(f"expected a HybridBlockCache, got {type(cache).__name__}")
         positions = inputs.position_ids
         valid, reset, slots = slot_state_rows(inputs, cache.state.num_slots)
-        block_inputs = paged_block_inputs(inputs, cache.block_size)
-        mask = build_mask(inputs, spec, phase)
+        paging = any(kind in _PAGING for kind in self.layer_types)
+        block_inputs = paged_block_inputs(inputs, cache.block_size) if paging else None
+        mask = build_mask(inputs, spec, phase) if paging else None
         layers = params["layers"]
         # the carry: hidden, pool, state and, where the step returns its
         # choices, every expert layer's (L_moe, B, S, k)
@@ -319,6 +332,27 @@ class HybridStack(LayerStack):
                 h = residual_add(h, out, spec)
             return (h, k, v, st, *rest), None
 
+        if POWER in self.layer_types:  # the rotary tables, once for every layer
+            from neuronx_distributed_inference_tpu.modules.rope import rope_cos_sin
+
+            cos, sin = rope_cos_sin(positions, params["rope"]["inv_freq"], spec.attention_scaling)
+
+        def power(carry, li):
+            from neuronx_distributed_inference_tpu.modules.power_retention import power_mixer
+
+            h, k, v, st, *rest = carry
+            lp = _take(layers[POWER], li)
+            with jax.named_scope("layer.norm"):
+                x = rms_norm(h, lp["input_layernorm"]["weight"], spec.rms_eps)
+            with jax.named_scope("layer.power"):
+                out, st = power_mixer(
+                    lp["self_attn"], x, cos, sin, st, li, valid, reset, self.pspec,
+                    spec.attn.rms_norm_eps, slots=slots,
+                )
+                h = residual_add(h, out, spec)
+            h = _decoder_layer_mlp(lp, h, spec, mlp_fn)
+            return (h, k, v, st, *rest), None
+
         def mla(carry, li):
             from neuronx_distributed_inference_tpu.models.deepseek import mla_decoder_layer
 
@@ -338,7 +372,8 @@ class HybridStack(LayerStack):
                 h = residual_add(h, mlp_fn(lp["mlp"], x, spec), spec)
             return (h, *rest), None
 
-        bodies = {MAMBA: mamba, ATTENTION: attention, KDA: kda, MLA: mla, DENSE: dense}
+        bodies = {MAMBA: mamba, ATTENTION: attention, KDA: kda, MLA: mla, DENSE: dense,
+                  POWER: power}
         if n_moe:
             from neuronx_distributed_inference_tpu.modules.moe import (
                 hoist_expert_stacks,

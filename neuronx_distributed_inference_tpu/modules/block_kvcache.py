@@ -958,6 +958,40 @@ class BlockAllocator:
         return table
 
 
+class NoPoolAllocator:
+    """The allocator of a model NONE of whose layers pages (every entry of
+    ``builder.cache_layers()`` is ``SLOT_STATE``: models/brumby.py): there is
+    no pool, a request of any length holds no block, and nothing is ever
+    exhausted, so the session admits by free slots alone and never preempts
+    for blocks. It answers what the serving step asks of an allocator with
+    nothing: an empty block list, a zero block table, and a slot mapping
+    whose only content is WHICH positions are real (0 where one is; the
+    chunk program reads ``slot_mapping >= 0`` as a pass's real positions and
+    writes nothing anywhere)."""
+
+    num_blocks = 0
+    free = ()
+
+    def __init__(self, block_size: int):
+        self.block_size = block_size
+        self.seq_blocks: Dict[int, List[int]] = {}
+
+    def alloc_seq(self, seq_id: int, num_tokens: int) -> List[int]:
+        return []
+
+    def free_seq(self, seq_id: int):
+        pass
+
+    def quarantine_seq(self, seq_id: int) -> List[int]:
+        return []
+
+    def slot_mapping(self, seq_id: int, positions: np.ndarray) -> np.ndarray:
+        return np.zeros(len(positions), np.int32)
+
+    def block_table(self, seq_id: int, max_blocks: int) -> np.ndarray:
+        return np.zeros(max_blocks, np.int32)
+
+
 @dataclass
 class PrefixCachingAllocator(BlockAllocator):
     """Content-addressed block reuse (prefix caching).

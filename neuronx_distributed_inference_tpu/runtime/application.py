@@ -310,7 +310,12 @@ class TpuModelForCausalLM:
             paged = self.paged_layers
             # what a token leaves in a paging layer is the builder's to say
             streams = self.builder.cache_streams()
-            if tc.pa_num_blocks is None and tc.pa_pool_bytes is not None:
+            if not paged:
+                # no layer pages (models/brumby.py): no pool. The cache keeps
+                # its K and V leaves, of zero layers and one (garbage) block:
+                # no byte, nothing to budget and nothing to divide by
+                tc.pa_num_blocks = 0
+            elif tc.pa_num_blocks is None and tc.pa_pool_bytes is not None:
                 # byte-budgeted pool: the block count follows the TRUE
                 # per-block cost in the cache dtype — a quantized cache
                 # admits ~2x the blocks for the same HBM budget

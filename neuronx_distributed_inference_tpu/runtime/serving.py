@@ -233,12 +233,16 @@ class ServingSession:
         self.chunk_size = cpc.kernel_q_tile_size if cpc else 128
         self.max_prefill_seqs = cpc.max_num_seqs if cpc else 8
         self.allocator = None
+        #: whether block mode has a pool at all (a model none of whose
+        #: layers pages has none: modules/block_kvcache.NoPoolAllocator)
+        self.pooled = False
         self.block_bytes = 0
         self.latent_layers = 0
         self.sparse_layers = self.sparse_topk = 0
         if self.block_mode:
             from neuronx_distributed_inference_tpu.modules.block_kvcache import (
                 BlockAllocator,
+                NoPoolAllocator,
                 PrefixCachingAllocator,
                 chunk_write_blocks,
                 kv_block_bytes,
@@ -254,15 +258,21 @@ class ServingSession:
                     "(init_kv_cache sizes the pool from pa_pool_bytes and "
                     "the cache dtype) before creating a ServingSession"
                 )
-            cls = PrefixCachingAllocator if self.prefix_caching else BlockAllocator
-            self.allocator = cls(tc.pa_num_blocks, tc.pa_block_size)
+            # the layers that page (a hybrid model's state-space layers keep
+            # a per-slot state and cost no block): the builder's to say
+            paged_layers = getattr(app, "paged_layers", app.spec.num_layers)
+            self.pooled = paged_layers > 0
+            if self.pooled:
+                cls = PrefixCachingAllocator if self.prefix_caching else BlockAllocator
+                self.allocator = cls(tc.pa_num_blocks, tc.pa_block_size)
+            else:
+                # no layer pages (models/brumby.py): no pool, admission by
+                # free slots alone, no preemption for blocks
+                self.allocator = NoPoolAllocator(tc.pa_block_size)
             # true per-block HBM cost in the CACHE dtype (NOT a hardcoded
             # bf16 itemsize): quantized caches admit ~2x the blocks for the
             # same pool budget, and this is what capacity reporting uses
-            # the layers that page (a hybrid model's state-space layers keep
-            # a per-slot state and cost no block) and what a token leaves in
-            # each of them: the builder's to say
-            paged_layers = getattr(app, "paged_layers", app.spec.num_layers)
+            # what a token leaves in each paging layer: the builder's to say
             streams = app.builder.cache_streams()
             self.block_bytes = kv_block_bytes(
                 paged_layers, tc.pa_block_size, dtype=tc.kv_dtype, streams=streams
@@ -379,7 +389,7 @@ class ServingSession:
         # a model whose layers keep a constant-size per-slot state
         # (HybridBlockCache.state: state-space layers, a one-token carry):
         # the scrub of a slot covers it, and the family its KIND names
-        # counts it (nxdi_ssm_*, nxdi_kda_*, nxdi_latent_carry_*); a model with routed
+        # counts it (nxdi_ssm_*, nxdi_kda_*, nxdi_power_*, nxdi_latent_carry_*); a model with routed
         # experts that says so is counted by nxdi_moe_* (_count_pass)
         state = getattr(app.kv_cache, "state", None)
         self.slot_state = state is not None
@@ -804,7 +814,7 @@ class ServingSession:
             never_fits = req.prompt_len > self._max_admissible_prompt() or (
                 # re-prefilling prompt+committed can NEVER fit the whole
                 # pool: retrying would spin (each cycle preempts again)
-                self.block_mode
+                self.pooled
                 and -(-req.prompt_len // self.allocator.block_size)
                 > self.allocator.num_blocks
             )
@@ -1313,7 +1323,7 @@ class ServingSession:
                     rows=(len(ran), len(flights) * R - len(ran)),
                 )
                 kv_blocks = None
-                if tel.enabled:
+                if tel.enabled and self.pooled:
                     # the blocks a row's causal context holds once this chunk is in
                     live = [-(-(r.prefill_pos + n) // bs) for r, n in ran]
                     kv_blocks = (sum(live), self._chunk_kv_blocks_walked(live, mb))
@@ -1953,7 +1963,7 @@ class ServingSession:
                         block_table[r.slot] = self.allocator.block_table(r.slot, mb)
                     if not rows:
                         return None, []
-                    if tel.enabled:
+                    if tel.enabled and self.pooled:
                         live = [-(-(p + K) // bs) for _, p in rows]
                         kv_blocks = (sum(live), self._kv_blocks_walked(live, mb))
                     # no host slot mapping: decode writes derive their slots
@@ -2005,7 +2015,7 @@ class ServingSession:
                 tel.step("decode")
                 tel.bucket_dispatch(tkg.tag, tkg.last_bucket)
                 tel.decode_pass(len(rows), B)
-                if self.block_mode and tel.enabled:
+                if self.pooled and tel.enabled:
                     tel.kv_write_rows(self._decode_write_form(K, width), len(rows))
                 self._count_pass(
                     "decode", (B, K), len(rows), len(rows) * K, 1, kv_blocks=kv_blocks,
@@ -2092,7 +2102,7 @@ class ServingSession:
             self.tel.kv_blocks(program, *kv_blocks)
         if block_rows is not None:
             self.tel.block_pass(*block_rows, positions=tokens)
-        if self.slot_state_kind in ("ssm", "kda"):
+        if self.slot_state_kind in ("ssm", "kda", "power"):
             self.tel.ssm_pass(program, rows, self.slot_state_bytes, resets=resets,
                               kind=self.slot_state_kind)
         elif self.slot_state_kind == "latent_carry":

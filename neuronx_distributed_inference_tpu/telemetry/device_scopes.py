@@ -46,6 +46,7 @@ DEVICE_SCOPES = (
     "layer.shared_mlp",
     "layer.ssm",
     "layer.kda",
+    "layer.power",
     "layer.other",
     "loop.norm",
     "head",

@@ -403,6 +403,18 @@ class TelemetrySession:
             "nxdi_kda_state_bytes",
             "HBM of the per-slot delta-rule state (conv tails + the float32 "
             "matrix state a head, every KDA layer, every slot)")
+        self._power_rows = r.counter(
+            "nxdi_power_rows_advanced_total",
+            "rows whose power-retention state a dispatch of the split serving "
+            "step advanced", labels=("program",))
+        self._power_resets = r.counter(
+            "nxdi_power_state_resets_total",
+            "rows a chunk pass started from a zero power-retention state (first "
+            "position 0: a new request, or a re-prefill after preemption)")
+        self._power_bytes = r.gauge(
+            "nxdi_power_state_bytes",
+            "HBM of the per-slot power-retention state (the float32 state and "
+            "normaliser a KV head, every layer, every slot)")
         self._carry_rows = r.counter(
             "nxdi_latent_carry_rows_advanced_total",
             "rows whose one-token carry (latent attention with conv mixing: "
@@ -1286,14 +1298,16 @@ class TelemetrySession:
         keep a recurrent state a slot: the rows whose state it advanced
         (``program``: "decode" or "chunk"), of those the rows it started
         from zero, and the bytes the state of all slots holds. ``kind`` is
-        the state's ``KIND``: ``ssm`` (state-space layers, ``nxdi_ssm_*``) or
-        ``kda`` (delta-rule linear attention, ``nxdi_kda_*``). Counted from
-        what the step already knows."""
+        the state's ``KIND``: ``ssm`` (state-space layers, ``nxdi_ssm_*``),
+        ``kda`` (delta-rule linear attention, ``nxdi_kda_*``) or ``power``
+        (power retention, ``nxdi_power_*``). Counted from what the step
+        already knows."""
         if not self.enabled:
             return
-        rows_total, resets_total, bytes_held = (
-            (self._kda_rows, self._kda_resets, self._kda_bytes) if kind == "kda"
-            else (self._ssm_rows, self._ssm_resets, self._ssm_bytes))
+        rows_total, resets_total, bytes_held = {
+            "kda": (self._kda_rows, self._kda_resets, self._kda_bytes),
+            "power": (self._power_rows, self._power_resets, self._power_bytes),
+        }.get(kind, (self._ssm_rows, self._ssm_resets, self._ssm_bytes))
         rows_total.child((program,)).inc(rows)
         bytes_held.set(state_bytes)
         if resets:

@@ -94,6 +94,7 @@ MAIN_PATH_KERNELS = [
     ("ssm_state_update", "h64g1x64x128", "float32"),  # granite-4.0-h-micro decode: 48 slots, one group, 32 heads a tile
     ("ssm_state_update", "h64g8x64x128", "float32"),  # nemotron-3-nano-30b-a3b decode: 64 slots, 8 groups of B/C
     ("kda_state_update", "rows128", "float32"),  # kimi-linear-48b-a3b decode, 128 slots
+    ("power_state_update", "rows16", "float32"),  # brumby-14b-base decode, 16 slots: tiles of 1088 x 128 of a KV head's 8704
     ("grouped_matmul", "k2048_n2048", "bfloat16"),  # zaya1-8b's chunk program: 1024 rows, 16 experts of a 20-layer stack
     ("grouped_matmul", "k2048_n768", "bfloat16"),  # sdar-30b-a3b's gate and up: 8192 rows, 128 experts
     ("grouped_matmul", "k768_n2048", "bfloat16"),  # its down
@@ -827,7 +828,8 @@ def _work_under_no_scope(compiled):
     from neuronx_distributed_inference_tpu.telemetry import device_scopes
 
     table = device_scopes.scope_table(compiled.as_text())["ops"]
-    work = ("convolution", "dot", "grouped_matmul", "ssm_state_update", "kda_state_update", "paged_", "reduce")
+    work = ("convolution", "dot", "grouped_matmul", "ssm_state_update", "kda_state_update",
+            "power_state_update", "paged_", "reduce")
     return sorted(name for name, scope in table.items()
                   if scope == device_scopes.LAYER_OTHER and name.startswith(work))
 
@@ -1198,5 +1200,100 @@ def test_kimi_linear_serving_step_updates_the_state_in_place_and_fits_the_chip(
     mem = compiled.memory_analysis()
     print(f"\nkimi-linear-48b-a3b {program}: {bodies} block bodies, arguments "
           f"{mem.argument_size_in_bytes / 2**30:.3f} GiB, temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
+          f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
+    assert _planned_bytes(compiled) < 14.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# brumby-14b-base: a power-retention state a KV head in every layer, no pool
+# ---------------------------------------------------------------------------
+
+
+def _abstract_brumby_app(mesh):
+    """benchmark/configs/brumby-14b-base.json over a described chip: params, the
+    pool of ZERO layers and the per-slot power-retention state as
+    ShapeDtypeStructs."""
+    import json
+    import os
+
+    from benchmark.harness import system
+    from neuronx_distributed_inference_tpu.modules.block_kvcache import (
+        HybridBlockCache,
+        init_block_cache,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "brumby-14b-base.json")) as f:
+        file = json.load(f)
+    app = system.build_app(file, mesh.devices.ravel().tolist(), 0)
+    tc, b = app.config.tpu_config, app.builder
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, P()))
+
+    def cache():
+        pool = init_block_cache(app.paged_layers, 0, tc.pa_block_size,
+                                dtype=jnp.bfloat16, streams=b.cache_streams())
+        return HybridBlockCache(k=pool.k, v=pool.v, state=b.init_slot_state(tc.batch_size)[0])
+
+    params = jax.tree.map(place, jax.eval_shape(b.random_params))
+    return app, params, jax.tree.map(place, jax.eval_shape(cache))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_brumby_serving_step_has_no_pool_and_updates_the_state_in_place(
+        chip_mesh, program, monkeypatch):
+    """brumby-14b-base at the benchmark's widths (benchmark/configs/
+    brumby-14b-base.json: 8 of 40 layers, 40 query heads over 8 KV heads of
+    128, the whole vocabulary, 16 slots), both step programs compiled for a
+    described v5e at the one kv bucket.
+
+    The cache is a pool of ZERO layers (no byte) beside a per-slot state of
+    (8, 16, 8, 8704, 128) float32 = 4.25 GiB and its normaliser. Neither
+    program takes the block table (an argument of the traced function that
+    nothing reads: jit leaves it out of the executable, whose entry has no
+    int32 parameter of the table's shape), neither writes K/V (nothing under
+    ``layer.kv_write`` or ``layer.attn``, no scatter), and neither holds a copy
+    of the state: not of the stack, not of a layer's slice, not of a slot's
+    (8, 8704, 128). decode (16 x 1): ``power_state_update`` under
+    ``layer.power``, the state aliased in and out. chunk (8 x 128): the chunked
+    form as XLA's own products under ``layer.power`` (no state kernel), the
+    live rows' tiles taken from and put back into the stack in place. Each
+    plans under 14.75 GiB."""
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
+    from neuronx_distributed_inference_tpu.telemetry import device_scopes
+
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
+    app, params, cache = _abstract_brumby_app(chip_mesh(1))
+    assert app.paged_layers == 0 and cache.k.shape[0] == 0 and cache.k.size == 0
+    assert cache.state.ssm.shape == (8, 16, 8, 8704, 128) and cache.state.conv.shape == (8, 16, 8, 8704)
+    assert params["lm_head"]["weight"].shape == (5120, 151936)
+    tkg = app.token_generation_model
+    inputs = tkg.example_inputs(8192, q_len=128 if program == "chunk" else None)
+    rows = 16 if program == "decode" else 8
+    assert inputs.input_ids.shape == ((16, 1) if program == "decode" else (8, 128))
+    assert inputs.block_table.shape == (rows, 256)
+    compiled = _compile_step(app, tkg, inputs, params, cache)
+    text = compiled.as_text()
+    entry = text.partition("\nENTRY ")[2].split("\n", 1)[0]
+    assert f"s32[{rows},256]" not in entry, "the block table is a parameter of the executable"
+    assert f"s32[{rows},8192]" not in entry, "the kv mask is a parameter of the executable"
+    table = device_scopes.scope_table(text)["ops"]
+    assert not {"layer.kv_write", "layer.attn"} & set(table.values())
+    assert {"layer.qkv", "layer.power", "layer.o_proj", "layer.mlp"} <= set(table.values())
+    assert " scatter(" not in text
+    state = cache.state.ssm.shape
+    for shape in (state, state[1:], state[2:]):
+        assert not _copies_of(compiled, "f32", shape), _copies_of(compiled, "f32", shape)[:3]
+    kernel = [name for name in table if name.startswith("power_state_update")]
+    if program == "decode":
+        assert kernel and {table[c] for c in kernel} == {"layer.power"}
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+    else:
+        assert not kernel and "layer.power" in set(table.values())
+    assert not _work_under_no_scope(compiled), _work_under_no_scope(compiled)[:5]
+    mem = compiled.memory_analysis()
+    print(f"\nbrumby-14b-base {program}: arguments {mem.argument_size_in_bytes / 2**30:.3f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB, "
           f"planned {_planned_bytes(compiled) / 2**30:.3f} GiB")
     assert _planned_bytes(compiled) < 14.75 * 2**30
