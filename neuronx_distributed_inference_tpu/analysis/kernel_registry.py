@@ -328,6 +328,26 @@ def _latent_case(B, Sq, MB, bs, m=_MLA):
     return build
 
 
+def _index_case(B, Sq, MB, bs, heads=32, dim=128, layers=5):
+    """The paged index-score kernel of ops/index_scores.py over the pool's
+    index-key stream at glm-5's widths (benchmark/configs/glm-5.json: 32
+    index heads of 128, 5 layers): the decode program's 32 rows of one query,
+    a chunk pass's 8 rows of ``Sq``."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import index_scores as ix
+
+        q = _sds((B, Sq, heads, dim), jnp.bfloat16)
+        w = _sds((B, Sq, heads), jnp.float32)
+        keys = _sds((layers, 65, 1, bs, dim), jnp.bfloat16)
+        li, bt, frontier = _sds((), jnp.int32), _sds((B, MB), jnp.int32), _sds((B,), jnp.int32)
+        return _unjit(ix.paged_index_scores), (q, w, keys, li, bt, frontier)
+
+    return build
+
+
 def _ragged_case(T, R, MB, bs, cache_dtype):
     def build():
         import jax.numpy as jnp
@@ -575,6 +595,27 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         step_copy_bytes=lambda inst: sum(b for _, _, b in inst.scratch[:2]) // 2,
         cases=(KernelCase("blk1x32x512", "bfloat16", _latent_case(8, 128, 256, 32)),),
     ),
+    # the indexer's scores off the pool's third stream (ops/index_scores.py):
+    # one kernel for both step programs, so the decode shape is a second case
+    # of the one block shape, told apart by its label. ``rows``: the most
+    # score rows of a product (index heads of a tile x the pass's positions);
+    # ``pages``: the blocks of a group
+    KernelSpec(
+        name="paged_index_scores",
+        site=("decode_attention.py", "_common_call"),
+        entry="paged_index_scores",
+        fallback="neuronx_distributed_inference_tpu.modules.sparse_index:index_scores",
+        parity_test="tests/test_index_scores.py",
+        lowering_test="tests/test_chip_compile.py",
+        tile_params=("rows", "pages"),
+        sweep=(("rows", (256, 512, 1024, 2048)), ("pages", (16, 32, 64))),
+        # a step fills ONE of the two slots of the keys' VMEM scratch by hand
+        step_copy_bytes=lambda inst: inst.scratch[0][2] // 2,
+        cases=(
+            KernelCase("blk1x32x128", "bfloat16", _index_case(8, 128, 528, 32)),
+            KernelCase("blk1x32x128", "bfloat16_decode", _index_case(32, 1, 528, 32)),
+        ),
+    ),
     KernelSpec(
         name="ragged_paged_attention",
         site=("ragged_paged_attention.py", "ragged_paged_attention"),
@@ -699,6 +740,8 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     },
     # latent_attention.Q_ROWS, and GROUP_TOKENS (1024) over the block's 32 tokens
     "paged_latent_flash_attention": {"blk1x32x512": {"rows": 512, "pages": 32}},
+    # index_scores.Q_ROWS, and GROUP_TOKENS (1024) over the block's 32 tokens
+    "paged_index_scores": {"blk1x32x128": {"rows": 1024, "pages": 32}},
     "ragged_paged_attention": {"*": {"tq": 16}},
     # ssm_state_update.DEFAULT_HEADS_PER_BLOCK
     "ssm_state_update": {"*": {"heads": 16}},

@@ -20,9 +20,17 @@ and the attention attends ``S_t`` alone. Three parts, each under its own
 named scope in the layer (``layer.indexer``: projections and scores,
 ``layer.select``, ``layer.attn``):
 
-* :func:`index_projections`, :func:`index_scores`;
+* :func:`index_projections`, then the scores: on the serving path
+  ``ops/index_scores.paged_index_scores``, which reads the index keys where
+  they lie in the pool's third stream and walks a row's LIVE block groups
+  (no gathered copy of the kv bucket, nothing scored past a row's frontier,
+  an empty row free; what it leaves unwritten there nobody reads);
+  :func:`index_scores` over keys gathered by the block table for what the
+  kernel's gate refuses (off the chip, a stream off the lanes, a width
+  under a group), and over a whole prompt's own keys;
 * :func:`select`: the chosen keys as a predicate ``(B, S, W)`` over the
-  row's kv width, found WITHOUT a sort: the ``index_topk``-th largest score
+  row's kv width (it still reads the BUCKET's width, the live keys alone
+  by the mask), found WITHOUT a sort: the ``index_topk``-th largest score
   of a query is bisected on the scores' bit patterns (32 counting passes
   over the scores; a top-k of 2048 out of 16k is a full sort on the chip),
   ties at the threshold broken towards the lower position as
@@ -207,8 +215,15 @@ def sparse_latent_attention(
     live for a query. At a kv width of no more than ``index_topk`` the
     selection is every live key and the layer is dense latent attention (the
     existing kernels, unchanged); past it the indexer scores the row's live
-    keys (scope ``layer.indexer``), :func:`select` picks (``layer.select``)
-    and the attention (``layer.attn``) runs over the picked alone, through
+    keys (scope ``layer.indexer``: in both step programs
+    ``paged_index_scores`` over the row's live block groups in the pool where
+    ``use_index_kernel`` admits the call, by what it shows: the chip, the
+    stream on whole lanes, a kv width of whole lane rows; else, and for a
+    whole prompt, :func:`index_scores` over the gathered bucket),
+    :func:`select` picks over the bucket's width, reading the live keys alone
+    (``layer.select``: what the kernel left unwritten past a row's frontier
+    is never read), and the attention (``layer.attn``) runs over the picked
+    alone, through
     ``latent_attend`` with the selection as a predicate, by the rule the
     dense call follows: the decode program's through the latent decode kernel
     with the selection as its mask, a chunk pass's inside the latent chunk
@@ -226,6 +241,10 @@ def sparse_latent_attention(
     from neuronx_distributed_inference_tpu.modules.block_kvcache import (
         read_latent_cache_at_layer,
         read_stream_at_layer,
+    )
+    from neuronx_distributed_inference_tpu.ops.index_scores import (
+        paged_index_scores,
+        use_index_kernel,
     )
     from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
     from neuronx_distributed_inference_tpu.ops.latent_attention import (
@@ -257,8 +276,16 @@ def sparse_latent_attention(
 
     row_live = jnp.any(live, axis=(1, 2))
     with jax.named_scope("layer.indexer"):
-        k_all = k_i if whole_prompt else read_stream_at_layer(index_cache, layer_idx, block_table)
-        scores = index_scores(q_i, w_i, k_all, row_live)
+        if not whole_prompt and use_index_kernel(index_cache, W):
+            # one past a row's last live key: what the mask shows, in both programs
+            frontier = jnp.max(jnp.where(live, jnp.arange(1, W + 1, dtype=jnp.int32), 0), axis=(1, 2))
+            scores = paged_index_scores(
+                q_i, w_i, index_cache, layer_idx, block_table, frontier,
+                interpret=kernel_interpret(),
+            )
+        else:
+            k_all = k_i if whole_prompt else read_stream_at_layer(index_cache, layer_idx, block_table)
+            scores = index_scores(q_i, w_i, k_all, row_live)
     with jax.named_scope("layer.select"):
         picked = select(scores, live, k)
         chosen = chosen_positions(picked, k) if want_positions else None

@@ -305,6 +305,16 @@ class ServingSession:
             self._chunk_kv_blocks_walked = functools.partial(
                 chunk_kernel.kv_blocks_walked, **shape
             )
+            if self.sparse_layers:
+                # and what the indexers score of the index keys' stream for a
+                # row (shape and dtype alone: the pool is donated every step)
+                from neuronx_distributed_inference_tpu.ops import index_scores
+
+                keys = app.kv_cache.extra[0]
+                self._index_blocks_walked = functools.partial(
+                    index_scores.index_blocks_walked,
+                    index_cache=jax.ShapeDtypeStruct(keys.shape, keys.dtype),
+                )
             # and what the paged KV write of a chunk pass moves, where it
             # moves whole blocks
             batch_sharded = app.spec.attention_dp * app.spec.data_parallel > 1
@@ -2122,6 +2132,16 @@ class ServingSession:
                 program, tokens * self.sparse_layers, scored * self.sparse_layers,
                 attended * self.sparse_layers,
             )
+            if kv_width > k:
+                # the blocks of index keys the pass's indexers scored, of the
+                # bucket's width over every row of its dispatches
+                bs = self.allocator.block_size
+                mb = kv_width // bs
+                walked = self._index_blocks_walked([-(-(first + n) // bs) for first, n in spans], mb)
+                self.tel.index_key_blocks(
+                    program, walked * self.sparse_layers,
+                    (dispatches * shape[0] * mb - walked) * self.sparse_layers,
+                )
         if self.window_layers and self.tel.enabled:
             # query t of a row has t + 1 live keys and attends min(t + 1,
             # window) of them in a window layer; a pass that enters a logical

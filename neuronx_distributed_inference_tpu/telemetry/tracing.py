@@ -443,6 +443,18 @@ class TelemetrySession:
             "keys attended after the selection: over the rows, layers and "
             "queries of a pass, min(live keys, index_topk)",
             labels=("program",))
+        self._index_key_blocks = r.counter(
+            "nxdi_index_key_blocks_total",
+            "pool blocks of indexer keys per pass of the split serving step "
+            "whose kv width is past index_topk, over the layers with an "
+            "indexer: kind=walked, the block-table entries the paged "
+            "index-score kernel scores (whole groups of blocks up to each "
+            "live row's frontier; every entry of a live row's table where "
+            "the bucket is gathered instead); kind=skipped, the rest of the "
+            "bucket's width for the pass's live rows and all of it for the "
+            "empty rows of its dispatches. skipped / (walked + skipped) = "
+            "the share of the bucket the indexer left alone",
+            labels=("program", "kind"))
         self._attn_keys_live = r.counter(
             "nxdi_attn_keys_live_total",
             "a stack that mixes window and full attention layers: live keys "
@@ -1336,6 +1348,15 @@ class TelemetrySession:
         self._index_keys.inc(written)
         self._sparse_scored.child((program,)).inc(scored)
         self._sparse_attended.child((program,)).inc(attended)
+
+    def index_key_blocks(self, program: str, walked: int, skipped: int) -> None:
+        """One pass of the split serving step whose indexers scored: the
+        blocks of index keys they walked, and those of the bucket's width
+        (over every row of the pass's dispatches) they did not."""
+        if not self.enabled:
+            return
+        self._index_key_blocks.child((program, "walked")).inc(walked)
+        self._index_key_blocks.child((program, "skipped")).inc(skipped)
 
     def window_pass(self, program: str, live: int, attended: int, full_layers: int,
                     window_layers: int, recycled: int) -> None:

@@ -88,6 +88,8 @@ MAIN_PATH_KERNELS = [
     ("paged_flash_attention", "blk16x32x128", "bfloat16"),
     ("paged_latent_decode_attention", "blk1x32x512", "bfloat16"),  # Kimi-VL: 64 rows, kv 8192, 512 + 64 lanes
     ("paged_latent_flash_attention", "blk1x32x512", "bfloat16"),  # its chunk: 8 rows of 128, 16 heads a latent
+    ("paged_index_scores", "blk1x32x128", "bfloat16"),  # glm-5's indexer, a chunk pass: 8 rows of 128, the 16896 bucket
+    ("paged_index_scores", "blk1x32x128", "bfloat16_decode"),  # its decode program: 32 rows of one query
     ("ragged_paged_attention", "mixed", "bfloat16"),  # ragged mixed step
     ("ragged_paged_attention", "mixed", "int8"),
     ("quant_matmul", "k4096_n14336", "bfloat16"),  # 8B int4 weights
@@ -150,6 +152,37 @@ def test_latent_chunk_kernel_takes_a_selection_at_glm5s_widths(one_chip, q_len):
     assert la.CHUNK_KERNEL in text and la.DECODE_KERNEL not in text
     # the predicate in the kernel's column order: 17 groups x 2 lane groups x (32 blocks x 16 rows)
     assert ("s8[8,17,2,1,128,512]" in text) == (q_len == 128)
+
+
+@pytest.mark.parametrize("rows,q_len,kv", [
+    (32, 1, 8192), (32, 1, 12288), (32, 1, 16896),
+    (8, 8, 16896), (8, 16, 12288), (8, 32, 8192), (8, 64, 16896), (8, 128, 12288),
+])
+def test_index_score_kernel_compiles_at_every_rung_glm5_warms(one_chip, rows, q_len, kv):
+    """``paged_index_scores`` at the shapes glm-5.sparsectx's warm-up compiles
+    it for: the decode program's 32 rows of one query and a chunk pass's 8
+    rows at the q ladder's five widths, over the three kv buckets past
+    ``index_topk`` (16896 is 16.5 groups of 1024: the last group is drawn
+    back), 32 index heads of 128 over blocks of 32. The gate admits each on
+    the chip, the scores come out at the bucket's width in float32, and at
+    128 queries the heads are taken eight a product (1024 score rows)."""
+    from neuronx_distributed_inference_tpu.ops import index_scores as ix
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    keys = sds((5, 16769, 1, 32, 128), jnp.bfloat16)
+    args = (
+        sds((rows, q_len, 32, 128), jnp.bfloat16), sds((rows, q_len, 32), jnp.float32), keys,
+        sds((), jnp.int32), sds((rows, kv // 32), jnp.int32), sds((rows,), jnp.int32),
+    )
+    with force_compiled_kernels(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ix, "on_tpu", lambda: True)  # the gate asks jax.default_backend()
+        assert ix.use_index_kernel(keys, kv)
+        compiled = jax.jit(ix.paged_index_scores.__wrapped__).lower(*args).compile()
+    text = compiled.as_text()
+    assert ix.KERNEL in text and _custom_calls(compiled) == 1
+    assert f"f32[{rows},{q_len},{kv}]" in text
+    if q_len == 128:
+        assert "bf16[8,4,1024,128]" in text  # 4 tiles of 8 heads x 128 positions
 
 
 def test_flash_compiles_at_8b_head_dim(one_chip):
@@ -972,13 +1005,17 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     the 0.978 GiB the dense walk planned, the kernel's VMEM request under
     the chip's), the three scopes of the mechanism hold work, no program
     copies a stream of the pool, and each plans under 14.75 GiB of the
-    chip's 15.75. At a kv bucket of ``index_topk`` the same programs hold
-    the dense latent kernels and no indexer score."""
-    from neuronx_distributed_inference_tpu.ops import kernel_mode, latent_attention
+    chip's 15.75. The indexer in front of it reads its keys where they lie:
+    ``paged_index_scores`` under ``layer.indexer`` in both programs, and no
+    gathered copy of the bucket's index keys, ``bf16[B, 16896, 128]``. At a
+    kv bucket of ``index_topk`` the same programs hold the dense latent
+    kernels and no indexer score."""
+    from neuronx_distributed_inference_tpu.ops import index_scores, kernel_mode, latent_attention
     from neuronx_distributed_inference_tpu.telemetry import device_scopes
 
     # the gates ask jax.default_backend(), which is the CPU here
     monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(index_scores, "on_tpu", lambda: True)
     monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)
     app, params, cache = _abstract_paged_app(chip_mesh(1), "glm-5")
     assert cache.k.shape == (5, 16769, 1, 32, 512) and cache.v.shape == (5, 16769, 1, 16, 128)
@@ -995,6 +1032,9 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     table = device_scopes.scope_table(text)["ops"]
     assert {scope for name, scope in table.items() if name.startswith(kernel)} == {"layer.attn"}
     assert {"layer.indexer", "layer.select", "layer.attn", "layer.kv_write"} <= set(table.values())
+    scorer = index_scores.KERNEL
+    assert {scope for name, scope in table.items() if name.startswith(scorer)} == {"layer.indexer"}
+    assert not re.search(r"bf16\[(8|32),16896,128\]", text)  # the index keys stay in the pool
     for stream in (cache.k, cache.v, cache.extra[0]):
         assert _pool_copies(compiled, stream.shape)[0] == 0
     mem = compiled.memory_analysis()
@@ -1011,8 +1051,9 @@ def test_glm5_serving_step_selects_then_attends_and_fits_the_chip(chip_mesh, pro
     assert _planned_bytes(compiled) < 14.75 * 2**30
     # at index_topk the selection is everything: dense latent attention, as it was
     dense = _compile_step(app, tkg, tkg.example_inputs(2048, q_len=q), params, cache).as_text()
-    assert kernel in dense
-    assert "layer.select" not in set(device_scopes.scope_table(dense)["ops"].values())
+    dense_ops = device_scopes.scope_table(dense)["ops"]
+    assert kernel in dense and not any(name.startswith(scorer) for name in dense_ops)
+    assert "layer.select" not in set(dense_ops.values())
 
 
 # ---------------------------------------------------------------------------
