@@ -245,6 +245,25 @@ def _kda_update_case(rows):
     return build
 
 
+def _kda_chunk_case(q_len, rows=8, slots=128):
+    """Kimi-Linear's KDA geometry (above) in the chunk program: ``rows`` rows
+    of ``q_len`` positions over the stacked state of ``slots`` serving slots."""
+
+    def build():
+        import jax.numpy as jnp
+
+        from neuronx_distributed_inference_tpu.ops import kda_chunk_scan as kc
+
+        L, H, D = 12, 32, 128
+        x = _sds((rows, q_len, H, D), jnp.float32)
+        args = (_sds((L, slots, H, D, D), jnp.float32), _sds((), jnp.int32), x, x, x, x,
+                _sds((rows, q_len, H), jnp.float32), _sds((rows, q_len), jnp.bool_),
+                _sds((rows,), jnp.bool_), _sds((rows,), jnp.int32))
+        return _unjit(kc.kda_chunk_scan), args
+
+    return build
+
+
 def _power_update_case(rows):
     """Brumby-14B's power-retention geometry (8 of its layers, 8 KV heads of
     8704 x 128 read by 5 query heads each) at ``rows`` serving slots: the
@@ -683,6 +702,25 @@ REGISTRY: Tuple[KernelSpec, ...] = (
         # tuning-table entry: nothing was swept on the chip yet
         cases=(KernelCase("rows128", "float32", _kda_update_case(128)),),
     ),
+    # the chunk program's recurrence between sub-chunks on the stacked state
+    # (ops/kda_chunk_scan.py). ``heads``: the heads a tile (the entry's
+    # ``heads_per_block`` keyword overrides it), swept on the chip at the
+    # widest chunk (PERF.md, PR 68); the narrowest width, one sub-chunk of 8,
+    # is a case of its own
+    KernelSpec(
+        name="kda_chunk_scan",
+        site=("kda_chunk_scan.py", "scan_on_stack"),
+        entry="kda_chunk_scan",
+        fallback="neuronx_distributed_inference_tpu.modules.kda:kda_chunk",
+        parity_test="tests/test_kda_chunk_scan.py",
+        lowering_test="tests/test_chip_compile.py",
+        tile_params=("heads",),
+        sweep=(("heads", (2, 4, 8)),),
+        cases=(
+            KernelCase("q128c16x128", "float32", _kda_chunk_case(128)),
+            KernelCase("q8c8x128", "float32", _kda_chunk_case(8)),
+        ),
+    ),
     KernelSpec(
         name="power_state_update",
         site=("power_state_update.py", "power_state_update"),
@@ -745,6 +783,8 @@ HAND_PICKED: Dict[str, Dict[str, Dict[str, int]]] = {
     "ragged_paged_attention": {"*": {"tq": 16}},
     # ssm_state_update.DEFAULT_HEADS_PER_BLOCK
     "ssm_state_update": {"*": {"heads": 16}},
+    # kda_chunk_scan.DEFAULT_HEADS_PER_BLOCK
+    "kda_chunk_scan": {"*": {"heads": 8}},
     "grouped_matmul": {"*": {"tm": 128}},
     "quant_matmul": {"*": {"bn": 256}},
 }

@@ -20,9 +20,8 @@ last ``conv_kernel - 1`` inputs of its depthwise causal convolution over
 Three forms of the one recurrence:
 
 * :func:`kda_step` — one token a row, the definition.
-* :func:`kda_chunk` — a (rows, q) chunk from the incoming state, in the
-  chunked form: inside a sub-chunk of ``chunk_size`` positions, with ``G_t =
-  sum_{s<=t} g_s``,
+* the chunked form — a (rows, q) chunk from the incoming state: inside a
+  sub-chunk of ``chunk_size`` positions, with ``G_t = sum_{s<=t} g_s``,
 
       A_ij = b_i (k_i * e^{G_i - G_j}) . k_j   (j < i)      T = (I + A)^-1 diag(b)
       W = T (K * e^G),  U = T V,  V' = U - W S_0
@@ -33,15 +32,25 @@ Three forms of the one recurrence:
   <= 0`` are ever exponentiated: the pairwise decays of a sub-chunk are
   taken one (i, j, channel) at a time, never as ``e^{G_i} e^{-G_j}``.
   ``(I + A)^-1`` of the strictly lower-triangular ``A`` is the finite series
-  ``sum_k (-A)^k = (I - A)(I + A^2)(I + A^4)...``.
+  ``sum_k (-A)^k = (I - A)(I + A^2)(I + A^4)...``. What does not depend on
+  the state (``W``, ``U``, ``Q e^G``, ``K e^{G_C - G}``, ``P``, ``G_C``) is
+  :func:`chunk_operands`; the recurrence between sub-chunks then runs
+
+  - as ``ops/kda_chunk_scan.py``, a kernel on the STACKED state in place
+    that keeps a head's ``S`` in VMEM across a row's sub-chunks and moves
+    nothing for a row with no valid position (the chunk program, wherever
+    ``ops/kernel_mode.use_kda_chunk_scan`` admits the call), or
+  - as the ``lax.scan`` of :func:`kda_chunk` over the rows' gathered state:
+    the fallback (heads that are not whole lane rows, a chunk that is not
+    whole sub-chunks, a sharded mesh) and the kernel's reference.
 * ``ops/kda_state_update.py`` — one token a row on the STACKED state in
   place (the decode program), held to :func:`kda_step`.
 
 The contract of ``modules/ssm.py`` holds here too: an invalid position (a
 padded chunk tail, a row that sits a pass out) leaves the conv tail and ``S``
 bit-identical (it sees ``g = 0`` and ``b = 0``: decay 1, no delta, and a row
-with no valid position is passed through by a select); valid positions are a
-prefix of the row.
+with no valid position is passed through: by a select in the scan, untouched
+by the kernel); valid positions are a prefix of the row.
 """
 
 from __future__ import annotations
@@ -155,6 +164,50 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     return inv
 
 
+def chunk_operands(q, k, v, g, beta, valid, chunk_size: int = 16):
+    """What the chunked form takes between sub-chunks, from a chunk's (R, Q,
+    H, ...) inputs (as :func:`kda_chunk` takes them): ``(W, U, q_in, k_out)``
+    (R, H, n, c, D), ``P`` (R, H, n, c, c) and ``G_end`` (R, H, n, D), float32,
+    for ``n`` sub-chunks of ``c = min(chunk_size, Q)`` positions, the last
+    zero padded (``g = 0``, ``b = 0``: no-ops). One sub-chunk ``t`` from the
+    state ``S`` before it:
+
+        V' = U_t - W_t S,   O_t = q_in_t S + P_t V',   S <- diag(e^{G_end_t}) S + k_out_t^T V'
+
+    Both executors of that recurrence call this (the scan of
+    :func:`kda_chunk`, the kernel of ``ops/kda_chunk_scan.py``)."""
+    f32 = jnp.float32
+    R, Q, H, D = q.shape
+    g = jnp.where(valid[..., None, None], g.astype(f32), 0.0)
+    beta = jnp.where(valid[..., None], beta.astype(f32), 0.0)
+    c = min(int(chunk_size), Q)
+    n = -(-Q // c)
+    pad = n * c - Q
+
+    def split(a):  # (R, Q, H, ...) -> (R, H, n, c, ...), zero padded
+        a = jnp.pad(a.astype(f32), ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape((R, n, c) + a.shape[2:])
+        return jnp.transpose(a, (0, 3, 1, 2) + tuple(range(4, a.ndim)))
+
+    q, k, v, g, beta = split(q), split(k), split(v), split(g), split(beta)
+    G = jnp.cumsum(g, axis=3)  # (R, H, n, c, D), inclusive, <= 0
+    # the pairwise decays, for A's rows (k_i) and P's rows (q_i) in one pass:
+    # M[i, j] = sum_d x_id k_jd e^{G_i - G_j}, never exponentiated above 0
+    x = jnp.concatenate([k, q], axis=3)  # (R, H, n, 2c, D)
+    diff = jnp.concatenate([G, G], axis=3)[..., :, None, :] - G[..., None, :, :]
+    M = jnp.sum(x[..., :, None, :] * k[..., None, :, :] * jnp.exp(jnp.minimum(diff, 0.0)), axis=-1)
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    A = jnp.where(i > j, M[..., :c, :], 0.0) * beta[..., None]
+    P = jnp.where(i >= j, M[..., c:, :], 0.0)
+    T = _unit_lower_inverse(A) * beta[..., None, :]
+    eG = jnp.exp(G)
+    W = jnp.matmul(T, k * eG, precision=_HI)  # (R, H, n, c, D)
+    U = jnp.matmul(T, v, precision=_HI)
+    G_end = G[..., -1, :]  # (R, H, n, D)
+    k_out = k * jnp.exp(G_end[..., None, :] - G)  # k_j decayed from j to the sub-chunk's end
+    return W, U, q * eG, k_out, P, G_end
+
+
 def kda_chunk(
     q: jax.Array,  # (R, Q, H, D) normalised and scaled
     k: jax.Array,  # (R, Q, H, D) normalised
@@ -169,49 +222,21 @@ def kda_chunk(
     float32, the state after each row's valid positions). Matrix products
     run at ``Precision.HIGHEST``: their operands are float32 (the state, the
     cumulative decays), which the default would round to bf16."""
-    f32 = jnp.float32
     R, Q, H, D = q.shape
-    g = jnp.where(valid[..., None, None], g.astype(f32), 0.0)
-    beta = jnp.where(valid[..., None], beta.astype(f32), 0.0)
-    c = min(int(chunk_size), Q)
-    n = -(-Q // c)
-    pad = n * c - Q
-
-    def split(a):  # (R, Q, H, ...) -> (n, R, H, c, ...), zero padded (g = 0, b = 0: no-ops)
-        # the sub-chunks lead from here on: the scan below then takes its steps
-        # off the major axis (read on the chip, PR 61: with them third, slicing a
-        # step's operands was a third of the scan's time)
-        a = jnp.pad(a.astype(f32), ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-        a = a.reshape((R, n, c) + a.shape[2:])
-        return jnp.transpose(a, (1, 0, 3, 2) + tuple(range(4, a.ndim)))
-
-    q, k, v, g, beta = split(q), split(k), split(v), split(g), split(beta)
-    G = jnp.cumsum(g, axis=3)  # (n, R, H, c, D), inclusive, <= 0
-    # the pairwise decays, for A's rows (k_i) and P's rows (q_i) in one pass:
-    # M[i, j] = sum_d x_id k_jd e^{G_i - G_j}, never exponentiated above 0
-    x = jnp.concatenate([k, q], axis=3)  # (n, R, H, 2c, D)
-    diff = jnp.concatenate([G, G], axis=3)[..., :, None, :] - G[..., None, :, :]
-    M = jnp.sum(x[..., :, None, :] * k[..., None, :, :] * jnp.exp(jnp.minimum(diff, 0.0)), axis=-1)
-    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    A = jnp.where(i > j, M[..., :c, :], 0.0) * beta[..., None]
-    P = jnp.where(i >= j, M[..., c:, :], 0.0)
-    T = _unit_lower_inverse(A) * beta[..., None, :]
-    eG = jnp.exp(G)
-    W = jnp.matmul(T, k * eG, precision=_HI)  # (n, R, H, c, D)
-    U = jnp.matmul(T, v, precision=_HI)
-    G_end = G[..., -1, :]  # (n, R, H, D)
-    k_out = k * jnp.exp(G_end[..., None, :] - G)  # k_j decayed from j to the sub-chunk's end
-    q_in = q * eG
+    # the sub-chunks lead: the scan takes its steps off the major axis (read on
+    # the chip, PR 61: with them third, slicing a step's operands was a third
+    # of the scan's time)
+    operands = tuple(jnp.moveaxis(a, 2, 0) for a in chunk_operands(q, k, v, g, beta, valid, chunk_size))
 
     def body(s, t):  # s (R, H, D, D); one sub-chunk
-        W_t, U_t, q_t, P_t, k_t, end_t = t
+        W_t, U_t, q_t, k_t, P_t, end_t = t
         v_new = U_t - jnp.matmul(W_t, s, precision=_HI)  # (R, H, c, D)
         o_t = jnp.matmul(q_t, s, precision=_HI) + jnp.matmul(P_t, v_new, precision=_HI)
         s = s * jnp.exp(end_t)[..., None] + jnp.einsum("rhck,rhcv->rhkv", k_t, v_new, precision=_HI)
         return s, o_t
 
-    new, o = jax.lax.scan(body, state, (W, U, q_in, P, k_out, G_end))  # o (n, R, H, c, D)
-    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(R, n * c, H, D)[:, :Q]
+    new, o = jax.lax.scan(body, state, operands)  # o (n, R, H, c, D)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(R, -1, H, D)[:, :Q]
     new = jnp.where(jnp.any(valid, axis=1)[:, None, None, None], new, state)
     return o, new
 
@@ -255,10 +280,14 @@ def kda_mixer(m: dict, x: jax.Array, state: DeltaState, li, valid, reset, spec: 
     index ``li`` for the ``valid`` (R, Q) positions; ``reset`` (R,) rows start
     from zero; ``slots`` as ``models/granite_hybrid.mamba_layer`` takes them
     (the chunk program's rows carry their slot; None: row r owns slot r, the
-    decode program, which runs ``ops/kda_state_update``). Returns (the
+    decode program, which runs ``ops/kda_state_update``). A chunk runs
+    ``ops/kda_chunk_scan`` on the stacked state where the gate admits it
+    (the kernel addresses ``(li, slot)`` itself: no rows' state is gathered
+    or scattered), else :func:`kda_chunk` over the rows' state. Returns (the
     mixer's output (R, Q, hidden), the state)."""
-    from neuronx_distributed_inference_tpu.ops.kernel_mode import kernel_interpret
+    from neuronx_distributed_inference_tpu.ops import kernel_mode
     from neuronx_distributed_inference_tpu.ops.quant import linear
+    from neuronx_distributed_inference_tpu.parallel.sharding import head_shard_degree
 
     R, Q, _ = x.shape
     H, D, d_inner = spec.num_heads, spec.head_dim, spec.d_inner
@@ -276,9 +305,16 @@ def kda_mixer(m: dict, x: jax.Array, state: DeltaState, li, valid, reset, spec: 
 
         o, new = kda_state_update(
             state.ssm, li, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], valid[:, 0], reset,
-            interpret=kernel_interpret(),
+            interpret=kernel_mode.kernel_interpret(),
         )
         o = o[:, None]
+    elif kernel_mode.use_kda_chunk_scan(D, Q, spec.chunk_size, head_shard_degree()):
+        from neuronx_distributed_inference_tpu.ops import kda_chunk_scan as scan
+
+        o, new = scan.kda_chunk_scan(
+            state.ssm, li, q, k, v, g, beta, valid, reset, slots, chunk_size=spec.chunk_size,
+            interpret=kernel_mode.kernel_interpret(),
+        )
     else:
         s = ssm.rows_state(state.ssm, li, reset, slots)
         o, s = kda_chunk(q, k, v, g, beta, s, valid, chunk_size=spec.chunk_size)

@@ -50,6 +50,8 @@ grouped expert matmul         TPU, plain experts in the rows' dtype on the
 ragged mixed-step             TPU backend, heads divide the degree
 int4 quant matmul             TPU backend + single shard (see
                               :func:`use_quant_matmul`)
+KDA chunk scan                TPU backend (or interpreted off it), one head
+                              shard (see :func:`use_kda_chunk_scan`)
 ============================  ==============================================
 """
 
@@ -323,6 +325,27 @@ def use_ragged(spec, total_q: int, ragged_q_tile: int = 16) -> bool:
     if spec.use_flash_kernel:
         return True
     return on_tpu()
+
+
+def use_kda_chunk_scan(head_dim: int, q_len: int, chunk_size: int, shards: int) -> bool:
+    """Gate for the chunked delta rule's sub-chunk recurrence as a kernel on
+    the stacked state (ops/kda_chunk_scan.py; ``modules/kda.kda_mixer`` asks
+    with its spec's ``head_dim`` and ``chunk_size``, the chunk's positions a
+    row and the ambient mesh's head shards): on the chip, or off it where
+    kernels run interpreted; one shard (a ``pallas_call`` has no partitioning
+    rule); a head's state on whole lane rows; the chunk made of whole
+    sub-chunks ``min(chunk_size, q_len)`` of whole sublane tiles (8 / 16 / 32
+    / 64 / 128 positions under a sub-chunk of 16: the 8 as one sub-chunk).
+    No option forces it: what it cannot serve keeps the scan of
+    ``modules/kda.kda_chunk``."""
+    sub = min(int(chunk_size), q_len)
+    return (
+        (on_tpu() or kernel_interpret())
+        and shards == 1
+        and head_dim % 128 == 0
+        and sub % 8 == 0
+        and q_len % sub == 0
+    )
 
 
 # --- int4 quant matmul (ops/quant_matmul.py) -------------------------------

@@ -57,16 +57,17 @@ from neuronx_distributed_inference_tpu.modules.power_retention import (
     pairs_per_tile,
     phi,
 )
+from neuronx_distributed_inference_tpu.ops.row_modes import (
+    LIVE as _LIVE,
+    NONE_LIVE as _NONE_LIVE,
+    TO_LAST as _TO_LAST,
+    _row_modes,
+)
 
 #: block pairs a tile: 34 x 64 = 2176 rows of d float32, 1.1 MB in and as much
 #: out (read on the chip, PR 66, a layer at 16 rows: 17 / 34 / 68 pairs a tile
 #: 2.546 / 2.454 / 2.664 ms; the pair loop is unrolled, so compile time goes with it)
 PAIRS_PER_TILE = 34
-
-#: what a row's grid steps do (``mode``): advance its state; name the next
-#: live step's block; name the last live step's block; (no live row) copy
-#: one block through
-_LIVE, _TO_NEXT, _TO_LAST, _NONE_LIVE = 0, 1, 2, 3
 
 
 def _round_up(n: int, m: int) -> int:
@@ -124,19 +125,6 @@ def _kernel(li_ref, mode_ref, er_ref, fresh_ref, pi_ref, pj_ref, scal_ref, cols_
     # a select on the scalar, not a product by 0: a non-finite state does not survive a reset
     pl.when((mode == _LIVE) & (fresh_ref[r] != 0))(lambda: advance(True))
     pl.when((mode == _LIVE) & (fresh_ref[r] == 0))(lambda: advance(False))
-
-
-def _row_modes(live: jax.Array):
-    """(mode, effective row) of each row (:data:`_LIVE` ...): a row that is
-    not live names the next live row, else the last."""
-    R = live.shape[0]
-    idx = jnp.arange(R, dtype=jnp.int32)
-    nxt = jax.lax.cummin(jnp.where(live, idx, R), reverse=True)  # next live row at or after r
-    last = jnp.max(jnp.where(live, idx, -1))
-    mode = jnp.where(live, _LIVE, jnp.where(nxt < R, _TO_NEXT, _TO_LAST))
-    mode = jnp.where(last < 0, _NONE_LIVE, mode)
-    row = jnp.where(nxt < R, nxt, jnp.maximum(last, 0))
-    return mode.astype(jnp.int32), row.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))

@@ -405,6 +405,19 @@ class ServingSession:
         self.slot_state = state is not None
         self.slot_state_kind = getattr(state, "KIND", None)
         self.slot_state_bytes = state.nbytes if self.slot_state else 0
+        # delta-rule layers: whether a chunk pass of q positions a row runs
+        # their recurrence as the chunk-scan kernel (what modules/kda.kda_mixer
+        # asks of the same gate), over how many layers
+        self.kda_layers = 0
+        if self.slot_state_kind == "kda":
+            from neuronx_distributed_inference_tpu.ops.kernel_mode import use_kda_chunk_scan
+
+            kspec = app.builder.kda_spec()
+            self.kda_layers = state.ssm.shape[0]
+            self._kda_chunk_kernel = functools.partial(
+                use_kda_chunk_scan, kspec.head_dim, chunk_size=kspec.chunk_size,
+                shards=app.spec.attn.model_parallel,
+            )
         # a stack of window and full attention layers: the window layers' ring
         # of blocks a slot (block_kvcache.WindowRing) beside the allocator's
         # pool over the full layers. A slot holds its ring from admission to
@@ -2117,6 +2130,10 @@ class ServingSession:
                               kind=self.slot_state_kind)
         elif self.slot_state_kind == "latent_carry":
             self.tel.carry_pass(program, rows)
+        if program == "chunk" and self.kda_layers and self._kda_chunk_kernel(shape[1]):
+            self.tel.kda_chunk_rows(
+                rows * self.kda_layers, (dispatches * shape[0] - rows) * self.kda_layers
+            )
         if self.latent_layers:
             self.tel.latent_pass(program, tokens * self.latent_layers)
         if self.sparse_layers and self.tel.enabled:

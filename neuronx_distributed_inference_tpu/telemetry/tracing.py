@@ -403,6 +403,16 @@ class TelemetrySession:
             "nxdi_kda_state_bytes",
             "HBM of the per-slot delta-rule state (conv tails + the float32 "
             "matrix state a head, every KDA layer, every slot)")
+        self._kda_chunk_rows = r.counter(
+            "nxdi_kda_chunk_rows_total",
+            "rows x KDA layers of the chunk program's dispatches where its "
+            "delta-rule recurrence runs as the chunk-scan kernel on the "
+            "stacked state (ops/kda_chunk_scan.py): kind=advanced, a row's "
+            "state read once and written once; kind=skipped, a row that "
+            "prefills nothing, for which the kernel moved nothing (advanced + "
+            "skipped = dispatches x chunk rows x KDA layers). Not fed where "
+            "the layers take the scan",
+            labels=("kind",))
         self._power_rows = r.counter(
             "nxdi_power_rows_advanced_total",
             "rows whose power-retention state a dispatch of the split serving "
@@ -1324,6 +1334,15 @@ class TelemetrySession:
         bytes_held.set(state_bytes)
         if resets:
             resets_total.inc(resets)
+
+    def kda_chunk_rows(self, advanced: int, skipped: int) -> None:
+        """One chunk pass whose KDA layers ran the chunk-scan kernel: the
+        rows x layers whose state it read and wrote, and those it moved
+        nothing for."""
+        if not self.enabled:
+            return
+        self._kda_chunk_rows.child(("advanced",)).inc(advanced)
+        self._kda_chunk_rows.child(("skipped",)).inc(skipped)
 
     def carry_pass(self, program: str, rows: int) -> None:
         """One pass of the split serving step over a model that keeps a

@@ -96,6 +96,8 @@ MAIN_PATH_KERNELS = [
     ("ssm_state_update", "h64g1x64x128", "float32"),  # granite-4.0-h-micro decode: 48 slots, one group, 32 heads a tile
     ("ssm_state_update", "h64g8x64x128", "float32"),  # nemotron-3-nano-30b-a3b decode: 64 slots, 8 groups of B/C
     ("kda_state_update", "rows128", "float32"),  # kimi-linear-48b-a3b decode, 128 slots
+    ("kda_chunk_scan", "q128c16x128", "float32"),  # its chunk program: 8 rows of 128 = 8 sub-chunks of 16 on the stacked state
+    ("kda_chunk_scan", "q8c8x128", "float32"),  # the narrowest chunk width: one sub-chunk of 8
     ("power_state_update", "rows16", "float32"),  # brumby-14b-base decode, 16 slots: tiles of 1088 x 128 of a KV head's 8704
     ("grouped_matmul", "k2048_n2048", "bfloat16"),  # zaya1-8b's chunk program: 1024 rows, 16 experts of a 20-layer stack
     ("grouped_matmul", "k2048_n768", "bfloat16"),  # sdar-30b-a3b's gate and up: 8192 rows, 128 experts
@@ -183,6 +185,33 @@ def test_index_score_kernel_compiles_at_every_rung_glm5_warms(one_chip, rows, q_
     assert f"f32[{rows},{q_len},{kv}]" in text
     if q_len == 128:
         assert "bf16[8,4,1024,128]" in text  # 4 tiles of 8 heads x 128 positions
+
+
+@pytest.mark.parametrize("q_len", [8, 16, 32, 64, 128])
+def test_kda_chunk_scan_compiles_at_every_chunk_width_kimi_linear_warms(one_chip, q_len, monkeypatch):
+    """``kda_chunk_scan`` at the widths kimi-linear-48b-a3b.longgen's warm-up
+    compiles its chunk program for (modules/autobucketing.
+    generate_chunk_q_buckets: 8 / 16 / 32 / 64 / 128 positions a row, 8 rows),
+    on the stacked state of 12 KDA layers x 128 slots x 32 heads x 128 x 128
+    float32: the gate admits each on the chip (the 8 as one sub-chunk of 8),
+    ONE kernel, the state aliased in and out (no copy of it, whole, a layer's
+    or the rows'), no loop of the scan, ``o`` leaves as (rows, q, heads, 128)."""
+    from neuronx_distributed_inference_tpu.ops import kda_chunk_scan as kc, kernel_mode
+
+    monkeypatch.setattr(kernel_mode, "on_tpu", lambda: True)  # the gate asks jax.default_backend()
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state, x = sds((12, 128, 32, 128, 128)), sds((8, q_len, 32, 128))
+    args = (state, sds((), jnp.int32), x, x, x, x, sds((8, q_len, 32)), sds((8, q_len), jnp.bool_),
+            sds((8,), jnp.bool_), sds((8,), jnp.int32))
+    with force_compiled_kernels():
+        assert kernel_mode.use_kda_chunk_scan(128, q_len, 16, 1)
+        compiled = jax.jit(kc.kda_chunk_scan.__wrapped__, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert kc.KERNEL in text and _custom_calls(compiled) == 1
+    assert " while(" not in text
+    for shape in (state.shape, state.shape[1:], (8,) + state.shape[2:]):
+        assert not _copies_of(compiled, "f32", shape)
+    assert f"f32[8,{q_len},32,128]" in text
 
 
 def test_flash_compiles_at_8b_head_dim(one_chip):
@@ -640,6 +669,13 @@ def _copies_of(compiled, dtype, shape):
         line for line in compiled.as_text().splitlines()
         if re.search(r"= " + re.escape(want) + r"\{[^}]*\} copy\(", line)
     ]
+
+
+def _loops_under(text, scope):
+    """The ``while`` ops of a compiled program traced under the named scope
+    (a ``lax.scan`` inside a layer; the stack's own loop over layers carries
+    no layer scope)."""
+    return [line for line in text.splitlines() if " while(" in line and scope + "/" in line]
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
@@ -1184,12 +1220,14 @@ def test_kimi_linear_serving_step_updates_the_state_in_place_and_fits_the_chip(
     ``layer.kda`` (the state aliased in and out: no copy of the state's
     shape, whole or one layer's) and ``paged_latent_decode_attention``; the
     experts are the batched products over the 16 held. chunk (8 x 128):
-    ``paged_latent_flash_attention``, the chunked delta rule as XLA's own
-    products (no state kernel) and the three grouped products of an expert
-    layer as ``grouped_matmul`` on the stacks in place. Neither copies the
+    ``paged_latent_flash_attention``, the chunked delta rule's recurrence
+    between sub-chunks as ``kda_chunk_scan`` under ``layer.kda`` on the stacked
+    state in place (no ``kda_state_update``, no loop of the scan, no rows'
+    state gathered) and the three grouped products of an expert layer as
+    ``grouped_matmul`` on the stacks in place. Neither copies the
     pool; each plans under 14.75 GiB."""
     from neuronx_distributed_inference_tpu.models.granite_hybrid import layer_plan
-    from neuronx_distributed_inference_tpu.ops import kernel_mode, latent_attention
+    from neuronx_distributed_inference_tpu.ops import kda_chunk_scan, kernel_mode, latent_attention
     from neuronx_distributed_inference_tpu.telemetry import device_scopes
 
     # the gates ask jax.default_backend(), which is the CPU here
@@ -1216,16 +1254,21 @@ def test_kimi_linear_serving_step_updates_the_state_in_place_and_fits_the_chip(
     assert _pool_copies(compiled, cache.k.shape)[0] == 0 and _pool_copies(compiled, cache.v.shape)[0] == 0
     gmm = [name for name in table if name.startswith("grouped_matmul")]
     kda = [name for name in table if name.startswith("kda_state_update")]
+    scan = [name for name in table if name.startswith(kda_chunk_scan.KERNEL)]
     stacks = [(16, 2304, 1024), (16, 1024, 2304)]
     if program == "decode":
         assert "paged_latent_decode_attention" in text and not gmm
-        assert kda and {table[c] for c in kda} == {"layer.kda"}
+        assert kda and {table[c] for c in kda} == {"layer.kda"} and not scan
         # 128 rows over 16 held experts: the batched form reads the stacks as stored (the
         # unbatched one re-laid both whole stacks, 2 x 1.05 GiB, on every step: modules/moe.py)
         assert not re.search(r"= \w+\[15,16,\d+,\d+\]\S* copy\(", text)
         assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
     else:
         assert "paged_latent_flash_attention" in text and not kda
+        # the recurrence between sub-chunks: the kernel on the stacked state, not the scan
+        # over the 8 rows' gathered state (no loop under the layer's scope)
+        assert scan and {table[c] for c in scan} == {"layer.kda"}
+        assert not _loops_under(text, "layer.kda")
         assert not _stack_shaped(compiled, stacks), _stack_shaped(compiled, stacks)[:3]
         assert gmm and len(gmm) % 3 == 0 and {table[c] for c in gmm} == {"layer.moe.experts"}
     assert not _work_under_no_scope(compiled), _work_under_no_scope(compiled)[:5]
@@ -1236,6 +1279,10 @@ def test_kimi_linear_serving_step_updates_the_state_in_place_and_fits_the_chip(
         # expert products copy no stack of expert weights
         narrow = _compile_step(app, tkg, tkg.example_inputs(8192, q_len=8), params, cache)
         assert "paged_latent_decode_attention" in narrow.as_text()
+        # and the kernel at one sub-chunk of 8; at this width nothing else has the shape of the
+        # 8 rows' state, so: no rows' state gathered, scanned over or selected at all
+        assert kda_chunk_scan.KERNEL in narrow.as_text() and not _loops_under(narrow.as_text(), "layer.kda")
+        assert "f32[8,32,128,128]" not in narrow.as_text()
         assert not re.search(r"= \w+\[15,16,\d+,\d+\]\S* copy\(", narrow.as_text())
         assert _planned_bytes(narrow) < 14.75 * 2**30
     mem = compiled.memory_analysis()
